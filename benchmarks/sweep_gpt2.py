@@ -66,13 +66,14 @@ def run_config(jax, jnp, np, optax, *, batch: int, seq: int, remat: bool,
     n_params = cfg.n_params_approx
     fpt = 6 * n_params + 12 * cfg.n_layer * cfg.n_embd * seq
     dev = jax.devices()[0]
-    peaks = {"v4": 137e12, "v5p": 459e12, "v5": 197e12, "v6": 918e12}
-    kind = getattr(dev, "device_kind", "").lower()
-    peak = next((v for k, v in peaks.items() if k in kind), 197e12)
-    mfu = toks * fpt / peak if dev.platform != "cpu" else 0.0
+    mfu = None  # the CPU smoke run has no peak to be measured against
+    if dev.platform != "cpu":
+        from raytpu.core.chip_specs import chip_spec
+
+        mfu = round(toks * fpt / chip_spec(dev.device_kind).bf16_flops, 4)
     return {
         "batch": batch, "seq": seq, "remat": remat, "attn": attn,
-        "tokens_per_sec": round(toks, 1), "mfu": round(mfu, 4),
+        "tokens_per_sec": round(toks, 1), "mfu": mfu,
         "steps": steps, "wall_s": round(dt, 3),
         "compile_s": round(compile_s, 1), "loss": loss_host,
     }
@@ -90,13 +91,6 @@ def main() -> None:
         os.environ["JAX_PLATFORMS"] = "cpu"
 
     import jax
-
-    if smoke:
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
-
     import jax.numpy as jnp
     import numpy as np
     import optax

@@ -2,6 +2,8 @@
 device-ready batches (reference analogue: Ray Data quickstart).
 
   python examples/data_pipeline.py
+On a machine without an accelerator (or to leave one alone):
+  JAX_PLATFORMS=cpu python examples/data_pipeline.py
 """
 
 import os
@@ -10,12 +12,6 @@ import sys
 # Run in-repo without installation.
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

@@ -2,6 +2,8 @@
 RLlib's PPO quickstart).
 
   python examples/rllib_ppo.py
+On a machine without an accelerator (or to leave one alone):
+  JAX_PLATFORMS=cpu python examples/rllib_ppo.py
 """
 
 import os
@@ -10,13 +12,6 @@ import sys
 # Run in-repo without installation.
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import raytpu
 from raytpu.rllib import PPOConfig

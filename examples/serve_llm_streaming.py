@@ -1,7 +1,7 @@
 """Stream LLM tokens from the paged-KV inference engine behind serve
 (reference analogue: vLLM's continuous batching behind Ray Serve).
 
-Deploys ``LLMDeployment`` (tiny CPU Llama), fires two staggered
+Deploys ``LLMDeployment`` (a tiny Llama), fires two staggered
 requests with different prompt/output lengths, and prints tokens as
 they stream back — both sequences share decode iterations inside the
 single engine while each client sees only its own stream. A second
@@ -11,6 +11,8 @@ graft them from the prefix cache and prefill only their 3-token tails
 (watch ``prefill_tokens`` vs ``prefix_cache.hit_tokens``).
 
   python examples/serve_llm_streaming.py
+On a machine without an accelerator (or to leave one alone):
+  JAX_PLATFORMS=cpu python examples/serve_llm_streaming.py
 """
 
 import os
@@ -19,8 +21,6 @@ import sys
 # Run in-repo without installation.
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import threading
 import time

@@ -2,6 +2,8 @@
 Ray Serve streaming responses).
 
   python examples/serve_token_streaming.py
+(on a machine without an accelerator, or to leave one alone:
+  JAX_PLATFORMS=cpu python examples/serve_token_streaming.py)
 then:
   curl -N -H 'Accept: text/event-stream' localhost:8000/generate?prompt=2
 """
@@ -13,14 +15,9 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import time
 
+import jax
 import jax.numpy as jnp
 
 import raytpu

@@ -7,12 +7,12 @@ shards with a DistributedSampler. When ready for TPU, move the loop to
 ``JaxTrainer`` (see train_gpt2.py) — the surrounding config is identical.
 
 Run:  python examples/torch_migration.py
+(nothing here needs an accelerator; JAX_PLATFORMS=cpu leaves one alone)
 """
 
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -52,9 +52,6 @@ def train_loop_per_worker(config):
 
 
 def main():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import raytpu
     from raytpu.train import RunConfig, ScalingConfig, TorchTrainer
 

@@ -1,10 +1,10 @@
 """Distributed GPT-2 pretraining with JaxTrainer (reference analogue:
 Ray Train's TorchTrainer DDP quickstart).
 
-Runs on the virtual CPU mesh out of the box:
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
-      python examples/train_gpt2.py
-On TPU hardware, drop the env vars and scale num_workers to your slice.
+  python examples/train_gpt2.py
+uses whatever JAX finds (on a TPU the attention is the Pallas flash
+kernel). On a machine without an accelerator, or to leave one alone:
+  JAX_PLATFORMS=cpu python examples/train_gpt2.py
 """
 
 import os
@@ -14,15 +14,9 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -34,14 +28,12 @@ from raytpu.train import JaxTrainer, ScalingConfig
 def train_loop(config):
     from raytpu import train
 
-    cfg = dataclasses.replace(
-        GPT2Config.tiny(), dtype=jnp.float32, attn_impl="reference",
-        remat="dots")
+    cfg = dataclasses.replace(GPT2Config.tiny(), remat="dots")
     model = GPT2(cfg)
     params = init_params(model, cfg, batch=config["batch"])
     opt = optax.adamw(config["lr"])
     opt_state = opt.init(params)
-    step = jax.jit(make_train_step(model, opt))
+    step = jax.jit(make_train_step(model, opt), donate_argnums=(0, 1))
     tokens = jax.random.randint(
         jax.random.PRNGKey(train.get_context().get_world_rank()),
         (config["batch"], cfg.block_size), 0, cfg.vocab_size, jnp.int32)
@@ -58,8 +50,10 @@ def main():
         scaling_config=ScalingConfig(num_workers=2),
     )
     result = trainer.fit()
-    print("final metrics:", result.metrics)
     raytpu.shutdown()
+    if result.error is not None:  # fit() reports a failed gang here
+        raise result.error
+    print("final metrics:", result.metrics)
 
 
 if __name__ == "__main__":

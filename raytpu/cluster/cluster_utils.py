@@ -57,7 +57,6 @@ class Cluster:
                  head_storage: Optional[str] = None,
                  addr_file: Optional[str] = None):
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # Child processes must import raytpu from the same tree as us even
         # when it isn't pip-installed.
         import raytpu

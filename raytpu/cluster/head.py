@@ -1530,7 +1530,15 @@ class HeadServer:
                     del self._object_waiters[oid]
 
     def _health_loop(self) -> None:
+        last_tick = time.monotonic()
         while not self._stop.wait(CHECK_PERIOD_S):
+            # This loop oversleeping means this process, or the whole
+            # host, stood still: four TPU runtimes starting at once froze
+            # a v5e host for 16 s. No heartbeat could be received in that
+            # stretch, so it counts against no node.
+            tick = time.monotonic()
+            stalled = tick - last_tick - CHECK_PERIOD_S
+            last_tick = tick
             if self._fenced:
                 # A superseded head must not keep declaring nodes dead
                 # or firing alerts — the elected head owns the cluster.
@@ -1541,6 +1549,9 @@ class HeadServer:
             now = time.monotonic()
             dead = []
             with self._lock:
+                if stalled > CHECK_PERIOD_S:
+                    for entry in self._nodes.values():
+                        entry.last_heartbeat += stalled
                 for entry in self._nodes.values():
                     if entry.alive and \
                             now - entry.last_heartbeat > HEARTBEAT_TIMEOUT_S:

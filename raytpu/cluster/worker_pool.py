@@ -28,7 +28,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from raytpu.cluster import constants as tuning
 from raytpu.cluster.protocol import RpcClient
 from raytpu.core.config import cfg
-from raytpu.util import errors
+from raytpu.util import compile_cache, errors
 from raytpu.util import tracing
 from raytpu.util.failpoints import DROP, failpoint
 from raytpu.util.events import record_event
@@ -47,17 +47,44 @@ def runtime_env_hash(runtime_env: Optional[dict]) -> str:
         return "unhashable"
 
 
+# Chip bounds of a worker holding part of a four-chip (2x2) host; a
+# worker holding the whole host keeps the host's own.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+# libtpu's default mesh-controller port; every process on a host needs
+# its own.
+_MESH_CONTROLLER_PORT = 8476
+
+
 def chip_env(chips: Tuple[int, ...]) -> Dict[str, str]:
-    """Per-worker TPU visibility env (reference ``tpu.py:30-49``)."""
+    """Per-worker TPU visibility env (reference ``tpu.py:30-49``).
+
+    A chip belongs to one process at a time, and a process that imports
+    JAX takes every chip it can see. So a worker that leased chips sees
+    exactly those, and one that leased none is pinned to the CPU — it
+    could otherwise take a chip from the worker that leased it.
+    """
     if not chips:
-        return {"RAYTPU_VISIBLE_CHIPS": ""}
+        return {"RAYTPU_VISIBLE_CHIPS": "", "JAX_PLATFORMS": "cpu"}
     ids = ",".join(str(c) for c in chips)
-    return {
+    env = {
         "RAYTPU_VISIBLE_CHIPS": ids,
         "TPU_VISIBLE_CHIPS": ids,
-        "TPU_CHIPS_PER_PROCESS_BOUNDS": f"1,{len(chips)},1",
-        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_VISIBLE_DEVICES": ids,
     }
+    bounds = _CHIP_BOUNDS.get(len(chips))
+    if bounds is not None:
+        port = _MESH_CONTROLLER_PORT + min(chips)
+        env.update({
+            # Both spellings: a TPU VM's own environment sets the
+            # *_HOST_* ones for the whole host.
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+            "TPU_CHIPS_PER_HOST_BOUNDS": bounds,
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_HOST_BOUNDS": "1,1,1",
+            "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+            "TPU_MESH_CONTROLLER_PORT": str(port),
+        })
+    return env
 
 
 class WorkerHandle:
@@ -292,6 +319,8 @@ class WorkerPool:
         # env=True (or inherited by this daemon) reach the worker too.
         env = dict(os.environ)
         env.update(self.base_env)
+        if h.chips:  # the workers that compile for a chip
+            env.update(compile_cache.spawn_env())
         env.update(chip_env(h.chips))
         # The host this node is reachable at — gang rendezvous publishes
         # coordinator addresses on it (a worker cannot otherwise know its

@@ -156,6 +156,8 @@ declare_env("RAYTPU_HEARTBEAT_PERIOD_S", "node heartbeat send period (s)")
 declare_env("RAYTPU_HEALTH_CHECK_PERIOD_S", "head health-check sweep period (s)")
 declare_env("RAYTPU_HOST_IP", "advertised address override for this host")
 declare_env("RAYTPU_NUM_TPUS", "TPU chip count override for topology detection")
+declare_env("RAYTPU_VISIBLE_CHIPS",
+            "chips this worker leased (set by the node at spawn; empty = none)")
 
 # Control-plane fast path (cluster/constants.py, cluster/protocol.py,
 # cluster/client.py): wire-frame coalescing + pipelined task submission.
@@ -244,8 +246,6 @@ declare_env("RAYTPU_PROFILE_BUFFER_MAX",
             "per-process pending profile-frame buffer cap")
 declare_env("RAYTPU_PROFILE_STACKS_MAX",
             "hottest stacks kept per profile snapshot before (other)")
-declare_env("RAYTPU_CHIP_PEAK_FLOPS",
-            "per-chip peak FLOP/s override for MFU accounting")
 
 # Disaggregated serving plane (serve router + inference/disagg.py).
 declare_env("RAYTPU_SERVE_PROBE_TIMEOUT_S",

@@ -2,31 +2,21 @@
 
 Reference analogue: the Cython/C++ boundary of the reference's scheduling
 substrate (``src/ray/common/scheduling/`` reached from Python through
-``_raylet.pyx``). Build: ``make -C src`` (auto-attempted on first import).
-Falls back cleanly — callers check :func:`available` and keep the pure-
-Python path otherwise.
+``_raylet.pyx``). Built from ``src/`` on first use
+(:mod:`raytpu.core.native`). Falls back cleanly — callers check
+:func:`available` and keep the pure-Python path otherwise.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 from typing import List, Optional, Sequence, Tuple
 
+from raytpu.core.native import lib_path
+
 _lib = None
 _load_lock = threading.Lock()
-_LIB_PATH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "_native", "libschedcore.so")
-
-
-def _build() -> None:
-    src_dir = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "src")
-    if os.path.isdir(src_dir):
-        subprocess.run(["make", "-C", src_dir], capture_output=True,
-                       timeout=120, check=False)
 
 
 def _load():
@@ -34,14 +24,10 @@ def _load():
     with _load_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            try:
-                _build()
-            except Exception:
-                return None
-        if not os.path.exists(_LIB_PATH):
+        try:
+            lib = ctypes.CDLL(lib_path("libschedcore.so"))
+        except (RuntimeError, OSError):
             return None
-        lib = ctypes.CDLL(_LIB_PATH)
         lib.topo_create.argtypes = [ctypes.POINTER(ctypes.c_int),
                                     ctypes.c_int]
         lib.topo_create.restype = ctypes.c_int64

@@ -18,17 +18,7 @@ import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
-# Known generations: (chips per host, ICI dims per chip layout, HBM GiB/chip,
-# bf16 peak TFLOP/s per chip). Peaks are public numbers.
-GENERATIONS = {
-    "v2": {"chips_per_host": 4, "hbm_gib": 8, "tflops_bf16": 23},
-    "v3": {"chips_per_host": 4, "hbm_gib": 16, "tflops_bf16": 61},
-    "v4": {"chips_per_host": 4, "hbm_gib": 32, "tflops_bf16": 137},
-    "v5e": {"chips_per_host": 4, "hbm_gib": 16, "tflops_bf16": 197},
-    "v5litepod": {"chips_per_host": 4, "hbm_gib": 16, "tflops_bf16": 197},
-    "v5p": {"chips_per_host": 4, "hbm_gib": 95, "tflops_bf16": 459},
-    "v6e": {"chips_per_host": 4, "hbm_gib": 32, "tflops_bf16": 918},
-}
+from raytpu.core.chip_specs import generation_spec
 
 
 @dataclass(frozen=True)
@@ -46,16 +36,15 @@ class SliceType:
         # "v4-32" → generation v4, 32 cores. v4/v5p count 2 cores per chip;
         # v5e/v6e pod names count chips directly (e.g. v5e-16).
         gen, _, n = name.partition("-")
-        n = int(n)
-        cores_per_chip = 2 if gen in ("v2", "v3", "v4", "v5p") else 1
-        chips = max(1, n // cores_per_chip)
-        info = GENERATIONS.get(gen, GENERATIONS["v4"])
-        hosts = max(1, chips // info["chips_per_host"])
-        return cls(name, gen, chips, hosts, _default_box(chips, gen))
+        spec = generation_spec(gen)  # an unknown generation raises
+        chips = max(1, int(n) // spec.cores_per_chip)
+        hosts = max(1, chips // spec.chips_per_host)
+        return cls(name, spec.generation, chips, hosts,
+                   _default_box(chips, spec.generation))
 
     @property
     def tflops_bf16(self) -> float:
-        return GENERATIONS.get(self.generation, GENERATIONS["v4"])["tflops_bf16"]
+        return generation_spec(self.generation).bf16_flops / 1e12
 
 
 def _default_box(chips: int, gen: str) -> Tuple[int, ...]:
@@ -214,7 +203,6 @@ def detect_local_tpu() -> Dict[str, object]:
     would make ``init()`` block (we only consult JAX if some other code in
     this process already initialized it).
     """
-    env_type = os.environ.get("TPU_ACCELERATOR_TYPE")
     chips, kind = 0, ""
 
     env_chips = os.environ.get("RAYTPU_NUM_TPUS")
@@ -239,10 +227,4 @@ def detect_local_tpu() -> Dict[str, object]:
                     kind = devs[0].device_kind if devs else ""
             except Exception:
                 pass
-    gen = "v4"
-    low = (env_type or kind).lower().replace(" ", "")
-    for g in sorted(GENERATIONS, key=len, reverse=True):
-        if g in low:
-            gen = g
-            break
-    return {"chips": chips, "generation": gen, "device_kind": kind}
+    return {"chips": chips, "device_kind": kind}

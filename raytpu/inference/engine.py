@@ -27,6 +27,7 @@ Sampling runs on the host with per-request RNGs (see
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence as SequenceT
@@ -37,7 +38,7 @@ from raytpu.inference.kv_cache import PagedKVCache
 from raytpu.inference.prefix_cache import PrefixCache
 from raytpu.inference.sampling import SamplingParams, sample_token
 from raytpu.inference.scheduler import Scheduler, Sequence
-from raytpu.util import task_events, tracing
+from raytpu.util import compile_cache, task_events, tracing
 from raytpu.util.metrics import Counter, Gauge, Histogram
 from raytpu.util.profiler import profiling_enabled
 from raytpu.util.stepprof import cost_analysis_flops, step_profiler
@@ -143,11 +144,13 @@ class InferenceEngine:
         self.cache = PagedKVCache(
             model_config.n_layer, num_pages, page_size, kv_heads, head_dim,
             dtype=model_config.dtype)
-        # Tensor parallelism: shard the weights with the proven
-        # parallel-layer rule table and the KV pools along the kv-head
-        # axis. Both jit sites then compile to one SPMD program whose
-        # per-shard body is the unmodified single-chip computation over
-        # a head slice — the paged-attention kernel never notices.
+        # Tensor parallelism: shard the weights with the parallel-layer
+        # rule table and the KV pools along the kv-head axis. Each jit
+        # site then compiles to one SPMD program. XLA partitions
+        # everything in it but the attention kernels, which it cannot;
+        # those run once per shard over their slice of the heads
+        # (``ops.flash_attention.per_shard``), and find the mesh because
+        # step() sets it around every call.
         self.mesh = mesh
         if self.mesh is None and tp > 1:
             from raytpu.parallel.mesh import build_mesh
@@ -218,6 +221,7 @@ class InferenceEngine:
         self._hbm_tick = 0
         self._jnp = jax.numpy
         self._jax = jax
+        compile_cache.enable()
         self._prefill_fn = self._build_prefill_fn(jax)
         self._chunk_fn = self._build_chunk_prefill_fn(jax)
         self._decode_fn = self._build_decode_fn(jax)
@@ -339,15 +343,18 @@ class InferenceEngine:
         retire finished sequences (freeing their pages)."""
         out: List[StepOutput] = []
         plan = self.scheduler.schedule()
-        t0 = time.perf_counter()
-        prefilled = 0
-        for seq in plan.prefills:
-            prefilled += self._run_prefill(seq, out)
-        t1 = time.perf_counter()
-        decoded = 0
-        if plan.decodes:
-            decoded = self._run_decode(plan.decodes, out)
-        t2 = time.perf_counter()
+        # The mesh is thread-local state and any thread may step.
+        with (self._jax.set_mesh(self.mesh) if self.mesh is not None
+              else contextlib.nullcontext()):
+            t0 = time.perf_counter()
+            prefilled = 0
+            for seq in plan.prefills:
+                prefilled += self._run_prefill(seq, out)
+            t1 = time.perf_counter()
+            decoded = 0
+            if plan.decodes:
+                decoded = self._run_decode(plan.decodes, out)
+            t2 = time.perf_counter()
 
         # Throughput gauges reflect THIS step — a step that moved no
         # tokens zeroes them, so autoscalers never read the last busy
@@ -613,6 +620,8 @@ class InferenceEngine:
             # 0 on the kernel path).
             "gathered_pages": self._pages_gathered,
             "paged_attn_impl": self.paged_attn_impl,
+            "devices": sorted(f"{d.platform}:{d.id}"
+                              for d in self.cache.k[0].devices()),
             "num_preemptions": self.scheduler.num_preemptions,
             "running": len(self.scheduler.running),
             "waiting": len(self.scheduler.waiting),

@@ -87,6 +87,9 @@ class PrefixCache:
         self._hash_of: Dict[int, bytes] = {}
         # ref-0 registered pages, least-recently-matched first
         self._lru: "OrderedDict[int, None]" = OrderedDict()
+        # Bumped whenever the hash index changes, so a holder of an
+        # old summary() can tell without walking the index.
+        self.version = 0
         cache._retainer = self
 
     # ---- lookup / registration --------------------------------------
@@ -136,6 +139,7 @@ class PrefixCache:
             self._by_hash[h] = page
             self._hash_of[page] = h
             added += 1
+        self.version += added
         return added
 
     def adopt(self, pages: Sequence[int], hashes: Sequence[bytes]) -> int:
@@ -155,6 +159,7 @@ class PrefixCache:
             self._by_hash[h] = page
             self._hash_of[page] = h
             added += 1
+        self.version += added
         return added
 
     def summary(self, max_entries: int = 1024) -> List[str]:
@@ -202,6 +207,7 @@ class PrefixCache:
             freed += 1
         if freed:
             _evictions_total.inc(freed)
+        self.version += freed
         return freed
 
     # ---- introspection ----------------------------------------------

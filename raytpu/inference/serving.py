@@ -36,6 +36,7 @@ replica selection. See :mod:`raytpu.inference.disagg`.
 
 from __future__ import annotations
 
+import os
 import threading
 import uuid
 from collections import deque
@@ -47,6 +48,48 @@ from raytpu.inference.engine import InferenceEngine
 from raytpu.inference.sampling import SamplingParams
 from raytpu.serve.deployment import deployment
 from raytpu.util import serve_slo, task_events
+
+
+class _FifoLock:
+    """A mutex that threads take in the order they asked for it.
+
+    The stepping loop drops the engine lock between iterations and asks
+    for it again at once. With a plain lock it wins that race every
+    time — it is running, the waiter has yet to be woken — so consumers
+    saw no token and new requests were not admitted until the engine ran
+    dry. Here ``release`` hands the lock to the longest waiter, and the
+    loop queues behind it.
+    """
+
+    def __init__(self):
+        self._mutex = threading.Lock()
+        self._held = False
+        self._waiters: deque = deque()  # one locked gate per waiter
+
+    def acquire(self, blocking: bool = True) -> bool:
+        with self._mutex:
+            if not self._held:
+                self._held = True
+                return True
+            if not blocking:
+                return False
+            gate = threading.Lock()
+            gate.acquire()
+            self._waiters.append(gate)
+        gate.acquire()  # release() opens it: the lock is now ours
+        return True
+
+    def release(self) -> None:
+        with self._mutex:
+            if self._waiters:
+                self._waiters.popleft().release()  # stays held: handed over
+            else:
+                self._held = False
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc) -> None:
+        self.release()
 
 
 class _HandlePeer:
@@ -147,7 +190,7 @@ class LLMDeployment:
         # One condition serializes engine mutation (add/abort/step) and
         # carries wakeups both ways: producers signal "new work" to the
         # loop, the loop signals "new tokens" to consumers.
-        self._cv = threading.Condition()
+        self._cv = threading.Condition(_FifoLock())
         self._buffers: Dict[str, deque] = {}
         self._finished: Dict[str, str] = {}
         # O(1) request-liveness: ids currently registered with the
@@ -159,6 +202,11 @@ class LLMDeployment:
         # Lock-free pressure snapshot: the loop REPLACES the dict, so
         # readers never see a half-written one (GIL-atomic store).
         self._pressure = self._engine.pressure()
+        # Likewise the prefix-cache digests the router and the
+        # controller's health check read: a probe must never wait for
+        # the engine lock, which a step holds for as long as a compile.
+        self._prefix_digests: list = []
+        self._prefix_version = -1
         self._step_thread = threading.Thread(
             target=self._step_loop, name="llm-step-loop", daemon=True)
         self._step_thread.start()
@@ -174,6 +222,7 @@ class LLMDeployment:
                 while not self._closed and not self._engine.has_unfinished():
                     self._engine.note_idle()
                     self._pressure = self._engine.pressure()
+                    self._publish_prefix_digests()  # KV handoffs adopt pages
                     self._cv.wait(timeout=0.5)
                 if self._closed:
                     return
@@ -185,10 +234,19 @@ class LLMDeployment:
                     if out.finished:
                         self._finished[out.request_id] = out.finish_reason
                 self._pressure = self._engine.pressure()
+                self._publish_prefix_digests()
                 if outs:
                     self._cv.notify_all()
             # The lock is dropped between iterations so request threads
             # can drain buffers / add / abort while the engine is busy.
+
+    def _publish_prefix_digests(self) -> None:
+        """Refresh the digest snapshot if the prefix cache's index
+        changed. Called by the stepping loop, under the lock."""
+        cache = self._engine.prefix_cache
+        if cache is not None and cache.version != self._prefix_version:
+            self._prefix_version = cache.version
+            self._prefix_digests = cache.summary(tuning.PREFIX_SUMMARY_MAX)
 
     def shutdown(self) -> None:
         """Stop the stepping loop (used by direct-instantiation tests;
@@ -365,17 +423,13 @@ class LLMDeployment:
     def prefix_summary(self) -> dict:
         """Compact routing summary for the prefix-aware router:
         registered page-chain digests plus the load signals (the same
-        KV-occupancy/TTFT numbers that ride the TSDB gauges)."""
-        eng = self._engine
-        digests = []
-        if eng.prefix_cache is not None:
-            with self._cv:
-                digests = eng.prefix_cache.summary(
-                    tuning.PREFIX_SUMMARY_MAX)
+        KV-occupancy/TTFT numbers that ride the TSDB gauges). Reads the
+        stepping loop's snapshots and takes no lock: the replica's event
+        loop calls this from its health check."""
         pressure = self.engine_pressure()
         return {
-            "digests": digests,
-            "page_size": eng.page_size,
+            "digests": list(self._prefix_digests),
+            "page_size": self._engine.page_size,
             "role": self._role,
             "kv_utilization": pressure.get("kv_utilization", 0.0),
             "ttft_p95_s": pressure.get("ttft_p95_s", 0.0),
@@ -390,8 +444,14 @@ class LLMDeployment:
         return dict(self._pressure)
 
     def stats(self) -> dict:
+        """Engine statistics, plus which process this replica is and
+        the chips it leased (empty in local mode)."""
         with self._cv:
-            return self._engine.stats()
+            stats = self._engine.stats()
+        stats["replica"] = {
+            "pid": os.getpid(),
+            "chips": os.environ.get("RAYTPU_VISIBLE_CHIPS", "")}
+        return stats
 
     def abort(self, request_id: str) -> bool:
         with self._cv:

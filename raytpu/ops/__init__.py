@@ -5,9 +5,8 @@ plane is ours, so the ops that dominate the profile get hand-tiled MXU/VMEM
 kernels with jnp fallbacks everywhere else.
 """
 
-from raytpu.ops.flash_attention import flash_attention
-from raytpu.ops.fused import rmsnorm, swiglu
+from raytpu.ops.flash_attention import flash_attention, resolve_flash_impl
 from raytpu.ops.paged_attention import paged_attention, resolve_paged_impl
 
-__all__ = ["flash_attention", "paged_attention", "resolve_paged_impl",
-           "rmsnorm", "swiglu"]
+__all__ = ["flash_attention", "paged_attention", "resolve_flash_impl",
+           "resolve_paged_impl"]

@@ -7,20 +7,30 @@ head_dim) HBM traffic per generated token — then run dense fp32
 attention over mostly padding.  This module replaces that with a Pallas
 kernel that reads KV pages **in place**, vLLM-PagedAttention style:
 
-- grid ``(batch, kv_head, q_blocks, kv_pages)``; the innermost page
-  dimension is sequential so online-softmax state (m / l / acc) lives
-  in VMEM scratch across it.
+- grid ``(batch, kv_head_group, q_blocks, kv_pages)``; the innermost
+  page dimension is sequential so online-softmax state (m / l / acc)
+  lives in VMEM scratch across it.
 - the block table and per-sequence query-start positions are
   scalar-prefetch operands: the k/v BlockSpec index maps translate the
   page-grid coordinate through the block table, so each step DMAs one
-  ``[page_size, head_dim]`` tile straight out of the pool.
+  ``[page_size, group_lanes]`` tile straight out of the pool.
+- a pool row is read flattened to ``[kv_heads * head_dim]`` and a block
+  takes the fewest kv heads that fill whole 128-lane tiles (two at
+  ``head_dim`` 64, one at 128), because Mosaic has no block of one head
+  out of a ``[kv_heads, head_dim]`` minor pair. The heads of a group
+  share the lane axis: each query row is zero outside its own head's
+  lanes, one dense product scores every head, and the wrapper keeps
+  each row's own lanes of the result. The MXU does ``group`` times the
+  needed work; the pool bytes read, which bound decode, do not change.
 - pages past a sequence's live length are *clamped* to the last live
   page in the index map — the Mosaic pipeline sees the same block again
   and skips the fetch — and ``pl.when`` skips their flops.
-- GQA folds query heads onto their kv head: q ``[B, T, H, D]`` becomes
-  ``[B, KV, T*rep, D]`` (row = t*rep + r, matching ``jnp.repeat``), so
-  one grid step attends all query heads sharing a kv head.
+- GQA folds query heads onto their kv head (row = t*rep + r, matching
+  ``jnp.repeat``), so one grid step attends all query heads sharing the
+  group's kv heads.
 - pages may be bf16; scores and accumulators are fp32.
+- under a mesh (``jax.set_mesh``) the call runs per shard, the pools
+  split on the kv-head axis (``per_shard``).
 
 Like :mod:`raytpu.ops.flash_attention` this ships a sanctioned dense
 reference (`paged_attention_reference`, the ONE place a materializing
@@ -56,7 +66,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from raytpu.ops.flash_attention import _on_tpu
+from raytpu.ops.flash_attention import _on_tpu, per_shard
 
 _NEG_INF = -1e30
 # Online-softmax running max/denominator are (rows, LANES) f32 scratch:
@@ -175,21 +185,26 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, positions,
 def _paged_kernel(bt_ref, qs_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr,
                   *, sm_scale, page_size, bq_t, rep, n_pg):
-    """One grid step: all query heads of kv-head j, query-token block
-    iq, attending page ik of sequence b. Scratch carries the online
-    softmax across the (sequential) page dimension."""
+    """One grid step: the query heads of one kv-head group, query-token
+    block iq, attending page ik of sequence b. Scratch carries the
+    online softmax across the (sequential) page dimension.
+
+    A group's heads sit side by side on the lane axis. Each query row
+    is zero outside its own head's lanes, so one dense
+    ``[rows, lanes] x [page, lanes]^T`` product yields every head's
+    scores, and row r of ``p @ v`` is right on the lanes of r's head
+    (the wrapper keeps those and drops the rest)."""
     b = pl.program_id(0)
     iq = pl.program_id(2)
     ik = pl.program_id(3)
-    rows = bq_t * rep
-    d = q_ref.shape[-1]
+    rows, lanes = acc_scr.shape
     q_start = qs_ref[b]  # absolute position of query token 0
 
     @pl.when(ik == 0)
     def _init():
         m_scr[...] = jnp.full((rows, _LANES), _NEG_INF, jnp.float32)
         l_scr[...] = jnp.zeros((rows, _LANES), jnp.float32)
-        acc_scr[...] = jnp.zeros((rows, d), jnp.float32)
+        acc_scr[...] = jnp.zeros((rows, lanes), jnp.float32)
 
     # The last page any row of this q block may see; later pages are
     # clamped in the index maps (no DMA) and skipped here (no flops).
@@ -197,16 +212,19 @@ def _paged_kernel(bt_ref, qs_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]  # [rows, d]
-        kb = k_ref[0, :, 0, :].astype(q.dtype)  # [page_size, d]
-        vb = v_ref[0, :, 0, :].astype(q.dtype)
+        q = q_ref[0, 0]  # [rows, lanes]
+        kb = k_ref[0].astype(q.dtype)  # [page_size, lanes]
+        vb = v_ref[0].astype(q.dtype)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        # Row r holds query token iq*bq_t + r//rep; column c is slot
-        # ik*page_size + c.
-        tok = iq * bq_t + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 0) // rep
+        # Rows run (head in group, token, query head of the kv head):
+        # row r holds query token iq*bq_t + (r mod bq_t*rep) // rep;
+        # column c is slot ik*page_size + c. Rows past the live ones
+        # are padding.
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
+        tok = iq * bq_t + jax.lax.div(
+            jax.lax.rem(row, bq_t * rep), rep)
         slot = ik * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (rows, page_size), 1)
         s = jnp.where(slot <= q_start + tok, s, _NEG_INF)
@@ -239,24 +257,50 @@ def _fit_q_block(t: int, want: int) -> int:
     return want
 
 
+def _kv_heads_per_block(kv: int, d: int) -> int:
+    """Fewest kv heads whose features fill whole 128-lane tiles; all of
+    them when no count does. Mosaic wants the minor dimension of a
+    block to be a multiple of 128 or the array's whole minor
+    dimension, and a pool row flattened to ``[kv * d]`` offers both."""
+    for g in range(1, kv):
+        if kv % g == 0 and (g * d) % _LANES == 0:
+            return g
+    return kv
+
+
 @functools.partial(
     jax.jit, static_argnames=("sm_scale", "interpret"))
 def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
                   *, sm_scale, interpret):
     b, t, h, d = q.shape
-    _, page_size, kv, _ = k_pages.shape
+    n_pages, page_size, kv, _ = k_pages.shape
     if h % kv:
         raise ValueError(f"heads ({h}) not a multiple of kv_heads ({kv})")
     rep = h // kv
     n_pg = block_tables.shape[1]
     bq_t = _fit_q_block(t, _env_block("RAYTPU_PAGED_BLOCK_Q", 256))
-    rows = bq_t * rep
     n_qb = t // bq_t
+    g = _kv_heads_per_block(kv, d)
+    n_grp = kv // g
+    lanes = g * d
+    live_rows = g * bq_t * rep
+    # Whole sublane tiles: 8 rows of 32-bit, 16 of 16-bit (decode is
+    # one row per query head otherwise).
+    sublanes = 32 // q.dtype.itemsize
+    rows = -(-live_rows // sublanes) * sublanes
 
-    # Fold query heads onto their kv head: row = t*rep + r matches
-    # jnp.repeat(axis=2) semantics in the reference.
-    qg = q.reshape(b, t, kv, rep, d).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(b, kv, t * rep, d)
+    # Query head (j*g + i)*rep + r of token iq*bq_t + tt becomes row
+    # (i, tt, r) of group j's block iq, on lanes [i*d, (i+1)*d) and
+    # zero elsewhere. r runs inside tt as jnp.repeat(axis=2) would in
+    # the reference.
+    qg = q.reshape(b, n_qb, bq_t, n_grp, g, rep, d)
+    qg = qg.transpose(0, 3, 1, 4, 2, 5, 6)
+    own = jnp.eye(g, dtype=q.dtype)[:, None, None, :, None]
+    qg = (qg[..., None, :] * own).reshape(b, n_grp, n_qb, live_rows, lanes)
+    qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, rows - live_rows), (0, 0)))
+    qg = qg.reshape(b, n_grp, n_qb * rows, lanes)
+    k_rows = k_pages.reshape(n_pages, page_size, kv * d)
+    v_rows = v_pages.reshape(n_pages, page_size, kv * d)
     q_start = positions[:, 0].astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
 
@@ -269,28 +313,24 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
         # repeated block and skips the DMA.
         last = (qs_ref[b_] + iq * bq_t + bq_t - 1) // page_size
         last = jnp.clip(last, 0, n_pg - 1)
-        return (bt_ref[b_, jnp.minimum(ik, last)], 0, j, 0)
-
-    def o_index(b_, j, iq, ik, bt_ref, qs_ref):
-        del ik, bt_ref, qs_ref
-        return (b_, j, iq, 0)
+        return (bt_ref[b_, jnp.minimum(ik, last)], 0, j)
 
     kernel = functools.partial(
         _paged_kernel, sm_scale=sm_scale, page_size=page_size,
         bq_t=bq_t, rep=rep, n_pg=n_pg)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, n_qb, n_pg),
+        grid=(b, n_grp, n_qb, n_pg),
         in_specs=[
-            pl.BlockSpec((1, 1, rows, d), q_index),
-            pl.BlockSpec((1, page_size, 1, d), kv_index),
-            pl.BlockSpec((1, page_size, 1, d), kv_index),
+            pl.BlockSpec((1, 1, rows, lanes), q_index),
+            pl.BlockSpec((1, page_size, lanes), kv_index),
+            pl.BlockSpec((1, page_size, lanes), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, rows, d), o_index),
+        out_specs=pl.BlockSpec((1, 1, rows, lanes), q_index),
         scratch_shapes=[
             pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, lanes), jnp.float32),
         ],
     )
     kwargs = {}
@@ -305,12 +345,16 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, t * rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=interpret,
         **kwargs,
-    )(block_tables, q_start, qg, k_pages, v_pages)
-    out = out.reshape(b, kv, t, rep, d).transpose(0, 2, 1, 3, 4)
-    return out.reshape(b, t, h, d)
+    )(block_tables, q_start, qg, k_rows, v_rows)
+    # Keep each row's own head's lanes (the diagonal of the two
+    # head-in-group axes) and undo the fold.
+    out = out.reshape(b, n_grp, n_qb, rows, lanes)[:, :, :, :live_rows]
+    out = out.reshape(b, n_grp, n_qb, g, bq_t, rep, g, d)
+    out = jnp.einsum("bjqitrid->bjqitrd", out)
+    return out.transpose(0, 2, 4, 1, 3, 5, 6).reshape(b, t, h, d)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, positions, *,
@@ -341,6 +385,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions, *,
         return paged_attention_reference(
             q, k_pages, v_pages, block_tables, positions,
             sm_scale=sm_scale)
-    return _paged_pallas(
-        q, k_pages, v_pages, block_tables, positions,
-        sm_scale=sm_scale, interpret=(impl == "interpret"))
+    # Per shard under a mesh: q and the result [B, T, H, D], the pools
+    # [pages, page_size, KV, D], tables and positions [B, ...].
+    return per_shard(
+        functools.partial(_paged_pallas, sm_scale=sm_scale,
+                          interpret=(impl == "interpret")),
+        (q, k_pages, v_pages, block_tables, positions),
+        ("b.h.", "..h.", "..h.", "b.", "b."), "b.h.")

@@ -25,14 +25,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 def _vary(x, axis_name: str):
     """Mark a freshly-created array as device-varying over `axis_name`
-    (newer shard_map tracks varying-manual-axes; loop carries must agree)."""
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:
-        return x
-    try:
-        return pcast(x, (axis_name,), to="varying")
-    except TypeError:
-        return pcast(x, axis_name)
+    (shard_map tracks varying-manual-axes; loop carries must agree)."""
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def _block_attn_update(q, k, v, m, l, o, mask, sm_scale):

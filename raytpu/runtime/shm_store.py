@@ -22,38 +22,15 @@ from __future__ import annotations
 import ctypes
 import mmap
 import os
-import subprocess
 import weakref
 from typing import Optional
 
 from raytpu.core.errors import ObjectStoreFullError
 from raytpu.core.ids import ObjectID
+from raytpu.core.native import lib_path
 from raytpu.runtime.serialization import (
     SerializedValue, serialize_into, wire_size_of,
 )
-
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "_native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libshmstore.so")
-_SRC = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(__file__))),
-    "src", "store", "shm_store.cc",
-)
-
-
-def _ensure_built() -> str:
-    if os.path.exists(_LIB_PATH) and (
-        not os.path.exists(_SRC)
-        or os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC)
-    ):
-        return _LIB_PATH
-    os.makedirs(_NATIVE_DIR, exist_ok=True)
-    subprocess.run(
-        ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-o", _LIB_PATH,
-         _SRC, "-lpthread", "-lrt"],
-        check=True, capture_output=True,
-    )
-    return _LIB_PATH
-
 
 _lib = None
 
@@ -61,7 +38,7 @@ _lib = None
 def _load():
     global _lib
     if _lib is None:
-        lib = ctypes.CDLL(_ensure_built())
+        lib = ctypes.CDLL(lib_path("libshmstore.so"))
         lib.shm_store_open.restype = ctypes.c_void_p
         lib.shm_store_open.argtypes = [
             ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64, ctypes.c_int]

@@ -38,11 +38,22 @@ class ReplicaWrapper:
         self.handle = handle
         self.config = config
         self.healthy = True
+        # False until the replica's first health reply, i.e. until its
+        # constructor has finished. A starting replica gets no traffic
+        # and is held to no health-check deadline: loading a model and
+        # compiling for it take as long as they take.
+        self.ready = False
         self.last_health_check = time.monotonic()
         self.draining = False
         # Latest prefix-cache advertisement piggybacked on this
         # replica's health reply (None until it advertises one).
         self.prefix_summary = None
+
+    @property
+    def serving(self) -> bool:
+        """Constructed and not known dead: counts as RUNNING, and is
+        published to the routers."""
+        return self.healthy and self.ready
 
 
 class DeploymentState:
@@ -93,9 +104,10 @@ class ServeController(LongPollHost):
         # no replicas to route to (the scale-from-zero signal; reference:
         # handles report queued metrics to the controller for autoscaling).
         self._pending_demand: Dict[str, list] = {}
-        # In-flight replica stop tasks (concurrent drains; the reconcile
-        # loop must not stall behind graceful_shutdown_timeout_s).
-        self._stop_tasks: set = set()
+        # In-flight replica start-up waits and stop tasks (concurrent
+        # drains; the reconcile loop must not stall behind a constructor
+        # or graceful_shutdown_timeout_s). Held so they are not collected.
+        self._background_tasks: set = set()
 
     def _ensure_loop(self):
         if self._loop_task is None or self._loop_task.done():
@@ -190,7 +202,7 @@ class ServeController(LongPollHost):
         for app, states in self._apps.items():
             deps = {}
             for name, st in states.items():
-                healthy = sum(1 for r in st.replicas.values() if r.healthy)
+                healthy = sum(1 for r in st.replicas.values() if r.serving)
                 if healthy >= st.target_num_replicas:
                     status = "RUNNING"
                 elif st.replicas:
@@ -260,8 +272,8 @@ class ServeController(LongPollHost):
         state.replicas.pop(rep.replica_id, None)
         self._publish_replicas(state)
         task = asyncio.ensure_future(self._drain_and_kill(rep))
-        self._stop_tasks.add(task)
-        task.add_done_callback(self._stop_tasks.discard)
+        self._background_tasks.add(task)
+        task.add_done_callback(self._background_tasks.discard)
         return task
 
     def _start_replica(self, state: DeploymentState):
@@ -274,7 +286,28 @@ class ServeController(LongPollHost):
         handle = raytpu.remote(Replica).options(**opts).remote(
             rid, cloudpickle.dumps(state.replica_config)
         )
-        state.replicas[rid] = ReplicaWrapper(rid, handle, state.replica_config)
+        rep = ReplicaWrapper(rid, handle, state.replica_config)
+        state.replicas[rid] = rep
+        task = asyncio.ensure_future(self._await_ready(state, rep))
+        self._background_tasks.add(task)
+        task.add_done_callback(self._background_tasks.discard)
+
+    async def _await_ready(self, state: DeploymentState,
+                           rep: ReplicaWrapper):
+        """Wait, without a deadline, for a new replica's first health
+        reply; then publish it to the routers. A constructor that raises
+        (the actor dies) marks it unhealthy, and reconcile replaces it."""
+        try:
+            reply = await _await_ref(rep.handle.check_health.remote())
+        except Exception:
+            rep.healthy = False
+            return
+        rep.ready = True
+        rep.last_health_check = time.monotonic()
+        if isinstance(reply, dict):
+            rep.prefix_summary = reply.get("prefix_summary")
+        if state.replicas.get(rep.replica_id) is rep:
+            self._publish_replicas(state)
 
     async def _drain_and_kill(self, rep: ReplicaWrapper):
         import raytpu
@@ -299,7 +332,7 @@ class ServeController(LongPollHost):
         now = time.monotonic()
         period = state.replica_config.deployment_config.health_check_period_s
         for rep in list(state.replicas.values()):
-            if now - rep.last_health_check < period:
+            if not rep.ready or now - rep.last_health_check < period:
                 continue
             rep.last_health_check = now
             try:
@@ -426,7 +459,7 @@ class ServeController(LongPollHost):
             return
         waiting = kv_util = ttft = 0.0
         saw_pressure = False
-        for rep in list(state.replicas.values()):
+        for rep in [r for r in state.replicas.values() if r.ready]:
             try:
                 m = await asyncio.wait_for(
                     _await_ref(rep.handle.get_metrics.remote()), timeout=2.0
@@ -461,7 +494,7 @@ class ServeController(LongPollHost):
         snapshot = {
             "replicas": [
                 (r.replica_id, r.handle)
-                for r in state.replicas.values() if r.healthy
+                for r in state.replicas.values() if r.serving
             ],
             # Routers size their saturation threshold from the deployment's
             # actual config, not the handle-constructor default.
@@ -488,8 +521,7 @@ class ServeController(LongPollHost):
                 if state.full_name == full_name:
                     return [
                         (r.replica_id, r.handle)
-                        for r in state.replicas.values()
-                        if r.healthy
+                        for r in state.replicas.values() if r.serving
                     ]
         return []
 

@@ -6,9 +6,11 @@ bench JSONs; the ROADMAP's 40%+ MFU target needs a LIVE measurement.
 This module derives per-step FLOPs from the jit ``cost_analysis`` at
 compile time (cached per shape bucket — the lowering already happened,
 so the question costs one AOT cache hit per bucket, never per step) and
-divides by the chip's peak to emit ``raytpu_train_mfu`` /
-``raytpu_infer_decode_mfu`` gauges plus step-time histograms that
-``raytpu top`` and alert rules consume.
+divides by the chip's published peak (:mod:`raytpu.core.chip_specs`) to
+emit ``raytpu_train_mfu`` / ``raytpu_infer_decode_mfu`` gauges plus
+step-time histograms that ``raytpu top`` and alert rules consume. On a
+device the table does not know (the CPU included) the utilization
+gauges publish nothing.
 
 Every emission site is behind the ``profiling_enabled()`` flag at the
 CALLER (lint rule RTP019) — this module never checks the flag itself,
@@ -17,51 +19,23 @@ so a hook pays exactly one boolean read when profiling is off.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Callable, Dict, Optional
 
+from raytpu.core.chip_specs import chip_spec
 from raytpu.util.metrics import Gauge, Histogram
-
-ENV_PEAK_FLOPS = "RAYTPU_CHIP_PEAK_FLOPS"
-
-# Per-chip dense bf16 peak FLOP/s by device-kind substring (public TPU
-# specs); first match wins. The CPU fallback makes MFU a *relative*
-# utilization signal on dev boxes instead of an absent series.
-_PEAK_BY_KIND = (
-    ("v6e", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
-_FALLBACK_PEAK_FLOPS = 1e12
 
 _STEP_BUCKETS = (1e-4, 5e-4, 1e-3, 5e-3, 1e-2, 2.5e-2, 5e-2, 0.1,
                  0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 
 
 def device_peak_flops() -> float:
-    """Peak FLOP/s of one local chip: ``RAYTPU_CHIP_PEAK_FLOPS``
-    override first, then the device-kind table, then the CPU fallback."""
-    env = os.environ.get(ENV_PEAK_FLOPS, "")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
-        import jax
+    """Published dense bf16 peak FLOP/s of one local chip; raises
+    ``ValueError`` on a device the table does not know."""
+    import jax
 
-        kind = jax.local_devices()[0].device_kind.lower()
-        for sub, peak in _PEAK_BY_KIND:
-            if sub in kind:
-                return peak
-    except Exception:
-        pass
-    return _FALLBACK_PEAK_FLOPS
+    return chip_spec(jax.local_devices()[0].device_kind).bf16_flops
 
 
 def cost_analysis_flops(jitted, *args, **kwargs) -> Optional[float]:
@@ -69,8 +43,6 @@ def cost_analysis_flops(jitted, *args, **kwargs) -> Optional[float]:
     ``cost_analysis``; None when the backend doesn't report."""
     try:
         ca = jitted.lower(*args, **kwargs).compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         flops = float((ca or {}).get("flops", 0.0))
         return flops if flops > 0 else None
     except Exception:
@@ -103,6 +75,8 @@ class StepProfiler:
                                "device memory high-water mark",
                                tag_keys=("device",))
         self._flops: Dict[object, Optional[float]] = {}
+        # Peak FLOP/s of the local chip, looked up on first use; 0.0 on
+        # a device with no published peak, which silences the gauge.
         self._peak: Optional[float] = None
         self._last_mark: Optional[float] = None
         self._lock = threading.Lock()
@@ -128,7 +102,10 @@ class StepProfiler:
 
     def peak_flops(self) -> float:
         if self._peak is None:
-            self._peak = device_peak_flops()
+            try:
+                self._peak = device_peak_flops()
+            except ValueError:
+                self._peak = 0.0
         return self._peak
 
     # -- emission (callers guard with profiling_enabled(); RTP019) ---------
@@ -145,7 +122,7 @@ class StepProfiler:
         if flops is None and key is not None:
             with self._lock:
                 flops = self._flops.get(key)
-        if flops:
+        if flops and self.peak_flops():
             self._mfu.set(min(1.0, float(flops) / dt_s /
                               self.peak_flops()))
 

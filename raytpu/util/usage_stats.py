@@ -56,10 +56,10 @@ def _cluster_metadata() -> Dict[str, Any]:
         "os": platform.system().lower(),
         "timestamp": int(time.time()),
     }
-    try:
-        import jax
+    try:  # the version without the import: a driver may never touch JAX
+        from importlib.metadata import version
 
-        meta["jax_version"] = jax.__version__
+        meta["jax_version"] = version("jax")
     except Exception:
         pass
     return meta

@@ -147,6 +147,29 @@ class TestPrefixRoutingPolicy:
             dep.shutdown()
 
 
+    def test_summary_never_waits_for_the_engine_lock(self):
+        """The replica's event loop probes the summary from its health
+        check. A step holds the engine lock for as long as a compile
+        takes (seconds, cold on a chip); a probe that waited for it
+        froze every stream and got the replica replaced."""
+        import threading
+
+        dep = _dep()
+        try:
+            list(dep.generate(PROMPT, max_new_tokens=2))
+            got = []
+            with dep._cv:  # a step in progress
+                probe = threading.Thread(
+                    target=lambda: got.append(dep.prefix_summary()))
+                probe.start()
+                probe.join(timeout=5.0)
+                assert not probe.is_alive(), "probe blocked on the lock"
+            want = prefix_router.prompt_digests(PROMPT[:COVERED], 8)
+            assert set(want) <= set(got[0]["digests"])
+        finally:
+            dep.shutdown()
+
+
 # -- tensor-parallel engine ---------------------------------------------------
 
 

@@ -308,3 +308,55 @@ class TestEnginePressureAutoscaling:
             scaled_down = reps["running_replicas"] == 1
             time.sleep(0.25)
         assert scaled_down
+
+
+class TestSteppingLoopHandsTheLockOver:
+    def test_tokens_stream_and_requests_join_while_the_engine_is_busy(
+            self, monkeypatch):
+        """The stepping loop takes the engine lock again the instant it
+        drops it. Consumers and new requests must still get their turn
+        between steps: with a lock that lets the loop barge, no token
+        reached a consumer and nobody was admitted until the engine ran
+        dry."""
+        from raytpu.inference.engine import InferenceEngine
+
+        step = InferenceEngine.step
+
+        def device_paced_step(self):
+            out = step(self)
+            time.sleep(0.02)  # a device step, the lock held throughout
+            return out
+
+        monkeypatch.setattr(InferenceEngine, "step", device_paced_step)
+        dep = serve.LLMDeployment._target(
+            model="llama", engine_options=ENGINE_OPTIONS, seed=0)
+        arrivals = {"a": [], "b": []}
+
+        def consume(tag, prompt, n):
+            for _ in dep.generate(prompt, max_new_tokens=n):
+                arrivals[tag].append(time.monotonic())
+
+        try:
+            list(dep.generate([5, 6], max_new_tokens=2))  # compile
+            ta = threading.Thread(target=consume,
+                                  args=("a", list(range(1, 12)), 40))
+            ta.start()
+            deadline = time.monotonic() + 60
+            while not arrivals["a"] and time.monotonic() < deadline:
+                time.sleep(0.005)
+            tb = threading.Thread(target=consume, args=("b", [7, 3, 9], 5))
+            tb.start()
+            ta.join(timeout=120)
+            tb.join(timeout=120)
+            assert not ta.is_alive() and not tb.is_alive()
+            hist = dep.stats()["decode_batch_hist"]
+        finally:
+            dep.shutdown()
+        assert len(arrivals["a"]) == 40 and len(arrivals["b"]) == 5
+        # a's tokens arrived as they were decoded (40 steps of >= 20 ms),
+        # not in one burst at the end...
+        assert arrivals["a"][-1] - arrivals["a"][0] > 0.4
+        # ...and b, sent after a's first token, was admitted into a's
+        # decode instead of waiting for a to finish.
+        assert arrivals["b"][0] < arrivals["a"][-1]
+        assert max(hist) >= 2

@@ -1,14 +1,19 @@
-"""Pallas kernel tests (interpret mode on CPU; real lowering happens on
-TPU at bench time)."""
+"""Pallas kernel tests: numerics in interpret mode on the CPU, and the
+lowering for the TPU platform from the CPU host (what Mosaic then makes
+of it only the chip, or an AOT compile against libtpu, can say)."""
+
+import functools
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raytpu.ops.flash_attention import flash_attention
-from raytpu.ops.fused import rmsnorm
+from raytpu.ops.paged_attention import paged_attention
+from raytpu.parallel.mesh import build_mesh
 
 
 class TestFlashAttention:
@@ -128,11 +133,152 @@ class TestFlashAttention:
             flash_attention(q, q, q, force="interpret")
 
 
-class TestRMSNorm:
-    def test_matches_reference(self):
-        x = jax.random.normal(jax.random.PRNGKey(3), (64, 128))
-        scale = jnp.ones(128) * 1.5
-        ref = rmsnorm(x, scale, force="reference")
-        got = rmsnorm(x, scale, force="interpret")
+def _flash_shapes(b, h, t, d):
+    return (jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16),) * 3
+
+
+def _flash_fwd(q, k, v):
+    return flash_attention(q, k, v, force="tpu")
+
+
+def _flash_loss(q, k, v):
+    return _flash_fwd(q, k, v).astype(jnp.float32).sum()
+
+
+def _paged_shapes(b, t, h, kv, d, pages=513, page_size=16, width=64):
+    pool = jax.ShapeDtypeStruct((pages, page_size, kv, d), jnp.bfloat16)
+    return (jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16), pool, pool,
+            jax.ShapeDtypeStruct((b, width), jnp.int32),
+            jax.ShapeDtypeStruct((b, t), jnp.int32))
+
+
+_paged = functools.partial(paged_attention, force="tpu")
+
+# GPT-2 124M (12 heads of 64) at the shapes training and the engine use,
+# and one GQA 32/8 shape at head_dim 128.
+_TPU_CASES = {
+    "flash_fwd_124m": (_flash_fwd, _flash_shapes(8, 12, 1024, 64)),
+    "flash_bwd_124m": (jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                       _flash_shapes(8, 12, 1024, 64)),
+    "flash_fwd_prefill_bucket": (_flash_fwd, _flash_shapes(1, 12, 16, 64)),
+    "flash_bwd_gqa_d128": (jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                           _flash_shapes(4, 32, 1024, 128)),
+    "paged_decode_124m": (_paged, _paged_shapes(8, 1, 12, 12, 64)),
+    "paged_chunk_124m": (_paged, _paged_shapes(1, 64, 12, 12, 64)),
+    "paged_decode_gqa_d128": (_paged, _paged_shapes(8, 1, 32, 8, 128)),
+}
+
+
+class TestTpuLowering:
+    """Every Pallas kernel must lower for the TPU from a CPU host, alone
+    and from a program sharded over four devices."""
+
+    @pytest.mark.parametrize("case", sorted(_TPU_CASES))
+    def test_single_device(self, case):
+        fn, shapes = _TPU_CASES[case]
+        text = jax.jit(fn).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
+
+    @pytest.mark.parametrize("axes", [{"dp": 4}, {"tp": 4},
+                                      {"fsdp": 2, "tp": 2}])
+    @pytest.mark.parametrize("case", sorted(_TPU_CASES))
+    def test_four_device_mesh(self, case, axes):
+        fn, shapes = _TPU_CASES[case]
+        mesh = build_mesh(axes, jax.devices()[:4])
+        batch = tuple(a for a in ("dp", "fsdp") if a in axes) or None
+        if shapes[0].shape[0] == 1:  # a batch of one cannot be split
+            batch = None
+        heads = "tp" if "tp" in axes else None
+        if case.startswith("flash"):
+            specs = (P(batch, heads),) * 3
+        else:
+            specs = (P(batch, None, heads), P(None, None, heads),
+                     P(None, None, heads), P(batch), P(batch))
+        shardings = tuple(NamedSharding(mesh, s) for s in specs)
+        with jax.set_mesh(mesh):
+            text = jax.jit(fn, in_shardings=shardings).trace(
+                *shapes).lower(lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
+
+    def test_sharded_program_without_a_mesh_is_refused(self):
+        # No jax.set_mesh: XLA would have to partition the kernel.
+        fn, shapes = _TPU_CASES["flash_fwd_124m"]
+        mesh = build_mesh({"dp": 4}, jax.devices()[:4])
+        sh = NamedSharding(mesh, P("dp"))
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            jax.jit(fn, in_shardings=(sh,) * 3).trace(*shapes).lower(
+                lowering_platforms=("tpu",))
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """Devices of libtpu's compile-only client: no chip needed."""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # no libtpu here
+        pytest.skip(f"no TPU compile-only client: {e}")
+
+
+@pytest.mark.slow
+class TestTpuAotCompile:
+    """Past the lowering: Mosaic itself compiles every kernel for the
+    v5e. Whether the result is right, only a chip run says."""
+
+    @pytest.mark.parametrize("case", sorted(_TPU_CASES))
+    def test_mosaic_accepts(self, case, v5e_devices):
+        fn, shapes = _TPU_CASES[case]
+        one = NamedSharding(
+            jax.sharding.Mesh(np.array(v5e_devices[:1]), ("x",)), P())
+        jax.jit(fn, in_shardings=one, out_shardings=one).trace(
+            *shapes).lower(lowering_platforms=("tpu",)).compile()
+
+
+class TestPerShard:
+    """Numerics of the kernels called from a sharded program: the
+    interpreted kernel per shard against the unsharded reference."""
+
+    def test_flash_fwd_bwd_over_batch_and_heads(self):
+        mesh = build_mesh({"dp": 2, "tp": 2}, jax.devices()[:4])
+        q, k, v = jax.random.normal(jax.random.PRNGKey(5),
+                                    (3, 4, 4, 128, 32), jnp.float32)
+        sh = NamedSharding(mesh, P("dp", "tp"))
+
+        def loss(force, q, k, v):
+            return (flash_attention(q, k, v, force=force) ** 2).sum()
+
+        ref = jax.value_and_grad(functools.partial(loss, "reference"),
+                                 argnums=(0, 1, 2))(q, k, v)
+        with jax.set_mesh(mesh):
+            got = jax.jit(jax.value_and_grad(
+                functools.partial(loss, "interpret"), argnums=(0, 1, 2)))(
+                    *(jax.device_put(x, sh) for x in (q, k, v)))
+        assert got[1][0].sharding.is_equivalent_to(sh, 4)
+        for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=5e-4, rtol=5e-4)
+
+    def test_paged_over_kv_heads(self):
+        mesh = build_mesh({"tp": 4}, jax.devices()[:4])
+        rng = np.random.default_rng(3)
+        b, t, h, kv, d, page, width = 2, 1, 8, 4, 32, 8, 4
+        q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+        k, v = (jnp.asarray(rng.standard_normal(
+            (b * width + 1, page, kv, d)), jnp.float32) for _ in range(2))
+        tables = jnp.asarray(
+            np.arange(1, b * width + 1).reshape(b, width), jnp.int32)
+        pos = jnp.asarray([[13], [30]], jnp.int32)
+        ref = paged_attention(q, k, v, tables, pos, force="reference")
+        pool_sh = NamedSharding(mesh, P(None, None, "tp"))
+        with jax.set_mesh(mesh):
+            got = jax.jit(functools.partial(
+                paged_attention, force="interpret"))(
+                    jax.device_put(q, NamedSharding(mesh, P(None, None,
+                                                            "tp"))),
+                    jax.device_put(k, pool_sh), jax.device_put(v, pool_sh),
+                    tables, pos)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
+                                   atol=2e-5, rtol=2e-5)

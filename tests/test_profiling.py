@@ -336,7 +336,7 @@ class TestProfileStore:
 
 class TestStepProfiler:
     def test_observe_step_emits_hist_and_mfu_with_flops(self, monkeypatch):
-        monkeypatch.setenv("RAYTPU_CHIP_PEAK_FLOPS", "1e12")
+        monkeypatch.setattr(stepprof, "device_peak_flops", lambda: 1e12)
         sp = stepprof.StepProfiler("train")
         sp.observe_step(0.5, flops=1e11)         # 1e11/0.5/1e12 = 0.2
         assert sp._mfu.value == pytest.approx(0.2)
@@ -345,7 +345,7 @@ class TestStepProfiler:
         assert sp._mfu.value == pytest.approx(0.2)
 
     def test_mfu_clamped_to_one(self, monkeypatch):
-        monkeypatch.setenv("RAYTPU_CHIP_PEAK_FLOPS", "1e6")
+        monkeypatch.setattr(stepprof, "device_peak_flops", lambda: 1e6)
         sp = stepprof.StepProfiler("infer")
         sp.observe_step(0.001, flops=1e9)
         assert sp._mfu.value == 1.0
@@ -372,11 +372,28 @@ class TestStepProfiler:
         dt = sp.mark()
         assert dt is not None and dt > 0
 
-    def test_peak_flops_env_override(self, monkeypatch):
-        monkeypatch.setenv("RAYTPU_CHIP_PEAK_FLOPS", "42e12")
-        assert stepprof.device_peak_flops() == 42e12
-        monkeypatch.setenv("RAYTPU_CHIP_PEAK_FLOPS", "junk")
-        assert stepprof.device_peak_flops() > 0  # falls through table
+    def test_peaks_come_from_the_one_table(self):
+        from raytpu.core.chip_specs import chip_spec, generation_spec
+
+        v5e = chip_spec("TPU v5 lite")  # what a v5e chip reports
+        assert (v5e.generation, v5e.bf16_flops, v5e.hbm_bytes_per_s,
+                v5e.hbm_bytes) == ("v5e", 197e12, 819e9, 16e9)
+        assert generation_spec("v5litepod") is v5e
+        for unknown in ("cpu", "TPU v9", ""):
+            with pytest.raises(ValueError, match="device_kind"):
+                chip_spec(unknown)
+        with pytest.raises(ValueError, match="generation"):
+            generation_spec("v9")
+
+    def test_unknown_device_publishes_no_mfu(self):
+        # The CPU has no published peak: the lookup raises, and the
+        # always-on gauge stays silent instead of guessing one.
+        with pytest.raises(ValueError):
+            stepprof.device_peak_flops()
+        sp = stepprof.StepProfiler("train")
+        before = sp._mfu.value
+        sp.observe_step(0.5, flops=1e11)
+        assert sp._mfu.value == before
 
     def test_cost_analysis_flops_positive_or_none(self):
         jax = pytest.importorskip("jax")

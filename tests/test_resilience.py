@@ -618,3 +618,35 @@ class TestTuningConstants:
         assert tuning.PG_POLL_PERIOD_S < tuning.PG_CREATE_TIMEOUT_S
         assert tuning.OBJECT_POLL_MIN_S <= tuning.OBJECT_POLL_MAX_S
         assert tuning.RECONNECT_BASE_DELAY_S <= tuning.RECONNECT_MAX_DELAY_S
+
+
+class TestHeadStall:
+    def test_a_frozen_head_does_not_blame_its_nodes(self, monkeypatch):
+        """While the head process (or the whole host) stands still it
+        receives no heartbeat. When it wakes, that silence says nothing
+        about any node. A v5e host froze for 16 s while four TPU
+        runtimes started at once, and the head then declared its only
+        node dead."""
+        from raytpu.cluster.cluster_utils import Cluster
+
+        monkeypatch.setenv("RAYTPU_HEARTBEAT_TIMEOUT_S", "1.0")
+        monkeypatch.setenv("RAYTPU_HEARTBEAT_PERIOD_S", "0.2")
+        monkeypatch.setenv("RAYTPU_HEALTH_CHECK_PERIOD_S", "0.2")
+        cluster = Cluster(num_nodes=1)
+        try:
+            cluster.wait_for_nodes()
+            cluster.pause_head()
+            time.sleep(2.5)  # well past the heartbeat timeout
+            cluster.resume_head()
+            client = RpcClient(cluster.address)
+            try:
+                deadline = time.monotonic() + 3.0
+                while time.monotonic() < deadline:
+                    nodes = [n for n in client.call("list_nodes")
+                             if n["labels"].get("role") != "driver"]
+                    assert [n["alive"] for n in nodes] == [True]
+                    time.sleep(0.2)
+            finally:
+                client.close()
+        finally:
+            cluster.shutdown()

@@ -120,6 +120,36 @@ class TestServeBasics:
         assert h.remote(1).result() == 2
 
 
+class TestReplicaReadiness:
+    def test_a_slow_constructor_is_waited_for_not_killed(
+            self, serve_instance, tmp_path):
+        """A replica that loads a model and compiles for it starts in
+        longer than any health-check deadline. It must get no traffic
+        and no deadline until its constructor has finished; it used to
+        count as RUNNING from birth and be replaced when its first
+        health check, queued behind the constructor, timed out."""
+        births = tmp_path / "births"
+
+        @serve.deployment(health_check_period_s=0.1,
+                          health_check_timeout_s=0.3)
+        class SlowStart:
+            def __init__(self):
+                with open(births, "a") as f:
+                    f.write("x")
+                time.sleep(1.5)  # five health-check deadlines
+                self.loaded = True
+
+            def __call__(self, x):
+                return self.loaded and x
+
+        handle = serve.run(SlowStart.bind(), name="slow-start")
+        # run() returned: the replica is constructed, once, and answers.
+        assert births.read_text() == "x"
+        assert handle.remote(7).result() == 7
+        time.sleep(1.0)  # several more health-check periods
+        assert births.read_text() == "x"
+
+
 class TestAutoscalingPolicy:
     def test_scale_up_after_delay(self):
         cfg = AutoscalingConfig(min_replicas=1, max_replicas=10,

@@ -88,6 +88,27 @@ class TestProcessExecution:
 
 
 class TestChipIsolation:
+    def test_chip_env_shows_the_tpu_only_to_its_leaseholder(self):
+        from raytpu.cluster.worker_pool import chip_env
+
+        one = chip_env((2,))
+        assert one["TPU_VISIBLE_CHIPS"] == one["RAYTPU_VISIBLE_CHIPS"] == "2"
+        assert one["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert one["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert "JAX_PLATFORMS" not in one
+        # Processes sharing a host must not share a controller port.
+        assert one["TPU_MESH_CONTROLLER_PORT"] != \
+            chip_env((3,))["TPU_MESH_CONTROLLER_PORT"]
+        assert chip_env((0, 1))["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+        # The whole 2x2 host: every chip visible, no bounds to get wrong.
+        host = chip_env((0, 1, 2, 3))
+        assert host["TPU_VISIBLE_CHIPS"] == "0,1,2,3"
+        assert "TPU_CHIPS_PER_PROCESS_BOUNDS" not in host
+        assert "JAX_PLATFORMS" not in host
+        # No lease: held to the CPU, so it cannot take a leased chip.
+        assert chip_env(()) == {"RAYTPU_VISIBLE_CHIPS": "",
+                                "JAX_PLATFORMS": "cpu"}
+
     def test_two_actors_disjoint_chips(self, driver):
         @raytpu.remote(num_tpus=1)
         class ChipOwner:
