@@ -1,0 +1,18 @@
+"""Share of the traced window, averaged over the chips, in which a
+collective was in flight and no other instruction ran on that chip
+(``trace_reduce.collective_exposed_seconds``)."""
+
+LAYER = "sharding"
+UNIT = "%"
+MOVES = "train_tokens_per_s_chip"
+SOURCE = "device_trace"
+
+
+def read(run):
+    from perfbench import trace_reduce
+
+    if run.trace is None or not run.trace.device:
+        return None
+    lo, hi = trace_reduce.window_of(run.trace)
+    return 100.0 * trace_reduce.collective_exposed_seconds(run.trace) \
+        / (hi - lo)

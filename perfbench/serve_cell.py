@@ -1,0 +1,485 @@
+"""A serving cell: ``serve.run(LLMDeployment)`` under a closed loop of
+clients or an open loop of arrivals, timed at the client.
+
+Tokens are counted and stamped as they reach the client thread that
+iterates ``handle.generate.remote_streaming``; the engine is observed
+through ``probe.ProbedEngine``. Set-up is everything before the window:
+weights, deployment, one warm request per program the mix can reach, the
+logits check against the plain reference, and the lead-in traffic.
+"""
+
+from __future__ import annotations
+
+import statistics
+import threading
+import time
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+
+import numpy as np
+
+from perfbench import probe, traffic
+from perfbench.byname import load_family
+from perfbench.train_cell import SEED_MASK, memory_peak_bytes
+
+clock = time.perf_counter
+JOIN_TIMEOUT_S = 120.0
+# One warm request, cold: the program's compile is inside it. A request
+# that outlasts this has met a dead engine (its step loop raised), and
+# the run fails instead of waiting for ever.
+COLD_REQUEST_TIMEOUT_S = 600.0
+
+
+def consume_all(streams: Sequence["Stream"], handle, timeout_s: float
+                ) -> None:
+    """Run the streams to their end, each on a thread of its own."""
+    never = threading.Event()
+    threads = [threading.Thread(target=s.consume, args=(handle, never),
+                                daemon=True) for s in streams]
+    for th in threads:
+        th.start()
+    deadline = clock() + timeout_s
+    for th in threads:
+        th.join(max(0.0, deadline - clock()))
+    if any(th.is_alive() for th in threads):
+        raise RuntimeError(
+            f"no end of a request within {timeout_s:.0f} s: the engine's "
+            f"step loop has most likely died (see the errors above)")
+
+
+class Stream:
+    """One request as its client saw it."""
+
+    def __init__(self, index: int, prompt: List[int], new_tokens: int,
+                 due: Optional[float] = None):
+        self.index = index
+        self.prompt = prompt
+        self.new_tokens = new_tokens
+        self.due = due            # open loop: when it should have been sent
+        self.sent: Optional[float] = None
+        self.times: List[float] = []   # arrival of each token
+        self.tokens: List[int] = []
+        self.request_id = ""
+        self.error: Optional[str] = None
+        self.cancelled = False
+        self.finished = False
+
+    def consume(self, handle, stop: threading.Event, on_token=None) -> None:
+        self.sent = clock()
+        try:
+            gen = handle.generate.remote_streaming(
+                self.prompt, max_new_tokens=self.new_tokens)
+            self.request_id = gen.request_id
+            for tok in gen:
+                self.times.append(clock())
+                self.tokens.append(int(tok))
+                if on_token is not None:
+                    on_token(self)
+                if stop.is_set() and len(self.tokens) < self.new_tokens:
+                    gen.close()
+                    self.cancelled = True
+                    return
+            self.finished = True
+        except Exception as e:  # a failed stream is a result, not a crash
+            self.error = repr(e)
+
+    def ok(self, vocab: int) -> bool:
+        """``vocab`` is the rows the served model holds: the published
+        vocabulary padded, any of which its output head can name."""
+        return (self.error is None
+                and all(0 <= t < vocab for t in self.tokens)
+                and (len(self.tokens) == self.new_tokens if self.finished
+                     else self.cancelled))
+
+
+# ---- the window, on step boundaries --------------------------------------------
+
+
+def step_clusters(times: Sequence[float], threshold: float
+                  ) -> List[Tuple[float, float, int]]:
+    """Token arrivals grouped into engine steps: a step's tokens reach the
+    clients together, steps are further apart than ``threshold``.
+    Returns (first, last, count) per group, in order."""
+    out: List[List] = []
+    for t in sorted(times):
+        if out and t - out[-1][1] <= threshold:
+            out[-1][1] = t
+            out[-1][2] += 1
+        else:
+            out.append([t, t, 1])
+    return [tuple(c) for c in out]
+
+
+def aligned_window(times: Sequence[float], t_ref: float, seconds: float,
+                   threshold: float) -> Tuple[float, float]:
+    """Open at the end of the step that delivered the token stamped
+    ``t_ref``; close at the end of the first step that ends ``seconds``
+    or more later."""
+    clusters = step_clusters(times, threshold)
+    opened = next(c for c in clusters if c[0] <= t_ref <= c[1])
+    t_open = opened[1]
+    for c in clusters:
+        if c[1] >= t_open + seconds:
+            return t_open, c[1]
+    raise RuntimeError("the traffic stopped before the window closed")
+
+
+def gaps_in(streams: Sequence[Stream], window: Tuple[float, float]
+            ) -> List[float]:
+    """Every gap between consecutive tokens of one stream whose later
+    token arrived inside the window."""
+    lo, hi = window
+    return [b - a for s in streams for a, b in zip(s.times, s.times[1:])
+            if lo < b <= hi]
+
+
+def from_due(streams: Sequence[Stream], what: str) -> List[float]:
+    """Of requests that had a due time (an open loop's), the seconds from
+    when each was due to its first token (``"first_token"``) or to when
+    the generator really sent it (``"sent"``): a request is timed from
+    when its user wanted it, and a generator that runs late must show."""
+    if what == "first_token":
+        return [s.times[0] - s.due for s in streams
+                if s.due is not None and s.times]
+    return [s.sent - s.due for s in streams
+            if s.due is not None and s.sent is not None]
+
+
+def percentile(values: Sequence[float], q: float) -> float:
+    return float(np.percentile(np.asarray(values, np.float64), q))
+
+
+# ---- set-up -----------------------------------------------------------------------
+
+
+def deploy(family, cfg: Mapping, mix: Mapping, seed: int,
+           timeout_s: float):
+    from raytpu import serve
+
+    probe.install()
+    app = serve.LLMDeployment.bind(
+        model=family.SERVE_MODEL, model_config=family.program_config(
+            cfg, mix.get("model_overrides", ())),
+        engine_options=dict(mix["engine_options"]), seed=seed & SEED_MASK)
+    handle = serve.run(app, name="perfbench", route_prefix=None,
+                       wait_for_ready_timeout_s=timeout_s)
+    return handle, probe.ProbedEngine.instances[-1]
+
+
+def shutdown() -> None:
+    import raytpu
+    from raytpu import serve
+
+    try:
+        serve.shutdown()
+    finally:
+        raytpu.shutdown()
+        probe.uninstall()
+
+
+def warm(handle, mix: Mapping, seed: int, vocab: int, rows: int
+         ) -> List[Stream]:
+    """One request at a time per entry of the mix's ``warmup``
+    (``[prompt_tokens, new_tokens]``): the mix's author lists what reaches
+    every program its traffic can reach, and nothing else."""
+    done = []
+    for i, (plen, new) in enumerate(mix["warmup"]):
+        s = Stream(-1 - i, traffic.prompt_tokens(seed, i, plen, vocab,
+                                                 stream=8), new)
+        consume_all([s], handle, COLD_REQUEST_TIMEOUT_S)
+        if not s.ok(rows):
+            raise RuntimeError(f"warm request {plen}+{new} failed: "
+                               f"{s.error or s.tokens}")
+        done.append(s)
+    return done
+
+
+def check_logits(handle, engine, family, cfg: Mapping, mix: Mapping,
+                 seed: int) -> Dict:
+    """Prefill and eight decoded positions of two seeded prompts, through
+    the served engine's cache, against the reference's one forward pass
+    over the same tokens and parameters. Logits, not tokens."""
+    import jax
+
+    vocab = int(cfg["vocab_size"])
+    held = family.vocab_rows_held(cfg)
+    spec = mix["check"]
+    positions = int(spec.get("decode_positions", 8))
+    streams = [Stream(-100 - i, traffic.prompt_tokens(seed, i, n, vocab,
+                                                      stream=9),
+                      positions + 1)
+               for i, n in enumerate(spec["prompt_tokens"])]
+    captured = engine.capture_logits()
+    try:
+        consume_all(streams, handle, COLD_REQUEST_TIMEOUT_S)
+    finally:
+        engine.stop_capture()
+    if not all(s.ok(held) for s in streams):
+        raise RuntimeError(f"check requests failed: "
+                           f"{[s.error or len(s.tokens) for s in streams]}")
+    # What the engine computed, per request, in order of position.
+    rows: Dict[str, List[np.ndarray]] = {s.request_id: [] for s in streams}
+    plen = {s.request_id: len(s.prompt) for s in streams}
+    pending_prefill = None
+    decode_ids: List[str] = []
+    for kind, value, *_ in captured:
+        if kind == "prefill_id":
+            pending_prefill = value
+        elif kind == "prefill":
+            rows[pending_prefill].append(
+                np.asarray(value[plen[pending_prefill] - 1], np.float32))
+        elif kind == "decode_ids":
+            decode_ids = value
+        elif kind == "decode":
+            got = np.asarray(value, np.float32)
+            for i, rid in enumerate(decode_ids):
+                if rid in rows and len(rows[rid]) <= positions:
+                    rows[rid].append(got[i])
+    width = max(len(s.prompt) for s in streams) + positions
+    toks = np.zeros((len(streams), width), np.int32)
+    for i, s in enumerate(streams):
+        seq = s.prompt + s.tokens[:positions]
+        toks[i, :len(seq)] = seq
+    ref = np.asarray(jax.jit(
+        lambda p, t: family.logits(cfg, p, t))(engine.params_given, toks))
+    worst = 0.0
+    for i, s in enumerate(streams):
+        got = np.stack(rows[s.request_id])
+        n = len(s.prompt)
+        want = ref[i, n - 1:n + positions]
+        if got.shape != want.shape:
+            raise RuntimeError(f"check: engine gave {got.shape} logits, "
+                               f"reference {want.shape}")
+        worst = max(worst, float(np.abs(got - want).max()
+                                 / np.abs(want).max()))
+    return {"rel_err": worst, "tolerance": float(spec["tolerance"]),
+            "ok": bool(np.isfinite(worst) and worst <= spec["tolerance"])}
+
+
+# ---- traffic --------------------------------------------------------------------
+
+
+def trace_from(tracer: Optional[probe.Tracer], engine, start_at: float
+               ) -> Optional[threading.Thread]:
+    """Trace the first ``tracer.seconds`` after ``start_at`` from a thread
+    of its own: starting and stopping the profiler blocks for a while, and
+    must hold up neither the arrivals nor the window's end."""
+    if tracer is None:
+        return None
+
+    def body() -> None:
+        time.sleep(max(0.0, start_at - clock()))
+        engine.tracer = tracer
+        tracer.start()
+        time.sleep(tracer.seconds)
+        tracer.stop()
+
+    th = threading.Thread(target=body, name="pb-tracer", daemon=True)
+    th.start()
+    return th
+
+
+def run_closed(handle, engine, mix: Mapping, seed: int, vocab: int,
+               seconds: float, tracer: Optional[probe.Tracer]) -> Dict:
+    plan = traffic.closed_schedule(mix, seed)["clients"]
+    stop = threading.Event()
+    opened = threading.Event()
+    streams: List[List[Stream]] = [[] for _ in plan]
+    ref_client = int(mix.get("window_opens_after_client", 0))
+
+    def client(c: int) -> None:
+        for k, req in enumerate(plan[c]):
+            s = Stream(req["index"], traffic.prompt_tokens(
+                seed, req["index"], req["prompt_len"], vocab),
+                req["new_tokens"])
+            streams[c].append(s)
+            mark = (lambda _s: opened.set()) \
+                if c == ref_client and k == 1 else None
+            s.consume(handle, stop, mark)
+            if stop.is_set() or s.error:
+                return
+
+    threads = [threading.Thread(target=client, args=(c,),
+                                name=f"pb-client-{c}", daemon=True)
+               for c in range(len(plan))]
+    for th in threads:
+        th.start()
+    if not opened.wait(JOIN_TIMEOUT_S * 3):
+        stop.set()
+        raise RuntimeError("the first wave never turned over")
+    t_ref = streams[ref_client][1].times[0]
+    tracing = trace_from(tracer, engine, t_ref)
+    # Past the nominal end by a few steps, so the closing step is whole.
+    step_s = statistics.median(
+        b - a for s in streams[ref_client][:1]
+        for a, b in zip(s.times, s.times[1:]))
+    time.sleep(max(0.0, t_ref + seconds + max(1.0, 4 * step_s) - clock()))
+    if tracing:
+        tracing.join(JOIN_TIMEOUT_S)
+    stop.set()
+    for th in threads:
+        th.join(JOIN_TIMEOUT_S)
+    flat = [s for c in streams for s in c]
+    alive = [th.name for th in threads if th.is_alive()]
+    window = aligned_window([t for s in flat for t in s.times], t_ref,
+                            seconds, 0.25 * step_s)
+    return {"streams": flat, "window": window, "stuck_threads": alive}
+
+
+def run_open(handle, engine, mix: Mapping, seed: int, vocab: int,
+             seconds: float, tracer: Optional[probe.Tracer]) -> Dict:
+    lead = float(mix.get("lead_in_s", 3.0))
+    drain = float(mix.get("drain_s", 30.0))
+    plan = traffic.open_schedule(mix, seed, lead + seconds)["arrivals"]
+    stop = threading.Event()   # set only to cancel what is left at the end
+    streams: List[Stream] = []
+    t0 = clock() + 0.05
+    threads: List[threading.Thread] = []
+    window = (t0 + lead, t0 + lead + seconds)
+    tracing = trace_from(tracer, engine, window[0])
+    for arrival in plan:
+        due = t0 + arrival["due_s"]
+        if due > window[1]:
+            break
+        s = Stream(arrival["index"], traffic.prompt_tokens(
+            seed, arrival["index"], arrival["prompt_len"], vocab),
+            arrival["new_tokens"], due)
+        streams.append(s)
+        time.sleep(max(0.0, due - clock()))
+        th = threading.Thread(target=s.consume, args=(handle, stop),
+                              name=f"pb-arrival-{arrival['index']}",
+                              daemon=True)
+        th.start()
+        threads.append(th)
+    time.sleep(max(0.0, window[1] - clock()))
+    if tracing:
+        tracing.join(JOIN_TIMEOUT_S)
+    # Every request due inside the window is waited for, up to one
+    # request's time past its end; what is still running then has stalled.
+    deadline = window[1] + drain
+    for th in threads:
+        th.join(max(0.0, deadline - clock()))
+    stuck = [th.name for th in threads if th.is_alive()]
+    stop.set()
+    for th in threads:
+        th.join(5.0)
+    return {"streams": streams, "window": window, "stuck_threads": stuck}
+
+
+# ---- one run ----------------------------------------------------------------------
+
+
+def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
+        devices, process_start, marks, log) -> Dict:
+    from perfbench.rundata import RunData
+
+    import raytpu
+
+    family = load_family(dirs, cfg)
+    vocab = int(cfg["vocab_size"])        # prompts draw below this
+    rows = family.vocab_rows_held(cfg)    # outputs may name any row held
+
+    def progress(what: str) -> None:
+        log("progress", {"at_s": round(clock() - process_start, 1),
+                         "done": what})
+
+    t = clock()
+    raytpu.init()
+    marks["fabric"] = clock() - t
+    t = clock()
+    handle, engine = deploy(family, cfg, mix, seed,
+                            float(mix.get("deploy_timeout_s", 1200.0)))
+    progress("deploy")
+    try:
+        # LLMDeployment makes the parameters, then builds its engine.
+        marks["weights"] = engine.init_started - t
+        marks["deploy"] = clock() - engine.init_started
+        t = clock()
+        warm(handle, mix, seed, vocab, rows)
+        marks["programs_and_warm_traffic"] = clock() - t
+        progress("warm traffic")
+        programs = engine.steps[-1].compiles if engine.steps else 0
+        t = clock()
+        check = check_logits(handle, engine, family, cfg, mix, seed)
+        marks["check"] = clock() - t
+        progress("check")
+        tracer = probe.Tracer(trace_dir, trace_seconds) if trace_dir \
+            else None
+        t = clock()
+        runner = {"closed": run_closed, "open": run_open}[mix["kind"]]
+        out = runner(handle, engine, mix, seed, vocab, seconds, tracer)
+        marks["lead_in_traffic"] = out["window"][0] - t
+        progress("window")
+        peak = max(memory_peak_bytes(d) for d in devices)
+        memory_stats = dict(devices[0].memory_stats() or {})
+    finally:
+        shutdown()
+    progress("shutdown")
+
+    lo, hi = window = out["window"]
+    streams: List[Stream] = out["streams"]
+    steps = [r for r in engine.steps if lo < r.end <= hi]
+    # Running totals are differenced against the last step before the
+    # window, so that what its first step compiled or preempted counts.
+    before = next((r for r in reversed(engine.steps) if r.end <= lo), None)
+    marks["setup_s"] = lo - process_start
+    log("setup", {k: round(v, 3) for k, v in marks.items()}
+        | {"programs": programs, "check_rel_err": check["rel_err"]})
+
+    if mix["kind"] == "open":
+        judged = [s for s in streams if lo <= s.due <= hi]
+        bad = [s for s in judged if not (s.finished and s.ok(rows))]
+    else:
+        judged = [s for s in streams if s.times and s.times[-1] > lo]
+        bad = [s for s in judged if not s.ok(rows)]
+    gaps = gaps_in(streams, window)
+    delivered = sum(1 for s in streams for x in s.times if lo < x <= hi)
+    # No step before the window (no warm-up): every compile counts.
+    compiles = steps[-1].compiles - (before.compiles if before else 0) \
+        if steps else 0
+    e2e = {"setup_s": lo - process_start,
+           "out_tokens_per_s": delivered / (hi - lo)}
+    if gaps:
+        e2e["itl_p95_ms"] = 1e3 * percentile(gaps, 95)
+    ttft, late = (from_due(judged, what) for what in ("first_token",
+                                                      "sent"))
+    if ttft:
+        e2e["ttft_p95_ms"] = 1e3 * percentile(ttft, 95)
+    turnovers = [s for s in streams
+                 if s.finished and lo < s.times[-1] <= hi]
+
+    def in_flight(at: float) -> int:
+        """Requests sent and not yet finished at ``at``: a backlog that
+        grows over the window means the offered rate is past the knee."""
+        return sum(1 for s in streams if s.sent is not None and s.sent <= at
+                   and not (s.finished and s.times[-1] <= at))
+    log("window", {
+        "seconds": hi - lo, "tokens": delivered, "requests": len(judged),
+        "engine_steps": len(steps),
+        "decode_steps": sum(1 for r in steps if r.decodes),
+        "prefills": sum(r.prefills for r in steps),
+        "prefill_tokens": steps[-1].prefill_tokens - before.prefill_tokens
+        if steps and before else None,
+        "turnovers": len(turnovers),
+        "in_flight_at_open": in_flight(lo), "in_flight_at_close":
+        in_flight(hi),
+        "generator_late_p95_ms": 1e3 * percentile(late, 95) if late
+        else None,
+        "largest_gap_ms": 1e3 * max(gaps) if gaps else None,
+        "median_gap_ms": 1e3 * statistics.median(gaps) if gaps else None,
+        "first_step_index": engine.steps.index(steps[0]) if steps else None,
+        "last_step_index": engine.steps.index(steps[-1]) if steps else None,
+        "steps_from_open_to_first_turnover": next(
+            (i for i, r in enumerate(steps) if r.prefills), None),
+        "compiles_in_window": compiles, "failed": len(bad),
+        "memory_stats": memory_stats,
+        "stuck_threads": out["stuck_threads"]})
+    data = RunData(
+        cell=cell, cfg=cfg, mix=mix, family=family, chips=len(devices),
+        peaks=None, window=window, end_to_end=e2e,
+        memory_peak_bytes=int(peak), streams=streams, engine_steps=steps,
+        step_before=before,
+        traced_steps=[r for r in engine.steps if r.traced])
+    return {"data": data, "xplane": tracer.xplane() if tracer else None,
+            "correct": check["ok"] and not bad and compiles == 0
+            and not out["stuck_threads"],
+            "attempted": len(judged), "failed": len(bad)}
