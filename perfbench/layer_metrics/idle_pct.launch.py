@@ -1,0 +1,14 @@
+"""Share of the traced window in which chip 0 ran nothing while the host was
+on its way to a launch: ``infer.schedule``, a prefill phase or
+``infer.decode.launch`` innermost (``steplog.idle_bucket``)."""
+
+LAYER = "device"
+UNIT = "%"
+MOVES = "itl_p95_ms"
+SOURCE = "device_trace"
+
+
+def read(run):
+    from perfbench import steplog
+
+    return steplog.idle_pct(run, "launch")

@@ -1,0 +1,223 @@
+"""The readers of the program's step log (``steplog.py`` and the ten
+``layer_metrics`` files that call it): on a CPU rehearsal of a closed
+cell, where the six medians are numbers and the four idle shares are left
+out for want of a device trace; and on ``recorded_trace.json`` with a
+hand-made step log, where the four idle shares must sum to the trace's
+idle share and a log that cannot be trusted gives nothing.
+"""
+
+import json
+import os
+import types
+
+import pytest
+
+from perfbench import byname, probe, run, steplog
+from perfbench import trace_reduce as tr
+from perfbench.rundata import RunData
+from perfbench.tests.test_rehearsal import (CELLS, REHEARSAL, SEED,
+                                            benchmark_with)
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+MEDIANS = ("sched_ms_p50", "decode_launch_ms_p50", "decode_wait_ms_p50",
+           "decode_sample_ms_p50", "prefill_ms_p50", "step_gap_ms_p50")
+IDLE = ("idle_pct.launch", "idle_pct.wait", "idle_pct.sample",
+        "idle_pct.between_steps")
+CLOCK = 1000.0  # perf_counter reads this much more than the trace's clock
+
+
+def read(name, data):
+    return byname.load_reader([run.HERE], name).read(data)
+
+
+def test_rehearsal_gives_the_six_medians_and_no_idle_share(tmp_path):
+    bench = benchmark_with(CELLS)
+    result = run.run_cell(bench, [REHEARSAL, run.HERE], "tiny-closed", SEED,
+                          2.0, True, require_tpu=False,
+                          work_dir=str(tmp_path))
+    assert result["correct"] is True, result
+    got = result["metrics"]
+    for name in MEDIANS:
+        assert got[name]["unit"] == "ms" and got[name]["value"] > 0, name
+    assert not set(IDLE) & set(got)
+    # The log is the engine's own, whole, on the window's clock, and tells
+    # the same story as the benchmark's span around ``step``: a step that
+    # only decoded is its scheduler and the three parts of its decode.
+    engine = probe.ProbedEngine.instances[-1]
+    log = engine.step_log()
+    assert log["oldest_start"] == log["steps"][0]["start"]
+    assert len(log["steps"]) == len(engine.steps)
+    for record, seen in zip(log["steps"], engine.steps):
+        assert seen.start <= record["start"] <= record["end"] <= seen.end
+        assert record["decodes"] == seen.decodes
+        assert record["live_pages"] == seen.live_pages
+        assert len(record.get("prefills", ())) == seen.prefills
+    assert any(s.get("prefills") for s in log["steps"])
+    assert all(s["compiled"] == 0 for s in log["steps"][-20:])
+    only_decoded = [s for s in log["steps"][-100:]
+                    if s["decodes"] and not s.get("prefills")]
+    assert only_decoded
+    for record in only_decoded:
+        parts = steplog.phase_seconds(record, (
+            "infer.schedule", "infer.decode.launch", "infer.decode.wait",
+            "infer.decode.sample"))
+        assert 0 <= record["end"] - record["start"] - parts < 1e-3
+
+
+# ---- a hand-made log against a recorded trace ------------------------------
+
+
+def step_record(lo, hi, decodes=8):
+    """A decode step filling the trace's span ``(lo, hi)``, on the
+    program's clock, with the replica loop's two phases around it."""
+    at = lambda x: CLOCK + lo + x * (hi - lo)  # noqa: E731
+    return {
+        "start": at(0.0), "end": at(1.0), "decodes": decodes,
+        "phases": [["serve.llm.lock_wait", at(-0.0004), at(-0.0001)],
+                   ["infer.schedule", at(0.01), at(0.02)],
+                   ["infer.decode", at(0.02), at(0.999)],
+                   ["infer.decode.launch", at(0.02), at(0.30)],
+                   ["infer.decode.wait", at(0.30), at(0.90)],
+                   ["infer.decode.sample", at(0.90), at(0.999)],
+                   ["serve.llm.publish", at(1.0002), at(1.0005)]]}
+
+
+class Engine:
+    def __init__(self, steps, oldest=CLOCK - 1.0):
+        # By default the ring still holds steps from before the window.
+        self.log = {"steps": steps, "oldest_start": oldest}
+
+    def step_log(self, since=0.0):
+        return self.log
+
+
+def run_data(trace, engine, monkeypatch, offsets=(3e-6, 5e-6)):
+    """What ``run_cell`` hands the readers, for a trace whose
+    ``pb.engine.step`` spans opened ``offsets`` after their stamps."""
+    monkeypatch.setattr(probe.ProbedEngine, "instances", [engine])
+    marks = tr.spans(trace, "pb.engine.step")
+    traced = [types.SimpleNamespace(start=CLOCK + m.start - late)
+              for m, late in zip(marks, offsets)]
+    return RunData(cell={}, cfg={}, mix={}, family=None, chips=1, peaks=None,
+                   window=(CLOCK, CLOCK + 1.0), end_to_end={},
+                   memory_peak_bytes=0, trace=trace, traced_steps=traced)
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    with open(os.path.join(HERE, "recorded_trace.json")) as f:
+        return tr.Trace.from_json(json.load(f))
+
+
+def recorded_steps(trace):
+    return [step_record(m.start, m.end)
+            for m in tr.spans(trace, "pb.engine.step")]
+
+
+def test_idle_shares_sum_to_the_traces_idle_share(recorded, monkeypatch):
+    data = run_data(recorded, Engine(recorded_steps(recorded)), monkeypatch)
+    shares = {name: read(name, data) for name in IDLE}
+    assert all(v is not None and v > 0 for v in shares.values()), shares
+    assert sum(shares.values()) == pytest.approx(data.device_idle_pct(),
+                                                 abs=1e-6)
+    # The device runs from 34 % to 75 % of each step: idle before it is
+    # the launch's (and the scheduler's), after it the wait's until 90 %.
+    assert shares["idle_pct.launch"] > shares["idle_pct.sample"]
+    assert shares["idle_pct.wait"] > shares["idle_pct.sample"]
+    # Between the steps: the 6.36 us between the two spans, and the 4 us
+    # (the median offset of the pairs) by which the first record opens
+    # after the window does.
+    first, second = tr.spans(recorded, "pb.engine.step")
+    lo, hi = tr.window_of(recorded)
+    assert shares["idle_pct.between_steps"] == pytest.approx(
+        100 * (second.start - first.end + 4e-6) / (hi - lo), rel=1e-3)
+
+
+def test_each_piece_of_a_gap_goes_to_the_phase_open_over_it(monkeypatch):
+    """One step of 10 ms whose device works from 3 to 7 ms: the gap
+    that begins in the wait is cut where the wait ends."""
+    trace = tr.Trace(
+        device={0: {"XLA Ops": [tr.Event("%fusion.1 = f32[8] fusion()",
+                                         0.003, 0.007)]}},
+        host={"python": [tr.Event("pb.engine.step", 0.0, 0.010)]})
+    data = run_data(trace, Engine([step_record(0.0, 0.010)]), monkeypatch,
+                    offsets=(0.0,))
+    # schedule 0.1-0.2, launch 0.2-3.0, wait 3.0-9.0, sample 9.0-9.99 ms;
+    # 0.1 ms before the schedule and 0.01 ms after the decode are the
+    # step's own and count with the launch.
+    assert read("idle_pct.launch", data) == pytest.approx(30.0 + 0.1)
+    assert read("idle_pct.wait", data) == pytest.approx(20.0)
+    assert read("idle_pct.sample", data) == pytest.approx(9.9)
+    assert read("idle_pct.between_steps", data) == pytest.approx(0.0)
+    assert sum(read(n, data) for n in IDLE) == pytest.approx(60.0)
+
+
+def test_offsets_that_disagree_give_nothing(recorded, monkeypatch):
+    engine = Engine(recorded_steps(recorded))
+    data = run_data(recorded, engine, monkeypatch, offsets=(3e-6, 303e-6))
+    assert steplog.clock_offset(data) is None
+    assert [read(name, data) for name in IDLE] == [None] * 4
+    # Within 200 us they agree, and the median is the offset.
+    data = run_data(recorded, engine, monkeypatch, offsets=(3e-6, 103e-6))
+    assert steplog.clock_offset(data) == pytest.approx(-CLOCK + 53e-6)
+    assert read("idle_pct.wait", data) > 0
+
+
+def test_one_late_span_among_many_does_not_decide(monkeypatch):
+    """A span that opened 4 ms after its stamp (another thread held the
+    interpreter) is the benchmark's delay, not the clocks'."""
+    spans = [tr.Event("pb.engine.step", 0.01 * i, 0.01 * i + 0.009)
+             for i in range(9)]
+    trace = tr.Trace(
+        device={0: {"XLA Ops": [tr.Event("%fusion.1 = f32[8] fusion()",
+                                         s.start + 0.003, s.start + 0.007)
+                                for s in spans]}},
+        host={"python": spans})
+    engine = Engine([step_record(s.start, s.end) for s in spans])
+    late = [3e-6] * 9
+    late[4] = 4e-3
+    data = run_data(trace, engine, monkeypatch, offsets=late)
+    assert steplog.clock_offset(data) == pytest.approx(-CLOCK + 3e-6)
+    assert sum(read(n, data) for n in IDLE) == pytest.approx(
+        data.device_idle_pct())
+
+
+def test_a_truncated_log_gives_nothing(recorded, monkeypatch):
+    steps = recorded_steps(recorded)
+    # The ring's oldest record starts after the window opened.
+    data = run_data(recorded, Engine(steps, oldest=CLOCK + 0.001),
+                    monkeypatch)
+    assert [read(name, data) for name in MEDIANS + IDLE] == [None] * 10
+    data = run_data(recorded, Engine(steps), monkeypatch)
+    assert all(read(name, data) is not None
+               for name in MEDIANS if name != "prefill_ms_p50")
+
+
+def test_a_program_without_a_step_log_gives_nothing(recorded, monkeypatch):
+    """The parent of the PR that brought ``step_log()``: the readers
+    return None and do not raise, and the line leaves them out."""
+    data = run_data(recorded, object(), monkeypatch)
+    assert [read(name, data) for name in MEDIANS + IDLE] == [None] * 10
+    monkeypatch.setattr(probe.ProbedEngine, "instances", [])
+    assert [read(name, data) for name in MEDIANS + IDLE] == [None] * 10
+
+
+def test_medians_of_a_hand_made_window(monkeypatch):
+    steps = [step_record(0.01 * i, 0.01 * i + 0.009) for i in range(5)]
+    steps[2]["phases"].insert(2, ["infer.prefill", steps[2]["start"] + 1e-3,
+                                  steps[2]["start"] + 3e-3])
+    steps[2]["phases"].insert(3, ["infer.prefill_chunk",
+                                  steps[2]["start"] + 3e-3,
+                                  steps[2]["start"] + 4e-3])
+    trace = tr.Trace(device={}, host={})
+    data = run_data(trace, Engine(steps), monkeypatch, offsets=())
+    assert read("sched_ms_p50", data) == pytest.approx(0.09)
+    assert read("decode_launch_ms_p50", data) == pytest.approx(0.28 * 9)
+    assert read("decode_wait_ms_p50", data) == pytest.approx(0.60 * 9)
+    assert read("decode_sample_ms_p50", data) == pytest.approx(0.099 * 9)
+    assert read("prefill_ms_p50", data) == pytest.approx(3.0)  # one step
+    assert read("step_gap_ms_p50", data) == pytest.approx(1.0)
+    assert [read(name, data) for name in IDLE] == [None] * 4
+    # Only steps that ended inside the window count.
+    data.window = (steps[1]["end"], steps[3]["end"])
+    assert len(steplog.window_steps(data)) == 2

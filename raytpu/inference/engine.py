@@ -209,7 +209,8 @@ class InferenceEngine:
         self._prefill_compiles: Dict[int, int] = {}
         self._chunk_compiles: Dict[str, int] = {}
         self._decode_compiles: Dict[str, int] = {}
-        self._decode_batch_hist: List[int] = []
+        # One record per step(): its phases' stamps and what it ran.
+        self.recorder = tracing.StepRecorder()
         self._prefill_tokens = 0
         self._decode_tokens = 0
         self._arrival_ts: Dict[str, float] = {}
@@ -340,41 +341,62 @@ class InferenceEngine:
     def step(self) -> List[StepOutput]:
         """One scheduler iteration: run every admitted prefill, then one
         padded decode step over all running sequences; sample on host;
-        retire finished sequences (freeing their pages)."""
+        retire finished sequences (freeing their pages). Leaves one
+        record in ``self.recorder`` (see :meth:`step_log`)."""
         out: List[StepOutput] = []
-        plan = self.scheduler.schedule()
-        # The mesh is thread-local state and any thread may step.
-        with (self._jax.set_mesh(self.mesh) if self.mesh is not None
-              else contextlib.nullcontext()):
-            t0 = time.perf_counter()
-            prefilled = 0
-            for seq in plan.prefills:
-                prefilled += self._run_prefill(seq, out)
-            t1 = time.perf_counter()
-            decoded = 0
-            if plan.decodes:
-                decoded = self._run_decode(plan.decodes, out)
-            t2 = time.perf_counter()
+        recorder = self.recorder
+        compiled = self._programs_traced()
+        preempted = self.scheduler.num_preemptions
+        with recorder.step("infer.step", {
+                "decodes": 0, "bucket": 0, "table_width": 0,
+                "live_pages": 0}) as st:
+            with recorder.phase("infer.schedule") as ph:
+                waiting = len(self.scheduler.waiting)
+                plan = self.scheduler.schedule()
+                ph.attrs["admitted"] = st.attrs["admitted"] = (
+                    waiting + len(plan.preempted)
+                    - len(self.scheduler.waiting))
+            # The mesh is thread-local state and any thread may step.
+            with (self._jax.set_mesh(self.mesh) if self.mesh is not None
+                  else contextlib.nullcontext()):
+                prefilled = 0
+                for seq in plan.prefills:
+                    prefilled += self._run_prefill(seq, out)
+                decoded = 0
+                if plan.decodes:
+                    decoded = self._run_decode(plan.decodes, out)
+            st.attrs["compiled"] = self._programs_traced() - compiled
+            st.attrs["preempted"] = \
+                self.scheduler.num_preemptions - preempted
 
-        # Throughput gauges reflect THIS step — a step that moved no
-        # tokens zeroes them, so autoscalers never read the last busy
-        # step's value as live pressure.
-        if prefilled:
-            self._prefill_tokens += prefilled
-            _prefill_tokens_total.inc(prefilled)
-            _prefill_tps_gauge.set(prefilled / max(t1 - t0, 1e-9))
-        else:
-            _prefill_tps_gauge.set(0.0)
-        if decoded:
-            self._decode_tokens += decoded
-            _decode_tokens_total.inc(decoded)
-            _decode_tps_gauge.set(decoded / max(t2 - t1, 1e-9))
-        else:
-            _decode_tps_gauge.set(0.0)
-        _running_gauge.set(len(self.scheduler.running))
-        _waiting_gauge.set(len(self.scheduler.waiting))
-        _kv_util_gauge.set(self.cache.utilization())
+            # Throughput gauges reflect THIS step — a step that moved no
+            # tokens zeroes them, so autoscalers never read the last busy
+            # step's value as live pressure.
+            record = recorder.open
+            if prefilled:
+                self._prefill_tokens += prefilled
+                _prefill_tokens_total.inc(prefilled)
+                _prefill_tps_gauge.set(prefilled / max(record.seconds(
+                    "infer.prefill", "infer.prefill_chunk"), 1e-9))
+            else:
+                _prefill_tps_gauge.set(0.0)
+            if decoded:
+                self._decode_tokens += decoded
+                _decode_tokens_total.inc(decoded)
+                _decode_tps_gauge.set(decoded / max(record.seconds(
+                    "infer.decode"), 1e-9))
+            else:
+                _decode_tps_gauge.set(0.0)
+            _running_gauge.set(len(self.scheduler.running))
+            _waiting_gauge.set(len(self.scheduler.waiting))
+            _kv_util_gauge.set(self.cache.utilization())
         return out
+
+    def _programs_traced(self) -> int:
+        """Programs traced (so compiled) so far, all three kinds."""
+        return (sum(self._prefill_compiles.values())
+                + sum(self._chunk_compiles.values())
+                + sum(self._decode_compiles.values()))
 
     def _run_prefill(self, seq: Sequence, out: List[StepOutput]) -> int:
         """Advance one sequence's prefill by (at most) one chunk.
@@ -396,10 +418,20 @@ class InferenceEngine:
                 task_events.RequestTransition.PREFILL_START,
                 deployment=seq.deployment, tenant=seq.tenant,
                 data={"prompt_tokens": len(seq.prompt), "cached": start})
-        if start == 0 and plen <= self.prefill_chunk:
-            n = self._prefill_full(seq, plen, out)
-        else:
-            n = self._prefill_one_chunk(seq, start, plen, out)
+        # The phase is the whole stall this prefill puts on the batch:
+        # inputs, the call, the logits on the host, the first token out.
+        whole = start == 0 and plen <= self.prefill_chunk
+        with self.recorder.phase(
+                "infer.prefill" if whole else "infer.prefill_chunk",
+                {"request_id": seq.request_id}) as ph:
+            arrived = self._arrival_ts.get(seq.request_id)
+            if arrived is not None:  # None: resumed after a preemption
+                ph.attrs["waited_s"] = ph.t0 - arrived
+            if whole:
+                n = self._prefill_full(seq, plen, out, ph.attrs)
+            else:
+                n = self._prefill_one_chunk(seq, start, plen, out, ph.attrs)
+        self.recorder.open.fields.setdefault("prefills", []).append(ph.attrs)
         if task_events.request_events_enabled() \
                 and seq.cached_len >= plen \
                 and seq.request_id in self._prefill_announced:
@@ -421,18 +453,16 @@ class InferenceEngine:
                 min(seq.cached_len, len(seq.prompt)))
 
     def _prefill_full(self, seq: Sequence, plen: int,
-                      out: List[StepOutput]) -> int:
+                      out: List[StepOutput], attrs: dict) -> int:
         bucket = _bucket_for(plen, self.prefill_buckets)
+        attrs.update(tokens=plen, bucket=bucket)
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, :plen] = seq.tokens[:plen]
         dests = self.cache.prefill_dests(seq.request_id, plen, bucket)
-        with tracing.span("infer.prefill", {
-                "request_id": seq.request_id, "len": plen,
-                "bucket": bucket}):
-            logits, ks, vs = self._prefill_fn(
-                self._params, self.cache.k, self.cache.v,
-                self._put(tokens), self._put(dests))
-            self.cache.k, self.cache.v = ks, vs
+        logits, ks, vs = self._prefill_fn(
+            self._params, self.cache.k, self.cache.v,
+            self._put(tokens), self._put(dests))
+        self.cache.k, self.cache.v = ks, vs
         seq.cached_len = plen
         self._register_prefix(seq)
         if not seq.generated:
@@ -445,9 +475,10 @@ class InferenceEngine:
         return plen
 
     def _prefill_one_chunk(self, seq: Sequence, start: int, plen: int,
-                           out: List[StepOutput]) -> int:
+                           out: List[StepOutput], attrs: dict) -> int:
         take = min(self.prefill_chunk, plen - start)
         bucket = _bucket_for(take, self.chunk_buckets)
+        attrs.update(tokens=take, bucket=bucket, start=start)
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, :take] = seq.tokens[start:start + take]
         positions = np.zeros(bucket, dtype=np.int32)
@@ -460,14 +491,11 @@ class InferenceEngine:
         tables = self.cache.table_array([seq.request_id], p_used)
         if self.paged_attn_impl == "reference":
             self._pages_gathered += p_used
-        with tracing.span("infer.prefill_chunk", {
-                "request_id": seq.request_id, "start": start,
-                "take": take, "bucket": bucket}):
-            logits, ks, vs = self._chunk_fn(
-                self._params, self.cache.k, self.cache.v,
-                self._put(tokens), self._put(positions),
-                self._put(dests), self._put(tables))
-            self.cache.k, self.cache.v = ks, vs
+        logits, ks, vs = self._chunk_fn(
+            self._params, self.cache.k, self.cache.v,
+            self._put(tokens), self._put(positions),
+            self._put(dests), self._put(tables))
+        self.cache.k, self.cache.v = ks, vs
         seq.cached_len = start + take
         self._register_prefix(seq)
         if seq.cached_len >= plen and not seq.generated:
@@ -480,57 +508,70 @@ class InferenceEngine:
 
     def _run_decode(self, seqs: List[Sequence],
                     out: List[StepOutput]) -> int:
-        b = len(seqs)
-        bucket = _bucket_for(b, self.decode_buckets)
-        # Trim the block tables to the batch's actual max page count
-        # (bucketed): the reference gather then reads O(batch max
-        # context), not O(longest-ever sequence).
-        P = _bucket_for(max(self.cache.num_seq_pages(s.request_id)
-                            for s in seqs), self.page_buckets)
-        tokens = np.zeros(bucket, dtype=np.int32)
-        positions = np.zeros(bucket, dtype=np.int32)
-        dests = np.zeros(bucket, dtype=np.int32)  # page-0 slot 0 = scratch
-        context_lens = np.ones(bucket, dtype=np.int32)
-        for i, seq in enumerate(seqs):
-            pos = seq.cached_len
-            tokens[i] = seq.tokens[-1]
-            positions[i] = pos
-            dests[i] = self.cache.slot(seq.request_id, pos)
-            context_lens[i] = pos + 1
-        tables = self.cache.table_array(
-            [s.request_id for s in seqs], P, batch=bucket)
-        if self.paged_attn_impl == "reference":
-            self._pages_gathered += bucket * P
-        t_dec = time.perf_counter()
-        with tracing.span("infer.decode", {"batch": b, "bucket": bucket}):
-            logits, ks, vs = self._decode_fn(
-                self._params, self.cache.k, self.cache.v,
-                self._put(tokens), self._put(positions),
-                self._put(dests), self._put(tables),
-                self._put(context_lens))
-            self.cache.k, self.cache.v = ks, vs
-        logits_np = np.asarray(logits)  # host sync: dt covers the real step
-        if profiling_enabled():
-            prof = step_profiler("infer")
-            # FLOPs from XLA's own cost model, computed once per
-            # (batch bucket x table width) program — lower() reuses the
-            # jit cache, so this never triggers a second compile.
-            flops = prof.ensure_flops(
-                ("decode", bucket, P),
-                lambda: cost_analysis_flops(
-                    self._decode_fn, self._params, self.cache.k,
-                    self.cache.v, self._put(tokens),
-                    self._put(positions), self._put(dests),
-                    self._put(tables), self._put(context_lens)))
-            prof.observe_step(time.perf_counter() - t_dec, flops=flops)
-            self._hbm_tick += 1
-            if self._hbm_tick % 32 == 1:
-                prof.observe_hbm()
-        for i, seq in enumerate(seqs):
-            seq.cached_len += 1
-            token = sample_token(logits_np[i], seq.sampling, seq.rng)
-            self._emit(seq, token, out)
-        self._decode_batch_hist.append(b)
+        recorder = self.recorder
+        with recorder.phase("infer.decode") as dec:
+            with recorder.phase("infer.decode.launch") as launch:
+                b = len(seqs)
+                bucket = _bucket_for(b, self.decode_buckets)
+                # Trim the block tables to the batch's actual max page
+                # count (bucketed): the reference gather then reads
+                # O(batch max context), not O(longest-ever sequence).
+                P = _bucket_for(max(self.cache.num_seq_pages(s.request_id)
+                                    for s in seqs), self.page_buckets)
+                tokens = np.zeros(bucket, dtype=np.int32)
+                positions = np.zeros(bucket, dtype=np.int32)
+                # page-0 slot 0 = scratch
+                dests = np.zeros(bucket, dtype=np.int32)
+                context_lens = np.ones(bucket, dtype=np.int32)
+                live_pages = 0  # what the paged kernel must read
+                for i, seq in enumerate(seqs):
+                    pos = seq.cached_len
+                    tokens[i] = seq.tokens[-1]
+                    positions[i] = pos
+                    dests[i] = self.cache.slot(seq.request_id, pos)
+                    context_lens[i] = pos + 1
+                    live_pages += self.cache.pages_for(pos + 1)
+                tables = self.cache.table_array(
+                    [s.request_id for s in seqs], P, batch=bucket)
+                if self.paged_attn_impl == "reference":
+                    self._pages_gathered += bucket * P
+                dec.attrs.update(batch=b, bucket=bucket)
+                recorder.open.fields.update(
+                    decodes=b, bucket=bucket, table_width=P,
+                    live_pages=live_pages)
+                logits, ks, vs = self._decode_fn(
+                    self._params, self.cache.k, self.cache.v,
+                    self._put(tokens), self._put(positions),
+                    self._put(dests), self._put(tables),
+                    self._put(context_lens))
+                self.cache.k, self.cache.v = ks, vs
+            with recorder.phase("infer.decode.wait") as wait:
+                # The host blocked on the device and on the copy back.
+                logits_np = np.asarray(logits)
+                wait.attrs["bytes"] = logits_np.nbytes
+            with recorder.phase("infer.decode.sample"):
+                for i, seq in enumerate(seqs):
+                    seq.cached_len += 1
+                    token = sample_token(logits_np[i], seq.sampling,
+                                         seq.rng)
+                    self._emit(seq, token, out)
+            if profiling_enabled():
+                prof = step_profiler("infer")
+                # FLOPs from XLA's own cost model, computed once per
+                # (batch bucket x table width) program — lower() reuses
+                # the jit cache, so this never triggers a second compile.
+                flops = prof.ensure_flops(
+                    ("decode", bucket, P),
+                    lambda: cost_analysis_flops(
+                        self._decode_fn, self._params, self.cache.k,
+                        self.cache.v, self._put(tokens),
+                        self._put(positions), self._put(dests),
+                        self._put(tables), self._put(context_lens)))
+                # Launch to logits on the host: the real step.
+                prof.observe_step(wait.t1 - launch.t0, flops=flops)
+                self._hbm_tick += 1
+                if self._hbm_tick % 32 == 1:
+                    prof.observe_hbm()
         return b
 
     def _emit(self, seq: Sequence, token: int,
@@ -604,6 +645,25 @@ class InferenceEngine:
             "ttft_p95_s": float(self.ttft_quantile(0.95)),
         }
 
+    def step_log(self, since: float = 0.0) -> dict:
+        """The steps that ended after ``since`` (seconds on the clock of
+        the records' stamps, the process's monotonic performance
+        counter), oldest first, as plain dicts under ``"steps"``:
+        ``start``, ``end``, ``phases`` (``[name, t0, t1]`` in order of
+        their start: ``infer.schedule``, ``infer.prefill`` or
+        ``infer.prefill_chunk`` per prefill, ``infer.decode`` and inside
+        it ``.launch``, ``.wait``, ``.sample``, plus what the stepping
+        loop put around the step), and what the step ran: ``decodes``,
+        ``bucket``, ``table_width``, ``live_pages`` (pages its decode
+        had to read), ``admitted``, ``compiled`` (programs traced in
+        it), ``preempted``, ``prefills`` (``request_id``, ``tokens``,
+        ``bucket``, ``waited_s`` each) and ``error`` if it raised.
+        ``"oldest_start"`` is the start of the oldest step still held,
+        so a reader can tell a truncated log from a quiet engine. Call
+        it from the thread that steps, or under the lock that
+        serialises ``step()``."""
+        return self.recorder.log(since)
+
     def stats(self) -> dict:
         # Bucket keys as strings: the dict crosses the wire from serve
         # replicas and msgpack (strict_map_key) rejects int map keys.
@@ -614,7 +674,8 @@ class InferenceEngine:
                                        in self._chunk_compiles.items()},
             "decode_compiles": {str(k): v for k, v
                                 in self._decode_compiles.items()},
-            "decode_batch_hist": list(self._decode_batch_hist),
+            # Of the steps the recorder's ring still holds.
+            "decode_batch_hist": self.recorder.values("decodes"),
             # Block-table columns handed to the reference gather (each
             # model layer materializes page_size tokens per column;
             # 0 on the kernel path).

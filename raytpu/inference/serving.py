@@ -36,6 +36,7 @@ replica selection. See :mod:`raytpu.inference.disagg`.
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import uuid
@@ -48,6 +49,11 @@ from raytpu.inference.engine import InferenceEngine
 from raytpu.inference.sampling import SamplingParams
 from raytpu.serve.deployment import deployment
 from raytpu.util import serve_slo, task_events
+
+logger = logging.getLogger(__name__)
+
+# Newest step records the loop publishes for ``step_log``.
+_STEP_TAIL = 64
 
 
 class _FifoLock:
@@ -85,6 +91,10 @@ class _FifoLock:
                 self._waiters.popleft().release()  # stays held: handed over
             else:
                 self._held = False
+
+    def waiting(self) -> int:
+        """Threads queued for the lock right now (unlocked read)."""
+        return len(self._waiters)
 
     __enter__ = acquire
 
@@ -190,7 +200,8 @@ class LLMDeployment:
         # One condition serializes engine mutation (add/abort/step) and
         # carries wakeups both ways: producers signal "new work" to the
         # loop, the loop signals "new tokens" to consumers.
-        self._cv = threading.Condition(_FifoLock())
+        self._lock = _FifoLock()
+        self._cv = threading.Condition(self._lock)
         self._buffers: Dict[str, deque] = {}
         self._finished: Dict[str, str] = {}
         # O(1) request-liveness: ids currently registered with the
@@ -199,6 +210,9 @@ class LLMDeployment:
         self._live: set = set()
         self._req_info: Dict[str, dict] = {}
         self._closed = False
+        # What killed the stepping loop, if anything did: every stream,
+        # live or new, then ends by raising it.
+        self._error: Optional[BaseException] = None
         # Lock-free pressure snapshot: the loop REPLACES the dict, so
         # readers never see a half-written one (GIL-atomic store).
         self._pressure = self._engine.pressure()
@@ -207,6 +221,8 @@ class LLMDeployment:
         # the engine lock, which a step holds for as long as a compile.
         self._prefix_digests: list = []
         self._prefix_version = -1
+        # And the newest step records, for ``step_log``.
+        self._step_tail: list = []
         self._step_thread = threading.Thread(
             target=self._step_loop, name="llm-step-loop", daemon=True)
         self._step_thread.start()
@@ -216,9 +232,17 @@ class LLMDeployment:
     def _step_loop(self) -> None:
         """Pump the engine while any request is unfinished; park on the
         condition when idle. Runs on a daemon thread for the replica's
-        whole life — consumers never step the engine themselves."""
+        whole life — consumers never step the engine themselves. Its
+        own two phases, the wait for the lock before a step and the
+        publishing after it, go into that step's record."""
+        recorder = self._engine.recorder
         while True:
-            with self._cv:
+            # Asked for the lock -> holding it: the FIFO hand-over
+            # behind the consumers the last step woke.
+            with recorder.phase("serve.llm.lock_wait",
+                                {"waiters": self._lock.waiting()}):
+                self._cv.acquire()
+            try:
                 while not self._closed and not self._engine.has_unfinished():
                     self._engine.note_idle()
                     self._pressure = self._engine.pressure()
@@ -226,19 +250,34 @@ class LLMDeployment:
                     self._cv.wait(timeout=0.5)
                 if self._closed:
                     return
-                outs = self._engine.step()
-                for out in outs:
-                    buf = self._buffers.get(out.request_id)
-                    if buf is not None:
-                        buf.append(out.token_id)
-                    if out.finished:
-                        self._finished[out.request_id] = out.finish_reason
-                self._pressure = self._engine.pressure()
-                self._publish_prefix_digests()
-                if outs:
+                try:
+                    outs = self._engine.step()
+                except Exception as e:
+                    # The step's record and span carry the error; a dead
+                    # loop must not leave its streams waiting for ever.
+                    logger.exception("engine step failed; ending %d "
+                                     "stream(s)", len(self._live))
+                    self._error = e
                     self._cv.notify_all()
-            # The lock is dropped between iterations so request threads
-            # can drain buffers / add / abort while the engine is busy.
+                    return
+                with recorder.phase("serve.llm.publish", {
+                        "tokens": len(outs)}, after=True):
+                    for out in outs:
+                        buf = self._buffers.get(out.request_id)
+                        if buf is not None:
+                            buf.append(out.token_id)
+                        if out.finished:
+                            self._finished[out.request_id] = \
+                                out.finish_reason
+                    self._pressure = self._engine.pressure()
+                    self._publish_prefix_digests()
+                    if outs:
+                        self._cv.notify_all()
+            finally:
+                self._step_tail = recorder.tail(_STEP_TAIL)
+                # Dropped between iterations so request threads can
+                # drain buffers / add / abort while the engine is busy.
+                self._cv.release()
 
     def _publish_prefix_digests(self) -> None:
         """Refresh the digest snapshot if the prefix cache's index
@@ -287,6 +326,7 @@ class LLMDeployment:
                                     deployment=deployment_name,
                                     tenant=tenant)
         with self._cv:
+            self._raise_if_dead()
             seq = self._engine.add_request(request_id, prompt, sampling)
             seq.deployment = deployment_name
             seq.tenant = tenant
@@ -318,6 +358,7 @@ class LLMDeployment:
                     return None
                 if buf:
                     return buf.popleft()
+                self._raise_if_dead()
                 if request_id in self._finished or self._closed:
                     return None
                 if not self._engine_knows(request_id):
@@ -327,6 +368,12 @@ class LLMDeployment:
                 # Timed wait guards against a lost wakeup if the loop
                 # notified between our buffer check and the wait.
                 self._cv.wait(timeout=1.0)
+
+    def _raise_if_dead(self) -> None:
+        if self._error is not None:
+            raise RuntimeError(
+                f"the engine's step loop died: {self._error!r}"
+            ) from self._error
 
     def _engine_knows(self, request_id: str) -> bool:
         # O(1) live-set membership — the consumer wakeup path checks
@@ -442,6 +489,15 @@ class LLMDeployment:
         lock — the controller polls this through ``get_metrics`` even
         while a step is in flight."""
         return dict(self._pressure)
+
+    def step_log(self, last: int = _STEP_TAIL) -> list:
+        """The newest ``last`` engine steps (at most 64), oldest first,
+        as :meth:`InferenceEngine.step_log` gives them, each with the
+        loop's ``serve.llm.lock_wait`` and ``serve.llm.publish`` among
+        its phases: the answer to "why was that token late". Reads the
+        stepping loop's snapshot and takes no lock."""
+        tail = self._step_tail
+        return [r.as_dict() for r in tail[-last:]] if last > 0 else []
 
     def stats(self) -> dict:
         """Engine statistics, plus which process this replica is and

@@ -26,6 +26,14 @@ in TensorBoard/Perfetto include per-op HBM/MXU utilization), host-side is
 the task-event timeline the backend already buffers. Both are exposed
 here: ``profile()`` wraps a region with a jax profiler trace; ``timeline``
 dumps chrome-trace JSON of task events.
+
+Step records (:class:`StepRecorder`): the inference engine keeps one
+record per step in a bounded ring of its own, never shipped. A phase of
+a step is stamped with ``time.perf_counter()`` into the record, entered
+as a ``jax.profiler.TraceAnnotation`` (so a profiler session shows it on
+the device trace's clock) and opened as a :func:`span` of the same name.
+Ring spans carry that same monotonic clock as ``t0`` beside the wall
+clock ``start``, so the three can be laid on one axis.
 """
 
 from __future__ import annotations
@@ -33,9 +41,11 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import itertools
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -239,6 +249,7 @@ class _Span:
                     "span_id": ctx.span_id,
                     "parent_span_id": ctx.parent_span_id,
                     "start": self._start,
+                    "t0": self._t0,  # perf_counter: the step records' clock
                     "duration_s": dur,
                     "pid": os.getpid(),
                     "tid": threading.get_native_id(),
@@ -261,6 +272,162 @@ def span(name: str, attributes: Optional[Dict[str, Any]] = None):
     if not _enabled:
         return _NOOP_SPAN
     return _Span(name, attributes)
+
+
+# -- step records -------------------------------------------------------------
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation`` if this process has imported jax,
+    else None. Never imports it: a driver must stay off JAX (a process
+    that touches it takes the chip)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        jax = sys.modules.get("jax")
+        _trace_annotation = getattr(getattr(jax, "profiler", None),
+                                    "TraceAnnotation", None)
+    return _trace_annotation
+
+
+class StepRecord:
+    """One step: ``start``/``end`` on ``time.perf_counter()``, its phases
+    as ``[name, t0, t1]`` in order of their start (a phase before the
+    phases inside it), and what the step's sites wrote into ``fields``."""
+
+    __slots__ = ("start", "end", "phases", "fields")
+
+    def __init__(self, fields: Dict[str, Any]):
+        self.start = self.end = 0.0
+        self.phases: List[list] = []
+        self.fields = fields
+
+    def seconds(self, *names: str) -> float:
+        """Total duration of the phases called one of ``names``."""
+        return sum(t1 - t0 for name, t0, t1 in self.phases if name in names)
+
+    def as_dict(self) -> Dict[str, Any]:
+        return dict(self.fields, start=self.start, end=self.end,
+                    phases=[list(p) for p in self.phases])
+
+
+class _Phase:
+    """An open phase (or, with ``opens``, the step itself). Entering
+    stamps the clock last and leaving stamps it first, so the stamps are
+    the innermost of the three records of the phase."""
+
+    __slots__ = ("name", "attrs", "t0", "t1", "_recorder", "_opens",
+                 "_after", "_entry", "_annotation", "_span")
+
+    def __init__(self, recorder: "StepRecorder", name: str,
+                 attrs: Optional[Dict[str, Any]], opens: bool, after: bool):
+        self.name = name
+        self.attrs: Dict[str, Any] = {} if attrs is None else attrs
+        self.t0 = self.t1 = 0.0
+        self._recorder = recorder
+        self._opens = opens
+        self._after = after
+
+    def __enter__(self) -> "_Phase":
+        self._span = None
+        if _enabled:
+            self._span = _Span(self.name)
+            self._span.attrs = self.attrs  # one dict: sites write it once
+            self._span.__enter__()
+        annotation = _trace_annotation or _annotation_type()
+        self._annotation = None
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
+        recorder = self._recorder
+        if self._opens:
+            record = recorder.open = StepRecord(self.attrs)
+            record.phases.extend(recorder._early)
+            recorder._early.clear()
+            self._entry = record
+            self.t0 = record.start = time.perf_counter()
+        else:
+            self._entry = entry = [self.name, 0.0, 0.0]
+            record = recorder.open
+            if record is None and self._after and recorder._ring:
+                record = recorder._ring[-1]
+            (recorder._early if record is None
+             else record.phases).append(entry)
+            self.t0 = entry[1] = entry[2] = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.t1 = time.perf_counter()
+        if self._opens:
+            self._entry.end = self.t1
+            if ev is not None:
+                self.attrs["error"] = repr(ev)
+            self._recorder.open = None
+            self._recorder._ring.append(self._entry)
+        else:
+            self._entry[2] = self.t1
+        if self._annotation is not None:
+            self._annotation.__exit__(et, ev, tb)
+        if self._span is not None:
+            self._span.__exit__(et, ev, tb)
+        return False
+
+
+class StepRecorder:
+    """A bounded ring of :class:`StepRecord`, local to its owner (the
+    inference engine) and read in-process: nothing drains it over RPC.
+    One thread at a time steps, so one step at most is open, and a
+    closed record is written only by the phases that follow its step
+    (``after=True``). ``maxlen`` 4096 holds a 40 s window at a 10 ms
+    step."""
+
+    def __init__(self, maxlen: int = 4096):
+        self._ring: "deque[StepRecord]" = deque(maxlen=maxlen)
+        self.open: Optional[StepRecord] = None
+        # Phases closed while no step was open, for the next to adopt
+        # (the wait for the lock that precedes it).
+        self._early: "deque[list]" = deque(maxlen=16)
+
+    def step(self, name: str,
+             fields: Optional[Dict[str, Any]] = None) -> _Phase:
+        """Open the step: ``with recorder.step("infer.step") as st``.
+        ``st.attrs`` is the record's ``fields`` and the span's
+        attributes, one dict."""
+        return _Phase(self, name, fields, True, False)
+
+    def phase(self, name: str, attrs: Optional[Dict[str, Any]] = None,
+              after: bool = False) -> _Phase:
+        """Open a phase of the step in flight. With no step open it
+        belongs to the next one, or with ``after`` to the last."""
+        return _Phase(self, name, attrs, False, after)
+
+    def __len__(self) -> int:
+        return len(self._ring)
+
+    def values(self, field: str) -> list:
+        """The truthy values of ``field`` over the ring, oldest first."""
+        return [v for r in self._ring if (v := r.fields.get(field))]
+
+    def tail(self, n: int) -> List[StepRecord]:
+        """The newest ``n`` closed records, oldest first."""
+        out = list(itertools.islice(reversed(self._ring), n))
+        out.reverse()
+        return out
+
+    def log(self, since: float = 0.0) -> Dict[str, Any]:
+        """The records that ended after ``since`` (``perf_counter``
+        seconds), oldest first, as plain dicts; and the start of the
+        oldest record the ring still holds (None when empty), so that a
+        reader can tell truncation from silence."""
+        steps = []
+        for record in reversed(self._ring):
+            if record.end <= since:
+                break
+            steps.append(record.as_dict())
+        steps.reverse()
+        return {"oldest_start": self._ring[0].start if self._ring else None,
+                "steps": steps}
 
 
 def traced(name: Optional[str] = None) -> Callable:
@@ -316,13 +483,23 @@ def _span_event(s: dict, pid: Optional[int] = None) -> dict:
 
 @contextlib.contextmanager
 def profile(logdir: str, *, host_tracer_level: int = 2):
-    """XLA device profiling for the enclosed region. Produces a trace
-    viewable in TensorBoard's profiler / Perfetto (per-op timing, HBM
-    pressure, MXU utilization — the TPU analogue of the reference's
-    nsight runtime-env plugin)."""
+    """XLA device profiling for the enclosed region: the operator's entry
+    to ``jax.profiler``. Produces a trace viewable in TensorBoard's
+    profiler / Perfetto (per-op timing, HBM pressure, MXU utilization —
+    the TPU analogue of the reference's nsight runtime-env plugin). The
+    host's lines hold what entered a ``TraceAnnotation`` in the region:
+    every phase of a :class:`StepRecorder` (``infer.*``, ``serve.llm.*``)
+    lies beside the device's operations, on their clock.
+    ``host_tracer_level`` is the profiler's (1: annotations only, 2: the
+    runtime's own events too); Python's call tracer stays off, it would
+    bury the phases under every function call."""
     import jax
 
-    jax.profiler.start_trace(logdir, create_perfetto_trace=False)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = int(host_tracer_level)
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, create_perfetto_trace=False,
+                             profiler_options=options)
     try:
         yield
     finally:

@@ -332,6 +332,214 @@ class TestEngineLlama:
         assert engine_mod._kv_util_gauge.value == 0.0
 
 
+class TestStepLog:
+    """One record per step, its phases live spans (ISSUE 24)."""
+
+    def make_engine(self, params, **kw):
+        kw.setdefault("page_size", 8)
+        kw.setdefault("max_num_seqs", 4)
+        kw.setdefault("max_model_len", 64)
+        return InferenceEngine(LCFG, params, **kw)
+
+    @staticmethod
+    def phases(step, name):
+        return [p for p in step["phases"] if p[0] == name]
+
+    def test_phases_are_ordered_nest_in_the_step_and_tile_the_decode(
+            self, llama_model):
+        _, params = llama_model
+        eng = self.make_engine(params)
+        eng.generate([[1, 2, 3], [4, 5, 6, 7]],
+                     SamplingParams(max_new_tokens=4))
+        eng.generate([[1, 2, 3], [4, 5, 6, 7]],
+                     SamplingParams(max_new_tokens=4))  # warm: no compile
+        steps = eng.step_log()["steps"]
+        first = steps[0]
+        assert [p[0] for p in first["phases"]] == [
+            "infer.schedule", "infer.prefill", "infer.prefill"]
+        assert first["admitted"] == 2 and first["decodes"] == 0
+        assert [(p["tokens"], p["bucket"]) for p in first["prefills"]] \
+            == [(3, 16), (4, 16)]
+        decoded = [s for s in steps if s["decodes"]]
+        assert decoded and all(s["compiled"] == 0 for s in decoded[-3:])
+        for step in decoded[-3:]:
+            assert [p[0] for p in step["phases"]] == [
+                "infer.schedule", "infer.decode", "infer.decode.launch",
+                "infer.decode.wait", "infer.decode.sample"]
+            last = step["start"]
+            for name, t0, t1 in step["phases"]:
+                assert step["start"] <= t0 <= t1 <= step["end"], name
+                assert t0 >= last, name  # in order of their start
+                last = t0
+            (dec,) = self.phases(step, "infer.decode")
+            parts = [self.phases(step, f"infer.decode.{k}")[0]
+                     for k in ("launch", "wait", "sample")]
+            assert dec[1] <= parts[0][1] and parts[-1][2] <= dec[2]
+            for a, b in zip(parts, parts[1:]):
+                assert a[2] <= b[1]
+            covered = sum(t1 - t0 for _, t0, t1 in parts)
+            assert 0 <= (dec[2] - dec[1]) - covered < 1e-3
+            assert step["decodes"] == 2 and step["bucket"] == 2
+            assert step["table_width"] == 1 and step["live_pages"] == 2
+        for a, b in zip(steps, steps[1:]):
+            assert a["end"] <= b["start"]
+
+    def test_decode_span_contains_the_host_fetch(self, llama_model):
+        from raytpu.util import tracing
+
+        _, params = llama_model
+        eng = self.make_engine(params)
+        tracing.clear_spans()
+        tracing.enable_tracing()
+        try:
+            eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=3))
+            spans = tracing.get_spans()
+        finally:
+            tracing.disable_tracing()
+            tracing.clear_spans()
+        by_id = {s["span_id"]: s for s in spans}
+        waits = [s for s in spans if s["name"] == "infer.decode.wait"]
+        assert len(waits) == 2
+        for wait in waits:
+            dec = by_id[wait["parent_span_id"]]
+            assert dec["name"] == "infer.decode"
+            assert dec["duration_s"] >= wait["duration_s"] > 0
+            assert dec["t0"] <= wait["t0"]
+            assert wait["attributes"]["bytes"] == 4 * LCFG.vocab_size
+            assert by_id[dec["parent_span_id"]]["name"] == "infer.step"
+        step = [s for s in spans if s["name"] == "infer.step"][-1]
+        assert {"decodes", "bucket", "table_width", "live_pages", "compiled",
+                "preempted", "admitted"} <= set(step["attributes"])
+
+    def test_step_log_since_filters_and_reports_the_oldest_start(
+            self, llama_model):
+        _, params = llama_model
+        eng = self.make_engine(params)
+        assert eng.step_log() == {"oldest_start": None, "steps": []}
+        eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=5))
+        log = eng.step_log()
+        assert len(log["steps"]) == 5  # prefill, then four decodes
+        assert log["oldest_start"] == log["steps"][0]["start"]
+        cut = log["steps"][2]["end"]
+        later = eng.step_log(since=cut)
+        assert [s["start"] for s in later["steps"]] \
+            == [s["start"] for s in log["steps"][3:]]
+        assert later["oldest_start"] == log["oldest_start"]
+
+    def test_ring_and_decode_batch_hist_stay_bounded(self, llama_model):
+        from raytpu.util import tracing
+
+        _, params = llama_model
+        eng = self.make_engine(params)
+        assert eng.recorder._ring.maxlen == 4096
+        # The same ring at a size a test can overrun.
+        eng.recorder = tracing.StepRecorder(maxlen=16)
+        eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=27))
+        log = eng.step_log()
+        assert len(eng.recorder) == len(log["steps"]) == 16  # of 16 + 11
+        assert log["oldest_start"] == log["steps"][0]["start"]
+        assert eng.stats()["decode_batch_hist"] == [1] * 16
+        assert eng.stats()["decode_tokens"] == 26  # the totals are not cut
+
+    def test_compiled_counts_the_first_step_of_a_bucket_only(
+            self, llama_model):
+        _, params = llama_model
+        eng = self.make_engine(params)
+        eng.add_request("a", [1, 2, 3], SamplingParams(max_new_tokens=12))
+        for _ in range(3):
+            eng.step()
+        prefill, first, second = eng.step_log()["steps"]
+        assert prefill["compiled"] == 1 and first["compiled"] == 1
+        assert second["compiled"] == 0
+        # A second sequence joins: a new batch bucket, compiled once.
+        eng.add_request("b", [4, 5, 6], SamplingParams(max_new_tokens=4))
+        for _ in range(3):
+            eng.step()
+        admitted, joined, after = eng.step_log()["steps"][-3:]
+        # Its prefill's program is warm; it decodes from the next step.
+        assert admitted["decodes"] == 1 and admitted["compiled"] == 0
+        assert joined["decodes"] == 2 and joined["compiled"] == 1
+        assert after["decodes"] == 2 and after["compiled"] == 0
+        assert sum(s["compiled"] for s in eng.step_log()["steps"]) == sum(
+            sum(eng.stats()[k].values()) for k in (
+                "prefill_compiles", "chunk_prefill_compiles",
+                "decode_compiles"))
+
+    def test_waited_s_covers_the_time_behind_a_full_batch(self, llama_model):
+        import time
+
+        _, params = llama_model
+        eng = self.make_engine(params, max_num_seqs=1)
+        eng.add_request("first", [1, 2, 3], SamplingParams(max_new_tokens=4))
+        eng.add_request("behind", [4, 5, 6], SamplingParams(max_new_tokens=2))
+        queued = time.perf_counter()
+        time.sleep(0.05)
+        sat = None
+        while eng.has_unfinished():
+            eng.step()
+            step = eng.step_log()["steps"][-1]
+            for p in step.get("prefills", ()):
+                if p["request_id"] == "behind":
+                    sat = step["phases"][1][1] - queued  # its prefill's t0
+                    waited = p["waited_s"]
+        assert sat is not None and sat >= 0.05
+        assert waited >= sat
+        first = eng.step_log()["steps"][0]["prefills"][0]
+        assert first["request_id"] == "first"
+        assert 0 <= first["waited_s"] < waited
+
+    def test_preempted_is_counted_in_the_step_that_preempts(
+            self, llama_model):
+        _, params = llama_model
+        # 4 usable pages of 4 tokens: two sequences outgrow the pool.
+        eng = self.make_engine(params, page_size=4, num_pages=5,
+                               max_num_seqs=2, max_model_len=16)
+        eng.generate([[1, 2, 3], [4, 5, 6]],
+                     SamplingParams(max_new_tokens=8))
+        steps = eng.step_log()["steps"]
+        assert sum(s["preempted"] for s in steps) \
+            == eng.stats()["num_preemptions"] > 0
+        resumed = [p for s in steps for p in s.get("prefills", ())
+                   if "waited_s" not in p]
+        assert resumed  # a resume prefill has no arrival to wait from
+
+    def test_profile_region_shows_the_phases_on_the_profilers_clock(
+            self, llama_model, tmp_path):
+        """``tracing.profile()``: the xplane's host lines hold the
+        engine's phases as events, beside whatever the device ran."""
+        import glob
+
+        from raytpu.util import tracing
+
+        try:
+            from jax.profiler import ProfileData
+        except ImportError:
+            pytest.skip("this jax cannot read an xplane (no ProfileData)")
+        _, params = llama_model
+        eng = self.make_engine(params)
+        eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=2))  # warm
+        with tracing.profile(str(tmp_path), host_tracer_level=1):
+            eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=3))
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        names = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name.startswith("infer."):
+                            names.setdefault(ev.name, []).append(
+                                (ev.start_ns, ev.duration_ns))
+        assert len(names["infer.step"]) == 3
+        assert len(names["infer.decode.sample"]) == 2
+        assert {"infer.schedule", "infer.prefill", "infer.decode",
+                "infer.decode.launch", "infer.decode.wait"} <= set(names)
+        # On one clock: every sample event lies inside a step event.
+        for start, dur in names["infer.decode.sample"]:
+            assert any(s <= start and start + dur <= s + d
+                       for s, d in names["infer.step"])
+
+
 class TestEngineGPT2:
     def test_batched_greedy_matches_reference(self, gpt2_model):
         model, params = gpt2_model

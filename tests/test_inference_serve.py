@@ -251,6 +251,110 @@ class TestReplicaSteppingLoop:
             dep.shutdown()
 
 
+class TestSteppingLoopStepLog:
+    """The loop's own phases in the step's record, and its death made
+    visible (ISSUE 24)."""
+
+    def test_step_log_carries_the_loops_phases_and_takes_no_lock(self):
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            assert dep.step_log() == []
+            tokens = list(dep.generate([1, 2, 3], max_new_tokens=4))
+            assert len(tokens) == 4
+            deadline = time.monotonic() + 10
+            while len(dep.step_log()) < 4 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            # With the engine lock held by someone else, as a step
+            # holds it for as long as a compile.
+            got = []
+            with dep._cv:
+                reader = threading.Thread(
+                    target=lambda: got.append(dep.step_log()))
+                reader.start()
+                reader.join(timeout=5)
+                assert not reader.is_alive(), "step_log waited for the lock"
+            (steps,) = got
+        finally:
+            dep.shutdown()
+        assert len(steps) == 4  # one prefill, three decodes
+        assert dep.step_log(last=2) == steps[-2:]
+        assert dep.step_log(last=0) == []
+        for step in steps:
+            names = [p[0] for p in step["phases"]]
+            assert names[0] == "serve.llm.lock_wait"
+            assert names[1] == "infer.schedule"
+            assert names[-1] == "serve.llm.publish"
+            wait, publish = step["phases"][0], step["phases"][-1]
+            assert wait[1] <= wait[2] <= step["start"]
+            assert step["end"] <= publish[1] <= publish[2]
+        assert [s["decodes"] for s in steps] == [0, 1, 1, 1]
+        for a, b in zip(steps, steps[1:]):
+            # The gap between steps holds the publish and the next wait.
+            assert a["phases"][-1][2] <= b["start"]
+            assert a["end"] <= b["phases"][0][1]
+
+    def test_a_step_that_raises_ends_every_stream(self, monkeypatch):
+        """An exception out of ``engine.step()`` used to kill the loop
+        in silence and leave every stream waiting for ever (it cost
+        PR 23 44 chip-minutes)."""
+        from raytpu.inference.engine import InferenceEngine
+
+        step = InferenceEngine.step
+        calls = {"n": 0}
+
+        def step_that_dies(self):
+            calls["n"] += 1
+            if calls["n"] > 3:
+                calls["died"] = time.monotonic()
+                with self.recorder.step("infer.step", {"decodes": 0}):
+                    raise MemoryError("RESOURCE_EXHAUSTED: the program "
+                                      "does not load")
+            return step(self)
+
+        monkeypatch.setattr(InferenceEngine, "step", step_that_dies)
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        ended = {}
+
+        def consume(tag, prompt):
+            seen = []
+            try:
+                for tok in dep.generate(prompt, max_new_tokens=40):
+                    seen.append(tok)
+                ended[tag] = ("finished", seen, time.monotonic())
+            except RuntimeError as e:
+                ended[tag] = (e, seen, time.monotonic())
+
+        threads = [threading.Thread(target=consume, args=(t, p))
+                   for t, p in (("a", [1, 2, 3]), ("b", [4, 5, 6, 7]))]
+        try:
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=60)
+            assert not any(th.is_alive() for th in threads), \
+                "a stream is still waiting on a dead loop"
+            died_at = dep.step_log()[-1]
+            # The loop stopped; nothing waits, old or new.
+            dep._step_thread.join(timeout=2)
+            assert not dep._step_thread.is_alive()
+            t0 = time.monotonic()
+            with pytest.raises(RuntimeError, match="step loop died"):
+                list(dep.generate([9, 9], max_new_tokens=2))
+            assert time.monotonic() - t0 < 2.0
+        finally:
+            dep.shutdown()
+        assert calls["n"] == 4
+        for tag in ("a", "b"):
+            err, seen, at = ended[tag]
+            assert at - calls["died"] < 2.0
+            assert isinstance(err, RuntimeError), ended[tag]
+            assert "RESOURCE_EXHAUSTED" in str(err)
+            assert isinstance(err.__cause__, MemoryError)
+            assert 1 <= len(seen) < 40  # what was decoded was delivered
+        assert "RESOURCE_EXHAUSTED" in died_at["error"]
+        assert died_at["phases"][0][0] == "serve.llm.lock_wait"
+
+
 class TestEnginePressureAutoscaling:
     def test_engine_queue_scales_replicas_up_then_down(self, serve_instance):
         """Admission-queue depth inside a max_num_seqs=1 engine —
