@@ -96,6 +96,18 @@ class InferenceEngine:
     Drive it with :meth:`add_request` + :meth:`step` (one scheduler
     iteration per call — the serve replica's loop), or use
     :meth:`generate` to run a closed batch to completion.
+
+    The engine serves from a *working copy* of ``params``, made once at
+    construction by the family's ``serving_params`` (beside its forwards
+    in :mod:`raytpu.models`): the leaves the forwards use in
+    ``model_config.dtype`` (matmul kernels, biases, embeddings) are held
+    in it, the norms' leaves, which enter float32 arithmetic, as given.
+    The three programs take that tree, so a step holds no conversion of
+    a weight; a leaf already in the compute type is the caller's own
+    array. The tree given is not kept: float32 weights under bf16
+    compute cost half their bytes once the caller lets go of them, and
+    a caller that wants the original keeps it. ``stats()["param_bytes"]``
+    has the copy's bytes by dtype.
     """
 
     def __init__(self, model_config, params, *, page_size: int = 16,
@@ -113,7 +125,8 @@ class InferenceEngine:
 
         if isinstance(model_config, LlamaConfig):
             from raytpu.models.llama import (llama_decode, llama_prefill,
-                                             llama_prefill_chunk)
+                                             llama_prefill_chunk,
+                                             serving_params)
 
             self._prefill_fwd, self._decode_fwd = llama_prefill, llama_decode
             self._chunk_fwd = llama_prefill_chunk
@@ -121,7 +134,8 @@ class InferenceEngine:
             head_dim = model_config.head_dim
         elif isinstance(model_config, GPT2Config):
             from raytpu.models.gpt2 import (gpt2_decode, gpt2_prefill,
-                                            gpt2_prefill_chunk)
+                                            gpt2_prefill_chunk,
+                                            serving_params)
 
             self._prefill_fwd, self._decode_fwd = gpt2_prefill, gpt2_decode
             self._chunk_fwd = gpt2_prefill_chunk
@@ -131,7 +145,16 @@ class InferenceEngine:
             raise TypeError(f"unsupported model config: {model_config!r}")
 
         self._config = model_config
-        self._params = params
+        # The working copy, made once and before anything else takes
+        # memory: what the family's forwards cast to the compute type is
+        # in it already, so no step converts a weight. The tree given is
+        # not kept; a caller that wants it keeps it.
+        self._params = serving_params(model_config, params)
+        self._param_bytes: Dict[str, int] = {}
+        for leaf in jax.tree_util.tree_leaves(self._params):
+            name = str(leaf.dtype)
+            self._param_bytes[name] = (self._param_bytes.get(name, 0)
+                                       + leaf.size * leaf.dtype.itemsize)
         self.max_model_len = min(max_model_len or model_config.block_size,
                                  model_config.block_size)
         self.page_size = page_size
@@ -681,6 +704,9 @@ class InferenceEngine:
             # 0 on the kernel path).
             "gathered_pages": self._pages_gathered,
             "paged_attn_impl": self.paged_attn_impl,
+            # Bytes of the tree the programs take, by dtype, over all
+            # shards: all in the compute type but the norms' leaves.
+            "param_bytes": dict(self._param_bytes),
             "devices": sorted(f"{d.platform}:{d.id}"
                               for d in self.cache.k[0].devices()),
             "num_preemptions": self.scheduler.num_preemptions,

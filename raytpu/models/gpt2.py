@@ -124,14 +124,11 @@ class CausalSelfAttention(nn.Module):
         q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
         k_cache = k.reshape(b, t, h, d)[0]  # [T, H, D]
         v_cache = v.reshape(b, t, h, d)[0]
-        n_pages, page_size = k_pages.shape[0], k_pages.shape[1]
-        flat = (n_pages * page_size, h, d)
-        k_pages = k_pages.reshape(flat).at[dests].set(
-            k_cache.astype(k_pages.dtype)).reshape(k_pages.shape)
-        v_pages = v_pages.reshape(flat).at[dests].set(
-            v_cache.astype(v_pages.dtype)).reshape(v_pages.shape)
-        from raytpu.ops.paged_attention import paged_attention
+        from raytpu.ops.paged_attention import (paged_attention,
+                                                scatter_kv_slots)
 
+        k_pages = scatter_kv_slots(k_pages, dests, k_cache)
+        v_pages = scatter_kv_slots(v_pages, dests, v_cache)
         o = paged_attention(q.transpose(0, 2, 1, 3), k_pages, v_pages,
                             block_tables, positions[None, :],
                             force=c.paged_attn)
@@ -150,14 +147,11 @@ class CausalSelfAttention(nn.Module):
         qkv = self.c_attn(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(b, h, d)
-        n_pages, page_size = k_pages.shape[0], k_pages.shape[1]
-        flat = (n_pages * page_size, h, d)
-        k_pages = k_pages.reshape(flat).at[dests].set(
-            k.reshape(b, h, d).astype(k_pages.dtype)).reshape(k_pages.shape)
-        v_pages = v_pages.reshape(flat).at[dests].set(
-            v.reshape(b, h, d).astype(v_pages.dtype)).reshape(v_pages.shape)
-        from raytpu.ops.paged_attention import paged_attention
+        from raytpu.ops.paged_attention import (paged_attention,
+                                                scatter_kv_slots)
 
+        k_pages = scatter_kv_slots(k_pages, dests, k.reshape(b, h, d))
+        v_pages = scatter_kv_slots(v_pages, dests, v.reshape(b, h, d))
         # The token at position p sees slots 0..p = 0..context_lens-1.
         o = paged_attention(q[:, None], k_pages, v_pages, block_tables,
                             (context_lens - 1)[:, None],
@@ -329,6 +323,46 @@ def layer_params(params, i: int):
     if "h" in params:
         return jax.tree_util.tree_map(lambda p: p[i], params["h"])
     return params[f"h_{i}"]
+
+
+def cast_leaves(params, dtype, cast):
+    """``params`` with every leaf whose path ``cast`` accepts (it is given
+    the path's dict keys, outermost first) held in ``dtype``. A leaf
+    already in ``dtype`` is passed through as the same array, so a tree
+    that needs nothing costs nothing. The rest are converted one leaf at
+    a time where they live; a shape standing in for an array (a program
+    that is only compiled) has its dtype changed."""
+    dtype = jnp.dtype(dtype)
+
+    def one(path, leaf):
+        if leaf.dtype == dtype or not cast([k.key for k in path]):
+            return leaf
+        if isinstance(leaf, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(leaf.shape, dtype,
+                                        sharding=leaf.sharding)
+        return leaf.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
+# The modules whose leaves the forwards below use in ``config.dtype``:
+# the four ``nn.Dense`` (flax's ``promote_dtype`` casts kernel and bias)
+# and the two embeddings.
+_SERVED_IN_COMPUTE_DTYPE = ("c_attn", "c_proj", "c_fc", "wte", "wpe")
+
+
+def serving_params(config: GPT2Config, params):
+    """The working copy of ``params`` to serve from: every leaf that
+    :func:`gpt2_prefill`, :func:`gpt2_prefill_chunk` and
+    :func:`gpt2_decode` cast to ``config.dtype`` is in it already, so
+    their casts are no-ops and no step converts a weight; the values are
+    the ones they would have computed, so the logits are the same bits.
+    The layer norms' ``scale`` and ``bias`` stay as given:
+    ``nn.LayerNorm(dtype=...)`` normalises, scales and shifts in float32
+    and casts the result, so rounding them first would change it. Make
+    it once (``InferenceEngine`` does), not per call."""
+    return cast_leaves(params, config.dtype,
+                       lambda keys: keys[-2] in _SERVED_IN_COMPUTE_DTYPE)
 
 
 def _tied_logits(c: GPT2Config, params, x):

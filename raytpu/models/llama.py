@@ -23,6 +23,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from raytpu.models.gpt2 import cast_leaves
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -189,14 +191,11 @@ class LlamaAttention(nn.Module):
         k = apply_rope(k, cos, sin)
         k_cache = k.transpose(0, 2, 1, 3)[0]  # [T, KV, D]
         v_cache = v.transpose(0, 2, 1, 3)[0]
-        n_pages, page_size = k_pages.shape[0], k_pages.shape[1]
-        flat = (n_pages * page_size, kv, d)
-        k_pages = k_pages.reshape(flat).at[dests].set(
-            k_cache.astype(k_pages.dtype)).reshape(k_pages.shape)
-        v_pages = v_pages.reshape(flat).at[dests].set(
-            v_cache.astype(v_pages.dtype)).reshape(v_pages.shape)
-        from raytpu.ops.paged_attention import paged_attention
+        from raytpu.ops.paged_attention import (paged_attention,
+                                                scatter_kv_slots)
 
+        k_pages = scatter_kv_slots(k_pages, dests, k_cache)
+        v_pages = scatter_kv_slots(v_pages, dests, v_cache)
         # Each chunk token attends cached slots <= its absolute
         # position (gathered/paged slot l holds logical position l).
         o = paged_attention(q.transpose(0, 2, 1, 3), k_pages, v_pages,
@@ -230,14 +229,11 @@ class LlamaAttention(nn.Module):
         cos, sin = rope_tables(d, positions, c.rope_theta)
         q = apply_rope_single(q, cos, sin)
         k = apply_rope_single(k, cos, sin)
-        n_pages, page_size = k_pages.shape[0], k_pages.shape[1]
-        flat = (n_pages * page_size, kv, d)
-        k_pages = k_pages.reshape(flat).at[dests].set(
-            k.astype(k_pages.dtype)).reshape(k_pages.shape)
-        v_pages = v_pages.reshape(flat).at[dests].set(
-            v.astype(v_pages.dtype)).reshape(v_pages.shape)
-        from raytpu.ops.paged_attention import paged_attention
+        from raytpu.ops.paged_attention import (paged_attention,
+                                                scatter_kv_slots)
 
+        k_pages = scatter_kv_slots(k_pages, dests, k)
+        v_pages = scatter_kv_slots(v_pages, dests, v)
         # The token at position p sees slots 0..p = 0..context_lens-1.
         o = paged_attention(q[:, None], k_pages, v_pages, block_tables,
                             (context_lens - 1)[:, None],
@@ -365,6 +361,19 @@ def layer_params(params, i: int):
     if "layers" in params:
         return jax.tree_util.tree_map(lambda p: p[i], params["layers"])
     return params[f"layers_{i}"]
+
+
+def serving_params(config: LlamaConfig, params):
+    """The working copy of ``params`` to serve from: the leaves that
+    :func:`llama_prefill`, :func:`llama_prefill_chunk` and
+    :func:`llama_decode` cast to ``config.dtype`` (every ``nn.Dense``
+    kernel, ``lm_head`` among them, and ``embed_tokens``) are in it
+    already, so no step converts a weight and the logits are the same
+    bits. The norms' ``scale`` stays as given: :class:`RMSNorm`
+    multiplies by it in float32. Same contract as
+    :func:`raytpu.models.gpt2.serving_params`."""
+    return cast_leaves(params, config.dtype,
+                       lambda keys: keys[-1] in ("kernel", "embedding"))
 
 
 def _lm_logits(c: LlamaConfig, params, x):

@@ -135,6 +135,30 @@ def resolve_paged_impl(selector=None) -> str:
 
 
 # ---------------------------------------------------------------------------
+# Writing new tokens' K/V into a pool.
+# ---------------------------------------------------------------------------
+
+
+def scatter_kv_slots(pages: jax.Array, dests: jax.Array,
+                     rows: jax.Array) -> jax.Array:
+    """``pages`` ``[num_pages, page_size, kv_heads, head_dim]`` with
+    ``rows`` ``[N, kv_heads, head_dim]`` written at the flat slots
+    ``dests`` ``[N]`` (``page * page_size + offset``, as
+    ``PagedKVCache.slot`` gives them; padding rows name page 0).
+
+    Indexed by (page, offset) on the pool as it is, not through a
+    reshape to ``[num_pages * page_size, ...]`` and back: on the TPU a
+    pool lives in a layout in which that reshape is a copy of the whole
+    pool into a padded form, and a decode program that made it kept all
+    its layers' padded copies to its end (4.5 GB of scratch for GPT-2
+    XL's 1.9 GB of pools; PERF.md, PR 25).
+    """
+    page_size = pages.shape[1]
+    return pages.at[dests // page_size, dests % page_size].set(
+        rows.astype(pages.dtype))
+
+
+# ---------------------------------------------------------------------------
 # Sanctioned dense reference.
 # ---------------------------------------------------------------------------
 

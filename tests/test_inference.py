@@ -6,13 +6,17 @@ recompute, sampling invariance, and the jit-placement AST lint."""
 import ast
 import dataclasses
 import pathlib
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from raytpu.inference import (InferenceEngine, PagedKVCache, SamplingParams,
                               Scheduler, Sequence)
+from raytpu.models import gpt2 as gpt2_mod
+from raytpu.models import llama as llama_mod
 from raytpu.models.gpt2 import GPT2, GPT2Config
 from raytpu.models.gpt2 import init_params as gpt2_init
 from raytpu.models.llama import Llama, LlamaConfig
@@ -550,6 +554,191 @@ class TestEngineGPT2:
         assert outs[0] == reference_greedy(model, params, pa, 6)
         assert outs[1] == reference_greedy(model, params, pb, 6)
         assert max(eng.stats()["decode_batch_hist"]) >= 2
+
+
+# ---------------------------------------------------------------------------
+# The working copy: float32 parameters under bf16 compute are cast once,
+# when the engine is built, by the family's serving_params; the programs
+# take that tree and convert no weight.
+# ---------------------------------------------------------------------------
+
+BF16 = {
+    "gpt2": (dataclasses.replace(GPT2Config.tiny(), attn_impl="reference",
+                                 paged_attn="reference", remat=False),
+             GPT2, gpt2_init, gpt2_mod),
+    "llama": (dataclasses.replace(LlamaConfig.tiny(), attn_impl="reference",
+                                  paged_attn="reference", remat=False),
+              Llama, llama_init, llama_mod),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(BF16))
+def bf16_family(request):
+    """(module, bf16-compute config, float32 parameters) of a family."""
+    cfg, model_cls, init, mod = BF16[request.param]
+    assert cfg.dtype == jnp.bfloat16
+    params = init(model_cls(cfg), cfg, seed=0, batch=1)
+    assert {a.dtype for a in jax.tree_util.tree_leaves(params)} \
+        == {jnp.dtype(jnp.float32)}
+    return mod, cfg, params
+
+
+def _weight_shapes(params):
+    """Shapes of the matmul kernels and embeddings, stacked and as one
+    layer's slice. (A stacked bias has the shape of a stacked norm scale,
+    so shape cannot tell those two apart; their dtypes are checked by
+    name.)"""
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if path[-1].key in ("kernel", "embedding"):
+            shapes.add(leaf.shape)
+            if path[0].key in ("h", "layers"):  # stacked over the layers
+                shapes.add(leaf.shape[1:])
+    return shapes
+
+
+def _f32_to_bf16_converts(text):
+    """Shapes of every float32 -> bf16 ``convert`` of a lowered program."""
+    found = re.findall(
+        r"stablehlo\.convert [^\n]*\(tensor<([0-9x]+)xf32>\) -> "
+        r"tensor<\1xbf16>", text)
+    return {tuple(int(n) for n in dims.split("x")) for dims in found}
+
+
+class TestServingParams:
+    def _programs(self, mod, cfg):
+        """Each forward under jit with inputs like the engine's: a filled
+        pool of 5 pages of 8 and two 4-token rows at positions 9..12."""
+        prefix = "gpt2" if mod is gpt2_mod else "llama"
+        kv = getattr(cfg, "n_kv_head", cfg.n_head)
+        rng = np.random.default_rng(0)
+        pools = [[jnp.asarray(rng.standard_normal(
+            (5, 8, kv, cfg.n_embd // cfg.n_head)), cfg.dtype)
+            for _ in range(cfg.n_layer)] for _ in range(2)]
+        tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (1, 16)),
+                             jnp.int32)
+        positions = jnp.arange(9, 13, dtype=jnp.int32)
+        dests = 16 + positions  # page 2, after page 1's eight slots
+        table = jnp.asarray([[1, 2]], jnp.int32)
+        return {
+            "prefill": lambda p: getattr(mod, f"{prefix}_prefill")(
+                cfg, p, tokens),
+            "chunk": lambda p: getattr(mod, f"{prefix}_prefill_chunk")(
+                cfg, p, tokens[:, :4], positions, dests, table, *pools),
+            "decode": lambda p: getattr(mod, f"{prefix}_decode")(
+                cfg, p, tokens[0, :4], positions, dests,
+                jnp.tile(table, (4, 1)), positions + 1, *pools),
+        }
+
+    @pytest.mark.parametrize("program", ["prefill", "chunk", "decode"])
+    def test_working_copy_gives_the_same_bits(self, bf16_family, program):
+        mod, cfg, params = bf16_family
+        fwd = jax.jit(self._programs(mod, cfg)[program])
+        working = mod.serving_params(cfg, params)
+        by_dtype = {str(a.dtype) for a in jax.tree_util.tree_leaves(working)}
+        assert by_dtype == {"bfloat16", "float32"}
+        want = jax.tree_util.tree_leaves(fwd(params))
+        got = jax.tree_util.tree_leaves(fwd(working))
+        assert len(got) == len(want) == 1 + 2 * cfg.n_layer
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            # The same bits, not close ones: the operands are the same.
+            assert np.array_equal(np.asarray(g.astype(jnp.float32)),
+                                  np.asarray(w.astype(jnp.float32)))
+        assert np.isfinite(np.asarray(got[0])).all() and np.asarray(
+            got[0]).std() > 0
+
+    def test_norm_leaves_are_not_cast_and_the_rest_are(self, bf16_family):
+        mod, cfg, params = bf16_family
+        flat, _ = jax.tree_util.tree_flatten_with_path(
+            mod.serving_params(cfg, params))
+        for path, leaf in flat:
+            keys = [k.key for k in path]
+            is_norm = any("norm" in k or k.startswith("ln_") for k in keys)
+            assert leaf.dtype == (jnp.float32 if is_norm else jnp.bfloat16), \
+                keys
+        assert any(l.dtype == jnp.float32 for _, l in flat)
+
+    def test_a_tree_in_the_compute_type_is_passed_through(self, bf16_family):
+        mod, cfg, params = bf16_family
+        # float32 compute (every other test of this file), and a bf16
+        # tree under bf16 compute: the same arrays come back, no copy.
+        f32_cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+        working = mod.serving_params(cfg, params)
+        for c, tree in ((f32_cfg, params), (cfg, working)):
+            again = mod.serving_params(c, tree)
+            for a, b in zip(jax.tree_util.tree_leaves(again),
+                            jax.tree_util.tree_leaves(tree)):
+                assert a is b
+
+    def test_abstract_leaves_change_dtype_only(self, bf16_family):
+        # perfbench's AOT compile builds an engine on shapes alone.
+        mod, cfg, params = bf16_family
+        shapes = jax.eval_shape(lambda: params)
+        working = mod.serving_params(cfg, params)
+        for a, b in zip(
+                jax.tree_util.tree_leaves(mod.serving_params(cfg, shapes)),
+                jax.tree_util.tree_leaves(working)):
+            assert isinstance(a, jax.ShapeDtypeStruct)
+            assert (a.shape, a.dtype) == (b.shape, b.dtype)
+
+    def _lower(self, eng, params):
+        i32 = jnp.int32
+        b, t = 4, 16
+        prefill = eng._prefill_fn.lower(
+            params, eng.cache.k, eng.cache.v, jnp.zeros((1, t), i32),
+            jnp.zeros((t,), i32)).as_text()
+        decode = eng._decode_fn.lower(
+            params, eng.cache.k, eng.cache.v, jnp.zeros((b,), i32),
+            jnp.zeros((b,), i32), jnp.zeros((b,), i32),
+            jnp.zeros((b, 2), i32), jnp.ones((b,), i32)).as_text()
+        return prefill, decode
+
+    def test_engine_programs_take_bf16_weights_and_convert_none(
+            self, bf16_family):
+        mod, cfg, params = bf16_family
+        eng = InferenceEngine(cfg, params, page_size=8, max_num_seqs=4,
+                              max_model_len=64)
+        weights = _weight_shapes(params)
+        for text in self._lower(eng, eng._params):
+            assert not _f32_to_bf16_converts(text) & weights
+            # The program's parameters: no float32 one of a weight's shape.
+            main = text[text.index("@main("):]
+            main = main[:main.index(") -> ")]
+            f32_args = {tuple(int(n) for n in dims.split("x")) for dims in
+                        re.findall(r"tensor<([0-9x]+)xf32>", main)}
+            assert not f32_args & weights
+            assert "xbf16>" in main
+        # The same jitted functions on the tree as given do convert
+        # them, so the check above can fail.
+        for text in self._lower(eng, params):
+            assert _f32_to_bf16_converts(text) & weights
+        want: dict = {}
+        for leaf in jax.tree_util.tree_leaves(eng._params):
+            want[str(leaf.dtype)] = want.get(str(leaf.dtype), 0) + leaf.nbytes
+        got = eng.stats()["param_bytes"]
+        assert got == want and set(got) == {"bfloat16", "float32"}
+        total = sum(a.nbytes for a in jax.tree_util.tree_leaves(params))
+        assert got["float32"] < 0.01 * total
+        assert got["bfloat16"] == (total - got["float32"]) // 2
+        out = eng.generate([[5, 6, 7]], SamplingParams(max_new_tokens=3))
+        assert len(out[0]) == 3
+
+    def test_tp2_shards_the_working_copy(self, bf16_family):
+        mod, cfg, params = bf16_family
+        eng = InferenceEngine(cfg, params, page_size=8, max_num_seqs=2,
+                              max_model_len=32, tp=2)
+        split = 0
+        for leaf in jax.tree_util.tree_leaves(eng._params):
+            assert len(leaf.sharding.device_set) == 2
+            if "tp" in leaf.sharding.spec:
+                # What the tp rules split is a matmul weight: cast first.
+                assert leaf.dtype == jnp.bfloat16
+                split += 1
+        assert split >= 4
+        assert eng.stats()["param_bytes"] == InferenceEngine(
+            cfg, params, page_size=8, max_num_seqs=2,
+            max_model_len=32).stats()["param_bytes"]
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,7 @@ the reference-gather trim."""
 import dataclasses
 import warnings
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -24,6 +25,7 @@ from raytpu.ops.paged_attention import (
     paged_attention,
     paged_attention_reference,
     resolve_paged_impl,
+    scatter_kv_slots,
 )
 
 LCFG = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32,
@@ -123,6 +125,38 @@ class TestKernelNumerics:
                                       np.asarray(k[3]))
         np.testing.assert_array_equal(np.asarray(out[1, 4:]),
                                       np.asarray(k[2]))
+
+
+class TestScatterKVSlots:
+    @pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("n", [1, 5])
+    def test_equals_the_write_through_the_flat_view(self, n, pool_dtype):
+        """Slot ``page * page_size + offset`` is row ``offset`` of page
+        ``page``: the same pool as writing through ``[pages * size, ...]``,
+        rows cast to the pool's dtype, every other slot untouched."""
+        rng = np.random.default_rng(n)
+        pool = jnp.asarray(rng.standard_normal((7, 4, 2, 8)), pool_dtype)
+        rows = jnp.asarray(rng.standard_normal((n, 2, 8)), jnp.float32)
+        dests = jnp.asarray(rng.choice(np.arange(4, 28), n, replace=False),
+                            jnp.int32)
+        want = pool.reshape(28, 2, 8).at[dests].set(
+            rows.astype(pool_dtype)).reshape(pool.shape)
+        got = jax.jit(scatter_kv_slots)(pool, dests, rows)
+        assert got.dtype == pool.dtype and got.shape == pool.shape
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        for i, dest in enumerate(np.asarray(dests)):
+            np.testing.assert_array_equal(
+                np.asarray(got[dest // 4, dest % 4], np.float32),
+                np.asarray(rows[i].astype(pool_dtype), np.float32))
+
+    def test_padding_rows_land_in_the_scratch_page_only(self):
+        pool = jnp.ones((3, 4, 1, 2), jnp.float32)
+        rows = jnp.full((3, 1, 2), 7.0)
+        got = scatter_kv_slots(pool, jnp.asarray([0, 0, 9], jnp.int32), rows)
+        np.testing.assert_array_equal(np.asarray(got[1]), np.ones((4, 1, 2)))
+        assert float(got[2, 1, 0, 0]) == 7.0 and float(got[0, 0, 0, 0]) == 7.0
+        assert float(np.asarray(got).sum()) == 24 + 2 * 2 * 6.0
 
 
 class TestImplResolution:
