@@ -30,7 +30,8 @@ import collections
 import contextlib
 import dataclasses
 import time
-from typing import Dict, List, Optional, Sequence as SequenceT
+from typing import (Callable, Dict, List, Optional,
+                    Sequence as SequenceT)
 
 import numpy as np
 
@@ -124,6 +125,9 @@ class InferenceEngine:
         from raytpu.models.llama import LlamaConfig
 
         if isinstance(model_config, LlamaConfig):
+            # Mixtral's and OLMoE's configs are LlamaConfigs too: the same
+            # three walks, which for them also return the tokens each
+            # expert of each layer received.
             from raytpu.models.llama import (llama_decode, llama_prefill,
                                              llama_prefill_chunk,
                                              serving_params)
@@ -142,7 +146,18 @@ class InferenceEngine:
             kv_heads = model_config.n_head
             head_dim = model_config.n_embd // model_config.n_head
         else:
-            raise TypeError(f"unsupported model config: {model_config!r}")
+            raise TypeError(
+                f"unsupported model config: {model_config!r}; the engine "
+                f"serves LlamaConfig (MixtralConfig and OlmoeConfig among "
+                f"them) and GPT2Config")
+        # A routed-expert config: its programs return a fourth value.
+        n_expert = getattr(model_config, "n_expert", 0)
+        if n_expert and (tp > 1 or mesh is not None):
+            raise ValueError(
+                "a routed-expert model is served on one device: sharding "
+                "its expert layer (tp, ep) in the engine is not there yet")
+        self._expert_tokens = np.zeros(
+            (model_config.n_layer, n_expert), np.int64) if n_expert else None
 
         self._config = model_config
         # The working copy, made once and before anything else takes
@@ -234,6 +249,10 @@ class InferenceEngine:
         self._decode_compiles: Dict[str, int] = {}
         # One record per step(): its phases' stamps and what it ran.
         self.recorder = tracing.StepRecorder()
+        # Called, if set, once a decode step's program is on its way to
+        # the device, first thing in ``infer.decode.wait``: while the
+        # chip works the host is free, and nowhere else in a step is it.
+        self.on_launch: Optional[Callable[[], None]] = None
         self._prefill_tokens = 0
         self._decode_tokens = 0
         self._arrival_ts: Dict[str, float] = {}
@@ -256,12 +275,17 @@ class InferenceEngine:
         cfg, fwd = self._config, self._prefill_fwd
         compiles = self._prefill_compiles
         kv_sh = self._kv_sharding
+        routed = self._expert_tokens is not None
 
         def _prefill(params, ks, vs, tokens, dests):
             # Trace-time only: counts XLA compiles per length bucket.
             bucket = tokens.shape[1]
             compiles[bucket] = compiles.get(bucket, 0) + 1
-            logits, new_k, new_v = fwd(cfg, params, tokens)
+            live = {}
+            if routed:  # its padded positions are sent to no expert
+                from raytpu.models.llama import live_rows
+                live["live"] = live_rows(dests, ks[0])[None]
+            logits, new_k, new_v, *experts = fwd(cfg, params, tokens, **live)
             flat = ks[0].shape[0] * ks[0].shape[1]
             ks2, vs2 = [], []
             for kc, vc, nk, nv in zip(ks, vs, new_k, new_v):
@@ -276,7 +300,7 @@ class InferenceEngine:
                        for x in ks2]
                 vs2 = [jax.lax.with_sharding_constraint(x, kv_sh)
                        for x in vs2]
-            return logits[0], ks2, vs2
+            return (logits[0], ks2, vs2, *experts)
 
         return jax.jit(_prefill)
 
@@ -290,14 +314,14 @@ class InferenceEngine:
             # one XLA program.
             bucket = f"{tokens.shape[1]}x{block_tables.shape[1]}"
             compiles[bucket] = compiles.get(bucket, 0) + 1
-            logits, ks2, vs2 = fwd(cfg, params, tokens, positions, dests,
-                                   block_tables, ks, vs)
+            logits, ks2, vs2, *experts = fwd(
+                cfg, params, tokens, positions, dests, block_tables, ks, vs)
             if kv_sh is not None:
                 ks2 = [jax.lax.with_sharding_constraint(x, kv_sh)
                        for x in ks2]
                 vs2 = [jax.lax.with_sharding_constraint(x, kv_sh)
                        for x in vs2]
-            return logits, ks2, vs2
+            return (logits, ks2, vs2, *experts)
 
         return jax.jit(_chunk)
 
@@ -312,14 +336,15 @@ class InferenceEngine:
             # one XLA program.
             bucket = f"{tokens.shape[0]}x{block_tables.shape[1]}"
             compiles[bucket] = compiles.get(bucket, 0) + 1
-            logits, ks2, vs2 = fwd(cfg, params, tokens, positions, dests,
-                                   block_tables, context_lens, ks, vs)
+            logits, ks2, vs2, *experts = fwd(
+                cfg, params, tokens, positions, dests, block_tables,
+                context_lens, ks, vs)
             if kv_sh is not None:
                 ks2 = [jax.lax.with_sharding_constraint(x, kv_sh)
                        for x in ks2]
                 vs2 = [jax.lax.with_sharding_constraint(x, kv_sh)
                        for x in vs2]
-            return logits, ks2, vs2
+            return (logits, ks2, vs2, *experts)
 
         return jax.jit(_decode)
 
@@ -330,6 +355,24 @@ class InferenceEngine:
         if self._repl_sharding is not None:
             return self._jax.device_put(x, self._repl_sharding)
         return self._jnp.asarray(x)
+
+    def _count_experts(self, experts) -> None:
+        """Add what one program of a routed model returned beside its
+        logits (int32 ``[layers, experts]``: live tokens each expert
+        received) to the running total and to the open step's record.
+        A dense family's programs return nothing: ``experts`` is empty."""
+        if not experts:
+            return
+        counts = np.asarray(experts[0])
+        self._expert_tokens += counts
+        fields = self.recorder.open.fields
+        fields["moe_assignments"] = (fields.get("moe_assignments", 0)
+                                     + int(counts.sum()))
+        fields["moe_experts_touched"] = (
+            fields.get("moe_experts_touched", 0)
+            + int(np.count_nonzero(counts)))
+        fields["moe_expert_max"] = max(fields.get("moe_expert_max", 0),
+                                       int(counts.max()))
 
     # ---- request lifecycle ------------------------------------------
 
@@ -482,10 +525,11 @@ class InferenceEngine:
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, :plen] = seq.tokens[:plen]
         dests = self.cache.prefill_dests(seq.request_id, plen, bucket)
-        logits, ks, vs = self._prefill_fn(
+        logits, ks, vs, *experts = self._prefill_fn(
             self._params, self.cache.k, self.cache.v,
             self._put(tokens), self._put(dests))
         self.cache.k, self.cache.v = ks, vs
+        self._count_experts(experts)
         seq.cached_len = plen
         self._register_prefix(seq)
         if not seq.generated:
@@ -514,11 +558,12 @@ class InferenceEngine:
         tables = self.cache.table_array([seq.request_id], p_used)
         if self.paged_attn_impl == "reference":
             self._pages_gathered += p_used
-        logits, ks, vs = self._chunk_fn(
+        logits, ks, vs, *experts = self._chunk_fn(
             self._params, self.cache.k, self.cache.v,
             self._put(tokens), self._put(positions),
             self._put(dests), self._put(tables))
         self.cache.k, self.cache.v = ks, vs
+        self._count_experts(experts)
         seq.cached_len = start + take
         self._register_prefix(seq)
         if seq.cached_len >= plen and not seq.generated:
@@ -562,16 +607,24 @@ class InferenceEngine:
                 recorder.open.fields.update(
                     decodes=b, bucket=bucket, table_width=P,
                     live_pages=live_pages)
-                logits, ks, vs = self._decode_fn(
+                logits, ks, vs, *experts = self._decode_fn(
                     self._params, self.cache.k, self.cache.v,
                     self._put(tokens), self._put(positions),
                     self._put(dests), self._put(tables),
                     self._put(context_lens))
                 self.cache.k, self.cache.v = ks, vs
+                for count in experts:
+                    # Asked for now, it comes back beside the logits; left
+                    # to the wait it is a transfer of its own, 0.5 ms.
+                    count.copy_to_host_async()
             with recorder.phase("infer.decode.wait") as wait:
-                # The host blocked on the device and on the copy back.
+                # The host blocked on the device and on the copy back,
+                # after whatever its owner has for it meanwhile.
+                if self.on_launch is not None:
+                    self.on_launch()
                 logits_np = np.asarray(logits)
                 wait.attrs["bytes"] = logits_np.nbytes
+                self._count_experts(experts)
             with recorder.phase("infer.decode.sample"):
                 for i, seq in enumerate(seqs):
                     seq.cached_len += 1
@@ -680,7 +733,12 @@ class InferenceEngine:
         ``bucket``, ``table_width``, ``live_pages`` (pages its decode
         had to read), ``admitted``, ``compiled`` (programs traced in
         it), ``preempted``, ``prefills`` (``request_id``, ``tokens``,
-        ``bucket``, ``waited_s`` each) and ``error`` if it raised.
+        ``bucket``, ``waited_s`` each) and ``error`` if it raised. A
+        routed-expert model's steps also carry, over the step's programs,
+        ``moe_assignments`` ((token, expert) pairs computed),
+        ``moe_experts_touched`` (experts that received a token, summed
+        over layers and programs) and ``moe_expert_max`` (the most
+        tokens one expert of one layer received in one program).
         ``"oldest_start"`` is the start of the oldest step still held,
         so a reader can tell a truncated log from a quiet engine. Call
         it from the thread that steps, or under the lock that
@@ -715,6 +773,10 @@ class InferenceEngine:
             "kv_utilization": self.cache.utilization(),
             "prefill_tokens": self._prefill_tokens,
             "decode_tokens": self._decode_tokens,
+            # Live tokens each expert of each layer has received,
+            # [layers][experts]; None for a dense family.
+            "expert_tokens": (self._expert_tokens.tolist()
+                              if self._expert_tokens is not None else None),
             "ttft_p50_s": self.ttft_quantile(0.5),
             "ttft_p95_s": self.ttft_quantile(0.95),
             "prefix_cache": (self.prefix_cache.stats()

@@ -17,6 +17,7 @@ model-specific code.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -36,7 +37,14 @@ class LlamaConfig:
     n_embd: int = 768
     n_inter: int = 2048              # SwiGLU hidden (≈ 8/3 · n_embd)
     rope_theta: float = 10000.0
+    norm_eps: float = 1e-5           # every RMSNorm's epsilon
+    # RMSNorm over the whole q and the whole k projection, before the
+    # heads are split and roped (OLMoE).
+    qk_norm: bool = False
     dtype: Any = jnp.bfloat16
+    # The type the matrices and the embedding are *held* in. The norms'
+    # scales (and a routed layer's router) stay float32 whatever it is.
+    param_dtype: Any = jnp.float32
     remat: Any = "dots"              # False/"none" | True/"full" | "dots"
     scan_layers: bool = True
     attn_impl: Optional[str] = None
@@ -128,16 +136,26 @@ class LlamaAttention(nn.Module):
 
     def setup(self):
         c = self.config
-        self.q_proj = nn.Dense(c.n_head * c.head_dim, use_bias=False,
-                               dtype=c.dtype)
-        self.k_proj = nn.Dense(c.n_kv_head * c.head_dim, use_bias=False,
-                               dtype=c.dtype)
-        self.v_proj = nn.Dense(c.n_kv_head * c.head_dim, use_bias=False,
-                               dtype=c.dtype)
-        self.o_proj = nn.Dense(c.n_embd, use_bias=False, dtype=c.dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=c.dtype,
+                                  param_dtype=c.param_dtype)
+        self.q_proj = dense(c.n_head * c.head_dim)
+        self.k_proj = dense(c.n_kv_head * c.head_dim)
+        self.v_proj = dense(c.n_kv_head * c.head_dim)
+        self.o_proj = dense(c.n_embd)
+        if c.qk_norm:
+            self.q_norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
+            self.k_norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
 
     def __call__(self, x):
         return self.prefill(x)[0]
+
+    def _qkv(self, x):
+        """The three projections of ``x`` [..., E], q and k normed over
+        their whole width where the config says so; heads not yet split."""
+        q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
+        if self.config.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
+        return q, k, v
 
     def prefill(self, x):
         """Full-sequence attention over ``x`` [B, T, E]; returns
@@ -147,9 +165,10 @@ class LlamaAttention(nn.Module):
         c = self.config
         b, t, _ = x.shape
         h, kv, d = c.n_head, c.n_kv_head, c.head_dim
-        q = self.q_proj(x).reshape(b, t, h, d).transpose(0, 2, 1, 3)
-        k = self.k_proj(x).reshape(b, t, kv, d).transpose(0, 2, 1, 3)
-        v = self.v_proj(x).reshape(b, t, kv, d).transpose(0, 2, 1, 3)
+        q, k, v = self._qkv(x)
+        q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+        k = k.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
+        v = v.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
         cos, sin = rope_tables(d, jnp.arange(t), c.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -183,9 +202,10 @@ class LlamaAttention(nn.Module):
         c = self.config
         b, t, _ = x.shape
         h, kv, d = c.n_head, c.n_kv_head, c.head_dim
-        q = self.q_proj(x).reshape(b, t, h, d).transpose(0, 2, 1, 3)
-        k = self.k_proj(x).reshape(b, t, kv, d).transpose(0, 2, 1, 3)
-        v = self.v_proj(x).reshape(b, t, kv, d).transpose(0, 2, 1, 3)
+        q, k, v = self._qkv(x)
+        q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+        k = k.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
+        v = v.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
         cos, sin = rope_tables(d, positions, c.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -223,9 +243,8 @@ class LlamaAttention(nn.Module):
         c = self.config
         b, _ = x.shape
         h, kv, d = c.n_head, c.n_kv_head, c.head_dim
-        q = self.q_proj(x).reshape(b, h, d)
-        k = self.k_proj(x).reshape(b, kv, d)
-        v = self.v_proj(x).reshape(b, kv, d)
+        q, k, v = self._qkv(x)
+        q, k, v = q.reshape(b, h, d), k.reshape(b, kv, d), v.reshape(b, kv, d)
         cos, sin = rope_tables(d, positions, c.rope_theta)
         q = apply_rope_single(q, cos, sin)
         k = apply_rope_single(k, cos, sin)
@@ -248,12 +267,11 @@ class LlamaMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        gate = nn.Dense(c.n_inter, use_bias=False, dtype=c.dtype,
-                        name="gate_proj")(x)
-        up = nn.Dense(c.n_inter, use_bias=False, dtype=c.dtype,
-                      name="up_proj")(x)
-        return nn.Dense(c.n_embd, use_bias=False, dtype=c.dtype,
-                        name="down_proj")(nn.silu(gate) * up)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=c.dtype,
+                                  param_dtype=c.param_dtype)
+        gate = dense(c.n_inter, name="gate_proj")(x)
+        up = dense(c.n_inter, name="up_proj")(x)
+        return dense(c.n_embd, name="down_proj")(nn.silu(gate) * up)
 
 
 class LlamaBlock(nn.Module):
@@ -263,9 +281,10 @@ class LlamaBlock(nn.Module):
     def __call__(self, x):
         c = self.config
         x = x + LlamaAttention(c, name="attn")(
-            RMSNorm(dtype=c.dtype, name="input_norm")(x))
+            RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="input_norm")(x))
         x = x + LlamaMLP(c, name="mlp")(
-            RMSNorm(dtype=c.dtype, name="post_attn_norm")(x))
+            RMSNorm(dtype=c.dtype, eps=c.norm_eps,
+                    name="post_attn_norm")(x))
         return x
 
 
@@ -276,6 +295,7 @@ class Llama(nn.Module):
     def __call__(self, tokens, return_hidden: bool = False):
         c = self.config
         x = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
+                     param_dtype=c.param_dtype,
                      name="embed_tokens")(tokens)
         block = LlamaBlock
         if c.remat and c.remat != "none":
@@ -294,12 +314,12 @@ class Llama(nn.Module):
         else:
             for i in range(c.n_layer):
                 x = block(c, name=f"layers_{i}")(x)
-        x = RMSNorm(dtype=c.dtype, name="final_norm")(x)
+        x = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="final_norm")(x)
         if return_hidden:
             return x
         # Untied LM head (llama-style), bf16 matmul with fp32 accumulation.
         logits = nn.Dense(c.vocab_size, use_bias=False, dtype=c.dtype,
-                          name="lm_head")(x)
+                          param_dtype=c.param_dtype, name="lm_head")(x)
         return logits.astype(jnp.float32)
 
 
@@ -367,13 +387,17 @@ def serving_params(config: LlamaConfig, params):
     """The working copy of ``params`` to serve from: the leaves that
     :func:`llama_prefill`, :func:`llama_prefill_chunk` and
     :func:`llama_decode` cast to ``config.dtype`` (every ``nn.Dense``
-    kernel, ``lm_head`` among them, and ``embed_tokens``) are in it
+    kernel, ``lm_head`` among them, ``embed_tokens``, and a routed
+    layer's stacked expert matrices ``wi``/``wg``/``wo``) are in it
     already, so no step converts a weight and the logits are the same
     bits. The norms' ``scale`` stays as given: :class:`RMSNorm`
-    multiplies by it in float32. Same contract as
+    multiplies by it in float32; so does a router's kernel, which is
+    multiplied in float32. Same contract as
     :func:`raytpu.models.gpt2.serving_params`."""
-    return cast_leaves(params, config.dtype,
-                       lambda keys: keys[-1] in ("kernel", "embedding"))
+    return cast_leaves(
+        params, config.dtype,
+        lambda keys: keys[-1] in ("embedding", "wi", "wg", "wo")
+        or (keys[-1] == "kernel" and keys[-2] != "router"))
 
 
 def _lm_logits(c: LlamaConfig, params, x):
@@ -381,16 +405,39 @@ def _lm_logits(c: LlamaConfig, params, x):
     return jnp.dot(x, kernel).astype(jnp.float32)
 
 
-def llama_prefill(config: LlamaConfig, params, tokens):
+def _feed_forward(c: LlamaConfig, lp, h, live):
+    """The second half of a block on the normed ``h``: SwiGLU, or the
+    routed experts where the config is a ``MixtralConfig`` (OLMoE's is
+    one). ``live`` marks the rows that are tokens and not padding; only
+    the routed layer needs it, to route padding nowhere. Returns the
+    output and the tokens each expert received (``None`` when dense)."""
+    from raytpu.models import mixtral  # it imports this module
+
+    if isinstance(c, mixtral.MixtralConfig):
+        return mixtral.MoEFFN(c).apply({"params": lp["moe"]}, h, live)
+    return LlamaMLP(c).apply({"params": lp["mlp"]}, h), None
+
+
+def _walk_result(logits, new_k, new_v, routed):
+    """What a serving walk returns: logits first, then the K and V lists,
+    and for a routed config the int32 ``[layers, experts]`` count of
+    tokens each expert received."""
+    if routed[0] is None:
+        return logits, new_k, new_v
+    return logits, new_k, new_v, jnp.stack(routed)
+
+
+def llama_prefill(config: LlamaConfig, params, tokens, live=None):
     """Prefill forward: ``tokens`` [B, T] -> (fp32 logits [B, T, V],
     per-layer roped K [B, T, KV, D] list, per-layer V list) — the K/V
-    halves are what the engine scatters into the paged cache."""
+    halves are what the engine scatters into the paged cache. ``live``
+    [B, T] marks real positions of a padded bucket (all, if None). A
+    routed config returns a fourth value, see :func:`_walk_result`."""
     c = config
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     attn = LlamaAttention(c)
-    mlp = LlamaMLP(c)
-    norm = RMSNorm(dtype=c.dtype)
-    ks, vs = [], []
+    norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
+    ks, vs, routed = [], [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
         h = norm.apply({"params": lp["input_norm"]}, x)
@@ -399,9 +446,17 @@ def llama_prefill(config: LlamaConfig, params, tokens):
         vs.append(v)
         x = x + y
         h = norm.apply({"params": lp["post_attn_norm"]}, x)
-        x = x + mlp.apply({"params": lp["mlp"]}, h)
+        y, counts = _feed_forward(c, lp, h, live)
+        routed.append(counts)
+        x = x + y
     x = norm.apply({"params": params["final_norm"]}, x)
-    return _lm_logits(c, params, x), ks, vs
+    return _walk_result(_lm_logits(c, params, x), ks, vs, routed)
+
+
+def live_rows(dests, k_cache):
+    """Rows of a bucket that are tokens, from where their K/V is written:
+    the engine points padding at the scratch page, page 0."""
+    return dests >= k_cache.shape[1]
 
 
 def llama_prefill_chunk(config: LlamaConfig, params, tokens, positions,
@@ -413,9 +468,9 @@ def llama_prefill_chunk(config: LlamaConfig, params, tokens, positions,
     c = config
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     attn = LlamaAttention(c)
-    mlp = LlamaMLP(c)
-    norm = RMSNorm(dtype=c.dtype)
-    new_k, new_v = [], []
+    norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
+    live = live_rows(dests, k_caches[0])[None]
+    new_k, new_v, routed = [], [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
         h = norm.apply({"params": lp["input_norm"]}, x)
@@ -426,9 +481,11 @@ def llama_prefill_chunk(config: LlamaConfig, params, tokens, positions,
         new_v.append(vc)
         x = x + y
         h = norm.apply({"params": lp["post_attn_norm"]}, x)
-        x = x + mlp.apply({"params": lp["mlp"]}, h)
+        y, counts = _feed_forward(c, lp, h, live)
+        routed.append(counts)
+        x = x + y
     x = norm.apply({"params": params["final_norm"]}, x)
-    return _lm_logits(c, params, x), new_k, new_v
+    return _walk_result(_lm_logits(c, params, x), new_k, new_v, routed)
 
 
 def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
@@ -439,9 +496,9 @@ def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
     c = config
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     attn = LlamaAttention(c)
-    mlp = LlamaMLP(c)
-    norm = RMSNorm(dtype=c.dtype)
-    new_k, new_v = [], []
+    norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
+    live = live_rows(dests, k_caches[0])
+    new_k, new_v, routed = [], [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
         h = norm.apply({"params": lp["input_norm"]}, x)
@@ -452,6 +509,8 @@ def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
         new_v.append(vc)
         x = x + y
         h = norm.apply({"params": lp["post_attn_norm"]}, x)
-        x = x + mlp.apply({"params": lp["mlp"]}, h)
+        y, counts = _feed_forward(c, lp, h, live)
+        routed.append(counts)
+        x = x + y
     x = norm.apply({"params": params["final_norm"]}, x)
-    return _lm_logits(c, params, x), new_k, new_v
+    return _walk_result(_lm_logits(c, params, x), new_k, new_v, routed)

@@ -1,21 +1,31 @@
-"""Mixtral-family sparse-MoE decoder — the third flagship model family.
+"""Sparse-MoE decoders — the third model family: Mixtral and OLMoE.
 
 Reference scope: MoE machinery is absent from the reference (SURVEY.md
 §2.5 EP row); serving/training MoE models there is delegated to user
 libraries. Here the family is first-class and TPU-shaped: llama blocks
-(RMSNorm/RoPE/GQA via :mod:`raytpu.models.llama`) whose FFN is a top-k
-routed expert layer using the dense one-hot dispatch formulation —
-einsum-only (MXU-shaped, static shapes, no scatter), with a Switch-style
-load-balancing auxiliary loss sown as an intermediate. Expert parameters
-are stacked on a leading experts dim so ``TRANSFORMER_RULES`` shards them
-over the ``ep`` mesh axis with no model-specific code (XLA inserts the
-all-to-alls when tokens meet sharded experts).
+(RMSNorm/RoPE/GQA via :mod:`raytpu.models.llama`) whose feed-forward is
+one top-k routed expert layer, :class:`MoEFFN`. It is *dropless*: every
+(token, expert) pair the router chooses is computed, so a token's output
+does not depend on what shares its batch. The assignments are sorted by
+expert and the three expert matrices multiplied group by group
+(``jax.lax.ragged_dot``, which the TPU compiler turns into a kernel of
+its own that reads only the experts that received a row), so the work
+grows with ``n_expert_per_tok`` and not with ``n_expert``. A Switch-style
+load-balancing loss is sown as an intermediate for training. Expert
+parameters are stacked on a leading experts dim so ``TRANSFORMER_RULES``
+shards them over the ``ep`` mesh axis with no model-specific code.
+
+Training goes through :class:`Mixtral`; serving through the llama
+family's three walks (:func:`raytpu.models.llama.llama_prefill` and its
+siblings), which pick this layer for a :class:`MixtralConfig`.
+:class:`OlmoeConfig` is OLMoE-1B-7B's block: 64 experts of which a token
+takes 8 with weights that are not renormalised, and a norm over the
+whole q and k projections.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -28,7 +38,8 @@ from raytpu.models.llama import LlamaAttention, LlamaConfig, RMSNorm
 class MixtralConfig(LlamaConfig):
     n_expert: int = 8
     n_expert_per_tok: int = 2
-    capacity_factor: float = 1.25
+    # Whether the chosen experts' weights are rescaled to sum to one.
+    norm_topk_prob: bool = True
     router_aux_coef: float = 0.01
 
     @classmethod
@@ -38,29 +49,58 @@ class MixtralConfig(LlamaConfig):
                    n_expert_per_tok=2)
 
 
-class MoEFFN(nn.Module):
-    """Top-k routed experts with capacity, dense dispatch einsums.
+@dataclasses.dataclass(frozen=True)
+class OlmoeConfig(MixtralConfig):
+    """OLMoE-1B-7B (``allenai/OLMoE-1B-7B-0125-Instruct``) as published;
+    ``n_inter`` is one expert's width."""
 
-    FLOPs scale with k·capacity_factor (tokens actually routed), not with
-    the expert count — the einsum shapes stay static so XLA tiles them
-    onto the MXU, and the experts dim shards over ``ep``.
+    vocab_size: int = 50304
+    block_size: int = 4096
+    n_layer: int = 16
+    n_head: int = 16
+    n_kv_head: int = 16
+    n_embd: int = 2048
+    n_inter: int = 1024
+    n_expert: int = 64
+    n_expert_per_tok: int = 8
+    norm_topk_prob: bool = False
+    qk_norm: bool = True
+
+    @classmethod
+    def tiny(cls) -> "OlmoeConfig":
+        return cls(vocab_size=512, block_size=128, n_layer=2, n_head=4,
+                   n_kv_head=4, n_embd=64, n_inter=32, n_expert=8,
+                   n_expert_per_tok=2)
+
+
+class MoEFFN(nn.Module):
+    """Top-k routed SwiGLU experts, dropless.
+
+    ``x`` is ``[..., D]``; ``live`` (same leading shape, bool) marks the
+    rows that are tokens: padding is routed nowhere, costs no expert a
+    row and is not counted. Returns ``(y, tokens)``: the layer's output
+    and the int32 ``[n_expert]`` number of live tokens each expert
+    received. The router runs in float32 at full precision over all the
+    experts; the expert matrices multiply in ``config.dtype``.
     """
 
     config: MixtralConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, live=None):
         c = self.config
-        b, t, d = x.shape
-        n = b * t
-        k = c.n_expert_per_tok
-        e = c.n_expert
-        xf = x.reshape(n, d)
-        router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
-                          name="router")(xf.astype(jnp.float32))
-        probs = jax.nn.softmax(router, axis=-1)           # [N, E]
-        topw, topi = jax.lax.top_k(probs, k)              # [N, k]
-        topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
+        d = x.shape[-1]
+        k, e = c.n_expert_per_tok, c.n_expert
+        xf = x.reshape(-1, d)
+        n = xf.shape[0]
+        with jax.named_scope("moe.router"):
+            router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST,
+                              name="router")(xf.astype(jnp.float32))
+            probs = jax.nn.softmax(router, axis=-1)           # [N, E]
+            topw, topi = jax.lax.top_k(probs, k)              # [N, k]
+            if c.norm_topk_prob:
+                topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
 
         # Switch-style load balance: E * sum_e(frac_routed_e * mean_prob_e)
         top1 = jax.nn.one_hot(topi[:, 0], e, dtype=jnp.float32)
@@ -68,41 +108,34 @@ class MoEFFN(nn.Module):
                           * jnp.mean(probs, axis=0))
         self.sow("intermediates", "moe_aux", aux)
 
-        capacity = max(1, int(c.capacity_factor * n * k / e))
-        # Slot-major assignment stream [k*N]: slot 0 of every token claims
-        # buffer positions before slot 1, so primary routes win capacity.
-        flat_idx = topi.T.reshape(k * n)                  # [k*N]
-        flat_w = topw.T.reshape(k * n)
-        onehot = jax.nn.one_hot(flat_idx, e, dtype=jnp.float32)
-        pos = jnp.cumsum(onehot, axis=0) * onehot - 1.0
-        pos_in_e = jnp.sum(pos, axis=-1).astype(jnp.int32)
-        keep = (pos_in_e < capacity).astype(jnp.float32)
-        pos_oh = jax.nn.one_hot(pos_in_e, capacity, dtype=jnp.float32)
-        dispatch = onehot[:, :, None] * pos_oh[:, None, :] \
-            * keep[:, None, None]                          # [kN, E, C]
-        combine = dispatch * flat_w[:, None, None]
-
-        # Routing/dispatch math stays fp32; the expert matmuls (the
-        # block's dominant FLOPs) run in the model compute dtype so the
-        # MXU sees bf16 like the dense llama FFN.
-        x_rep = jnp.tile(xf, (k, 1)).astype(jnp.float32)   # [kN, D]
-        expert_in = jnp.einsum("sec,sd->ecd", dispatch,
-                               x_rep).astype(c.dtype)
-        wi = self.param("wi", nn.initializers.normal(d ** -0.5),
-                        (e, d, c.n_inter))
-        wg = self.param("wg", nn.initializers.normal(d ** -0.5),
-                        (e, d, c.n_inter))
-        wo = self.param("wo", nn.initializers.normal(c.n_inter ** -0.5),
-                        (e, c.n_inter, d))
-        h = (nn.silu(jnp.einsum("ecd,edf->ecf", expert_in,
-                                wg.astype(c.dtype)))
-             * jnp.einsum("ecd,edf->ecf", expert_in, wi.astype(c.dtype)))
-        expert_out = jnp.einsum("ecf,efd->ecd", h,
-                                wo.astype(c.dtype))      # [E, C, D]
-        y = jnp.einsum("sec,ecd->sd", combine,
-                       expert_out.astype(jnp.float32))    # [kN, D]
-        y = jnp.sum(y.reshape(k, n, d), axis=0)
-        return y.reshape(b, t, d).astype(c.dtype)
+        init = nn.initializers.normal
+        wi = self.param("wi", init(d ** -0.5), (e, d, c.n_inter),
+                        c.param_dtype)
+        wg = self.param("wg", init(d ** -0.5), (e, d, c.n_inter),
+                        c.param_dtype)
+        wo = self.param("wo", init(c.n_inter ** -0.5), (e, c.n_inter, d),
+                        c.param_dtype)
+        with jax.named_scope("moe.experts"):
+            # One row per (token, expert) pair, in order of expert. A
+            # dead row's expert is ``e``: it sorts past the last group,
+            # belongs to none and is multiplied by nothing.
+            flat = topi.reshape(n * k)
+            if live is not None:
+                flat = jnp.where(jnp.repeat(live.reshape(n), k), flat, e)
+            order = jnp.argsort(flat)
+            tokens = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
+            rows = xf.astype(c.dtype)[order // k]             # [kN, D]
+            grouped = jax.lax.ragged_dot  # row groups x their experts
+            h = (nn.silu(grouped(rows, wg.astype(c.dtype), tokens))
+                 * grouped(rows, wi.astype(c.dtype), tokens))
+            out = grouped(h, wo.astype(c.dtype), tokens)
+            # Weighted and summed per token in float32, in the order of
+            # the token's own top-k: the same whatever else is batched.
+            out = jnp.where((flat[order] < e)[:, None],
+                            out.astype(jnp.float32)
+                            * topw.reshape(n * k)[order][:, None], 0.0)
+            y = jnp.sum(out[jnp.argsort(order)].reshape(n, k, d), axis=1)
+        return y.reshape(x.shape).astype(c.dtype), tokens
 
 
 class MixtralBlock(nn.Module):
@@ -112,10 +145,11 @@ class MixtralBlock(nn.Module):
     def __call__(self, x):
         c = self.config
         x = x + LlamaAttention(c, name="attn")(
-            RMSNorm(dtype=c.dtype, name="input_norm")(x))
-        x = x + MoEFFN(c, name="moe")(
-            RMSNorm(dtype=c.dtype, name="post_attn_norm")(x))
-        return x
+            RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="input_norm")(x))
+        y, _ = MoEFFN(c, name="moe")(
+            RMSNorm(dtype=c.dtype, eps=c.norm_eps,
+                    name="post_attn_norm")(x))
+        return x + y
 
 
 class Mixtral(nn.Module):
@@ -125,6 +159,7 @@ class Mixtral(nn.Module):
     def __call__(self, tokens, return_hidden: bool = False):
         c = self.config
         x = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
+                     param_dtype=c.param_dtype,
                      name="embed_tokens")(tokens)
         block = MixtralBlock
         if c.remat and c.remat != "none":
@@ -143,11 +178,12 @@ class Mixtral(nn.Module):
         else:
             for i in range(c.n_layer):
                 x = block(c, name=f"layers_{i}")(x)
-        x = RMSNorm(dtype=c.dtype, name="final_norm")(x)
+        x = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="final_norm")(x)
         if return_hidden:
             return x
+        # Untied output head, as llama's.
         logits = nn.Dense(c.vocab_size, use_bias=False, dtype=c.dtype,
-                          name="lm_head")(x)
+                          param_dtype=c.param_dtype, name="lm_head")(x)
         return logits.astype(jnp.float32)
 
 
@@ -173,5 +209,12 @@ def make_train_step(model: Mixtral, optimizer):
     return _shared(model, optimizer, loss_fn=mixtral_loss_fn)
 
 
-# Same signature/behavior as the llama helper — reuse it.
-from raytpu.models.llama import init_params  # noqa: E402,F401
+def init_params(model: Mixtral, config: MixtralConfig, seed: int = 0,
+                batch: int = 2):
+    """Seeded parameters, made inside one jitted program: the forward
+    pass ``model.init`` traces is dead code there, so a 7 B tree costs
+    its own bytes and no activations. Same signature as the llama
+    helper."""
+    tokens = jnp.zeros((batch, config.block_size), jnp.int32)
+    return jax.jit(lambda key: model.init(key, tokens)["params"])(
+        jax.random.PRNGKey(seed))

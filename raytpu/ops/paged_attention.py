@@ -15,9 +15,11 @@ kernel that reads KV pages **in place**, vLLM-PagedAttention style:
   page-grid coordinate through the block table, so each step DMAs one
   ``[page_size, group_lanes]`` tile straight out of the pool.
 - a pool row is read flattened to ``[kv_heads * head_dim]`` and a block
-  takes the fewest kv heads that fill whole 128-lane tiles (two at
-  ``head_dim`` 64, one at 128), because Mosaic has no block of one head
-  out of a ``[kv_heads, head_dim]`` minor pair. The heads of a group
+  takes kv heads that fill whole 128-lane tiles (two or more at
+  ``head_dim`` 64, one or more at 128: as many as keep the block's
+  query rows inside one pass of the matrix unit, so all of them in a
+  decode step and the fewest in a prompt's chunk), because Mosaic has
+  no block of one head out of a ``[kv_heads, head_dim]`` minor pair. The heads of a group
   share the lane axis: each query row is zero outside its own head's
   lanes, one dense product scores every head, and the wrapper keeps
   each row's own lanes of the result. The MXU does ``group`` times the
@@ -281,15 +283,29 @@ def _fit_q_block(t: int, want: int) -> int:
     return want
 
 
-def _kv_heads_per_block(kv: int, d: int) -> int:
-    """Fewest kv heads whose features fill whole 128-lane tiles; all of
-    them when no count does. Mosaic wants the minor dimension of a
-    block to be a multiple of 128 or the array's whole minor
-    dimension, and a pool row flattened to ``[kv * d]`` offers both."""
-    for g in range(1, kv):
-        if kv % g == 0 and (g * d) % _LANES == 0:
-            return g
-    return kv
+# Rows of one matrix-unit pass: a block of this many query rows or fewer
+# costs the MXU the same whatever heads share it.
+_MXU_ROWS = 128
+
+
+def _kv_heads_per_block(kv: int, d: int, rows_per_head: int = _MXU_ROWS
+                        ) -> int:
+    """How many kv heads one block takes. Mosaic wants the minor
+    dimension of a block to be a multiple of 128 or the array's whole
+    minor dimension, and a pool row flattened to ``[kv * d]`` offers
+    both: any count whose features fill whole 128-lane tiles, or all the
+    heads. Every grid step costs about a quarter of a microsecond
+    whatever it moves, so of those counts the largest is taken whose
+    query rows (``rows_per_head`` each: tokens of the block times query
+    heads of a kv head) still fit one pass of the matrix unit; a decode
+    step, one token a sequence, then reads a page of all 16 heads of
+    128 in one step and not in 16 (33 ms of an 80 ms step of
+    OLMoE-1B-7B at batch 16; PERF.md, PR 26). A chunk of a prompt has
+    hundreds of rows a head and takes the fewest heads, as before."""
+    fits = [g for g in range(1, kv)
+            if kv % g == 0 and (g * d) % _LANES == 0] + [kv]
+    few = [g for g in fits if g * rows_per_head <= _MXU_ROWS]
+    return max(few) if few else fits[0]
 
 
 @functools.partial(
@@ -304,7 +320,7 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
     n_pg = block_tables.shape[1]
     bq_t = _fit_q_block(t, _env_block("RAYTPU_PAGED_BLOCK_Q", 256))
     n_qb = t // bq_t
-    g = _kv_heads_per_block(kv, d)
+    g = _kv_heads_per_block(kv, d, bq_t * rep)
     n_grp = kv // g
     lanes = g * d
     live_rows = g * bq_t * rep

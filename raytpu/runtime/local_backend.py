@@ -554,8 +554,14 @@ class LocalBackend:
                 a.num_handles += 1
 
     def actor_handle_removed(self, actor_id: ActorID):
-        with self._lock:
-            a = self._actors.get(actor_id)
+        # No lock: ``ActorHandle.__del__`` calls this, and the collector
+        # runs it on whatever thread allocates next, inside whatever
+        # critical section that thread is in. Inside ``ObjectStore.put``
+        # it waited here for this lock while ``wait_any_object_ready``
+        # held it and waited for the store's: sixteen token streams hung
+        # a serving run in 2 of 27 (PERF.md, PR 26). A dict read is
+        # atomic under the interpreter's lock.
+        a = self._actors.get(actor_id)
         if a is not None:
             a.num_handles -= 1
             if a.num_handles <= 0 and not a.detached and not a.dead:

@@ -661,3 +661,43 @@ class TestRefCounting:
         worker = raytpu.runtime.api._global_worker_or_none()
         rec = worker.reference_counter.get(big.id)
         assert rec is not None and rec.submitted_task_ref_count == 0
+
+
+def test_dropping_an_actor_handle_never_waits_for_the_backend_lock(
+        raytpu_local):
+    """``ActorHandle.__del__`` runs wherever the collector runs it, e.g.
+    inside ``ObjectStore.put`` while another thread holds the backend's
+    lock and waits for the store's (``wait_any_object_ready``): it must
+    take no lock, or a serving run with many token streams hangs."""
+    import threading
+
+    from raytpu.runtime import api
+
+    @raytpu_local.remote
+    class Box:
+        def get(self):
+            return 1
+
+    a = Box.remote()
+    assert raytpu_local.get(a.get.remote()) == 1
+    backend = api._backend_or_none()
+    backend.actor_handle_added(a._actor_id)  # the handle about to go away
+    held, release, done = (threading.Event() for _ in range(3))
+
+    def hold():
+        with backend._lock:
+            held.set()
+            release.wait(10)
+
+    holder = threading.Thread(target=hold, daemon=True)
+    holder.start()
+    assert held.wait(5)
+    threading.Thread(
+        target=lambda: (backend.actor_handle_removed(a._actor_id),
+                        done.set()), daemon=True).start()
+    try:
+        assert done.wait(2), "actor_handle_removed waited for the lock"
+    finally:
+        release.set()
+        holder.join(5)
+    assert raytpu_local.get(a.get.remote()) == 1

@@ -283,15 +283,48 @@ class TestSteppingLoopStepLog:
             names = [p[0] for p in step["phases"]]
             assert names[0] == "serve.llm.lock_wait"
             assert names[1] == "infer.schedule"
-            assert names[-1] == "serve.llm.publish"
-            wait, publish = step["phases"][0], step["phases"][-1]
+            wait = step["phases"][0]
             assert wait[1] <= wait[2] <= step["start"]
-            assert step["end"] <= publish[1] <= publish[2]
         assert [s["decodes"] for s in steps] == [0, 1, 1, 1]
+        # A step's token is published while the next step runs on the
+        # device, as that step's wait begins; the last step's, which no
+        # launch follows, at once.
+        published = [[p for p in s["phases"] if p[0] == "serve.llm.publish"]
+                     for s in steps]
+        assert [len(p) for p in published] == [0, 1, 1, 2]
+        for step, (publish, *_) in zip(steps[1:], published[1:]):
+            at = {p[0]: p for p in step["phases"]}
+            wait = at["infer.decode.wait"]
+            assert at["infer.decode.launch"][2] <= wait[1] <= publish[1]
+            assert publish[2] <= wait[2]
+        last = steps[-1]["phases"][-1]
+        assert last[0] == "serve.llm.publish"
+        assert steps[-1]["end"] <= last[1] <= last[2]
         for a, b in zip(steps, steps[1:]):
-            # The gap between steps holds the publish and the next wait.
-            assert a["phases"][-1][2] <= b["start"]
+            # The gap between steps holds the next step's wait.
             assert a["end"] <= b["phases"][0][1]
+
+    def test_a_stream_reads_its_tokens_without_the_engine_lock(self):
+        """Tokens wait in the stream's own queue: a consumer that took
+        the engine lock for each one queued behind a whole step, and
+        the loop behind sixteen consumers."""
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            gen = dep.generate([1, 2, 3], max_new_tokens=4)
+            got = [next(gen)]
+            deadline = time.monotonic() + 10
+            while len(dep.step_log()) < 4 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            with dep._cv:  # as a step holds it
+                reader = threading.Thread(
+                    target=lambda: got.extend(next(gen) for _ in range(3)))
+                reader.start()
+                reader.join(timeout=5)
+                assert not reader.is_alive(), "a token waited for the lock"
+            assert len(got) == 4
+            assert list(gen) == []
+        finally:
+            dep.shutdown()
 
     def test_a_step_that_raises_ends_every_stream(self, monkeypatch):
         """An exception out of ``engine.step()`` used to kill the loop

@@ -127,6 +127,39 @@ class TestKernelNumerics:
                                       np.asarray(k[2]))
 
 
+class TestHeadsPerBlock:
+    """How many kv heads share a block is chosen from the shape: all that
+    keep the block's query rows inside one pass of the matrix unit, of
+    the counts Mosaic can block (whole 128-lane tiles, or every head)."""
+
+    @pytest.mark.parametrize("kv,d,rows_per_head,want", [
+        (25, 64, 1, 25),     # GPT-2 XL decode: no smaller count fills tiles
+        (16, 128, 1, 16),    # OLMoE decode: one step a page, not sixteen
+        (16, 128, 256, 1),   # its prompt chunks: the fewest heads
+        (12, 64, 1, 12),     # GPT-2 124M decode
+        (12, 64, 64, 2),     # and a 64-token chunk: 2 x 64 rows fit
+        (8, 128, 4, 8),      # GQA 32/8 decode: 8 x 4 rows
+        (8, 128, 64, 2),     # GQA chunk of 16 tokens x 4 query heads
+        (2, 32, 2, 2),       # no count fills a tile: every head
+    ])
+    def test_count_follows_the_rows(self, kv, d, rows_per_head, want):
+        from raytpu.ops.paged_attention import _kv_heads_per_block
+
+        assert _kv_heads_per_block(kv, d, rows_per_head) == want
+
+    @pytest.mark.parametrize("t", [1, 40])
+    def test_grouped_and_single_head_blocks_agree_with_reference(self, t):
+        # kv 4 x d 128: a decode block takes all four heads, a 40-token
+        # chunk one head (4 x 40 rows are more than a pass).
+        rng = np.random.default_rng(11)
+        args = _setup(rng, b=1 if t > 1 else 3, t=t, heads=4, kv=4, d=128,
+                      page_size=8, pages_per_seq=8, dtype=jnp.float32)
+        ref = paged_attention_reference(*args, sm_scale=128 ** -0.5)
+        out = paged_attention(*args, force="interpret")
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+
+
 class TestScatterKVSlots:
     @pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("n", [1, 5])
