@@ -170,17 +170,26 @@ def _spread_facts(eng, cfg, chips: int) -> dict:
     from raytpu.inference import SamplingParams
 
     shards = eng.cache.k[0].addressable_shards
-    heads = sorted(s.data.shape[2] for s in shards)
+    head_dim = cfg.n_embd // cfg.n_head  # a pool row is heads x head_dim
+    heads = sorted(s.data.shape[2] // head_dim for s in shards)
     check(len({s.device for s in shards}) == chips
           and heads == [cfg.n_head // chips] * chips,
           f"KV pool shards hold {heads} heads on "
           f"{len({s.device for s in shards})} devices, want "
           f"{cfg.n_head // chips} on each of {chips}")
     # The engine's own compiled decode: capture the arguments of a real
-    # step and read the program it ran back from the jit cache.
+    # step (their shapes: the call consumes the pools) and read the
+    # program it ran back from the jit cache.
+    import jax
+
     calls = []
     decode = eng._decode_fn
-    eng._decode_fn = lambda *a: calls.append(a) or decode(*a)
+
+    def shapes(args):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding), args)
+
+    eng._decode_fn = lambda *a: calls.append(shapes(a)) or decode(*a)
     eng.generate([[1, 2, 3, 4, 5], [6, 7, 8]],
                  SamplingParams(max_new_tokens=2))
     eng._decode_fn = decode

@@ -17,8 +17,10 @@ Flagged in the KV shipping seams (disagg, prefix router, serving):
   flagged);
 - whole-pool gathers: ``asarray``/``ascontiguousarray``/``array``/
   ``device_get`` applied to a bare ``<x>.k``/``<x>.v`` pool attribute
-  or to a single subscript of one (``cache.k[li]`` is a full layer of
-  pages; page reads subscript twice);
+  (or an export's ``<x>.held_k``/``<x>.held_v``, its own copy of the
+  pages it pinned) or to a single subscript of one (``cache.k[li]`` is
+  a full layer, ``[num_pages, page_size, kv_heads * head_dim]``; page
+  reads subscript twice);
 - ``join`` on a ``bytes``/``bytearray`` literal or constructor
   (assembling a stream on the heap instead of staging at offset);
 - ``pickle.dumps`` / ``cloudpickle.dumps`` (KV never rides pickle).
@@ -37,7 +39,7 @@ from raytpu.analysis.core import Rule, register
 _SANCTION = "kv-ship-ok:"
 
 _GATHERERS = ("asarray", "ascontiguousarray", "array", "device_get")
-_POOL_ATTRS = ("k", "v")
+_POOL_ATTRS = ("k", "v", "held_k", "held_v")
 
 
 def _line_sanctioned(mod, lineno: int) -> bool:
@@ -56,9 +58,11 @@ def _is_bytes_joiner(node: ast.expr) -> bool:
 
 
 def _is_pool_ref(node: ast.expr) -> bool:
-    """``<x>.k`` / ``<x>.v`` (the whole pool list) or one subscript of
-    it (``cache.k[li]``: every page of a layer). Two subscripts deep is
-    a single page — the sanctioned streaming grain."""
+    """``<x>.k`` / ``<x>.v`` (the whole pool list; likewise an export's
+    ``held_k`` / ``held_v``) or one subscript of it (``cache.k[li]``:
+    every page of a layer). Two subscripts deep is a single page,
+    ``[page_size, kv_heads * head_dim]`` — the sanctioned streaming
+    grain."""
     if isinstance(node, ast.Attribute) and node.attr in _POOL_ATTRS:
         return True
     return (isinstance(node, ast.Subscript)

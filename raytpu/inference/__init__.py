@@ -16,8 +16,9 @@ request batching).
 Layout:
 
 - :mod:`raytpu.inference.kv_cache` — paged KV cache: fixed-size pages
-  preallocated as ``[num_pages, page_size, kv_heads, head_dim]`` JAX
-  arrays (one per layer), per-sequence block tables with per-page
+  preallocated as ``[num_pages, page_size, kv_heads * head_dim]`` JAX
+  arrays (one per layer, written in place by the engine's programs,
+  which are given them donated), per-sequence block tables with per-page
   refcounts (shared prefix pages), allocate / allocate_shared /
   extend / free, utilization accounting. Decode never reallocates.
 - :mod:`raytpu.inference.prefix_cache` — content-hash prompt-page

@@ -13,9 +13,12 @@ engine untouched end to end:
   local :class:`~raytpu.inference.prefix_cache.PrefixCache` (prefilled
   on demand). ``begin`` pins those pages by grafting them into a dummy
   *pin sequence* via ``allocate_shared`` — the retainer protocol then
-  guarantees they cannot be evicted mid-stream — and serves chunk reads
-  as per-page host views. One page comes to host at a time (the
-  streaming grain); the pool is never flattened (lint rule RTP020).
+  guarantees they cannot be evicted mid-stream — gathers them, on the
+  device, into arrays of the export's own (an engine step consumes the
+  pool arrays it is given, so a lock-free reader cannot hold on to
+  those), and serves chunk reads as per-page host views of that copy.
+  One page comes to host at a time (the streaming grain); the pool is
+  never flattened (lint rule RTP020).
 - **Sink** (decode replica): allocates its own pin sequence, stages
   incoming chunks at their wire offset in a final-size host region
   (out-of-order safe, coverage-verified — the r11 receive discipline),
@@ -78,6 +81,10 @@ class _Export:
     page_bytes: int
     total_bytes: int
     opened: float
+    # The pinned pages as they were at ``begin``, a layer each:
+    # ``[len(page_ids), page_size, kv_heads * head_dim]`` on the device.
+    held_k: List[Any]
+    held_v: List[Any]
     # (segment index, backing array, byte view) of the segment served
     # last — chunk reads walk segments in order, so one entry suffices.
     seg_cache: Optional[Tuple[int, Any, memoryview]] = field(default=None)
@@ -89,9 +96,10 @@ class KVHandoffSource:
 
     Locking contract: ``begin``/``end``/``abort_all``/``sweep`` mutate
     the engine's page bookkeeping and must run under the deployment's
-    engine lock. ``read`` only touches pinned (immutable) pages and the
-    internal export table, so it runs lock-free — a slow stream never
-    blocks the stepping loop.
+    engine lock. ``read`` only touches the export's own copy of its
+    pinned (immutable) pages and the internal export table, so it runs
+    lock-free — a slow stream never blocks the stepping loop, and a
+    step that consumes the pool arrays takes nothing from under it.
     """
 
     def __init__(self, engine):
@@ -137,11 +145,15 @@ class KVHandoffSource:
                       * np.dtype(cache.dtype).itemsize)
         total = cache.num_layers * 2 * len(pages) * page_bytes
         hid = uuid.uuid4().hex
+        idx = np.asarray(pages, dtype=np.int32)
+        export = _Export(
+            handoff_id=hid, pin_id=pin_id, page_ids=list(pages),
+            page_bytes=page_bytes, total_bytes=total,
+            opened=time.monotonic(),
+            held_k=[layer[idx] for layer in cache.k],
+            held_v=[layer[idx] for layer in cache.v])
         with self._lock:
-            self._exports[hid] = _Export(
-                handoff_id=hid, pin_id=pin_id, page_ids=list(pages),
-                page_bytes=page_bytes, total_bytes=total,
-                opened=time.monotonic())
+            self._exports[hid] = export
         return {
             "handoff_id": hid,
             "num_pages": len(pages),
@@ -160,8 +172,8 @@ class KVHandoffSource:
 
         Layout: ``[layer][k|v][page]`` segments of ``page_bytes`` each.
         Chunks are sliced from per-page host views — page-granular, so
-        a sharded (tensor-parallel) pool device-gathers at most one
-        page per view, never the pool.
+        a sharded (tensor-parallel) export device-gathers at most one
+        page per view, never a layer's.
         """
         failpoint("disagg.read_chunk")
         with self._lock:
@@ -190,9 +202,9 @@ class KVHandoffSource:
         n = len(ex.page_ids)
         layer, rest = divmod(seg, 2 * n)
         kind, pidx = divmod(rest, n)
-        pool = self.engine.cache.k if kind == 0 else self.engine.cache.v
+        held = ex.held_k if kind == 0 else ex.held_v
         arr = np.ascontiguousarray(
-            np.asarray(pool[layer][ex.page_ids[pidx]])).view(np.uint8)
+            np.asarray(held[layer][pidx])).view(np.uint8)
         view = memoryview(arr.reshape(-1))
         ex.seg_cache = (seg, arr, view)
         return view
@@ -339,9 +351,10 @@ class KVHandoffSink:
         eng = self.engine
         cache = eng.cache
         n = int(self._meta["num_pages"])
+        # The wire's [.., kv_heads, head_dim] pages are a pool's rows.
         staged = self._buf.view(np.dtype(cache.dtype)).reshape(
-            cache.num_layers, 2, n, eng.page_size, cache.num_kv_heads,
-            cache.head_dim)
+            cache.num_layers, 2, n, eng.page_size,
+            cache.num_kv_heads * cache.head_dim)
         idx = jnp.asarray(np.asarray(self._pages, dtype=np.int32))
         for li in range(cache.num_layers):
             cache.k[li] = cache.k[li].at[idx].set(

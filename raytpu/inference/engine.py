@@ -5,6 +5,12 @@ The TPU compile-once discipline, concretely:
 - **Prefill** pads each prompt to the smallest length *bucket* (powers
   of two up to ``max_model_len``) and runs one sequence at a time, so
   XLA sees one program per bucket regardless of prompt length.
+- **The KV pools** (``self.cache.k`` / ``.v``: one
+  ``[num_pages, page_size, kv_heads * head_dim]`` array a layer) are
+  given to all three programs *donated*. A program writes its new rows
+  into the buffers it received and returns them, so the call consumes
+  the arrays it was passed and every call site rebinds ``cache.k`` /
+  ``cache.v`` on its next line, before anything else can read the cache.
 - **Decode** pads the batch to the smallest batch *bucket* (powers of
   two up to ``max_num_seqs``). Tokens/positions/slots/block tables are
   data, not shapes, so changing batch *composition* never recompiles —
@@ -74,6 +80,11 @@ class StepOutput:
     finish_reason: Optional[str] = None
 
 
+# Where ``ks`` and ``vs`` stand in the three programs' arguments: given
+# donated, so each program updates the pools in the buffers it received.
+_POOLS = (1, 2)
+
+
 def _pow2_buckets(lo: int, hi: int) -> List[int]:
     out = []
     b = lo
@@ -108,7 +119,14 @@ class InferenceEngine:
     array. The tree given is not kept: float32 weights under bf16
     compute cost half their bytes once the caller lets go of them, and
     a caller that wants the original keeps it. ``stats()["param_bytes"]``
-    has the copy's bytes by dtype.
+    has the copy's bytes by dtype, ``stats()["kv_pool_bytes"]`` the
+    bytes of the 2 x layers KV pools.
+
+    A program that fails while it runs (not while it is traced or
+    compiled) has consumed the pools it was given and returned none:
+    ``step()`` raises and the engine is left without a cache. There is
+    nothing to resume from; the serve replica's loop logs the error,
+    ends every stream with it and stops (``LLMDeployment._step_loop``).
     """
 
     def __init__(self, model_config, params, *, page_size: int = 16,
@@ -183,7 +201,8 @@ class InferenceEngine:
             model_config.n_layer, num_pages, page_size, kv_heads, head_dim,
             dtype=model_config.dtype)
         # Tensor parallelism: shard the weights with the parallel-layer
-        # rule table and the KV pools along the kv-head axis. Each jit
+        # rule table and the KV pools along their last dimension, whole
+        # heads to a shard (a head's features are contiguous). Each jit
         # site then compiles to one SPMD program. XLA partitions
         # everything in it but the attention kernels, which it cannot;
         # those run once per shard over their slice of the heads
@@ -208,12 +227,18 @@ class InferenceEngine:
                     f"n_kv_head={kv_heads} not divisible by tp={tp_size}")
             self._params = shard_params(self._params, self.mesh)
             self._kv_sharding = NamedSharding(
-                self.mesh, PartitionSpec(None, None, "tp", None))
+                self.mesh, PartitionSpec(None, None, "tp"))
             self._repl_sharding = NamedSharding(self.mesh, PartitionSpec())
             self.cache.k = [jax.device_put(a, self._kv_sharding)
                             for a in self.cache.k]
             self.cache.v = [jax.device_put(a, self._kv_sharding)
                             for a in self.cache.v]
+        # What stats() says of the pools, read once: a step in flight
+        # has consumed the arrays another thread would look at.
+        self._kv_pool_bytes = sum(
+            a.nbytes for a in self.cache.k + self.cache.v)
+        self._devices = sorted(f"{d.platform}:{d.id}"
+                               for d in self.cache.k[0].devices())
         self.prefix_cache = (PrefixCache(self.cache)
                              if enable_prefix_cache else None)
         self.scheduler = Scheduler(self.cache, max_num_seqs=max_num_seqs,
@@ -276,6 +301,7 @@ class InferenceEngine:
         compiles = self._prefill_compiles
         kv_sh = self._kv_sharding
         routed = self._expert_tokens is not None
+        from raytpu.ops.paged_attention import scatter_kv_slots
 
         def _prefill(params, ks, vs, tokens, dests):
             # Trace-time only: counts XLA compiles per length bucket.
@@ -286,13 +312,11 @@ class InferenceEngine:
                 from raytpu.models.llama import live_rows
                 live["live"] = live_rows(dests, ks[0])[None]
             logits, new_k, new_v, *experts = fwd(cfg, params, tokens, **live)
-            flat = ks[0].shape[0] * ks[0].shape[1]
-            ks2, vs2 = [], []
-            for kc, vc, nk, nv in zip(ks, vs, new_k, new_v):
-                ks2.append(kc.reshape((flat,) + kc.shape[2:]).at[dests].set(
-                    nk[0].astype(kc.dtype)).reshape(kc.shape))
-                vs2.append(vc.reshape((flat,) + vc.shape[2:]).at[dests].set(
-                    nv[0].astype(vc.dtype)).reshape(vc.shape))
+            # The prompt's K and V, [1, T, KV, D] a layer, as T pool rows.
+            ks2 = [scatter_kv_slots(kc, dests, nk.reshape(bucket, -1))
+                   for kc, nk in zip(ks, new_k)]
+            vs2 = [scatter_kv_slots(vc, dests, nv.reshape(bucket, -1))
+                   for vc, nv in zip(vs, new_v)]
             if kv_sh is not None:
                 # Pin the pool sharding through the update: the pools
                 # must come back kv-head-sharded, never resharded.
@@ -302,7 +326,7 @@ class InferenceEngine:
                        for x in vs2]
             return (logits[0], ks2, vs2, *experts)
 
-        return jax.jit(_prefill)
+        return jax.jit(_prefill, donate_argnums=_POOLS)
 
     def _build_chunk_prefill_fn(self, jax):
         cfg, fwd = self._config, self._chunk_fwd
@@ -323,7 +347,7 @@ class InferenceEngine:
                        for x in vs2]
             return (logits, ks2, vs2, *experts)
 
-        return jax.jit(_chunk)
+        return jax.jit(_chunk, donate_argnums=_POOLS)
 
     def _build_decode_fn(self, jax):
         cfg, fwd = self._config, self._decode_fwd
@@ -346,7 +370,7 @@ class InferenceEngine:
                        for x in vs2]
             return (logits, ks2, vs2, *experts)
 
-        return jax.jit(_decode)
+        return jax.jit(_decode, donate_argnums=_POOLS)
 
     def _put(self, x):
         """Host array → device input. Under a tp mesh, inputs are
@@ -765,8 +789,9 @@ class InferenceEngine:
             # Bytes of the tree the programs take, by dtype, over all
             # shards: all in the compute type but the norms' leaves.
             "param_bytes": dict(self._param_bytes),
-            "devices": sorted(f"{d.platform}:{d.id}"
-                              for d in self.cache.k[0].devices()),
+            # Bytes of the 2 x layers KV pools, over all shards.
+            "kv_pool_bytes": self._kv_pool_bytes,
+            "devices": list(self._devices),
             "num_preemptions": self.scheduler.num_preemptions,
             "running": len(self.scheduler.running),
             "waiting": len(self.scheduler.waiting),

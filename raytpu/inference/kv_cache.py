@@ -1,7 +1,14 @@
 """Paged KV cache (reference analogue: vLLM's PagedAttention, SOSP '23).
 
 The cache for every layer is ONE preallocated JAX array shaped
-``[num_pages, page_size, kv_heads, head_dim]`` (one for K, one for V).
+``[num_pages, page_size, kv_heads * head_dim]`` (one for K, one for V):
+a token's K (or V) is one row, its heads side by side as the projection
+wrote them. That is the one shape every reader and writer of a pool
+takes: the paged kernel blocks it as it is, a new token's row is written
+where it lies (``ops.paged_attention.scatter_kv_slots``), and its two
+minor dimensions tile on the TPU with next to no padding (GPT-2 XL's
+1600 features on 1664 lanes; a ``[.., 25, 64]`` minor pair pads by a
+third, and every program relaid it twice a step: PERF.md, PR 27).
 Sequences own pages through a *block table* — an ordered list of page
 ids — so a sequence's logical position ``p`` lives at flat slot
 ``table[p // page_size] * page_size + p % page_size``. Growing a
@@ -9,7 +16,10 @@ sequence by one token allocates at most one page; freeing returns the
 pages to a stack. Nothing is ever reallocated or compacted, which is
 the property the TPU decode step needs: the jitted program sees the
 same cache buffers every iteration and only the (tiny, host-built)
-block tables change.
+block tables change. The engine's three programs are given the pools
+*donated*: they write the new rows into the buffers they received and
+hand those back, so ``k`` and ``v`` are rebound after every call and an
+array read out of them before a step is deleted after it.
 
 Page 0 is reserved as *scratch*: it is never handed to a sequence, and
 every padded slot in a bucketed prefill or dummy row in a padded decode
@@ -48,7 +58,9 @@ class PagedKVCache:
         page_size: tokens per page.
         num_kv_heads: KV heads per token (``n_kv_head`` for GQA Llama,
             ``n_head`` for MHA GPT-2).
-        head_dim: per-head feature dim.
+        head_dim: per-head feature dim. A pool's last dimension is
+            ``num_kv_heads * head_dim``, head ``i`` on features
+            ``[i * head_dim, (i + 1) * head_dim)``.
         dtype: cache array dtype (the model's activation dtype).
     """
 
@@ -66,7 +78,7 @@ class PagedKVCache:
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
         self.dtype = dtype or jnp.float32
-        shape = (num_pages, page_size, num_kv_heads, head_dim)
+        shape = (num_pages, page_size, num_kv_heads * head_dim)
         self.k: List = [jnp.zeros(shape, self.dtype) for _ in range(num_layers)]
         self.v: List = [jnp.zeros(shape, self.dtype) for _ in range(num_layers)]
         # LIFO free list over pages 1..num_pages-1 (0 is scratch).

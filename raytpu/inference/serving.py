@@ -271,6 +271,8 @@ class LLMDeployment:
                 except Exception as e:
                     # The step's record and span carry the error; a dead
                     # loop must not leave its streams waiting for ever.
+                    # There is no step to retry: a program that failed
+                    # while it ran has consumed the KV pools it was given.
                     logger.exception("engine step failed; ending %d "
                                      "stream(s)", len(self._live))
                     self._error = e

@@ -121,15 +121,13 @@ class CausalSelfAttention(nn.Module):
         d = e // h
         qkv = self.c_attn(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
-        k_cache = k.reshape(b, t, h, d)[0]  # [T, H, D]
-        v_cache = v.reshape(b, t, h, d)[0]
         from raytpu.ops.paged_attention import (paged_attention,
                                                 scatter_kv_slots)
 
-        k_pages = scatter_kv_slots(k_pages, dests, k_cache)
-        v_pages = scatter_kv_slots(v_pages, dests, v_cache)
-        o = paged_attention(q.transpose(0, 2, 1, 3), k_pages, v_pages,
+        # A pool row is a token's K (or V) as c_attn wrote it: [T, E].
+        k_pages = scatter_kv_slots(k_pages, dests, k[0])
+        v_pages = scatter_kv_slots(v_pages, dests, v[0])
+        o = paged_attention(q.reshape(b, t, h, d), k_pages, v_pages,
                             block_tables, positions[None, :],
                             force=c.paged_attn)
         y = o.reshape(b, t, e)
@@ -150,8 +148,8 @@ class CausalSelfAttention(nn.Module):
         from raytpu.ops.paged_attention import (paged_attention,
                                                 scatter_kv_slots)
 
-        k_pages = scatter_kv_slots(k_pages, dests, k.reshape(b, h, d))
-        v_pages = scatter_kv_slots(v_pages, dests, v.reshape(b, h, d))
+        k_pages = scatter_kv_slots(k_pages, dests, k)  # rows [B, E]
+        v_pages = scatter_kv_slots(v_pages, dests, v)
         # The token at position p sees slots 0..p = 0..context_lens-1.
         o = paged_attention(q[:, None], k_pages, v_pages, block_tables,
                             (context_lens - 1)[:, None],

@@ -209,8 +209,9 @@ class LlamaAttention(nn.Module):
         cos, sin = rope_tables(d, positions, c.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        k_cache = k.transpose(0, 2, 1, 3)[0]  # [T, KV, D]
-        v_cache = v.transpose(0, 2, 1, 3)[0]
+        # Rows as the pools hold them: a token's heads side by side.
+        k_cache = k.transpose(0, 2, 1, 3).reshape(t, kv * d)
+        v_cache = v.transpose(0, 2, 1, 3).reshape(t, kv * d)
         from raytpu.ops.paged_attention import (paged_attention,
                                                 scatter_kv_slots)
 
@@ -230,7 +231,7 @@ class LlamaAttention(nn.Module):
 
         Args:
             x: [B, E] current-token hidden states.
-            k_pages / v_pages: [num_pages, page_size, KV, D] cache.
+            k_pages / v_pages: [num_pages, page_size, KV * D] cache.
             dests: [B] flat slots where this token's K/V is written.
             block_tables: [B, P] page ids per sequence (0-padded; page
                 0 is scratch so padding attends to masked garbage only).
@@ -251,8 +252,8 @@ class LlamaAttention(nn.Module):
         from raytpu.ops.paged_attention import (paged_attention,
                                                 scatter_kv_slots)
 
-        k_pages = scatter_kv_slots(k_pages, dests, k)
-        v_pages = scatter_kv_slots(v_pages, dests, v)
+        k_pages = scatter_kv_slots(k_pages, dests, k.reshape(b, kv * d))
+        v_pages = scatter_kv_slots(v_pages, dests, v.reshape(b, kv * d))
         # The token at position p sees slots 0..p = 0..context_lens-1.
         o = paged_attention(q[:, None], k_pages, v_pages, block_tables,
                             (context_lens - 1)[:, None],

@@ -113,7 +113,7 @@ def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-def per_shard(fn, args, in_layouts, out_layouts):
+def per_shard(fn, args, in_layouts, out_layouts, *, heads=()):
     """Call ``fn(*args)``, a function built on Mosaic kernels, from a
     program sharded over the mesh set with ``jax.set_mesh``.
 
@@ -126,6 +126,9 @@ def per_shard(fn, args, in_layouts, out_layouts):
     ``b`` batch, ``h`` heads, ``.`` neither (``"bh.."`` is
     ``[B, H, T, D]``). ``out_layouts`` mirrors ``fn``'s result. With no
     mesh set, or inside a ``shard_map`` already, ``fn`` is called as is.
+    ``heads`` are head counts that ``tp`` must divide as well: of an
+    array whose ``h`` dimension holds each head's features side by side
+    (a KV pool's rows), so that a shard takes whole heads.
     """
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1 or mesh.manual_axes:
@@ -137,6 +140,8 @@ def per_shard(fn, args, in_layouts, out_layouts):
         for dim, kind in enumerate(layout):
             if x.shape[dim] % math.prod(size[a] for a in axes[kind]):
                 axes[kind] = ()
+    if any(n % math.prod(size[a] for a in axes["h"]) for n in heads):
+        axes["h"] = ()
 
     def spec(layout):
         return PartitionSpec(*(axes[kind] or None for kind in layout))

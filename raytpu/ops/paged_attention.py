@@ -14,15 +14,17 @@ kernel that reads KV pages **in place**, vLLM-PagedAttention style:
   scalar-prefetch operands: the k/v BlockSpec index maps translate the
   page-grid coordinate through the block table, so each step DMAs one
   ``[page_size, group_lanes]`` tile straight out of the pool.
-- a pool row is read flattened to ``[kv_heads * head_dim]`` and a block
+- a pool is ``[num_pages, page_size, kv_heads * head_dim]``, a token's
+  heads side by side in one row, and is blocked as it is held: a block
   takes kv heads that fill whole 128-lane tiles (two or more at
   ``head_dim`` 64, one or more at 128: as many as keep the block's
   query rows inside one pass of the matrix unit, so all of them in a
   decode step and the fewest in a prompt's chunk), because Mosaic has
-  no block of one head out of a ``[kv_heads, head_dim]`` minor pair. The heads of a group
-  share the lane axis: each query row is zero outside its own head's
-  lanes, one dense product scores every head, and the wrapper keeps
-  each row's own lanes of the result. The MXU does ``group`` times the
+  no block of one head out of a ``[kv_heads, head_dim]`` minor pair
+  (which is also why no pool is held 4-D). The heads of a group share
+  the lane axis: each query row is zero outside its own head's lanes,
+  one dense product scores every head, and the wrapper keeps each
+  row's own lanes of the result. The MXU does ``group`` times the
   needed work; the pool bytes read, which bound decode, do not change.
 - pages past a sequence's live length are *clamped* to the last live
   page in the index map — the Mosaic pipeline sees the same block again
@@ -32,7 +34,8 @@ kernel that reads KV pages **in place**, vLLM-PagedAttention style:
   group's kv heads.
 - pages may be bf16; scores and accumulators are fp32.
 - under a mesh (``jax.set_mesh``) the call runs per shard, the pools
-  split on the kv-head axis (``per_shard``).
+  split on their last dimension, in which a head's features are
+  contiguous (``per_shard``).
 
 Like :mod:`raytpu.ops.flash_attention` this ships a sanctioned dense
 reference (`paged_attention_reference`, the ONE place a materializing
@@ -143,17 +146,22 @@ def resolve_paged_impl(selector=None) -> str:
 
 def scatter_kv_slots(pages: jax.Array, dests: jax.Array,
                      rows: jax.Array) -> jax.Array:
-    """``pages`` ``[num_pages, page_size, kv_heads, head_dim]`` with
-    ``rows`` ``[N, kv_heads, head_dim]`` written at the flat slots
+    """``pages`` ``[num_pages, page_size, kv_heads * head_dim]`` with
+    ``rows`` ``[N, kv_heads * head_dim]`` written at the flat slots
     ``dests`` ``[N]`` (``page * page_size + offset``, as
-    ``PagedKVCache.slot`` gives them; padding rows name page 0).
+    ``PagedKVCache.slot`` gives them; padding rows name page 0). The
+    one way a pool is written: the prefill, the chunked prefill and
+    the decode step all come here, with the rows as the K and V
+    projections produce them.
 
     Indexed by (page, offset) on the pool as it is, not through a
     reshape to ``[num_pages * page_size, ...]`` and back: on the TPU a
     pool lives in a layout in which that reshape is a copy of the whole
     pool into a padded form, and a decode program that made it kept all
     its layers' padded copies to its end (4.5 GB of scratch for GPT-2
-    XL's 1.9 GB of pools; PERF.md, PR 25).
+    XL's 1.9 GB of pools; PERF.md, PR 25). Inside a program that was
+    given ``pages`` donated (the engine's three are) the rows are
+    written into the buffer that came in.
     """
     page_size = pages.shape[1]
     return pages.at[dests // page_size, dests % page_size].set(
@@ -165,15 +173,17 @@ def scatter_kv_slots(pages: jax.Array, dests: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def gather_kv_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
+def gather_kv_pages(pages: jax.Array, block_tables: jax.Array,
+                    head_dim: int) -> jax.Array:
     """Materialize ``[B, P*page_size, kv_heads, head_dim]`` from the
-    page pool.  This is the ONE sanctioned home of the
-    ``pages[block_tables]`` gather; RTP011 bans the pattern from
-    ``raytpu/models/`` and ``raytpu/inference/``.
+    page pool ``[num_pages, page_size, kv_heads * head_dim]``; the heads
+    are split after the gather, on the copy.  This is the ONE sanctioned
+    home of the ``pages[block_tables]`` gather; RTP011 bans the pattern
+    from ``raytpu/models/`` and ``raytpu/inference/``.
     """
     b = block_tables.shape[0]
-    _, _, kv, d = pages.shape
-    return pages[block_tables].reshape(b, -1, kv, d)
+    return pages[block_tables].reshape(b, -1, pages.shape[2] // head_dim,
+                                       head_dim)
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables, positions,
@@ -184,9 +194,9 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, positions,
     additive-free masking via where, jax.nn.softmax) exactly so
     fallback greedy generation is unchanged."""
     b, t, h, d = q.shape
-    kv = k_pages.shape[2]
-    ks = gather_kv_pages(k_pages, block_tables)
-    vs = gather_kv_pages(v_pages, block_tables)
+    ks = gather_kv_pages(k_pages, block_tables, d)
+    vs = gather_kv_pages(v_pages, block_tables, d)
+    kv = ks.shape[2]
     if kv != h:
         rep = h // kv
         ks = jnp.repeat(ks, rep, axis=2)
@@ -313,9 +323,13 @@ def _kv_heads_per_block(kv: int, d: int, rows_per_head: int = _MXU_ROWS
 def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
                   *, sm_scale, interpret):
     b, t, h, d = q.shape
-    n_pages, page_size, kv, _ = k_pages.shape
-    if h % kv:
-        raise ValueError(f"heads ({h}) not a multiple of kv_heads ({kv})")
+    # The pools as they are held: [num_pages, page_size, kv * d].
+    _, page_size, width = k_pages.shape
+    kv = width // d
+    if kv * d != width or h % kv:
+        raise ValueError(
+            f"a pool row of {width} features does not hold kv heads of "
+            f"{d} that divide the {h} query heads")
     rep = h // kv
     n_pg = block_tables.shape[1]
     bq_t = _fit_q_block(t, _env_block("RAYTPU_PAGED_BLOCK_Q", 256))
@@ -339,8 +353,6 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
     qg = (qg[..., None, :] * own).reshape(b, n_grp, n_qb, live_rows, lanes)
     qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, rows - live_rows), (0, 0)))
     qg = qg.reshape(b, n_grp, n_qb * rows, lanes)
-    k_rows = k_pages.reshape(n_pages, page_size, kv * d)
-    v_rows = v_pages.reshape(n_pages, page_size, kv * d)
     q_start = positions[:, 0].astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
 
@@ -388,7 +400,7 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=interpret,
         **kwargs,
-    )(block_tables, q_start, qg, k_rows, v_rows)
+    )(block_tables, q_start, qg, k_pages, v_pages)
     # Keep each row's own head's lanes (the diagonal of the two
     # head-in-group axes) and undo the fold.
     out = out.reshape(b, n_grp, n_qb, rows, lanes)[:, :, :, :live_rows]
@@ -403,8 +415,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions, *,
 
     Args:
       q: ``[B, T, H, D]`` queries (decode: T=1; chunked prefill: B=1).
-      k_pages / v_pages: ``[num_pages, page_size, kv_heads, head_dim]``
-        page pools (may be bf16).
+      k_pages / v_pages: ``[num_pages, page_size, kv_heads * head_dim]``
+        page pools (may be bf16), as ``PagedKVCache`` holds them;
+        ``kv_heads`` is the row's width over ``q``'s ``D``.
       block_tables: ``[B, P]`` int page ids per sequence; dead columns
         may hold any valid page id (page 0 scratch by convention).
       positions: ``[B, T]`` absolute position of each query token; a
@@ -426,9 +439,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions, *,
             q, k_pages, v_pages, block_tables, positions,
             sm_scale=sm_scale)
     # Per shard under a mesh: q and the result [B, T, H, D], the pools
-    # [pages, page_size, KV, D], tables and positions [B, ...].
+    # [pages, page_size, KV * D] (whole heads to a shard), tables and
+    # positions [B, ...].
     return per_shard(
         functools.partial(_paged_pallas, sm_scale=sm_scale,
                           interpret=(impl == "interpret")),
         (q, k_pages, v_pages, block_tables, positions),
-        ("b.h.", "..h.", "..h.", "b.", "b."), "b.h.")
+        ("b.h.", "..h", "..h", "b.", "b."), "b.h.",
+        heads=(k_pages.shape[2] // q.shape[3],))

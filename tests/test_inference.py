@@ -60,7 +60,10 @@ class TestPagedKVCache:
 
     def test_layout_and_accounting(self):
         c = self.make()
-        assert c.k[0].shape == (9, 4, 2, 8) and len(c.k) == 2
+        # A token's two heads of 8 side by side in one row of 16.
+        assert c.k[0].shape == (9, 4, 2 * 8) and len(c.k) == 2
+        assert c.v[1].shape == c.k[0].shape and c.k[0].dtype == jnp.float32
+        assert (c.num_kv_heads, c.head_dim) == (2, 8)
         assert c.total_pages == 8 and c.free_pages() == 8
         assert c.pages_for(1) == 1 and c.pages_for(4) == 1
         assert c.pages_for(5) == 2 and c.pages_for(0) == 0
@@ -613,7 +616,7 @@ class TestServingParams:
         kv = getattr(cfg, "n_kv_head", cfg.n_head)
         rng = np.random.default_rng(0)
         pools = [[jnp.asarray(rng.standard_normal(
-            (5, 8, kv, cfg.n_embd // cfg.n_head)), cfg.dtype)
+            (5, 8, kv * (cfg.n_embd // cfg.n_head))), cfg.dtype)
             for _ in range(cfg.n_layer)] for _ in range(2)]
         tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (1, 16)),
                              jnp.int32)
@@ -739,6 +742,169 @@ class TestServingParams:
         assert eng.stats()["param_bytes"] == InferenceEngine(
             cfg, params, page_size=8, max_num_seqs=2,
             max_model_len=32).stats()["param_bytes"]
+
+
+# ---------------------------------------------------------------------------
+# The pools: one [pages, page_size, kv_heads * head_dim] array a layer,
+# given donated to the three programs and written where they lie.
+# ---------------------------------------------------------------------------
+
+POOL_ENGINE = dict(page_size=4, num_pages=19, max_num_seqs=4,
+                   max_model_len=32, prefill_chunk=8)
+
+
+def _pool_engine(family, impl="reference", **kw):
+    cfg, model_cls, init, _ = BF16[family]
+    cfg = dataclasses.replace(cfg, paged_attn=impl)
+    params = init(model_cls(cfg), cfg, seed=0, batch=1)
+    return InferenceEngine(cfg, params, **dict(POOL_ENGINE, **kw))
+
+
+class TestPoolsWrittenInPlace:
+    @pytest.mark.parametrize("impl", ["reference", "interpret"])
+    @pytest.mark.parametrize("family", sorted(BF16))
+    def test_programs_take_every_pool_donated_and_relay_none(
+            self, family, impl):
+        """In each lowered program all 2 x layers pool arguments, and no
+        other, may be written in place, and no ``reshape`` or ``transpose``
+        touches a tensor of a pool's shape."""
+        from kv_pool_4d import pool_facts
+
+        eng = _pool_engine(family, impl)
+        i32, ks, vs = jnp.int32, eng.cache.k, eng.cache.v
+        assert ks[0].shape == (19, 4, eng._config.n_embd
+                               // eng._config.n_head * eng.cache.num_kv_heads)
+        programs = {
+            "prefill": eng._prefill_fn.lower(
+                eng._params, ks, vs, jnp.zeros((1, 16), i32),
+                jnp.zeros((16,), i32)),
+            "chunk": eng._chunk_fn.lower(
+                eng._params, ks, vs, jnp.zeros((1, 8), i32),
+                jnp.zeros((8,), i32), jnp.zeros((8,), i32),
+                jnp.zeros((1, 2), i32)),
+            "decode": eng._decode_fn.lower(
+                eng._params, ks, vs, jnp.zeros((4,), i32),
+                jnp.zeros((4,), i32), jnp.zeros((4,), i32),
+                jnp.zeros((4, 2), i32), jnp.ones((4,), i32)),
+        }
+        for name, lowered in programs.items():
+            facts = pool_facts(eng, lowered.as_text())
+            pools = 2 * eng.cache.num_layers
+            assert facts["pool_args"] == pools, (name, facts)
+            assert facts["donated"] == facts["donated_pools"] == pools, \
+                (name, facts)
+            assert not facts["relaid"], (name, facts["relaid"])
+        # Nothing ran: lowering consumes no pool.
+        assert not any(a.is_deleted() for a in ks + vs)
+
+    def test_the_check_above_sees_a_relaid_and_an_undonated_pool(self):
+        from kv_pool_4d import pool_facts
+
+        eng = _pool_engine("gpt2")
+        pool = eng.cache.k[0]
+
+        def relay(ks):
+            return [k.reshape(19 * 4, -1).reshape(k.shape) + 1 for k in ks]
+
+        facts = pool_facts(eng, jax.jit(relay).lower([pool, pool]).as_text())
+        assert facts["pool_args"] == 2 and facts["donated"] == 0
+        assert len(facts["relaid"]) == 4  # into the flat view and back, twice
+
+    @pytest.mark.parametrize("family", sorted(BF16))
+    def test_a_step_consumes_the_pools_it_was_given(self, family):
+        """Whole prefill, chunked prefill and decode: the arrays that were
+        ``cache.k`` / ``cache.v`` before a step are deleted after it, and
+        the lists hold live arrays of the same shape."""
+        eng = _pool_engine(family)
+        eng.add_request("whole", [1, 2, 3, 4, 5],
+                        SamplingParams(max_new_tokens=3))
+        eng.add_request("chunked", list(range(1, 20)),
+                        SamplingParams(max_new_tokens=3))
+        ran = set()
+        while eng.has_unfinished():
+            before = eng.cache.k + eng.cache.v
+            eng.step()
+            record = eng.step_log()["steps"][-1]
+            ran |= {name for name, _, _ in record["phases"]}
+            assert all(a.is_deleted() for a in before), record["phases"]
+            now = eng.cache.k + eng.cache.v
+            assert len(now) == 2 * eng.cache.num_layers
+            assert not any(a.is_deleted() for a in now)
+            assert {a.shape for a in now} == {before[0].shape}
+        assert {"infer.prefill", "infer.prefill_chunk", "infer.decode"} <= ran
+
+    def test_stats_count_the_pools_bytes_once(self):
+        eng = _pool_engine("llama")
+        c = eng._config
+        want = 2 * c.n_layer * 19 * 4 * c.n_kv_head * c.head_dim * 2  # bf16
+        assert eng.stats()["kv_pool_bytes"] == want
+        eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=2))
+        stats = eng.stats()  # of an engine whose first pools are gone
+        assert stats["kv_pool_bytes"] == want
+        assert stats["devices"] == [f"cpu:{jax.devices()[0].id}"]
+
+    @pytest.mark.parametrize("family", sorted(BF16))
+    def test_tp2_keeps_the_pools_split_on_their_last_dimension(self, family):
+        from jax.sharding import PartitionSpec
+
+        eng = _pool_engine(family, tp=2)
+        plain = _pool_engine(family)
+        prompts = [[1, 2, 3, 4, 5], list(range(1, 20))]
+        assert eng.generate(prompts, SamplingParams(max_new_tokens=4)) \
+            == plain.generate(prompts, SamplingParams(max_new_tokens=4))
+        width = eng.cache.num_kv_heads * eng.cache.head_dim
+        for pool in eng.cache.k + eng.cache.v:
+            assert pool.sharding.spec == PartitionSpec(None, None, "tp")
+            assert {s.data.shape for s in pool.addressable_shards} \
+                == {(19, 4, width // 2)}
+        assert eng.stats()["kv_pool_bytes"] == plain.stats()["kv_pool_bytes"]
+        # Whole heads to a shard: the same pages hold the same rows (to
+        # bf16's rounding: two shards sum a projection in another order).
+        for a, b in zip(eng.cache.k + eng.cache.v,
+                        plain.cache.k + plain.cache.v):
+            np.testing.assert_allclose(
+                np.asarray(a[1:].astype(jnp.float32)),
+                np.asarray(b[1:].astype(jnp.float32)), atol=0.06, rtol=0.02)
+
+    def test_kv_handoff_ships_the_4d_pools_bytes(self):
+        """The wire is ``[layers, K|V, pages, page_size, kv_heads,
+        head_dim]`` contiguous, as when the pools were 4-D; what is read
+        comes from the export's own copy (a step may consume the pools
+        meanwhile), and the sink's pools end with the same rows."""
+        from raytpu.inference import disagg
+
+        src = _pool_engine("llama", enable_prefix_cache=True)
+        dst = _pool_engine("llama", enable_prefix_cache=True)
+        prompt = list(range(1, 15))  # three full pages of 4 and a tail
+        src.generate([prompt], SamplingParams(max_new_tokens=1))
+        source = disagg.KVHandoffSource(src)
+        meta = source.begin(prompt)
+        cache = src.cache
+        pages = src.prefix_cache.match(prompt, max_pages=3)
+        assert meta["num_pages"] == len(pages) == 3
+        assert (meta["kv_heads"], meta["head_dim"]) == (
+            cache.num_kv_heads, cache.head_dim)
+        want = np.stack([
+            np.stack([np.asarray(pool[li])[pages].reshape(
+                3, 4, cache.num_kv_heads, cache.head_dim)
+                for pool in (cache.k, cache.v)])
+            for li in range(cache.num_layers)]).tobytes()
+        assert meta["total_bytes"] == len(want)
+        # Steps between begin and the reads consume the pools read above.
+        src.generate([[7, 8, 9]], SamplingParams(max_new_tokens=2))
+        got = b"".join(source.read(meta["handoff_id"], off,
+                                   min(100, len(want) - off))
+                       for off in range(0, len(want), 100))
+        assert got == want
+        sink = disagg.KVHandoffSink(dst)
+        assert sink.begin(meta, prompt)
+        sink.write(0, got)
+        assert sink.seal() == 3 and source.end(meta["handoff_id"])
+        landed = dst.prefix_cache.match(prompt, max_pages=3)
+        for li in range(cache.num_layers):
+            for a, b in ((src.cache.k, dst.cache.k), (src.cache.v, dst.cache.v)):
+                assert np.asarray(a[li])[pages].tobytes() \
+                    == np.asarray(b[li])[landed].tobytes()
 
 
 # ---------------------------------------------------------------------------

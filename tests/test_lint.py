@@ -979,15 +979,32 @@ class TestKVShipping:  # RTP020
         assert "pickle.dumps" in findings[1].message
 
     def test_page_granular_read_not_flagged(self):
-        # Two subscripts deep == one page: the sanctioned streaming
-        # grain (this is what disagg._segment_view actually does).
+        # Two subscripts deep == one page, [page_size, kv_heads *
+        # head_dim]: the sanctioned streaming grain. disagg._segment_view
+        # reads it from the export's own copy of its pinned pages (a step
+        # consumes the pool arrays), which the rule watches like a pool.
         assert run_rule_on_source(_rule("RTP020"), _src("""
             import numpy as np
 
             def segment(cache, layer, page):
                 return np.ascontiguousarray(
                     np.asarray(cache.k[layer][page])).view(np.uint8)
+
+            def segment_of_export(ex, kind, layer, pidx):
+                held = ex.held_k if kind == 0 else ex.held_v
+                return np.ascontiguousarray(
+                    np.asarray(held[layer][pidx])).view(np.uint8)
         """), rel="raytpu/inference/disagg.py") == []
+
+    def test_planted_gather_of_an_exports_held_layer(self):
+        findings = run_rule_on_source(_rule("RTP020"), _src("""
+            import numpy as np
+
+            def snapshot(ex):
+                return np.asarray(ex.held_k[0]), np.asarray(ex.held_v)
+        """), rel="raytpu/inference/disagg.py")
+        assert len(findings) == 2
+        assert all("whole-pool" in f.message for f in findings)
 
     def test_wire_framing_to_bytes_not_flagged(self):
         assert run_rule_on_source(_rule("RTP020"), _src("""

@@ -407,6 +407,46 @@ def test_a_bf16_tree_is_served_as_the_callers_own_arrays():
 # ---- the registry -----------------------------------------------------------------
 
 
+# ---- the pools ---------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("impl", ["reference", "interpret"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flat_donated_pools_give_the_4d_pools_bits(dtype, impl):
+    """Whole prefill, two chunks and two decode steps of the routed model
+    through the engine's programs (pools ``[pages, page_size, kv * d]``,
+    given donated) against the llama walks on the parent's 4-D pools:
+    logits and all pools, bit for bit (tests/kv_pool_4d.py)."""
+    from kv_pool_4d import check_engine_programs
+
+    c = dataclasses.replace(TINY, dtype=dtype, paged_attn=impl)
+    eng = InferenceEngine(c, model_and_params(c)[1], page_size=4,
+                          num_pages=19, max_num_seqs=4, max_model_len=32,
+                          enable_prefix_cache=False)
+    assert eng.cache.k[0].shape == (19, 4, c.n_kv_head * c.head_dim)
+    assert check_engine_programs(
+        eng, list(range(3, 14)), list(range(20, 36))) == [
+            "prefill", "chunk@0", "chunk@8", "decode#0", "decode#1"]
+
+
+def test_the_routed_programs_take_every_pool_donated_and_relay_none():
+    from kv_pool_4d import pool_facts
+
+    c = dataclasses.replace(TINY, dtype=jnp.bfloat16)
+    eng = InferenceEngine(c, model_and_params(c)[1], num_pages=19, **ENGINE)
+    for text in _lowered(eng, eng._params):
+        facts = pool_facts(eng, text)
+        assert facts["pool_args"] == 2 * c.n_layer == facts["donated_pools"]
+        assert facts["donated"] == 2 * c.n_layer and not facts["relaid"]
+    # A step returns the count beside pools written in place.
+    before = eng.cache.k + eng.cache.v
+    eng.generate([[1, 2, 3, 4, 5]], SamplingParams(max_new_tokens=2))
+    assert all(a.is_deleted() for a in before)
+    assert eng.stats()["kv_pool_bytes"] == sum(
+        a.nbytes for a in eng.cache.k + eng.cache.v)
+    assert np.asarray(eng.stats()["expert_tokens"]).sum() > 0
+
+
 def test_llm_deployment_streams_olmoe():
     dep = serve.LLMDeployment._target(model="olmoe", engine_options=ENGINE)
     try:

@@ -3,6 +3,7 @@ lowering for the TPU platform from the CPU host (what Mosaic then makes
 of it only the chip, or an AOT compile against libtpu, can say)."""
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -146,7 +147,7 @@ def _flash_loss(q, k, v):
 
 
 def _paged_shapes(b, t, h, kv, d, pages=513, page_size=16, width=64):
-    pool = jax.ShapeDtypeStruct((pages, page_size, kv, d), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((pages, page_size, kv * d), jnp.bfloat16)
     return (jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16), pool, pool,
             jax.ShapeDtypeStruct((b, width), jnp.int32),
             jax.ShapeDtypeStruct((b, t), jnp.int32))
@@ -267,7 +268,7 @@ class TestPerShard:
         b, t, h, kv, d, page, width = 2, 1, 8, 4, 32, 8, 4
         q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
         k, v = (jnp.asarray(rng.standard_normal(
-            (b * width + 1, page, kv, d)), jnp.float32) for _ in range(2))
+            (b * width + 1, page, kv * d)), jnp.float32) for _ in range(2))
         tables = jnp.asarray(
             np.arange(1, b * width + 1).reshape(b, width), jnp.int32)
         pos = jnp.asarray([[13], [30]], jnp.int32)
@@ -280,5 +281,29 @@ class TestPerShard:
                                                             "tp"))),
                     jax.device_put(k, pool_sh), jax.device_put(v, pool_sh),
                     tables, pos)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_paged_pools_are_split_by_whole_heads_only(self):
+        # 2 kv heads of 32 under tp=4: the pool's 64 features and the 4
+        # query heads both divide by 4, the kv heads do not, and a quarter
+        # of a row is half a head. The call then runs unsplit.
+        mesh = build_mesh({"tp": 4}, jax.devices()[:4])
+        rng = np.random.default_rng(4)
+        b, t, h, kv, d, page, width = 2, 1, 4, 2, 32, 8, 2
+        q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+        k, v = (jnp.asarray(rng.standard_normal(
+            (b * width + 1, page, kv * d)), jnp.float32) for _ in range(2))
+        tables = jnp.asarray(
+            np.arange(1, b * width + 1).reshape(b, width), jnp.int32)
+        pos = jnp.asarray([[5], [14]], jnp.int32)
+        ref = paged_attention(q, k, v, tables, pos, force="reference")
+        with jax.set_mesh(mesh):
+            fn = jax.jit(functools.partial(paged_attention,
+                                           force="interpret"))
+            specs = re.search(r"in_specs=\(.*?\)\)", str(
+                fn.trace(q, k, v, tables, pos).jaxpr)).group(0)
+            assert "PartitionSpec" in specs and "tp" not in specs
+            got = fn(q, k, v, tables, pos)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)

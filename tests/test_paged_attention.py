@@ -49,13 +49,14 @@ def gpt2_params():
 def _setup(rng, b, t, heads, kv, d, page_size, pages_per_seq, dtype,
            ctx=None):
     """Random pool + block tables + positions for ``b`` sequences whose
-    query tokens end at ragged context lengths."""
+    query tokens end at ragged context lengths. The pools are shaped as
+    the cache holds them: a token's ``kv`` heads side by side in a row."""
     num_pages = b * pages_per_seq + 1
     q = jnp.asarray(rng.standard_normal((b, t, heads, d)), dtype)
     k = jnp.asarray(rng.standard_normal(
-        (num_pages, page_size, kv, d)), dtype)
+        (num_pages, page_size, kv * d)), dtype)
     v = jnp.asarray(rng.standard_normal(
-        (num_pages, page_size, kv, d)), dtype)
+        (num_pages, page_size, kv * d)), dtype)
     # Distinct live pages per sequence (page 0 stays scratch).
     bt = np.arange(1, num_pages).reshape(b, pages_per_seq)
     if ctx is None:
@@ -117,14 +118,40 @@ class TestKernelNumerics:
 
     def test_gather_helper_layout(self):
         rng = np.random.default_rng(5)
-        k = jnp.asarray(rng.standard_normal((9, 4, 2, 8)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((9, 4, 2 * 8)), jnp.float32)
         bt = jnp.asarray([[3, 1], [2, 2]], jnp.int32)
-        out = gather_kv_pages(k, bt)
-        assert out.shape == (2, 8, 2, 8)
+        out = gather_kv_pages(k, bt, 8)
+        assert out.shape == (2, 8, 2, 8)  # heads split after the gather
         np.testing.assert_array_equal(np.asarray(out[0, :4]),
-                                      np.asarray(k[3]))
+                                      np.asarray(k[3]).reshape(4, 2, 8))
         np.testing.assert_array_equal(np.asarray(out[1, 4:]),
-                                      np.asarray(k[2]))
+                                      np.asarray(k[2]).reshape(4, 2, 8))
+
+    @pytest.mark.parametrize("impl", ["reference", "interpret"])
+    @pytest.mark.parametrize("heads,kv,d,t", [(4, 4, 16, 1), (8, 2, 16, 1),
+                                              (6, 3, 16, 24), (4, 4, 128, 1)])
+    def test_flat_pool_gives_the_4d_pools_bits(self, heads, kv, d, t, impl):
+        """The same attention, bit for bit, as the parent computed from
+        ``[pages, page_size, kv, d]`` pools (tests/kv_pool_4d.py)."""
+        from kv_pool_4d import attention_4d, same_bits
+
+        rng = np.random.default_rng(kv * d + t)
+        q, k, v, bt, pos = _setup(rng, b=1 if t > 1 else 3, t=t, heads=heads,
+                                  kv=kv, d=d, page_size=8, pages_per_seq=6,
+                                  dtype=jnp.float32)
+        shape4 = k.shape[:2] + (kv, d)
+        want = attention_4d(q, k.reshape(shape4), v.reshape(shape4), bt, pos,
+                            force=impl)
+        assert same_bits(paged_attention(q, k, v, bt, pos, force=impl), want)
+
+    def test_a_row_that_holds_no_whole_heads_is_refused(self):
+        rng = np.random.default_rng(0)
+        q, k, v, bt, pos = _setup(rng, b=2, t=1, heads=4, kv=2, d=16,
+                                  page_size=4, pages_per_seq=2,
+                                  dtype=jnp.float32)
+        with pytest.raises(ValueError, match="pool row of 24 features"):
+            paged_attention(q, k[..., :24], v[..., :24], bt, pos,
+                            force="interpret")
 
 
 class TestHeadsPerBlock:
@@ -165,15 +192,17 @@ class TestScatterKVSlots:
     @pytest.mark.parametrize("n", [1, 5])
     def test_equals_the_write_through_the_flat_view(self, n, pool_dtype):
         """Slot ``page * page_size + offset`` is row ``offset`` of page
-        ``page``: the same pool as writing through ``[pages * size, ...]``,
-        rows cast to the pool's dtype, every other slot untouched."""
+        ``page``: the same pool as writing ``[N, kv, d]`` rows through the
+        ``[pages * size, kv, d]`` view of a 4-D pool (how the parent's
+        prefill wrote), rows cast to the pool's dtype, every other slot
+        untouched."""
         rng = np.random.default_rng(n)
-        pool = jnp.asarray(rng.standard_normal((7, 4, 2, 8)), pool_dtype)
-        rows = jnp.asarray(rng.standard_normal((n, 2, 8)), jnp.float32)
+        pool = jnp.asarray(rng.standard_normal((7, 4, 2 * 8)), pool_dtype)
+        rows = jnp.asarray(rng.standard_normal((n, 2 * 8)), jnp.float32)
         dests = jnp.asarray(rng.choice(np.arange(4, 28), n, replace=False),
                             jnp.int32)
         want = pool.reshape(28, 2, 8).at[dests].set(
-            rows.astype(pool_dtype)).reshape(pool.shape)
+            rows.reshape(n, 2, 8).astype(pool_dtype)).reshape(pool.shape)
         got = jax.jit(scatter_kv_slots)(pool, dests, rows)
         assert got.dtype == pool.dtype and got.shape == pool.shape
         np.testing.assert_array_equal(np.asarray(got, np.float32),
@@ -184,12 +213,57 @@ class TestScatterKVSlots:
                 np.asarray(rows[i].astype(pool_dtype), np.float32))
 
     def test_padding_rows_land_in_the_scratch_page_only(self):
-        pool = jnp.ones((3, 4, 1, 2), jnp.float32)
-        rows = jnp.full((3, 1, 2), 7.0)
+        pool = jnp.ones((3, 4, 2), jnp.float32)
+        rows = jnp.full((3, 2), 7.0)
         got = scatter_kv_slots(pool, jnp.asarray([0, 0, 9], jnp.int32), rows)
-        np.testing.assert_array_equal(np.asarray(got[1]), np.ones((4, 1, 2)))
-        assert float(got[2, 1, 0, 0]) == 7.0 and float(got[0, 0, 0, 0]) == 7.0
+        np.testing.assert_array_equal(np.asarray(got[1]), np.ones((4, 2)))
+        assert float(got[2, 1, 0]) == 7.0 and float(got[0, 0, 0]) == 7.0
         assert float(np.asarray(got).sum()) == 24 + 2 * 2 * 6.0
+
+    def test_a_donated_pool_is_written_where_it_lies(self):
+        """Given donated, the program's result is the buffer that came in
+        (marked so in the lowered program) and the array passed is gone."""
+        write = jax.jit(scatter_kv_slots, donate_argnums=0)
+        pool = jnp.zeros((5, 4, 6), jnp.float32)
+        dests, rows = jnp.asarray([9], jnp.int32), jnp.ones((1, 6))
+        text = write.lower(pool, dests, rows).as_text()
+        assert "tf.aliasing_output = 0" in text or "jax.buffer_donor" in text
+        got = write(pool, dests, rows)
+        assert pool.is_deleted() and float(got[2, 1].sum()) == 6.0
+
+
+def _engine(cfg, params, dtype, impl):
+    cfg = dataclasses.replace(cfg, dtype=dtype, paged_attn=impl)
+    return InferenceEngine(cfg, params, page_size=4, num_pages=19,
+                           max_num_seqs=4, max_model_len=32,
+                           enable_prefix_cache=False)
+
+
+class TestFlatPoolsAgainstThe4DPools:
+    """The engine's three programs on ``[pages, page_size, kv * d]`` pools,
+    given donated, against the family's walks on the parent's 4-D pools
+    (tests/kv_pool_4d.py): logits and every pool, bit for bit."""
+
+    @pytest.mark.parametrize("impl", ["reference", "interpret"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_gpt2_programs(self, gpt2_params, dtype, impl):
+        from kv_pool_4d import check_engine_programs
+
+        eng = _engine(GCFG, gpt2_params, dtype, impl)
+        done = check_engine_programs(eng, list(range(3, 14)),
+                                     list(range(20, 36)))
+        assert done == ["prefill", "chunk@0", "chunk@8", "decode#0",
+                        "decode#1"]
+
+    @pytest.mark.parametrize("impl", ["reference", "interpret"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_llama_programs(self, llama_params, dtype, impl):
+        from kv_pool_4d import check_engine_programs
+
+        eng = _engine(LCFG, llama_params, dtype, impl)  # GQA: 2 kv heads
+        assert eng.cache.k[0].shape == (19, 4, 2 * LCFG.head_dim)
+        assert len(check_engine_programs(
+            eng, list(range(3, 14)), list(range(20, 36)))) == 5
 
 
 class TestImplResolution:
