@@ -7,12 +7,19 @@ layer, recorded from outside the program.
 the class the serve replica builds, so requests still take the normal
 path from ``serve.run`` down, and nothing in the program is changed.
 The running totals it keeps per step (compiles, preemptions, pool use,
-prefill tokens) are read from the engine's public ``stats()``. Two
+prefill tokens) are read from the program's record of the step
+(``step_log``) and the public ``cache.utilization()`` and
+``scheduler.num_preemptions``. Two
 things have no public source yet and come from overriding the engine's
 private ``_run_prefill`` and ``_run_decode`` (and, for the check only,
 wrapping ``_prefill_fn``/``_decode_fn``): the spans around the prefill
 and the decode, and the sequences and live pages of each decode. They
 are listed for the ``tracing`` PR in ``PERF.md``'s open questions.
+
+The program keeps its own step records in a ring (``step_log``). The
+probe takes each one as its step ends, on the thread that steps, and
+holds it beside its own, so that no reader depends on how many steps the
+ring holds, and copies nothing while the run goes.
 """
 
 from __future__ import annotations
@@ -70,7 +77,7 @@ class Tracer:
 class StepRecord:
     __slots__ = ("start", "end", "traced", "prefills", "decodes",
                  "live_pages", "kv_utilization", "preemptions", "compiles",
-                 "prefill_tokens")
+                 "prefill_tokens", "program")
 
     def __init__(self, start: float):
         self.start = start
@@ -85,6 +92,10 @@ class StepRecord:
         self.preemptions = 0
         self.compiles = 0     # programs traced so far, all three kinds
         self.prefill_tokens = 0
+        # The program's own record of the step (``tracing.StepRecord``),
+        # the object itself: the stepping loop may still add a phase to
+        # it (its publishing), and the ring may drop it.
+        self.program = None
 
 
 class ProbedEngine(InferenceEngine):
@@ -115,15 +126,28 @@ class ProbedEngine(InferenceEngine):
         with self._span("pb.engine.step"):
             out = super().step()
         rec.end = time.perf_counter()
-        stats = self.stats()
-        rec.kv_utilization = stats["kv_utilization"]
-        rec.preemptions = stats["num_preemptions"]
-        rec.prefill_tokens = stats["prefill_tokens"]
-        rec.compiles = sum(sum(stats[k].values()) for k in (
-            "prefill_compiles", "chunk_prefill_compiles",
-            "decode_compiles"))
+        # What the step ran, from the program's own record of it and two
+        # public counters: a constant cost a step. (``stats()`` walks the
+        # recorder's whole ring for its histogram: 0.13 ms a step at the
+        # window's opening and 0.72 at its close, my chip runs, PR 29.)
+        rec.program = done = self.recorder.tail(1)[0]
+        last = self.steps[-1] if self.steps else rec  # a new one: zeros
+        rec.kv_utilization = self.cache.utilization()
+        rec.preemptions = self.scheduler.num_preemptions
+        rec.prefill_tokens = last.prefill_tokens + sum(
+            p["tokens"] for p in done.fields.get("prefills", ()))
+        rec.compiles = last.compiles + done.fields["compiled"]
         self.steps.append(rec)
         return out
+
+    def step_log(self, since: float = 0.0) -> dict:
+        """As the program's ``step_log``, of every step since the engine
+        was built, however many its ring holds. With no step in flight
+        (the readers call it when the traffic has ended)."""
+        return {"oldest_start": self.steps[0].program.start
+                if self.steps else None,
+                "steps": [r.program.as_dict() for r in self.steps
+                          if r.program.end > since]}
 
     def _run_prefill(self, seq, out):
         if self.captured is not None:

@@ -10,6 +10,7 @@ logits check against the plain reference, and the lead-in traffic.
 
 from __future__ import annotations
 
+import gc
 import statistics
 import threading
 import time
@@ -17,7 +18,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
-from perfbench import probe, traffic
+from perfbench import probe, steplog, traffic
 from perfbench.byname import load_family
 from perfbench.train_cell import SEED_MASK, memory_peak_bytes
 
@@ -404,6 +405,15 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
         progress("check")
         tracer = probe.Tracer(trace_dir, trace_seconds) if trace_dir \
             else None
+        # The collector in one state at every window's opening. A full
+        # collection of this process (650,000 objects) holds the
+        # interpreter for 0.25 s with the chip idle, and XL's traffic
+        # brings one every 42-47 s: where set-up's last one fell decided
+        # whether a 40 s window held one (12 of 13 runs) or none, 0.6 % of
+        # its tokens (my chip runs, PR 29). Collected here, the next is
+        # due past the window's end; a program that leaves more behind a
+        # step has it inside, and pays for it here as it would in service.
+        gc.collect()
         t = clock()
         runner = {"closed": run_closed, "open": run_open}[mix["kind"]]
         out = runner(handle, engine, mix, seed, vocab, seconds, tracer)
@@ -447,6 +457,18 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
     turnovers = [s for s in streams
                  if s.finished and lo < s.times[-1] <= hi]
 
+    data = RunData(
+        cell=cell, cfg=cfg, mix=mix, family=family, chips=len(devices),
+        peaks=None, window=window, end_to_end=e2e,
+        memory_peak_bytes=int(peak), streams=streams, engine_steps=steps,
+        step_before=before,
+        traced_steps=[r for r in engine.steps if r.traced])
+    # The program's own record of the window's steps: a run that stalled
+    # between two of them, or inside one, says so, and in which phase.
+    logged = steplog.window_steps(data) or []
+    step_gap_ms, step_gap_phase = steplog.largest_step_gap(logged)
+    longest_ms, longest_phase = steplog.longest_step(logged)
+
     def in_flight(at: float) -> int:
         """Requests sent and not yet finished at ``at``: a backlog that
         grows over the window means the offered rate is past the knee."""
@@ -465,6 +487,9 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
         "generator_late_p95_ms": 1e3 * percentile(late, 95) if late
         else None,
         "largest_gap_ms": 1e3 * max(gaps) if gaps else None,
+        "largest_step_gap_ms": step_gap_ms,
+        "largest_step_gap_phase": step_gap_phase,
+        "longest_step_ms": longest_ms, "longest_step_phase": longest_phase,
         "median_gap_ms": 1e3 * statistics.median(gaps) if gaps else None,
         "first_step_index": engine.steps.index(steps[0]) if steps else None,
         "last_step_index": engine.steps.index(steps[-1]) if steps else None,
@@ -473,12 +498,6 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
         "compiles_in_window": compiles, "failed": len(bad),
         "memory_stats": memory_stats,
         "stuck_threads": out["stuck_threads"]})
-    data = RunData(
-        cell=cell, cfg=cfg, mix=mix, family=family, chips=len(devices),
-        peaks=None, window=window, end_to_end=e2e,
-        memory_peak_bytes=int(peak), streams=streams, engine_steps=steps,
-        step_before=before,
-        traced_steps=[r for r in engine.steps if r.traced])
     return {"data": data, "xplane": tracer.xplane() if tracer else None,
             "correct": check["ok"] and not bad and compiles == 0
             and not out["stuck_threads"],

@@ -5,7 +5,9 @@ metrics of the host path.
 ``end``, ``phases`` (``[name, t0, t1]``, taken with ``time.perf_counter``
 where the work happens) and what the step ran. The engine the serve
 replica built lives in this process (``probe.ProbedEngine.instances``), so
-its stamps are on the clock of ``run.window``. A program without a step
+its stamps are on the clock of ``run.window``; the probe takes each record
+out of the program's ring as its step ends, so a window may hold more
+steps than the ring. A program without a step
 log (the parent of the PR that brought it) gives ``None`` to every reader
 here, and the result line leaves their metrics out.
 
@@ -40,7 +42,7 @@ Segment = Tuple[float, float, str]  # start, end, innermost phase
 
 def engine_log(run) -> Optional[Dict]:
     """The whole step log of the run's engine; None if the program keeps
-    none, or if the ring no longer holds the window's first steps."""
+    none, or if records of the window's first steps were lost."""
     from perfbench import probe
 
     engines = probe.ProbedEngine.instances
@@ -87,6 +89,45 @@ def step_gap_ms_p50(run) -> Optional[float]:
         return None
     return 1e3 * statistics.median(
         b["start"] - a["end"] for a, b in zip(steps, steps[1:]))
+
+
+def largest_step_gap(steps: Sequence[Dict]
+                     ) -> Tuple[Optional[float], Optional[str]]:
+    """The longest time from one step's end to the next one's start, in
+    ms, and the stepping loop's phase that covers most of it (at least
+    half; ``"none"`` where none does: no request to step for, or the
+    thread was not running). A run that stalled between two steps says
+    so here, and where."""
+    pairs = list(zip(steps, steps[1:]))
+    if not pairs:
+        return None, None
+    a, b = max(pairs, key=lambda p: p[1]["start"] - p[0]["end"])
+    lo, hi = a["end"], b["start"]
+    covered = {}
+    for name, t0, t1 in a["phases"] + b["phases"]:
+        piece = min(t1, hi) - max(t0, lo)
+        if piece > 0:
+            covered[name] = covered.get(name, 0.0) + piece
+    name = max(covered, key=covered.get, default="none")
+    if covered.get(name, 0.0) < 0.5 * (hi - lo):
+        name = "none"
+    return 1e3 * (hi - lo), name
+
+
+def longest_step(steps: Sequence[Dict]
+                 ) -> Tuple[Optional[float], Optional[str]]:
+    """The longest step, in ms, and the innermost phase it spent most of
+    its time in: a pause inside a step (the wait for the device, the
+    launch) is no gap between steps, and shows here."""
+    if not steps:
+        return None, None
+    step = max(steps, key=lambda s: s["end"] - s["start"])
+    spent: Dict[str, float] = {}
+    for t0, t1, name in innermost_segments([step]):
+        inside = min(t1, step["end"]) - max(t0, step["start"])
+        if inside > 0:
+            spent[name] = spent.get(name, 0.0) + inside
+    return 1e3 * (step["end"] - step["start"]), max(spent, key=spent.get)
 
 
 # ---- on the device trace's clock ---------------------------------------------

@@ -68,8 +68,10 @@ def train_cell_programs(cell, cfg, mix, devices):
 
 def serve_cell_programs(cell, cfg, mix, devices):
     """Every program the mix's pinned buckets can reach, lowered from the
-    engine's own jitted functions (pool update included). The engine is
-    built on the CPU host with abstract parameters; only its pool of
+    engine's own jitted functions (pool update included) over the shapes
+    and types of the working copy it serves from (``serving_params``: the
+    compute type, not the float32 of the tree it is given). The engine
+    is built on the CPU host with abstract parameters; only its pool of
     zeros is real."""
     from raytpu.inference import InferenceEngine
 
@@ -81,10 +83,11 @@ def serve_cell_programs(cell, cfg, mix, devices):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    params = jax.tree_util.tree_map(
+    given = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype), jax.eval_shape(
             family.train_parts(mcfg)[0], jax.random.PRNGKey(0)))
-    eng = InferenceEngine(mcfg, params, **mix["engine_options"])
+    eng = InferenceEngine(mcfg, given, **mix["engine_options"])
+    params = eng._params
     pools = [sds(a.shape, a.dtype) for a in eng.cache.k]
     i32 = jnp.int32
     for t in eng.prefill_buckets:

@@ -1,6 +1,7 @@
 """The generator: same seed, same schedule; another seed, the same work in
-another order; the stagger turns one slot over every 32 steps; the open
-loop offers its rate and keeps its due times."""
+another order, or with ``order_seed`` in the same; the stagger turns one
+slot over every 32 steps; the open loop offers its rate and keeps its due
+times."""
 
 import json
 import os
@@ -25,13 +26,64 @@ def sizes(plan):
 
 
 def test_closed_same_seed_same_schedule_other_seed_other_order():
-    m = mix("batch-decode")
+    m = mix("moe-batch-decode")  # ordered by the run's seed
     a = traffic.closed_schedule(m, 2**31 + 5)
     b = traffic.closed_schedule(m, 2**31 + 5)
     c = traffic.closed_schedule(m, 17)
     assert a == b
     assert a != c
     assert sizes(a) == sizes(c)  # the same work, whatever the seed
+
+
+def table_widths(plan, steps, page_size):
+    """The page-table width of each decode step of a closed schedule
+    served as ``simulate_closed_turnovers`` models it: the next power of
+    two over the pages of the batch's longest context."""
+    queues = [list(c) for c in plan["clients"]]
+    running = []
+    for q in queues:
+        r = q.pop(0)
+        running.append([r["prompt_len"] + 1, r["new_tokens"] - 1])
+    out = []
+    for _ in range(steps):
+        pages = max(-(-ctx // page_size) for ctx, left in running if left)
+        out.append(1 << (pages - 1).bit_length())
+        for c, (ctx, left) in enumerate(running):
+            if left:
+                running[c] = [ctx + 1, left - 1]
+            else:  # admitted this step: prefill, first token
+                r = queues[c].pop(0)
+                running[c] = [r["prompt_len"] + 1, r["new_tokens"] - 1]
+    return out
+
+
+def test_a_fixed_order_gives_every_seed_the_same_steps():
+    """Which requests share the batch decides how wide a page table each
+    decode step reads: by the run's seed, 76-90 % of XL's steps are at
+    width 64. A mix that sets ``order_seed`` has one schedule for every
+    seed, so the same width at every step; the token ids still differ."""
+    m = dict(mix("batch-decode"))
+    page = m["engine_options"]["page_size"]
+    fixed_order = m.pop("order_seed", None)
+    by_seed = [table_widths(traffic.closed_schedule(m, seed), 2300, page)
+               for seed in (2**31 + 11, 2**31 + 101)]
+    assert by_seed[0] != by_seed[1]
+    assert set(by_seed[0]) == {32, 64}
+    m["order_seed"] = 5 if fixed_order is None else fixed_order
+    plans = [traffic.closed_schedule(m, seed)
+             for seed in (2**31 + 11, 2**31 + 101, 3)]
+    assert plans[0] == plans[1] == plans[2]
+    widths = table_widths(plans[0], 2300, page)
+    assert set(widths) == {32, 64}
+    req = plans[0]["clients"][3][2]
+    assert traffic.prompt_tokens(2**31 + 11, req["index"], req["prompt_len"],
+                                 50257) \
+        != traffic.prompt_tokens(2**31 + 101, req["index"],
+                                 req["prompt_len"], 50257)
+    # The open loop's arrivals take the same field.
+    o = dict(OPEN, order_seed=9)
+    assert traffic.open_schedule(o, 1, 30.0) == traffic.open_schedule(
+        o, 2, 30.0)
 
 
 def test_closed_every_round_holds_the_same_lengths():

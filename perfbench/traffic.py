@@ -9,6 +9,12 @@ only permutes them inside the block. Arrival gaps are the mid-quantiles
 of the exponential law, permuted the same way, so every seed offers the
 same number of requests over the same time. What the seed changes is the
 order, the token ids and the weights.
+
+A mix may fix the order too (``order_seed``): which requests share the
+batch decides the work of a step (a decode reads a page table as wide as
+the batch's longest context), so two orders of the same requests are not
+the same work. The permutations are then drawn from the mix's number and
+every run has one schedule; its seed draws the token ids and the weights.
 """
 
 from __future__ import annotations
@@ -52,8 +58,10 @@ def _blocks(values: np.ndarray, count: int, rng: np.random.Generator
                            for _ in range(reps)])[:count]
 
 
-def _rng(seed: int, stream: int) -> np.random.Generator:
-    return np.random.default_rng([int(seed), stream])
+def _order_rng(mix: Mapping, seed: int, stream: int) -> np.random.Generator:
+    """What permutes the blocks: the run's seed, or the mix's own
+    ``order_seed`` where it fixes one order for every run."""
+    return np.random.default_rng([int(mix.get("order_seed", seed)), stream])
 
 
 def prompt_tokens(seed: int, index: int, length: int, vocab: int,
@@ -77,7 +85,7 @@ def closed_schedule(mix: Mapping, seed: int) -> Dict:
     clients = int(mix["clients"])
     per_client = int(mix["requests_per_client"])
     total = clients * per_client
-    rng = _rng(seed, 1)
+    rng = _order_rng(mix, seed, 1)
     # One block is one round: every client sends one request of it.
     prompts = _blocks(quantile_sizes(mix["prompt_tokens"], clients),
                       total, rng)
@@ -109,7 +117,7 @@ def open_schedule(mix: Mapping, seed: int, duration_s: float) -> Dict:
     # Whole blocks, so that every seed offers the same requests; the
     # caller sends those that are due before its window ends.
     count = int(math.ceil(rate * duration_s / block)) * block
-    rng = _rng(seed, 2)
+    rng = _order_rng(mix, seed, 2)
     u = (np.arange(block) + 0.5) / block
     gaps = _blocks(-np.log1p(-u), count, rng)  # Exp(1) mid-quantiles
     due = np.cumsum(gaps) / rate
