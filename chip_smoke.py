@@ -118,7 +118,12 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
         decode = jax.jit(functools.partial(gpt2_decode, c))
         out = logits[name] = {}
         with _mesh_context(eng.mesh):
-            out["prefill"] = prefill(p, toks["a"][None, :chunk])[0]
+            # A whole prompt of one chunk, written to pages of its own.
+            cache.allocate("whole", chunk + 1)
+            pargs = (p, toks["a"][None, :chunk],
+                     cache.prefill_dests("whole", chunk, chunk),
+                     cache.k, cache.v)
+            out["prefill"], cache.k, cache.v = prefill(*pargs)
             # b then a, so the last chunk is a's second: the one that
             # attends pages an earlier chunk wrote.
             for sid in ("b", "a"):
@@ -143,8 +148,7 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
             out["decode"] = decode(*dargs)[0]
             if name == "kernel":
                 facts["mosaic_calls"] = {
-                    "prefill": _mosaic_calls(
-                        prefill, p, toks["a"][None, :chunk]),
+                    "prefill": _mosaic_calls(prefill, *pargs),
                     "chunk": _mosaic_calls(chunked, *args),
                     "decode": _mosaic_calls(decode, *dargs)}
                 if chips > 1:

@@ -18,8 +18,8 @@ The TPU compile-once discipline, concretely:
   scratch page (page 0) with ``context_len=1`` so padding attends to
   one masked-garbage slot and pollutes nothing.
 
-Both jitted callables are constructed exactly once, in
-``_build_prefill_fn`` / ``_build_decode_fn`` — the per-iteration loop
+The three jitted callables are constructed exactly once, by the one
+``_build_program`` — the per-iteration loop
 (:meth:`InferenceEngine.step`) only *calls* them. A lint test pins
 this: ``jax.jit`` may appear in ``_build_*`` constructors only. The
 compile counters increment inside the traced function body, which
@@ -109,6 +109,11 @@ class InferenceEngine:
     iteration per call — the serve replica's loop), or use
     :meth:`generate` to run a closed batch to completion.
 
+    What it serves is the config's to say: ``model_config.serving``
+    (:class:`raytpu.models.gpt2.Serving`) names the family's three entry
+    points, its working-copy rule and the pools' head shape; the engine
+    knows no family, and what a pool row holds it leaves to them.
+
     The engine serves from a *working copy* of ``params``, made once at
     construction by the family's ``serving_params`` (beside its forwards
     in :mod:`raytpu.models`): the leaves the forwards use in
@@ -139,50 +144,27 @@ class InferenceEngine:
                  tp: int = 1, mesh=None):
         import jax
 
-        from raytpu.models.gpt2 import GPT2Config
-        from raytpu.models.llama import LlamaConfig
-
-        if isinstance(model_config, LlamaConfig):
-            # Mixtral's and OLMoE's configs are LlamaConfigs too: the same
-            # three walks, which for them also return the tokens each
-            # expert of each layer received.
-            from raytpu.models.llama import (llama_decode, llama_prefill,
-                                             llama_prefill_chunk,
-                                             serving_params)
-
-            self._prefill_fwd, self._decode_fwd = llama_prefill, llama_decode
-            self._chunk_fwd = llama_prefill_chunk
-            kv_heads = model_config.n_kv_head
-            head_dim = model_config.head_dim
-        elif isinstance(model_config, GPT2Config):
-            from raytpu.models.gpt2 import (gpt2_decode, gpt2_prefill,
-                                            gpt2_prefill_chunk,
-                                            serving_params)
-
-            self._prefill_fwd, self._decode_fwd = gpt2_prefill, gpt2_decode
-            self._chunk_fwd = gpt2_prefill_chunk
-            kv_heads = model_config.n_head
-            head_dim = model_config.n_embd // model_config.n_head
-        else:
+        served = getattr(model_config, "serving", None)
+        if served is None:
             raise TypeError(
-                f"unsupported model config: {model_config!r}; the engine "
-                f"serves LlamaConfig (MixtralConfig and OlmoeConfig among "
-                f"them) and GPT2Config")
-        # A routed-expert config: its programs return a fourth value.
-        n_expert = getattr(model_config, "n_expert", 0)
-        if n_expert and (tp > 1 or mesh is not None):
+                f"{type(model_config).__name__} does not say how it is "
+                f"served: the engine asks a model config for `serving` "
+                f"(the family's prefill, prefill_chunk and decode entry "
+                f"points, its working-copy rule, kv_heads, head_dim)")
+        # A routed-expert family: its programs return a fourth value.
+        if served.expert_counts and (tp > 1 or mesh is not None):
             raise ValueError(
                 "a routed-expert model is served on one device: sharding "
                 "its expert layer (tp, ep) in the engine is not there yet")
-        self._expert_tokens = np.zeros(
-            (model_config.n_layer, n_expert), np.int64) if n_expert else None
+        self._expert_tokens = (np.zeros(served.expert_counts, np.int64)
+                               if served.expert_counts else None)
 
         self._config = model_config
         # The working copy, made once and before anything else takes
         # memory: what the family's forwards cast to the compute type is
         # in it already, so no step converts a weight. The tree given is
         # not kept; a caller that wants it keeps it.
-        self._params = serving_params(model_config, params)
+        self._params = served.params(model_config, params)
         self._param_bytes: Dict[str, int] = {}
         for leaf in jax.tree_util.tree_leaves(self._params):
             name = str(leaf.dtype)
@@ -198,8 +180,8 @@ class InferenceEngine:
         if num_pages is None:
             num_pages = max_num_seqs * self.max_pages_per_seq + 1
         self.cache = PagedKVCache(
-            model_config.n_layer, num_pages, page_size, kv_heads, head_dim,
-            dtype=model_config.dtype)
+            model_config.n_layer, num_pages, page_size, served.kv_heads,
+            served.head_dim, dtype=model_config.dtype)
         # Tensor parallelism: shard the weights with the parallel-layer
         # rule table and the KV pools along their last dimension, whole
         # heads to a shard (a head's features are contiguous). Each jit
@@ -222,9 +204,9 @@ class InferenceEngine:
             from jax.sharding import NamedSharding, PartitionSpec
             from raytpu.parallel.sharding import shard_params
             tp_size = dict(self.mesh.shape).get("tp", 1)
-            if tp_size > 1 and kv_heads % tp_size:
-                raise ValueError(
-                    f"n_kv_head={kv_heads} not divisible by tp={tp_size}")
+            if tp_size > 1 and served.kv_heads % tp_size:
+                raise ValueError(f"n_kv_head={served.kv_heads} not "
+                                 f"divisible by tp={tp_size}")
             self._params = shard_params(self._params, self.mesh)
             self._kv_sharding = NamedSharding(
                 self.mesh, PartitionSpec(None, None, "tp"))
@@ -290,33 +272,35 @@ class InferenceEngine:
         self._jnp = jax.numpy
         self._jax = jax
         compile_cache.enable()
-        self._prefill_fn = self._build_prefill_fn(jax)
-        self._chunk_fn = self._build_chunk_prefill_fn(jax)
-        self._decode_fn = self._build_decode_fn(jax)
+        # One XLA program per key: a prompt's length bucket; a chunk's
+        # length (a decode's batch) bucket x the trimmed table width.
+        self._prefill_fn = self._build_program(
+            jax, "_prefill", served.prefill, self._prefill_compiles,
+            lambda tokens, dests: tokens.shape[1])
+        self._chunk_fn = self._build_program(
+            jax, "_chunk", served.prefill_chunk, self._chunk_compiles,
+            lambda tokens, positions, dests, block_tables:
+            f"{tokens.shape[1]}x{block_tables.shape[1]}")
+        self._decode_fn = self._build_program(
+            jax, "_decode", served.decode, self._decode_compiles,
+            lambda tokens, positions, dests, block_tables, context_lens:
+            f"{tokens.shape[0]}x{block_tables.shape[1]}")
 
-    # ---- compiled steps (the ONLY jax.jit call sites) ---------------
+    # ---- compiled steps (the ONLY jax.jit call site) ----------------
 
-    def _build_prefill_fn(self, jax):
-        cfg, fwd = self._config, self._prefill_fwd
-        compiles = self._prefill_compiles
-        kv_sh = self._kv_sharding
-        routed = self._expert_tokens is not None
-        from raytpu.ops.paged_attention import scatter_kv_slots
+    def _build_program(self, jax, name, fwd, compiles, bucket_key):
+        """One of the three jitted programs, ``(params, ks, vs, *inputs)
+        -> (logits, ks, vs[, expert count])``: the family's entry point
+        ``fwd`` on the donated pools. ``compiles`` counts its traces
+        under ``bucket_key(*inputs)``; ``name`` is what a trace and the
+        compile cache know the program by."""
+        cfg, kv_sh = self._config, self._kv_sharding
 
-        def _prefill(params, ks, vs, tokens, dests):
-            # Trace-time only: counts XLA compiles per length bucket.
-            bucket = tokens.shape[1]
+        def program(params, ks, vs, *inputs):
+            # Trace-time only: counts XLA compiles per bucket.
+            bucket = bucket_key(*inputs)
             compiles[bucket] = compiles.get(bucket, 0) + 1
-            live = {}
-            if routed:  # its padded positions are sent to no expert
-                from raytpu.models.llama import live_rows
-                live["live"] = live_rows(dests, ks[0])[None]
-            logits, new_k, new_v, *experts = fwd(cfg, params, tokens, **live)
-            # The prompt's K and V, [1, T, KV, D] a layer, as T pool rows.
-            ks2 = [scatter_kv_slots(kc, dests, nk.reshape(bucket, -1))
-                   for kc, nk in zip(ks, new_k)]
-            vs2 = [scatter_kv_slots(vc, dests, nv.reshape(bucket, -1))
-                   for vc, nv in zip(vs, new_v)]
+            logits, ks2, vs2, *experts = fwd(cfg, params, *inputs, ks, vs)
             if kv_sh is not None:
                 # Pin the pool sharding through the update: the pools
                 # must come back kv-head-sharded, never resharded.
@@ -324,53 +308,10 @@ class InferenceEngine:
                        for x in ks2]
                 vs2 = [jax.lax.with_sharding_constraint(x, kv_sh)
                        for x in vs2]
-            return (logits[0], ks2, vs2, *experts)
-
-        return jax.jit(_prefill, donate_argnums=_POOLS)
-
-    def _build_chunk_prefill_fn(self, jax):
-        cfg, fwd = self._config, self._chunk_fwd
-        compiles = self._chunk_compiles
-        kv_sh = self._kv_sharding
-
-        def _chunk(params, ks, vs, tokens, positions, dests, block_tables):
-            # Length bucket x trimmed block-table width: each combo is
-            # one XLA program.
-            bucket = f"{tokens.shape[1]}x{block_tables.shape[1]}"
-            compiles[bucket] = compiles.get(bucket, 0) + 1
-            logits, ks2, vs2, *experts = fwd(
-                cfg, params, tokens, positions, dests, block_tables, ks, vs)
-            if kv_sh is not None:
-                ks2 = [jax.lax.with_sharding_constraint(x, kv_sh)
-                       for x in ks2]
-                vs2 = [jax.lax.with_sharding_constraint(x, kv_sh)
-                       for x in vs2]
             return (logits, ks2, vs2, *experts)
 
-        return jax.jit(_chunk, donate_argnums=_POOLS)
-
-    def _build_decode_fn(self, jax):
-        cfg, fwd = self._config, self._decode_fwd
-        compiles = self._decode_compiles
-        kv_sh = self._kv_sharding
-
-        def _decode(params, ks, vs, tokens, positions, dests, block_tables,
-                    context_lens):
-            # Batch bucket x trimmed block-table width: each combo is
-            # one XLA program.
-            bucket = f"{tokens.shape[0]}x{block_tables.shape[1]}"
-            compiles[bucket] = compiles.get(bucket, 0) + 1
-            logits, ks2, vs2, *experts = fwd(
-                cfg, params, tokens, positions, dests, block_tables,
-                context_lens, ks, vs)
-            if kv_sh is not None:
-                ks2 = [jax.lax.with_sharding_constraint(x, kv_sh)
-                       for x in ks2]
-                vs2 = [jax.lax.with_sharding_constraint(x, kv_sh)
-                       for x in vs2]
-            return (logits, ks2, vs2, *experts)
-
-        return jax.jit(_decode, donate_argnums=_POOLS)
+        program.__name__ = name
+        return jax.jit(program, donate_argnums=_POOLS)
 
     def _put(self, x):
         """Host array → device input. Under a tp mesh, inputs are
@@ -517,10 +458,7 @@ class InferenceEngine:
             arrived = self._arrival_ts.get(seq.request_id)
             if arrived is not None:  # None: resumed after a preemption
                 ph.attrs["waited_s"] = ph.t0 - arrived
-            if whole:
-                n = self._prefill_full(seq, plen, out, ph.attrs)
-            else:
-                n = self._prefill_one_chunk(seq, start, plen, out, ph.attrs)
+            n = self._prefill_step(seq, whole, out, ph.attrs)
         self.recorder.open.fields.setdefault("prefills", []).append(ph.attrs)
         if task_events.request_events_enabled() \
                 and seq.cached_len >= plen \
@@ -542,60 +480,48 @@ class InferenceEngine:
                 seq.request_id, seq.prompt,
                 min(seq.cached_len, len(seq.prompt)))
 
-    def _prefill_full(self, seq: Sequence, plen: int,
+    def _prefill_step(self, seq: Sequence, whole: bool,
                       out: List[StepOutput], attrs: dict) -> int:
-        bucket = _bucket_for(plen, self.prefill_buckets)
-        attrs.update(tokens=plen, bucket=bucket)
-        tokens = np.zeros((1, bucket), dtype=np.int32)
-        tokens[0, :plen] = seq.tokens[:plen]
-        dests = self.cache.prefill_dests(seq.request_id, plen, bucket)
-        logits, ks, vs, *experts = self._prefill_fn(
-            self._params, self.cache.k, self.cache.v,
-            self._put(tokens), self._put(dests))
-        self.cache.k, self.cache.v = ks, vs
-        self._count_experts(experts)
-        seq.cached_len = plen
-        self._register_prefix(seq)
-        if not seq.generated:
-            # Fresh prompt: its last logit samples the first new token.
-            # A preemption-resume prefill must NOT resample — the tail
-            # token was already emitted; the next decode rewrites its KV.
-            token = sample_token(np.asarray(logits[plen - 1]),
-                                 seq.sampling, seq.rng)
-            self._emit(seq, token, out)
-        return plen
-
-    def _prefill_one_chunk(self, seq: Sequence, start: int, plen: int,
-                           out: List[StepOutput], attrs: dict) -> int:
-        take = min(self.prefill_chunk, plen - start)
-        bucket = _bucket_for(take, self.chunk_buckets)
-        attrs.update(tokens=take, bucket=bucket, start=start)
+        """Run one prefill program for ``seq``: its whole prompt from
+        zero (``whole``), or its next chunk against the pages already
+        written. The two differ in their inputs alone."""
+        start, plen = seq.cached_len, seq.prefill_len
+        take = plen if whole else min(self.prefill_chunk, plen - start)
+        bucket = _bucket_for(
+            take, self.prefill_buckets if whole else self.chunk_buckets)
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, :take] = seq.tokens[start:start + take]
-        positions = np.zeros(bucket, dtype=np.int32)
-        positions[:take] = np.arange(start, start + take)
         dests = self.cache.chunk_dests(seq.request_id, start, take, bucket)
-        # Trim to this sequence's allocated pages (bucketed) — the
-        # reference gather pays O(table width), not O(P_max).
-        p_used = _bucket_for(self.cache.num_seq_pages(seq.request_id),
-                             self.page_buckets)
-        tables = self.cache.table_array([seq.request_id], p_used)
-        if self.paged_attn_impl == "reference":
-            self._pages_gathered += p_used
-        logits, ks, vs, *experts = self._chunk_fn(
+        if whole:
+            attrs.update(tokens=take, bucket=bucket)
+            fn, inputs = self._prefill_fn, (tokens, dests)
+        else:
+            attrs.update(tokens=take, bucket=bucket, start=start)
+            positions = np.zeros(bucket, dtype=np.int32)
+            positions[:take] = np.arange(start, start + take)
+            # Trim to this sequence's allocated pages (bucketed) — the
+            # reference gather pays O(table width), not O(P_max).
+            p_used = _bucket_for(self.cache.num_seq_pages(seq.request_id),
+                                 self.page_buckets)
+            tables = self.cache.table_array([seq.request_id], p_used)
+            if self.paged_attn_impl == "reference":
+                self._pages_gathered += p_used
+            fn, inputs = self._chunk_fn, (tokens, positions, dests, tables)
+        logits, ks, vs, *experts = fn(
             self._params, self.cache.k, self.cache.v,
-            self._put(tokens), self._put(positions),
-            self._put(dests), self._put(tables))
+            *map(self._put, inputs))
         self.cache.k, self.cache.v = ks, vs
         self._count_experts(experts)
         seq.cached_len = start + take
         self._register_prefix(seq)
         if seq.cached_len >= plen and not seq.generated:
-            # Final chunk of a fresh prompt: sample the first token
-            # from the last REAL row (same no-resample rule as above).
-            token = sample_token(np.asarray(logits[0, take - 1]),
-                                 seq.sampling, seq.rng)
-            self._emit(seq, token, out)
+            # The last chunk of a fresh prompt: its last REAL row's logit
+            # samples the first new token. A preemption-resume prefill
+            # must NOT resample — the tail token was already emitted;
+            # the next decode rewrites its KV.
+            last = logits[take - 1] if whole else logits[0, take - 1]
+            self._emit(seq, sample_token(np.asarray(last), seq.sampling,
+                                         seq.rng), out)
         return take
 
     def _run_decode(self, seqs: List[Sequence],

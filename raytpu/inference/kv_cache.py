@@ -266,12 +266,7 @@ class PagedKVCache:
         """Flat destination slots ``[bucket]`` int32 for writing a
         prefill of ``length`` real tokens padded to ``bucket``. Padding
         slots cycle through page 0 so bucketed garbage stays in scratch."""
-        out = np.empty(bucket, dtype=np.int32)
-        for i in range(min(length, bucket)):
-            out[i] = self.slot(seq_id, i)
-        for i in range(length, bucket):
-            out[i] = i % self.page_size  # page 0 slots
-        return out
+        return self.chunk_dests(seq_id, 0, length, bucket)
 
     def chunk_dests(self, seq_id: str, start: int, take: int,
                     bucket: int) -> np.ndarray:

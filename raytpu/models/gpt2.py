@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -57,6 +57,13 @@ class GPT2Config:
     def tiny(cls) -> "GPT2Config":
         return cls(vocab_size=512, block_size=128, n_layer=2, n_head=2,
                    n_embd=128)
+
+    @property
+    def serving(self) -> "Serving":
+        """How ``InferenceEngine`` serves this family."""
+        return Serving(gpt2_prefill, gpt2_prefill_chunk, gpt2_decode,
+                       serving_params, kv_heads=self.n_head,
+                       head_dim=self.n_embd // self.n_head)
 
     @property
     def n_params_approx(self) -> int:
@@ -370,34 +377,78 @@ def _tied_logits(c: GPT2Config, params, x):
                                preferred_element_type=jnp.float32)
 
 
-def _block_apply(c: GPT2Config, lp, x, attn_fn):
-    attn = CausalSelfAttention(c)
-    mlp = MLP(c)
-    ln = nn.LayerNorm(dtype=c.dtype)
-    h = ln.apply({"params": lp["ln_1"]}, x)
-    y, k, v = attn_fn(attn, lp["attn"], h)
-    x = x + y
-    h = ln.apply({"params": lp["ln_2"]}, x)
-    x = x + mlp.apply({"params": lp["mlp"]}, h)
-    return x, k, v
+@dataclasses.dataclass(frozen=True)
+class Serving:
+    """How a family is served: what ``InferenceEngine`` asks of a config
+    (its ``serving`` attribute) so that it need know no family. The three
+    entry points have one contract, ``fn(config, params, *inputs,
+    k_caches, v_caches)`` -> fp32 logits, then the two lists of pools
+    (``[num_pages, page_size, kv_heads * head_dim]`` a layer) with the
+    call's rows written, then, where ``expert_counts`` is set, the int32
+    tokens each expert of each layer received. What a pool row holds is
+    known to the family and to ``raytpu.ops.paged_attention`` alone.
+
+    ``inputs`` -> logits: ``prefill`` (tokens [1, T], dests [T]) ->
+    [T, V]; ``prefill_chunk`` (tokens [1, T], positions [T], dests [T],
+    block_tables [1, P]) -> [1, T, V]; ``decode`` (tokens [B], positions
+    [B], dests [B], block_tables [B, P], context_lens [B]) -> [B, V]."""
+
+    prefill: Callable
+    prefill_chunk: Callable
+    decode: Callable
+    params: Callable  # (config, params) -> the working copy to serve from
+    kv_heads: int
+    head_dim: int
+    expert_counts: Optional[Tuple[int, int]] = None  # (layers, experts)
 
 
-def gpt2_prefill(config: GPT2Config, params, tokens):
-    """Prefill forward: ``tokens`` [B, T] -> (fp32 logits [B, T, V],
-    per-layer K [B, T, H, D] list, per-layer V list)."""
-    c = config
-    b, t = tokens.shape
-    x = params["wte"]["embedding"].astype(c.dtype)[tokens] + \
-        params["wpe"]["embedding"].astype(c.dtype)[jnp.arange(t)][None]
+def write_prompt_rows(k_caches, v_caches, dests, ks, vs):
+    """The pools with a whole prompt's K and V, ``[1, T, KV, D]`` a layer
+    as an attention module's ``prefill`` returns them, written as T pool
+    rows at ``dests`` [T]; padding's rows go to the scratch page."""
+    from raytpu.ops.paged_attention import scatter_kv_slots
+
+    t = dests.shape[0]
+    return ([scatter_kv_slots(kc, dests, k.reshape(t, -1))
+             for kc, k in zip(k_caches, ks)],
+            [scatter_kv_slots(vc, dests, v.reshape(t, -1))
+             for vc, v in zip(v_caches, vs)])
+
+
+def _serve(c: GPT2Config, params, x, method: str, cache_args):
+    """The serving walk, written once: the blocks over the embedded
+    ``x``, ``ln_f`` and the tied head. Layer ``i`` attends through
+    ``CausalSelfAttention.<method>(h, *cache_args(i))``, which returns
+    its output and the layer's K and V (rows, or the pools it wrote).
+    Returns ``(fp32 logits, K list, V list)``."""
+    attn, mlp, ln = CausalSelfAttention(c), MLP(c), nn.LayerNorm(dtype=c.dtype)
     ks, vs = [], []
     for i in range(c.n_layer):
-        x, k, v = _block_apply(
-            c, layer_params(params, i), x,
-            lambda m, p, h: m.apply({"params": p}, h, method="prefill"))
+        lp = layer_params(params, i)
+        h = ln.apply({"params": lp["ln_1"]}, x)
+        y, k, v = attn.apply({"params": lp["attn"]}, h, *cache_args(i),
+                             method=method)
         ks.append(k)
         vs.append(v)
-    x = nn.LayerNorm(dtype=c.dtype).apply({"params": params["ln_f"]}, x)
+        x = x + y
+        h = ln.apply({"params": lp["ln_2"]}, x)
+        x = x + mlp.apply({"params": lp["mlp"]}, h)
+    x = ln.apply({"params": params["ln_f"]}, x)
     return _tied_logits(c, params, x), ks, vs
+
+
+def gpt2_prefill(config: GPT2Config, params, tokens, dests, k_caches,
+                 v_caches):
+    """Whole-prompt forward: ``tokens`` [1, T] from position 0 (flash
+    attention), its K and V written to the pools at ``dests`` [T] ->
+    (fp32 logits [T, V], k_caches, v_caches)."""
+    c = config
+    x = params["wte"]["embedding"].astype(c.dtype)[tokens] + \
+        params["wpe"]["embedding"].astype(c.dtype)[
+            jnp.arange(tokens.shape[1])][None]
+    logits, ks, vs = _serve(c, params, x, "prefill", lambda i: ())
+    ks, vs = write_prompt_rows(k_caches, v_caches, dests, ks, vs)
+    return logits[0], ks, vs
 
 
 def gpt2_prefill_chunk(config: GPT2Config, params, tokens, positions,
@@ -408,19 +459,8 @@ def gpt2_prefill_chunk(config: GPT2Config, params, tokens, positions,
     c = config
     x = params["wte"]["embedding"].astype(c.dtype)[tokens] + \
         params["wpe"]["embedding"].astype(c.dtype)[positions][None]
-    new_k, new_v = [], []
-    for i in range(c.n_layer):
-        ki, vi = k_caches[i], v_caches[i]
-
-        def attn_fn(m, p, h, ki=ki, vi=vi):
-            return m.apply({"params": p}, h, ki, vi, dests, block_tables,
-                           positions, method="prefill_chunk")
-
-        x, k, v = _block_apply(c, layer_params(params, i), x, attn_fn)
-        new_k.append(k)
-        new_v.append(v)
-    x = nn.LayerNorm(dtype=c.dtype).apply({"params": params["ln_f"]}, x)
-    return _tied_logits(c, params, x), new_k, new_v
+    return _serve(c, params, x, "prefill_chunk", lambda i: (
+        k_caches[i], v_caches[i], dests, block_tables, positions))
 
 
 def gpt2_decode(config: GPT2Config, params, tokens, positions, dests,
@@ -430,16 +470,5 @@ def gpt2_decode(config: GPT2Config, params, tokens, positions, dests,
     c = config
     x = params["wte"]["embedding"].astype(c.dtype)[tokens] + \
         params["wpe"]["embedding"].astype(c.dtype)[positions]
-    new_k, new_v = [], []
-    for i in range(c.n_layer):
-        ki, vi = k_caches[i], v_caches[i]
-
-        def attn_fn(m, p, h, ki=ki, vi=vi):
-            return m.apply({"params": p}, h, ki, vi, dests, block_tables,
-                           context_lens, method="decode_step")
-
-        x, k, v = _block_apply(c, layer_params(params, i), x, attn_fn)
-        new_k.append(k)
-        new_v.append(v)
-    x = nn.LayerNorm(dtype=c.dtype).apply({"params": params["ln_f"]}, x)
-    return _tied_logits(c, params, x), new_k, new_v
+    return _serve(c, params, x, "decode_step", lambda i: (
+        k_caches[i], v_caches[i], dests, block_tables, context_lens))

@@ -24,7 +24,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raytpu.models.gpt2 import cast_leaves
+from raytpu.models.gpt2 import Serving, cast_leaves, write_prompt_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +71,18 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.n_embd // self.n_head
+
+    @property
+    def serving(self) -> Serving:
+        """How ``InferenceEngine`` serves this family; a routed config
+        (``MixtralConfig`` and what extends it) through the same walk."""
+        from raytpu.models import mixtral  # it imports this module
+
+        routed = isinstance(self, mixtral.MixtralConfig)
+        return Serving(
+            llama_prefill, llama_prefill_chunk, llama_decode, serving_params,
+            kv_heads=self.n_kv_head, head_dim=self.head_dim,
+            expert_counts=(self.n_layer, self.n_expert) if routed else None)
 
     @property
     def n_params_approx(self) -> int:
@@ -419,30 +431,29 @@ def _feed_forward(c: LlamaConfig, lp, h, live):
     return LlamaMLP(c).apply({"params": lp["mlp"]}, h), None
 
 
-def _walk_result(logits, new_k, new_v, routed):
-    """What a serving walk returns: logits first, then the K and V lists,
-    and for a routed config the int32 ``[layers, experts]`` count of
-    tokens each expert received."""
-    if routed[0] is None:
-        return logits, new_k, new_v
-    return logits, new_k, new_v, jnp.stack(routed)
+def live_rows(dests, k_cache):
+    """Rows of a bucket that are tokens, from where their K/V is written:
+    the engine points padding at the scratch page, page 0."""
+    return dests >= k_cache.shape[1]
 
 
-def llama_prefill(config: LlamaConfig, params, tokens, live=None):
-    """Prefill forward: ``tokens`` [B, T] -> (fp32 logits [B, T, V],
-    per-layer roped K [B, T, KV, D] list, per-layer V list) — the K/V
-    halves are what the engine scatters into the paged cache. ``live``
-    [B, T] marks real positions of a padded bucket (all, if None). A
-    routed config returns a fourth value, see :func:`_walk_result`."""
-    c = config
-    x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
+def _serve(c: LlamaConfig, params, x, live, method: str, cache_args):
+    """The serving walk, written once: the blocks over the embedded
+    ``x``, the final norm and the head. Layer ``i`` attends through
+    ``LlamaAttention.<method>(h, *cache_args(i))``, which returns its
+    output and the layer's K and V (rows, or the pools it wrote);
+    ``live`` (``x``'s leading shape) marks the rows that are tokens, for
+    :func:`_feed_forward`. Returns ``(fp32 logits, K list, V list)`` and
+    for a routed config a fourth value, the int32 ``[layers, experts]``
+    count of tokens each expert received."""
     attn = LlamaAttention(c)
     norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
     ks, vs, routed = [], [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
         h = norm.apply({"params": lp["input_norm"]}, x)
-        y, k, v = attn.apply({"params": lp["attn"]}, h, method="prefill")
+        y, k, v = attn.apply({"params": lp["attn"]}, h, *cache_args(i),
+                             method=method)
         ks.append(k)
         vs.append(v)
         x = x + y
@@ -451,67 +462,47 @@ def llama_prefill(config: LlamaConfig, params, tokens, live=None):
         routed.append(counts)
         x = x + y
     x = norm.apply({"params": params["final_norm"]}, x)
-    return _walk_result(_lm_logits(c, params, x), ks, vs, routed)
+    logits = _lm_logits(c, params, x)
+    if routed[0] is None:
+        return logits, ks, vs
+    return logits, ks, vs, jnp.stack(routed)
 
 
-def live_rows(dests, k_cache):
-    """Rows of a bucket that are tokens, from where their K/V is written:
-    the engine points padding at the scratch page, page 0."""
-    return dests >= k_cache.shape[1]
+def llama_prefill(config: LlamaConfig, params, tokens, dests, k_caches,
+                  v_caches):
+    """Whole-prompt forward: ``tokens`` [1, T] from position 0 (flash
+    attention), its roped K and V written to the pools at ``dests`` [T]
+    -> (fp32 logits [T, V], k_caches, v_caches[, count])."""
+    c = config
+    live = live_rows(dests, k_caches[0])[None]
+    x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
+    logits, ks, vs, *count = _serve(c, params, x, live, "prefill",
+                                    lambda i: ())
+    ks, vs = write_prompt_rows(k_caches, v_caches, dests, ks, vs)
+    return (logits[0], ks, vs, *count)
 
 
 def llama_prefill_chunk(config: LlamaConfig, params, tokens, positions,
                         dests, block_tables, k_caches, v_caches):
     """Chunked-prefill forward: ``tokens`` [1, T] at absolute
     ``positions`` [T] -> (fp32 logits [1, T, V], updated k_caches,
-    v_caches). See :meth:`LlamaAttention.prefill_chunk` for the cache
-    argument shapes."""
+    v_caches[, count]). See :meth:`LlamaAttention.prefill_chunk` for the
+    cache argument shapes."""
     c = config
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
-    attn = LlamaAttention(c)
-    norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
     live = live_rows(dests, k_caches[0])[None]
-    new_k, new_v, routed = [], [], []
-    for i in range(c.n_layer):
-        lp = layer_params(params, i)
-        h = norm.apply({"params": lp["input_norm"]}, x)
-        y, kc, vc = attn.apply(
-            {"params": lp["attn"]}, h, k_caches[i], v_caches[i], dests,
-            block_tables, positions, method="prefill_chunk")
-        new_k.append(kc)
-        new_v.append(vc)
-        x = x + y
-        h = norm.apply({"params": lp["post_attn_norm"]}, x)
-        y, counts = _feed_forward(c, lp, h, live)
-        routed.append(counts)
-        x = x + y
-    x = norm.apply({"params": params["final_norm"]}, x)
-    return _walk_result(_lm_logits(c, params, x), new_k, new_v, routed)
+    return _serve(c, params, x, live, "prefill_chunk", lambda i: (
+        k_caches[i], v_caches[i], dests, block_tables, positions))
 
 
 def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
                  block_tables, context_lens, k_caches, v_caches):
     """Single-token decode forward: ``tokens`` [B] -> (fp32 logits
-    [B, V], updated k_caches, v_caches). See
+    [B, V], updated k_caches, v_caches[, count]). See
     :meth:`LlamaAttention.decode_step` for the cache argument shapes."""
     c = config
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
-    attn = LlamaAttention(c)
-    norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
     live = live_rows(dests, k_caches[0])
-    new_k, new_v, routed = [], [], []
-    for i in range(c.n_layer):
-        lp = layer_params(params, i)
-        h = norm.apply({"params": lp["input_norm"]}, x)
-        y, kc, vc = attn.apply(
-            {"params": lp["attn"]}, h, k_caches[i], v_caches[i], dests,
-            block_tables, positions, context_lens, method="decode_step")
-        new_k.append(kc)
-        new_v.append(vc)
-        x = x + y
-        h = norm.apply({"params": lp["post_attn_norm"]}, x)
-        y, counts = _feed_forward(c, lp, h, live)
-        routed.append(counts)
-        x = x + y
-    x = norm.apply({"params": params["final_norm"]}, x)
-    return _walk_result(_lm_logits(c, params, x), new_k, new_v, routed)
+    return _serve(c, params, x, live, "decode_step", lambda i: (
+        k_caches[i], v_caches[i], dests, block_tables, positions,
+        context_lens))

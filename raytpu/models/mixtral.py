@@ -16,8 +16,8 @@ parameters are stacked on a leading experts dim so ``TRANSFORMER_RULES``
 shards them over the ``ep`` mesh axis with no model-specific code.
 
 Training goes through :class:`Mixtral`; serving through the llama
-family's three walks (:func:`raytpu.models.llama.llama_prefill` and its
-siblings), which pick this layer for a :class:`MixtralConfig`.
+family's walk (:func:`raytpu.models.llama.llama_prefill` and its
+siblings), which picks this layer for a :class:`MixtralConfig`.
 :class:`OlmoeConfig` is OLMoE-1B-7B's block: 64 experts of which a token
 takes 8 with weights that are not renormalised, and a norm over the
 whole q and k projections.

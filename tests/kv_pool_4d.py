@@ -2,10 +2,11 @@
 tests of the flat pools: one ``[num_pages, page_size, kv_heads,
 head_dim]`` array a layer, written through the ``[pages * page_size,
 ...]`` view and read by the gather (or by the kernel on a reshaped
-copy). The family's own walks run on them, with the two pool functions
-they look up in ``raytpu.ops.paged_attention`` replaced; nothing here is
-donated. Logits and pool contents of the engine's three programs must
-equal these bit for bit.
+copy). The family's own entry points (``config.serving``) run on them,
+with the two pool functions they look up in
+``raytpu.ops.paged_attention`` replaced; nothing here is donated.
+Logits and pool contents of the engine's three programs must equal
+these bit for bit.
 """
 
 import contextlib
@@ -79,28 +80,18 @@ def check_engine_programs(eng, prompt_a, prompt_b, chunk: int = 8):
     same bits. Returns the names of the programs compared."""
     cfg, params, cache = eng._config, eng._params, eng.cache
     kv, d, ps = cache.num_kv_heads, cache.head_dim, cache.page_size
-    routed = eng._expert_tokens is not None
     shape4 = (cache.num_pages, ps, kv, d)
     k4 = [jnp.zeros(shape4, cache.dtype) for _ in range(cache.num_layers)]
     v4 = [jnp.zeros(shape4, cache.dtype) for _ in range(cache.num_layers)]
 
-    def prefill_4d(params, ks, vs, tokens, dests):
-        live = {}
-        if routed:
-            live["live"] = (dests >= ps)[None]
-        logits, new_k, new_v, *_ = eng._prefill_fwd(
-            cfg, params, tokens, **live)
-        return (logits[0], [scatter_4d(kc, dests, nk[0])
-                            for kc, nk in zip(ks, new_k)],
-                [scatter_4d(vc, dests, nv[0]) for vc, nv in zip(vs, new_v)])
+    def on_4d(fwd):
+        """A family's entry point as the engine's program calls it."""
+        return lambda params, ks, vs, *inputs: fwd(
+            cfg, params, *inputs, ks, vs)[:3]
 
-    def chunk_4d(params, ks, vs, tokens, positions, dests, tables):
-        return eng._chunk_fwd(cfg, params, tokens, positions, dests, tables,
-                              ks, vs)[:3]
-
-    def decode_4d(params, ks, vs, tokens, positions, dests, tables, lens):
-        return eng._decode_fwd(cfg, params, tokens, positions, dests, tables,
-                               lens, ks, vs)[:3]
+    served = cfg.serving
+    prefill_4d, chunk_4d, decode_4d = map(
+        on_4d, (served.prefill, served.prefill_chunk, served.decode))
 
     done = []
 

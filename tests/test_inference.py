@@ -558,6 +558,55 @@ class TestEngineGPT2:
         assert outs[1] == reference_greedy(model, params, pb, 6)
         assert max(eng.stats()["decode_batch_hist"]) >= 2
 
+    def _stranger(self, **namespace):
+        """GCFG's values in a config class of this test's own, no kin of
+        ``GPT2Config`` and unknown to ``raytpu.inference``."""
+        cls = dataclasses.make_dataclass(
+            "StrangerConfig",
+            [(f.name, f.type) for f in dataclasses.fields(GPT2Config)],
+            frozen=True, namespace=namespace)
+        cfg = cls(**{f.name: getattr(GCFG, f.name)
+                     for f in dataclasses.fields(GPT2Config)})
+        assert not isinstance(cfg, GPT2Config)
+        return cfg
+
+    def test_engine_serves_a_config_by_its_description_alone(
+            self, gpt2_model):
+        # The description is built here from GPT-2's entry points; each
+        # says when it is traced, so what the engine ran is known.
+        model, params = gpt2_model
+        traced = []
+
+        def said(name):
+            fwd = getattr(gpt2_mod, name)
+            return lambda *a: traced.append(name) or fwd(*a)
+
+        cfg = self._stranger(serving=property(lambda c: gpt2_mod.Serving(
+            said("gpt2_prefill"), said("gpt2_prefill_chunk"),
+            said("gpt2_decode"), gpt2_mod.serving_params,
+            kv_heads=c.n_head, head_dim=c.n_embd // c.n_head)))
+        eng = InferenceEngine(cfg, params, page_size=8, max_num_seqs=4,
+                              max_model_len=64, prefill_chunk=8)
+        pa, pb = list(range(1, 14)), [11, 12]  # pa: two chunks
+        outs = eng.generate([pa, pb], SamplingParams(max_new_tokens=6))
+        assert outs[0] == reference_greedy(model, params, pa, 6)
+        assert outs[1] == reference_greedy(model, params, pb, 6)
+        assert {"gpt2_prefill", "gpt2_prefill_chunk", "gpt2_decode"} \
+            == set(traced)
+        assert len(traced) == eng._programs_traced()  # once a bucket
+        assert eng.stats()["expert_tokens"] is None
+
+    def test_a_config_that_cannot_say_how_it_is_served_is_refused(
+            self, gpt2_model):
+        _, params = gpt2_model
+        with pytest.raises(TypeError) as err:
+            InferenceEngine(self._stranger(), params, page_size=8)
+        said = str(err.value)
+        assert "StrangerConfig does not say how it is served" in said
+        assert "`serving`" in said
+        # What is missing, not a list of the families the tree has.
+        assert not re.search("GPT2|Llama|Mixtral|Olmoe", said)
+
 
 # ---------------------------------------------------------------------------
 # The working copy: float32 parameters under bf16 compute are cast once,
@@ -625,7 +674,7 @@ class TestServingParams:
         table = jnp.asarray([[1, 2]], jnp.int32)
         return {
             "prefill": lambda p: getattr(mod, f"{prefix}_prefill")(
-                cfg, p, tokens),
+                cfg, p, tokens, 8 + jnp.arange(16), *pools),
             "chunk": lambda p: getattr(mod, f"{prefix}_prefill_chunk")(
                 cfg, p, tokens[:, :4], positions, dests, table, *pools),
             "decode": lambda p: getattr(mod, f"{prefix}_decode")(
@@ -938,7 +987,34 @@ class TestInferenceJitLint:
         for path in sorted(pkg.glob("*.py")):
             t, _ = jit_calls_outside_builders(ast.parse(path.read_text()))
             total.extend(t)
-        assert len(total) >= 2, "expected the prefill + decode jit sites"
+        assert len(total) >= 1, "expected the program builder's jit site"
+
+    def test_engine_names_no_model_family(self):
+        """The seam: the engine imports no family's module and asks no
+        config for its type; it goes by ``config.serving`` alone."""
+        path = pathlib.Path(__file__).resolve().parent.parent / \
+            "raytpu" / "inference" / "engine.py"
+        imported, called, builders = [], [], []
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.FunctionDef) \
+                    and node.name.startswith("_build_"):
+                builders.append(node.name)
+            elif isinstance(node, ast.ImportFrom):
+                imported.append(node.module or "")
+            elif isinstance(node, ast.Import):
+                imported.extend(a.name for a in node.names)
+            elif isinstance(node, ast.Call) \
+                    and isinstance(node.func, ast.Name):
+                called.append((node.func.id, [
+                    a.value for a in node.args
+                    if isinstance(a, ast.Constant)]))
+        assert not [m for m in imported if m.startswith("raytpu.models")]
+        # (Imports inside a method are seen too: this is one.)
+        assert "raytpu.ops.paged_attention" in imported
+        assert not [c for c in called if c[0] == "isinstance"]
+        assert sorted(c[1] for c in called if c[0] == "getattr") \
+            == [["paged_attn", None], ["serving", None]]
+        assert builders == ["_build_program"]
 
     def test_lint_catches_planted_violation(self):
         from raytpu.analysis.core import run_rule_on_source

@@ -464,10 +464,30 @@ def test_llm_deployment_streams_olmoe():
         dep.shutdown()
 
 
+def test_routed_configs_are_served_by_llamas_description():
+    """``MixtralConfig`` and ``OlmoeConfig`` say how they are served with
+    no line of their own: llama's entry points, and the count's shape."""
+    dense = llama_mod.LlamaConfig.tiny().serving
+    assert dense.expert_counts is None
+    for c in (TINY, MixtralConfig.tiny()):
+        assert "serving" not in vars(type(c))
+        served = c.serving
+        assert (served.prefill, served.prefill_chunk, served.decode,
+                served.params) == (dense.prefill, dense.prefill_chunk,
+                                   dense.decode, dense.params)
+        assert (served.kv_heads, served.head_dim) == (c.n_kv_head, c.head_dim)
+        assert served.expert_counts == (c.n_layer, c.n_expert)
+    _, params = model_and_params(TINY)
+    eng = InferenceEngine(TINY, params, **ENGINE)
+    assert np.asarray(eng.stats()["expert_tokens"]).shape \
+        == TINY.serving.expert_counts
+
+
 def test_errors_name_the_families_that_exist():
     with pytest.raises(ValueError, match="'olmoe'"):
         serve.LLMDeployment._target(model="moe")
-    with pytest.raises(TypeError, match="OlmoeConfig"):
+    # The engine knows no family by name: it says what the config lacks.
+    with pytest.raises(TypeError, match="object does not say.*`serving`"):
         InferenceEngine(object(), {})
     _, params = model_and_params(TINY)
     with pytest.raises(ValueError, match="one device"):
