@@ -85,7 +85,11 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
     a full prefill (flash), two sequences prefilled chunk by chunk
     through the paged cache (paged kernel, T > 1, the second chunk
     attending the first's pages), then one ragged decode step for both
-    (paged kernel, T = 1). With ``chips > 1`` both paths run sharded as
+    (paged kernel, T = 1), and one more over contexts that take the
+    kernel through several blocks of pages: nearly all the positions
+    the model has, a third of them, and one chunk, behind one table
+    (``decode_long``: a long row, a short one's dead tail). With
+    ``chips > 1`` both paths run sharded as
     ``InferenceEngine(tp=chips)`` shards them, and the kernel engine is
     checked for spread: quarter pool shards, no all-gather of a pool in
     its compiled decode."""
@@ -101,9 +105,12 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
                                     paged_attn="reference")
     params = init_params(GPT2(cfg), cfg, seed=0, batch=1)
     rng = np.random.default_rng(0)
-    toks = {sid: rng.integers(0, cfg.vocab_size, size=2 * chunk + 1,
-                              dtype=np.int32) for sid in ("a", "b")}
-    lens = {"a": 2 * chunk, "b": chunk}  # ragged contexts at decode
+    chunks = cfg.block_size // chunk - 1  # 960 tokens of GPT-2's 1024
+    # Ragged contexts at decode: a and b, then c, d and b.
+    lens = {"a": 2 * chunk, "b": chunk, "c": chunks * chunk,
+            "d": max(chunks // 3, 1) * chunk}
+    toks = {sid: rng.integers(0, cfg.vocab_size, size=n + 1, dtype=np.int32)
+            for sid, n in lens.items()}
 
     facts: dict = {"mosaic_calls": {}}
     logits = {}
@@ -124,9 +131,7 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
                      cache.prefill_dests("whole", chunk, chunk),
                      cache.k, cache.v)
             out["prefill"], cache.k, cache.v = prefill(*pargs)
-            # b then a, so the last chunk is a's second: the one that
-            # attends pages an earlier chunk wrote.
-            for sid in ("b", "a"):
+            def prefill_in_chunks(sid):
                 cache.allocate(sid, lens[sid] + 1)
                 for start in range(0, lens[sid], chunk):
                     args = (p, toks[sid][None, start:start + chunk],
@@ -135,17 +140,31 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
                             cache.table_array(
                                 [sid], cache.num_seq_pages(sid)),
                             cache.k, cache.v)
-                    out["chunk"], cache.k, cache.v = chunked(*args)
-            pos = np.asarray([lens["a"], lens["b"]], np.int32)
-            dargs = (p, np.asarray([toks["a"][lens["a"]],
-                                    toks["b"][lens["b"]]], np.int32),
-                     pos,
-                     np.asarray([cache.slot("a", lens["a"]),
-                                 cache.slot("b", lens["b"])], np.int32),
-                     cache.table_array(["a", "b"],
-                                       cache.num_seq_pages("a")),
-                     pos + 1, cache.k, cache.v)
+                    last, cache.k, cache.v = chunked(*args)
+                return args, last
+
+            def decode_args(sids):
+                pos = np.asarray([lens[sid] for sid in sids], np.int32)
+                return (p, np.asarray([toks[sid][lens[sid]] for sid in sids],
+                                      np.int32),
+                        pos,
+                        np.asarray([cache.slot(sid, lens[sid])
+                                    for sid in sids], np.int32),
+                        cache.table_array(
+                            sids, max(map(cache.num_seq_pages, sids))),
+                        pos + 1, cache.k, cache.v)
+
+            # b then a, so the last chunk is a's second: the one that
+            # attends pages an earlier chunk wrote.
+            prefill_in_chunks("b")
+            args, out["chunk"] = prefill_in_chunks("a")
+            dargs = decode_args(["a", "b"])
             out["decode"] = decode(*dargs)[0]
+            for sid in ("whole", "a"):  # room for the long ones
+                cache.free(sid)
+            for sid in ("c", "d"):
+                prefill_in_chunks(sid)
+            out["decode_long"] = decode(*decode_args(["c", "d", "b"]))[0]
             if name == "kernel":
                 facts["mosaic_calls"] = {
                     "prefill": _mosaic_calls(prefill, *pargs),

@@ -7,13 +7,33 @@ head_dim) HBM traffic per generated token — then run dense fp32
 attention over mostly padding.  This module replaces that with a Pallas
 kernel that reads KV pages **in place**, vLLM-PagedAttention style:
 
-- grid ``(batch, kv_head_group, q_blocks, kv_pages)``; the innermost
-  page dimension is sequential so online-softmax state (m / l / acc)
-  lives in VMEM scratch across it.
-- the block table and per-sequence query-start positions are
-  scalar-prefetch operands: the k/v BlockSpec index maps translate the
-  page-grid coordinate through the block table, so each step DMAs one
-  ``[page_size, group_lanes]`` tile straight out of the pool.
+- grid ``(batch, kv_head_group, q_blocks)``, run in order; a grid step
+  is one sequence's query rows against all of that sequence's live
+  pages, taken ``pages_per_block`` at a time by a loop inside the kernel
+  whose trip count comes from the prefetched position. Online-softmax
+  state (m / l / acc) lives in VMEM scratch across the loop.
+- the pools enter whole and stay in HBM (``memory_space=pl.ANY``), as
+  they are held. The block table and the per-sequence query-start
+  positions are scalar-prefetch operands: the kernel looks a block's
+  live pages up in the table and copies each by a DMA of its own, K and
+  V, all started together, into one half of a double buffer
+  ``[2, pages_per_block, page_size, lanes]`` a pool. While a block is
+  computed the next one's copies are in flight; the pass that finishes
+  a sequence starts the next grid step's first block, so the copies'
+  latency is exposed once a call and not once a sequence.
+- a block is computed on whole: ``q [rows, lanes] x k [slots, lanes]^T``
+  with ``slots = pages_per_block * page_size`` (at least 128, so the
+  scores fill a lane tile), the position mask, one update of m, l and
+  the accumulator, ``p x v``. ``_pages_per_block`` sizes it from what
+  the call can see.
+- only live pages are read, so the bytes moved are the live pages' K
+  and V rows and nothing grows with the table's width: a dead column
+  costs no copy, no flops and no pass of the loop (the kernel this
+  replaced paid a quarter of a microsecond of grid step for every
+  column of the table, live or dead: 7.4 ms of GPT-2 XL's 17.3 ms
+  decode period; PERF.md, PR 31). A block's slots past the live pages
+  are masked, and their rows of the V buffer zeroed (0 x a stale NaN is
+  NaN).
 - a pool is ``[num_pages, page_size, kv_heads * head_dim]``, a token's
   heads side by side in one row, and is blocked as it is held: a block
   takes kv heads that fill whole 128-lane tiles (two or more at
@@ -26,9 +46,6 @@ kernel that reads KV pages **in place**, vLLM-PagedAttention style:
   one dense product scores every head, and the wrapper keeps each
   row's own lanes of the result. The MXU does ``group`` times the
   needed work; the pool bytes read, which bound decode, do not change.
-- pages past a sequence's live length are *clamped* to the last live
-  page in the index map — the Mosaic pipeline sees the same block again
-  and skips the fetch — and ``pl.when`` skips their flops.
 - GQA folds query heads onto their kv head (row = t*rep + r, matching
   ``jnp.repeat``), so one grid step attends all query heads sharing the
   group's kv heads.
@@ -218,51 +235,107 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, positions,
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(bt_ref, qs_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr,
-                  *, sm_scale, page_size, bq_t, rep, n_pg):
-    """One grid step: the query heads of one kv-head group, query-token
-    block iq, attending page ik of sequence b. Scratch carries the
-    online softmax across the (sequential) page dimension.
+def _paged_kernel(bt_ref, qs_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, half_ref, m_scr, l_scr, acc_scr,
+                  *, sm_scale, bq_t, rep, n_pg, n_grp, n_qb, span):
+    """One grid step: the query heads of one kv-head group and query-token
+    block iq of sequence b, attending that sequence's live pages a block
+    of ``ppb`` at a time. A block's pages are copied by one DMA each into
+    half of a double buffer while the other half is computed on; the step
+    that finishes a sequence starts the next step's first block, so the
+    copies' latency is exposed once a call. ``half_ref`` carries which
+    half is current from step to step.
 
     A group's heads sit side by side on the lane axis. Each query row
     is zero outside its own head's lanes, so one dense
-    ``[rows, lanes] x [page, lanes]^T`` product yields every head's
+    ``[rows, lanes] x [slots, lanes]^T`` product yields every head's
     scores, and row r of ``p @ v`` is right on the lanes of r's head
     (the wrapper keeps those and drops the rest)."""
-    b = pl.program_id(0)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    b, j, iq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    step = (b * n_grp + j) * n_qb + iq
+    n_steps = pl.num_programs(0) * n_grp * n_qb
     rows, lanes = acc_scr.shape
+    _, ppb, page_size, _ = k_buf.shape
+    slots = ppb * page_size
+
+    def last_page(b_, iq_):
+        # The last page any row of q block iq_ may see.
+        last = (qs_ref[b_] + iq_ * bq_t + bq_t - 1) // page_size
+        return jnp.clip(last, 0, n_pg - 1)
+
+    def live_pages(last_, i_):
+        # Of block i_'s pages, those up to the sequence's last.
+        return jnp.minimum(ppb, last_ + 1 - i_ * ppb)
+
+    def page_copies(page, j_, half, p):
+        # A page of the group's heads: ``span`` lanes from the group's
+        # first, out of each of the page's rows.
+        first = pl.multiple_of(j_ * lanes, _LANES) if n_grp > 1 else 0
+        return [pltpu.make_async_copy(
+            hbm.at[page, :, pl.ds(first, span)],
+            buf.at[half, p, :, pl.ds(0, span)], sems.at[i, half])
+            for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
+
+    def start_block(b_, j_, iq_, i_, half):
+        @pl.loop(0, live_pages(last_page(b_, iq_), i_))
+        def _start_page(p):
+            for copy in page_copies(bt_ref[b_, i_ * ppb + p], j_, half, p):
+                copy.start()
+
+    @pl.when(step == 0)
+    def _first_block():
+        half_ref[0] = 0
+        start_block(b, j, iq, 0, 0)
+
+    m_scr[...] = jnp.full((rows, _LANES), _NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros((rows, _LANES), jnp.float32)
+    acc_scr[...] = jnp.zeros((rows, lanes), jnp.float32)
+
     q_start = qs_ref[b]  # absolute position of query token 0
+    last = last_page(b, iq)
+    n_blk = last // ppb + 1
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full((rows, _LANES), _NEG_INF, jnp.float32)
-        l_scr[...] = jnp.zeros((rows, _LANES), jnp.float32)
-        acc_scr[...] = jnp.zeros((rows, lanes), jnp.float32)
+    def block(i, half):
+        # The block after this one: the sequence's next, or the first
+        # of the next grid step's.
+        ends = i + 1 == n_blk
+        nxt = step + 1
 
-    # The last page any row of this q block may see; later pages are
-    # clamped in the index maps (no DMA) and skipped here (no flops).
-    live = ik * page_size <= q_start + iq * bq_t + bq_t - 1
+        @pl.when(jnp.logical_or(~ends, nxt < n_steps))
+        def _next_block():
+            start_block(jnp.where(ends, nxt // (n_grp * n_qb), b),
+                        jnp.where(ends, nxt // n_qb % n_grp, j),
+                        jnp.where(ends, nxt % n_qb, iq),
+                        jnp.where(ends, 0, i + 1), 1 - half)
 
-    @pl.when(live)
-    def _compute():
+        live = live_pages(last, i)
+
+        @pl.loop(0, live)
+        def _wait_page(p):
+            for copy in page_copies(0, j, half, p):
+                copy.wait()
+
+        # Pages of the block past the live ones were not fetched: their
+        # slots are masked below, but 0 x a stale NaN of V is NaN.
+        @pl.loop(live, ppb)
+        def _zero_page(p):
+            v_buf[half, p] = jnp.zeros((page_size, lanes), v_buf.dtype)
+
         q = q_ref[0, 0]  # [rows, lanes]
-        kb = k_ref[0].astype(q.dtype)  # [page_size, lanes]
-        vb = v_ref[0].astype(q.dtype)
+        kb = k_buf[half].reshape(slots, lanes).astype(q.dtype)
+        vb = v_buf[half].reshape(slots, lanes).astype(q.dtype)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         # Rows run (head in group, token, query head of the kv head):
         # row r holds query token iq*bq_t + (r mod bq_t*rep) // rep;
-        # column c is slot ik*page_size + c. Rows past the live ones
-        # are padding.
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
+        # column c is slot i*slots + c. Rows past the live ones are
+        # padding.
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, slots), 0)
         tok = iq * bq_t + jax.lax.div(
             jax.lax.rem(row, bq_t * rep), rep)
-        slot = ik * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
+        slot = i * slots + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, slots), 1)
         s = jnp.where(slot <= q_start + tok, s, _NEG_INF)
 
         m_prev = m_scr[:, :1]
@@ -277,11 +350,11 @@ def _paged_kernel(bt_ref, qs_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p.astype(q.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        return 1 - half
 
-    @pl.when(ik == n_pg - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    half_ref[0] = jax.lax.fori_loop(0, n_blk, block, half_ref[0])
+    l = jnp.maximum(l_scr[:, :1], 1e-30)
+    o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def _fit_q_block(t: int, want: int) -> int:
@@ -316,6 +389,38 @@ def _kv_heads_per_block(kv: int, d: int, rows_per_head: int = _MXU_ROWS
             if kv % g == 0 and (g * d) % _LANES == 0] + [kv]
     few = [g for g in fits if g * rows_per_head <= _MXU_ROWS]
     return max(few) if few else fits[0]
+
+
+def _whole_lane_tiles(lanes: int) -> int:
+    """``lanes`` rounded up to whole 128-lane tiles: what a row of that
+    many features occupies in HBM and in VMEM."""
+    return -(-lanes // _LANES) * _LANES
+
+
+# VMEM the two pools' double buffers may take, and a block's float32
+# scores.
+_KV_BUFFER_BYTES = 4 << 20
+_SCORE_BYTES = 1 << 20
+
+
+def _pages_per_block(page_size: int, rows: int, lanes: int,
+                     itemsize: int) -> int:
+    """How many pages of a sequence one pass of the kernel's loop takes.
+    At least as many as give the scores a whole 128-lane tile (8 pages
+    of 16 slots), and twice that where the four buffers (K and V, two
+    halves each, rows padded to whole lane tiles) and the
+    ``[rows, slots]`` scores stay inside their budgets: the copies in
+    flight are one block, and a block of 16 pages keeps the HBM busier
+    than one of 8 (84 against 78 % of the byte roofline at GPT-2 XL's
+    decode shape and 1,000 tokens of context; PERF.md, PR 31). No more
+    than that: a sequence's last block is computed on whole, half of it
+    dead slots on average, and nothing measured says a wider one pays
+    for them."""
+    least = -(-_LANES // page_size)
+    row = _whole_lane_tiles(lanes) * itemsize
+    fits = min(_KV_BUFFER_BYTES // (4 * page_size * row),
+               _SCORE_BYTES // (rows * page_size * 4))
+    return max(least, min(2 * least, fits // least * least))
 
 
 @functools.partial(
@@ -356,30 +461,38 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
     q_start = positions[:, 0].astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
 
-    def q_index(b_, j, iq, ik, bt_ref, qs_ref):
-        del ik, bt_ref, qs_ref
+    ppb = _pages_per_block(page_size, rows, lanes, k_pages.dtype.itemsize)
+
+    def q_index(b_, j, iq, bt_ref, qs_ref):
+        del bt_ref, qs_ref
         return (b_, j, iq, 0)
 
-    def kv_index(b_, j, iq, ik, bt_ref, qs_ref):
-        # Clamp dead pages to the last live one: the pipeline sees a
-        # repeated block and skips the DMA.
-        last = (qs_ref[b_] + iq * bq_t + bq_t - 1) // page_size
-        last = jnp.clip(last, 0, n_pg - 1)
-        return (bt_ref[b_, jnp.minimum(ik, last)], 0, j)
-
+    # A copy moves whole 128-lane tiles. Mosaic holds a pool whose rows
+    # are no multiple of 128 lanes wide (GPT-2 XL's 1600) padded to
+    # one, pool and buffer alike, and refuses a DMA of a slice that
+    # ends inside the last tile ("Slice shape along dimension 2 must be
+    # aligned to tiling"); one that takes the padding along compiles,
+    # and nothing reads the padding. The interpreter holds none.
+    span = lanes if interpret else _whole_lane_tiles(lanes)
     kernel = functools.partial(
-        _paged_kernel, sm_scale=sm_scale, page_size=page_size,
-        bq_t=bq_t, rep=rep, n_pg=n_pg)
+        _paged_kernel, sm_scale=sm_scale, bq_t=bq_t, rep=rep, n_pg=n_pg,
+        n_grp=n_grp, n_qb=n_qb, span=span)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_grp, n_qb, n_pg),
+        grid=(b, n_grp, n_qb),
         in_specs=[
             pl.BlockSpec((1, 1, rows, lanes), q_index),
-            pl.BlockSpec((1, page_size, lanes), kv_index),
-            pl.BlockSpec((1, page_size, lanes), kv_index),
+            # The pools stay in HBM, as they are held; the kernel copies
+            # the live pages itself.
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, lanes), q_index),
         scratch_shapes=[
+            pltpu.VMEM((2, ppb, page_size, lanes), k_pages.dtype),
+            pltpu.VMEM((2, ppb, page_size, lanes), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # (K or V, buffer half)
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, lanes), jnp.float32),
@@ -387,13 +500,11 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
     )
     kwargs = {}
     if not interpret:
+        # The buffer half and the copies in flight are carried from one
+        # grid step to the next: every dimension runs in order.
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=(
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.ARBITRARY,
-            ))
+                pltpu.GridDimensionSemantics.ARBITRARY,) * 3)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -167,6 +167,9 @@ _TPU_CASES = {
     "paged_decode_124m": (_paged, _paged_shapes(8, 1, 12, 12, 64)),
     "paged_chunk_124m": (_paged, _paged_shapes(1, 64, 12, 12, 64)),
     "paged_decode_gqa_d128": (_paged, _paged_shapes(8, 1, 32, 8, 128)),
+    # GPT-2 XL: rows of 1600 features, no whole number of 128-lane tiles
+    # (the kernel's copies take the last tile's padding along).
+    "paged_decode_xl": (_paged, _paged_shapes(8, 1, 25, 25, 64, pages=385)),
 }
 
 
@@ -191,6 +194,8 @@ class TestTpuLowering:
         if shapes[0].shape[0] == 1:  # a batch of one cannot be split
             batch = None
         heads = "tp" if "tp" in axes else None
+        if case == "paged_decode_xl":  # 25 heads: whole on every chip
+            heads = None
         if case.startswith("flash"):
             specs = (P(batch, heads),) * 3
         else:

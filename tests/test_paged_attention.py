@@ -174,6 +174,24 @@ class TestHeadsPerBlock:
 
         assert _kv_heads_per_block(kv, d, rows_per_head) == want
 
+    @pytest.mark.parametrize("page_size,rows,lanes,itemsize,want", [
+        (16, 32, 1600, 2, 16),    # GPT-2 XL decode: 256 slots, 3.4 MB
+        (16, 16, 2048, 2, 16),    # OLMoE decode: 4 MiB of buffers, the most
+        (16, 16, 4096, 2, 8),     # rows twice as wide: a lane tile of slots
+        (16, 16, 768, 2, 16),     # GPT-2 124M: never more than 256 slots
+        (16, 512, 128, 2, 16),    # a chunk of 256 tokens, two heads of 64
+        (16, 2048, 256, 2, 8),    # GQA chunk: the scores hold it to 128
+        (4, 8, 32, 4, 64),        # the tests' float32 pages of 4 ...
+        (8, 8, 256, 4, 32),       # ... and of 8
+        (128, 8, 1024, 2, 2),     # a page that is a lane tile itself
+        (16, 16, 16384, 2, 8),    # no budget goes under a lane tile
+    ])
+    def test_pages_a_block_follow_the_shapes(self, page_size, rows, lanes,
+                                             itemsize, want):
+        from raytpu.ops.paged_attention import _pages_per_block
+
+        assert _pages_per_block(page_size, rows, lanes, itemsize) == want
+
     @pytest.mark.parametrize("t", [1, 40])
     def test_grouped_and_single_head_blocks_agree_with_reference(self, t):
         # kv 4 x d 128: a decode block takes all four heads, a 40-token
@@ -185,6 +203,113 @@ class TestHeadsPerBlock:
         out = paged_attention(*args, force="interpret")
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5)
+
+
+def _block_setup(rng, positions, t, heads, kv, d, page_size, width, dtype):
+    """A pool, tables ``[len(positions), width]`` and query positions for
+    sequences whose last query token stands at ``positions``: live pages
+    distinct, every dead column naming the scratch page 0."""
+    b = len(positions)
+    last = np.asarray(positions)
+    live = last // page_size + 1
+    num_pages = int(live.sum()) + 3
+    q = jnp.asarray(rng.standard_normal((b, t, heads, d)), dtype)
+    k, v = (np.asarray(rng.standard_normal((num_pages, page_size, kv * d)),
+                       np.float32) for _ in range(2))
+    bt = np.zeros((b, width), np.int32)
+    owned = np.split(rng.permutation(np.arange(1, int(live.sum()) + 1)),
+                     np.cumsum(live)[:-1])
+    for i, pages in enumerate(owned):
+        bt[i, :len(pages)] = pages
+    pos = np.maximum(last[:, None] - (t - 1) + np.arange(t)[None], 0)
+    return (q, k, v, jnp.asarray(bt), jnp.asarray(pos, jnp.int32),
+            np.concatenate(owned))
+
+
+# The shapes the cells run, in small: (heads, kv heads, head_dim).
+_XL_LIKE = (5, 5, 64)      # 320 lanes in one group, not whole lane tiles
+_OLMOE_LIKE = (2, 2, 128)  # kv heads of 128, one query head each
+_GQA_128 = (8, 2, 128)     # the same under GQA
+
+
+class TestBlockPath:
+    """The kernel takes a sequence's pages a block at a time
+    (``_pages_per_block``): contexts that end at every kind of place in
+    a block, tables far wider than the sequences, the cells' shapes in
+    small, against the reference."""
+
+    # Each case: heads/kv/d, t, page size, pool dtype, and the positions
+    # of the batch's last query tokens as a function of a block's slots.
+    CASES = {
+        "inside_a_blocks_first_page": (
+            _XL_LIKE, 1, 16, jnp.bfloat16, lambda n, ps: [n + 1, 2 * n + 5]),
+        "on_a_page_boundary": (
+            _XL_LIKE, 1, 16, jnp.bfloat16,
+            lambda n, ps: [n + ps - 1, n + ps, 3 * ps - 1]),
+        "on_a_block_boundary": (
+            _XL_LIKE, 1, 16, jnp.bfloat16, lambda n, ps: [n - 1, n, 2 * n - 1]),
+        "one_token_of_context": (
+            _OLMOE_LIKE, 1, 16, jnp.bfloat16, lambda n, ps: [0, 0, n]),
+        "table_blocks_wider_than_the_longest": (
+            _OLMOE_LIKE, 1, 16, jnp.bfloat16, lambda n, ps: [ps + 2, 5, n // 2],
+            5),
+        "rows_of_very_different_lengths": (
+            _GQA_128, 1, 16, jnp.bfloat16,
+            lambda n, ps: [0, 3 * n + 7, ps, 2 * n - 1, n + ps + 1, 9]),
+        "xl_like_float32_pages_of_4": (
+            _XL_LIKE, 1, 4, jnp.float32, lambda n, ps: [n - 1, n, 2 * n + 2, 1]),
+        "gqa_float32_pages_of_8": (
+            _GQA_128, 1, 8, jnp.float32, lambda n, ps: [2 * n, n + 3, 0]),
+        "olmoe_like_float32_pages_of_8": (
+            _OLMOE_LIKE, 1, 8, jnp.float32, lambda n, ps: [n, 2 * n + 9]),
+        "chunk_inside_the_second_block": (
+            _XL_LIKE, 24, 16, jnp.bfloat16, lambda n, ps: [n + 30]),
+        "chunk_across_a_block_boundary": (
+            _GQA_128, 24, 8, jnp.float32, lambda n, ps: [n + 10]),
+        "chunk_of_a_prompts_first_tokens": (
+            _OLMOE_LIKE, 40, 4, jnp.float32, lambda n, ps: [39]),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_matches_reference(self, case):
+        from raytpu.ops.paged_attention import _pages_per_block
+
+        (heads, kv, d), t, page_size, dtype, where, *spare = self.CASES[case]
+        # The most slots a block of these shapes can take: whatever rule
+        # sizes it, every case's positions straddle the blocks it makes.
+        slots = page_size * _pages_per_block(
+            page_size, 8, kv * d, jnp.dtype(dtype).itemsize)
+        positions = where(slots, page_size)
+        blocks = max(positions) // slots + 1 + (spare[0] if spare else 0)
+        rng = np.random.default_rng(len(case))
+        q, k, v, bt, pos, _ = _block_setup(
+            rng, positions, t, heads, kv, d, page_size,
+            blocks * slots // page_size, dtype)
+        k, v = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+        ref = paged_attention_reference(q, k, v, bt, pos, sm_scale=d ** -0.5)
+        out = paged_attention(q, k, v, bt, pos, force="interpret")
+        tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=tol, rtol=tol)
+
+    def test_pages_nobody_owns_cannot_poison_the_result(self):
+        """The scratch page and every page no sequence owns hold NaN: a
+        dead column's page is never fetched, and a slot that was not
+        fetched is masked and its V row zero (0 x a stale NaN is NaN)."""
+        rng = np.random.default_rng(5)
+        positions = [0, 37, 150, 129]
+        q, k, v, bt, pos, owned = _block_setup(
+            rng, positions, 1, 4, 2, 16, 4, 80, jnp.float32)
+        ref = paged_attention_reference(
+            q, jnp.asarray(k), jnp.asarray(v), bt, pos, sm_scale=0.25)
+        nobody = np.setdiff1d(np.arange(k.shape[0]), owned)
+        assert 0 in nobody and len(nobody) == 3
+        k[nobody], v[nobody] = np.nan, np.nan
+        out = np.asarray(paged_attention(
+            q, jnp.asarray(k), jnp.asarray(v), bt, pos, force="interpret"))
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out, np.asarray(ref), atol=1e-5, rtol=1e-5)
 
 
 class TestScatterKVSlots:

@@ -91,7 +91,8 @@ class TestPhasesAtTinySize:
         facts = chip_smoke.logits_phase(
             TINY, page_size=OPTIONS["page_size"],
             chunk=OPTIONS["prefill_chunk"], chips=chips, tol=1e-4)
-        assert set(facts["rel_err"]) == {"prefill", "chunk", "decode"}
+        assert set(facts["rel_err"]) == {"prefill", "chunk", "decode",
+                                         "decode_long"}
         if chips > 1:
             assert facts["spread"]["pool_shard_heads"] == [1, 1]
 
