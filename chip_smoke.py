@@ -14,6 +14,13 @@ random weights), in the one process that owns the chip(s):
 3. *train*: ``JaxTrainer(...).fit()`` for a few steps of
    ``make_train_step`` at batch 8 x 1024 with the default attention
    (flash forward and fused backward).
+4. *window* (one chip): a small model with window layers among full ones
+   (``WINDOW_MODEL``: Mellum2's block at a fifth of its hidden size)
+   through ``InferenceEngine`` over two kinds of KV pool: a prompt three
+   windows long in chunks, one a window and a half long whole, then
+   decodes, every window sliding; kernel path against reference path.
+   (Mellum2 itself against its plain reference at the published widths:
+   ``chip_mellum.py``.)
 
     python chip_smoke.py              # one chip
     python chip_smoke.py --chips 4    # one process over the four-chip host:
@@ -48,6 +55,15 @@ import numpy as np
 # (measured on the v5e, see PERF.md; 1e-7 in float32 on the CPU), so 5e-2
 # leaves room without letting a wrong mask or a misplaced head through.
 LOGIT_TOL = 5e-2
+
+# The window phase's model has a routed layer (2 experts of 8 a token,
+# weights renormalised): where the two paths' rounding makes a token
+# choose another second expert, that row moves by more than rounding
+# does. 0.033 and 0.047 measured on the v5e, in two runs (PERF.md
+# section 6, PR 32: before the reference path was forced on the kernel
+# path's tokens the two parted ways at a rounding and read 1.1). At
+# Mellum2's size a window ignored reads 0.26 and more (chip_mellum.py).
+WINDOW_LOGIT_TOL = 1e-1
 
 ENGINE_OPTIONS = {"page_size": 16, "max_num_seqs": 8, "prefill_chunk": 64}
 TRAIN_BATCH = 8
@@ -185,6 +201,71 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
         facts["rel_err"][step] = err
         check(err <= tol, f"{step} logits differ from the reference path "
               f"by {err:.3g} of the logit range (tolerance {tol})")
+    return facts
+
+
+def window_model():
+    """Mellum2's block, small: 8 query heads on 2 kv heads of 128 over a
+    hidden size of 512 (not 8 x 128), one period S S S F with a window
+    of 256, YaRN on the full layer, 8 experts of 256 of which a token
+    takes 2."""
+    from raytpu.models.llama import Rope
+    from raytpu.models.mixtral import MellumConfig
+
+    return MellumConfig(
+        vocab_size=8192, block_size=2048, n_layer=4, n_head=8, n_kv_head=2,
+        n_embd=512, head_dim=128, n_inter=256, n_expert=8,
+        n_expert_per_tok=2, window=256,
+        full_rope=Rope(theta=500000.0, yarn_factor=4.0,
+                       original_max_position=512))
+
+
+def window_phase(model_config, *, page_size: int, chunk: int,
+                 tol: float = WINDOW_LOGIT_TOL) -> dict:
+    """Window layers behind their own kind of pool, kernels against
+    references: a prompt of three windows and a bit (chunked: the paged
+    kernel at ``T > 1`` from the window's first page), one of a window
+    and a half (whole: the windowed flash forward, its rows left of the
+    window written to scratch), then eight decodes of both, each of the
+    last row's and the decodes' logits compared within ``tol``."""
+    from chip_mellum import DECODES, served_rows
+    from raytpu.models.mixtral import Mixtral, init_params
+
+    cfg = model_config
+    window = cfg.window
+    check(chunk >= window + window // 2, "the whole prompt has to be "
+          "longer than the window")
+    params = init_params(Mixtral(cfg), cfg, seed=0, batch=1)
+    rng = np.random.default_rng(0)
+    prompts = [[int(t) for t in rng.integers(1, cfg.vocab_size, size=n)]
+               for n in (3 * window + 5, window + window // 2)]
+    options = dict(page_size=page_size, max_num_seqs=2,
+                   max_model_len=4 * window + 2 * DECODES,
+                   prefill_chunk=chunk)
+    reference = dataclasses.replace(cfg, attn_impl="reference",
+                                    paged_attn="reference")
+    got, sampled, facts = served_rows(cfg, params, prompts, options)
+    facts["prompt_tokens"] = [len(p) for p in prompts]
+    check(facts["window_pages_released"] > 0
+          and bool(facts["programs"]["chunk_prefill_compiles"])
+          and bool(facts["programs"]["prefill_compiles"]),
+          f"the phase did not slide a window through both prefill "
+          f"programs: {facts}")
+    # The reference path is forced on the tokens the kernel path sampled
+    # (a seeded model's next token turns on a rounding): each prompt and
+    # its decoded tokens as one prompt, judged on its last rows.
+    forced = [p + s[:DECODES] for p, s in zip(prompts, sampled)]
+    want, _, _ = served_rows(reference, params, forced, options,
+                             tail=DECODES + 1, new_tokens=1)
+    facts["rel_err"] = {}
+    for prompt, rows, ref in zip(prompts, got, want):
+        check(bool(np.isfinite(rows).all()),
+              f"prompt of {len(prompt)}: logits not finite")
+        err = float(np.abs(rows - ref).max() / np.abs(ref).max())
+        facts["rel_err"][f"prompt_{len(prompt)}"] = err
+        check(err <= tol, f"prompt of {len(prompt)}: window-layer logits "
+              f"differ from the reference path by {err:.3g} of the logit "
+              f"range (tolerance {tol})")
     return facts
 
 
@@ -576,7 +657,9 @@ def main(argv=None) -> int:
                 serve_phase, cfg, ENGINE_OPTIONS, chips=args.chips)),
             ("train", functools.partial(
                 train_phase, cfg, chips=args.chips)),
-        )
+        ) + ((("window", functools.partial(
+            window_phase, window_model(), page_size=128, chunk=512)),)
+            if args.chips == 1 else ())
         for name, phase in phases:
             t0 = time.perf_counter()
             summary[name] = phase()

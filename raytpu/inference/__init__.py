@@ -20,7 +20,10 @@ Layout:
   arrays (one per layer, written in place by the engine's programs,
   which are given them donated), per-sequence block tables with per-page
   refcounts (shared prefix pages), allocate / allocate_shared /
-  extend / free, utilization accounting. Decode never reallocates.
+  extend / free, utilization accounting. Decode never reallocates. A
+  model with window layers among full ones has two kinds of pool there
+  and a block table a kind: a window layer's table slides, its pages
+  left of the window given back (``slide``).
 - :mod:`raytpu.inference.prefix_cache` — content-hash prompt-page
   cache: chained page hashes over token ids, retain-on-release of
   unreferenced prompt pages, LRU eviction under allocation pressure.

@@ -117,7 +117,7 @@ class KVHandoffSource:
         """
         eng = self.engine
         pc = eng.prefix_cache
-        if pc is None:
+        if pc is None:  # off, or a model with window layers: never on
             return None
         self.sweep()
         prompt = [int(t) for t in prompt]

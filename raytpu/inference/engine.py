@@ -17,6 +17,13 @@ The TPU compile-once discipline, concretely:
   only the first time a bucket size appears. Dummy rows point at the
   scratch page (page 0) with ``context_len=1`` so padding attends to
   one masked-garbage slot and pollutes nothing.
+- **Two kinds of layer** (``serving.layer_windows``: window layers among
+  full ones) are two kinds of pool behind the one cache manager, and
+  the chunk and decode programs take ``dests`` and block tables as a
+  pair, full first (:mod:`raytpu.inference.kv_cache`). Before a program
+  writes a sequence's positions the engine slides its window table on
+  (``cache.slide``); a model of one kind gets the single arrays and the
+  programs it always had.
 
 The three jitted callables are constructed exactly once, by the one
 ``_build_program`` — the per-iteration loop
@@ -95,6 +102,14 @@ def _pow2_buckets(lo: int, hi: int) -> List[int]:
     return out
 
 
+def _width(block_tables) -> int:
+    """Columns of a program's block tables: of the one array, or of the
+    pair a model of two kinds of layer is given (both as wide)."""
+    import jax
+
+    return jax.tree_util.tree_leaves(block_tables)[0].shape[1]
+
+
 def _bucket_for(n: int, buckets: SequenceT[int]) -> int:
     for b in buckets:
         if b >= n:
@@ -125,7 +140,8 @@ class InferenceEngine:
     compute cost half their bytes once the caller lets go of them, and
     a caller that wants the original keeps it. ``stats()["param_bytes"]``
     has the copy's bytes by dtype, ``stats()["kv_pool_bytes"]`` the
-    bytes of the 2 x layers KV pools.
+    bytes of the 2 x layers KV pools (``kv_pool_bytes_by_kind``: of the
+    full and of the window layers').
 
     A program that fails while it runs (not while it is traced or
     compiled) has consumed the pools it was given and returned none:
@@ -140,7 +156,8 @@ class InferenceEngine:
                  prefill_buckets: Optional[SequenceT[int]] = None,
                  decode_buckets: Optional[SequenceT[int]] = None,
                  prefill_chunk: Optional[int] = None,
-                 enable_prefix_cache: bool = True,
+                 chunk_buckets: Optional[SequenceT[int]] = None,
+                 enable_prefix_cache: Optional[bool] = None,
                  tp: int = 1, mesh=None):
         import jax
 
@@ -179,9 +196,38 @@ class InferenceEngine:
         self.max_pages_per_seq = -(-self.max_model_len // page_size)
         if num_pages is None:
             num_pages = max_num_seqs * self.max_pages_per_seq + 1
+        # Chunked prefill: at most this many prompt tokens per engine
+        # step per sequence, so a long prompt never stalls in-flight
+        # decodes. Default = max_model_len, i.e. one-shot prefill (the
+        # chunk path still runs for prefix-hit tails, which start at a
+        # nonzero offset).
+        self.prefill_chunk = min(prefill_chunk or self.max_model_len,
+                                 self.max_model_len)
+        # Window layers: pools of their own, a seat for every sequence
+        # slot and one chunk's burst. Their pages are never shared, so
+        # such a model is served without the prefix cache (the default
+        # then) and an engine asked for one says why not.
+        window = next((w for w in served.layer_windows if w), None)
+        window_pages = None
+        if window is not None:
+            if enable_prefix_cache:
+                raise ValueError(
+                    "a model with window layers is served without the "
+                    "prefix cache: a prompt's window pages are given back "
+                    "as the window slides on, so there is nothing to share")
+            if tp > 1 or mesh is not None:
+                raise ValueError("a model with window layers is served on "
+                                 "one device: its pools are not sharded yet")
+            enable_prefix_cache = False
+            window_pages = PagedKVCache.window_pool_pages(
+                window, page_size, max_num_seqs, self.prefill_chunk)
+        elif enable_prefix_cache is None:
+            enable_prefix_cache = True
         self.cache = PagedKVCache(
             model_config.n_layer, num_pages, page_size, served.kv_heads,
-            served.head_dim, dtype=model_config.dtype)
+            served.head_dim, dtype=model_config.dtype,
+            layer_windows=served.layer_windows, window_pages=window_pages,
+            window_burst=self.prefill_chunk)
         # Tensor parallelism: shard the weights with the parallel-layer
         # rule table and the KV pools along their last dimension, whole
         # heads to a shard (a head's features are contiguous). Each jit
@@ -219,6 +265,12 @@ class InferenceEngine:
         # has consumed the arrays another thread would look at.
         self._kv_pool_bytes = sum(
             a.nbytes for a in self.cache.k + self.cache.v)
+        self._two_kinds = len(self.cache.kinds) > 1
+        self._kv_pool_bytes_by_kind = {
+            name: sum(k.nbytes + v.nbytes for k, v, of in zip(
+                self.cache.k, self.cache.v, self.cache.layer_kinds)
+                if of == kind)
+            for kind, name in enumerate(("full", "window"))}
         self._devices = sorted(f"{d.platform}:{d.id}"
                                for d in self.cache.k[0].devices())
         self.prefix_cache = (PrefixCache(self.cache)
@@ -226,17 +278,16 @@ class InferenceEngine:
         self.scheduler = Scheduler(self.cache, max_num_seqs=max_num_seqs,
                                    max_model_len=self.max_model_len,
                                    prefix_cache=self.prefix_cache)
-        # Chunked prefill: at most this many prompt tokens per engine
-        # step per sequence, so a long prompt never stalls in-flight
-        # decodes. Default = max_model_len, i.e. one-shot prefill (the
-        # chunk path still runs for prefix-hit tails, which start at a
-        # nonzero offset).
-        self.prefill_chunk = min(prefill_chunk or self.max_model_len,
-                                 self.max_model_len)
         self.prefill_buckets = sorted(prefill_buckets or _pow2_buckets(
             min(16, self.max_model_len), self.max_model_len))
-        self.chunk_buckets = _pow2_buckets(
-            min(16, self.prefill_chunk), self.prefill_chunk)
+        # A chunk's length buckets: pinned (``chunk_buckets``: fewer
+        # programs for a mix whose prompts end anywhere, their last
+        # chunks padded further) or powers of two up to the chunk.
+        self.chunk_buckets = sorted(chunk_buckets or _pow2_buckets(
+            min(16, self.prefill_chunk), self.prefill_chunk))
+        if self.chunk_buckets[-1] < self.prefill_chunk:
+            raise ValueError(f"chunk_buckets {self.chunk_buckets} hold no "
+                             f"chunk of prefill_chunk={self.prefill_chunk}")
         self.decode_buckets = sorted(decode_buckets or _pow2_buckets(
             1, max_num_seqs))
         # Block-table width buckets: decode/chunk pass tables trimmed
@@ -280,11 +331,11 @@ class InferenceEngine:
         self._chunk_fn = self._build_program(
             jax, "_chunk", served.prefill_chunk, self._chunk_compiles,
             lambda tokens, positions, dests, block_tables:
-            f"{tokens.shape[1]}x{block_tables.shape[1]}")
+            f"{tokens.shape[1]}x{_width(block_tables)}")
         self._decode_fn = self._build_program(
             jax, "_decode", served.decode, self._decode_compiles,
             lambda tokens, positions, dests, block_tables, context_lens:
-            f"{tokens.shape[0]}x{block_tables.shape[1]}")
+            f"{tokens.shape[0]}x{_width(block_tables)}")
 
     # ---- compiled steps (the ONLY jax.jit call site) ----------------
 
@@ -320,6 +371,14 @@ class InferenceEngine:
         if self._repl_sharding is not None:
             return self._jax.device_put(x, self._repl_sharding)
         return self._jnp.asarray(x)
+
+    def _by_kind(self, of):
+        """``of(kind)`` on the device, as a program takes what it is
+        given a kind of pool (``dests``, block tables): the one array,
+        or over two kinds the pair, full first."""
+        if not self._two_kinds:
+            return self._put(of(0))
+        return tuple(self._put(of(kind)) for kind in self.cache.kinds)
 
     def _count_experts(self, experts) -> None:
         """Add what one program of a routed model returned beside its
@@ -380,7 +439,9 @@ class InferenceEngine:
         preempted = self.scheduler.num_preemptions
         with recorder.step("infer.step", {
                 "decodes": 0, "bucket": 0, "table_width": 0,
-                "live_pages": 0}) as st:
+                "live_pages": 0, "live_pages_full": 0,
+                "live_pages_window": 0, "window_pages_released": 0,
+                "pages_owned_full": 0, "pages_owned_window": 0}) as st:
             with recorder.phase("infer.schedule") as ph:
                 waiting = len(self.scheduler.waiting)
                 plan = self.scheduler.schedule()
@@ -399,6 +460,10 @@ class InferenceEngine:
             st.attrs["compiled"] = self._programs_traced() - compiled
             st.attrs["preempted"] = \
                 self.scheduler.num_preemptions - preempted
+            if self._two_kinds:
+                st.attrs["pages_owned_full"] = self.cache.used_pages()
+                st.attrs["pages_owned_window"] = \
+                    self.cache.window_pages_owned()
 
             # Throughput gauges reflect THIS step — a step that moved no
             # tokens zeroes them, so autoscalers never read the last busy
@@ -491,10 +556,17 @@ class InferenceEngine:
             take, self.prefill_buckets if whole else self.chunk_buckets)
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, :take] = seq.tokens[start:start + take]
-        dests = self.cache.chunk_dests(seq.request_id, start, take, bucket)
+        # A chunk's rows are all in the window pools before any attends;
+        # of a whole prompt's (flash attention, no pool read) only what
+        # the decode after it will see.
+        end = start + take
+        released = self.cache.slide(seq.request_id,
+                                    end if whole else start, end)
+        dests = self._by_kind(lambda kind: self.cache.chunk_dests(
+            seq.request_id, start, take, bucket, kind))
         if whole:
             attrs.update(tokens=take, bucket=bucket)
-            fn, inputs = self._prefill_fn, (tokens, dests)
+            fn, inputs = self._prefill_fn, (self._put(tokens), dests)
         else:
             attrs.update(tokens=take, bucket=bucket, start=start)
             positions = np.zeros(bucket, dtype=np.int32)
@@ -503,16 +575,20 @@ class InferenceEngine:
             # reference gather pays O(table width), not O(P_max).
             p_used = _bucket_for(self.cache.num_seq_pages(seq.request_id),
                                  self.page_buckets)
-            tables = self.cache.table_array([seq.request_id], p_used)
+            tables = self._by_kind(lambda kind: self.cache.table_array(
+                [seq.request_id], p_used, kind=kind))
             if self.paged_attn_impl == "reference":
                 self._pages_gathered += p_used
-            fn, inputs = self._chunk_fn, (tokens, positions, dests, tables)
+            fn, inputs = self._chunk_fn, (
+                self._put(tokens), self._put(positions), dests, tables)
         logits, ks, vs, *experts = fn(
-            self._params, self.cache.k, self.cache.v,
-            *map(self._put, inputs))
+            self._params, self.cache.k, self.cache.v, *inputs)
         self.cache.k, self.cache.v = ks, vs
         self._count_experts(experts)
-        seq.cached_len = start + take
+        seq.cached_len = end
+        # The chunk's burst goes back: what the next query will see stays.
+        self.recorder.open.fields["window_pages_released"] += \
+            released + self.cache.slide(seq.request_id, end, end)
         self._register_prefix(seq)
         if seq.cached_len >= plen and not seq.generated:
             # The last chunk of a fresh prompt: its last REAL row's logit
@@ -542,6 +618,8 @@ class InferenceEngine:
                 dests = np.zeros(bucket, dtype=np.int32)
                 context_lens = np.ones(bucket, dtype=np.int32)
                 live_pages = 0  # what the paged kernel must read
+                ids = [s.request_id for s in seqs]
+                fields = recorder.open.fields
                 for i, seq in enumerate(seqs):
                     pos = seq.cached_len
                     tokens[i] = seq.tokens[-1]
@@ -549,19 +627,33 @@ class InferenceEngine:
                     dests[i] = self.cache.slot(seq.request_id, pos)
                     context_lens[i] = pos + 1
                     live_pages += self.cache.pages_for(pos + 1)
-                tables = self.cache.table_array(
-                    [s.request_id for s in seqs], P, batch=bucket)
+                if self._two_kinds:
+                    # The same for the window layers' pools, their tables
+                    # slid on to this step's positions first.
+                    wdests = np.zeros(bucket, dtype=np.int32)
+                    for i, seq in enumerate(seqs):
+                        pos = seq.cached_len
+                        fields["window_pages_released"] += self.cache.slide(
+                            seq.request_id, pos, pos + 1)
+                        wdests[i] = self.cache.slot(seq.request_id, pos, 1)
+                        fields["live_pages_window"] += \
+                            self.cache.pages_read(pos, 1)
+                    dests = (dests, wdests)
+                else:
+                    dests = (dests,)
+                dests = self._by_kind(lambda kind: dests[kind])
+                tables = self._by_kind(lambda kind: self.cache.table_array(
+                    ids, P, batch=bucket, kind=kind))
                 if self.paged_attn_impl == "reference":
                     self._pages_gathered += bucket * P
                 dec.attrs.update(batch=b, bucket=bucket)
-                recorder.open.fields.update(
+                fields.update(
                     decodes=b, bucket=bucket, table_width=P,
-                    live_pages=live_pages)
+                    live_pages=live_pages, live_pages_full=live_pages)
                 logits, ks, vs, *experts = self._decode_fn(
                     self._params, self.cache.k, self.cache.v,
                     self._put(tokens), self._put(positions),
-                    self._put(dests), self._put(tables),
-                    self._put(context_lens))
+                    dests, tables, self._put(context_lens))
                 self.cache.k, self.cache.v = ks, vs
                 for count in experts:
                     # Asked for now, it comes back beside the logits; left
@@ -591,8 +683,8 @@ class InferenceEngine:
                     lambda: cost_analysis_flops(
                         self._decode_fn, self._params, self.cache.k,
                         self.cache.v, self._put(tokens),
-                        self._put(positions), self._put(dests),
-                        self._put(tables), self._put(context_lens)))
+                        self._put(positions), dests, tables,
+                        self._put(context_lens)))
                 # Launch to logits on the host: the real step.
                 prof.observe_step(wait.t1 - launch.t0, flops=flops)
                 self._hbm_tick += 1
@@ -681,8 +773,15 @@ class InferenceEngine:
         it ``.launch``, ``.wait``, ``.sample``, plus what the stepping
         loop put around the step), and what the step ran: ``decodes``,
         ``bucket``, ``table_width``, ``live_pages`` (pages its decode
-        had to read), ``admitted``, ``compiled`` (programs traced in
-        it), ``preempted``, ``prefills`` (``request_id``, ``tokens``,
+        had to read of a sequence's whole context), ``live_pages_full``
+        and ``live_pages_window`` (pages its decode read in one full
+        layer, the same number, and in one window layer: the windows'
+        spans; 0 for a model without window layers),
+        ``window_pages_released`` (pages the window tables gave back in
+        the step), ``pages_owned_full`` and ``pages_owned_window`` (pages
+        sequences own in one pool of each kind when the step ends; both
+        0 for a model without window layers), ``admitted``, ``compiled``
+        (programs traced in it), ``preempted``, ``prefills`` (``request_id``, ``tokens``,
         ``bucket``, ``waited_s`` each) and ``error`` if it raised. A
         routed-expert model's steps also carry, over the step's programs,
         ``moe_assignments`` ((token, expert) pairs computed),
@@ -715,8 +814,10 @@ class InferenceEngine:
             # Bytes of the tree the programs take, by dtype, over all
             # shards: all in the compute type but the norms' leaves.
             "param_bytes": dict(self._param_bytes),
-            # Bytes of the 2 x layers KV pools, over all shards.
+            # Bytes of the 2 x layers KV pools, over all shards, and the
+            # same by kind of layer (``window``: 0 without such layers).
             "kv_pool_bytes": self._kv_pool_bytes,
+            "kv_pool_bytes_by_kind": dict(self._kv_pool_bytes_by_kind),
             "devices": list(self._devices),
             "num_preemptions": self.scheduler.num_preemptions,
             "running": len(self.scheduler.running),

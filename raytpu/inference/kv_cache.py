@@ -37,6 +37,33 @@ fail, so caching never reduces usable capacity.
 
 Host-side bookkeeping (block tables, free list, refcounts) is plain
 Python — it's O(pages touched) per step and never traced.
+
+**Pools by kind of layer.** A model whose layers are not all alike
+(``layer_windows``) has two kinds of pool under this one manager, and a
+sequence one block table a kind. A *full* layer's pool is everything
+above: ``num_pages`` pages, a table that grows with the sequence. A
+*window* layer (its query sees the ``window`` newest positions only)
+has a pool of ``window_pages`` pages of its own and a *sliding* table:
+``(first, pages)``, the sequence's logical pages ``first ..
+first + len(pages) - 1``. :meth:`slide` is called before every program
+that writes a sequence's positions ``[lo, hi)``: it takes pages on the
+right up to the one that holds ``hi - 1`` and gives back to the window
+free list every page wholly left of position ``lo - window + 1``, the
+oldest one any of the call's queries sees. So a sequence owns at most
+``window_seq_pages`` (``ceil(window / page_size) + 1``) window pages
+between calls, whatever its length, and ``window_burst_pages`` more
+while a chunk of ``window_burst`` tokens is written (all of a chunk's
+rows have to be in the pool before any attends). A window table is
+handed to a program as wide as the full one, scratch (0) in the columns
+it has given back: the paged kernel starts at the window's first page
+and never reads them, and a whole prompt's rows left of the window are
+written to the scratch page. Admission keeps one seat of
+``window_seq_pages`` a sequence and one burst for the pool, so
+:meth:`slide` never fails and only the full pools can run out:
+``allocate`` succeeds if both kinds have room. A model of one kind has
+exactly the pools and tables described above. Pages of a window pool are
+never shared: a prompt's are gone by the time another could use them,
+so the prefix cache and the KV hand-off refuse such a model.
 """
 
 from __future__ import annotations
@@ -62,10 +89,19 @@ class PagedKVCache:
             ``num_kv_heads * head_dim``, head ``i`` on features
             ``[i * head_dim, (i + 1) * head_dim)``.
         dtype: cache array dtype (the model's activation dtype).
+        layer_windows: per layer ``None`` (full) or the layer's window
+            in tokens; empty is every layer full. One window for all.
+        window_pages: a window pool's pages INCLUDING its scratch page
+            0 (:meth:`window_pool_pages` sizes it for a number of
+            sequences); needed where a layer has a window.
+        window_burst: the most tokens one program writes for one
+            sequence (the engine's prefill chunk).
     """
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
-                 num_kv_heads: int, head_dim: int, dtype=None):
+                 num_kv_heads: int, head_dim: int, dtype=None, *,
+                 layer_windows: Sequence[Optional[int]] = (),
+                 window_pages: Optional[int] = None, window_burst: int = 1):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is scratch)")
         if page_size < 1:
@@ -78,9 +114,37 @@ class PagedKVCache:
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
         self.dtype = dtype or jnp.float32
-        shape = (num_pages, page_size, num_kv_heads * head_dim)
-        self.k: List = [jnp.zeros(shape, self.dtype) for _ in range(num_layers)]
-        self.v: List = [jnp.zeros(shape, self.dtype) for _ in range(num_layers)]
+        # Each layer's kind of pool: 0 full, 1 window.
+        windows = {w for w in layer_windows if w is not None}
+        if len(windows) > 1 or (layer_windows
+                                and len(layer_windows) != num_layers):
+            raise ValueError(
+                f"layer_windows gives each of {num_layers} layers None or "
+                f"the one window they share: {tuple(layer_windows)}")
+        self.window: Optional[int] = windows.pop() if windows else None
+        self.layer_kinds = tuple(int(w is not None) for w in layer_windows) \
+            or (0,) * num_layers
+        self.window_seq_pages = self.window_burst_pages = 0
+        self.num_window_pages = 0
+        if self.window is not None:
+            self.window_seq_pages, self.window_burst_pages = \
+                self._window_seat_pages(self.window, page_size, window_burst)
+            self.num_window_pages = window_pages or 0
+            if self.max_window_seqs < 1:
+                raise ValueError(
+                    f"window_pages={self.num_window_pages} holds no "
+                    f"sequence: one needs {self.window_seq_pages} pages, "
+                    f"a chunk {self.window_burst_pages} more, and page 0 "
+                    f"is scratch")
+        width = num_kv_heads * head_dim
+        shapes = [(self.num_window_pages if kind else num_pages, page_size,
+                   width) for kind in self.layer_kinds]
+        self.k: List = [jnp.zeros(shape, self.dtype) for shape in shapes]
+        self.v: List = [jnp.zeros(shape, self.dtype) for shape in shapes]
+        # The window pools' free list and each sequence's sliding table,
+        # [first logical page, its pages from there].
+        self._wfree: List[int] = list(range(self.num_window_pages - 1, 0, -1))
+        self._wtables: Dict[str, list] = {}
         # LIFO free list over pages 1..num_pages-1 (0 is scratch).
         self._free: List[int] = list(range(num_pages - 1, 0, -1))
         self._tables: Dict[str, List[int]] = {}
@@ -95,14 +159,44 @@ class PagedKVCache:
 
     # ---- accounting -------------------------------------------------
 
+    @staticmethod
+    def _window_seat_pages(window: int, page_size: int, burst: int):
+        """``(a sequence's seat, the pool's burst)`` in pages: a window of
+        positions lies on ``ceil(window / page_size) + 1`` pages at most
+        wherever it starts; while ``burst`` tokens are written after the
+        ``window - 1`` before them, on that many more."""
+        seat = -(-window // page_size) + 1
+        return seat, -(-(window - 1 + max(1, burst)) // page_size) + 1 - seat
+
+    @classmethod
+    def window_pool_pages(cls, window: int, page_size: int, seqs: int,
+                          burst: int = 1) -> int:
+        """``window_pages`` for ``seqs`` sequences: a seat each, one
+        burst, and the scratch page."""
+        seat, extra = cls._window_seat_pages(window, page_size, burst)
+        return seqs * seat + extra + 1
+
     def pages_for(self, num_tokens: int) -> int:
         """Pages needed to hold ``num_tokens`` tokens."""
         return max(0, math.ceil(num_tokens / self.page_size))
 
     @property
+    def kinds(self) -> tuple:
+        """The kinds of pool held: ``(0,)``, or ``(0, 1)`` with window
+        layers. What a program takes a kind (``dests``, block tables)
+        is given in this order."""
+        return (0,) if self.window is None else (0, 1)
+
+    @property
     def total_pages(self) -> int:
-        """Usable pages (excludes scratch)."""
+        """Usable pages of a full layer's pool (excludes scratch)."""
         return self.num_pages - 1
+
+    @property
+    def max_window_seqs(self) -> int:
+        """Sequences the window pools have seats for."""
+        return (self.num_window_pages - 1 - self.window_burst_pages) \
+            // self.window_seq_pages
 
     def free_pages(self) -> int:
         """Allocatable pages: the free list plus whatever the retainer
@@ -116,9 +210,18 @@ class PagedKVCache:
         """Pages referenced by at least one live sequence."""
         return self.total_pages - self.free_pages()
 
+    def window_pages_owned(self) -> int:
+        """Window-pool pages in sequences' sliding tables."""
+        return self.num_window_pages - 1 - len(self._wfree) \
+            if self.window is not None else 0
+
     def utilization(self) -> float:
-        """Fraction of usable pages currently owned by sequences."""
-        return self.used_pages() / self.total_pages
+        """Fraction of usable pages currently owned by sequences, over
+        the pages of both kinds where there are two."""
+        if self.window is None:
+            return self.used_pages() / self.total_pages
+        return (self.used_pages() + self.window_pages_owned()) \
+            / (self.total_pages + self.num_window_pages - 1)
 
     def num_sequences(self) -> int:
         return len(self._tables)
@@ -138,9 +241,10 @@ class PagedKVCache:
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
         need = self.pages_for(max(1, num_tokens))
-        if not self._reserve(need):
+        if not self._window_seat() or not self._reserve(need):
             return False
         self._tables[seq_id] = [self._take_free() for _ in range(need)]
+        self._seat(seq_id)
         return True
 
     def allocate_shared(self, seq_id: str, num_tokens: int,
@@ -152,6 +256,10 @@ class PagedKVCache:
         on failure nothing is referenced."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
+        if self.window is not None and prefix_pages:
+            raise ValueError(
+                "a window layer's pages are not shared: the prefix's are "
+                "given back as its window slides on")
         need = self.pages_for(max(1, num_tokens))
         tail = need - len(prefix_pages)
         if tail < 0:
@@ -163,12 +271,13 @@ class PagedKVCache:
         # the retainer's eviction list.
         for page in prefix_pages:
             self._incref(page)
-        if not self._reserve(tail):
+        if not self._window_seat() or not self._reserve(tail):
             for page in reversed(prefix_pages):
                 self._decref(page)  # rollback: back to parked/free
             return False
         self._tables[seq_id] = list(prefix_pages) + [
             self._take_free() for _ in range(tail)]
+        self._seat(seq_id)
         return True
 
     def extend(self, seq_id: str, num_tokens_total: int) -> bool:
@@ -190,11 +299,64 @@ class PagedKVCache:
         the pool only when its last reference drops; ref-0 pages the
         retainer claims stay out of the free list but reclaimable."""
         table = self._tables.pop(seq_id, None)
+        first_pages = self._wtables.pop(seq_id, None)
+        if first_pages:
+            self._wfree.extend(reversed(first_pages[1]))
         if not table:
             return
         # LIFO reuse keeps the hot working set in a few pages.
         for page in reversed(table):
             self._decref(page)
+
+    # ---- a window layer's sliding tables -----------------------------
+
+    def _window_seat(self) -> bool:
+        """Whether the window pools have a seat for one more sequence
+        (always, for a model without window layers)."""
+        return self.window is None \
+            or len(self._wtables) < self.max_window_seqs
+
+    def _seat(self, seq_id: str) -> None:
+        if self.window is not None:
+            self._wtables[seq_id] = [0, []]
+
+    def slide(self, seq_id: str, lo: int, hi: int) -> int:
+        """Before a program whose queries for ``seq_id`` stand at
+        positions ``lo ..`` and which writes its positions up to ``hi -
+        1``: have its window table hold the pages of positions ``lo -
+        window + 1 .. hi - 1`` and give back every page left of them.
+        ``lo == hi`` keeps what the next query, at ``lo``, will see of
+        what is written. Returns the pages given back; 0, always, for a
+        model without window layers. Cannot fail: a seat holds the
+        pages (see the module docstring)."""
+        held = self._wtables.get(seq_id)
+        if held is None:
+            if self.window is None:
+                return 0
+            raise KeyError(f"sequence {seq_id!r} has no allocation")
+        first, owned = held
+        keep = max(0, lo - self.window + 1) // self.page_size
+        released = min(max(0, keep - first), len(owned))
+        if released:
+            self._wfree.extend(reversed(owned[:released]))
+            del owned[:released]
+        held[0] = first = first + released if owned else keep
+        for _ in range(self.pages_for(hi) - first - len(owned)):
+            owned.append(self._wfree.pop())
+        return released
+
+    def window_table(self, seq_id: str):
+        """``(first logical page, its pages from there)`` of ``seq_id``."""
+        first, pages = self._wtables[seq_id]
+        return first, list(pages)
+
+    def pages_read(self, pos: int, kind: int = 0) -> int:
+        """Pages of one layer of ``kind`` that a query at position
+        ``pos`` reads: its whole context, or its window's span."""
+        last = pos // self.page_size
+        if kind == 0:
+            return last + 1
+        return last - max(0, pos - self.window + 1) // self.page_size + 1
 
     # ---- refcount plumbing ------------------------------------------
 
@@ -239,26 +401,40 @@ class PagedKVCache:
         engine reads this per step to trim block-table widths)."""
         return len(self._tables[seq_id])
 
-    def slot(self, seq_id: str, pos: int) -> int:
+    def _logical_pages(self, seq_id: str, kind: int):
+        """``(first, pages)``: ``seq_id``'s pages of ``kind`` and the
+        logical page the first of them is."""
+        if kind:
+            return self._wtables[seq_id]
+        return 0, self._tables[seq_id]
+
+    def slot(self, seq_id: str, pos: int, kind: int = 0) -> int:
         """Flat slot index (into ``[num_pages*page_size]``) of logical
-        token position ``pos`` of sequence ``seq_id``."""
-        table = self._tables[seq_id]
-        page = pos // self.page_size
+        token position ``pos`` of sequence ``seq_id`` in a pool of
+        ``kind``. A position a window table has slid past has none left:
+        it is given a slot of the scratch page."""
+        first, table = self._logical_pages(seq_id, kind)
+        page = pos // self.page_size - first
         if page >= len(table):
             raise IndexError(
                 f"pos {pos} beyond allocation of {seq_id!r} "
                 f"({len(table)} pages x {self.page_size})")
+        if page < 0:
+            return pos % self.page_size
         return table[page] * self.page_size + pos % self.page_size
 
     def table_array(self, seq_ids: Sequence[str], max_pages: int,
-                    batch: Optional[int] = None) -> np.ndarray:
+                    batch: Optional[int] = None, kind: int = 0
+                    ) -> np.ndarray:
         """Stacked block tables ``[batch, max_pages]`` int32, padded
-        with 0 (scratch) — rows past ``len(seq_ids)`` are dummy rows."""
+        with 0 (scratch) — rows past ``len(seq_ids)`` are dummy rows.
+        Column ``c`` is logical page ``c`` for either ``kind``: a window
+        table's columns left of its first page are scratch too."""
         b = batch if batch is not None else len(seq_ids)
         out = np.zeros((b, max_pages), dtype=np.int32)
         for i, sid in enumerate(seq_ids):
-            table = self._tables[sid]
-            out[i, :len(table)] = table
+            first, table = self._logical_pages(sid, kind)
+            out[i, first:first + len(table)] = table
         return out
 
     def prefill_dests(self, seq_id: str, length: int,
@@ -269,13 +445,23 @@ class PagedKVCache:
         return self.chunk_dests(seq_id, 0, length, bucket)
 
     def chunk_dests(self, seq_id: str, start: int, take: int,
-                    bucket: int) -> np.ndarray:
+                    bucket: int, kind: int = 0) -> np.ndarray:
         """Flat destination slots ``[bucket]`` int32 for writing a
         prefill CHUNK covering logical positions ``[start, start+take)``
-        padded to ``bucket``; padding cycles through page 0."""
-        out = np.empty(bucket, dtype=np.int32)
-        for i in range(min(take, bucket)):
-            out[i] = self.slot(seq_id, start + i)
-        for i in range(take, bucket):
-            out[i] = i % self.page_size  # page 0 slots
+        padded to ``bucket``, in a pool of ``kind``; padding cycles
+        through page 0."""
+        out = np.arange(bucket, dtype=np.int32) % self.page_size  # page 0
+        take = min(take, bucket)
+        if take > 0:
+            first, table = self._logical_pages(seq_id, kind)
+            pos = np.arange(start, start + take)
+            page = pos // self.page_size - first
+            if page[-1] >= len(table):
+                raise IndexError(
+                    f"pos {start + take - 1} beyond allocation of "
+                    f"{seq_id!r} ({len(table)} pages x {self.page_size})")
+            held = page >= 0  # a window table has slid past the others
+            out[:take][held] = (
+                np.asarray(table, dtype=np.int64)[page[held]]
+                * self.page_size + pos[held] % self.page_size)
         return out

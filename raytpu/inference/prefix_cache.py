@@ -79,6 +79,11 @@ class PrefixCache:
     """
 
     def __init__(self, cache: PagedKVCache):
+        if cache.window is not None:
+            raise ValueError(
+                "no prefix cache over window layers: a prompt's window "
+                "pages are given back as the window slides on, so a "
+                "later prompt finds its prefix in the full pools only")
         self.cache = cache
         self.page_size = cache.page_size
         # chain hash -> page id holding that page's KV

@@ -150,9 +150,10 @@ class LLMDeployment:
     """Serve a decoder LM with continuous batching + streaming tokens.
 
     Args:
-        model: "llama", "gpt2", "mixtral" or "olmoe".
+        model: "llama", "gpt2", "mixtral", "olmoe" or "mellum".
         model_config: the family's config (``LlamaConfig``,
-            ``GPT2Config``, ``MixtralConfig``, ``OlmoeConfig``) or a
+            ``GPT2Config``, ``MixtralConfig``, ``OlmoeConfig``,
+            ``MellumConfig``) or a
             kwargs dict for one. Defaults to the family's ``tiny()``
             config in fp32/reference-attention mode (CPU-runnable).
         engine_options: kwargs forwarded to :class:`InferenceEngine`
@@ -166,7 +167,9 @@ class LLMDeployment:
             before admission and decodes).
         prefill: the prefill peer for ``role="decode"`` — a
             DeploymentHandle (serve composition) or any object with the
-            ``kv_export_*`` trio (direct-instantiation tests).
+            ``kv_export_*`` trio (direct-instantiation tests). A model
+            with window layers takes no role: what is handed off are
+            prefix-cache pages, and it is served without that cache.
     """
 
     def __init__(self, model: str = "llama", model_config=None,
@@ -184,15 +187,17 @@ class LLMDeployment:
             from raytpu.models.gpt2 import GPT2, GPT2Config, init_params
 
             cfg_cls, model_cls, init = GPT2Config, GPT2, init_params
-        elif model in ("mixtral", "olmoe"):
+        elif model in ("mixtral", "olmoe", "mellum"):
             from raytpu.models import mixtral
 
             cfg_cls = {"mixtral": mixtral.MixtralConfig,
-                       "olmoe": mixtral.OlmoeConfig}[model]
+                       "olmoe": mixtral.OlmoeConfig,
+                       "mellum": mixtral.MellumConfig}[model]
             model_cls, init = mixtral.Mixtral, mixtral.init_params
         else:
             raise ValueError(f"unknown model family: {model!r}; known: "
-                             f"'llama', 'gpt2', 'mixtral', 'olmoe'")
+                             f"'llama', 'gpt2', 'mixtral', 'olmoe', "
+                             f"'mellum'")
         if model_config is None:
             model_config = dataclasses.replace(
                 cfg_cls.tiny(), dtype=jnp.float32, attn_impl="reference",
@@ -208,6 +213,11 @@ class LLMDeployment:
         self._peer = None
         self._engine = InferenceEngine(model_config, params,
                                        **(engine_options or {}))
+        if role is not None and self._engine.cache.window is not None:
+            raise ValueError(
+                f"role={role!r}: a model with window layers is not "
+                f"served disaggregated: the hand-off ships prefix-cache "
+                f"pages, and its window pools share none")
         self._handoff_source = disagg.KVHandoffSource(self._engine)
         # One condition serializes engine mutation (add/abort/step):
         # producers signal "new work" to the loop through it.

@@ -388,6 +388,13 @@ class Serving:
     tokens each expert of each layer received. What a pool row holds is
     known to the family and to ``raytpu.ops.paged_attention`` alone.
 
+    The cache's shape by kind of layer: ``kv_heads`` and ``head_dim`` are
+    every pool's row, and ``layer_windows`` (empty: every layer full)
+    gives each layer ``None``, a full layer whose pool grows with the
+    sequence, or its window in tokens, a window layer whose pool keeps a
+    sequence's newest positions only. Over two kinds the engine gives
+    ``dests`` and ``block_tables`` as a pair of arrays, full first.
+
     ``inputs`` -> logits: ``prefill`` (tokens [1, T], dests [T]) ->
     [T, V]; ``prefill_chunk`` (tokens [1, T], positions [T], dests [T],
     block_tables [1, P]) -> [1, T, V]; ``decode`` (tokens [B], positions
@@ -400,19 +407,22 @@ class Serving:
     kv_heads: int
     head_dim: int
     expert_counts: Optional[Tuple[int, int]] = None  # (layers, experts)
+    layer_windows: Tuple[Optional[int], ...] = ()
 
 
 def write_prompt_rows(k_caches, v_caches, dests, ks, vs):
     """The pools with a whole prompt's K and V, ``[1, T, KV, D]`` a layer
     as an attention module's ``prefill`` returns them, written as T pool
-    rows at ``dests`` [T]; padding's rows go to the scratch page."""
+    rows at ``dests`` [T] (or at a ``dests`` a layer, where the pools are
+    of two kinds); padding's rows go to the scratch page."""
     from raytpu.ops.paged_attention import scatter_kv_slots
 
-    t = dests.shape[0]
-    return ([scatter_kv_slots(kc, dests, k.reshape(t, -1))
-             for kc, k in zip(k_caches, ks)],
-            [scatter_kv_slots(vc, dests, v.reshape(t, -1))
-             for vc, v in zip(v_caches, vs)])
+    per_layer = dests if isinstance(dests, list) else [dests] * len(ks)
+    t = per_layer[0].shape[0]
+    return ([scatter_kv_slots(kc, d, k.reshape(t, -1))
+             for kc, d, k in zip(k_caches, per_layer, ks)],
+            [scatter_kv_slots(vc, d, v.reshape(t, -1))
+             for vc, d, v in zip(v_caches, per_layer, vs)])
 
 
 def _serve(c: GPT2Config, params, x, method: str, cache_args):

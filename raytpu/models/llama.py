@@ -16,15 +16,42 @@ model-specific code.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
-from typing import Any, Optional
+import math
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from raytpu.models.gpt2 import Serving, cast_leaves, write_prompt_rows
+
+# The two kinds of attention layer, as a published ``layer_types`` names
+# them, and where each stands in what is given a kind (a cache's tables,
+# a program's ``dests``): full first.
+FULL, WINDOW = "full_attention", "sliding_attention"
+KINDS = (FULL, WINDOW)
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """One layer kind's rotary embedding: plain at ``theta``, or YaRN
+    (Peng et al. 2023, as ``transformers`` computes ``rope_type: yarn``)
+    where ``yarn_factor`` is set: the frequencies a context of
+    ``original_max_position`` turns more than ``beta_fast`` times stay,
+    those it turns fewer than ``beta_slow`` times are divided by the
+    factor, the ones between are blended linearly, and cos and sin are
+    both scaled by ``attention_factor`` (``0.1 ln(factor) + 1`` if not
+    given)."""
+
+    theta: float = 10000.0
+    yarn_factor: Optional[float] = None
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,8 +62,19 @@ class LlamaConfig:
     n_head: int = 12
     n_kv_head: int = 4               # grouped-query attention
     n_embd: int = 768
+    # A head's size; None is n_embd // n_head, which a model need not
+    # keep (Mellum2: 32 heads of 128 on a hidden size of 2,304).
+    head_dim: Optional[int] = None
     n_inter: int = 2048              # SwiGLU hidden (≈ 8/3 · n_embd)
     rope_theta: float = 10000.0
+    # Each layer's kind, FULL or WINDOW; None is every layer full. A
+    # window layer's query sees the ``window`` newest positions, its own
+    # among them. ``full_rope`` / ``window_rope`` give a kind a rotary
+    # embedding of its own; None is plain rope at ``rope_theta``.
+    layer_types: Optional[Tuple[str, ...]] = None
+    window: Optional[int] = None
+    full_rope: Optional[Rope] = None
+    window_rope: Optional[Rope] = None
     norm_eps: float = 1e-5           # every RMSNorm's epsilon
     # RMSNorm over the whole q and the whole k projection, before the
     # heads are split and roped (OLMoE).
@@ -68,9 +106,26 @@ class LlamaConfig:
         return cls(vocab_size=32000, block_size=4096, n_layer=32,
                    n_head=32, n_kv_head=32, n_embd=4096, n_inter=11008)
 
-    @property
-    def head_dim(self) -> int:
-        return self.n_embd // self.n_head
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.n_embd // self.n_head)
+        if self.layer_types is not None:
+            types = tuple(self.layer_types)
+            object.__setattr__(self, "layer_types", types)
+            if len(types) != self.n_layer or set(types) - set(KINDS):
+                raise ValueError(
+                    f"layer_types names {self.n_layer} layers, each "
+                    f"{FULL!r} or {WINDOW!r}: got {types}")
+            if WINDOW in types and not self.window:
+                raise ValueError("a window layer needs `window`")
+
+    def layer_kind(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else FULL
+
+    def rope_of(self, kind: str):
+        """What :func:`rope_tables` takes for a layer of ``kind``."""
+        own = self.window_rope if kind == WINDOW else self.full_rope
+        return own if own is not None else self.rope_theta
 
     @property
     def serving(self) -> Serving:
@@ -82,7 +137,10 @@ class LlamaConfig:
         return Serving(
             llama_prefill, llama_prefill_chunk, llama_decode, serving_params,
             kv_heads=self.n_kv_head, head_dim=self.head_dim,
-            expert_counts=(self.n_layer, self.n_expert) if routed else None)
+            expert_counts=(self.n_layer, self.n_expert) if routed else None,
+            layer_windows=tuple(
+                self.window if kind == WINDOW else None
+                for kind in self.layer_types or ()))
 
     @property
     def n_params_approx(self) -> int:
@@ -106,8 +164,37 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(self.dtype)
 
 
-def rope_tables(head_dim: int, positions, theta: float):
-    """(cos, sin) tables for rotary embeddings, fp32, [T, head_dim/2]."""
+def yarn_frequencies(head_dim: int, rope: Rope):
+    """YaRN's ``head_dim / 2`` blended inverse frequencies, float64."""
+    import numpy as np
+
+    plain = rope.theta ** -(np.arange(0, head_dim, 2, dtype=np.float64)
+                            / head_dim)
+
+    def dim_of(turns: float) -> float:
+        # The (fractional) pair whose wavelength fits ``turns`` times
+        # into the original context.
+        return head_dim * math.log(rope.original_max_position
+                                   / (2 * math.pi * turns)) \
+            / (2 * math.log(rope.theta))
+
+    low = min(max(math.floor(dim_of(rope.beta_fast)), 0), head_dim - 1)
+    high = min(max(math.ceil(dim_of(rope.beta_slow)), 0), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low)
+                   / (high - low if high != low else 0.001), 0.0, 1.0)
+    return plain / rope.yarn_factor * ramp + plain * (1.0 - ramp)
+
+
+def rope_tables(head_dim: int, positions, rope):
+    """(cos, sin) tables for rotary embeddings, fp32, [T, head_dim/2].
+    ``rope`` is plain rope's theta, or a :class:`Rope`."""
+    if isinstance(rope, Rope) and rope.yarn_factor:
+        freqs = jnp.asarray(yarn_frequencies(head_dim, rope), jnp.float32)
+        scale = rope.attention_factor or (
+            0.1 * math.log(rope.yarn_factor) + 1.0)
+        angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
+        return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+    theta = rope.theta if isinstance(rope, Rope) else rope
     freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                         dtype=jnp.float32) / head_dim))
     angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
@@ -142,9 +229,16 @@ class LlamaAttention(nn.Module):
     can touch the projections; attribute names keep the param tree
     identical to the old compact version (q_proj/k_proj/v_proj/o_proj),
     so ``TRANSFORMER_RULES`` sharding and existing checkpoints are
-    unaffected."""
+    unaffected. ``kind`` is the layer's (FULL or WINDOW): it picks the
+    rotary embedding and, for a window layer, the window every one of
+    the three attends through."""
 
     config: LlamaConfig
+    kind: str = FULL
+
+    @property
+    def window(self) -> Optional[int]:
+        return self.config.window if self.kind == WINDOW else None
 
     def setup(self):
         c = self.config
@@ -181,7 +275,7 @@ class LlamaAttention(nn.Module):
         q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
-        cos, sin = rope_tables(d, jnp.arange(t), c.rope_theta)
+        cos, sin = rope_tables(d, jnp.arange(t), c.rope_of(self.kind))
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         k_cache = k.transpose(0, 2, 1, 3)
@@ -193,7 +287,8 @@ class LlamaAttention(nn.Module):
             v = jnp.repeat(v, rep, axis=1)
         from raytpu.ops.flash_attention import flash_attention
 
-        y = flash_attention(q, k, v, causal=True, force=c.attn_impl)
+        y = flash_attention(q, k, v, causal=True, force=c.attn_impl,
+                            window=self.window)
         y = y.transpose(0, 2, 1, 3).reshape(b, t, h * d)
         return self.o_proj(y), k_cache, v_cache
 
@@ -218,7 +313,7 @@ class LlamaAttention(nn.Module):
         q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
-        cos, sin = rope_tables(d, positions, c.rope_theta)
+        cos, sin = rope_tables(d, positions, c.rope_of(self.kind))
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         # Rows as the pools hold them: a token's heads side by side.
@@ -233,7 +328,7 @@ class LlamaAttention(nn.Module):
         # position (gathered/paged slot l holds logical position l).
         o = paged_attention(q.transpose(0, 2, 1, 3), k_pages, v_pages,
                             block_tables, positions[None, :],
-                            force=c.paged_attn)
+                            force=c.paged_attn, window=self.window)
         y = o.reshape(b, t, h * d)
         return self.o_proj(y), k_pages, v_pages
 
@@ -258,7 +353,7 @@ class LlamaAttention(nn.Module):
         h, kv, d = c.n_head, c.n_kv_head, c.head_dim
         q, k, v = self._qkv(x)
         q, k, v = q.reshape(b, h, d), k.reshape(b, kv, d), v.reshape(b, kv, d)
-        cos, sin = rope_tables(d, positions, c.rope_theta)
+        cos, sin = rope_tables(d, positions, c.rope_of(self.kind))
         q = apply_rope_single(q, cos, sin)
         k = apply_rope_single(k, cos, sin)
         from raytpu.ops.paged_attention import (paged_attention,
@@ -269,7 +364,7 @@ class LlamaAttention(nn.Module):
         # The token at position p sees slots 0..p = 0..context_lens-1.
         o = paged_attention(q[:, None], k_pages, v_pages, block_tables,
                             (context_lens - 1)[:, None],
-                            force=c.paged_attn)
+                            force=c.paged_attn, window=self.window)
         y = o[:, 0].reshape(b, h * d)
         return self.o_proj(y), k_pages, v_pages
 
@@ -289,11 +384,12 @@ class LlamaMLP(nn.Module):
 
 class LlamaBlock(nn.Module):
     config: LlamaConfig
+    kind: str = FULL
 
     @nn.compact
     def __call__(self, x):
         c = self.config
-        x = x + LlamaAttention(c, name="attn")(
+        x = x + LlamaAttention(c, self.kind, name="attn")(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="input_norm")(x))
         x = x + LlamaMLP(c, name="mlp")(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps,
@@ -316,7 +412,7 @@ class Llama(nn.Module):
             if c.remat == "dots":
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             block = nn.remat(LlamaBlock, prevent_cse=False, policy=policy)
-        if c.scan_layers:
+        if c.scan_layers and not c.layer_types:
             x, _ = nn.scan(
                 lambda mdl, carry, _: (mdl(carry), None),
                 variable_axes={"params": 0},
@@ -325,8 +421,9 @@ class Llama(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(block(c, name="layers"), x, None)
         else:
+            # Layers of different kinds are not one scanned body.
             for i in range(c.n_layer):
-                x = block(c, name=f"layers_{i}")(x)
+                x = block(c, c.layer_kind(i), name=f"layers_{i}")(x)
         x = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="final_norm")(x)
         if return_hidden:
             return x
@@ -431,10 +528,19 @@ def _feed_forward(c: LlamaConfig, lp, h, live):
     return LlamaMLP(c).apply({"params": lp["mlp"]}, h), None
 
 
+def of_kind(x, kind: str):
+    """``x`` for a layer of ``kind``: an engine over a cache of two kinds
+    of pool gives ``dests`` and ``block_tables`` as one array a kind, in
+    the order of ``KINDS``; over one kind, the array itself."""
+    return x[KINDS.index(kind)] if isinstance(x, (tuple, list)) else x
+
+
 def live_rows(dests, k_cache):
-    """Rows of a bucket that are tokens, from where their K/V is written:
-    the engine points padding at the scratch page, page 0."""
-    return dests >= k_cache.shape[1]
+    """Rows of a bucket that are tokens, from where their K/V is written
+    in the full layers' pools: the engine points padding at the scratch
+    page, page 0. (A window layer's pool also sends there the rows of a
+    whole prompt that lie left of its window.)"""
+    return of_kind(dests, FULL) >= k_cache.shape[1]
 
 
 def _serve(c: LlamaConfig, params, x, live, method: str, cache_args):
@@ -445,15 +551,21 @@ def _serve(c: LlamaConfig, params, x, live, method: str, cache_args):
     ``live`` (``x``'s leading shape) marks the rows that are tokens, for
     :func:`_feed_forward`. Returns ``(fp32 logits, K list, V list)`` and
     for a routed config a fourth value, the int32 ``[layers, experts]``
-    count of tokens each expert received."""
-    attn = LlamaAttention(c)
+    count of tokens each expert received. Where the layers are of two
+    kinds each attends under ``jax.named_scope("attn.full")`` or
+    ``("attn.window")``."""
+    attn = {kind: LlamaAttention(c, kind) for kind in KINDS}
     norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
     ks, vs, routed = [], [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
         h = norm.apply({"params": lp["input_norm"]}, x)
-        y, k, v = attn.apply({"params": lp["attn"]}, h, *cache_args(i),
-                             method=method)
+        kind = c.layer_kind(i)
+        with (jax.named_scope("attn.window" if kind == WINDOW
+                              else "attn.full")
+              if c.layer_types else contextlib.nullcontext()):
+            y, k, v = attn[kind].apply({"params": lp["attn"]}, h,
+                                       *cache_args(i), method=method)
         ks.append(k)
         vs.append(v)
         x = x + y
@@ -478,6 +590,8 @@ def llama_prefill(config: LlamaConfig, params, tokens, dests, k_caches,
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     logits, ks, vs, *count = _serve(c, params, x, live, "prefill",
                                     lambda i: ())
+    if c.layer_types:  # each layer's rows where its kind of pool has them
+        dests = [of_kind(dests, c.layer_kind(i)) for i in range(c.n_layer)]
     ks, vs = write_prompt_rows(k_caches, v_caches, dests, ks, vs)
     return (logits[0], ks, vs, *count)
 
@@ -492,7 +606,8 @@ def llama_prefill_chunk(config: LlamaConfig, params, tokens, positions,
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     live = live_rows(dests, k_caches[0])[None]
     return _serve(c, params, x, live, "prefill_chunk", lambda i: (
-        k_caches[i], v_caches[i], dests, block_tables, positions))
+        k_caches[i], v_caches[i], of_kind(dests, c.layer_kind(i)),
+        of_kind(block_tables, c.layer_kind(i)), positions))
 
 
 def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
@@ -504,5 +619,5 @@ def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     live = live_rows(dests, k_caches[0])
     return _serve(c, params, x, live, "decode_step", lambda i: (
-        k_caches[i], v_caches[i], dests, block_tables, positions,
-        context_lens))
+        k_caches[i], v_caches[i], of_kind(dests, c.layer_kind(i)),
+        of_kind(block_tables, c.layer_kind(i)), positions, context_lens))

@@ -20,7 +20,10 @@ family's walk (:func:`raytpu.models.llama.llama_prefill` and its
 siblings), which picks this layer for a :class:`MixtralConfig`.
 :class:`OlmoeConfig` is OLMoE-1B-7B's block: 64 experts of which a token
 takes 8 with weights that are not renormalised, and a norm over the
-whole q and k projections.
+whole q and k projections. :class:`MellumConfig` is Mellum2-12B-A2.5B's:
+window layers among full ones, a rotary embedding a kind, a head size
+of its own; :class:`Mixtral` trains it too (``Mellum`` is its name
+there), its layers unrolled because they are not all alike.
 """
 
 from __future__ import annotations
@@ -31,7 +34,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raytpu.models.llama import LlamaAttention, LlamaConfig, RMSNorm
+from raytpu.models.llama import (FULL, WINDOW, LlamaAttention, LlamaConfig,
+                                 RMSNorm, Rope)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +75,58 @@ class OlmoeConfig(MixtralConfig):
         return cls(vocab_size=512, block_size=128, n_layer=2, n_head=4,
                    n_kv_head=4, n_embd=64, n_inter=32, n_expert=8,
                    n_expert_per_tok=2)
+
+
+@dataclasses.dataclass(frozen=True)
+class MellumConfig(MixtralConfig):
+    """Mellum2-12B-A2.5B (``JetBrains/Mellum2-12B-A2.5B-Instruct``) as
+    published: 32 query heads on 4 kv heads of 128 over a hidden size of
+    2,304, three window layers of 1,024 positions to every full one, a
+    rotary embedding a kind (YaRN x16 over 8,192 on the full layers),
+    every layer's feed-forward 64 experts of width 896 (``n_inter``)
+    of which a token takes 8, renormalised. Its ``layer_types`` is the
+    S S S F pattern cut to ``n_layer``; layers are held one tree each."""
+
+    vocab_size: int = 98304
+    block_size: int = 131072
+    n_layer: int = 28
+    n_head: int = 32
+    n_kv_head: int = 4
+    n_embd: int = 2304
+    head_dim: int = 128
+    n_inter: int = 896
+    n_expert: int = 64
+    n_expert_per_tok: int = 8
+    norm_topk_prob: bool = True
+    norm_eps: float = 1e-6
+    rope_theta: float = 500000.0
+    window: int = 1024
+    full_rope: Rope = Rope(theta=500000.0, yarn_factor=16.0,
+                           original_max_position=8192, beta_fast=32.0,
+                           beta_slow=1.0,
+                           attention_factor=1.2772588722239782)
+    scan_layers: bool = False
+
+    def __post_init__(self):
+        # The published pattern, or the first ``n_layer`` entries of a
+        # longer list (a cut in depth keeps the list's head).
+        types = self.layer_types or tuple(
+            FULL if i % 4 == 3 else WINDOW for i in range(self.n_layer))
+        object.__setattr__(self, "layer_types",
+                           tuple(types)[:self.n_layer])
+        super().__post_init__()
+
+    @classmethod
+    def tiny(cls) -> "MellumConfig":
+        """Two periods at toy widths: head_dim 16 is not 64 / 8, window
+        8, and an original length of 32 so that a context of a hundred
+        positions reaches both of YaRN's regimes."""
+        return cls(vocab_size=512, block_size=256, n_layer=8, n_head=8,
+                   n_kv_head=2, n_embd=64, head_dim=16, n_inter=32,
+                   n_expert=8, n_expert_per_tok=2, window=8,
+                   rope_theta=10000.0,
+                   full_rope=Rope(theta=10000.0, yarn_factor=4.0,
+                                  original_max_position=32))
 
 
 class MoEFFN(nn.Module):
@@ -140,11 +196,12 @@ class MoEFFN(nn.Module):
 
 class MixtralBlock(nn.Module):
     config: MixtralConfig
+    kind: str = FULL
 
     @nn.compact
     def __call__(self, x):
         c = self.config
-        x = x + LlamaAttention(c, name="attn")(
+        x = x + LlamaAttention(c, self.kind, name="attn")(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="input_norm")(x))
         y, _ = MoEFFN(c, name="moe")(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps,
@@ -167,7 +224,7 @@ class Mixtral(nn.Module):
             if c.remat == "dots":
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             block = nn.remat(MixtralBlock, prevent_cse=False, policy=policy)
-        if c.scan_layers:
+        if c.scan_layers and not c.layer_types:
             x, _ = nn.scan(
                 lambda mdl, carry, _: (mdl(carry), None),
                 variable_axes={"params": 0, "intermediates": 0},
@@ -176,8 +233,9 @@ class Mixtral(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(block(c, name="layers"), x, None)
         else:
+            # Layers of different kinds are not one scanned body.
             for i in range(c.n_layer):
-                x = block(c, name=f"layers_{i}")(x)
+                x = block(c, c.layer_kind(i), name=f"layers_{i}")(x)
         x = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="final_norm")(x)
         if return_hidden:
             return x
@@ -218,3 +276,8 @@ def init_params(model: Mixtral, config: MixtralConfig, seed: int = 0,
     tokens = jnp.zeros((batch, config.block_size), jnp.int32)
     return jax.jit(lambda key: model.init(key, tokens)["params"])(
         jax.random.PRNGKey(seed))
+
+
+# Mellum2's training forward is Mixtral's over a config whose layers are
+# of two kinds (``MixtralBlock.kind``).
+Mellum = Mixtral

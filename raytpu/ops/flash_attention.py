@@ -154,13 +154,22 @@ def per_shard(fn, args, in_layouts, out_layouts, *, heads=()):
 # -- reference path (also the backward) --------------------------------------
 
 
-def _attn_fwd_reference(q, k, v, causal: bool, sm_scale: float):
+def _causal_mask(t_q: int, t_k: int, window=None):
+    """Bottom-aligned causal mask [t_q, t_k]; with a ``window`` a query
+    sees the ``window`` newest keys up to its own, and no older one."""
+    mask = jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q)
+    if window is not None:
+        mask &= ~jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q - window)
+    return mask
+
+
+def _attn_fwd_reference(q, k, v, causal: bool, sm_scale: float,
+                        window=None):
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
-        t_q, t_k = q.shape[2], k.shape[2]
-        mask = jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q)
+        mask = _causal_mask(q.shape[2], k.shape[2], window)
         s = jnp.where(mask[None, None], s, -1e30)
     lse = jax.scipy.special.logsumexp(s, axis=-1, keepdims=True)
     p = jnp.exp(s - lse)
@@ -168,13 +177,13 @@ def _attn_fwd_reference(q, k, v, causal: bool, sm_scale: float):
     return o.astype(q.dtype), lse
 
 
-def _attn_bwd_reference(q, k, v, o, lse, g, causal: bool, sm_scale: float):
+def _attn_bwd_reference(q, k, v, o, lse, g, causal: bool, sm_scale: float,
+                        window=None):
     qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
     gf = g.astype(jnp.float32)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * sm_scale
     if causal:
-        t_q, t_k = q.shape[2], k.shape[2]
-        mask = jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q)
+        mask = _causal_mask(q.shape[2], k.shape[2], window)
         s = jnp.where(mask[None, None], s, -1e30)
     p = jnp.exp(s - lse)
     dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
@@ -195,7 +204,7 @@ _LANES = 128  # VMEM scratch lane width; m/l broadcast across lanes.
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *, causal: bool,
                   sm_scale: float, block_q: int, block_k: int, n_kb: int,
-                  off: int, dot_mode: str):
+                  off: int, dot_mode: str, window=None):
     from jax.experimental import pallas as pl  # noqa: PLC0415
 
     d = q_ref.shape[2]
@@ -214,6 +223,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     # bottom-aligned for t_q != t_kv (off = t_kv - t_q), matching the
     # reference path's tril(k=t_kv-t_q).
     live = (k_start <= q_start + block_q - 1 + off) if causal else True
+    if window is not None:
+        # Blocks wholly left of the first row's window contribute nothing
+        # either: the block's last key is older than the oldest it sees.
+        live = jnp.logical_and(
+            live, k_start + block_k - 1 > q_start + off - window)
 
     @pl.when(live)
     def _compute():
@@ -231,7 +245,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 0)
             kpos = k_start + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos + off, s, -1e30)
+            seen = kpos <= qpos + off
+            if window is not None:
+                seen = jnp.logical_and(seen, kpos > qpos + off - window)
+            s = jnp.where(seen, s, -1e30)
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -254,7 +271,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _flash_forward_pallas(q, k, v, causal: bool, sm_scale: float,
-                          block_q: int, block_k: int, interpret: bool):
+                          block_q: int, block_k: int, interpret: bool,
+                          window=None):
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
@@ -272,9 +290,18 @@ def _flash_forward_pallas(q, k, v, causal: bool, sm_scale: float,
     kernel = functools.partial(
         _flash_kernel, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, n_kb=n_kb, off=off,
-        dot_mode=DEFAULT_DOT_MODE)
+        dot_mode=DEFAULT_DOT_MODE, window=window)
 
-    if causal:
+    if causal and window is not None:
+        # As below, from both sides: iterations left of the window
+        # re-reference its first block, those past the diagonal its last.
+        def kv_index(ib, iq, ik):
+            last = (iq * block_q + block_q - 1 + off) // block_k
+            last = jnp.clip(last, 0, n_kb - 1)
+            first = (iq * block_q + off - window + 1) // block_k
+            first = jnp.clip(first, 0, n_kb - 1)
+            return (ib, jnp.clip(ik, first, last), 0)
+    elif causal:
         # Clamp the K/V walk to the last causally-live block: iterations
         # past the diagonal re-reference an already-fetched block, so the
         # pipeline never DMAs fully-masked K/V from HBM (`pl.when` skips
@@ -560,27 +587,33 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
 _BHTD = "bh.."  # per_shard layout of q/k/v/o/g and of lse [B, H, T, 1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, sm_scale, use_pallas):
-    o, _ = _flash_fwd(q, k, v, causal, sm_scale, use_pallas)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, use_pallas, window=None):
+    o, _ = _flash_fwd(q, k, v, causal, sm_scale, use_pallas, window)
     return o
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, use_pallas):
+def _flash_fwd(q, k, v, causal, sm_scale, use_pallas, window=None):
     if use_pallas in ("tpu", "interpret"):
         o, lse = per_shard(
             functools.partial(
                 _flash_forward_pallas, causal=causal, sm_scale=sm_scale,
                 block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                interpret=(use_pallas == "interpret")),
+                interpret=(use_pallas == "interpret"), window=window),
             (q, k, v), (_BHTD,) * 3, (_BHTD, _BHTD))
     else:
-        o, lse = _attn_fwd_reference(q, k, v, causal, sm_scale)
+        o, lse = _attn_fwd_reference(q, k, v, causal, sm_scale, window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, sm_scale, use_pallas, res, g):
+def _flash_bwd(causal, sm_scale, use_pallas, window, res, g):
     q, k, v, o, lse = res
+    if window is not None:
+        # The two backward kernels know no window yet: a windowed
+        # backward takes the masked reference whatever ran forward (the
+        # saved log-sum-exp is the same quantity on either path).
+        return _attn_bwd_reference(q, k, v, o, lse, g, causal, sm_scale,
+                                   window)
     if use_pallas in ("tpu", "interpret"):
         return per_shard(
             functools.partial(
@@ -607,9 +640,17 @@ def resolve_flash_impl(force: Optional[str] = None) -> str:
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    force: Optional[str] = None):
+                    force: Optional[str] = None,
+                    window: Optional[int] = None):
     """Flash attention on [B, H, T, D]; ``force`` as in
-    :func:`resolve_flash_impl`."""
+    :func:`resolve_flash_impl`. ``window`` (causal only): a query sees the
+    ``window`` newest keys up to its own. The forward kernel skips and
+    masks by it; the backward kernels do not know it, so a windowed
+    backward runs the masked reference (O(T^2) scores: no windowed model
+    is trained at a length where that matters yet)."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    return _flash(q, k, v, causal, sm_scale, resolve_flash_impl(force))
+    if window is not None and not causal:
+        raise ValueError("a window is a causal layer's: causal=False")
+    return _flash(q, k, v, causal, sm_scale, resolve_flash_impl(force),
+                  window)

@@ -204,12 +204,13 @@ def gather_kv_pages(pages: jax.Array, block_tables: jax.Array,
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables, positions,
-                              *, sm_scale):
+                              *, sm_scale, window=None):
     """Dense fp32 attention over the gathered pages — numerics ground
     truth for the kernel, and the CPU default. Reproduces the op order
     of the pre-kernel model code (gather, repeat, fp32 einsums,
     additive-free masking via where, jax.nn.softmax) exactly so
-    fallback greedy generation is unchanged."""
+    fallback greedy generation is unchanged. With a ``window`` a query at
+    position p sees slots ``p - window < l <= p`` only."""
     b, t, h, d = q.shape
     ks = gather_kv_pages(k_pages, block_tables, d)
     vs = gather_kv_pages(v_pages, block_tables, d)
@@ -222,8 +223,12 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, positions,
                    ks.astype(jnp.float32)) * sm_scale
     # Slot l holds token l of the sequence; query token at absolute
     # position p sees slots 0..p.
-    visible = (jnp.arange(ks.shape[1], dtype=jnp.int32)[None, None, :]
-               <= positions[:, :, None])
+    slots = jnp.arange(ks.shape[1], dtype=jnp.int32)[None, None, :]
+    visible = slots <= positions[:, :, None]
+    if window is not None:
+        # A window layer: the ``window`` newest slots, the token's own
+        # among them.
+        visible &= slots > positions[:, :, None] - window
     s = jnp.where(visible[:, None, :, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhtl,blhd->bthd", p, vs.astype(jnp.float32))
@@ -237,7 +242,7 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, positions,
 
 def _paged_kernel(bt_ref, qs_ref, q_ref, k_hbm, v_hbm, o_ref,
                   k_buf, v_buf, sems, half_ref, m_scr, l_scr, acc_scr,
-                  *, sm_scale, bq_t, rep, n_pg, n_grp, n_qb, span):
+                  *, sm_scale, bq_t, rep, n_pg, n_grp, n_qb, span, window):
     """One grid step: the query heads of one kv-head group and query-token
     block iq of sequence b, attending that sequence's live pages a block
     of ``ppb`` at a time. A block's pages are copied by one DMA each into
@@ -250,7 +255,13 @@ def _paged_kernel(bt_ref, qs_ref, q_ref, k_hbm, v_hbm, o_ref,
     is zero outside its own head's lanes, so one dense
     ``[rows, lanes] x [slots, lanes]^T`` product yields every head's
     scores, and row r of ``p @ v`` is right on the lanes of r's head
-    (the wrapper keeps those and drops the rest)."""
+    (the wrapper keeps those and drops the rest).
+
+    With a ``window`` (a window layer's pool) a query at position p sees
+    slots ``p - window < l <= p``: the loop starts at the page that holds
+    the first slot the block's first row may see, not at page 0, so the
+    table's columns left of it are never looked up (the cache has given
+    those pages back) and the pages read do not grow with the context."""
     b, j, iq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     step = (b * n_grp + j) * n_qb + iq
     n_steps = pl.num_programs(0) * n_grp * n_qb
@@ -263,9 +274,21 @@ def _paged_kernel(bt_ref, qs_ref, q_ref, k_hbm, v_hbm, o_ref,
         last = (qs_ref[b_] + iq_ * bq_t + bq_t - 1) // page_size
         return jnp.clip(last, 0, n_pg - 1)
 
-    def live_pages(last_, i_):
+    def first_page(b_, iq_):
+        # The first page any row of q block iq_ may see: page 0, or the
+        # page of the oldest slot inside its first row's window.
+        if window is None:
+            return None
+        oldest = qs_ref[b_] + iq_ * bq_t - (window - 1)
+        return jnp.clip(oldest, 0, n_pg * page_size - 1) // page_size
+
+    def block_base(first_, i_):
+        # The first page of the sequence's block i_.
+        return i_ * ppb if first_ is None else first_ + i_ * ppb
+
+    def live_pages(last_, first_, i_):
         # Of block i_'s pages, those up to the sequence's last.
-        return jnp.minimum(ppb, last_ + 1 - i_ * ppb)
+        return jnp.minimum(ppb, last_ + 1 - block_base(first_, i_))
 
     def page_copies(page, j_, half, p):
         # A page of the group's heads: ``span`` lanes from the group's
@@ -277,9 +300,12 @@ def _paged_kernel(bt_ref, qs_ref, q_ref, k_hbm, v_hbm, o_ref,
             for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
 
     def start_block(b_, j_, iq_, i_, half):
-        @pl.loop(0, live_pages(last_page(b_, iq_), i_))
+        first_ = first_page(b_, iq_)
+
+        @pl.loop(0, live_pages(last_page(b_, iq_), first_, i_))
         def _start_page(p):
-            for copy in page_copies(bt_ref[b_, i_ * ppb + p], j_, half, p):
+            for copy in page_copies(bt_ref[b_, block_base(first_, i_) + p],
+                                    j_, half, p):
                 copy.start()
 
     @pl.when(step == 0)
@@ -293,7 +319,8 @@ def _paged_kernel(bt_ref, qs_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     q_start = qs_ref[b]  # absolute position of query token 0
     last = last_page(b, iq)
-    n_blk = last // ppb + 1
+    first = first_page(b, iq)
+    n_blk = (last if first is None else last - first) // ppb + 1
 
     def block(i, half):
         # The block after this one: the sequence's next, or the first
@@ -308,7 +335,7 @@ def _paged_kernel(bt_ref, qs_ref, q_ref, k_hbm, v_hbm, o_ref,
                         jnp.where(ends, nxt % n_qb, iq),
                         jnp.where(ends, 0, i + 1), 1 - half)
 
-        live = live_pages(last, i)
+        live = live_pages(last, first, i)
 
         @pl.loop(0, live)
         def _wait_page(p):
@@ -336,7 +363,13 @@ def _paged_kernel(bt_ref, qs_ref, q_ref, k_hbm, v_hbm, o_ref,
             jax.lax.rem(row, bq_t * rep), rep)
         slot = i * slots + jax.lax.broadcasted_iota(
             jnp.int32, (rows, slots), 1)
-        s = jnp.where(slot <= q_start + tok, s, _NEG_INF)
+        if first is None:
+            seen = slot <= q_start + tok
+        else:
+            slot = first * page_size + slot
+            seen = jnp.logical_and(slot <= q_start + tok,
+                                   slot > q_start + tok - window)
+        s = jnp.where(seen, s, _NEG_INF)
 
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
@@ -424,9 +457,9 @@ def _pages_per_block(page_size: int, rows: int, lanes: int,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("sm_scale", "interpret"))
+    jax.jit, static_argnames=("sm_scale", "interpret", "window"))
 def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
-                  *, sm_scale, interpret):
+                  *, sm_scale, interpret, window=None):
     b, t, h, d = q.shape
     # The pools as they are held: [num_pages, page_size, kv * d].
     _, page_size, width = k_pages.shape
@@ -476,7 +509,7 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
     span = lanes if interpret else _whole_lane_tiles(lanes)
     kernel = functools.partial(
         _paged_kernel, sm_scale=sm_scale, bq_t=bq_t, rep=rep, n_pg=n_pg,
-        n_grp=n_grp, n_qb=n_qb, span=span)
+        n_grp=n_grp, n_qb=n_qb, span=span, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_grp, n_qb),
@@ -521,7 +554,7 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, positions, *,
-                    sm_scale=None, force=None):
+                    sm_scale=None, force=None, window=None):
     """Attention of queries ``q`` against the paged KV cache.
 
     Args:
@@ -536,6 +569,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions, *,
       sm_scale: softmax scale (default ``head_dim ** -0.5``).
       force: implementation selector (the model config's ``paged_attn``
         field); ``None`` defers to ``RAYTPU_PAGED_ATTN``.
+      window: a window layer's width in tokens: a token at position p
+        attends slots ``p - window < l <= p`` (``window`` of them, its
+        own among them). The table's columns left of the page that holds
+        slot ``p - window + 1`` of the call's first query are not read
+        and may hold anything (the cache names scratch there).
 
     Returns ``[B, T, H, D]`` in q's dtype.  Rows whose position is
     padding produce well-defined garbage (they attend real slots of
@@ -548,13 +586,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions, *,
     if impl == "reference":
         return paged_attention_reference(
             q, k_pages, v_pages, block_tables, positions,
-            sm_scale=sm_scale)
+            sm_scale=sm_scale, window=window)
     # Per shard under a mesh: q and the result [B, T, H, D], the pools
     # [pages, page_size, KV * D] (whole heads to a shard), tables and
     # positions [B, ...].
     return per_shard(
         functools.partial(_paged_pallas, sm_scale=sm_scale,
-                          interpret=(impl == "interpret")),
+                          interpret=(impl == "interpret"), window=window),
         (q, k_pages, v_pages, block_tables, positions),
         ("b.h.", "..h", "..h", "b.", "b."), "b.h.",
         heads=(k_pages.shape[2] // q.shape[3],))

@@ -30,7 +30,8 @@ def scatter_4d(pages, dests, rows):
 
 
 def attention_4d(q, k_pages, v_pages, block_tables, positions, *,
-                 sm_scale=None, force=None):
+                 sm_scale=None, force=None, window=None):
+    assert window is None  # the parent's pools knew no window layer
     b, t, h, d = q.shape
     n, ps, kv, _ = k_pages.shape
     if sm_scale is None:

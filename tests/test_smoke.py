@@ -106,6 +106,33 @@ class TestPhasesAtTinySize:
                 TINY, page_size=OPTIONS["page_size"],
                 chunk=OPTIONS["prefill_chunk"], tol=1e-4)
 
+    def test_window_phase(self):
+        from raytpu.models.mixtral import MellumConfig
+
+        tiny = dataclasses.replace(
+            MellumConfig.tiny(), dtype=TINY.dtype, attn_impl="interpret",
+            paged_attn="interpret", remat=False)
+        facts = chip_smoke.window_phase(tiny, page_size=4, chunk=16,
+                                        tol=1e-4)
+        assert facts["prompt_tokens"] == [29, 12]
+        assert set(facts["rel_err"]) == {"prompt_29", "prompt_12"}
+        assert facts["window_pages_released"] >= 6
+        assert facts["live_pages_window_max"] <= 2 * 3
+
+    def test_window_phase_catches_an_ignored_window(self, monkeypatch):
+        from raytpu.models.mixtral import MellumConfig
+
+        pa = sys.modules["raytpu.ops.paged_attention"]
+        kernel = pa._paged_pallas
+        monkeypatch.setattr(
+            pa, "_paged_pallas",
+            lambda *a, window=None, **kw: kernel(*a, **kw))
+        tiny = dataclasses.replace(
+            MellumConfig.tiny(), dtype=TINY.dtype, attn_impl="interpret",
+            paged_attn="interpret", remat=False)
+        with pytest.raises(RuntimeError, match="window-layer logits"):
+            chip_smoke.window_phase(tiny, page_size=4, chunk=16, tol=1e-4)
+
     def test_serve_phase(self, raytpu_local):
         facts = chip_smoke.serve_phase(TINY, OPTIONS, new_tokens=6,
                                        expect_impl="interpret")
