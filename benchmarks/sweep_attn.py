@@ -1,166 +1,197 @@
-"""Flash-attention block-size sweep (VERDICT r3 next-round #3).
+"""Flash-attention tile sweep: the three kernels, each timed alone.
 
-Measures fwd+bwd wall time of :func:`raytpu.ops.flash_attention` at the
-GPT-2 bench shape across pallas tile shapes, one SUBPROCESS per combo
-(the kernel reads RAYTPU_FLASH_BLOCK_Q/K at import), plus the XLA
-reference implementation as the A/B baseline. Prints one JSON line per
-combo and a final summary line; run on the real chip:
+For every shape and every tile (rows of q x rows of k) this times the
+forward, dq and dk/dv kernels of ``raytpu/ops/flash_attention.py`` one at a
+time, in ONE process (a process a tile cost a quarter of a minute each to
+reach the chip, and SWEEP_ATTN_r05.json is five of them timing out). The
+tiles go in through ``DEFAULT_BLOCK_Q/K``, the module attributes that
+RAYTPU_FLASH_BLOCK_Q/K set at import. Run it on the chip:
 
-    python benchmarks/sweep_attn.py              # full sweep
-    RAYTPU_ATTN_SWEEP_SMOKE=1 ... (tiny, CPU ok)
+    chiprun --chips 1 -- python benchmarks/sweep_attn.py
+    ... --shapes 256x1024x64,16x256x128 --tiles 512x256,256x512
+    ... --file some/other/flash_attention.py   # another copy of the kernels
 
-The same honesty discipline as bench.py: warmup excluded, the clock
-stops on a host fetch of a value depending on every step, steps double
-until a minimum wall time.
+One JSON line per (shape, tile), a summary line with the best tile of each
+kernel per shape, and the whole table in chiprun_out/sweep_attn.json. The
+first tile of each shape is also checked against the einsum reference.
+Off a TPU it refuses to time anything (``--smoke`` runs tiny shapes in the
+interpreter to prove the control flow).
+
+A time is the device's own: the mean length of the kernel's custom call
+in a profiler trace of ``--repeat`` calls, compile and warm-up excluded
+(on the host's clock a call of these sizes is mostly its dispatch).
 """
 
 from __future__ import annotations
 
+import argparse
+import glob
+import importlib.util
 import json
 import os
-import subprocess
+import shutil
 import sys
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-COMBOS = [(128, 128), (256, 128), (128, 256), (256, 256),
-          (512, 128), (128, 512), (512, 512)]
+SHAPES = "256x1024x64,100x1024x64,16x256x128,25x512x64"
+TILES = ",".join(f"{q}x{k}" for q in (128, 256, 512, 1024)
+                 for k in (128, 256, 512, 1024))
 
 
-def measure_one(impl: str) -> dict:
-    """Runs inside the per-combo subprocess."""
+def load(path):
+    spec = importlib.util.spec_from_file_location("flash_under_sweep", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def device_ms(calls, logdir, repeat):
+    """Milliseconds a call of each kernel takes on the device: the mean
+    length of its custom call's events in a profiler trace of ``repeat``
+    calls of each of ``calls`` (name -> thunk), read and told apart as the
+    benchmark's ``flash_attn_roofline`` reads them."""
+    import jax
+
+    from perfbench import trace_reduce
+    from perfbench.layer_metrics.flash_attn_roofline import classify
+
+    for thunk in calls.values():
+        jax.block_until_ready(thunk())  # compile, warm
+    shutil.rmtree(logdir, ignore_errors=True)
+    with jax.profiler.trace(logdir):
+        for thunk in calls.values():
+            for _ in range(repeat):
+                out = thunk()
+            jax.block_until_ready(out)
+    path, = glob.glob(os.path.join(logdir, "plugins/profile/*/*.xplane.pb"))
+    events = trace_reduce.kernel_events(trace_reduce.load_xplane(path),
+                                        trace_reduce.is_pallas)
+    spent = {}
+    for e in (e for evs in events.values() for e in evs):
+        found = classify(e.name)
+        if found and found[0] in calls:
+            spent.setdefault(found[0], []).append(e.seconds * 1e3)
+    return {k: round(sum(v) / len(v), 4) for k, v in spent.items()}
+
+
+def wall_ms(calls):
+    """The interpreter's wall time of one call each: --smoke only."""
+    import jax
+
+    out = {}
+    for name, thunk in calls.items():
+        t0 = time.perf_counter()
+        jax.block_until_ready(thunk())
+        out[name] = round((time.perf_counter() - t0) * 1e3, 4)
+    return out
+
+
+def kernels(fa, interpret):
+    """The three kernels as functions of (q, k, v, o, lse, g)."""
+    import jax
+
+    scale = lambda q: q.shape[-1] ** -0.5  # noqa: E731
+
+    def fwd(q, k, v):
+        return fa._flash_forward_pallas(
+            q, k, v, True, scale(q), fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K,
+            interpret)
+
+    def bwd(q, k, v, o, lse, g):
+        return fa._flash_backward_pallas(
+            q, k, v, o, lse, g, True, scale(q), fa.DEFAULT_BLOCK_Q,
+            fa.DEFAULT_BLOCK_K, interpret)
+
+    # XLA drops the call whose results are not returned.
+    return {"fwd": jax.jit(fwd),
+            "dq": jax.jit(lambda *a: bwd(*a)[0]),
+            "dkv": jax.jit(lambda *a: bwd(*a)[1:])}
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shapes", default=SHAPES,
+                    help="batch*heads x T x head_dim, comma-separated")
+    ap.add_argument("--tiles", default=TILES,
+                    help="rows of q x rows of k, comma-separated; 0x0 is "
+                         "the file's own table")
+    ap.add_argument("--file", default=os.path.join(
+        REPO, "raytpu", "ops", "flash_attention.py"))
+    ap.add_argument("--repeat", type=int, default=8,
+                    help="traced calls of each kernel a tile")
+    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--out", default=os.path.join(
+        REPO, "chiprun_out", "sweep_attn.json"))
+    args = ap.parse_args()
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    smoke = os.environ.get("RAYTPU_ATTN_SWEEP_SMOKE") == "1"
-    if smoke:
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
+    device = jax.devices()[0]
+    if device.platform != "tpu" and not args.smoke:
+        sys.exit("sweep_attn times kernels on a TPU; --smoke for the CPU")
+    fa = load(args.file)
+    shapes = [tuple(int(x) for x in s.split("x"))
+              for s in args.shapes.split(",")]
+    tiles = [tuple(int(x) for x in s.split("x"))
+             for s in args.tiles.split(",")]
+    if args.smoke:
+        shapes, tiles = [(2, 256, 64)], [(128, 128)]
+    names = ("fwd", "dq", "dkv")
 
-    import importlib
-
-    # raytpu.ops re-exports the flash_attention FUNCTION, which shadows
-    # the submodule on plain attribute imports.
-    fa = importlib.import_module("raytpu.ops.flash_attention")
-
-    if smoke:
-        b, h, t, d = 1, 2, 256, 64
-        min_wall = 0.3
-    else:
-        b, h, t, d = int(os.environ.get("RAYTPU_ATTN_B", 8)), 12, 1024, 64
-        min_wall = 1.0
-    force = impl if impl != "reference" else "reference"
-    if smoke and impl == "tpu":
-        force = "interpret"
-
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (b, h, t, d), jnp.bfloat16)
-
-    def loss(q):
-        out = fa.flash_attention(q, q, q, force=force)
-        return jnp.sum(out.astype(jnp.float32))
-
-    step = jax.jit(jax.grad(loss))
-    g = step(q)
-    np.asarray(jax.device_get(g[0, 0, 0, 0]))  # warmup + compile
-    steps = 3
-    while True:
-        t0 = time.perf_counter()
-        acc = q
-        for _ in range(steps):
-            acc = step(acc).astype(jnp.bfloat16)
-        host = float(np.asarray(jax.device_get(acc[0, 0, 0, 0])))
-        dt = time.perf_counter() - t0
-        if dt >= min_wall:
-            break
-        steps *= 2
-    ms = dt / steps * 1e3
-    import math
-    return {"impl": impl, "dot": fa.DEFAULT_DOT_MODE,
-            "block_q": fa.DEFAULT_BLOCK_Q, "block_k": fa.DEFAULT_BLOCK_K,
-            "fwd_bwd_ms": round(ms, 3), "steps": steps,
-            # NaN (iterated-gradient sink overflows bf16 for some impls)
-            # is not valid JSON — strict consumers like jq reject it.
-            "shape": [b, h, t, d], "sink": None if math.isnan(host)
-            else host,
-            "device": str(jax.devices()[0])}
-
-
-def main() -> None:
-    if os.environ.get("_RAYTPU_ATTN_CHILD"):
-        print(json.dumps(measure_one(os.environ["_RAYTPU_ATTN_IMPL"])))
-        return
-
-    results = []
-
-    def child(env_extra, impl):
-        env = dict(os.environ, _RAYTPU_ATTN_CHILD="1",
-                   _RAYTPU_ATTN_IMPL=impl, **env_extra)
-        try:
-            r = subprocess.run(
-                [sys.executable, os.path.abspath(__file__)], env=env,
-                capture_output=True, text=True, timeout=600)
-        except subprocess.TimeoutExpired:
-            out = {"impl": impl, "env": env_extra,
-                   "error": "child timed out after 600s"}
-            results.append(out)
-            print(json.dumps(out), flush=True)
-            return
-        out = None
-        lines = r.stdout.strip().splitlines()
-        if r.returncode == 0 and lines:
+    rows = []
+    for bh, t, d in shapes:
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, k, v, g = (jax.random.normal(kk, (1, bh, t, d), jnp.bfloat16)
+                      for kk in keys)
+        ref_o, ref_lse = fa._attn_fwd_reference(q, k, v, True, d ** -0.5)
+        ref_grads = fa._attn_bwd_reference(q, k, v, ref_o, ref_lse, g, True,
+                                           d ** -0.5)
+        for n, (tq, tk) in enumerate(tiles):
+            # The tile is read when a call is traced: new functions a tile.
+            fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K = tq or None, tk or None
+            fns = kernels(fa, interpret=args.smoke)
+            row = {"shape": [bh, t, d], "tile": [tq, tk]}
             try:
-                out = json.loads(lines[-1])
-            except json.JSONDecodeError:
-                out = None
-        if not out or "fwd_bwd_ms" not in out:
-            out = {"impl": impl, "env": env_extra,
-                   "error": ((r.stderr or r.stdout)[-400:]
-                             or f"rc={r.returncode}, no output")}
-        results.append(out)
-        print(json.dumps(out), flush=True)
+                o, lse = fns["fwd"](q, k, v)
+                bwd_args = (q, k, v, o, lse, g)
+                calls = {"fwd": lambda: fns["fwd"](q, k, v),
+                         "dq": lambda: fns["dq"](*bwd_args),
+                         "dkv": lambda: fns["dkv"](*bwd_args)}
+                ms = wall_ms(calls) if args.smoke else device_ms(
+                    calls, os.path.join(os.path.dirname(args.out),
+                                        "sweep_attn_trace"), args.repeat)
+                row.update((name + "_ms", ms[name]) for name in names)
+                if n == 0:  # against the reference, once a shape
+                    got = [o, fns["dq"](*bwd_args), *fns["dkv"](*bwd_args)]
+                    want = [ref_o, *ref_grads]
+                    row["max_err"] = [
+                        float(np.abs(np.asarray(a, np.float32)
+                                     - np.asarray(b, np.float32)).max())
+                        for a, b in zip(got, want)]
+            except Exception as e:  # a tile Mosaic refuses (VMEM, alignment)
+                row["error"] = str(e)[-300:]
+            rows.append(row)
+            print(json.dumps(row), flush=True)
 
-    combos = COMBOS
-    env_combos = os.environ.get("RAYTPU_ATTN_SWEEP_COMBOS")
-    if env_combos:  # e.g. "512x512,256x256" — focused A/B runs
-        combos = []
-        for tok in env_combos.split(","):
-            parts = tok.strip().split("x")
-            if len(parts) == 2 and all(p.strip().isdigit() for p in parts):
-                combos.append((int(parts[0]), int(parts[1])))
-            else:
-                print(f"# skipping malformed combo {tok!r}",
-                      file=sys.stderr)
-        if not combos:
-            print("# RAYTPU_ATTN_SWEEP_COMBOS had no valid QxK entries; "
-                  "using the default sweep", file=sys.stderr)
-            combos = COMBOS
-
-    # Dot mode doesn't affect the XLA reference path, so focused A/B
-    # re-runs can skip re-measuring the identical baseline.
-    if os.environ.get("RAYTPU_ATTN_SWEEP_SKIP_REF") != "1":
-        child({}, "reference")  # XLA baseline at the same shape
-    for bq, bk in combos:
-        child({"RAYTPU_FLASH_BLOCK_Q": str(bq),
-               "RAYTPU_FLASH_BLOCK_K": str(bk)}, "tpu")
-    ok = [r for r in results if "fwd_bwd_ms" in r and r["impl"] == "tpu"]
-    summary = {"metric": "flash_attention_block_sweep"}
-    if ok:
-        best = min(ok, key=lambda r: r["fwd_bwd_ms"])
-        summary.update(best=best,
-                       reference_ms=next(
-                           (r["fwd_bwd_ms"] for r in results
-                            if r["impl"] == "reference"
-                            and "fwd_bwd_ms" in r), None))
-    else:
-        summary["error"] = "no pallas combo succeeded"
-    summary["sweep"] = results
-    print(json.dumps(summary))
+    summary = {"metric": "flash_attention_tile_sweep", "device": str(device),
+               "device_kind": device.device_kind, "file": args.file,
+               "best": {}}
+    for shape in shapes:
+        mine = [r for r in rows if r["shape"] == list(shape)]
+        summary["best"]["x".join(map(str, shape))] = {
+            name: min(([r[name + "_ms"], r["tile"]] for r in mine
+                       if name + "_ms" in r), default=None)
+            for name in names}
+    summary["sweep"] = rows
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(summary, f, indent=1)
+    print(json.dumps({k: v for k, v in summary.items() if k != "sweep"}))
 
 
 if __name__ == "__main__":

@@ -210,7 +210,9 @@ declare_env("RAYTPU_ZEROCOPY",
             "(bool, default on; off is byte-identical to the legacy layout)")
 
 # Kernels (ops/flash_attention.py, ops/paged_attention.py).
-declare_env("RAYTPU_FLASH_DOT", "force the dot-product flash-attention path (bool)")
+declare_env("RAYTPU_FLASH_DOT",
+            "operand type of the flash kernels' products: input (as q, k, v "
+            "come, float32 accumulation; the default) | f32 (upcast first)")
 declare_env("RAYTPU_FLASH_BLOCK_Q", "flash-attention query tile rows")
 declare_env("RAYTPU_FLASH_BLOCK_K", "flash-attention key tile rows")
 declare_env("RAYTPU_PAGED_ATTN",

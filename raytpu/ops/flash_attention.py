@@ -1,23 +1,47 @@
-"""Flash attention as a Pallas TPU kernel.
+"""Flash attention as three Pallas TPU kernels: forward, dq and dk/dv.
 
-Blockwise-stable softmax with O(T) memory. The grid is
-``(batch*heads, q_blocks, kv_blocks)`` with the K/V walk as the
-*innermost grid dimension*, so the Mosaic pipeline double-buffers the
-K/V block DMAs from HBM into VMEM while running max / denominator /
-output accumulator persist in VMEM scratch across kv iterations (the
-canonical TPU flash pattern — scratch carries state because TPU grids
-execute sequentially over the arbitrary dimension). Matmuls hit the MXU
-in fp32 accumulation (``preferred_element_type``); causally fully-masked
-K/V blocks are skipped with `pl.when` predication.
+Blockwise-stable softmax with O(T) memory; the backward recomputes the
+scores from the residuals (q, k, v, o, lse), so the [T, T] matrix never
+reaches HBM. All three work on one kind of tile: **keys down the sublanes,
+queries along the lanes** (the scores' transpose). In that orientation a
+query's statistics (running max and sum, lse, delta) are a lane-major row
+that broadcasts down the sublanes for nothing, the max and the sum over
+keys are plain vector maxima and additions with one sublane reduction per
+128 queries at the end, the accumulators ``[D, Bq]`` fill their lanes at
+``head_dim`` 64, and lse and delta travel as ``f32[bh, 1, T]``: 4 bytes a
+row. With queries down the sublanes (as this file had it until PR 33) a
+tile of 512 x 512 paid 64 cross-lane reductions for each of the max and
+the sum, and they, not the products, were most of the forward
+(PERF.md §6, PR 33: 2.00 ms a call at [256, 1024, 64], 1.26 with the max
+and sum left out, 0.66 now).
 
-Backward is flash-style recompute: residuals are just (q, k, v, o, lse).
-On TPU two Pallas kernels produce the gradients without ever
-materializing the [T, T] score matrix in HBM — a dq kernel (grid walks
-K/V innermost, dq accumulates in VMEM scratch) and a dk/dv kernel (grid
-walks Q innermost, dk/dv accumulate in scratch); `p = exp(s - lse)`
-reuses the saved log-sum-exp so no running max is needed. Elsewhere the
-reference einsum formulation runs instead (tests compare the kernels in
-interpret mode against it).
+A grid step holds a block of one side (queries in the forward and in dq,
+keys in dk/dv) and walks the other in sub-blocks, in rolled loops whose
+bounds are the causal limit's (and the window's, forward): a sub-block no
+query sees is not visited, one every query sees whole takes a body with
+no mask, and only those the diagonal or the window's edge crosses are
+masked. Where the diagonal runs from corner to corner of the held block
+(equal lengths, no window: every training call and every whole-prompt
+prefill) the tiles it crosses are walked unrolled, in chunks of keys that
+each take the queries from their own first on, so the corner no query
+sees is not computed (`_diagonal_chunk`). The walked side arrives a major
+block at a time along the innermost grid dimension (the whole of it up to
+`_MAJOR_ROWS`), double-buffered by the pipeline; a major block no query
+sees is neither fetched nor visited. A grid axis of one step hands the
+kernel a Python 0 for its index, so a call of one block (a short prefill)
+knows its walk when it is traced and is one tile with no loop beside it:
+a serving program traces and lowers the forward once a layer, and
+set-up pays for every line of its body 48 times a program in GPT-2 XL
+(PERF.md §6, PR 33). ``sm_scale`` multiplies q (k in
+dk/dv) once a block where that is exact, a power of two, and the float32
+scores otherwise; dq and dk take it on their ``[D, B]`` result. Products
+feed the MXU in the operand type with float32 accumulation; ``exp`` and
+the statistics are float32.
+
+The tile's sizes are `_TILES`: what the chip chose (benchmarks/
+sweep_attn.py, PERF.md §6), fitted to the lengths by `_fit_block`. Off a
+TPU the reference einsum formulation runs instead (tests compare the
+kernels in interpret mode against it).
 
 Cross-length causal (t_q != t_kv) uses a bottom-aligned diagonal
 (``tril(k=t_kv-t_q)``, matching the reference path). For t_q > t_kv the
@@ -38,22 +62,42 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
-def _env_block(name: str, default: int) -> int:
-    """Malformed/empty/non-positive overrides fall back silently — a bad
-    env var must not break every import of raytpu.ops."""
+
+def _env_block(name: str) -> Optional[int]:
+    """A tile's rows from the environment, or None: malformed, empty and
+    non-positive values fall back silently (a bad env var must not break
+    every import of raytpu.ops)."""
     try:
-        v = int(os.environ.get(name) or default)
+        v = int(os.environ.get(name) or 0)
     except ValueError:
-        return default
-    return v if v > 0 else default
+        return None
+    return v if v > 0 else None
 
 
-# Tile shape of the pallas kernel's grid. Env-overridable so
-# benchmarks/sweep_attn.py can A/B block shapes per process without code
-# edits (_fit_block shrinks them to tile the actual sequence length).
-# 512x512 has not been measured against other shapes on a chip.
-DEFAULT_BLOCK_Q = _env_block("RAYTPU_FLASH_BLOCK_Q", 512)
-DEFAULT_BLOCK_K = _env_block("RAYTPU_FLASH_BLOCK_K", 512)
+# Rows of q and of k in a tile of scores where the environment (or a test,
+# or benchmarks/sweep_attn.py) overrides the table below; unset, the table.
+DEFAULT_BLOCK_Q = _env_block("RAYTPU_FLASH_BLOCK_Q")
+DEFAULT_BLOCK_K = _env_block("RAYTPU_FLASH_BLOCK_K")
+
+# kernel: (rows of q, rows of k, rows of keys in a chunk on the diagonal),
+# as the chip chose them at [256, 1024, 64], [100, 1024, 64] and the
+# prefills' [25, 512, 64], [16, 256, 128], [16, 1280, 128] (PERF.md, PR 33:
+# every kernel was fastest holding 1,024 rows, which at T = 1,024 leaves
+# no tile off the diagonal, and each at its own chunk). One row: no length
+# or head size measured wanted another.
+_TILES = {"fwd": (1024, 512, 512), "dq": (1024, 512, 256),
+          "dkv": (512, 1024, 128)}
+
+
+def _tile(kernel: str, block_q=None, block_k=None):
+    """``(rows of q, rows of k, rows of a chunk on the diagonal)`` of a
+    tile of scores in ``kernel`` ("fwd", "dq" or "dkv"), before
+    :func:`_fit_block` fits them to the lengths. In the forward and in dq
+    the rows of q are the block a grid step holds and the rows of k the
+    sub-block it walks the keys in; in dk/dv the other way round. The
+    lengths and the head size are no arguments: `_TILES` has one row."""
+    want_q, want_k, chunk = _TILES[kernel]
+    return block_q or want_q, block_k or want_k, chunk
 
 
 def _env_dot_mode() -> str:
@@ -71,11 +115,11 @@ def _env_dot_mode() -> str:
     return mode
 
 
-# MXU operand dtype inside the kernels. "input" feeds q/k/v (and p/ds,
-# cast back down) to the MXU in their input dtype with fp32 accumulation
-# via preferred_element_type — the official TPU flash pattern; "f32"
-# upcasts every operand first (r4-and-earlier behavior, ~roundoff-free
-# but slower when inputs are bf16). Env-overridable for the sweep A/B.
+# MXU operand dtype inside the kernels (RAYTPU_FLASH_DOT). "input" feeds
+# q, k, v (and p and ds, cast back down) to the MXU as they come, with
+# float32 accumulation: what every cell runs and every measurement in
+# PERF.md is of. "f32" upcasts every operand first (several passes of the
+# MXU a product); no chip run has timed it since the tile was turned over.
 DEFAULT_DOT_MODE = _env_dot_mode()
 
 
@@ -195,387 +239,556 @@ def _attn_bwd_reference(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-# -- pallas kernel ------------------------------------------------------------
+# -- pallas kernels -----------------------------------------------------------
+#
+# The module docstring says what a grid step holds and walks. Every tile
+# below is [keys, queries]: keys down the sublanes, queries along the lanes.
+
+_LANES = 128
+_MASKED = -1e30
+# Rows of the walked side that a grid step holds in VMEM (twice over, the
+# pipeline's two buffers): 2 MB of K and V at head_dim 128.
+_MAJOR_ROWS = 2048
 
 
-_LANES = 128  # VMEM scratch lane width; m/l broadcast across lanes.
+def _walk_blocks(t: int, want: int, interpret: bool):
+    """``(sub, major)``: the sub-block the kernels walk ``t`` rows in, and
+    how many rows of it a grid step holds. A sub-block that is no whole
+    number of 128-lane tiles cannot be sliced out of a longer block (its
+    row statistics lie along the lanes), so it is a major block alone."""
+    sub = _fit_block(t, want, interpret)
+    n = t // sub
+    if sub % _LANES:
+        return sub, sub
+    count = max(c for c in range(1, n + 1)
+                if n % c == 0 and (c == 1 or sub * c <= _MAJOR_ROWS))
+    return sub, sub * count
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                  m_scr, l_scr, acc_scr, *, causal: bool,
-                  sm_scale: float, block_q: int, block_k: int, n_kb: int,
-                  off: int, dot_mode: str, window=None):
+def _stat_lanes(block: int, n_blocks: int) -> int:
+    """Lanes one block's row statistics take in ``f32[bh, 1, ·]``: the
+    block's rows, padded to whole tiles where Mosaic could not address a
+    block of them otherwise."""
+    if n_blocks == 1 or block % _LANES == 0:
+        return block
+    return -(-block // _LANES) * _LANES
+
+
+def _stat_rows(x, block: int):
+    """``[bh, T]`` row statistics as the kernels take them: one lane-major
+    row a head, ``[bh, 1, ·]``, a slot of :func:`_stat_lanes` a block."""
+    bh, t = x.shape
+    n = t // block
+    lanes = _stat_lanes(block, n)
+    if lanes != block:
+        x = jnp.pad(x.reshape(bh, n, block),
+                    ((0, 0), (0, 0), (0, lanes - block)))
+    return x.reshape(bh, 1, n * lanes)
+
+
+def _clip(x, lo, hi):
+    """``x`` held to ``[lo, hi]``: a Python int where all three are (a grid
+    of one step knows its bounds when it is traced), else traced."""
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+def _seen_keys(q_start, block_q: int, sub: int, first, count: int, off: int,
+               causal: bool, window):
+    """Of the ``count`` sub-blocks of keys from ``first``, which a block of
+    query rows walks: some row sees a key of ``[lo, hi)``, and every row
+    sees all of ``[whole_lo, whole_hi)``. The diagonal is bottom-aligned
+    (``off = t_kv - t_q``, the reference's ``tril(k=off)``)."""
+    last = first + count
+    if not causal:
+        return first, first, last, last
+    top = q_start + off  # the newest key the first row sees
+    bot = top + block_q - 1  # and the last row
+    hi = _clip(bot // sub + 1, first, last)
+    if window is None:
+        lo = whole_lo = first
+    else:
+        lo = _clip((top - window + 1) // sub, first, hi)
+        whole_lo = _clip((bot - window) // sub + 1, lo, hi)
+    whole_hi = _clip((top + 1) // sub, whole_lo, hi)
+    return lo, whole_lo, whole_hi, hi
+
+
+def _seeing_queries(k_start, block_k: int, sub: int, first, count: int,
+                    off: int, causal: bool):
+    """The mirror of :func:`_seen_keys` for a block of keys: of the
+    sub-blocks of query rows from ``first``, those from ``lo`` on hold a
+    row that sees a key of the block, and from ``whole_lo`` on every row
+    sees them all."""
+    last = first + count
+    if not causal:
+        return first, first, last
+    lo = _clip((k_start - off) // sub, first, last)
+    whole_lo = _clip((k_start + block_k - 2 - off) // sub + 1, lo, last)
+    return lo, whole_lo, last
+
+
+def _diagonal_chunk(want: int, block: int, sub: int, off: int,
+                    one_major: bool, causal: bool, window=None) -> int:
+    """Rows of a chunk the tiles on the diagonal are cut into, or 0. Where
+    the diagonal runs from corner to corner of every held block (equal
+    lengths, whole sub-blocks to a block, the walked side one major block,
+    no window), the tiles it crosses lie the same in every block, so they
+    are walked unrolled, in chunks of keys of which each takes the queries
+    from its own first on: the corner no query sees is not computed."""
+    chunk = min(want, sub)
+    if (not causal or window is not None or not one_major or off
+            or block % sub or chunk % _LANES or sub % chunk
+            or block // chunk > 8):
+        return 0
+    return chunk
+
+
+def _grid_index(axis: int, steps: int):
+    """This grid step's index along ``axis``: a Python 0 where the axis has
+    one step, so that a call of one block (a short prefill; T = 1,024 under
+    the table) knows its walk when it is traced and holds no loop that
+    would not run and no slice at a computed row."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
 
-    d = q_ref.shape[2]
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    return 0 if steps == 1 else pl.program_id(axis)
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full((block_q, _LANES), -1e30, jnp.float32)
-        l_scr[...] = jnp.zeros((block_q, _LANES), jnp.float32)
-        acc_scr[...] = jnp.zeros((block_q, d), jnp.float32)
 
-    q_start = iq * block_q
-    k_start = ik * block_k
-    # Causally fully-masked K/V blocks contribute nothing. The diagonal is
-    # bottom-aligned for t_q != t_kv (off = t_kv - t_q), matching the
-    # reference path's tril(k=t_kv-t_q).
-    live = (k_start <= q_start + block_q - 1 + off) if causal else True
+def _when(cond):
+    """``pl.when``, decided at trace time where ``cond`` is a Python bool."""
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+
+    if isinstance(cond, bool):
+        return lambda body: body() if cond else None
+    return pl.when(cond)
+
+
+def _loop(lo, hi, body):
+    """A rolled loop over ``[lo, hi)``; none where it is empty at trace
+    time."""
+    if isinstance(lo, int) and isinstance(hi, int) and hi <= lo:
+        return
+    lax.fori_loop(lo, hi, body, 0)
+
+
+def _rows(start, size: int, multiple: Optional[int] = None):
+    """``size`` rows from ``start``, a multiple of ``multiple`` (of
+    ``size`` if none is given)."""
+    from jax.experimental import pallas as pl  # noqa: PLC0415
+
+    if isinstance(start, int):
+        return pl.ds(start, size)
+    return pl.ds(pl.multiple_of(start, multiple or size), size)
+
+
+def _sub_rows(i, sub: int, n_sub: int):
+    """The ``i``-th sub-block of a major block's rows."""
+    return _rows(0 if n_sub == 1 else i * sub, sub)
+
+
+def _key_less_query(keys: int, queries: int):
+    """A tile's key row less its query column, ``[keys, queries]``."""
+    return (lax.broadcasted_iota(jnp.int32, (keys, queries), 0)
+            - lax.broadcasted_iota(jnp.int32, (keys, queries), 1))
+
+
+def _mask(s, rel, limit, window):
+    """Scores a query does not see, to -1e30, in a tile of keys down the
+    sublanes and queries along the lanes. ``rel`` is
+    :func:`_key_less_query` and ``limit`` the largest a query sees: its
+    place in the tile, plus where the tile's queries start on the
+    (bottom-aligned) diagonal, less where its keys start."""
+    seen = rel <= limit
     if window is not None:
-        # Blocks wholly left of the first row's window contribute nothing
-        # either: the block's last key is older than the oldest it sees.
-        live = jnp.logical_and(
-            live, k_start + block_k - 1 > q_start + off - window)
+        seen = jnp.logical_and(seen, rel > limit - window)
+    return jnp.where(seen, s, _MASKED)
 
-    @pl.when(live)
-    def _compute():
-        # "input" mode feeds the MXU in the residual dtype (bf16 in, fp32
-        # accumulate) — native MXU speed; "f32" upcasts operands first.
-        mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
-        q = q_ref[0].astype(mxu)  # [Bq, D]
-        kb = k_ref[0].astype(mxu)  # [Bk, D]
-        vb = v_ref[0].astype(mxu)  # [Bk, D]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            seen = kpos <= qpos + off
-            if window is not None:
-                seen = jnp.logical_and(seen, kpos > qpos + off - window)
-            s = jnp.where(seen, s, -1e30)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, ((contract, ((), ()))),
+                           preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))  # a @ b.T
+_NN = ((1,), (0,))  # a @ b
+_TN = ((0,), (0,))  # a.T @ b
+
+
+def _walk_keys(tile, q_start, block_q: int, sub: int, n_sub: int, first,
+               chunk: int, off: int, causal: bool, window=None):
+    """The forward's and dq's walk: ``tile(rows, limit, masked, q0)`` over
+    the sub-blocks of keys a held block of queries sees in this major
+    block. Rolled loops, one body masked and one not; the diagonal's
+    tiles unrolled in chunks where :func:`_diagonal_chunk` allows."""
+    lo, whole_lo, whole_hi, hi = _seen_keys(
+        q_start, block_q, sub, first, n_sub, off, causal, window)
+
+    def step(j, carry, masked):
+        tile(_sub_rows(j - first, sub, n_sub), q_start + off - j * sub,
+             masked, 0)
+        return carry
+
+    if window is not None:
+        _loop(lo, whole_lo, functools.partial(step, masked=True))
+    _loop(whole_lo, whole_hi, functools.partial(step, masked=False))
+    if chunk:
+        for q0 in range(0, block_q, chunk):
+            tile(_rows(q_start + off + q0, chunk), 0, True, q0)
+    elif causal:
+        _loop(whole_hi, hi, functools.partial(step, masked=True))
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                  *, causal: bool, sm_scale: float, scale_q: bool, sub: int,
+                  chunk: int, off: int, steps, dot_mode: str, window=None):
+    """``steps``: the grid's steps along its last two axes (blocks of
+    queries, major blocks of keys), in all three kernels."""
+    block_q, d = q_ref.shape[1:]
+    n_sub = k_ref.shape[1] // sub
+    iq = _grid_index(1, steps[0])
+    ik = _grid_index(2, steps[1])
+
+    @_when(ik == 0)
+    def _init():
+        m_scr[...] = jnp.full((1, block_q), _MASKED, jnp.float32)
+        l_scr[...] = jnp.zeros((1, block_q), jnp.float32)
+        acc_scr[...] = jnp.zeros((d, block_q), jnp.float32)
+
+    # "input" mode feeds the MXU in the residual dtype (bf16 in, fp32
+    # accumulate); "f32" upcasts operands first.
+    mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
+    q = q_ref[0].astype(mxu)  # [Bq, D]
+    if scale_q:
+        q = q * sm_scale
+    q_start = iq * block_q
+    rel = _key_less_query(sub, block_q) if causal else None
+
+    def tile(rows, limit, masked, q0):
+        """The keys in ``rows`` of the major block against the queries
+        from ``q0`` on; ``limit`` as :func:`_mask` takes it."""
+        lanes = slice(q0, block_q)
+        kb = k_ref[0, rows, :].astype(mxu)  # [Sk, D]
+        vb = v_ref[0, rows, :].astype(mxu)
+        s = _dot(kb, q[q0:], _NT)  # [Sk, Bq - q0]
+        if not scale_q:
+            s = s * sm_scale
+        if masked:
+            s = _mask(s, rel[:s.shape[0], :s.shape[1]], limit, window)
+        m_prev = m_scr[:, lanes]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[...] = jnp.broadcast_to(m_new, (block_q, _LANES))
-        l_scr[...] = jnp.broadcast_to(l_new, (block_q, _LANES))
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p.astype(mxu), vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_scr[:, lanes] = (acc_scr[:, lanes] * corr
+                             + _dot(vb, p.astype(mxu), _TN))
+        l_scr[:, lanes] = (l_scr[:, lanes] * corr
+                           + jnp.sum(p, axis=0, keepdims=True))
+        m_scr[:, lanes] = m_new
 
-    @pl.when(ik == n_kb - 1)
+    _walk_keys(tile, q_start, block_q, sub, n_sub, ik * n_sub, chunk, off,
+               causal, window)
+
+    @_when(ik == steps[1] - 1)
     def _finalize():
-        m = m_scr[:, :1]
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(
-            (m + jnp.log(l)), (block_q, _LANES)).astype(jnp.float32)
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).T.astype(o_ref.dtype)
+        lse_ref[0, :, :block_q] = m_scr[...] + jnp.log(l)
+
+
+def _walked_index(block: int, major: int, n_major: int, off: int,
+                  causal: bool, window=None):
+    """Index map of the walked side's major blocks under a grid of
+    ``(bh, blocks of the held side, major blocks)``, for a held block of
+    ``block`` query rows. A major block no row sees is not fetched: its
+    grid step re-references the nearest one that is seen, which the
+    pipeline already holds."""
+    def index(ib, iq, ik):
+        if causal:
+            last = (iq * block + block - 1 + off) // major
+            ik = jnp.minimum(ik, jnp.clip(last, 0, n_major - 1))
+        if window is not None:
+            first = (iq * block + off - window + 1) // major
+            ik = jnp.maximum(ik, jnp.clip(first, 0, n_major - 1))
+        return (ib, ik, 0)
+    return index
+
+
+def _compiler(interpret: bool):
+    from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
+
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=(
+        pltpu.GridDimensionSemantics.PARALLEL,
+        pltpu.GridDimensionSemantics.PARALLEL,
+        pltpu.GridDimensionSemantics.ARBITRARY))}
+
+
+def _scale_on_operand(sm_scale: float) -> bool:
+    """A power of two scales q (or k) exactly in any float type; any
+    other scale stays a float32 multiply of the scores."""
+    return math.frexp(sm_scale)[0] == 0.5
 
 
 def _flash_forward_pallas(q, k, v, causal: bool, sm_scale: float,
-                          block_q: int, block_k: int, interpret: bool,
-                          window=None):
+                          block_q, block_k, interpret: bool, window=None):
+    """``(o [B, H, T, D], lse [B, H, T])``."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
     b, h, t_q, d = q.shape
     t_kv = k.shape[2]
     bh = b * h
-    q3 = q.reshape(bh, t_q, d)
-    k3 = k.reshape(bh, t_kv, d)
-    v3 = v.reshape(bh, t_kv, d)
-    block_q = _fit_block(t_q, block_q, interpret)
-    block_k = _fit_block(t_kv, block_k, interpret)
-    n_kb = t_kv // block_k
-
+    want_q, want_k, chunk = _tile("fwd", block_q, block_k)
+    block_q = _fit_block(t_q, want_q, interpret)
+    sub, major = _walk_blocks(t_kv, want_k, interpret)
+    n_qb = t_q // block_q
+    n_major = t_kv // major
+    lanes = _stat_lanes(block_q, n_qb)
     off = t_kv - t_q  # bottom-aligned diagonal (reference tril k=off)
-    kernel = functools.partial(
-        _flash_kernel, causal=causal, sm_scale=sm_scale,
-        block_q=block_q, block_k=block_k, n_kb=n_kb, off=off,
-        dot_mode=DEFAULT_DOT_MODE, window=window)
 
-    if causal and window is not None:
-        # As below, from both sides: iterations left of the window
-        # re-reference its first block, those past the diagonal its last.
-        def kv_index(ib, iq, ik):
-            last = (iq * block_q + block_q - 1 + off) // block_k
-            last = jnp.clip(last, 0, n_kb - 1)
-            first = (iq * block_q + off - window + 1) // block_k
-            first = jnp.clip(first, 0, n_kb - 1)
-            return (ib, jnp.clip(ik, first, last), 0)
-    elif causal:
-        # Clamp the K/V walk to the last causally-live block: iterations
-        # past the diagonal re-reference an already-fetched block, so the
-        # pipeline never DMAs fully-masked K/V from HBM (`pl.when` skips
-        # their compute; this skips their bandwidth too).
-        def kv_index(ib, iq, ik):
-            last = (iq * block_q + block_q - 1 + off) // block_k
-            last = jnp.clip(last, 0, n_kb - 1)
-            return (ib, jnp.minimum(ik, last), 0)
-    else:
-        def kv_index(ib, iq, ik):
-            return (ib, ik, 0)
+    def held(ib, iq, ik):
+        return (ib, iq, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda ib, iq, ik: (ib, iq, 0)),
-        pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_k, d), kv_index),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, block_q, d), lambda ib, iq, ik: (ib, iq, 0)),
-        pl.BlockSpec((1, block_q, _LANES), lambda ib, iq, ik: (ib, iq, 0)),
-    ]
-    scratch_shapes = [
-        pltpu.VMEM((block_q, _LANES), jnp.float32),
-        pltpu.VMEM((block_q, _LANES), jnp.float32),
-        pltpu.VMEM((block_q, d), jnp.float32),
-    ]
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=(
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.ARBITRARY,
-            ))
-
+    walked = _walked_index(block_q, major, n_major, off, causal, window)
     o3, lse3 = pl.pallas_call(
-        kernel,
-        grid=(bh, t_q // block_q, n_kb),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        functools.partial(
+            _flash_kernel, causal=causal, sm_scale=sm_scale,
+            scale_q=_scale_on_operand(sm_scale), sub=sub,
+            chunk=_diagonal_chunk(chunk, block_q, sub, off, n_major == 1,
+                                  causal, window),
+            off=off, steps=(n_qb, n_major), dot_mode=DEFAULT_DOT_MODE,
+            window=window),
+        grid=(bh, n_qb, n_major),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), held),
+            pl.BlockSpec((1, major, d), walked),
+            pl.BlockSpec((1, major, d), walked),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, d), held),
+            pl.BlockSpec((1, 1, lanes), lambda ib, iq, ik: (ib, 0, iq)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, n_qb * lanes), jnp.float32),
         ],
-        scratch_shapes=scratch_shapes,
+        scratch_shapes=[
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((d, block_q), jnp.float32),
+        ],
         interpret=interpret,
-        **kwargs,
-    )(q3, k3, v3)
-    return (o3.reshape(b, h, t_q, d),
-            lse3[:, :, :1].reshape(b, h, t_q, 1))
+        **_compiler(interpret),
+    )(q.reshape(bh, t_q, d), k.reshape(bh, t_kv, d), v.reshape(bh, t_kv, d))
+    if lanes != block_q:
+        lse3 = lse3.reshape(bh, n_qb, lanes)[:, :, :block_q]
+    return o3.reshape(b, h, t_q, d), lse3.reshape(b, h, t_q)
 
 
 # -- pallas backward kernels --------------------------------------------------
+#
+# Flash-style recompute from (q, k, v, lse) and delta = sum(g * o): no
+# running max. lse and delta arrive as lane-major rows, which is how the
+# [keys, queries] tile takes them; dq accumulates transposed, [D, Bq], and
+# is turned over once a block.
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal: bool, sm_scale: float,
-                         block_q: int, block_k: int, n_kb: int, off: int,
-                         dot_mode: str):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
+                         scale_q: bool, sub: int, chunk: int, off: int,
+                         steps, dot_mode: str):
+    block_q, d = q_ref.shape[1:]
+    n_sub = k_ref.shape[1] // sub
+    iq = _grid_index(1, steps[0])
+    ik = _grid_index(2, steps[1])
 
-    d = q_ref.shape[2]
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
+    @_when(ik == 0)
     def _init():
-        dq_scr[...] = jnp.zeros((block_q, d), jnp.float32)
+        dq_scr[...] = jnp.zeros((d, block_q), jnp.float32)
 
+    mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
+    q = q_ref[0].astype(mxu)
+    if scale_q:
+        q = q * sm_scale
+    g = g_ref[0].astype(mxu)
     q_start = iq * block_q
-    k_start = ik * block_k
-    live = (k_start <= q_start + block_q - 1 + off) if causal else True
+    rel = _key_less_query(sub, block_q) if causal else None
 
-    @pl.when(live)
-    def _compute():
-        mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
-        q = q_ref[0].astype(mxu)
-        kb = k_ref[0].astype(mxu)
-        vb = v_ref[0].astype(mxu)
-        g = g_ref[0].astype(mxu)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos + off, s, -1e30)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            g, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(mxu), kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile(rows, limit, masked, q0):
+        lanes = slice(q0, block_q)
+        kb = k_ref[0, rows, :].astype(mxu)
+        vb = v_ref[0, rows, :].astype(mxu)
+        s = _dot(kb, q[q0:], _NT)  # [Sk, Bq - q0]
+        if not scale_q:
+            s = s * sm_scale
+        if masked:
+            s = _mask(s, rel[:s.shape[0], :s.shape[1]], limit, None)
+        p = jnp.exp(s - lse_ref[0, :, lanes])  # a row, [1, Bq - q0]
+        ds = p * (_dot(vb, g[q0:], _NT) - delta_ref[0, :, lanes])
+        dq_scr[:, lanes] += _dot(kb, ds.astype(mxu), _TN)  # [D, Bq - q0]
 
-    @pl.when(ik == n_kb - 1)
+    _walk_keys(tile, q_start, block_q, sub, n_sub, ik * n_sub, chunk, off,
+               causal)
+
+    @_when(ik == steps[1] - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * sm_scale).T.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
-                          sm_scale: float, block_q: int, block_k: int,
-                          n_qb: int, off: int, dot_mode: str):
-    from jax.experimental import pallas as pl  # noqa: PLC0415
+                          sm_scale: float, scale_k: bool, sub: int, chunk: int,
+                          off: int, steps, dot_mode: str):
+    block_k, d = k_ref.shape[1:]
+    n_sub = q_ref.shape[1] // sub
+    ik = _grid_index(1, steps[0])
+    iq = _grid_index(2, steps[1])
 
-    d = q_ref.shape[2]
-    ik = pl.program_id(1)
-    iq = pl.program_id(2)
-
-    @pl.when(iq == 0)
+    @_when(iq == 0)
     def _init():
         dk_scr[...] = jnp.zeros((block_k, d), jnp.float32)
         dv_scr[...] = jnp.zeros((block_k, d), jnp.float32)
 
-    q_start = iq * block_q
+    mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
+    kb = k_ref[0].astype(mxu)  # [Bk, D]
+    if scale_k:
+        kb = kb * sm_scale
+    vb = v_ref[0].astype(mxu)
     k_start = ik * block_k
-    live = (q_start + block_q - 1 + off >= k_start) if causal else True
+    first = iq * n_sub
+    lo, whole_lo, last = _seeing_queries(
+        k_start, block_k, sub, first, n_sub, off, causal)
+    rel = _key_less_query(block_k, max(sub, block_k)) if causal else None
 
-    @pl.when(live)
-    def _compute():
-        mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
-        q = q_ref[0].astype(mxu)
-        kb = k_ref[0].astype(mxu)
-        vb = v_ref[0].astype(mxu)
-        g = g_ref[0].astype(mxu)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos + off, s, -1e30)
-        p = jnp.exp(s - lse)  # [Bq, Bk]
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(mxu), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            g, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(mxu), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile(rows, limit, masked, k0=0, k1=block_k):
+        """The keys ``k0:k1`` of the block against the queries in ``rows``
+        of the major block."""
+        q = q_ref[0, rows, :].astype(mxu)  # [Sq, D]
+        g = g_ref[0, rows, :].astype(mxu)
+        s = _dot(kb[k0:k1], q, _NT)  # [k1 - k0, Sq]: the scores' transpose
+        if not scale_k:
+            s = s * sm_scale
+        if masked:
+            s = _mask(s, rel[:s.shape[0], :s.shape[1]], limit, None)
+        p = jnp.exp(s - lse_ref[0, :, rows])
+        dv_scr[k0:k1] += _dot(p.astype(mxu), g, _NN)
+        ds = p * (_dot(vb[k0:k1], g, _NT) - delta_ref[0, :, rows])
+        dk_scr[k0:k1] += _dot(ds.astype(mxu), q, _NN)
 
-    @pl.when(iq == n_qb - 1)
+    def step(i, carry, masked):
+        tile(_sub_rows(i - first, sub, n_sub), i * sub + off - k_start,
+             masked)
+        return carry
+
+    if chunk:
+        for k0 in range(0, block_k, chunk):
+            tile(_rows(k_start - off + k0, block_k - k0, chunk), 0, True,
+                 k0, k0 + chunk)
+    elif causal:
+        _loop(lo, whole_lo, functools.partial(step, masked=True))
+    _loop(whole_lo, last, functools.partial(step, masked=False))
+
+    @_when(iq == steps[1] - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
-                           block_q: int, block_k: int, interpret: bool):
+                           block_q, block_k, interpret: bool):
+    """``lse`` is ``[B, H, T]``, as the forward returns it."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
     b, h, t_q, d = q.shape
     t_kv = k.shape[2]
     bh = b * h
-    block_q = _fit_block(t_q, block_q, interpret)
-    block_k = _fit_block(t_kv, block_k, interpret)
-    n_qb = t_q // block_q
-    n_kb = t_kv // block_k
-
+    off = t_kv - t_q  # bottom-aligned diagonal (reference tril k=off)
     q3 = q.reshape(bh, t_q, d)
     k3 = k.reshape(bh, t_kv, d)
     v3 = v.reshape(bh, t_kv, d)
     g3 = g.reshape(bh, t_q, d)
-    # lse/delta enter lane-broadcast so the kernel reads [Bq, 1] columns
-    # without an in-kernel transpose (Mosaic-friendly layout).
-    lse3 = jnp.broadcast_to(
-        lse.reshape(bh, t_q, 1), (bh, t_q, _LANES)).astype(jnp.float32)
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta3 = jnp.broadcast_to(
-        delta.reshape(bh, t_q, 1), (bh, t_q, _LANES))
+    lse2 = lse.reshape(bh, t_q).astype(jnp.float32)
+    delta2 = jnp.sum(g3.astype(jnp.float32) * o.reshape(bh, t_q, d).astype(
+        jnp.float32), axis=-1)
+    scale_on_operand = _scale_on_operand(sm_scale)
 
-    def qspec(f):
-        return pl.BlockSpec((1, block_q, d), f)
+    # dq: a block of query rows walks the keys, as the forward does.
+    want_q, want_k, chunk = _tile("dq", block_q, block_k)
+    held_q = _fit_block(t_q, want_q, interpret)
+    sub, major = _walk_blocks(t_kv, want_k, interpret)
+    n_held = t_q // held_q
+    lanes = _stat_lanes(held_q, n_held)
 
-    def kspec(f):
-        return pl.BlockSpec((1, block_k, d), f)
+    def held(ib, iq, ik):
+        return (ib, iq, 0)
 
-    def lspec(f):
-        return pl.BlockSpec((1, block_q, _LANES), f)
-
-    off = t_kv - t_q  # bottom-aligned diagonal (reference tril k=off)
-    if causal:
-        # Same bandwidth trick as the forward: clamp dead iterations onto
-        # an already-needed block so masked K/V (dq kernel) and masked Q
-        # rows (dk/dv kernel) are never fetched.
-        def kv_of_q(ib, iq, ik):
-            last = (iq * block_q + block_q - 1 + off) // block_k
-            last = jnp.clip(last, 0, n_kb - 1)
-            return (ib, jnp.minimum(ik, last), 0)
-
-        def q_of_kv(ib, ik, iq):
-            first = (ik * block_k - off) // block_q
-            first = jnp.clip(first, 0, n_qb - 1)
-            return (ib, jnp.maximum(iq, first), 0)
-    else:
-        def kv_of_q(ib, iq, ik):
-            return (ib, ik, 0)
-
-        def q_of_kv(ib, ik, iq):
-            return (ib, iq, 0)
-
-    compiler = {}
-    if not interpret:
-        compiler["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=(
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.ARBITRARY,
-            ))
-
+    walked = _walked_index(held_q, major, t_kv // major, off, causal)
+    held_spec = pl.BlockSpec((1, held_q, d), held)
+    walked_spec = pl.BlockSpec((1, major, d), walked)
+    stat_spec = pl.BlockSpec((1, 1, lanes), lambda ib, iq, ik: (ib, 0, iq))
     dq3 = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, n_kb=n_kb, off=off,
+            scale_q=scale_on_operand, sub=sub,
+            chunk=_diagonal_chunk(chunk, held_q, sub, off, t_kv == major,
+                                  causal),
+            off=off, steps=(n_held, t_kv // major),
             dot_mode=DEFAULT_DOT_MODE),
-        grid=(bh, n_qb, n_kb),
-        in_specs=[
-            qspec(lambda ib, iq, ik: (ib, iq, 0)),
-            kspec(kv_of_q),
-            kspec(kv_of_q),
-            qspec(lambda ib, iq, ik: (ib, iq, 0)),
-            lspec(lambda ib, iq, ik: (ib, iq, 0)),
-            lspec(lambda ib, iq, ik: (ib, iq, 0)),
-        ],
-        out_specs=qspec(lambda ib, iq, ik: (ib, iq, 0)),
+        grid=(bh, n_held, t_kv // major),
+        in_specs=[held_spec, walked_spec, walked_spec, held_spec,
+                  stat_spec, stat_spec],
+        out_specs=held_spec,
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, held_q), jnp.float32)],
         interpret=interpret,
-        **compiler,
-    )(q3, k3, v3, g3, lse3, delta3)
+        **_compiler(interpret),
+    )(q3, k3, v3, g3, _stat_rows(lse2, held_q), _stat_rows(delta2, held_q))
 
+    # dk/dv: a block of keys walks the query rows that see it.
+    want_q, want_k, chunk = _tile("dkv", block_q, block_k)
+    held_k = _fit_block(t_kv, want_k, interpret)
+    sub, major = _walk_blocks(t_q, want_q, interpret)
+    n_major = t_q // major
+
+    def walked(ib, ik, iq):
+        if causal:  # as _walked_index: rows above the block see none of it
+            first = (ik * held_k - off) // major
+            iq = jnp.maximum(iq, jnp.clip(first, 0, n_major - 1))
+        return (ib, iq, 0)
+
+    def held(ib, ik, iq):
+        return (ib, ik, 0)
+
+    held_spec = pl.BlockSpec((1, held_k, d), held)
+    walked_spec = pl.BlockSpec((1, major, d), walked)
+    stat_spec = pl.BlockSpec(
+        (1, 1, _stat_lanes(major, n_major)),
+        lambda ib, ik, iq: (ib, 0, walked(ib, ik, iq)[1]))
     dk3, dv3 = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, n_qb=n_qb, off=off,
+            scale_k=scale_on_operand, sub=sub,
+            chunk=_diagonal_chunk(chunk, held_k, sub, off, n_major == 1,
+                                  causal),
+            off=off, steps=(t_kv // held_k, n_major),
             dot_mode=DEFAULT_DOT_MODE),
-        grid=(bh, n_kb, n_qb),
-        in_specs=[
-            qspec(q_of_kv),
-            kspec(lambda ib, ik, iq: (ib, ik, 0)),
-            kspec(lambda ib, ik, iq: (ib, ik, 0)),
-            qspec(q_of_kv),
-            lspec(q_of_kv),
-            lspec(q_of_kv),
-        ],
-        out_specs=[
-            kspec(lambda ib, ik, iq: (ib, ik, 0)),
-            kspec(lambda ib, ik, iq: (ib, ik, 0)),
-        ],
+        grid=(bh, t_kv // held_k, n_major),
+        in_specs=[walked_spec, held_spec, held_spec, walked_spec,
+                  stat_spec, stat_spec],
+        out_specs=[held_spec, held_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t_kv, d), k.dtype),
             jax.ShapeDtypeStruct((bh, t_kv, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((held_k, d), jnp.float32),
+            pltpu.VMEM((held_k, d), jnp.float32),
         ],
         interpret=interpret,
-        **compiler,
-    )(q3, k3, v3, g3, lse3, delta3)
+        **_compiler(interpret),
+    )(q3, k3, v3, g3, _stat_rows(lse2, major), _stat_rows(delta2, major))
 
     return (dq3.reshape(b, h, t_q, d),
             dk3.reshape(b, h, t_kv, d),
@@ -584,7 +797,8 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
 
 # -- public op with custom vjp ------------------------------------------------
 
-_BHTD = "bh.."  # per_shard layout of q/k/v/o/g and of lse [B, H, T, 1]
+_BHTD = "bh.."  # per_shard layout of q/k/v/o/g
+_BHT = "bh."  # and of lse [B, H, T]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -600,28 +814,27 @@ def _flash_fwd(q, k, v, causal, sm_scale, use_pallas, window=None):
                 _flash_forward_pallas, causal=causal, sm_scale=sm_scale,
                 block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                 interpret=(use_pallas == "interpret"), window=window),
-            (q, k, v), (_BHTD,) * 3, (_BHTD, _BHTD))
+            (q, k, v), (_BHTD,) * 3, (_BHTD, _BHT))
     else:
         o, lse = _attn_fwd_reference(q, k, v, causal, sm_scale, window)
+        lse = lse[..., 0]
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, sm_scale, use_pallas, window, res, g):
     q, k, v, o, lse = res
-    if window is not None:
-        # The two backward kernels know no window yet: a windowed
-        # backward takes the masked reference whatever ran forward (the
-        # saved log-sum-exp is the same quantity on either path).
-        return _attn_bwd_reference(q, k, v, o, lse, g, causal, sm_scale,
-                                   window)
-    if use_pallas in ("tpu", "interpret"):
+    if window is None and use_pallas in ("tpu", "interpret"):
         return per_shard(
             functools.partial(
                 _flash_backward_pallas, causal=causal, sm_scale=sm_scale,
                 block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                 interpret=(use_pallas == "interpret")),
-            (q, k, v, o, lse, g), (_BHTD,) * 6, (_BHTD,) * 3)
-    return _attn_bwd_reference(q, k, v, o, lse, g, causal, sm_scale)
+            (q, k, v, o, lse, g), (_BHTD,) * 4 + (_BHT, _BHTD), (_BHTD,) * 3)
+    # The two backward kernels know no window yet: a windowed backward
+    # takes the masked reference whatever ran forward (the saved
+    # log-sum-exp is the same quantity on either path).
+    return _attn_bwd_reference(q, k, v, o, lse[..., None], g, causal,
+                               sm_scale, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

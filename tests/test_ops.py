@@ -134,6 +134,137 @@ class TestFlashAttention:
             flash_attention(q, q, q, force="interpret")
 
 
+# The kernels' joints: (head_dim, t_q, t_kv, window, dtype, rows of a tile
+# of q and of k or None for the table's, rows a grid step holds at most).
+_JOINTS = {
+    "one_sub_block": (64, 128, 128, None, jnp.float32, None, 2048),
+    "table_tiles": (64, 512, 512, None, jnp.float32, None, 2048),
+    "several_sub_blocks_d64": (64, 512, 512, None, jnp.float32, 128, 2048),
+    "several_sub_blocks_d128": (128, 512, 512, None, jnp.float32, 128, 2048),
+    "several_major_blocks": (64, 512, 512, None, jnp.float32, 128, 256),
+    "bf16_d64": (64, 512, 512, None, jnp.bfloat16, 128, 2048),
+    "bf16_d128": (128, 256, 256, None, jnp.bfloat16, 128, 2048),
+    "t384_no_multiple_of_the_table": (64, 384, 384, None, jnp.float32, None,
+                                      2048),
+    "t768_no_multiple_of_the_table": (64, 768, 768, None, jnp.float32, None,
+                                      2048),
+    "blocks_of_no_whole_tile": (64, 192, 192, None, jnp.float32, 96, 2048),
+    "fewer_queries_than_keys": (64, 128, 512, None, jnp.float32, 128, 2048),
+    "fewer_queries_several_blocks": (64, 256, 384, None, jnp.bfloat16, 128,
+                                     2048),
+    "window_ends_inside_a_sub_block": (64, 512, 512, 200, jnp.float32, 128,
+                                       2048),
+    "window_shorter_than_a_sub_block": (128, 256, 384, 72, jnp.float32, 128,
+                                        256),
+    "window_bf16": (64, 512, 512, 300, jnp.bfloat16, 128, 2048),
+}
+
+
+@pytest.fixture
+def joint(request, monkeypatch):
+    """q, k, v of a case of _JOINTS, with its tiles set in the module."""
+    import importlib
+
+    fa = importlib.import_module("raytpu.ops.flash_attention")
+    d, t_q, t_kv, window, dtype, tile, major = _JOINTS[request.param]
+    monkeypatch.setattr(fa, "DEFAULT_BLOCK_Q", tile)
+    monkeypatch.setattr(fa, "DEFAULT_BLOCK_K", tile)
+    monkeypatch.setattr(fa, "_MAJOR_ROWS", major)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(t_q + t_kv + d), 3)
+    q = jax.random.normal(kq, (1, 2, t_q, d), dtype)
+    k = jax.random.normal(kk, (1, 2, t_kv, d), dtype)
+    v = jax.random.normal(kv, (1, 2, t_kv, d), dtype)
+    return fa, (q, k, v), window
+
+
+def _pallas_calls(jaxpr):
+    """The pallas_call equations of a jaxpr, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+class TestFlashJoints:
+    """What the three kernels walk, mask and skip, in the interpreter
+    against the reference: forward and the three gradients."""
+
+    @pytest.mark.parametrize("joint", sorted(_JOINTS), indirect=True)
+    def test_forward_and_gradients(self, joint):
+        _, qkv, window = joint
+        bf16 = qkv[0].dtype == jnp.bfloat16
+
+        def loss(force, q, k, v):
+            o = flash_attention(q, k, v, window=window, force=force)
+            return (o.astype(jnp.float32) ** 2).sum(), o
+
+        (_, ref), g_ref = jax.value_and_grad(
+            functools.partial(loss, "reference"), argnums=(0, 1, 2),
+            has_aux=True)(*qkv)
+        (_, got), g_got = jax.value_and_grad(
+            functools.partial(loss, "interpret"), argnums=(0, 1, 2),
+            has_aux=True)(*qkv)
+        tol = 3e-2 if bf16 else 2e-5
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=tol, rtol=tol)
+        tol = 5e-2 if bf16 else 5e-4
+        for a, b_ in zip(g_got, g_ref):
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b_, np.float32),
+                                       atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("joint", sorted(_JOINTS), indirect=True)
+    def test_saved_lse_is_the_references(self, joint):
+        fa, qkv, window = joint
+        scale = qkv[0].shape[-1] ** -0.5
+        _, (*_, ref) = fa._flash_fwd(*qkv, True, scale, "reference", window)
+        _, (*_, got) = fa._flash_fwd(*qkv, True, scale, "interpret", window)
+        assert got.shape == ref.shape == qkv[0].shape[:3]
+        assert got.dtype == jnp.float32
+        tol = 2e-2 if qkv[0].dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("t,products", [(256, 2), (1024, 4)])
+    def test_a_call_of_one_block_is_decided_when_it_is_traced(self, t,
+                                                              products):
+        """A serving program holds the forward once a layer and traces and
+        lowers it as often (48 times in GPT-2 XL), so a prefill of one
+        block is one tile and nothing beside it: no loop, no branch, no
+        grid index. T = 1,024 under the table is its two chunks."""
+        q = jnp.zeros((1, 2, t, 64), jnp.bfloat16)
+        call, = _pallas_calls(jax.make_jaxpr(functools.partial(
+            flash_attention, force="interpret"))(q, q, q).jaxpr)
+        names = [e.primitive.name for e in call.params["jaxpr"].eqns]
+        assert not {"while", "scan", "cond", "program_id"} & set(names)
+        assert names.count("dot_general") == products
+
+    def test_results_the_benchmark_tells_the_calls_apart_by(self):
+        """perfbench's flash_attn_roofline.classify keys on the results of
+        the three custom calls: the forward's (bf16 [bh, T, d], f32 of
+        three dimensions), dq's one bf16 [bh, T, d], dk/dv's two."""
+        bh, t, d = 4, 256, 64
+        q = jnp.zeros((1, bh, t, d), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, force="interpret").astype(
+                jnp.float32).sum()
+
+        calls = sorted(([(v.aval.dtype.name, v.aval.shape) for v in e.outvars]
+                        for e in _pallas_calls(jax.make_jaxpr(jax.grad(
+                            loss, argnums=(0, 1, 2)))(q, q, q).jaxpr)),
+                       key=len)
+        out = ("bfloat16", (bh, t, d))
+        assert calls[0] == [out]  # dq
+        dkv, fwd = sorted(calls[1:], key=lambda c: c[1][0])
+        assert dkv == [out, out]
+        assert fwd[0] == out
+        assert fwd[1][0] == "float32" and len(fwd[1][1]) == 3
+        assert len(calls) == 3
+
+
 def _flash_shapes(b, h, t, d):
     return (jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16),) * 3
 
