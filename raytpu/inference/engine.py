@@ -141,7 +141,8 @@ class InferenceEngine:
     a caller that wants the original keeps it. ``stats()["param_bytes"]``
     has the copy's bytes by dtype, ``stats()["kv_pool_bytes"]`` the
     bytes of the 2 x layers KV pools (``kv_pool_bytes_by_kind``: of the
-    full and of the window layers').
+    full and of the window layers'), or of a latent-attention model's
+    one pool a layer (``serving.kv_row``: ``cache.v`` is then empty).
 
     A program that fails while it runs (not while it is traced or
     compiled) has consumed the pools it was given and returned none:
@@ -175,6 +176,11 @@ class InferenceEngine:
                 "its expert layer (tp, ep) in the engine is not there yet")
         self._expert_tokens = (np.zeros(served.expert_counts, np.int64)
                                if served.expert_counts else None)
+        if served.kv_row and (tp > 1 or mesh is not None):
+            raise ValueError(
+                "a model of one latent pool a layer is served on one "
+                "device: every head reads the whole row, so the pool has "
+                "no head axis to shard on")
 
         self._config = model_config
         # The working copy, made once and before anything else takes
@@ -227,7 +233,7 @@ class InferenceEngine:
             model_config.n_layer, num_pages, page_size, served.kv_heads,
             served.head_dim, dtype=model_config.dtype,
             layer_windows=served.layer_windows, window_pages=window_pages,
-            window_burst=self.prefill_chunk)
+            window_burst=self.prefill_chunk, latent_row=served.kv_row)
         # Tensor parallelism: shard the weights with the parallel-layer
         # rule table and the KV pools along their last dimension, whole
         # heads to a shard (a head's features are contiguous). Each jit
@@ -266,11 +272,14 @@ class InferenceEngine:
         self._kv_pool_bytes = sum(
             a.nbytes for a in self.cache.k + self.cache.v)
         self._two_kinds = len(self.cache.kinds) > 1
+        pools_a_layer = 2 if self.cache.v else 1  # K and V, or a latent
         self._kv_pool_bytes_by_kind = {
-            name: sum(k.nbytes + v.nbytes for k, v, of in zip(
-                self.cache.k, self.cache.v, self.cache.layer_kinds)
-                if of == kind)
+            name: sum(pools_a_layer * k.nbytes for k, of in zip(
+                self.cache.k, self.cache.layer_kinds) if of == kind)
             for kind, name in enumerate(("full", "window"))}
+        # A token's bytes in the pools of every layer, as held (a latent
+        # row is held on whole tiles).
+        self._kv_token_bytes = self.cache.token_bytes
         self._devices = sorted(f"{d.platform}:{d.id}"
                                for d in self.cache.k[0].devices())
         self.prefix_cache = (PrefixCache(self.cache)
@@ -382,9 +391,10 @@ class InferenceEngine:
 
     def _count_experts(self, experts) -> None:
         """Add what one program of a routed model returned beside its
-        logits (int32 ``[layers, experts]``: live tokens each expert
-        received) to the running total and to the open step's record.
-        A dense family's programs return nothing: ``experts`` is empty."""
+        logits (int32 ``[layers, experts]``: live tokens each expert held
+        here received) to the running total and to the open step's
+        record. A dense family's programs return nothing: ``experts`` is
+        empty."""
         if not experts:
             return
         counts = np.asarray(experts[0])
@@ -441,7 +451,8 @@ class InferenceEngine:
                 "decodes": 0, "bucket": 0, "table_width": 0,
                 "live_pages": 0, "live_pages_full": 0,
                 "live_pages_window": 0, "window_pages_released": 0,
-                "pages_owned_full": 0, "pages_owned_window": 0}) as st:
+                "pages_owned_full": 0, "pages_owned_window": 0,
+                "kv_bytes_per_token": self._kv_token_bytes}) as st:
             with recorder.phase("infer.schedule") as ph:
                 waiting = len(self.scheduler.waiting)
                 plan = self.scheduler.schedule()
@@ -787,7 +798,11 @@ class InferenceEngine:
         ``moe_assignments`` ((token, expert) pairs computed),
         ``moe_experts_touched`` (experts that received a token, summed
         over layers and programs) and ``moe_expert_max`` (the most
-        tokens one expert of one layer received in one program).
+        tokens one expert of one layer received in one program); where
+        the experts held here are a share of the router's, a pair whose
+        expert is not held is in none of them (no row was computed for
+        it). ``kv_bytes_per_token`` is what one token costs in the pools
+        of every layer, as held.
         ``"oldest_start"`` is the start of the oldest step still held,
         so a reader can tell a truncated log from a quiet engine. Call
         it from the thread that steps, or under the lock that

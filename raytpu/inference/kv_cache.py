@@ -21,6 +21,11 @@ block tables change. The engine's three programs are given the pools
 hand those back, so ``k`` and ``v`` are rebound after every call and an
 array read out of them before a step is deleted after it.
 
+A latent-attention model (``latent_row``) has one pool a layer, not a K
+and a V: a token's row holds the compressed latent its keys and values
+are both computed from, so ``v`` is empty and everything else here, which
+deals in pages and not in what a row holds, is unchanged.
+
 Page 0 is reserved as *scratch*: it is never handed to a sequence, and
 every padded slot in a bucketed prefill or dummy row in a padded decode
 batch writes there. Garbage lands only in page 0, so real pages are
@@ -96,12 +101,18 @@ class PagedKVCache:
             sequences); needed where a layer has a window.
         window_burst: the most tokens one program writes for one
             sequence (the engine's prefill chunk).
+        latent_row: a latent-attention model's row width as held. A
+            layer then has ONE pool ``[num_pages, page_size,
+            latent_row]``, from whose rows keys and values are both
+            read: ``k`` holds it and ``v`` is empty. Tables, slots and
+            the free list are what they are for any model of one kind.
     """
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_kv_heads: int, head_dim: int, dtype=None, *,
                  layer_windows: Sequence[Optional[int]] = (),
-                 window_pages: Optional[int] = None, window_burst: int = 1):
+                 window_pages: Optional[int] = None, window_burst: int = 1,
+                 latent_row: Optional[int] = None):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is scratch)")
         if page_size < 1:
@@ -136,11 +147,15 @@ class PagedKVCache:
                     f"sequence: one needs {self.window_seq_pages} pages, "
                     f"a chunk {self.window_burst_pages} more, and page 0 "
                     f"is scratch")
-        width = num_kv_heads * head_dim
+        if latent_row and self.window is not None:
+            raise ValueError("a latent pool has no window layers")
+        self.latent_row = latent_row
+        width = latent_row or num_kv_heads * head_dim
         shapes = [(self.num_window_pages if kind else num_pages, page_size,
                    width) for kind in self.layer_kinds]
         self.k: List = [jnp.zeros(shape, self.dtype) for shape in shapes]
-        self.v: List = [jnp.zeros(shape, self.dtype) for shape in shapes]
+        self.v: List = [] if latent_row else [
+            jnp.zeros(shape, self.dtype) for shape in shapes]
         # The window pools' free list and each sequence's sliding table,
         # [first logical page, its pages from there].
         self._wfree: List[int] = list(range(self.num_window_pages - 1, 0, -1))
@@ -179,6 +194,12 @@ class PagedKVCache:
     def pages_for(self, num_tokens: int) -> int:
         """Pages needed to hold ``num_tokens`` tokens."""
         return max(0, math.ceil(num_tokens / self.page_size))
+
+    @property
+    def token_bytes(self) -> int:
+        """Bytes one token costs in the pools of every layer, as held: a
+        row of K and one of V a layer, or a latent layer's one row."""
+        return sum(a.shape[2] * a.dtype.itemsize for a in self.k + self.v)
 
     @property
     def kinds(self) -> tuple:

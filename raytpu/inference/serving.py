@@ -32,6 +32,14 @@ the local prefix cache before admission, so the engine grafts the
 pages and starts at ``cached_len`` without re-prefilling). Routers can
 also probe :meth:`LLMDeployment.prefix_summary` for prefix-cache-aware
 replica selection. See :mod:`raytpu.inference.disagg`.
+
+Families (``model=``): "llama", "gpt2", "mixtral", "olmoe", "mellum"
+(window layers among full ones: two kinds of KV pool, no prefix cache,
+no role) and "joyai" (latent attention: one latent pool a layer read
+through the absorbed kernel, a leading dense layer, sigmoid-routed
+experts of which ``experts_held`` may be a share, a shared expert; the
+prefix cache works over its pages, a role is refused). Each reaches the
+engine through its config's ``serving`` and nothing else.
 """
 
 from __future__ import annotations
@@ -150,10 +158,10 @@ class LLMDeployment:
     """Serve a decoder LM with continuous batching + streaming tokens.
 
     Args:
-        model: "llama", "gpt2", "mixtral", "olmoe" or "mellum".
+        model: "llama", "gpt2", "mixtral", "olmoe", "mellum" or "joyai".
         model_config: the family's config (``LlamaConfig``,
             ``GPT2Config``, ``MixtralConfig``, ``OlmoeConfig``,
-            ``MellumConfig``) or a
+            ``MellumConfig``, ``JoyAIConfig``) or a
             kwargs dict for one. Defaults to the family's ``tiny()``
             config in fp32/reference-attention mode (CPU-runnable).
         engine_options: kwargs forwarded to :class:`InferenceEngine`
@@ -170,6 +178,9 @@ class LLMDeployment:
             ``kv_export_*`` trio (direct-instantiation tests). A model
             with window layers takes no role: what is handed off are
             prefix-cache pages, and it is served without that cache.
+            Nor does a latent-attention model ("joyai": one pool a
+            layer): the hand-off's wire segments are pages of K and of V,
+            ``kv_heads * head_dim`` wide.
     """
 
     def __init__(self, model: str = "llama", model_config=None,
@@ -187,17 +198,18 @@ class LLMDeployment:
             from raytpu.models.gpt2 import GPT2, GPT2Config, init_params
 
             cfg_cls, model_cls, init = GPT2Config, GPT2, init_params
-        elif model in ("mixtral", "olmoe", "mellum"):
+        elif model in ("mixtral", "olmoe", "mellum", "joyai"):
             from raytpu.models import mixtral
 
             cfg_cls = {"mixtral": mixtral.MixtralConfig,
                        "olmoe": mixtral.OlmoeConfig,
-                       "mellum": mixtral.MellumConfig}[model]
+                       "mellum": mixtral.MellumConfig,
+                       "joyai": mixtral.JoyAIConfig}[model]
             model_cls, init = mixtral.Mixtral, mixtral.init_params
         else:
             raise ValueError(f"unknown model family: {model!r}; known: "
                              f"'llama', 'gpt2', 'mixtral', 'olmoe', "
-                             f"'mellum'")
+                             f"'mellum', 'joyai'")
         if model_config is None:
             model_config = dataclasses.replace(
                 cfg_cls.tiny(), dtype=jnp.float32, attn_impl="reference",
@@ -218,6 +230,12 @@ class LLMDeployment:
                 f"role={role!r}: a model with window layers is not "
                 f"served disaggregated: the hand-off ships prefix-cache "
                 f"pages, and its window pools share none")
+        if role is not None and self._engine.cache.latent_row:
+            raise ValueError(
+                f"role={role!r}: a latent-attention model is not served "
+                f"disaggregated: the hand-off's wire segments are pages of "
+                f"K and of V, kv_heads * head_dim wide, and its layers "
+                f"hold one latent pool")
         self._handoff_source = disagg.KVHandoffSource(self._engine)
         # One condition serializes engine mutation (add/abort/step):
         # producers signal "new work" to the loop through it.

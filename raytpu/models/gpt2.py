@@ -395,6 +395,12 @@ class Serving:
     sequence's newest positions only. Over two kinds the engine gives
     ``dests`` and ``block_tables`` as a pair of arrays, full first.
 
+    A layer of one pool (``kv_row``): a latent-attention layer holds one
+    row a token, ``kv_row`` features wide as held, from which keys and
+    values are both read, and no V pool. The programs then take and give
+    back ``k_caches`` one pool a layer and ``v_caches`` empty.
+    ``kv_heads`` and ``head_dim`` are then the query's, and size no pool.
+
     ``inputs`` -> logits: ``prefill`` (tokens [1, T], dests [T]) ->
     [T, V]; ``prefill_chunk`` (tokens [1, T], positions [T], dests [T],
     block_tables [1, P]) -> [1, T, V]; ``decode`` (tokens [B], positions
@@ -408,6 +414,7 @@ class Serving:
     head_dim: int
     expert_counts: Optional[Tuple[int, int]] = None  # (layers, experts)
     layer_windows: Tuple[Optional[int], ...] = ()
+    kv_row: Optional[int] = None
 
 
 def write_prompt_rows(k_caches, v_caches, dests, ks, vs):

@@ -127,17 +127,32 @@ class LlamaConfig:
         own = self.window_rope if kind == WINDOW else self.full_rope
         return own if own is not None else self.rope_theta
 
+    def attn_scope(self, kind: str) -> Optional[str]:
+        """The ``jax.named_scope`` a layer of ``kind`` attends under in
+        the serving walk; ``None`` where every layer is alike."""
+        if not self.layer_types:
+            return None
+        return "attn.window" if kind == WINDOW else "attn.full"
+
+    def attention(self, kind: str = FULL, **kw):
+        """The attention module of a layer of ``kind``: what a block
+        builds (``name=``) and what the serving walk applies."""
+        return LlamaAttention(self, kind, **kw)
+
+    def ffn_width(self, i: int) -> Optional[int]:
+        """Layer ``i``'s feed-forward by its index: the width of its
+        SwiGLU, or ``None`` where it is a routed-expert layer."""
+        return self.n_inter
+
     @property
     def serving(self) -> Serving:
         """How ``InferenceEngine`` serves this family; a routed config
         (``MixtralConfig`` and what extends it) through the same walk."""
-        from raytpu.models import mixtral  # it imports this module
-
-        routed = isinstance(self, mixtral.MixtralConfig)
+        routed = sum(self.ffn_width(i) is None for i in range(self.n_layer))
         return Serving(
             llama_prefill, llama_prefill_chunk, llama_decode, serving_params,
             kv_heads=self.n_kv_head, head_dim=self.head_dim,
-            expert_counts=(self.n_layer, self.n_expert) if routed else None,
+            expert_counts=(routed, self.n_expert_held) if routed else None,
             layer_windows=tuple(
                 self.window if kind == WINDOW else None
                 for kind in self.layer_types or ()))
@@ -370,15 +385,19 @@ class LlamaAttention(nn.Module):
 
 
 class LlamaMLP(nn.Module):
+    """SwiGLU of ``width`` (``config.n_inter`` if not given)."""
+
     config: LlamaConfig
+    width: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
         c = self.config
+        width = self.width or c.n_inter
         dense = functools.partial(nn.Dense, use_bias=False, dtype=c.dtype,
                                   param_dtype=c.param_dtype)
-        gate = dense(c.n_inter, name="gate_proj")(x)
-        up = dense(c.n_inter, name="up_proj")(x)
+        gate = dense(width, name="gate_proj")(x)
+        up = dense(width, name="up_proj")(x)
         return dense(c.n_embd, name="down_proj")(nn.silu(gate) * up)
 
 
@@ -515,17 +534,18 @@ def _lm_logits(c: LlamaConfig, params, x):
     return jnp.dot(x, kernel).astype(jnp.float32)
 
 
-def _feed_forward(c: LlamaConfig, lp, h, live):
-    """The second half of a block on the normed ``h``: SwiGLU, or the
-    routed experts where the config is a ``MixtralConfig`` (OLMoE's is
-    one). ``live`` marks the rows that are tokens and not padding; only
-    the routed layer needs it, to route padding nowhere. Returns the
-    output and the tokens each expert received (``None`` when dense)."""
-    from raytpu.models import mixtral  # it imports this module
+def _feed_forward(c: LlamaConfig, lp, h, live, i: int):
+    """The second half of block ``i`` on the normed ``h``, as the config
+    says of that layer (``ffn_width``): SwiGLU, or the routed experts.
+    ``live`` marks the rows that are tokens and not padding; only the
+    routed layer needs it, to route padding nowhere. Returns the output
+    and the tokens each expert received (``None`` when dense)."""
+    width = c.ffn_width(i)
+    if width is None:
+        from raytpu.models import mixtral  # it imports this module
 
-    if isinstance(c, mixtral.MixtralConfig):
         return mixtral.MoEFFN(c).apply({"params": lp["moe"]}, h, live)
-    return LlamaMLP(c).apply({"params": lp["mlp"]}, h), None
+    return LlamaMLP(c, width).apply({"params": lp["mlp"]}, h), None
 
 
 def of_kind(x, kind: str):
@@ -546,38 +566,46 @@ def live_rows(dests, k_cache):
 def _serve(c: LlamaConfig, params, x, live, method: str, cache_args):
     """The serving walk, written once: the blocks over the embedded
     ``x``, the final norm and the head. Layer ``i`` attends through
-    ``LlamaAttention.<method>(h, *cache_args(i))``, which returns its
-    output and the layer's K and V (rows, or the pools it wrote);
+    ``c.attention(kind).<method>(h, *cache_args(i))``, which returns its
+    output and the layer's K and V (rows, or the pools it wrote), or
+    the one pool of a latent layer (``V list`` is then empty);
     ``live`` (``x``'s leading shape) marks the rows that are tokens, for
     :func:`_feed_forward`. Returns ``(fp32 logits, K list, V list)`` and
-    for a routed config a fourth value, the int32 ``[layers, experts]``
-    count of tokens each expert received. Where the layers are of two
-    kinds each attends under ``jax.named_scope("attn.full")`` or
-    ``("attn.window")``."""
-    attn = {kind: LlamaAttention(c, kind) for kind in KINDS}
+    for a config with routed layers a fourth value, the int32 ``[routed
+    layers, experts held]`` count of tokens each expert received. Where
+    the layers are of two kinds each attends under
+    ``jax.named_scope("attn.full")`` or ``("attn.window")``; a latent
+    layer under ``("attn.mla")``."""
+    attn = {kind: c.attention(kind) for kind in KINDS}
     norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
     ks, vs, routed = [], [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
         h = norm.apply({"params": lp["input_norm"]}, x)
         kind = c.layer_kind(i)
-        with (jax.named_scope("attn.window" if kind == WINDOW
-                              else "attn.full")
-              if c.layer_types else contextlib.nullcontext()):
-            y, k, v = attn[kind].apply({"params": lp["attn"]}, h,
-                                       *cache_args(i), method=method)
+        with (jax.named_scope(c.attn_scope(kind))
+              if c.attn_scope(kind) else contextlib.nullcontext()):
+            y, k, *v = attn[kind].apply({"params": lp["attn"]}, h,
+                                        *cache_args(i), method=method)
         ks.append(k)
-        vs.append(v)
+        vs.extend(v)
         x = x + y
         h = norm.apply({"params": lp["post_attn_norm"]}, x)
-        y, counts = _feed_forward(c, lp, h, live)
-        routed.append(counts)
+        y, counts = _feed_forward(c, lp, h, live, i)
+        if counts is not None:
+            routed.append(counts)
         x = x + y
     x = norm.apply({"params": params["final_norm"]}, x)
     logits = _lm_logits(c, params, x)
-    if routed[0] is None:
+    if not routed:
         return logits, ks, vs
     return logits, ks, vs, jnp.stack(routed)
+
+
+def _pools(k_caches, v_caches, i: int):
+    """Layer ``i``'s pools as its attention module takes them: K and V,
+    or the one pool of a latent layer (``v_caches`` is then empty)."""
+    return (k_caches[i], v_caches[i]) if v_caches else (k_caches[i],)
 
 
 def llama_prefill(config: LlamaConfig, params, tokens, dests, k_caches,
@@ -606,7 +634,7 @@ def llama_prefill_chunk(config: LlamaConfig, params, tokens, positions,
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     live = live_rows(dests, k_caches[0])[None]
     return _serve(c, params, x, live, "prefill_chunk", lambda i: (
-        k_caches[i], v_caches[i], of_kind(dests, c.layer_kind(i)),
+        *_pools(k_caches, v_caches, i), of_kind(dests, c.layer_kind(i)),
         of_kind(block_tables, c.layer_kind(i)), positions))
 
 
@@ -619,5 +647,5 @@ def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     live = live_rows(dests, k_caches[0])
     return _serve(c, params, x, live, "decode_step", lambda i: (
-        k_caches[i], v_caches[i], of_kind(dests, c.layer_kind(i)),
+        *_pools(k_caches, v_caches, i), of_kind(dests, c.layer_kind(i)),
         of_kind(block_tables, c.layer_kind(i)), positions, context_lens))

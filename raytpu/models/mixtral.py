@@ -24,17 +24,22 @@ whole q and k projections. :class:`MellumConfig` is Mellum2-12B-A2.5B's:
 window layers among full ones, a rotary embedding a kind, a head size
 of its own; :class:`Mixtral` trains it too (``Mellum`` is its name
 there), its layers unrolled because they are not all alike.
+:class:`JoyAIConfig` is JoyAI-LLM-Flash's: latent attention
+(:mod:`raytpu.models.mla`) behind one pool a layer, sigmoid routing with
+a correction bias, a shared expert, a leading dense layer, and
+optionally a share of the experts held (``experts_held``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raytpu.models.llama import (FULL, WINDOW, LlamaAttention, LlamaConfig,
+from raytpu.models.llama import (FULL, WINDOW, LlamaConfig, LlamaMLP,
                                  RMSNorm, Rope)
 
 
@@ -45,6 +50,51 @@ class MixtralConfig(LlamaConfig):
     # Whether the chosen experts' weights are rescaled to sum to one.
     norm_topk_prob: bool = True
     router_aux_coef: float = 0.01
+    # How the router scores an expert: "softmax" over all of them, or
+    # "sigmoid" of each alone (DeepSeek-V3's ``noaux_tc``).
+    scoring: str = "softmax"
+    # Not None: the experts are chosen by score + a learned float32
+    # vector, ``bias``, that moves the choice and not the weight; the
+    # value is the standard deviation it is seeded with (a trained one is
+    # not zero).
+    choice_bias: Optional[float] = None
+    # What the chosen experts' weights are multiplied by, normalised or not.
+    routed_scale: float = 1.0
+    # Shared experts: one SwiGLU of ``n_shared * n_inter`` beside the
+    # routed ones, applied to every token.
+    n_shared: int = 0
+    # The leading layers whose feed-forward is a dense SwiGLU of
+    # ``dense_inter`` and not a routed layer.
+    first_dense: int = 0
+    dense_inter: Optional[int] = None
+    # ``(first, count)``: the experts held here, where a layer's experts
+    # are shared out over chips. The router keeps its ``n_expert`` outputs
+    # and a token its ``n_expert_per_tok`` choices, weights normalised over
+    # all of them; a pair whose expert is not held is a dead row, and the
+    # layer returns the held experts' part (plus the shared expert).
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring is 'softmax' or 'sigmoid': "
+                             f"{self.scoring!r}")
+        if self.experts_held is not None:
+            first, count = held = tuple(self.experts_held)
+            object.__setattr__(self, "experts_held", held)
+            if first < 0 or count < 1 or first + count > self.n_expert:
+                raise ValueError(
+                    f"experts_held={held} is not a (first, count) share of "
+                    f"the router's {self.n_expert} experts")
+        if self.first_dense and not self.dense_inter:
+            raise ValueError("leading dense layers need `dense_inter`")
+
+    @property
+    def n_expert_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_expert
+
+    def ffn_width(self, i: int) -> Optional[int]:
+        return self.dense_inter if i < self.first_dense else None
 
     @classmethod
     def tiny(cls) -> "MixtralConfig":
@@ -129,6 +179,74 @@ class MellumConfig(MixtralConfig):
                                   original_max_position=32))
 
 
+@dataclasses.dataclass(frozen=True)
+class JoyAIConfig(MixtralConfig):
+    """JoyAI-LLM-Flash (``jdopensource/JoyAI-LLM-Flash``, 48B-A2.7B) as
+    published: 40 layers of latent attention (:mod:`raytpu.models.mla`:
+    32 heads, queries through a rank of 1,536, keys and values through a
+    latent of 512 and one roped key of 64, interleaved rope at theta
+    32,000,000 with no scaling); layer 0 a dense SwiGLU of 7,168, the
+    others 256 routed experts of 768 (``n_inter``) of which a token takes
+    8 by sigmoid score + a correction bias, weights normalised and
+    multiplied by 2.5, beside one shared expert. The multi-token
+    prediction module is not built. Layers are held one tree each.
+    ``head_dim`` (64, as the config has it) sizes nothing here."""
+
+    vocab_size: int = 129280
+    block_size: int = 131072
+    n_layer: int = 40
+    n_head: int = 32
+    n_kv_head: int = 32
+    n_embd: int = 2048
+    head_dim: int = 64
+    n_inter: int = 768
+    n_expert: int = 256
+    n_expert_per_tok: int = 8
+    norm_topk_prob: bool = True
+    norm_eps: float = 1e-6
+    rope_theta: float = 32000000.0
+    scoring: str = "sigmoid"
+    choice_bias: float = 0.0
+    routed_scale: float = 2.5
+    n_shared: int = 1
+    first_dense: int = 1
+    dense_inter: int = 7168
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    rope_interleave: bool = True
+    scan_layers: bool = False
+
+    def attention(self, kind: str = FULL, **kw):
+        from raytpu.models.mla import LatentAttention  # it imports llama
+
+        return LatentAttention(self, **kw)
+
+    def attn_scope(self, kind: str) -> str:
+        return "attn.mla"
+
+    @property
+    def serving(self):
+        from raytpu.ops.mla_attention import latent_row_width
+
+        return dataclasses.replace(
+            super().serving,
+            kv_row=latent_row_width(self.kv_lora_rank, self.qk_rope_dim))
+
+    @classmethod
+    def tiny(cls) -> "JoyAIConfig":
+        """A dense layer and two routed ones at toy widths; the latent is
+        128 wide because the kernel slices values out of a row by whole
+        lane tiles."""
+        return cls(vocab_size=512, block_size=256, n_layer=3, n_head=4,
+                   n_kv_head=4, n_embd=64, head_dim=16, n_inter=32,
+                   n_expert=16, n_expert_per_tok=4, dense_inter=96,
+                   q_lora_rank=48, kv_lora_rank=128, qk_nope_dim=16,
+                   qk_rope_dim=8, v_head_dim=16)
+
+
 class MoEFFN(nn.Module):
     """Top-k routed SwiGLU experts, dropless.
 
@@ -138,6 +256,17 @@ class MoEFFN(nn.Module):
     and the int32 ``[n_expert]`` number of live tokens each expert
     received. The router runs in float32 at full precision over all the
     experts; the expert matrices multiply in ``config.dtype``.
+
+    Scores are a softmax over the experts, or each expert's own sigmoid
+    (``config.scoring``), then chosen by score + ``bias`` where the
+    config has a ``choice_bias`` (the weights are the scores without it),
+    normalised over the chosen (``norm_topk_prob``) and multiplied by
+    ``routed_scale``. With ``experts_held = (first, count)`` the three
+    matrices hold ``count`` experts, the router still ``n_expert``: a
+    pair whose expert lies outside the share is a dead row like
+    padding's, ``tokens`` counts the held experts alone, and the output
+    is their part of the layer's. A shared expert (``n_shared``) is
+    added under ``jax.named_scope("moe.shared")``.
     """
 
     config: MixtralConfig
@@ -146,22 +275,34 @@ class MoEFFN(nn.Module):
     def __call__(self, x, live=None):
         c = self.config
         d = x.shape[-1]
-        k, e = c.n_expert_per_tok, c.n_expert
+        k, e = c.n_expert_per_tok, c.n_expert_held
         xf = x.reshape(-1, d)
         n = xf.shape[0]
         with jax.named_scope("moe.router"):
-            router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+            router = nn.Dense(c.n_expert, use_bias=False, dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST,
                               name="router")(xf.astype(jnp.float32))
-            probs = jax.nn.softmax(router, axis=-1)           # [N, E]
-            topw, topi = jax.lax.top_k(probs, k)              # [N, k]
+            if c.scoring == "softmax":
+                probs = jax.nn.softmax(router, axis=-1)       # [N, E]
+            else:
+                probs = jax.nn.sigmoid(router)
+            if c.choice_bias is not None:
+                bias = self.param(
+                    "bias", nn.initializers.normal(c.choice_bias),
+                    (c.n_expert,), jnp.float32)
+                _, topi = jax.lax.top_k(probs + bias, k)
+                topw = jnp.take_along_axis(probs, topi, axis=-1)
+            else:
+                topw, topi = jax.lax.top_k(probs, k)          # [N, k]
             if c.norm_topk_prob:
                 topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
+            if c.routed_scale != 1.0:
+                topw = topw * c.routed_scale
 
         # Switch-style load balance: E * sum_e(frac_routed_e * mean_prob_e)
-        top1 = jax.nn.one_hot(topi[:, 0], e, dtype=jnp.float32)
-        aux = e * jnp.sum(jnp.mean(top1, axis=0)
-                          * jnp.mean(probs, axis=0))
+        top1 = jax.nn.one_hot(topi[:, 0], c.n_expert, dtype=jnp.float32)
+        aux = c.n_expert * jnp.sum(jnp.mean(top1, axis=0)
+                                   * jnp.mean(probs, axis=0))
         self.sow("intermediates", "moe_aux", aux)
 
         init = nn.initializers.normal
@@ -176,6 +317,10 @@ class MoEFFN(nn.Module):
             # dead row's expert is ``e``: it sorts past the last group,
             # belongs to none and is multiplied by nothing.
             flat = topi.reshape(n * k)
+            if c.experts_held is not None:
+                # An expert of another chip's share: no row here.
+                flat = flat - c.experts_held[0]
+                flat = jnp.where((flat >= 0) & (flat < e), flat, e)
             if live is not None:
                 flat = jnp.where(jnp.repeat(live.reshape(n), k), flat, e)
             order = jnp.argsort(flat)
@@ -191,21 +336,30 @@ class MoEFFN(nn.Module):
                             out.astype(jnp.float32)
                             * topw.reshape(n * k)[order][:, None], 0.0)
             y = jnp.sum(out[jnp.argsort(order)].reshape(n, k, d), axis=1)
-        return y.reshape(x.shape).astype(c.dtype), tokens
+        y = y.reshape(x.shape).astype(c.dtype)
+        if c.n_shared:
+            with jax.named_scope("moe.shared"):
+                y = y + LlamaMLP(c, c.n_shared * c.n_inter, name="shared")(x)
+        return y, tokens
 
 
 class MixtralBlock(nn.Module):
+    """``dense_width``: the block's feed-forward is a SwiGLU that wide
+    and not the routed layer (``config.ffn_width`` of its index)."""
+
     config: MixtralConfig
     kind: str = FULL
+    dense_width: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
         c = self.config
-        x = x + LlamaAttention(c, self.kind, name="attn")(
+        x = x + c.attention(self.kind, name="attn")(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="input_norm")(x))
-        y, _ = MoEFFN(c, name="moe")(
-            RMSNorm(dtype=c.dtype, eps=c.norm_eps,
-                    name="post_attn_norm")(x))
+        h = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="post_attn_norm")(x)
+        if self.dense_width is not None:
+            return x + LlamaMLP(c, self.dense_width, name="mlp")(h)
+        y, _ = MoEFFN(c, name="moe")(h)
         return x + y
 
 
@@ -224,7 +378,7 @@ class Mixtral(nn.Module):
             if c.remat == "dots":
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             block = nn.remat(MixtralBlock, prevent_cse=False, policy=policy)
-        if c.scan_layers and not c.layer_types:
+        if c.scan_layers and not c.layer_types and not c.first_dense:
             x, _ = nn.scan(
                 lambda mdl, carry, _: (mdl(carry), None),
                 variable_axes={"params": 0, "intermediates": 0},
@@ -235,7 +389,8 @@ class Mixtral(nn.Module):
         else:
             # Layers of different kinds are not one scanned body.
             for i in range(c.n_layer):
-                x = block(c, c.layer_kind(i), name=f"layers_{i}")(x)
+                x = block(c, c.layer_kind(i), c.ffn_width(i),
+                          name=f"layers_{i}")(x)
         x = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="final_norm")(x)
         if return_hidden:
             return x
@@ -279,5 +434,7 @@ def init_params(model: Mixtral, config: MixtralConfig, seed: int = 0,
 
 
 # Mellum2's training forward is Mixtral's over a config whose layers are
-# of two kinds (``MixtralBlock.kind``).
+# of two kinds (``MixtralBlock.kind``); JoyAI-LLM-Flash's over one whose
+# attention is latent and whose first layer is dense.
 Mellum = Mixtral
+JoyAI = Mixtral
