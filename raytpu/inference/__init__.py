@@ -33,8 +33,8 @@ Layout:
   (grafting prefix-cache hits), merges fresh prefills with in-flight
   decodes, preempts-to-recompute the youngest sequence under pressure.
 - :mod:`raytpu.inference.sampling` — greedy / temperature / top-k
-  sampling with a *per-request* RNG, so sampled outputs are invariant
-  to batch composition.
+  sampling on the device, a row keyed by its request's seed and its own
+  position, so sampled outputs are invariant to batch composition.
 - :mod:`raytpu.inference.engine` — :class:`InferenceEngine`: bucketed
   static-shape prefill (full or chunked, interleaved with decodes),
   a single jit-compiled decode step, stop conditions, ``raytpu_infer_*``

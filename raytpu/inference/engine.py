@@ -25,16 +25,19 @@ The TPU compile-once discipline, concretely:
   (``cache.slide``); a model of one kind gets the single arrays and the
   programs it always had.
 
-The three jitted callables are constructed exactly once, by the one
-``_build_program`` — the per-iteration loop
-(:meth:`InferenceEngine.step`) only *calls* them. A lint test pins
-this: ``jax.jit`` may appear in ``_build_*`` constructors only. The
-compile counters increment inside the traced function body, which
-Python executes only during tracing — i.e. exactly once per XLA
-compile — giving tests and the bench an honest recompile count.
+The jitted callables are constructed exactly once, the three programs
+by the one ``_build_program`` and the sampler by ``_build_sampler`` —
+the per-iteration loop (:meth:`InferenceEngine.step`) only *calls*
+them. A lint test pins this: ``jax.jit`` may appear in ``_build_*``
+constructors only. The compile counters increment inside the traced
+function body, which Python executes only during tracing — i.e. exactly
+once per XLA compile — giving tests and the bench an honest recompile
+count.
 
-Sampling runs on the host with per-request RNGs (see
-:mod:`raytpu.inference.sampling`), so batched output == solo output.
+Sampling runs on the device (:func:`raytpu.inference.sampling.sample`):
+a program's logits stay there and a step brings back ``int32[bucket]``
+token ids. A row's draw is keyed by its request's seed and its own
+position, so batched output == solo output.
 """
 
 from __future__ import annotations
@@ -42,6 +45,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import operator
 import time
 from typing import (Callable, Dict, List, Optional,
                     Sequence as SequenceT)
@@ -50,7 +54,7 @@ import numpy as np
 
 from raytpu.inference.kv_cache import PagedKVCache
 from raytpu.inference.prefix_cache import PrefixCache
-from raytpu.inference.sampling import SamplingParams, sample_token
+from raytpu.inference.sampling import SamplingParams, sample
 from raytpu.inference.scheduler import Scheduler, Sequence
 from raytpu.util import compile_cache, task_events, tracing
 from raytpu.util.metrics import Counter, Gauge, Histogram
@@ -314,6 +318,11 @@ class InferenceEngine:
         self._prefill_compiles: Dict[int, int] = {}
         self._chunk_compiles: Dict[str, int] = {}
         self._decode_compiles: Dict[str, int] = {}
+        self._sample_compiles: Dict[str, int] = {}
+        # The decode batch the sampler's per-request rows were last put
+        # for: (its sequences, the rows on the device, how many of them
+        # are stochastic). Remade when the batch's membership changes.
+        self._sampled_batch = ([], None, 0)
         # One record per step(): its phases' stamps and what it ran.
         self.recorder = tracing.StepRecorder()
         # Called, if set, once a decode step's program is on its way to
@@ -345,8 +354,9 @@ class InferenceEngine:
             jax, "_decode", served.decode, self._decode_compiles,
             lambda tokens, positions, dests, block_tables, context_lens:
             f"{tokens.shape[0]}x{_width(block_tables)}")
+        self._sample_fn = self._build_sampler(jax, self._sample_compiles)
 
-    # ---- compiled steps (the ONLY jax.jit call site) ----------------
+    # ---- compiled steps (the ONLY jax.jit call sites) ---------------
 
     def _build_program(self, jax, name, fwd, compiles, bucket_key):
         """One of the three jitted programs, ``(params, ks, vs, *inputs)
@@ -372,6 +382,36 @@ class InferenceEngine:
 
         program.__name__ = name
         return jax.jit(program, donate_argnums=_POOLS)
+
+    def _build_sampler(self, jax, compiles):
+        """The jitted sampler, ``(logits, temperature, top_k, seed,
+        position) -> ids``: :func:`sampling.sample` over a program's
+        logits where they lie, a decode's ``[bucket, vocab]`` or the one
+        row of a prefill's. ``compiles`` counts its traces under the
+        logits' shape."""
+
+        def _sample(logits, temperature, top_k, seed, position):
+            # Trace-time only, as in ``_build_program``.
+            shape = "x".join(str(n) for n in logits.shape)
+            compiles[shape] = compiles.get(shape, 0) + 1
+            return sample(logits.reshape(-1, logits.shape[-1]),
+                          temperature, top_k, seed, position)
+
+        return jax.jit(_sample)
+
+    def _sampling_rows(self, seqs: SequenceT[Sequence], bucket: int):
+        """What the sampler takes of each request, a row a sequence on
+        the device (``temperature``, ``top_k``, ``seed``; padding rows
+        greedy), and how many of the rows are stochastic."""
+        temperature = np.zeros(bucket, dtype=np.float32)
+        top_k = np.zeros(bucket, dtype=np.int32)
+        seed = np.zeros(bucket, dtype=np.uint32)
+        for i, seq in enumerate(seqs):
+            temperature[i] = seq.sampling.temperature
+            top_k[i] = min(seq.sampling.top_k, np.iinfo(np.int32).max)
+            seed[i] = seq.sampling.seed & 0xFFFFFFFF
+        return ((self._put(temperature), self._put(top_k), self._put(seed)),
+                int(np.count_nonzero(temperature > 0.0)))
 
     def _put(self, x):
         """Host array → device input. Under a tp mesh, inputs are
@@ -440,9 +480,10 @@ class InferenceEngine:
 
     def step(self) -> List[StepOutput]:
         """One scheduler iteration: run every admitted prefill, then one
-        padded decode step over all running sequences; sample on host;
-        retire finished sequences (freeing their pages). Leaves one
-        record in ``self.recorder`` (see :meth:`step_log`)."""
+        padded decode step over all running sequences, its tokens
+        sampled on the device and fetched as ids; retire finished
+        sequences (freeing their pages). Leaves one record in
+        ``self.recorder`` (see :meth:`step_log`)."""
         out: List[StepOutput] = []
         recorder = self.recorder
         compiled = self._programs_traced()
@@ -452,6 +493,7 @@ class InferenceEngine:
                 "live_pages": 0, "live_pages_full": 0,
                 "live_pages_window": 0, "window_pages_released": 0,
                 "pages_owned_full": 0, "pages_owned_window": 0,
+                "sampled_stochastic": 0,
                 "kv_bytes_per_token": self._kv_token_bytes}) as st:
             with recorder.phase("infer.schedule") as ph:
                 waiting = len(self.scheduler.waiting)
@@ -500,10 +542,12 @@ class InferenceEngine:
         return out
 
     def _programs_traced(self) -> int:
-        """Programs traced (so compiled) so far, all three kinds."""
+        """Programs traced (so compiled) so far: the three kinds and
+        the sampler."""
         return (sum(self._prefill_compiles.values())
                 + sum(self._chunk_compiles.values())
-                + sum(self._decode_compiles.values()))
+                + sum(self._decode_compiles.values())
+                + sum(self._sample_compiles.values()))
 
     def _run_prefill(self, seq: Sequence, out: List[StepOutput]) -> int:
         """Advance one sequence's prefill by (at most) one chunk.
@@ -526,7 +570,7 @@ class InferenceEngine:
                 deployment=seq.deployment, tenant=seq.tenant,
                 data={"prompt_tokens": len(seq.prompt), "cached": start})
         # The phase is the whole stall this prefill puts on the batch:
-        # inputs, the call, the logits on the host, the first token out.
+        # inputs, the call, the last row sampled, the first token out.
         whole = start == 0 and plen <= self.prefill_chunk
         with self.recorder.phase(
                 "infer.prefill" if whole else "infer.prefill_chunk",
@@ -603,12 +647,15 @@ class InferenceEngine:
         self._register_prefix(seq)
         if seq.cached_len >= plen and not seq.generated:
             # The last chunk of a fresh prompt: its last REAL row's logit
-            # samples the first new token. A preemption-resume prefill
-            # must NOT resample — the tail token was already emitted;
-            # the next decode rewrites its KV.
+            # samples the first new token, at that row's position. A
+            # preemption-resume prefill must NOT resample — the tail
+            # token was already emitted; the next decode rewrites its KV.
             last = logits[take - 1] if whole else logits[0, take - 1]
-            self._emit(seq, sample_token(np.asarray(last), seq.sampling,
-                                         seq.rng), out)
+            rows, stochastic = self._sampling_rows([seq], 1)
+            ids = self._sample_fn(
+                last, *rows, self._put(np.array([end - 1], dtype=np.int32)))
+            self.recorder.open.fields["sampled_stochastic"] += stochastic
+            self._emit(seq, int(np.asarray(ids)[0]), out)
         return take
 
     def _run_decode(self, seqs: List[Sequence],
@@ -661,28 +708,39 @@ class InferenceEngine:
                 fields.update(
                     decodes=b, bucket=bucket, table_width=P,
                     live_pages=live_pages, live_pages_full=live_pages)
+                device_positions = self._put(positions)
                 logits, ks, vs, *experts = self._decode_fn(
                     self._params, self.cache.k, self.cache.v,
-                    self._put(tokens), self._put(positions),
+                    self._put(tokens), device_positions,
                     dests, tables, self._put(context_lens))
                 self.cache.k, self.cache.v = ks, vs
                 for count in experts:
-                    # Asked for now, it comes back beside the logits; left
+                    # Asked for now, it comes back beside the ids; left
                     # to the wait it is a transfer of its own, 0.5 ms.
                     count.copy_to_host_async()
             with recorder.phase("infer.decode.wait") as wait:
+                # The chip is on the decode. The sampler goes out behind
+                # it, over the logits where they lie and the positions
+                # the decode was given; the requests' own rows are put
+                # again only when the batch's membership has changed.
+                batch, rows, stochastic = self._sampled_batch
+                if len(batch) != b or not all(map(operator.is_, batch, seqs)):
+                    rows, stochastic = self._sampling_rows(seqs, bucket)
+                    self._sampled_batch = (list(seqs), rows, stochastic)
+                ids = self._sample_fn(logits, *rows, device_positions)
+                ids.copy_to_host_async()
+                fields["sampled_stochastic"] += stochastic
                 # The host blocked on the device and on the copy back,
                 # after whatever its owner has for it meanwhile.
                 if self.on_launch is not None:
                     self.on_launch()
-                logits_np = np.asarray(logits)
-                wait.attrs["bytes"] = logits_np.nbytes
+                ids = np.asarray(ids)
+                wait.attrs["bytes"] = ids.nbytes
                 self._count_experts(experts)
             with recorder.phase("infer.decode.sample"):
-                for i, seq in enumerate(seqs):
+                # Advancing and emitting what was sampled on the device.
+                for seq, token in zip(seqs, ids.tolist()):
                     seq.cached_len += 1
-                    token = sample_token(logits_np[i], seq.sampling,
-                                         seq.rng)
                     self._emit(seq, token, out)
             if profiling_enabled():
                 prof = step_profiler("infer")
@@ -696,7 +754,7 @@ class InferenceEngine:
                         self.cache.v, self._put(tokens),
                         self._put(positions), dests, tables,
                         self._put(context_lens)))
-                # Launch to logits on the host: the real step.
+                # Launch to token ids on the host: the real step.
                 prof.observe_step(wait.t1 - launch.t0, flops=flops)
                 self._hbm_tick += 1
                 if self._hbm_tick % 32 == 1:
@@ -791,8 +849,11 @@ class InferenceEngine:
         ``window_pages_released`` (pages the window tables gave back in
         the step), ``pages_owned_full`` and ``pages_owned_window`` (pages
         sequences own in one pool of each kind when the step ends; both
-        0 for a model without window layers), ``admitted``, ``compiled``
-        (programs traced in it), ``preempted``, ``prefills`` (``request_id``, ``tokens``,
+        0 for a model without window layers), ``sampled_stochastic`` (rows
+        whose token was drawn and not the argmax: how often the sampler's
+        stochastic branch had work), ``admitted``, ``compiled``
+        (programs traced in it, the sampler's among them),
+        ``preempted``, ``prefills`` (``request_id``, ``tokens``,
         ``bucket``, ``waited_s`` each) and ``error`` if it raised. A
         routed-expert model's steps also carry, over the step's programs,
         ``moe_assignments`` ((token, expert) pairs computed),
@@ -819,6 +880,8 @@ class InferenceEngine:
                                        in self._chunk_compiles.items()},
             "decode_compiles": {str(k): v for k, v
                                 in self._decode_compiles.items()},
+            # The sampler's, by the shape of the logits it was given.
+            "sample_compiles": dict(self._sample_compiles),
             # Of the steps the recorder's ring still holds.
             "decode_batch_hist": self.recorder.values("decodes"),
             # Block-table columns handed to the reference gather (each

@@ -1,14 +1,25 @@
 """Token sampling: greedy / temperature / top-k.
 
-Sampling runs on the HOST over one row of fp32 logits with a
-*per-request* ``numpy`` RNG, never a shared key: a request's random
-stream depends only on its own seed and how many tokens it has
-sampled, so outputs are invariant to batch composition. A request that
-decodes alone and the same request decoding inside a continuously
-batched group produce identical tokens — the property the engine's
-greedy-matches-reference tests pin down, and the property that makes
-continuous batching an invisible optimization rather than a behavior
-change.
+Sampling runs on the DEVICE, in the one :func:`sample` the engine jits
+beside its three programs: a step's ``[batch, vocab]`` float32 logits
+stay where the program left them and ``int32[batch]`` token ids come
+back. Every row carries its own request's ``temperature``, ``top_k``
+and ``seed``, and a stochastic row draws with the key
+``fold_in(key(seed), position)``, where ``position`` is the position of
+the row whose logits are sampled (``len(prompt) - 1`` for the first
+token, one more for each token after it). A request's random stream
+therefore depends only on its own seed and on how far it has come,
+never on a shared key or on what else is in the batch: a request that
+decodes alone and the same request inside a continuously batched group
+produce identical tokens, and one resumed after a preemption draws what
+it would have drawn. That is the property the engine's
+greedy-matches-reference and batch-invariance tests pin down, and what
+makes continuous batching an invisible optimization rather than a
+behavior change.
+
+:func:`sample_token` is the same mathematics on the host over one row
+with a ``numpy`` generator: the tests' oracle for the distribution. The
+engine does not call it.
 """
 
 from __future__ import annotations
@@ -24,7 +35,8 @@ class SamplingParams:
     """Per-request sampling and stop configuration.
 
     ``temperature <= 0`` selects greedy decoding (``top_k`` ignored);
-    ``top_k <= 0`` means no top-k truncation. ``stop_token_ids`` end
+    ``top_k <= 0`` means no top-k truncation. ``seed`` keys the
+    request's random stream (its low 32 bits). ``stop_token_ids`` end
     the sequence as soon as one is sampled (the stop token IS emitted,
     matching the reference serve semantics of streaming every token).
     """
@@ -42,9 +54,74 @@ class SamplingParams:
                            tuple(int(t) for t in self.stop_token_ids))
 
 
+def sample(logits, temperature, top_k, seed, position):
+    """One token id a row: ``(logits[B, V] f32, temperature[B] f32,
+    top_k[B] i32, seed[B] u32, position[B] i32) -> ids[B] i32``, to be
+    traced (``jax.numpy``).
+
+    A row with ``temperature <= 0`` takes its first maximum, as
+    ``np.argmax`` does. Any other row is scaled by its temperature,
+    masked below its own ``top_k``-th value (ties with it kept; ``top_k
+    <= 0`` or ``>= V``: no mask) and drawn from the softmax of what is
+    left with ``fold_in(key(seed), position)``. Finding the rows'
+    thresholds serves no greedy row, so it sits behind a ``cond`` on
+    whether the batch holds a stochastic one."""
+    import jax
+    import jax.numpy as jnp
+
+    vocab = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    stochastic = temperature > 0.0
+
+    def draw():
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        kept = jnp.where((top_k > 0) & (top_k < vocab), top_k, vocab)
+        order = _ordered_bits(scaled)
+        scaled = jnp.where(order >= _kth_largest(order, kept)[:, None],
+                           scaled, -jnp.inf)
+        keys = jax.vmap(lambda s, p: jax.random.fold_in(
+            jax.random.key(s), p))(seed, position)
+        drawn = jax.vmap(jax.random.categorical)(keys, scaled)
+        return jnp.where(stochastic, drawn.astype(jnp.int32), greedy)
+
+    return jax.lax.cond(jnp.any(stochastic), draw, lambda: greedy)
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 that compare as the floats do (a negative
+    number's bits turned over, a positive one's sign bit set; ``-0.0``
+    as ``0.0``)."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(x == 0.0, 0.0, x).astype(jnp.float32), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _kth_largest(order, k):
+    """``(order[B, V] u32, k[B] i32 in 1..V) -> u32[B]``: each row's
+    ``k``-th largest value, exactly, ``k`` a traced value of the row's
+    own. Bit by bit from the top: a bit stays set while ``k`` values
+    still reach the number built so far, 32 passes over the rows. (A
+    sort of the rows gives the same value and takes the TPU's compiler
+    half a minute a shape.)"""
+    import jax
+    import jax.numpy as jnp
+
+    def with_bit(i, found):
+        tried = found | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        reach = jnp.sum(order >= tried[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= k, tried, found)
+
+    return jax.lax.fori_loop(0, 32, with_bit,
+                             jnp.zeros(order.shape[0], jnp.uint32))
+
+
 def sample_token(logits: np.ndarray, params: SamplingParams,
                  rng: np.random.Generator) -> int:
-    """Sample one token id from a ``[vocab]`` fp32 logits row."""
+    """The tests' oracle: one token id from a ``[vocab]`` fp32 logits
+    row, on the host, by the mathematics of :func:`sample`."""
     logits = np.asarray(logits, dtype=np.float64)
     if params.temperature <= 0.0:
         return int(np.argmax(logits))

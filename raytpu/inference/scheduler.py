@@ -25,8 +25,6 @@ import dataclasses
 from collections import deque
 from typing import Deque, List, Optional
 
-import numpy as np
-
 from raytpu.inference.kv_cache import PagedKVCache
 from raytpu.inference.sampling import SamplingParams
 from raytpu.util import serve_slo, task_events
@@ -59,11 +57,6 @@ class Sequence:
 
     def __post_init__(self):
         self.prompt = [int(t) for t in self.prompt]
-        self._rng = np.random.default_rng(self.sampling.seed)
-
-    @property
-    def rng(self) -> np.random.Generator:
-        return self._rng
 
     @property
     def tokens(self) -> List[int]:

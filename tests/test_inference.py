@@ -15,12 +15,16 @@ import pytest
 
 from raytpu.inference import (InferenceEngine, PagedKVCache, SamplingParams,
                               Scheduler, Sequence)
+from raytpu.inference.sampling import sample, sample_token
 from raytpu.models import gpt2 as gpt2_mod
 from raytpu.models import llama as llama_mod
 from raytpu.models.gpt2 import GPT2, GPT2Config
 from raytpu.models.gpt2 import init_params as gpt2_init
 from raytpu.models.llama import Llama, LlamaConfig
 from raytpu.models.llama import init_params as llama_init
+from raytpu.models.mixtral import (JoyAIConfig, MellumConfig, Mixtral,
+                                   OlmoeConfig)
+from raytpu.models.mixtral import init_params as mixtral_init
 
 LCFG = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32,
                            attn_impl="reference", remat=False)
@@ -339,6 +343,301 @@ class TestEngineLlama:
         assert engine_mod._kv_util_gauge.value == 0.0
 
 
+# ---------------------------------------------------------------------------
+# Sampling on the device: one sampler for the prefill's first token and
+# every decode's, keyed by the request's seed and the row's position.
+# ---------------------------------------------------------------------------
+
+_F32 = dict(dtype=jnp.float32, attn_impl="reference", remat=False)
+SERVED = {
+    "gpt2": (GCFG, lambda: gpt2_init(GPT2(GCFG), GCFG, seed=0, batch=1)),
+    "llama": (LCFG, lambda: llama_init(Llama(LCFG), LCFG, seed=0, batch=1)),
+    **{name: (c, lambda c=c: mixtral_init(Mixtral(c), c, seed=1))
+       for name, c in (
+           ("olmoe", dataclasses.replace(
+               OlmoeConfig.tiny(), paged_attn="reference", **_F32)),
+           ("mellum", dataclasses.replace(
+               MellumConfig.tiny(), paged_attn="reference", **_F32)),
+           ("joyai", dataclasses.replace(
+               JoyAIConfig.tiny(), paged_attn="reference", **_F32)))},
+}
+
+
+def keep_sampled(eng):
+    """Every call of the engine's sampler from now on, as ``(logits rows
+    sampled, temperature, top_k, seed, position, ids)`` on the host."""
+    calls, fn = [], eng._sample_fn
+
+    def kept(logits, temperature, top_k, seed, position):
+        ids = fn(logits, temperature, top_k, seed, position)
+        calls.append((np.asarray(logits).reshape(-1, logits.shape[-1]),
+                      *(np.asarray(a) for a in (temperature, top_k, seed,
+                                                position, ids))))
+        return ids
+
+    eng._sample_fn = kept
+    return calls
+
+
+def oracle_probs(logits, temperature, top_k):
+    """The distribution ``sample_token`` draws a stochastic row from."""
+    scaled = np.asarray(logits, np.float64) / max(temperature, 1e-6)
+    if 0 < top_k < scaled.shape[0]:
+        scaled = np.where(scaled >= np.sort(scaled)[-top_k], scaled, -np.inf)
+    probs = np.exp(scaled - scaled.max())
+    return probs / probs.sum()
+
+
+class TestDeviceSampling:
+    N = 4096          # draws a frequency is taken over
+    TOLERANCE = 0.03  # of a frequency: 3.8 sigma of 4,096 draws at p = 1/2
+
+    @staticmethod
+    def draw(logits, temperature, top_k, seed, position):
+        """``sample`` jitted, over host values broadcast to the rows."""
+        rows = np.asarray(logits, np.float32)
+        n = rows.shape[0]
+        full = lambda x, dt: jnp.asarray(np.broadcast_to(
+            np.asarray(x, dt), (n,)))
+        return np.asarray(jax.jit(sample)(
+            jnp.asarray(rows), full(temperature, np.float32),
+            full(top_k, np.int32), full(seed, np.uint32),
+            full(position, np.int32)))
+
+    @pytest.mark.parametrize("family", sorted(SERVED))
+    def test_greedy_ids_are_the_argmax_of_the_steps_logits(self, family):
+        cfg, init = SERVED[family]
+        eng = InferenceEngine(cfg, init(), page_size=4, max_num_seqs=4,
+                              max_model_len=64, prefill_chunk=16)
+        calls = keep_sampled(eng)
+        rng = np.random.default_rng(0)
+        prompts = [[int(t) for t in rng.integers(1, cfg.vocab_size, size=n)]
+                   for n in (5, 11, 23)]  # the last one in two chunks
+        outs = eng.generate(prompts, SamplingParams(max_new_tokens=5))
+        assert len(calls) >= 3 + 4 and [len(o) for o in outs] == [5, 5, 5]
+        for logits, temperature, _, _, _, ids in calls:
+            assert ids.dtype == np.int32 and not temperature.any()
+            assert ids.tolist() == np.argmax(logits, axis=-1).tolist()
+        # Each prompt's first token is its prefill's one sampled row.
+        firsts = [c[5].tolist() for c in calls if c[0].shape[0] == 1
+                  and len(c[5]) == 1][:3]
+        assert sorted(f[0] for f in firsts) == sorted(o[0] for o in outs)
+        assert all(s["sampled_stochastic"] == 0
+                   for s in eng.step_log()["steps"])
+        assert all(v == 1 for v in eng.stats()["sample_compiles"].values())
+
+    def test_argmax_takes_the_first_maximum_as_numpy_does(self):
+        logits = np.zeros((3, 9), np.float32)
+        logits[0, [2, 5]] = 1.0
+        logits[1, [8, 0]] = 3.0
+        assert self.draw(logits, 0.0, 0, 0, 0).tolist() \
+            == np.argmax(logits, axis=-1).tolist() == [2, 0, 0]
+
+    @pytest.mark.parametrize("temperature,top_k", [
+        (0.7, 5), (1.0, 0), (1.5, 3), (0.3, 16)])
+    def test_stochastic_rows_follow_the_oracles_distribution(
+            self, temperature, top_k):
+        row = np.random.default_rng(7).normal(size=16).astype(np.float32)
+        probs = oracle_probs(row, temperature, top_k)
+        logits = np.broadcast_to(row, (self.N, 16))
+        # Over seeds at one position, and over positions of one seed.
+        for seed, position in ((np.arange(self.N), 3),
+                               (11, np.arange(self.N))):
+            ids = self.draw(logits, temperature, top_k, seed, position)
+            freq = np.bincount(ids, minlength=16) / self.N
+            assert np.abs(freq - probs).max() < self.TOLERANCE
+            assert not freq[probs == 0].any()  # none outside the top k
+        # The oracle itself, by the same yardstick.
+        rng = np.random.default_rng(5)
+        params = SamplingParams(temperature=temperature, top_k=top_k)
+        drawn = [sample_token(row, params, rng) for _ in range(self.N)]
+        freq = np.bincount(drawn, minlength=16) / self.N
+        assert np.abs(freq - probs).max() < self.TOLERANCE
+
+    def test_top_k_of_zero_or_past_the_vocabulary_masks_nothing(self):
+        logits = np.zeros((self.N, 8), np.float32)  # every id as likely
+        seeds = np.arange(self.N)
+        unmasked = self.draw(logits, 1.0, 0, seeds, 0)
+        assert set(unmasked.tolist()) == set(range(8))
+        for top_k in (8, 9, 1 << 20, -1):
+            assert self.draw(logits, 1.0, top_k, seeds, 0).tolist() \
+                == unmasked.tolist()
+        # Ties with the k-th value are kept, as the oracle keeps them.
+        assert set(self.draw(logits, 1.0, 2, seeds, 0).tolist()) \
+            == set(range(8))
+        one = self.draw(np.arange(8, dtype=np.float32)[None].repeat(
+            self.N, 0), 1.0, 1, seeds, 0)
+        assert set(one.tolist()) == {7}
+
+    @pytest.mark.parametrize("k", [1, 2, 37, 500, 999, 1000])
+    def test_a_rows_threshold_is_its_sorted_kth_value(self, k):
+        from raytpu.inference.sampling import _kth_largest, _ordered_bits
+        x = np.random.default_rng(k).normal(size=(5, 1000)).astype(np.float32)
+        x[0, :10], x[0, 10:20] = 0.0, -0.0          # one value to a float
+        x[1, 5], x[2, 7] = np.inf, -np.inf
+        x[3] = np.round(x[3])                        # ties at every rank
+        x[4] *= 1e30
+        order = _ordered_bits(jnp.asarray(x))
+        kth = jax.jit(_kth_largest)(order, jnp.full(5, k, jnp.int32))
+        kept = np.asarray(order >= kth[:, None])
+        want = np.sort(x, axis=-1)[:, -k]
+        assert (kept == (x >= want[:, None])).all()
+        assert (kept.sum(axis=-1) >= k).all()
+
+    def test_rows_of_one_batch_are_independent(self):
+        rng = np.random.default_rng(3)
+        logits = rng.normal(size=(6, 32)).astype(np.float32)
+        temperature = [0.0, 0.8, 1.3, 0.8, 0.0, 2.0]
+        top_k = [4, 4, 0, 9, 0, 40]
+        seed = [1, 1, 2, 1, 5, 0xFFFFFFFF]
+        position = [0, 7, 7, 7, 2, 100]
+        together = self.draw(logits, temperature, top_k, seed, position)
+        for i in range(6):
+            alone = self.draw(logits[i:i + 1], temperature[i], top_k[i],
+                              seed[i], position[i])
+            assert alone.tolist() == [together[i]]
+        assert together[0] == np.argmax(logits[0])
+        assert together[4] == np.argmax(logits[4])
+        # Reordered, and beside other rows, a row draws the same.
+        order = [3, 0, 5, 1]
+        pick = lambda xs: [xs[i] for i in order]
+        assert self.draw(logits[order], pick(temperature), pick(top_k),
+                         pick(seed), pick(position)).tolist() \
+            == together[order].tolist()
+
+    def test_the_draw_is_keyed_by_seed_and_position(self):
+        logits = np.zeros((1, 64), np.float32)
+        base = self.draw(logits, 1.0, 0, 9, 4)
+        assert self.draw(logits, 1.0, 0, 9, 4).tolist() == base.tolist()
+        seeds = self.draw(np.zeros((32, 64), np.float32), 1.0, 0,
+                          np.arange(32), 4)
+        positions = self.draw(np.zeros((32, 64), np.float32), 1.0, 0, 9,
+                              np.arange(32))
+        assert len(set(seeds.tolist())) > 8
+        assert len(set(positions.tolist())) > 8
+        assert positions[4] == base[0] == seeds[9]
+
+    def test_first_token_and_later_ones_come_from_one_stream(
+            self, llama_model):
+        _, params = llama_model
+        eng = InferenceEngine(LCFG, params, page_size=8, max_num_seqs=4,
+                              max_model_len=64)
+        calls = keep_sampled(eng)
+        prompt = [5, 6, 7, 8]
+        sampling = SamplingParams(max_new_tokens=6, temperature=0.9,
+                                  top_k=20, seed=(1 << 32) + 77)
+        (out,) = eng.generate([prompt], sampling)
+        assert len(calls) == 6
+        for i, (logits, temperature, top_k, seed, position, ids) \
+                in enumerate(calls):
+            # The row's own position: the prompt's last, then one on.
+            assert position.tolist() == [len(prompt) - 1 + i]
+            assert seed.tolist() == [77] and top_k.tolist() == [20]
+            assert ids.tolist() == [out[i]] == self.draw(
+                logits, 0.9, 20, 77, len(prompt) - 1 + i).tolist()
+        assert [s["sampled_stochastic"]
+                for s in eng.step_log()["steps"]] == [1] * 6
+
+    def test_a_preempted_request_draws_what_it_draws_unpreempted(
+            self, llama_model):
+        _, params = llama_model
+        pa, pb = list(range(1, 8)), list(range(20, 25))
+        requests = (("a", pa, SamplingParams(
+            max_new_tokens=8, temperature=0.8, top_k=12, seed=3)),
+            ("b", pb, SamplingParams(
+                max_new_tokens=8, temperature=1.1, seed=4)))
+
+        def run(**kw):
+            eng = InferenceEngine(LCFG, params, page_size=4, max_num_seqs=2,
+                                  max_model_len=24, **kw)
+            for request in requests:
+                eng.add_request(*request)
+            outs = {"a": [], "b": []}
+            while eng.has_unfinished():
+                for o in eng.step():
+                    outs[o.request_id].append(o.token_id)
+            return eng, outs
+
+        # 5 usable pages: the two cannot both stay resident.
+        tight, preempted = run(num_pages=6)
+        roomy, plain = run()
+        assert tight.stats()["num_preemptions"] >= 1
+        assert roomy.stats()["num_preemptions"] == 0
+        assert preempted == plain
+        assert [len(v) for v in plain.values()] == [8, 8]
+        # And neither stream is the greedy one.
+        greedy = InferenceEngine(LCFG, params, page_size=4, max_num_seqs=2,
+                                 max_model_len=24).generate(
+            [pa, pb], SamplingParams(max_new_tokens=8))
+        assert plain["a"] != greedy[0] and plain["b"] != greedy[1]
+
+    def test_under_a_tp_mesh_a_row_draws_what_one_device_draws(
+            self, llama_model):
+        _, params = llama_model
+        eng = InferenceEngine(LCFG, params, page_size=8, max_num_seqs=4,
+                              max_model_len=64, tp=2)
+        calls = keep_sampled(eng)
+        outs = eng.generate([[5, 6, 7, 8], [9, 10]], SamplingParams(
+            max_new_tokens=4, temperature=0.9, top_k=20, seed=6))
+        assert len(calls) == 2 + 3 and [len(o) for o in outs] == [4, 4]
+        for logits, temperature, top_k, seed, position, ids in calls:
+            assert ids.tolist() == self.draw(
+                logits, temperature, top_k, seed, position).tolist()
+
+    def test_mixed_batch_counts_its_stochastic_rows(self, llama_model):
+        _, params = llama_model
+        eng = InferenceEngine(LCFG, params, page_size=8, max_num_seqs=4,
+                              max_model_len=64)
+        eng.add_request("g", [1, 2, 3], SamplingParams(max_new_tokens=4))
+        eng.add_request("s", [4, 5, 6], SamplingParams(
+            max_new_tokens=4, temperature=1.0, seed=1))
+        eng.add_request("t", [7, 8], SamplingParams(
+            max_new_tokens=4, temperature=0.5, top_k=3, seed=2))
+        calls = keep_sampled(eng)
+        while eng.has_unfinished():
+            eng.step()
+        steps = eng.step_log()["steps"]
+        # A step of three prefills' first tokens (two of them drawn), then
+        # three decodes of a bucket of four with two stochastic rows each.
+        assert [s["decodes"] for s in steps] == [0, 3, 3, 3]
+        assert [s["sampled_stochastic"] for s in steps] == [2, 2, 2, 2]
+        for logits, temperature, *_, ids in calls:
+            greedy = temperature <= 0
+            assert ids[greedy].tolist() \
+                == np.argmax(logits, axis=-1)[greedy].tolist()
+
+    def test_request_rows_are_put_once_a_batch_not_once_a_step(
+            self, llama_model):
+        _, params = llama_model
+        eng = InferenceEngine(LCFG, params, page_size=8, max_num_seqs=4,
+                              max_model_len=64)
+        made, rows = [], eng._sampling_rows
+
+        def counted(seqs, bucket):
+            made.append([s.request_id for s in seqs])
+            return rows(seqs, bucket)
+
+        eng._sampling_rows = counted
+        eng.add_request("a", [1, 2, 3], SamplingParams(max_new_tokens=9))
+        eng.add_request("b", [4, 5], SamplingParams(max_new_tokens=4))
+        while eng.has_unfinished():
+            eng.step()
+        steps = eng.step_log()["steps"]
+        assert len([s for s in steps if s["decodes"]]) == 8
+        # A prefill's own row each, then once a membership of the batch.
+        assert made == [["a"], ["b"], ["a", "b"], ["a"]]
+        # The same ids under another request's parameters are another
+        # batch: a sequence is known by what it is, not by its name.
+        hot = SamplingParams(max_new_tokens=3, temperature=1.0, seed=8)
+        cold = SamplingParams(max_new_tokens=3)
+        first = eng.generate([[1, 2, 3]], cold)
+        again = InferenceEngine(LCFG, params, page_size=8, max_num_seqs=4,
+                                max_model_len=64)
+        assert again.generate([[1, 2, 3]], hot) \
+            == eng.generate([[1, 2, 3]], hot)
+        assert eng.generate([[1, 2, 3]], cold) == first
+
+
 class TestStepLog:
     """One record per step, its phases live spans (ISSUE 24)."""
 
@@ -412,7 +711,9 @@ class TestStepLog:
             assert dec["name"] == "infer.decode"
             assert dec["duration_s"] >= wait["duration_s"] > 0
             assert dec["t0"] <= wait["t0"]
-            assert wait["attributes"]["bytes"] == 4 * LCFG.vocab_size
+            # What comes back is the batch's token ids, not its logits.
+            assert dec["attributes"]["bucket"] == 1
+            assert wait["attributes"]["bytes"] == 4 * 1
             assert by_id[dec["parent_span_id"]]["name"] == "infer.step"
         step = [s for s in spans if s["name"] == "infer.step"][-1]
         assert {"decodes", "bucket", "table_width", "live_pages", "compiled",
@@ -456,7 +757,8 @@ class TestStepLog:
         for _ in range(3):
             eng.step()
         prefill, first, second = eng.step_log()["steps"]
-        assert prefill["compiled"] == 1 and first["compiled"] == 1
+        # A program and the sampler over its logits' shape.
+        assert prefill["compiled"] == 2 and first["compiled"] == 2
         assert second["compiled"] == 0
         # A second sequence joins: a new batch bucket, compiled once.
         eng.add_request("b", [4, 5, 6], SamplingParams(max_new_tokens=4))
@@ -465,12 +767,12 @@ class TestStepLog:
         admitted, joined, after = eng.step_log()["steps"][-3:]
         # Its prefill's program is warm; it decodes from the next step.
         assert admitted["decodes"] == 1 and admitted["compiled"] == 0
-        assert joined["decodes"] == 2 and joined["compiled"] == 1
+        assert joined["decodes"] == 2 and joined["compiled"] == 2
         assert after["decodes"] == 2 and after["compiled"] == 0
         assert sum(s["compiled"] for s in eng.step_log()["steps"]) == sum(
             sum(eng.stats()[k].values()) for k in (
                 "prefill_compiles", "chunk_prefill_compiles",
-                "decode_compiles"))
+                "decode_compiles", "sample_compiles"))
 
     def test_waited_s_covers_the_time_behind_a_full_batch(self, llama_model):
         import time
@@ -593,7 +895,9 @@ class TestEngineGPT2:
         assert outs[1] == reference_greedy(model, params, pb, 6)
         assert {"gpt2_prefill", "gpt2_prefill_chunk", "gpt2_decode"} \
             == set(traced)
-        assert len(traced) == eng._programs_traced()  # once a bucket
+        # Once a bucket; the sampler over their logits is the engine's.
+        assert len(traced) == eng._programs_traced() - sum(
+            eng.stats()["sample_compiles"].values())
         assert eng.stats()["expert_tokens"] is None
 
     def test_a_config_that_cannot_say_how_it_is_served_is_refused(
@@ -1014,7 +1318,7 @@ class TestInferenceJitLint:
         assert not [c for c in called if c[0] == "isinstance"]
         assert sorted(c[1] for c in called if c[0] == "getattr") \
             == [["paged_attn", None], ["serving", None]]
-        assert builders == ["_build_program"]
+        assert builders == ["_build_program", "_build_sampler"]
 
     def test_lint_catches_planted_violation(self):
         from raytpu.analysis.core import run_rule_on_source
