@@ -47,8 +47,8 @@ import contextlib
 import dataclasses
 import operator
 import time
-from typing import (Callable, Dict, List, Optional,
-                    Sequence as SequenceT)
+from typing import (Any, Callable, Dict, List, Optional,
+                    Sequence as SequenceT, Tuple)
 
 import numpy as np
 
@@ -319,6 +319,10 @@ class InferenceEngine:
         self._chunk_compiles: Dict[str, int] = {}
         self._decode_compiles: Dict[str, int] = {}
         self._sample_compiles: Dict[str, int] = {}
+        # What ``ops.grouped_matmul`` noted while a program was traced,
+        # under the program's name and bucket key: how many of its
+        # expert products go through the grouped kernel on these devices.
+        self._grouped_calls: Dict[Tuple[str, Any], int] = {}
         # The decode batch the sampler's per-request rows were last put
         # for: (its sequences, the rows on the device, how many of them
         # are stochastic). Remade when the batch's membership changes.
@@ -364,13 +368,19 @@ class InferenceEngine:
         ``fwd`` on the donated pools. ``compiles`` counts its traces
         under ``bucket_key(*inputs)``; ``name`` is what a trace and the
         compile cache know the program by."""
+        from raytpu.ops.grouped_matmul import kernel_calls
+
         cfg, kv_sh = self._config, self._kv_sharding
 
         def program(params, ks, vs, *inputs):
-            # Trace-time only: counts XLA compiles per bucket.
+            # Trace-time only: counts XLA compiles per bucket, and the
+            # products the grouped kernel takes of this one.
             bucket = bucket_key(*inputs)
             compiles[bucket] = compiles.get(bucket, 0) + 1
-            logits, ks2, vs2, *experts = fwd(cfg, params, *inputs, ks, vs)
+            with kernel_calls(self._devices[0].split(":")[0]) as grouped:
+                logits, ks2, vs2, *experts = fwd(
+                    cfg, params, *inputs, ks, vs)
+            self._grouped_calls[name, bucket] = grouped[0]
             if kv_sh is not None:
                 # Pin the pool sharding through the update: the pools
                 # must come back kv-head-sharded, never resharded.
@@ -429,12 +439,14 @@ class InferenceEngine:
             return self._put(of(0))
         return tuple(self._put(of(kind)) for kind in self.cache.kinds)
 
-    def _count_experts(self, experts) -> None:
+    def _count_experts(self, experts, program) -> None:
         """Add what one program of a routed model returned beside its
         logits (int32 ``[layers, experts]``: live tokens each expert held
         here received) to the running total and to the open step's
-        record. A dense family's programs return nothing: ``experts`` is
-        empty."""
+        record, and what was noted of ``program`` (its name and bucket
+        key) when it was traced: how many of its expert products go
+        through the grouped kernel. A dense family's programs return
+        nothing: ``experts`` is empty."""
         if not experts:
             return
         counts = np.asarray(experts[0])
@@ -447,6 +459,8 @@ class InferenceEngine:
             + int(np.count_nonzero(counts)))
         fields["moe_expert_max"] = max(fields.get("moe_expert_max", 0),
                                        int(counts.max()))
+        fields["moe_grouped_calls"] = (fields.get("moe_grouped_calls", 0)
+                                       + self._grouped_calls[program])
 
     # ---- request lifecycle ------------------------------------------
 
@@ -622,6 +636,7 @@ class InferenceEngine:
         if whole:
             attrs.update(tokens=take, bucket=bucket)
             fn, inputs = self._prefill_fn, (self._put(tokens), dests)
+            program = ("_prefill", bucket)
         else:
             attrs.update(tokens=take, bucket=bucket, start=start)
             positions = np.zeros(bucket, dtype=np.int32)
@@ -636,10 +651,11 @@ class InferenceEngine:
                 self._pages_gathered += p_used
             fn, inputs = self._chunk_fn, (
                 self._put(tokens), self._put(positions), dests, tables)
+            program = ("_chunk", f"{bucket}x{p_used}")
         logits, ks, vs, *experts = fn(
             self._params, self.cache.k, self.cache.v, *inputs)
         self.cache.k, self.cache.v = ks, vs
-        self._count_experts(experts)
+        self._count_experts(experts, program)
         seq.cached_len = end
         # The chunk's burst goes back: what the next query will see stays.
         self.recorder.open.fields["window_pages_released"] += \
@@ -736,7 +752,7 @@ class InferenceEngine:
                     self.on_launch()
                 ids = np.asarray(ids)
                 wait.attrs["bytes"] = ids.nbytes
-                self._count_experts(experts)
+                self._count_experts(experts, ("_decode", f"{bucket}x{P}"))
             with recorder.phase("infer.decode.sample"):
                 # Advancing and emitting what was sampled on the device.
                 for seq, token in zip(seqs, ids.tolist()):
@@ -859,7 +875,10 @@ class InferenceEngine:
         ``moe_assignments`` ((token, expert) pairs computed),
         ``moe_experts_touched`` (experts that received a token, summed
         over layers and programs) and ``moe_expert_max`` (the most
-        tokens one expert of one layer received in one program); where
+        tokens one expert of one layer received in one program) and
+        ``moe_grouped_calls`` (expert products that went through
+        ``ops.grouped_matmul``'s kernel and not ``ragged_dot``: two a
+        routed layer of a decode program on a TPU); where
         the experts held here are a share of the router's, a pair whose
         expert is not held is in none of them (no row was computed for
         it). ``kv_bytes_per_token`` is what one token costs in the pools

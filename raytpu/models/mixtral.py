@@ -8,9 +8,11 @@ one top-k routed expert layer, :class:`MoEFFN`. It is *dropless*: every
 (token, expert) pair the router chooses is computed, so a token's output
 does not depend on what shares its batch. The assignments are sorted by
 expert and the three expert matrices multiplied group by group
-(``jax.lax.ragged_dot``, which the TPU compiler turns into a kernel of
-its own that reads only the experts that received a row), so the work
-grows with ``n_expert_per_tok`` and not with ``n_expert``. A Switch-style
+(:mod:`raytpu.ops.grouped_matmul`: a Pallas kernel on one TPU where an
+expert's matrices fit its fast memory, and ``jax.lax.ragged_dot``, which
+the TPU compiler turns into a kernel of its own, everywhere else; both
+read only the experts that received a row), so the work grows with
+``n_expert_per_tok`` and not with ``n_expert``. A Switch-style
 load-balancing loss is sown as an intermediate for training. Expert
 parameters are stacked on a leading experts dim so ``TRANSFORMER_RULES``
 shards them over the ``ep`` mesh axis with no model-specific code.
@@ -41,6 +43,7 @@ import jax.numpy as jnp
 
 from raytpu.models.llama import (FULL, WINDOW, LlamaConfig, LlamaMLP,
                                  RMSNorm, Rope)
+from raytpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -255,7 +258,11 @@ class MoEFFN(nn.Module):
     row and is not counted. Returns ``(y, tokens)``: the layer's output
     and the int32 ``[n_expert]`` number of live tokens each expert
     received. The router runs in float32 at full precision over all the
-    experts; the expert matrices multiply in ``config.dtype``.
+    experts; the expert matrices multiply in ``config.dtype`` with
+    float32 sums, gate and up in one pass over the sorted rows and down
+    in another (``ops.grouped_matmul``: which of its two ways is a
+    matter of the program's shapes and of where it is lowered, and both
+    read an expert's matrices only if a row chose it).
 
     Scores are a softmax over the experts, or each expert's own sigmoid
     (``config.scoring``), then chosen by score + ``bias`` where the
@@ -326,10 +333,11 @@ class MoEFFN(nn.Module):
             order = jnp.argsort(flat)
             tokens = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
             rows = xf.astype(c.dtype)[order // k]             # [kN, D]
-            grouped = jax.lax.ragged_dot  # row groups x their experts
-            h = (nn.silu(grouped(rows, wg.astype(c.dtype), tokens))
-                 * grouped(rows, wi.astype(c.dtype), tokens))
-            out = grouped(h, wo.astype(c.dtype), tokens)
+            # Row groups x their experts: the grouped kernel or
+            # ``ragged_dot``, by shape and device (ops/grouped_matmul).
+            h = grouped_swiglu(rows, wg.astype(c.dtype), wi.astype(c.dtype),
+                               tokens)
+            out = grouped_matmul(h, wo.astype(c.dtype), tokens)
             # Weighted and summed per token in float32, in the order of
             # the token's own top-k: the same whatever else is batched.
             out = jnp.where((flat[order] < e)[:, None],

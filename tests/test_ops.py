@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raytpu.ops.flash_attention import flash_attention
+from raytpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 from raytpu.ops.paged_attention import paged_attention
 from raytpu.parallel.mesh import build_mesh
 
@@ -304,9 +305,40 @@ _TPU_CASES = {
 }
 
 
+def _routed_layer(rows, wg, wi, wo, tokens):
+    return grouped_matmul(grouped_swiglu(rows, wg, wi, tokens), wo, tokens)
+
+
+def _routed_shapes(rows, experts, k, n):
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    return (bf16(rows, k), bf16(experts, k, n), bf16(experts, k, n),
+            bf16(experts, n, k), jax.ShapeDtypeStruct((experts,), jnp.int32))
+
+
+# The routed layer's products at the three served families' decode shapes
+# (rows = sequences x experts a token) and at Mellum2's chunk of 2,048.
+_ROUTED_CASES = {
+    "mellum_decode": _routed_shapes(256, 64, 2304, 896),
+    "olmoe_decode": _routed_shapes(128, 64, 2048, 1024),
+    "joyai_decode": _routed_shapes(256, 32, 2048, 768),
+    "mellum_chunk": _routed_shapes(16384, 64, 2304, 896),
+}
+
+
 class TestTpuLowering:
     """Every Pallas kernel must lower for the TPU from a CPU host, alone
     and from a program sharded over four devices."""
+
+    @pytest.mark.parametrize("case", sorted(_ROUTED_CASES))
+    def test_routed_layer_is_two_kernels_for_the_tpu(self, case):
+        traced = jax.jit(_routed_layer).trace(*_ROUTED_CASES[case])
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+        assert len(re.findall(r"stablehlo.custom_call @tpu_custom_call",
+                              text)) == 2
+        assert "ragged_dot" not in text
+        assert "tpu_custom_call" not in traced.lower(
+            lowering_platforms=("cpu",)).as_text()
 
     @pytest.mark.parametrize("case", sorted(_TPU_CASES))
     def test_single_device(self, case):
@@ -372,6 +404,13 @@ class TestTpuAotCompile:
             jax.sharding.Mesh(np.array(v5e_devices[:1]), ("x",)), P())
         jax.jit(fn, in_shardings=one, out_shardings=one).trace(
             *shapes).lower(lowering_platforms=("tpu",)).compile()
+
+    @pytest.mark.parametrize("case", sorted(_ROUTED_CASES))
+    def test_mosaic_accepts_the_routed_layer(self, case, v5e_devices):
+        one = NamedSharding(
+            jax.sharding.Mesh(np.array(v5e_devices[:1]), ("x",)), P())
+        jax.jit(_routed_layer, in_shardings=one, out_shardings=one).trace(
+            *_ROUTED_CASES[case]).lower(lowering_platforms=("tpu",)).compile()
 
 
 class TestPerShard:
