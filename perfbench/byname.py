@@ -46,6 +46,15 @@ def load_reader(dirs: Sequence[str], name: str):
     return load_module(dirs, "layer_metrics", name)
 
 
+def load_beside(path: str, name: str):
+    """The reader ``<name>.py`` in the directory of the reader at ``path``:
+    for a reader that is another one under a second name, because its
+    cells report another end-to-end metric for it to move."""
+    return load_module([os.path.dirname(os.path.dirname(
+        os.path.abspath(path)))], os.path.basename(os.path.dirname(
+            os.path.abspath(path))), name)
+
+
 def load_family(dirs: Sequence[str], cfg: Dict):
     """The family file a configuration names (``"family"``)."""
     return load_module(dirs, "families", cfg["family"])

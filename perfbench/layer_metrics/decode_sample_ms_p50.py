@@ -1,6 +1,7 @@
 """Median of the program's ``infer.decode.sample`` phase over the window's
-decode steps: one ``sample_token`` per sequence on the host, and the
-emitting of each token."""
+decode steps: advancing each sequence and emitting the token ids the
+device sampled (since PR 35; one ``sample_token`` a sequence on the host
+before)."""
 
 LAYER = "engine step"
 UNIT = "ms"

@@ -12,7 +12,7 @@ them."""
 
 LAYER = "KV cache"
 UNIT = "%"
-MOVES = "out_tokens_per_s"
+MOVES = "itl_p50_ms"
 SOURCE = "program_counter"
 
 

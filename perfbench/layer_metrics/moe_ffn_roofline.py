@@ -1,6 +1,6 @@
 """The routed-expert layer's share of its roofline in the traced part of
 the window. Time: the device trace's events of the expert matrices
-(``perfbench/moe.py``: ``ragged-dot-*``) inside the traced
+(``perfbench/moe.py``: ``_moe_grouped_pallas*``, or ``ragged-dot-*``) inside the traced
 ``pb.engine.step`` spans. Least time: the larger of the weight bytes the
 experts touched in those same steps hold (three matrices each, from the
 step records' ``moe_experts_touched``) over the peak bandwidth, and the

@@ -6,7 +6,7 @@ cache does the share of a step's work the cell was built for."""
 
 LAYER = "kernels"
 UNIT = "%"
-MOVES = "out_tokens_per_s"
+MOVES = "itl_p50_ms"
 SOURCE = "device_trace"
 
 

@@ -12,7 +12,7 @@ per cent here."""
 
 LAYER = "kernels"
 UNIT = "%"
-MOVES = "itl_p95_ms"
+MOVES = "itl_p50_ms"
 SOURCE = "device_trace"
 
 

@@ -1,12 +1,15 @@
 """What the routed-expert layer's four readers share: its device time in
 the traced steps, and the routing counts the program's step log carries.
 
-Device time: the expert matrices are multiplied by ``jax.lax.ragged_dot``,
-which the TPU compiler turns into kernels of its own, so the trace's
-events are called ``ragged-dot-*`` (seen on the chip, PR 26: three
-``ragged-dot-none*`` a layer and one ``ragged-dot-metadata``). A Pallas
-kernel that replaces it is named after its function and has to start
-with one of ``EXPERT_OPS``. The router, the sort of the assignments, the
+Device time: since PR 40 the expert matrices are multiplied by the
+grouped-matmul Pallas kernel (``raytpu/ops/grouped_matmul.py``), whose
+trace events are ``_moe_grouped_pallas*``, two a layer. Where an expert's
+matrices do not fit the kernel's buffers the program falls back to
+``jax.lax.ragged_dot``, which the TPU compiler turns into kernels of its
+own called ``ragged-dot-*`` (seen on the chip, PR 26: three
+``ragged-dot-none*`` a layer and one ``ragged-dot-metadata``). A kernel
+of the routed layer is named after its function and has to start with
+one of ``EXPERT_OPS``. The router, the sort of the assignments, the
 gather and the weighted sum run under ``jax.named_scope("moe.router")``
 and ``("moe.experts")``, but a scope is HLO metadata and ``Trace`` keeps
 an event's name only: they are a few microseconds each and are not in

@@ -12,9 +12,11 @@ prefill tokens) are read from the program's record of the step
 ``scheduler.num_preemptions``. Two
 things have no public source yet and come from overriding the engine's
 private ``_run_prefill`` and ``_run_decode`` (and, for the check only,
-wrapping ``_prefill_fn``/``_decode_fn``): the spans around the prefill
-and the decode, and the sequences and live pages of each decode. They
-are listed for the ``tracing`` PR in ``PERF.md``'s open questions.
+wrapping ``_prefill_fn``/``_decode_fn`` and noting each sequence's
+``cached_len`` around the call: ``kept_rows`` states that contract): the
+spans around the prefill and the decode, and the sequences and live
+pages of each decode. They are listed for the ``tracing`` PR in
+``PERF.md``'s open questions.
 
 The program keeps its own step records in a ring (``step_log``). The
 probe takes each one as its step ends, on the thread that steps, and
@@ -29,7 +31,9 @@ import glob
 import os
 import shutil
 import time
-from typing import List, Optional
+from typing import Dict, List, Mapping, Optional, Sequence
+
+import numpy as np
 
 from raytpu.inference.engine import InferenceEngine
 
@@ -150,11 +154,17 @@ class ProbedEngine(InferenceEngine):
                           if r.program.end > since]}
 
     def _run_prefill(self, seq, out):
-        if self.captured is not None:
-            self.captured.append(("prefill_id", seq.request_id))
+        captured = self.captured  # the check's calls: see ``kept_rows``
+        if captured is not None:
+            captured.append(("prefill_id", seq.request_id))
+            before = seq.cached_len
         self._current.prefills += 1
         with self._span("pb.engine.prefill"):
-            return super()._run_prefill(seq, out)
+            n = super()._run_prefill(seq, out)
+        if captured is not None:
+            captured.append(("advanced",
+                             [(seq.request_id, before, seq.cached_len)]))
+        return n
 
     def _run_decode(self, seqs, out):
         rec = self._current
@@ -163,11 +173,17 @@ class ProbedEngine(InferenceEngine):
         # context (the token being written included) in whole pages.
         rec.live_pages = sum(self.cache.pages_for(s.cached_len + 1)
                              for s in seqs)
-        if self.captured is not None:
-            self.captured.append(("decode_ids",
-                                  [s.request_id for s in seqs]))
+        captured = self.captured
+        if captured is not None:
+            captured.append(("decode_ids", [s.request_id for s in seqs]))
+            before = [s.cached_len for s in seqs]
         with self._span("pb.engine.decode"):
-            return super()._run_decode(seqs, out)
+            n = super()._run_decode(seqs, out)
+        if captured is not None:
+            captured.append(("advanced", [
+                (s.request_id, b, s.cached_len)
+                for s, b in zip(seqs, before)]))
+        return n
 
     def capture_logits(self) -> list:
         """From now on keep every prefill's and decode's logits (the check
@@ -192,6 +208,70 @@ class ProbedEngine(InferenceEngine):
     def stop_capture(self) -> None:
         self._prefill_fn, self._decode_fn = self._plain_fns
         self.captured = None
+
+
+def kept_rows(captured: Sequence, wanted: Mapping[str, Sequence[int]]
+              ) -> Dict[str, Dict[int, np.ndarray]]:
+    """The logits the engine computed for each wanted (request, position),
+    float32, from what ``capture_logits`` kept.
+
+    The contract a program's step has to meet. ``_run_decode`` (and
+    ``_run_prefill``) is noted with each sequence's ``cached_len`` before
+    the call and after it (``"advanced"``: ``(request, before, after)`` a
+    sequence, in the order of ``seqs``). Inside the call the program
+    calls ``_decode_fn`` once, and its first result is ``[bucket, V]``
+    or ``[bucket, T, V]``. Row ``(i, j)`` belongs to ``seqs[i]`` at
+    position ``before + j``, and only the rows ``j < after - before`` are
+    kept: a step that verifies ``T`` positions a sequence and accepts
+    one or two of them advances ``cached_len`` by as many, and a sequence
+    that finished inside the step keeps what it advanced by. A whole
+    prompt's program (``_prefill_fn``, ``[bucket, V]``, one sequence) is
+    read the same way, a row a position; a chunk's is not captured, and
+    its note is passed over. Raises where a wanted position has no row
+    or two, where a call's result has fewer rows than a sequence
+    advanced by, and where ``_decode_fn`` ran twice in one call or not
+    at all."""
+    rows: Dict[str, Dict[int, np.ndarray]] = {rid: {} for rid in wanted}
+    pending = None  # (kind, logits) of the program that ran in this call
+    for kind, value, *_ in captured:
+        if kind in ("prefill", "decode"):
+            if pending is not None:
+                raise RuntimeError(f"check: two {kind} programs ran in one "
+                                   f"call of the engine's step")
+            pending = (kind, value)
+        elif kind == "advanced":
+            if pending is None:
+                if len(value) != 1:  # a chunk is one sequence's
+                    raise RuntimeError("check: a decode step ran no "
+                                       "captured program")
+                continue
+            (ran, logits), pending = pending, None
+            host = None  # the decode's logits, brought back once
+            for i, (rid, before, after) in enumerate(value):
+                for pos in wanted.get(rid, ()):
+                    if not before <= pos < after:
+                        continue
+                    if pos in rows[rid]:
+                        raise RuntimeError(
+                            f"check: two rows for position {pos} of {rid}")
+                    if ran == "prefill":
+                        row = logits[pos - before]
+                    else:
+                        if host is None:
+                            host = np.asarray(logits, np.float32)
+                            host = host[:, None] if host.ndim == 2 else host
+                        if pos - before >= host.shape[1]:
+                            raise RuntimeError(
+                                f"check: {rid} advanced by {after - before}"
+                                f" in a step of {host.shape[1]} row(s)")
+                        row = host[i, pos - before]
+                    rows[rid][pos] = np.asarray(row, np.float32)
+    missing = {rid: [p for p in wanted[rid] if p not in got]
+               for rid, got in rows.items()}
+    if any(missing.values()):
+        raise RuntimeError(f"check: no row for positions "
+                           f"{ {r: m for r, m in missing.items() if m} }")
+    return rows
 
 
 def install() -> None:

@@ -9,9 +9,11 @@ configuration is ``configs/<config>.json``, its traffic mix
 ``layer_metrics/<metric>.py``, all found by name (``byname.py``). The last line of
 standard output is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
-``--trace 1`` its per-layer metrics), ``device`` and, traced,
-``breakdown``. Off a TPU, or on a chip the peaks table does not hold, the
-command exits non-zero and prints no result.
+``--trace 1`` its per-layer metrics), ``device``, traced ``breakdown``,
+and last ``compared``: each number ``correct`` was decided from beside
+its limit, which are also the last lines of standard error. Off a TPU,
+or on a chip the peaks table does not hold, the command exits non-zero
+and prints no result.
 """
 
 from __future__ import annotations
@@ -143,6 +145,9 @@ def run_cell(benchmark: Mapping, dirs: Sequence[str], workload: str,
         result["breakdown"] = {
             "device_ops": trace_reduce.heaviest_ops(data.trace),
             "idle_gaps": trace_reduce.idle_gaps(data.trace)}
+    # Last: each number ``correct`` was decided from, ``[read, limit]``
+    # (a limit of null: read and printed, not judged).
+    result["compared"] = outcome["compared"]
     return result
 
 
@@ -158,6 +163,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     result = run_cell(benchmark, [HERE], args.workload, args.seed,
                       args.seconds, bool(args.trace))
     sys.stdout.flush()
+    for name, (read, limit) in result["compared"].items():
+        print(f"[perfbench] compared {name} {read} limit {limit}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -51,10 +51,13 @@ class Stream:
     """One request as its client saw it."""
 
     def __init__(self, index: int, prompt: List[int], new_tokens: int,
-                 due: Optional[float] = None):
+                 due: Optional[float] = None,
+                 sampling: Optional[Mapping] = None):
         self.index = index
         self.prompt = prompt
         self.new_tokens = new_tokens
+        # ``traffic.request_sampling``: nothing, or how this client samples
+        self.sampling = dict(sampling or {})
         self.due = due            # open loop: when it should have been sent
         self.sent: Optional[float] = None
         self.times: List[float] = []   # arrival of each token
@@ -68,7 +71,8 @@ class Stream:
         self.sent = clock()
         try:
             gen = handle.generate.remote_streaming(
-                self.prompt, max_new_tokens=self.new_tokens)
+                self.prompt, max_new_tokens=self.new_tokens,
+                **self.sampling)
             self.request_id = gen.request_id
             for tok in gen:
                 self.times.append(clock())
@@ -185,7 +189,8 @@ def warm(handle, mix: Mapping, seed: int, vocab: int, rows: int
     done = []
     for i, (plen, new) in enumerate(mix["warmup"]):
         s = Stream(-1 - i, traffic.prompt_tokens(seed, i, plen, vocab,
-                                                 stream=8), new)
+                                                 stream=8), new,
+                   sampling=traffic.request_sampling(mix, seed, -1 - i))
         consume_all([s], handle, COLD_REQUEST_TIMEOUT_S)
         if not s.ok(rows):
             raise RuntimeError(f"warm request {plen}+{new} failed: "
@@ -198,7 +203,28 @@ def check_logits(handle, engine, family, cfg: Mapping, mix: Mapping,
                  seed: int) -> Dict:
     """Prefill and eight decoded positions of two seeded prompts, through
     the served engine's cache, against the reference's one forward pass
-    over the same tokens and parameters. Logits, not tokens."""
+    over the same tokens and parameters. Logits, not tokens: the
+    reference is teacher-forced over the tokens each stream received, so
+    the comparison holds under a mix's own ``sampling`` too.
+
+    The rows are the engine's by (request, position)
+    (``probe.kept_rows``), whatever a step yields a sequence. Of each
+    row, its largest difference over the prompt's largest reference
+    logit; of those, the largest (``rel_err``, held to ``tolerance``),
+    the least and the median (``rows_min``, ``rows_median``). A routed
+    model's largest row is the token whose residual stream chose another
+    expert than the reference's; a fault in the precision moves every
+    row of the program it sits in. So the least is also taken a program:
+    over each prompt's last row (``prefill_rows_min``: the whole-prompt
+    program's, two rows) and over the rows after it
+    (``decode_rows_min``: the decode program's, through the cache, the
+    program the window times). A mix may hold ``decode_rows_min`` to
+    ``check.rows_tolerance``: one minimum over both programs' rows would
+    be met by a prompt's row where every decoded row is moved. The
+    prompt's rows are two, too few for a least of their own to be held
+    (a seed on which both took another expert is a sound run), and stay
+    under ``tolerance``. Without ``rows_tolerance`` nothing new is
+    judged."""
     import jax
 
     vocab = int(cfg["vocab_size"])
@@ -207,7 +233,8 @@ def check_logits(handle, engine, family, cfg: Mapping, mix: Mapping,
     positions = int(spec.get("decode_positions", 8))
     streams = [Stream(-100 - i, traffic.prompt_tokens(seed, i, n, vocab,
                                                       stream=9),
-                      positions + 1)
+                      positions + 1,
+                      sampling=traffic.request_sampling(mix, seed, -100 - i))
                for i, n in enumerate(spec["prompt_tokens"])]
     captured = engine.capture_logits()
     try:
@@ -217,24 +244,11 @@ def check_logits(handle, engine, family, cfg: Mapping, mix: Mapping,
     if not all(s.ok(held) for s in streams):
         raise RuntimeError(f"check requests failed: "
                            f"{[s.error or len(s.tokens) for s in streams]}")
-    # What the engine computed, per request, in order of position.
-    rows: Dict[str, List[np.ndarray]] = {s.request_id: [] for s in streams}
-    plen = {s.request_id: len(s.prompt) for s in streams}
-    pending_prefill = None
-    decode_ids: List[str] = []
-    for kind, value, *_ in captured:
-        if kind == "prefill_id":
-            pending_prefill = value
-        elif kind == "prefill":
-            rows[pending_prefill].append(
-                np.asarray(value[plen[pending_prefill] - 1], np.float32))
-        elif kind == "decode_ids":
-            decode_ids = value
-        elif kind == "decode":
-            got = np.asarray(value, np.float32)
-            for i, rid in enumerate(decode_ids):
-                if rid in rows and len(rows[rid]) <= positions:
-                    rows[rid].append(got[i])
+    # What the engine computed, per request and position: the prompt's
+    # last row and the ``positions`` after it.
+    rows = probe.kept_rows(captured, {
+        s.request_id: range(len(s.prompt) - 1, len(s.prompt) + positions)
+        for s in streams})
     width = max(len(s.prompt) for s in streams) + positions
     toks = np.zeros((len(streams), width), np.int32)
     for i, s in enumerate(streams):
@@ -242,18 +256,38 @@ def check_logits(handle, engine, family, cfg: Mapping, mix: Mapping,
         toks[i, :len(seq)] = seq
     ref = np.asarray(jax.jit(
         lambda p, t: family.logits(cfg, p, t))(engine.params_given, toks))
-    worst = 0.0
+    # Per program: a prompt's last row is the whole-prompt program's,
+    # the rows after it are the decode program's.
+    per_row = {"prefill": [], "decode": []}
     for i, s in enumerate(streams):
-        got = np.stack(rows[s.request_id])
         n = len(s.prompt)
+        got = np.stack([rows[s.request_id][p]
+                        for p in range(n - 1, n + positions)])
         want = ref[i, n - 1:n + positions]
         if got.shape != want.shape:
             raise RuntimeError(f"check: engine gave {got.shape} logits, "
                                f"reference {want.shape}")
-        worst = max(worst, float(np.abs(got - want).max()
-                                 / np.abs(want).max()))
-    return {"rel_err": worst, "tolerance": float(spec["tolerance"]),
-            "ok": bool(np.isfinite(worst) and worst <= spec["tolerance"])}
+        moved = np.abs(got - want).max(-1) / np.abs(want).max()
+        per_row["prefill"] += [float(x) for x in moved[:1]]
+        per_row["decode"] += [float(x) for x in moved[1:]]
+    every = np.asarray(per_row["prefill"] + per_row["decode"])
+    out = {"rel_err": float(every.max()),
+           "rows_min": float(every.min()),
+           "rows_median": float(np.median(every)),
+           "prefill_rows_min": min(per_row["prefill"]),
+           "decode_rows_min": min(per_row["decode"], default=None),
+           "per_row": per_row,
+           "tolerance": float(spec["tolerance"]),
+           "rows_tolerance": float(spec["rows_tolerance"])
+           if "rows_tolerance" in spec else None}
+    if out["rows_tolerance"] is not None and not per_row["decode"]:
+        raise ValueError("check.rows_tolerance holds the decoded rows, and "
+                         "decode_positions is 0")
+    out["ok"] = bool(
+        np.isfinite(every).all() and out["rel_err"] <= out["tolerance"]
+        and (out["rows_tolerance"] is None
+             or out["decode_rows_min"] <= out["rows_tolerance"]))
+    return out
 
 
 # ---- traffic --------------------------------------------------------------------
@@ -291,7 +325,8 @@ def run_closed(handle, engine, mix: Mapping, seed: int, vocab: int,
         for k, req in enumerate(plan[c]):
             s = Stream(req["index"], traffic.prompt_tokens(
                 seed, req["index"], req["prompt_len"], vocab),
-                req["new_tokens"])
+                req["new_tokens"], sampling=traffic.request_sampling(
+                    mix, seed, req["index"]))
             streams[c].append(s)
             mark = (lambda _s: opened.set()) \
                 if c == ref_client and k == 1 else None
@@ -343,7 +378,8 @@ def run_open(handle, engine, mix: Mapping, seed: int, vocab: int,
             break
         s = Stream(arrival["index"], traffic.prompt_tokens(
             seed, arrival["index"], arrival["prompt_len"], vocab),
-            arrival["new_tokens"], due)
+            arrival["new_tokens"], due, traffic.request_sampling(
+                mix, seed, arrival["index"]))
         streams.append(s)
         time.sleep(max(0.0, due - clock()))
         th = threading.Thread(target=s.consume, args=(handle, stop),
@@ -433,7 +469,11 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
     before = next((r for r in reversed(engine.steps) if r.end <= lo), None)
     marks["setup_s"] = lo - process_start
     log("setup", {k: round(v, 3) for k, v in marks.items()}
-        | {"programs": programs, "check_rel_err": check["rel_err"]})
+        | {"programs": programs, "check_rel_err": check["rel_err"],
+           "check_rows_min": check["rows_min"],
+           "check_rows_median": check["rows_median"],
+           "check_prefill_rows_min": check["prefill_rows_min"],
+           "check_decode_rows_min": check["decode_rows_min"]})
 
     if mix["kind"] == "open":
         judged = [s for s in streams if lo <= s.due <= hi]
@@ -450,6 +490,10 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
            "out_tokens_per_s": delivered / (hi - lo)}
     if gaps:
         e2e["itl_p95_ms"] = 1e3 * percentile(gaps, 95)
+        # The gap a stream sees as a rule: a plain step's length. The
+        # end-to-end metric of the cells whose tail and rate spread too
+        # widely from run to run to be held to a bound (PERF.md section 2).
+        e2e["itl_p50_ms"] = 1e3 * percentile(gaps, 50)
     ttft, late = (from_due(judged, what) for what in ("first_token",
                                                       "sent"))
     if ttft:
@@ -491,6 +535,9 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
         "largest_step_gap_phase": step_gap_phase,
         "longest_step_ms": longest_ms, "longest_step_phase": longest_phase,
         "median_gap_ms": 1e3 * statistics.median(gaps) if gaps else None,
+        # Here too, for the cells that report it per layer: a traced
+        # run's is the profiler's as much as the program's.
+        "p95_gap_ms": e2e.get("itl_p95_ms"),
         "first_step_index": engine.steps.index(steps[0]) if steps else None,
         "last_step_index": engine.steps.index(steps[-1]) if steps else None,
         "steps_from_open_to_first_turnover": next(
@@ -501,4 +548,14 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
     return {"data": data, "xplane": tracer.xplane() if tracer else None,
             "correct": check["ok"] and not bad and compiles == 0
             and not out["stuck_threads"],
-            "attempted": len(judged), "failed": len(bad)}
+            "attempted": len(judged), "failed": len(bad),
+            # Every number ``correct`` was decided from, beside its limit.
+            "compared": {
+                "check_rel_err": [check["rel_err"], check["tolerance"]],
+                "check_decode_rows_min": [check["decode_rows_min"],
+                                          check["rows_tolerance"]],
+                "check_prefill_rows_min": [check["prefill_rows_min"],
+                                           None],
+                "failed_requests": [len(bad), 0],
+                "compiles_in_window": [compiles, 0],
+                "stuck_threads": [len(out["stuck_threads"]), 0]}}

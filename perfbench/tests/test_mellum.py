@@ -221,8 +221,15 @@ def test_tiny_mellum_cell_end_to_end(traced, tmp_path):
     assert max(s["live_pages_full"] for s in log) \
         > 2 * max(s["live_pages_window"] for s in log)
     if not traced:
-        assert set(got) == names(bench, "end_to_end", CELL)
+        # The cell's rate and tail spread too widely on the chip for any
+        # bound: it reports the median gap end to end, them per layer.
+        assert set(got) == names(bench, "end_to_end", CELL) \
+            == {"itl_p50_ms", "setup_s"}
+        assert got["itl_p50_ms"]["value"] > 0
         return
+    assert got["itl_p95_ms.long"]["value"] \
+        >= got["engine_step_ms_p50.long"]["value"] > 0
+    assert got["out_tokens_per_s.long"]["value"] > 0
     assert "paged_attn_roofline" not in got
     # No TPU plane in a CPU trace: the two device metrics are left out,
     # the counter is a number. 2 full and 6 window layers: a sequence of
@@ -231,9 +238,9 @@ def test_tiny_mellum_cell_end_to_end(traced, tmp_path):
     assert not {"paged_attn_kinds_roofline", "paged_attn_busy_pct"} \
         & set(got)
     assert 35.0 < got["kv_resident_vs_flat_pct"]["value"] < 60.0
-    assert got["compiles_in_window"]["value"] == 0
-    assert got["preemptions"]["value"] == 0
-    assert 25.0 <= got["moe_experts_touched_pct"]["value"] <= 100.0
+    assert got["compiles_in_window.long"]["value"] == 0
+    assert got["preemptions.long"]["value"] == 0
+    assert 25.0 <= got["moe_experts_touched_pct.long"]["value"] <= 100.0
 
 
 # ---- the readers on a hand-made log and trace --------------------------------

@@ -10,6 +10,11 @@ of the exponential law, permuted the same way, so every seed offers the
 same number of requests over the same time. What the seed changes is the
 order, the token ids and the weights.
 
+A mix may say how its clients sample (``sampling``: ``temperature`` and
+``top_k``): every request then carries them and a seed of its own, made
+from the run's seed and the request's index (``request_sampling``).
+Without the field every request is greedy.
+
 A mix may fix the order too (``order_seed``): which requests share the
 batch decides the work of a step (a decode reads a page table as wide as
 the batch's longest context), so two orders of the same requests are not
@@ -72,6 +77,24 @@ def prompt_tokens(seed: int, index: int, length: int, vocab: int,
     ids = np.random.default_rng(
         [int(seed), int(stream), int(index)]).integers(1, vocab, size=length)
     return [int(t) for t in ids]
+
+
+def request_sampling(mix: Mapping, seed: int, index: int) -> Dict:
+    """What request ``index`` (negative: a warm-up's or the check's) asks
+    of the sampler beyond its length: nothing where the mix has no
+    ``sampling``; else its ``temperature`` and ``top_k`` and a ``seed``
+    drawn from the run's seed and the index, so that a request's draws
+    are its own whatever shares its batch."""
+    spec = mix.get("sampling")
+    if not spec:
+        return {}
+    unknown = set(spec) - {"temperature", "top_k"}
+    if unknown:
+        raise ValueError(f"sampling has no field {sorted(unknown)}")
+    drawn = np.random.default_rng(
+        [int(seed), 11, int(index) % 2**32]).integers(0, 2**31)
+    return {"temperature": float(spec.get("temperature", 0.0)),
+            "top_k": int(spec.get("top_k", 0)), "seed": int(drawn)}
 
 
 def closed_schedule(mix: Mapping, seed: int) -> Dict:

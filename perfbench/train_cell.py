@@ -237,4 +237,7 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds, device
     return {"data": data, "xplane": window.get("xplane"),
             "correct": bool(np.isfinite(rel) and rel <= tol and all(finite)
                             and window["retraces"] == 0),
-            "attempted": len(losses), "failed": finite.count(False)}
+            "attempted": len(losses), "failed": finite.count(False),
+            "compared": {"check_rel_err": [rel, tol],
+                         "non_finite_losses": [finite.count(False), 0],
+                         "retraces": [window["retraces"], 0]}}
