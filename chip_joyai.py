@@ -261,11 +261,12 @@ def compare(family, cfg, pcfg, params, prompts, options, engines, controls,
     return out
 
 
-def caught_by(result: dict, control: str, tolerance: float):
+def caught_by(result: dict, control: str, tolerance: float,
+              factor: float = ROWS_FACTOR):
     if result[control]["max"] > tolerance:
         return "max"
     for rows in ("min", "median"):
-        if result[control][rows] > ROWS_FACTOR * result["forced"][rows]:
+        if result[control][rows] > factor * result["forced"][rows]:
             return rows
     return None
 

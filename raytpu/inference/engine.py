@@ -38,6 +38,24 @@ Sampling runs on the device (:func:`raytpu.inference.sampling.sample`):
 a program's logits stay there and a step brings back ``int32[bucket]``
 token ids. A row's draw is keyed by its request's seed and its own
 position, so batched output == solo output.
+
+**A model that drafts for itself** (``serving.drafting``: a prediction
+module; on unless the engine is built with ``drafting=False``) decodes
+two positions a step: the token a sequence stands at and the module's
+draft for the next, verified by speculative sampling
+(:func:`raytpu.inference.sampling.speculative`), so a step yields a
+sequence one token or two and ``cached_len`` advances by as many. Three
+programs go out back to back with no host round trip between them:
+``_decode_fn`` (the model over ``[bucket, 2]`` positions; its first
+result is ``[bucket, 2, V]``), ``_accept_fn`` (accept or resample: ``int32
+[bucket, 2]`` ids come back, -1 where the draft was not kept) and
+``_draft_fn`` (the module over the kept tokens, which writes its own pool
+and leaves the next draft and its logits on the device, a row a sequence
+slot: ``_draft_state``). The prefill and chunk programs hold the module
+too and sample the first token inside, so every decoding sequence has a
+draft. The module's pool is one more full-attention pool behind the full
+layers' tables; a rejected draft's rows, there and in the model's pools,
+are written again by the next step.
 """
 
 from __future__ import annotations
@@ -54,7 +72,8 @@ import numpy as np
 
 from raytpu.inference.kv_cache import PagedKVCache
 from raytpu.inference.prefix_cache import PrefixCache
-from raytpu.inference.sampling import SamplingParams, sample
+from raytpu.inference.sampling import (SamplingParams, draft_token, sample,
+                                       speculative)
 from raytpu.inference.scheduler import Scheduler, Sequence
 from raytpu.util import compile_cache, task_events, tracing
 from raytpu.util.metrics import Counter, Gauge, Histogram
@@ -75,6 +94,10 @@ _prefill_tokens_total = Counter("raytpu_infer_prefill_tokens_total",
                                 "Prompt tokens prefilled")
 _decode_tokens_total = Counter("raytpu_infer_decode_tokens_total",
                                "Tokens decoded")
+_drafted_total = Counter("raytpu_infer_drafted_tokens_total",
+                         "Drafted tokens a decode step verified")
+_draft_accepted_total = Counter("raytpu_infer_draft_accepted_total",
+                                "Drafted tokens the verification kept")
 _ttft_hist = Histogram(
     "raytpu_infer_ttft_seconds",
     "Time from request admission to its first sampled token",
@@ -163,7 +186,7 @@ class InferenceEngine:
                  prefill_chunk: Optional[int] = None,
                  chunk_buckets: Optional[SequenceT[int]] = None,
                  enable_prefix_cache: Optional[bool] = None,
-                 tp: int = 1, mesh=None):
+                 tp: int = 1, mesh=None, drafting: Optional[bool] = None):
         import jax
 
         served = getattr(model_config, "serving", None)
@@ -178,13 +201,27 @@ class InferenceEngine:
             raise ValueError(
                 "a routed-expert model is served on one device: sharding "
                 "its expert layer (tp, ep) in the engine is not there yet")
-        self._expert_tokens = (np.zeros(served.expert_counts, np.int64)
-                               if served.expert_counts else None)
         if served.kv_row and (tp > 1 or mesh is not None):
             raise ValueError(
                 "a model of one latent pool a layer is served on one "
                 "device: every head reads the whole row, so the pool has "
                 "no head axis to shard on")
+
+        # Self-drafting: on for a family that has a prediction module,
+        # unless asked off; such an engine runs the one-position programs.
+        if drafting and served.drafting is None:
+            raise ValueError(
+                f"drafting=True: {type(model_config).__name__} has no "
+                f"prediction module to draft with (`serving.drafting`)")
+        self._drafting = served.drafting if drafting is not False else None
+        # Tokens each expert received, a row a routed layer; the module's
+        # routed layers after the model's.
+        self._expert_tokens = None
+        if served.expert_counts:
+            layers, experts = served.expert_counts
+            self._expert_tokens = np.zeros(
+                (layers + (self._drafting.pools if self._drafting else 0),
+                 experts), np.int64)
 
         self._config = model_config
         # The working copy, made once and before anything else takes
@@ -233,10 +270,14 @@ class InferenceEngine:
                 window, page_size, max_num_seqs, self.prefill_chunk)
         elif enable_prefix_cache is None:
             enable_prefix_cache = True
+        # The module's pools come after the model's: full-attention ones.
+        module_pools = self._drafting.pools if self._drafting else 0
         self.cache = PagedKVCache(
-            model_config.n_layer, num_pages, page_size, served.kv_heads,
-            served.head_dim, dtype=model_config.dtype,
-            layer_windows=served.layer_windows, window_pages=window_pages,
+            model_config.n_layer + module_pools, num_pages, page_size,
+            served.kv_heads, served.head_dim, dtype=model_config.dtype,
+            layer_windows=served.layer_windows and (
+                *served.layer_windows, *(None,) * module_pools),
+            window_pages=window_pages,
             window_burst=self.prefill_chunk, latent_row=served.kv_row)
         # Tensor parallelism: shard the weights with the parallel-layer
         # rule table and the KV pools along their last dimension, whole
@@ -290,7 +331,8 @@ class InferenceEngine:
                              if enable_prefix_cache else None)
         self.scheduler = Scheduler(self.cache, max_num_seqs=max_num_seqs,
                                    max_model_len=self.max_model_len,
-                                   prefix_cache=self.prefix_cache)
+                                   prefix_cache=self.prefix_cache,
+                                   step_positions=2 if self._drafting else 1)
         self.prefill_buckets = sorted(prefill_buckets or _pow2_buckets(
             min(16, self.max_model_len), self.max_model_len))
         # A chunk's length buckets: pinned (``chunk_buckets``: fewer
@@ -319,6 +361,8 @@ class InferenceEngine:
         self._chunk_compiles: Dict[str, int] = {}
         self._decode_compiles: Dict[str, int] = {}
         self._sample_compiles: Dict[str, int] = {}
+        self._accept_compiles: Dict[str, int] = {}
+        self._draft_compiles: Dict[str, int] = {}
         # What ``ops.grouped_matmul`` noted while a program was traced,
         # under the program's name and bucket key: how many of its
         # expert products go through the grouped kernel on these devices.
@@ -347,20 +391,48 @@ class InferenceEngine:
         compile_cache.enable()
         # One XLA program per key: a prompt's length bucket; a chunk's
         # length (a decode's batch) bucket x the trimmed table width.
-        self._prefill_fn = self._build_program(
-            jax, "_prefill", served.prefill, self._prefill_compiles,
-            lambda tokens, dests: tokens.shape[1])
-        self._chunk_fn = self._build_program(
-            jax, "_chunk", served.prefill_chunk, self._chunk_compiles,
-            lambda tokens, positions, dests, block_tables:
-            f"{tokens.shape[1]}x{_width(block_tables)}")
-        self._decode_fn = self._build_program(
-            jax, "_decode", served.decode, self._decode_compiles,
-            lambda tokens, positions, dests, block_tables, context_lens:
-            f"{tokens.shape[0]}x{_width(block_tables)}")
-        self._sample_fn = self._build_sampler(jax, self._sample_compiles)
+        if self._drafting is not None:
+            # A slot a running sequence (and one that padding rows name)
+            # in what the module leaves on the device between steps: the
+            # next draft and the logits it was drawn from.
+            self._slot_of: Dict[str, int] = {}
+            self._free_slots = list(range(max_num_seqs))
+            self._draft_state = (
+                self._put(np.zeros(max_num_seqs + 1, np.int32)),
+                self._put(np.zeros((max_num_seqs + 1,
+                                    model_config.vocab_size), np.float32)))
+            self._drafted = self._draft_accepted = 0
+            (self._prefill_fn, self._chunk_fn, self._decode_fn,
+             self._accept_fn, self._draft_fn) = self._build_drafting(
+                 jax, self._drafting)
+        else:
+            self._prefill_fn = self._build_program(
+                jax, "_prefill", served.prefill, self._prefill_compiles,
+                lambda tokens, dests: tokens.shape[1])
+            self._chunk_fn = self._build_program(
+                jax, "_chunk", served.prefill_chunk, self._chunk_compiles,
+                lambda tokens, positions, dests, block_tables:
+                f"{tokens.shape[1]}x{_width(block_tables)}")
+            self._decode_fn = self._build_program(
+                jax, "_decode", served.decode, self._decode_compiles,
+                lambda tokens, positions, dests, block_tables, context_lens:
+                f"{tokens.shape[0]}x{_width(block_tables)}")
+            self._sample_fn = self._build_sampler(
+                jax, self._sample_compiles)
 
     # ---- compiled steps (the ONLY jax.jit call sites) ---------------
+
+    @contextlib.contextmanager
+    def _traced(self, name, compiles, bucket):
+        """Around the body of a program while it is traced (trace-time
+        only): counts the XLA compile under ``compiles[bucket]`` and notes
+        the products the grouped kernel takes of this program."""
+        from raytpu.ops.grouped_matmul import kernel_calls
+
+        compiles[bucket] = compiles.get(bucket, 0) + 1
+        with kernel_calls(self._devices[0].split(":")[0]) as grouped:
+            yield
+        self._grouped_calls[name, bucket] = grouped[0]
 
     def _build_program(self, jax, name, fwd, compiles, bucket_key):
         """One of the three jitted programs, ``(params, ks, vs, *inputs)
@@ -368,19 +440,12 @@ class InferenceEngine:
         ``fwd`` on the donated pools. ``compiles`` counts its traces
         under ``bucket_key(*inputs)``; ``name`` is what a trace and the
         compile cache know the program by."""
-        from raytpu.ops.grouped_matmul import kernel_calls
-
         cfg, kv_sh = self._config, self._kv_sharding
 
         def program(params, ks, vs, *inputs):
-            # Trace-time only: counts XLA compiles per bucket, and the
-            # products the grouped kernel takes of this one.
-            bucket = bucket_key(*inputs)
-            compiles[bucket] = compiles.get(bucket, 0) + 1
-            with kernel_calls(self._devices[0].split(":")[0]) as grouped:
+            with self._traced(name, compiles, bucket_key(*inputs)):
                 logits, ks2, vs2, *experts = fwd(
                     cfg, params, *inputs, ks, vs)
-            self._grouped_calls[name, bucket] = grouped[0]
             if kv_sh is not None:
                 # Pin the pool sharding through the update: the pools
                 # must come back kv-head-sharded, never resharded.
@@ -409,6 +474,86 @@ class InferenceEngine:
 
         return jax.jit(_sample)
 
+    def _build_drafting(self, jax, drafting):
+        """The five jitted programs of a model that drafts for itself
+        (see the module's docstring), in place of the three and the
+        sampler. ``state`` is ``_draft_state``, ``(draft [slots], the
+        module's logits it was drawn from [slots, V])``, read and written
+        at a sequence's slot; the pools are donated to the three that
+        write them."""
+        cfg, jnp = self._config, self._jnp
+        traced = self._traced
+
+        def _build_prompt(name, model, module, compiles, bucket_key):
+            """A prompt's program: the model's walk, the first token
+            sampled from its row ``row`` (at ``position``) where the
+            prompt gives none to follow it (``next_tokens`` -1), the
+            module over every position beside the token that follows,
+            and the draft for ``position + 2`` left at ``slot``."""
+
+            def program(params, ks, vs, state, drafted, *inputs):
+                next_tokens, row, position, slot, *rows = drafted
+                with traced(name, compiles, bucket_key(*inputs)):
+                    logits, ks, vs, count, hidden = model(
+                        cfg, params, *inputs, ks, vs)
+                    last = logits.reshape(-1, logits.shape[-1])[row]
+                    first = sample(last[None], *rows, position[None])
+                    own, ks, vs, more = module(
+                        cfg, params, hidden,
+                        jnp.where(next_tokens < 0, first[0], next_tokens),
+                        row, *inputs[1:], ks, vs)
+                    draft = draft_token(own[None], *rows,
+                                        position[None] + 2)
+                return (logits, ks, vs,
+                        jnp.concatenate([count, more[None]]), first,
+                        (state[0].at[slot].set(draft[0]),
+                         state[1].at[slot].set(own)))
+
+            program.__name__ = name
+            return jax.jit(program, donate_argnums=_POOLS)
+
+        def _decode(params, ks, vs, state, slots, tokens, positions, dests,
+                    block_tables):
+            with traced("_decode", self._decode_compiles,
+                        f"{tokens.shape[0]}x{_width(block_tables)}"):
+                return drafting.verify(
+                    cfg, params,
+                    jnp.stack([tokens, state[0][slots]], axis=1),
+                    positions[:, None]
+                    + jnp.arange(2, dtype=positions.dtype),
+                    dests, block_tables, ks, vs)
+
+        def _accept(logits, state, slots, positions, *rows):
+            shape = "x".join(str(n) for n in logits.shape)
+            self._accept_compiles[shape] = \
+                self._accept_compiles.get(shape, 0) + 1
+            return speculative(logits, state[1][slots], state[0][slots],
+                               *rows, positions + 1)
+
+        def _draft(params, ks, vs, state, slots, hidden, ids, kept,
+                   positions, dests, block_tables, *rows):
+            with traced("_draft", self._draft_compiles,
+                        f"{ids.shape[0]}x{_width(block_tables)}"):
+                own, ks, vs, count = drafting.draft_rows(
+                    cfg, params, hidden, ids, kept - 1,
+                    positions[:, None]
+                    + jnp.arange(2, dtype=positions.dtype),
+                    dests, block_tables, ks, vs)
+                draft = draft_token(own, *rows, positions + kept + 1)
+            return ks, vs, count, (state[0].at[slots].set(draft),
+                                   state[1].at[slots].set(own))
+
+        return (
+            _build_prompt("_prefill", drafting.prefill,
+                           drafting.draft_prefill, self._prefill_compiles,
+                           lambda tokens, dests: tokens.shape[1]),
+            _build_prompt("_chunk", drafting.prefill_chunk,
+                           drafting.draft_chunk, self._chunk_compiles,
+                           lambda tokens, positions, dests, block_tables:
+                           f"{tokens.shape[1]}x{_width(block_tables)}"),
+            jax.jit(_decode, donate_argnums=_POOLS), jax.jit(_accept),
+            jax.jit(_draft, donate_argnums=_POOLS))
+
     def _sampling_rows(self, seqs: SequenceT[Sequence], bucket: int):
         """What the sampler takes of each request, a row a sequence on
         the device (``temperature``, ``top_k``, ``seed``; padding rows
@@ -431,6 +576,16 @@ class InferenceEngine:
             return self._jax.device_put(x, self._repl_sharding)
         return self._jnp.asarray(x)
 
+    def _batch_rows(self, seqs: SequenceT[Sequence], bucket: int):
+        """:meth:`_sampling_rows` of a decode's batch, put again only when
+        its membership has changed since the last step."""
+        batch, rows, stochastic = self._sampled_batch
+        if len(batch) != len(seqs) \
+                or not all(map(operator.is_, batch, seqs)):
+            rows, stochastic = self._sampling_rows(seqs, bucket)
+            self._sampled_batch = (list(seqs), rows, stochastic)
+        return rows, stochastic
+
     def _by_kind(self, of):
         """``of(kind)`` on the device, as a program takes what it is
         given a kind of pool (``dests``, block tables): the one array,
@@ -439,17 +594,19 @@ class InferenceEngine:
             return self._put(of(0))
         return tuple(self._put(of(kind)) for kind in self.cache.kinds)
 
-    def _count_experts(self, experts, program) -> None:
-        """Add what one program of a routed model returned beside its
+    def _count_experts(self, experts, *programs) -> None:
+        """Add what the programs of a routed model returned beside their
         logits (int32 ``[layers, experts]``: live tokens each expert held
-        here received) to the running total and to the open step's
-        record, and what was noted of ``program`` (its name and bucket
-        key) when it was traced: how many of its expert products go
+        here received; a verify step's model and module each give their
+        layers') to the running total and to the open step's
+        record, and what was noted of ``programs`` (name and bucket
+        key) when they were traced: how many of their expert products go
         through the grouped kernel. A dense family's programs return
         nothing: ``experts`` is empty."""
         if not experts:
             return
-        counts = np.asarray(experts[0])
+        counts = np.concatenate([np.atleast_2d(np.asarray(count))
+                                 for count in experts])
         self._expert_tokens += counts
         fields = self.recorder.open.fields
         fields["moe_assignments"] = (fields.get("moe_assignments", 0)
@@ -459,8 +616,9 @@ class InferenceEngine:
             + int(np.count_nonzero(counts)))
         fields["moe_expert_max"] = max(fields.get("moe_expert_max", 0),
                                        int(counts.max()))
-        fields["moe_grouped_calls"] = (fields.get("moe_grouped_calls", 0)
-                                       + self._grouped_calls[program])
+        fields["moe_grouped_calls"] = (
+            fields.get("moe_grouped_calls", 0)
+            + sum(self._grouped_calls[one] for one in programs))
 
     # ---- request lifecycle ------------------------------------------
 
@@ -474,7 +632,8 @@ class InferenceEngine:
             raise ValueError(
                 f"prompt length {len(prompt)} >= max_model_len "
                 f"{self.max_model_len} leaves no room to generate")
-        if self.cache.pages_for(len(prompt) + 1) > self.cache.total_pages:
+        if self.cache.pages_for(len(prompt) + self.scheduler.step_positions) \
+                > self.cache.total_pages:
             raise ValueError("prompt exceeds total KV-page capacity")
         seq = Sequence(request_id=request_id, prompt=prompt,
                        sampling=sampling)
@@ -508,13 +667,21 @@ class InferenceEngine:
                 "live_pages_window": 0, "window_pages_released": 0,
                 "pages_owned_full": 0, "pages_owned_window": 0,
                 "sampled_stochastic": 0,
-                "kv_bytes_per_token": self._kv_token_bytes}) as st:
+                "kv_bytes_per_token": self._kv_token_bytes} | (
+                    {"drafted": 0, "accepted": 0, "emitted": 0}
+                    if self._drafting else {})) as st:
             with recorder.phase("infer.schedule") as ph:
                 waiting = len(self.scheduler.waiting)
                 plan = self.scheduler.schedule()
                 ph.attrs["admitted"] = st.attrs["admitted"] = (
                     waiting + len(plan.preempted)
                     - len(self.scheduler.waiting))
+                if self._drafting:
+                    # The slots of what has finished or was preempted.
+                    running = {s.request_id for s in self.scheduler.running}
+                    for rid in [r for r in self._slot_of
+                                if r not in running]:
+                        self._free_slots.append(self._slot_of.pop(rid))
             # The mesh is thread-local state and any thread may step.
             with (self._jax.set_mesh(self.mesh) if self.mesh is not None
                   else contextlib.nullcontext()):
@@ -561,7 +728,9 @@ class InferenceEngine:
         return (sum(self._prefill_compiles.values())
                 + sum(self._chunk_compiles.values())
                 + sum(self._decode_compiles.values())
-                + sum(self._sample_compiles.values()))
+                + sum(self._sample_compiles.values())
+                + sum(self._accept_compiles.values())
+                + sum(self._draft_compiles.values()))
 
     def _run_prefill(self, seq: Sequence, out: List[StepOutput]) -> int:
         """Advance one sequence's prefill by (at most) one chunk.
@@ -652,8 +821,26 @@ class InferenceEngine:
             fn, inputs = self._chunk_fn, (
                 self._put(tokens), self._put(positions), dests, tables)
             program = ("_chunk", f"{bucket}x{p_used}")
-        logits, ks, vs, *experts = fn(
-            self._params, self.cache.k, self.cache.v, *inputs)
+        if self._drafting is None:
+            logits, ks, vs, *experts = fn(
+                self._params, self.cache.k, self.cache.v, *inputs)
+        else:
+            # The tokens that follow the rows', for the module; -1 where
+            # a fresh prompt ends and the program samples the one to come.
+            following = np.zeros((1, bucket), dtype=np.int32)
+            known = seq.tokens[start + 1:end + 1]
+            following[0, :len(known)] = known
+            following[0, len(known):take] = -1
+            if seq.request_id not in self._slot_of:
+                self._slot_of[seq.request_id] = self._free_slots.pop()
+            rows, stochastic = self._sampling_rows([seq], 1)
+            logits, ks, vs, count, first, self._draft_state = fn(
+                self._params, self.cache.k, self.cache.v, self._draft_state,
+                (self._put(following), self._put(np.int32(take - 1)),
+                 self._put(np.int32(end - 1)),
+                 self._put(np.int32(self._slot_of[seq.request_id])), *rows),
+                *inputs)
+            experts = [count]
         self.cache.k, self.cache.v = ks, vs
         self._count_experts(experts, program)
         seq.cached_len = end
@@ -666,16 +853,21 @@ class InferenceEngine:
             # samples the first new token, at that row's position. A
             # preemption-resume prefill must NOT resample — the tail
             # token was already emitted; the next decode rewrites its KV.
-            last = logits[take - 1] if whole else logits[0, take - 1]
-            rows, stochastic = self._sampling_rows([seq], 1)
-            ids = self._sample_fn(
-                last, *rows, self._put(np.array([end - 1], dtype=np.int32)))
+            if self._drafting is None:
+                last = logits[take - 1] if whole else logits[0, take - 1]
+                rows, stochastic = self._sampling_rows([seq], 1)
+                ids = self._sample_fn(last, *rows, self._put(
+                    np.array([end - 1], dtype=np.int32)))
+            else:
+                ids = first  # sampled inside the program, the same way
             self.recorder.open.fields["sampled_stochastic"] += stochastic
             self._emit(seq, int(np.asarray(ids)[0]), out)
         return take
 
     def _run_decode(self, seqs: List[Sequence],
                     out: List[StepOutput]) -> int:
+        if self._drafting is not None:
+            return self._run_verify(seqs, out)
         recorder = self.recorder
         with recorder.phase("infer.decode") as dec:
             with recorder.phase("infer.decode.launch") as launch:
@@ -739,10 +931,7 @@ class InferenceEngine:
                 # it, over the logits where they lie and the positions
                 # the decode was given; the requests' own rows are put
                 # again only when the batch's membership has changed.
-                batch, rows, stochastic = self._sampled_batch
-                if len(batch) != b or not all(map(operator.is_, batch, seqs)):
-                    rows, stochastic = self._sampling_rows(seqs, bucket)
-                    self._sampled_batch = (list(seqs), rows, stochastic)
+                rows, stochastic = self._batch_rows(seqs, bucket)
                 ids = self._sample_fn(logits, *rows, device_positions)
                 ids.copy_to_host_async()
                 fields["sampled_stochastic"] += stochastic
@@ -776,6 +965,104 @@ class InferenceEngine:
                 if self._hbm_tick % 32 == 1:
                     prof.observe_hbm()
         return b
+
+    def _run_verify(self, seqs: List[Sequence],
+                    out: List[StepOutput]) -> int:
+        """The decode step of a model that drafts for itself: two
+        positions a sequence, the token it stands at and its draft, and
+        one token or two out. Returns the tokens emitted."""
+        recorder = self.recorder
+        with recorder.phase("infer.decode") as dec:
+            with recorder.phase("infer.decode.launch"):
+                b = len(seqs)
+                bucket = _bucket_for(b, self.decode_buckets)
+                ids = [s.request_id for s in seqs]
+                P = _bucket_for(max(self.cache.num_seq_pages(r)
+                                    for r in ids), self.page_buckets)
+                tokens = np.zeros(bucket, dtype=np.int32)
+                positions = np.zeros(bucket, dtype=np.int32)
+                # Padding: the scratch page's first two slots, the
+                # scratch slot of the module's state.
+                dests = [np.tile(np.arange(2, dtype=np.int32), (bucket, 1))
+                         for _ in self.cache.kinds]
+                slots = np.full(bucket, len(self._draft_state[0]) - 1,
+                                dtype=np.int32)
+                fields = recorder.open.fields
+                live_pages = 0
+                for i, seq in enumerate(seqs):
+                    pos = seq.cached_len
+                    tokens[i] = (seq.generated or seq.prompt)[-1]
+                    positions[i] = pos
+                    slots[i] = self._slot_of[seq.request_id]
+                    live_pages += self.cache.pages_for(pos + 2)
+                    for kind in self.cache.kinds:
+                        if kind:  # the window table slid on to both
+                            fields["window_pages_released"] += \
+                                self.cache.slide(seq.request_id, pos, pos + 2)
+                            fields["live_pages_window"] += \
+                                self.cache.pages_read(pos + 1, 1)
+                        dests[kind][i] = [
+                            self.cache.slot(seq.request_id, pos + j, kind)
+                            for j in range(2)]
+                dests = self._by_kind(lambda kind: dests[kind])
+                tables = self._by_kind(lambda kind: self.cache.table_array(
+                    ids, P, batch=bucket, kind=kind))
+                if self.paged_attn_impl == "reference":
+                    self._pages_gathered += bucket * P
+                dec.attrs.update(batch=b, bucket=bucket)
+                fields.update(
+                    decodes=b, bucket=bucket, table_width=P,
+                    live_pages=live_pages, live_pages_full=live_pages,
+                    drafted=b)
+                rows, stochastic = self._batch_rows(seqs, bucket)
+                slots, positions = self._put(slots), self._put(positions)
+                state = self._draft_state
+                with recorder.phase("infer.decode.verify"):
+                    logits, ks, vs, count, hidden = self._decode_fn(
+                        self._params, self.cache.k, self.cache.v, state,
+                        slots, self._put(tokens), positions, dests, tables)
+                with recorder.phase("infer.decode.accept"):
+                    chosen, kept = self._accept_fn(
+                        logits, state, slots, positions, *rows)
+                    chosen.copy_to_host_async()
+                with recorder.phase("infer.decode.draft"):
+                    ks, vs, more, self._draft_state = self._draft_fn(
+                        self._params, ks, vs, state, slots, hidden, chosen,
+                        kept, positions, dests, tables, *rows)
+                self.cache.k, self.cache.v = ks, vs
+                for x in (count, more):
+                    x.copy_to_host_async()
+            with recorder.phase("infer.decode.wait") as wait:
+                # Every row draws twice (accept, then a token) and the
+                # module once more; counted as the sampler's rows are.
+                fields["sampled_stochastic"] += stochastic
+                if self.on_launch is not None:
+                    self.on_launch()
+                chosen = np.asarray(chosen)
+                wait.attrs["bytes"] = chosen.nbytes
+                key = f"{bucket}x{P}"
+                self._count_experts((count, more), ("_decode", key),
+                                    ("_draft", key))
+            with recorder.phase("infer.decode.sample"):
+                emitted = 0
+                for seq, (first, second) in zip(seqs, chosen.tolist()):
+                    seq.cached_len += 1
+                    self._emit(seq, first, out)
+                    emitted += 1
+                    if second < 0:
+                        continue
+                    fields["accepted"] += 1
+                    if seq.finish_reason is None:
+                        # Kept, and the sequence had room for it.
+                        seq.cached_len += 1
+                        self._emit(seq, second, out)
+                        emitted += 1
+                fields["emitted"] = emitted
+                self._drafted += b
+                self._draft_accepted += fields["accepted"]
+                _drafted_total.inc(b)
+                _draft_accepted_total.inc(fields["accepted"])
+        return emitted
 
     def _emit(self, seq: Sequence, token: int,
               out: List[StepOutput]) -> None:
@@ -867,7 +1154,16 @@ class InferenceEngine:
         sequences own in one pool of each kind when the step ends; both
         0 for a model without window layers), ``sampled_stochastic`` (rows
         whose token was drawn and not the argmax: how often the sampler's
-        stochastic branch had work), ``admitted``, ``compiled``
+        stochastic branch had work), for a model that drafts for itself
+        ``drafted`` (drafts the decode verified: its sequences),
+        ``accepted`` (drafts the verification kept) and ``emitted``
+        (tokens the decode gave out: a sequence that finished on its
+        first gives no second) and, inside ``infer.decode.launch``, the
+        phases ``infer.decode.verify``, ``.accept`` and ``.draft`` (the
+        three programs' launches; ``live_pages`` and ``live_pages_full``
+        then count the pages of both positions' context in one full
+        layer, and the module's pool is one more such layer),
+        ``admitted``, ``compiled``
         (programs traced in it, the sampler's among them),
         ``preempted``, ``prefills`` (``request_id``, ``tokens``,
         ``bucket``, ``waited_s`` each) and ``error`` if it raised. A
@@ -901,6 +1197,14 @@ class InferenceEngine:
                                 in self._decode_compiles.items()},
             # The sampler's, by the shape of the logits it was given.
             "sample_compiles": dict(self._sample_compiles),
+            # Of a model that drafts for itself: the accept/resample
+            # program's and the module's, and the drafts verified so far
+            # and kept (None: no drafting).
+            "accept_compiles": dict(self._accept_compiles),
+            "draft_compiles": dict(self._draft_compiles),
+            "drafted_tokens": self._drafted if self._drafting else None,
+            "draft_accepted": (self._draft_accepted if self._drafting
+                               else None),
             # Of the steps the recorder's ring still holds.
             "decode_batch_hist": self.recorder.values("decodes"),
             # Block-table columns handed to the reference gather (each

@@ -67,24 +67,117 @@ def sample(logits, temperature, top_k, seed, position):
     thresholds serves no greedy row, so it sits behind a ``cond`` on
     whether the batch holds a stochastic one."""
     import jax
+
+    return _drawn(logits, temperature, top_k, lambda: jax.vmap(
+        lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
+            seed, position))
+
+
+def _drawn(logits, temperature, top_k, keys):
+    """``sample``'s rule with the stochastic rows' keys from ``keys()``
+    (called inside the ``cond``: a greedy batch makes none)."""
+    import jax
     import jax.numpy as jnp
 
-    vocab = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     stochastic = temperature > 0.0
 
     def draw():
-        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-        kept = jnp.where((top_k > 0) & (top_k < vocab), top_k, vocab)
-        order = _ordered_bits(scaled)
-        scaled = jnp.where(order >= _kth_largest(order, kept)[:, None],
-                           scaled, -jnp.inf)
-        keys = jax.vmap(lambda s, p: jax.random.fold_in(
-            jax.random.key(s), p))(seed, position)
-        drawn = jax.vmap(jax.random.categorical)(keys, scaled)
+        scaled = _shaped(logits, temperature, top_k)
+        drawn = jax.vmap(jax.random.categorical)(keys(), scaled)
         return jnp.where(stochastic, drawn.astype(jnp.int32), greedy)
 
     return jax.lax.cond(jnp.any(stochastic), draw, lambda: greedy)
+
+
+def _shaped(logits, temperature, top_k):
+    """``logits`` [B, V] as a stochastic row is drawn from: over its
+    temperature, ``-inf`` below its own ``top_k``-th value."""
+    import jax.numpy as jnp
+
+    vocab = logits.shape[-1]
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    kept = jnp.where((top_k > 0) & (top_k < vocab), top_k, vocab)
+    order = _ordered_bits(scaled)
+    return jnp.where(order >= _kth_largest(order, kept)[:, None],
+                     scaled, -jnp.inf)
+
+
+# What a draw of speculative sampling is for. Its key is the request's
+# seed, the position the token would fill, and one of these: the three
+# draws at one position are independent, and none depends on the row,
+# the step or what shares the batch. (``sample`` folds in no tag and the
+# position of the row it reads, one before the one it fills.)
+ACCEPT, DRAW, DRAFT = 1, 2, 3
+
+
+def _keys(seed, position, tag: int):
+    import jax
+
+    return jax.vmap(lambda s, p: jax.random.fold_in(jax.random.fold_in(
+        jax.random.key(s), p), tag))(seed, position)
+
+
+def draft_token(logits, temperature, top_k, seed, position):
+    """The draft for ``position`` [B] from the drafter's ``logits``
+    [B, V]: their first maximum for a greedy row, else a draw from them
+    as ``sample`` shapes them (temperature, ``top_k``), keyed ``DRAFT``."""
+    return _drawn(logits, temperature, top_k,
+                  lambda: _keys(seed, position, DRAFT))
+
+
+def speculative(logits, draft_logits, draft, temperature, top_k, seed,
+                position):
+    """One draft a sequence verified (Leviathan et al. 2023; Chen et al.
+    2023): ``(logits [B, 2, V] f32, draft_logits [B, V] f32, draft [B]
+    i32, temperature, top_k, seed, position [B]) -> (ids [B, 2] i32, kept
+    [B] i32)``. ``logits[:, 0]`` are the verifier's for the position the
+    draft would fill, ``position``, and ``logits[:, 1]`` for the one after
+    it, given the draft; ``draft`` was drawn from ``draft_logits`` by
+    :func:`draft_token`. ``p`` and ``q`` are the softmaxes of the two as
+    ``sample`` shapes them.
+
+    A greedy row keeps the draft iff it is ``argmax p``, and its ids are
+    the two argmaxes. Any other row keeps it with probability ``min(1,
+    p(d) / q(d))`` (keyed ``ACCEPT``); kept, the ids are the draft and a
+    draw from ``logits[:, 1]`` for ``position + 1``; not kept, a draw
+    from ``max(0, p - q)`` renormalised (both keyed ``DRAW``), which
+    makes the first id's distribution exactly ``p`` whatever ``q`` is.
+    ``kept`` is 2 or 1, and ``ids[:, 1]`` is -1 where it is 1."""
+    import jax
+    import jax.numpy as jnp
+
+    b, t, vocab = logits.shape
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    stochastic = temperature > 0.0
+
+    def exact():
+        return greedy[:, 0], greedy[:, 1], draft == greedy[:, 0]
+
+    def drawn():
+        shaped = _shaped(logits.reshape(b * t, vocab),
+                         jnp.repeat(temperature, t),
+                         jnp.repeat(top_k, t)).reshape(b, t, vocab)
+        p = jax.nn.softmax(shaped[:, 0], axis=-1)
+        q = jax.nn.softmax(_shaped(draft_logits, temperature, top_k),
+                           axis=-1)
+        at = draft[:, None]
+        u = jax.vmap(jax.random.uniform)(_keys(seed, position, ACCEPT))
+        # Strictly: a token the verifier gives no mass is never kept.
+        ok = u * jnp.take_along_axis(q, at, 1)[:, 0] \
+            < jnp.take_along_axis(p, at, 1)[:, 0]
+        other = jax.vmap(jax.random.categorical)(
+            _keys(seed, position, DRAW), jnp.log(jnp.maximum(p - q, 0.0)))
+        after = jax.vmap(jax.random.categorical)(
+            _keys(seed, position + 1, DRAW), shaped[:, 1])
+        first = jnp.where(ok, draft, other.astype(jnp.int32))
+        return (jnp.where(stochastic, first, greedy[:, 0]),
+                jnp.where(stochastic, after.astype(jnp.int32), greedy[:, 1]),
+                jnp.where(stochastic, ok, draft == greedy[:, 0]))
+
+    first, second, ok = jax.lax.cond(jnp.any(stochastic), drawn, exact)
+    return (jnp.stack([first, jnp.where(ok, second, -1)], axis=1),
+            1 + ok.astype(jnp.int32))
 
 
 def _ordered_bits(x):

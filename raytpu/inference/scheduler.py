@@ -87,10 +87,17 @@ class ScheduleOutput:
 
 class Scheduler:
     def __init__(self, cache: PagedKVCache, max_num_seqs: int = 8,
-                 max_model_len: int = 2048, prefix_cache=None):
+                 max_model_len: int = 2048, prefix_cache=None,
+                 step_positions: int = 1):
         self.cache = cache
         self.max_num_seqs = max_num_seqs
         self.max_model_len = max_model_len
+        # Positions a decode step writes for a sequence: 1, or 2 where a
+        # step verifies a draft beside the token it stands at. Each has
+        # its slot secured before the step, kept or not. (A running
+        # sequence has fewer than ``max_model_len`` tokens, so the
+        # draft's position lies inside it.)
+        self.step_positions = step_positions
         # Optional raytpu.inference.prefix_cache.PrefixCache: admission
         # then grafts cached prompt pages instead of allocating them.
         self.prefix_cache = prefix_cache
@@ -154,8 +161,8 @@ class Scheduler:
     def schedule(self) -> ScheduleOutput:
         preempted: List[Sequence] = []
 
-        # 1) Secure a KV slot for every DECODING sequence's next token,
-        #    oldest first. Under page pressure evict the youngest
+        # 1) Secure a KV slot for every DECODING sequence's next token
+        #    (and for its draft's, ``step_positions``), oldest first. Under page pressure evict the youngest
         #    running sequence; if a sequence must evict itself, it just
         #    waits (it's already the lowest-priority survivor).
         #    Sequences still mid-prefill (chunked) skip this: their
@@ -165,7 +172,8 @@ class Scheduler:
                 continue  # preempted by an earlier turn of this loop
             if seq.cached_len < seq.prefill_len:
                 continue  # mid-prefill: allocation covers prefill_len
-            while not self.cache.extend(seq.request_id, seq.cached_len + 1):
+            while not self.cache.extend(
+                    seq.request_id, seq.cached_len + self.step_positions):
                 victim = max(self.running, key=lambda s: s.arrival)
                 self._preempt(victim)
                 preempted.append(victim)

@@ -38,7 +38,11 @@ Families (``model=``): "llama", "gpt2", "mixtral", "olmoe", "mellum"
 no role) and "joyai" (latent attention: one latent pool a layer read
 through the absorbed kernel, a leading dense layer, sigmoid-routed
 experts of which ``experts_held`` may be a share, a shared expert; the
-prefix cache works over its pages, a role is refused). Each reaches the
+prefix cache works over its pages, a role is refused) and "exaone_moe"
+(window layers among rope-less full ones, a sigmoid-routed expert layer
+and a prediction module through which the model drafts for itself: a
+decode step yields a sequence one token or two; as "mellum", no prefix
+cache and no role). Each reaches the
 engine through its config's ``serving`` and nothing else.
 """
 
@@ -158,10 +162,11 @@ class LLMDeployment:
     """Serve a decoder LM with continuous batching + streaming tokens.
 
     Args:
-        model: "llama", "gpt2", "mixtral", "olmoe", "mellum" or "joyai".
+        model: "llama", "gpt2", "mixtral", "olmoe", "mellum", "joyai" or
+            "exaone_moe".
         model_config: the family's config (``LlamaConfig``,
             ``GPT2Config``, ``MixtralConfig``, ``OlmoeConfig``,
-            ``MellumConfig``, ``JoyAIConfig``) or a
+            ``MellumConfig``, ``JoyAIConfig``, ``ExaoneMoeConfig``) or a
             kwargs dict for one. Defaults to the family's ``tiny()``
             config in fp32/reference-attention mode (CPU-runnable).
         engine_options: kwargs forwarded to :class:`InferenceEngine`
@@ -198,18 +203,19 @@ class LLMDeployment:
             from raytpu.models.gpt2 import GPT2, GPT2Config, init_params
 
             cfg_cls, model_cls, init = GPT2Config, GPT2, init_params
-        elif model in ("mixtral", "olmoe", "mellum", "joyai"):
+        elif model in ("mixtral", "olmoe", "mellum", "joyai", "exaone_moe"):
             from raytpu.models import mixtral
 
             cfg_cls = {"mixtral": mixtral.MixtralConfig,
                        "olmoe": mixtral.OlmoeConfig,
                        "mellum": mixtral.MellumConfig,
-                       "joyai": mixtral.JoyAIConfig}[model]
+                       "joyai": mixtral.JoyAIConfig,
+                       "exaone_moe": mixtral.ExaoneMoeConfig}[model]
             model_cls, init = mixtral.Mixtral, mixtral.init_params
         else:
             raise ValueError(f"unknown model family: {model!r}; known: "
                              f"'llama', 'gpt2', 'mixtral', 'olmoe', "
-                             f"'mellum', 'joyai'")
+                             f"'mellum', 'joyai', 'exaone_moe'")
         if model_config is None:
             model_config = dataclasses.replace(
                 cfg_cls.tiny(), dtype=jnp.float32, attn_impl="reference",

@@ -415,6 +415,38 @@ class Serving:
     expert_counts: Optional[Tuple[int, int]] = None  # (layers, experts)
     layer_windows: Tuple[Optional[int], ...] = ()
     kv_row: Optional[int] = None
+    # Not None: the family drafts for itself (a prediction module), and
+    # an engine built with drafting on runs these and not the three above.
+    drafting: Optional["Drafting"] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Drafting:
+    """How a family with a prediction module is served when it drafts for
+    itself: a decode step verifies one drafted token beside the last
+    emitted one and yields one or two.
+
+    The model's three walks, as :class:`Serving`'s but for one more value
+    at the end, the residual stream before the final norm (the module's
+    input), and ``verify`` in place of ``decode``: (tokens [B, 2],
+    positions [B, 2], dests [B, 2] a kind, block_tables) -> logits
+    [B, 2, V]. The module's three take ``(config, params, hidden,
+    next_tokens, row, *the walk's cache inputs, k_caches, v_caches)``:
+    the model's ``hidden`` of every position beside the token that
+    follows it, and return ``(the module's logits of row ``row`` (a
+    prompt's, [V]) or of row ``row[b]`` of each sequence ([B, V]),
+    k_caches, v_caches, the module's expert count)``. The module's
+    ``pools`` full-attention pools follow the model's in ``k_caches`` and
+    ``v_caches``, behind the full layers' tables and dests; its expert
+    counts follow the model's."""
+
+    prefill: Callable
+    prefill_chunk: Callable
+    verify: Callable
+    draft_prefill: Callable
+    draft_chunk: Callable
+    draft_rows: Callable
+    pools: int = 1
 
 
 def write_prompt_rows(k_caches, v_caches, dests, ks, vs):

@@ -79,6 +79,12 @@ class LlamaConfig:
     # RMSNorm over the whole q and the whole k projection, before the
     # heads are split and roped (OLMoE).
     qk_norm: bool = False
+    # RMSNorm over each head's ``head_dim`` values of q and of k, one
+    # scale vector for q and one for k a layer, before rope (EXAONE).
+    qk_head_norm: bool = False
+    # The kinds of layer whose q and k are roped; None is every kind. A
+    # kind left out sees no positions at all (EXAONE's full layers).
+    rope_kinds: Optional[Tuple[str, ...]] = None
     dtype: Any = jnp.bfloat16
     # The type the matrices and the embedding are *held* in. The norms'
     # scales (and a routed layer's router) stay float32 whatever it is.
@@ -123,7 +129,10 @@ class LlamaConfig:
         return self.layer_types[i] if self.layer_types else FULL
 
     def rope_of(self, kind: str):
-        """What :func:`rope_tables` takes for a layer of ``kind``."""
+        """What :func:`rope_tables` takes for a layer of ``kind``; None
+        for a kind that is not roped (``rope_kinds``)."""
+        if self.rope_kinds is not None and kind not in self.rope_kinds:
+            return None
         own = self.window_rope if kind == WINDOW else self.full_rope
         return own if own is not None else self.rope_theta
 
@@ -263,7 +272,7 @@ class LlamaAttention(nn.Module):
         self.k_proj = dense(c.n_kv_head * c.head_dim)
         self.v_proj = dense(c.n_kv_head * c.head_dim)
         self.o_proj = dense(c.n_embd)
-        if c.qk_norm:
+        if c.qk_norm or c.qk_head_norm:
             self.q_norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
             self.k_norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
 
@@ -272,11 +281,27 @@ class LlamaAttention(nn.Module):
 
     def _qkv(self, x):
         """The three projections of ``x`` [..., E], q and k normed over
-        their whole width where the config says so; heads not yet split."""
+        their whole width or over each head's where the config says so;
+        heads not yet split."""
+        c = self.config
         q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
-        if self.config.qk_norm:
+        if c.qk_norm:
             q, k = self.q_norm(q), self.k_norm(k)
+        if c.qk_head_norm:
+            q = self.q_norm(q.reshape(*q.shape[:-1], c.n_head,
+                                      c.head_dim)).reshape(q.shape)
+            k = self.k_norm(k.reshape(*k.shape[:-1], c.n_kv_head,
+                                      c.head_dim)).reshape(k.shape)
         return q, k, v
+
+    def _roped(self, q, k, positions, rotate):
+        """q and k rotated by ``rotate`` at ``positions`` [T], or as they
+        are in a layer whose kind is not roped."""
+        rope = self.config.rope_of(self.kind)
+        if rope is None:
+            return q, k
+        cos, sin = rope_tables(self.config.head_dim, positions, rope)
+        return rotate(q, cos, sin), rotate(k, cos, sin)
 
     def prefill(self, x):
         """Full-sequence attention over ``x`` [B, T, E]; returns
@@ -290,9 +315,7 @@ class LlamaAttention(nn.Module):
         q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
-        cos, sin = rope_tables(d, jnp.arange(t), c.rope_of(self.kind))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q, k = self._roped(q, k, jnp.arange(t), apply_rope)
         k_cache = k.transpose(0, 2, 1, 3)
         v_cache = v.transpose(0, 2, 1, 3)
         if kv != h:
@@ -328,9 +351,7 @@ class LlamaAttention(nn.Module):
         q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
-        cos, sin = rope_tables(d, positions, c.rope_of(self.kind))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q, k = self._roped(q, k, positions, apply_rope)
         # Rows as the pools hold them: a token's heads side by side.
         k_cache = k.transpose(0, 2, 1, 3).reshape(t, kv * d)
         v_cache = v.transpose(0, 2, 1, 3).reshape(t, kv * d)
@@ -368,9 +389,7 @@ class LlamaAttention(nn.Module):
         h, kv, d = c.n_head, c.n_kv_head, c.head_dim
         q, k, v = self._qkv(x)
         q, k, v = q.reshape(b, h, d), k.reshape(b, kv, d), v.reshape(b, kv, d)
-        cos, sin = rope_tables(d, positions, c.rope_of(self.kind))
-        q = apply_rope_single(q, cos, sin)
-        k = apply_rope_single(k, cos, sin)
+        q, k = self._roped(q, k, positions, apply_rope_single)
         from raytpu.ops.paged_attention import (paged_attention,
                                                 scatter_kv_slots)
 
@@ -382,6 +401,32 @@ class LlamaAttention(nn.Module):
                             force=c.paged_attn, window=self.window)
         y = o[:, 0].reshape(b, h * d)
         return self.o_proj(y), k_pages, v_pages
+
+    def decode_rows(self, x, k_pages, v_pages, dests, block_tables,
+                    positions):
+        """``T`` consecutive positions a sequence against the paged cache:
+        a step that verifies a draft. ``x`` [B, T, E]; ``dests`` and
+        ``positions`` [B, T] (a sequence's positions rise by one);
+        ``block_tables`` [B, P]. All of a sequence's rows are written
+        before any attends, so row ``j`` sees rows ``<= j`` of its own
+        step. Returns ``(out [B, T, E], k_pages', v_pages')``."""
+        c = self.config
+        b, t, _ = x.shape
+        h, kv, d = c.n_head, c.n_kv_head, c.head_dim
+        q, k, v = self._qkv(x)
+        q, k = self._roped(q.reshape(b * t, h, d), k.reshape(b * t, kv, d),
+                           positions.reshape(b * t), apply_rope_single)
+        from raytpu.ops.paged_attention import (paged_attention,
+                                                scatter_kv_slots)
+
+        k_pages = scatter_kv_slots(k_pages, dests.reshape(b * t),
+                                   k.reshape(b * t, kv * d))
+        v_pages = scatter_kv_slots(v_pages, dests.reshape(b * t),
+                                   v.reshape(b * t, kv * d))
+        o = paged_attention(q.reshape(b, t, h, d), k_pages, v_pages,
+                            block_tables, positions, force=c.paged_attn,
+                            window=self.window)
+        return self.o_proj(o.reshape(b, t, h * d)), k_pages, v_pages
 
 
 class LlamaMLP(nn.Module):
@@ -563,7 +608,8 @@ def live_rows(dests, k_cache):
     return of_kind(dests, FULL) >= k_cache.shape[1]
 
 
-def _serve(c: LlamaConfig, params, x, live, method: str, cache_args):
+def _serve(c: LlamaConfig, params, x, live, method: str, cache_args,
+           hidden: bool = False):
     """The serving walk, written once: the blocks over the embedded
     ``x``, the final norm and the head. Layer ``i`` attends through
     ``c.attention(kind).<method>(h, *cache_args(i))``, which returns its
@@ -575,7 +621,9 @@ def _serve(c: LlamaConfig, params, x, live, method: str, cache_args):
     layers, experts held]`` count of tokens each expert received. Where
     the layers are of two kinds each attends under
     ``jax.named_scope("attn.full")`` or ``("attn.window")``; a latent
-    layer under ``("attn.mla")``."""
+    layer under ``("attn.mla")``. With ``hidden`` a last value more: the
+    residual stream after the last block, before the final norm, which a
+    prediction module reads (:func:`raytpu.models.mixtral.draft_rows`)."""
     attn = {kind: c.attention(kind) for kind in KINDS}
     norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
     ks, vs, routed = [], [], []
@@ -595,11 +643,12 @@ def _serve(c: LlamaConfig, params, x, live, method: str, cache_args):
         if counts is not None:
             routed.append(counts)
         x = x + y
+    last = (x,) if hidden else ()
     x = norm.apply({"params": params["final_norm"]}, x)
     logits = _lm_logits(c, params, x)
     if not routed:
-        return logits, ks, vs
-    return logits, ks, vs, jnp.stack(routed)
+        return (logits, ks, vs, *last)
+    return (logits, ks, vs, jnp.stack(routed), *last)
 
 
 def _pools(k_caches, v_caches, i: int):

@@ -30,19 +30,28 @@ there), its layers unrolled because they are not all alike.
 (:mod:`raytpu.models.mla`) behind one pool a layer, sigmoid routing with
 a correction bias, a shared expert, a leading dense layer, and
 optionally a share of the experts held (``experts_held``).
+:class:`ExaoneMoeConfig` is K-EXAONE-236B-A23B's: window layers of 128
+positions, roped, among full ones that see no positions, a norm over each
+head of q and of k, the same sigmoid-routed layer, and one
+multi-token-prediction module (``mtp_layers``, :class:`PredictionModule`)
+through which the model drafts for itself when it is served
+(:func:`mtp_prefill` and its siblings; ``Serving.drafting``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from raytpu.models.gpt2 import Drafting, write_prompt_rows
 from raytpu.models.llama import (FULL, WINDOW, LlamaConfig, LlamaMLP,
-                                 RMSNorm, Rope)
+                                 RMSNorm, Rope, _lm_logits, _serve,
+                                 live_rows, of_kind)
 from raytpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 
 
@@ -76,6 +85,10 @@ class MixtralConfig(LlamaConfig):
     # all of them; a pair whose expert is not held is a dead row, and the
     # layer returns the held experts' part (plus the shared expert).
     experts_held: Optional[Tuple[int, int]] = None
+    # Multi-token-prediction modules after the last layer (DeepSeek-V3's
+    # form, :class:`PredictionModule`); 0 or 1. A family that drafts
+    # through it says so in its ``serving`` (:class:`ExaoneMoeConfig`).
+    mtp_layers: int = 0
 
     def __post_init__(self):
         super().__post_init__()
@@ -91,6 +104,9 @@ class MixtralConfig(LlamaConfig):
                     f"the router's {self.n_expert} experts")
         if self.first_dense and not self.dense_inter:
             raise ValueError("leading dense layers need `dense_inter`")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError("one prediction module at most: a step drafts "
+                             "one token")
 
     @property
     def n_expert_held(self) -> int:
@@ -250,6 +266,71 @@ class JoyAIConfig(MixtralConfig):
                    qk_rope_dim=8, v_head_dim=16)
 
 
+@dataclasses.dataclass(frozen=True)
+class ExaoneMoeConfig(MixtralConfig):
+    """K-EXAONE-236B-A23B (``LGAI-EXAONE/K-EXAONE-236B-A23B``,
+    ``model_type: exaone_moe``) as published: 64 query heads on 8 kv
+    heads of 128 over a hidden size of 6,144, three window layers of 128
+    positions to every full one, rope at theta 1e6 on the window layers
+    and none on the full ones, a norm over each head of q and of k;
+    layer 0 a dense SwiGLU of 18,432, the others 128 routed experts of
+    2,048 (``n_inter``) of which a token takes 8 by sigmoid score + a
+    correction bias, weights normalised and multiplied by 2.5, beside one
+    shared expert; one prediction module. ``layer_types`` is the S S S F
+    pattern cut to ``n_layer``; layers are held one tree each."""
+
+    vocab_size: int = 153600
+    block_size: int = 262144
+    n_layer: int = 48
+    n_head: int = 64
+    n_kv_head: int = 8
+    n_embd: int = 6144
+    head_dim: int = 128
+    n_inter: int = 2048
+    n_expert: int = 128
+    n_expert_per_tok: int = 8
+    norm_topk_prob: bool = True
+    norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    window: int = 128
+    qk_head_norm: bool = True
+    rope_kinds: Optional[Tuple[str, ...]] = (WINDOW,)
+    scoring: str = "sigmoid"
+    choice_bias: float = 0.0
+    routed_scale: float = 2.5
+    n_shared: int = 1
+    first_dense: int = 1
+    dense_inter: int = 18432
+    mtp_layers: int = 1
+    scan_layers: bool = False
+
+    def __post_init__(self):
+        types = self.layer_types or tuple(
+            FULL if i % 4 == 3 else WINDOW for i in range(self.n_layer))
+        object.__setattr__(self, "layer_types",
+                           tuple(types)[:self.n_layer])
+        super().__post_init__()
+
+    @property
+    def serving(self):
+        served = super().serving
+        if not self.mtp_layers:
+            return served
+        return dataclasses.replace(served, drafting=Drafting(
+            mtp_prefill, mtp_prefill_chunk, mtp_verify, draft_prefill,
+            draft_chunk, draft_rows, pools=self.mtp_layers))
+
+    @classmethod
+    def tiny(cls) -> "ExaoneMoeConfig":
+        """The dense layer, one period after it and the module at toy
+        widths: window 8, four query heads a kv head, and of 8 experts a
+        token takes 2."""
+        return cls(vocab_size=512, block_size=256, n_layer=5, n_head=8,
+                   n_kv_head=2, n_embd=64, head_dim=16, n_inter=32,
+                   n_expert=8, n_expert_per_tok=2, dense_inter=96,
+                   window=8, rope_theta=10000.0)
+
+
 class MoEFFN(nn.Module):
     """Top-k routed SwiGLU experts, dropless.
 
@@ -371,6 +452,35 @@ class MixtralBlock(nn.Module):
         return x + y
 
 
+class PredictionModule(nn.Module):
+    """One multi-token-prediction module in DeepSeek-V3's form
+    (arXiv:2412.19437, section 2.2), whose parameter names the published
+    configs use. For position ``i`` it takes the model's residual stream
+    after its last block, ``hidden`` (before the final norm), and the
+    embedding of the token that follows, ``next_emb``: ``u = W_eh
+    [RMSNorm_e(next_emb) ; RMSNorm_h(hidden)]`` (twice the width to
+    once), one block of the model's routed shape with full attention
+    over all ``u`` (keys and values of its own), and a final norm of its
+    own. The model's head over the result is the logits of the token
+    after the next. This is its whole-sequence form, which makes its
+    parameters; a served model runs it through :func:`draft_rows` and its
+    siblings. (Its training loss is not built: :class:`Mixtral` returns
+    the model's own logits.)"""
+
+    config: MixtralConfig
+
+    @nn.compact
+    def __call__(self, hidden, next_emb):
+        c = self.config
+        norm = functools.partial(RMSNorm, dtype=c.dtype, eps=c.norm_eps)
+        u = jnp.concatenate([norm(name="enorm")(next_emb),
+                             norm(name="hnorm")(hidden)], axis=-1)
+        u = nn.Dense(c.n_embd, use_bias=False, dtype=c.dtype,
+                     param_dtype=c.param_dtype, name="eh_proj")(u)
+        u = MixtralBlock(c, FULL, None, name="block")(u)
+        return norm(name="final_norm")(u)
+
+
 class Mixtral(nn.Module):
     config: MixtralConfig
 
@@ -399,6 +509,12 @@ class Mixtral(nn.Module):
             for i in range(c.n_layer):
                 x = block(c, c.layer_kind(i), c.ffn_width(i),
                           name=f"layers_{i}")(x)
+        if c.mtp_layers and self.is_initializing():
+            # The module's parameters are made with the model's; the
+            # training forward does not read them.
+            PredictionModule(c, name="mtp")(
+                x, self.variables["params"]["embed_tokens"]["embedding"]
+                .astype(c.dtype)[jnp.roll(tokens, -1, axis=-1)])
         x = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="final_norm")(x)
         if return_hidden:
             return x
@@ -441,8 +557,150 @@ def init_params(model: Mixtral, config: MixtralConfig, seed: int = 0,
         jax.random.PRNGKey(seed))
 
 
+# ---------------------------------------------------------------------------
+# Serving a model that drafts for itself (``Serving.drafting``): the
+# model's three walks, which also give the residual stream the module
+# reads, and the module's three. The module's pool is the last of
+# ``k_caches`` / ``v_caches``, behind the full layers' tables and dests.
+# ---------------------------------------------------------------------------
+
+
+def _embedded(c: MixtralConfig, params, tokens):
+    return params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
+
+
+def mtp_prefill(config, params, tokens, dests, k_caches, v_caches):
+    """:func:`raytpu.models.llama.llama_prefill` over the model's pools,
+    the module's handed on as they are, with the residual stream [1, T, E]
+    as the last value."""
+    c, n = config, config.n_layer
+    live = live_rows(dests, k_caches[0])[None]
+    logits, ks, vs, count, hidden = _serve(
+        c, params, _embedded(c, params, tokens), live, "prefill",
+        lambda i: (), hidden=True)
+    per_layer = [of_kind(dests, c.layer_kind(i)) for i in range(n)]
+    ks, vs = write_prompt_rows(k_caches[:n], v_caches[:n], per_layer, ks, vs)
+    return logits[0], ks + k_caches[n:], vs + v_caches[n:], count, hidden
+
+
+def _model_rows(c: MixtralConfig, params, tokens, live, method: str,
+                positions, dests, block_tables, k_caches, v_caches):
+    """The model's walk against its pools through the attention's
+    ``method``, the module's pools handed on as they are, with the
+    residual stream as the last value."""
+    logits, ks, vs, count, hidden = _serve(
+        c, params, _embedded(c, params, tokens), live, method,
+        lambda i: (k_caches[i], v_caches[i],
+                   of_kind(dests, c.layer_kind(i)),
+                   of_kind(block_tables, c.layer_kind(i)), positions),
+        hidden=True)
+    return (logits, ks + k_caches[c.n_layer:], vs + v_caches[c.n_layer:],
+            count, hidden)
+
+
+def mtp_prefill_chunk(config, params, tokens, positions, dests,
+                      block_tables, k_caches, v_caches):
+    """:func:`raytpu.models.llama.llama_prefill_chunk`, likewise."""
+    return _model_rows(
+        config, params, tokens, live_rows(dests, k_caches[0])[None],
+        "prefill_chunk", positions, dests, block_tables, k_caches, v_caches)
+
+
+def mtp_verify(config, params, tokens, positions, dests, block_tables,
+               k_caches, v_caches):
+    """A decode step of two positions a sequence: ``tokens`` [B, 2], the
+    last emitted one and the draft, at ``positions`` [B, 2] -> (fp32
+    logits [B, 2, V], k_caches, v_caches, count, the residual stream
+    [B, 2, E]). ``dests`` [B, 2] a kind; padding rows name the scratch
+    page. See :meth:`LlamaAttention.decode_rows`."""
+    return _model_rows(
+        config, params, tokens, live_rows(dests, k_caches[0]),
+        "decode_rows", positions, dests, block_tables, k_caches, v_caches)
+
+
+def _module(c: MixtralConfig, params, hidden, next_tokens, live,
+            method: str, cache_args):
+    """:class:`PredictionModule` over ``hidden`` [..., E] and the tokens
+    that follow, attending through ``c.attention(FULL).<method>(h,
+    *cache_args)`` as a block of :func:`_serve` does, under
+    ``jax.named_scope("attn.mtp")``. Returns the module's normed output,
+    what its attention returned of K and V, and its expert count."""
+    mp = params["mtp"]
+    norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
+
+    def normed(name, x, of=mp):
+        return norm.apply({"params": of[name]}, x)
+
+    u = jnp.concatenate(
+        [normed("enorm", _embedded(c, params, jnp.maximum(next_tokens, 0))),
+         normed("hnorm", hidden)], axis=-1)
+    u = jnp.dot(u, mp["eh_proj"]["kernel"].astype(c.dtype))
+    bp = mp["block"]
+    with jax.named_scope("attn.mtp"):
+        y, k, v = c.attention(FULL).apply(
+            {"params": bp["attn"]}, normed("input_norm", u, bp),
+            *cache_args, method=method)
+    u = u + y
+    y, count = MoEFFN(c).apply({"params": bp["moe"]},
+                               normed("post_attn_norm", u, bp), live)
+    return normed("final_norm", u + y), k, v, count
+
+
+def draft_prefill(config, params, hidden, next_tokens, row, dests,
+                  k_caches, v_caches):
+    """The module over a whole prompt: ``hidden`` [1, T, E] beside
+    ``next_tokens`` [1, T], its K and V written at the full layers'
+    ``dests`` -> (its logits of row ``row`` [V], k_caches, v_caches,
+    count [experts])."""
+    c = config
+    dests = of_kind(dests, FULL)
+    live = live_rows(dests, k_caches[0])[None]
+    x, k, v, count = _module(c, params, hidden, next_tokens, live,
+                             "prefill", ())
+    ks, vs = write_prompt_rows(k_caches[c.n_layer:], v_caches[c.n_layer:],
+                               dests, [k], [v])
+    return (_lm_logits(c, params, x[0, row]), k_caches[:c.n_layer] + ks,
+            v_caches[:c.n_layer] + vs, count)
+
+
+def draft_chunk(config, params, hidden, next_tokens, row, positions, dests,
+                block_tables, k_caches, v_caches):
+    """The module over a prompt's chunk against its pool, as
+    :func:`draft_prefill`."""
+    c = config
+    dests = of_kind(dests, FULL)
+    live = live_rows(dests, k_caches[0])[None]
+    x, k, v, count = _module(
+        c, params, hidden, next_tokens, live, "prefill_chunk",
+        (k_caches[c.n_layer], v_caches[c.n_layer], dests,
+         of_kind(block_tables, FULL), positions))
+    return (_lm_logits(c, params, x[0, row]), k_caches[:c.n_layer] + [k],
+            v_caches[:c.n_layer] + [v], count)
+
+
+def draft_rows(config, params, hidden, next_tokens, row, positions, dests,
+               block_tables, k_caches, v_caches):
+    """The module behind a verify step: ``hidden`` [B, 2, E] beside the
+    tokens the step kept, ``next_tokens`` [B, 2] (a rejected draft's
+    place holds any id: its row is routed nowhere, attends nothing that
+    is kept and is written again by the next step) -> (its logits of row
+    ``row[b]`` of each sequence [B, V], k_caches, v_caches, count)."""
+    c = config
+    dests = of_kind(dests, FULL)
+    live = live_rows(dests, k_caches[0]) \
+        & (jnp.arange(hidden.shape[1]) <= row[:, None])
+    x, k, v, count = _module(
+        c, params, hidden, next_tokens, live, "decode_rows",
+        (k_caches[c.n_layer], v_caches[c.n_layer], dests,
+         of_kind(block_tables, FULL), positions))
+    x = jnp.take_along_axis(x, row[:, None, None], axis=1)[:, 0]
+    return (_lm_logits(c, params, x), k_caches[:c.n_layer] + [k],
+            v_caches[:c.n_layer] + [v], count)
+
+
 # Mellum2's training forward is Mixtral's over a config whose layers are
 # of two kinds (``MixtralBlock.kind``); JoyAI-LLM-Flash's over one whose
 # attention is latent and whose first layer is dense.
 Mellum = Mixtral
 JoyAI = Mixtral
+ExaoneMoe = Mixtral

@@ -76,6 +76,10 @@ DECLARED_METRICS: Dict[str, str] = {
     "raytpu_infer_decode_mfu": "model FLOPs utilization per decode step",
     "raytpu_infer_decode_tokens_per_s": "decode throughput",
     "raytpu_infer_decode_tokens_total": "decode tokens generated",
+    "raytpu_infer_draft_accepted_total":
+        "drafted tokens the verification kept (self-drafting)",
+    "raytpu_infer_drafted_tokens_total":
+        "drafted tokens a decode step verified (self-drafting)",
     "raytpu_infer_handoff_aborts_total":
         "KV handoffs aborted mid-stream (peer death, TTL sweep)",
     "raytpu_infer_handoff_bytes_total":

@@ -1318,7 +1318,11 @@ class TestInferenceJitLint:
         assert not [c for c in called if c[0] == "isinstance"]
         assert sorted(c[1] for c in called if c[0] == "getattr") \
             == [["paged_attn", None], ["serving", None]]
-        assert builders == ["_build_program", "_build_sampler"]
+        # The three programs' one builder and the sampler's, and for a
+        # model that drafts for itself the five programs' (its prompts'
+        # two through one builder inside it).
+        assert builders == ["_build_program", "_build_sampler",
+                            "_build_drafting", "_build_prompt"]
 
     def test_lint_catches_planted_violation(self):
         from raytpu.analysis.core import run_rule_on_source
