@@ -3,21 +3,24 @@
 Wire path (all existing machinery): client calls
 ``handle.generate.remote_streaming(prompt, ...)`` → router
 ``assign_request_streaming`` → replica ``handle_request_streaming``
-drains the sync generator below on its executor → each yielded token
-id travels back through the worker's object stream →
+admits the request on its executor, once, and awaits the stream that
+:meth:`LLMDeployment.generate` returns on its event loop → each token
+id becomes an object of the worker's stream, stored on that loop →
 ``ObjectRefGenerator`` → ``DeploymentResponseGenerator`` on the
-client, which sees tokens *while the sequence still decodes*.
+client, which sees tokens *while the sequence still decodes*. A step's
+tokens reach the loop through one call, and no pool thread runs for a
+token.
 
 The engine is pumped by a REPLICA-OWNED background stepping loop: one
 daemon thread per replica steps the engine whenever any request is
-unfinished and parks on a condition variable otherwise. Request
-threads only drain their own buffers — a slow (or stalled) consumer
-never stalls other streams, and tokens keep decoding while nobody is
+unfinished and parks on a condition variable otherwise. Consumers
+only drain their own streams — a slow (or stalled) consumer never
+stalls other streams, and tokens keep decoding while nobody is
 pulling. This replaces the PR-4 caller-driven design where whichever
-request thread was waiting ran the step. Cancellation rides generator
-close: the client's ``close()`` (or GC of an abandoned stream)
-delivers GeneratorExit to :meth:`LLMDeployment.generate`'s frame,
-whose ``finally`` aborts the request — freeing its KV pages.
+request thread was waiting ran the step. Cancellation rides the
+stream's close: the client's ``close()`` (or GC of an abandoned
+stream) reaches :meth:`TokenStream.close`, which aborts the request —
+freeing its KV pages.
 
 The loop also maintains a lock-free ``engine_pressure()`` snapshot
 (waiting depth, KV-page occupancy, TTFT p95) that the replica exports
@@ -48,6 +51,7 @@ engine through its config's ``serving`` and nothing else.
 
 from __future__ import annotations
 
+import asyncio
 import logging
 import os
 import queue
@@ -67,8 +71,155 @@ logger = logging.getLogger(__name__)
 
 # Newest step records the loop publishes for ``step_log``.
 _STEP_TAIL = 64
-# Closes a request's token queue: nothing follows it.
-_END = object()
+
+
+class _END:
+    """Closes a request's stream: nothing follows it. A class, because
+    it pickles by reference: ``LLMDeployment`` reaches its replica
+    pickled by value, globals and all, and an ``object()`` among them
+    would arrive as another than the one :class:`TokenStream` knows."""
+
+
+class TokenStream:
+    """One request's token ids, as :meth:`LLMDeployment.generate` returns
+    them: iterable (``for token in stream``, ``close()``) and
+    asynchronously iterable (``async for``, ``aclose()``).
+
+    How it is consumed is what it observes: the first of ``__next__`` and
+    ``__anext__`` called on it. A thread that iterates waits on the
+    stream's queue. A coroutine that iterates waits on a future of its
+    event loop, never on a thread: from its first ``__anext__`` on, the
+    stepping loop hands the tokens of a step to all such streams through
+    one ``call_soon_threadsafe`` (:meth:`LLMDeployment._send`), and what
+    the queue held until then goes first.
+
+    The deployment holds the stream from admission until it sends the
+    stream's end or the consumer closes it, whichever is first. A
+    consumer that leaves early must close it (the serve replica does):
+    that aborts the request and frees its pages at once.
+    """
+
+    __slots__ = ("request_id", "_dep", "_queue", "_handover", "_loop",
+                 "_items", "_waiter", "_done")
+
+    def __init__(self, dep: "LLMDeployment", request_id: str):
+        self.request_id = request_id
+        self._dep = dep
+        self._queue: queue.SimpleQueue = queue.SimpleQueue()
+        # Held for an offer and for the hand-over to an event loop, so
+        # that no item lands in the queue behind the loop's back.
+        self._handover = threading.Lock()
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
+        # On the loop's side: what was delivered and not yet taken, and
+        # the future the consumer waits on while that is nothing.
+        self._items: deque = deque()
+        self._waiter: Optional[asyncio.Future] = None
+        self._done = False  # the end was taken, or the stream closed
+
+    def _offer(self, items: tuple, by_loop: dict) -> bool:
+        """``items`` into the queue (True), or onto the batch of the loop
+        that awaits the stream. From the stepping loop's side."""
+        with self._handover:
+            loop = self._loop
+            if loop is None:
+                for item in items:
+                    self._queue.put(item)
+                return True
+        by_loop.setdefault(loop, []).append((self, items))
+        return False
+
+    # ---- consumed by a thread ---------------------------------------
+
+    def __iter__(self) -> "TokenStream":
+        return self
+
+    def __next__(self) -> int:
+        """Waits on the stream's own queue and not on the engine lock,
+        which the loop holds for the whole of a step: sixteen streams
+        taking it in turn after every step kept the loop, and the chip,
+        waiting."""
+        if self._loop is not None:
+            raise RuntimeError("an event loop awaits this stream")
+        dep = self._dep
+        while not self._done:
+            try:
+                item = self._queue.get(timeout=1.0)
+            except queue.Empty:
+                # Guards against an end that closed no stream. (What a
+                # step published while this waited for the lock is in
+                # the queue, ahead of the end put here.)
+                with dep._cv:
+                    dep._raise_if_dead()
+                    if dep._closed or not dep._engine_knows(
+                            self.request_id):
+                        self._queue.put(_END)
+                continue
+            if item is _END:
+                self._done = True
+                dep._raise_if_dead()
+                break
+            return item
+        raise StopIteration
+
+    def close(self) -> None:
+        """Abort the request if it still runs, and let go of the stream.
+        Takes the engine lock, which a step holds to its end, unless the
+        stream's end was already sent."""
+        if self._done:
+            return
+        self._done = True
+        self._queue.put(_END)  # a thread in ``__next__`` ends with it
+        dep = self._dep
+        if dep._streams.get(self.request_id) is not self:
+            return  # ended by the engine, unread: nothing runs for it
+        with dep._cv:
+            dep._engine.abort(self.request_id)  # no-op if finished
+            dep._streams.pop(self.request_id, None)
+            dep._cv.notify_all()
+
+    # ---- consumed by a coroutine ------------------------------------
+
+    def __aiter__(self) -> "TokenStream":
+        return self
+
+    async def __anext__(self) -> int:
+        if self._loop is None:
+            with self._handover:
+                self._loop = asyncio.get_running_loop()
+                while not self._queue.empty():
+                    self._items.append(self._queue.get_nowait())
+        while not self._done:
+            if not self._items:
+                self._waiter = self._loop.create_future()
+                try:
+                    await self._waiter
+                finally:
+                    self._waiter = None
+                continue
+            item = self._items.popleft()
+            if item is _END:
+                self._done = True
+                self._dep._raise_if_dead()
+                break
+            return item
+        raise StopAsyncIteration
+
+    async def aclose(self) -> None:
+        """:meth:`close` from a coroutine: the wait for the engine lock
+        goes to a pool thread, once, and not onto the loop, where it
+        would stop every stream's delivery for a step."""
+        if not self._done:
+            await asyncio.get_running_loop().run_in_executor(None, self.close)
+
+
+def _deliver(batch: list) -> None:
+    """On the event loop: a step's tokens into the streams it awaits, and
+    every consumer that waited is woken."""
+    for stream, items in batch:
+        stream._items.extend(items)
+        waiter = stream._waiter
+        if waiter is not None and not waiter.done():
+            waiter.set_result(None)
 
 
 class _FifoLock:
@@ -79,8 +230,8 @@ class _FifoLock:
     time — it is running, the waiter has yet to be woken — so new
     requests were not admitted and aborts not heard until the engine ran
     dry. Here ``release`` hands the lock to the longest waiter, and the
-    loop queues behind it. (Tokens do not wait for it: each stream has
-    a queue of its own.)
+    loop queues behind it. (Tokens do not wait for it: each request has
+    a :class:`TokenStream` of its own.)
     """
 
     def __init__(self):
@@ -247,17 +398,18 @@ class LLMDeployment:
         # producers signal "new work" to the loop through it.
         self._lock = _FifoLock()
         self._cv = threading.Condition(self._lock)
-        # A request's tokens, then ``_END``: its consumer waits on the
-        # queue and never for the lock, which a step holds to its end.
-        self._buffers: Dict[str, queue.SimpleQueue] = {}
+        # A request's stream, from its admission until its end is sent
+        # or its consumer closes it: the consumer waits on the stream and
+        # never for the lock, which a step holds to its end. Written
+        # under the lock.
+        self._streams: Dict[str, TokenStream] = {}
         # The last step's tokens, until the loop publishes them.
         self._held: list = []
         self._engine.on_launch = self._publish
-        # O(1) request-liveness: ids currently registered with the
-        # engine, plus their serving attribution. Replaces the O(n)
-        # waiting+running scan `_engine_knows` used to do per wakeup.
-        self._live: set = set()
-        self._req_info: Dict[str, dict] = {}
+        # Running totals of ``_publish``: tokens, calls onto an event
+        # loop, and tokens that went through a stream's queue instead.
+        self._published = {"published_tokens": 0, "publish_loop_calls": 0,
+                           "published_queued": 0}
         self._closed = False
         # What killed the stepping loop, if anything did: every stream,
         # live or new, then ends by raising it.
@@ -308,7 +460,7 @@ class LLMDeployment:
                     # There is no step to retry: a program that failed
                     # while it ran has consumed the KV pools it was given.
                     logger.exception("engine step failed; ending %d "
-                                     "stream(s)", len(self._live))
+                                     "stream(s)", len(self._streams))
                     self._error = e
                     self._publish()
                     self._end_streams()
@@ -337,29 +489,65 @@ class LLMDeployment:
     def _publish(self) -> None:
         """Hand the tokens of the last step to their streams. The loop
         does, under the lock, and where it can as the engine's
-        ``on_launch``: while the next step runs on the device. A stream's
-        consumer then works through its token while the loop waits for
-        the chip, and has gone back to sleep by the time the loop builds
-        the next launch: woken at the end of a step, sixteen of them
-        held the interpreter for 9 of that launch's 12 ms, with the chip
-        idle. The phase goes into the record of the step it falls in
-        (inside its ``infer.decode.wait``), or of the one it follows."""
+        ``on_launch``: while the next step runs on the device. The
+        streams that an event loop awaits (every stream of a serve
+        replica) get theirs through one call onto that loop
+        (:meth:`_send`), where each is stored as its client's next object
+        while this thread waits for the chip; no pool thread runs for a
+        token. With a consumer thread a stream, woken one by one and each
+        token crossing the replica's executor twice, thirty-two streams
+        held the interpreter for 8 ms after the ids had arrived
+        (``infer.decode.wait`` 20.0 ms for 11.6; PERF.md, PR 44). The
+        phase goes into the record of the step it falls in (inside its
+        ``infer.decode.wait``), or of the one it follows, and its counts
+        into that record's ``publishes``."""
         outs, self._held = self._held, []
         if not outs:
             return
-        with self._engine.recorder.phase("serve.llm.publish", {
-                "tokens": len(outs)}, after=True):
+        with self._engine.recorder.phase(
+                "serve.llm.publish", {"tokens": len(outs)}, after=True) as ph:
+            sends = []
             for out in outs:
-                buf = self._buffers.get(out.request_id)
-                if buf is not None:
-                    buf.put(out.token_id)
-                    if out.finished:
-                        buf.put(_END)
+                stream = self._streams.get(out.request_id)
+                if stream is None:
+                    continue
+                if out.finished:
+                    del self._streams[out.request_id]
+                    sends.append((stream, (out.token_id, _END)))
+                else:
+                    sends.append((stream, (out.token_id,)))
+            loop_calls, queued = self._send(sends)
+            ph.attrs.update(loop_calls=loop_calls, queued=queued)
+        if ph.record is not None:
+            ph.record.fields.setdefault("publishes", []).append(ph.attrs)
+        totals = self._published
+        totals["published_tokens"] += len(outs)
+        totals["publish_loop_calls"] += loop_calls
+        totals["published_queued"] += queued
+
+    @staticmethod
+    def _send(sends: list) -> tuple:
+        """Each ``(stream, items)`` to its stream, in order: all that an
+        event loop awaits through ONE ``call_soon_threadsafe`` onto that
+        loop, the others through their queues. Returns the calls made
+        and the tokens queued."""
+        by_loop: dict = {}
+        queued = 0
+        for stream, items in sends:
+            if stream._offer(items, by_loop):
+                queued += len(items) - (items[-1] is _END)
+        for loop, batch in by_loop.items():
+            try:
+                loop.call_soon_threadsafe(_deliver, batch)
+            except RuntimeError:
+                pass  # the loop is closed: its consumers went with it
+        return len(by_loop), queued
 
     def _end_streams(self) -> None:
-        """Close every request's token queue. Under the lock."""
-        for buf in self._buffers.values():
-            buf.put(_END)
+        """Send every stream its end. Under the lock."""
+        streams = list(self._streams.values())
+        self._streams.clear()
+        self._send([(stream, (_END,)) for stream in streams])
 
     def shutdown(self) -> None:
         """Stop the stepping loop (used by direct-instantiation tests;
@@ -375,9 +563,13 @@ class LLMDeployment:
 
     def generate(self, prompt, max_new_tokens: int = 16,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
-                 stop_token_ids=()):
-        """Sync generator of token ids for one request; safe to call
-        from many requests concurrently — they share decode steps."""
+                 stop_token_ids=()) -> TokenStream:
+        """Admit one request and return the stream of its token ids, for
+        ``for`` or ``async for``; safe to call from many requests
+        concurrently — they share decode steps. Waits for the engine
+        lock, which a step holds to its end: call it from a thread (the
+        serve replica does, from its executor), not from an event loop
+        that streams wait on."""
         sampling = SamplingParams(
             max_new_tokens=max_new_tokens, temperature=temperature,
             top_k=top_k, seed=seed, stop_token_ids=tuple(stop_token_ids))
@@ -400,51 +592,18 @@ class LLMDeployment:
             self._maybe_pull_prefix(prompt, request_id=request_id,
                                     deployment=deployment_name,
                                     tenant=tenant)
+        stream = TokenStream(self, request_id)
         with self._cv:
             self._raise_if_dead()
+            if self._closed:
+                stream._offer((_END,), {})
+                return stream
             seq = self._engine.add_request(request_id, prompt, sampling)
             seq.deployment = deployment_name
             seq.tenant = tenant
-            self._buffers[request_id] = queue.SimpleQueue()
-            self._live.add(request_id)
-            self._req_info[request_id] = {"deployment": deployment_name,
-                                          "tenant": tenant}
+            self._streams[request_id] = stream
             self._cv.notify_all()  # wake the stepping loop
-        try:
-            while True:
-                token = self._next_token(request_id)
-                if token is None:
-                    return
-                yield token
-        finally:
-            with self._cv:
-                self._engine.abort(request_id)  # no-op if finished
-                self._buffers.pop(request_id, None)
-                self._live.discard(request_id)
-                self._req_info.pop(request_id, None)
-                self._cv.notify_all()
-
-    def _next_token(self, request_id: str) -> Optional[int]:
-        """The request's next token, or None at its end. Waits on the
-        request's own queue and not on the engine lock, which the loop
-        holds for the whole of a step: sixteen streams taking it in
-        turn after every step kept the loop, and the chip, waiting."""
-        buf = self._buffers.get(request_id)
-        while buf is not None:
-            try:
-                item = buf.get(timeout=1.0)
-            except queue.Empty:
-                # Guards against an end that closed no queue.
-                with self._cv:
-                    self._raise_if_dead()
-                    if self._closed or not self._engine_knows(request_id):
-                        return None
-                continue
-            if item is _END:
-                self._raise_if_dead()
-                return None
-            return item
-        return None
+        return stream
 
     def _raise_if_dead(self) -> None:
         if self._error is not None:
@@ -453,10 +612,9 @@ class LLMDeployment:
             ) from self._error
 
     def _engine_knows(self, request_id: str) -> bool:
-        # O(1) live-set membership — the consumer wakeup path checks
-        # this every notify; scanning waiting+running was O(n) per
-        # wakeup per stream.
-        return request_id in self._live
+        # O(1): the streams are held by request id from admission until
+        # the engine's last word on the request is sent.
+        return request_id in self._streams
 
     # ---- disaggregated prefill/decode (see inference/disagg.py) -----
 
@@ -581,6 +739,7 @@ class LLMDeployment:
         the chips it leased (empty in local mode)."""
         with self._cv:
             stats = self._engine.stats()
+            stats.update(self._published)
         stats["replica"] = {
             "pid": os.getpid(),
             "chips": os.environ.get("RAYTPU_VISIBLE_CHIPS", "")}
@@ -589,14 +748,9 @@ class LLMDeployment:
     def abort(self, request_id: str) -> bool:
         with self._cv:
             ok = self._engine.abort(request_id)
-            if ok:
-                # Out-of-band abort: drop liveness now so blocked
-                # consumers end their streams on the next wakeup
-                # (generate's finally re-discards harmlessly).
-                self._live.discard(request_id)
-                self._req_info.pop(request_id, None)
-                buf = self._buffers.get(request_id)
-                if buf is not None:
-                    buf.put(_END)
+            stream = self._streams.pop(request_id, None) if ok else None
+            if stream is not None:
+                # Out-of-band abort: its consumer ends with the stream.
+                self._send([(stream, (_END,))])
             self._cv.notify_all()
             return ok

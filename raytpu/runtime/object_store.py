@@ -174,6 +174,18 @@ class MemoryStore:
         if self.on_put is not None:
             self.on_put(oid)
 
+    def put_may_block(self, value: SerializedValue) -> bool:
+        """Whether :meth:`put` of ``value`` may wait on more than this
+        store's lock: a value over the inline limit is sealed into shared
+        memory or spilled to disk, and a heap over its budget spills
+        before the put returns. An event loop stores what cannot block
+        itself and hands the rest to a thread."""
+        size = value.total_bytes()
+        return (size > cfg.max_direct_call_object_size
+                or self._heap_bytes + size > int(
+                    cfg.object_store_memory_bytes
+                    * cfg.object_spilling_threshold))
+
     def begin_receive(self, oid: ObjectID, size: int) -> "_Receive":
         """Open a streaming receive destination of known wire size: each
         chunk writes its range directly into the final location (the shm

@@ -10,6 +10,7 @@ raise remotely-thrown errors; reference: RayTaskError plumbing).
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any, Callable, Dict, List, Optional
 
@@ -70,6 +71,10 @@ class Worker:
         # not visible here; the owner sends an explicit free instead —
         # reference: owner-based object lifetime, reference_count.h:61).
         self.pin_owned = False
+        # Elements of async actors' streams (their ends included) stored
+        # on the actor's event loop, and through the default executor.
+        self.stream_puts_inline = 0
+        self.stream_puts_executor = 0
 
     # -- ownership ------------------------------------------------------------
 
@@ -168,11 +173,13 @@ class Worker:
             self._streams[tid] = st
         return st
 
-    def _stream_put(self, spec: TaskSpec, st: dict, n: int, value) -> bool:
-        """Store element ``n`` (0-based) at return index ``n+1``. Returns
-        False when the consumer closed the stream — the producer must stop
-        (otherwise an abandoned infinite generator runs forever, pinning
-        every element)."""
+    def _stream_put(self, spec: TaskSpec, st: dict, n: int, value,
+                    sv: Optional[SerializedValue] = None) -> bool:
+        """Store element ``n`` (0-based) at return index ``n+1`` (``sv``:
+        the value, if the caller has serialized it). Returns False when
+        the consumer closed the stream — the producer must stop (otherwise
+        an abandoned infinite generator runs forever, pinning every
+        element)."""
         oid = ObjectID.for_task_return(spec.task_id, n + 1)
         with self._streams_cv:
             if st["closed"]:
@@ -184,7 +191,7 @@ class Worker:
                 # so ack/close release exactly the pins that exist.
                 st["pinned"].add(n + 1)
                 self.reference_counter.add_local_ref(oid)
-        self.put_serialized(oid, serialize(value),
+        self.put_serialized(oid, serialize(value) if sv is None else sv,
                             creating_task=spec.task_id)
         if self.on_stream_element is not None:
             self.on_stream_element(oid)
@@ -192,12 +199,14 @@ class Worker:
             st["produced"] = n + 1
         return True
 
-    def _stream_finish(self, spec: TaskSpec, st: dict, n: int) -> None:
+    def _stream_finish(self, spec: TaskSpec, st: dict, n: int,
+                       sv: Optional[SerializedValue] = None) -> None:
         from raytpu.runtime.generator import StreamEnd
 
         done_oid = ObjectID.for_task_return(spec.task_id, 0)
-        self.put_serialized(done_oid, serialize(StreamEnd(n)),
-                            creating_task=spec.task_id)
+        self.put_serialized(
+            done_oid, serialize(StreamEnd(n)) if sv is None else sv,
+            creating_task=spec.task_id)
         if self.on_stream_element is not None:
             self.on_stream_element(done_oid)
         # Cluster workers pin nothing (pin_owned): drop the state now so
@@ -253,12 +262,36 @@ class Worker:
                 if self._streams.get(tid) is st:
                     self._streams.pop(tid, None)
 
+    async def _store_async(self, value, store: Callable) -> Any:
+        """``store(sv)``, ``sv`` being ``value`` serialized or None, for
+        an async actor's stream: on the actor's event loop where the
+        store cannot block, else on the default executor. It cannot where
+        this worker forwards nothing (a cluster worker's
+        ``on_stream_element`` is an RPC to its node daemon) and the store
+        keeps the serialized value in this process's memory (a larger
+        one seals shared memory or spills to disk). A pool thread and
+        two wake-ups of the loop an element were most of what a stream
+        of small values, an LLM replica's token ids, cost."""
+        import asyncio
+
+        sv = None
+        if self.on_stream_element is None:
+            sv = serialize(value)
+            if not self.store.put_may_block(sv):
+                self.stream_puts_inline += 1
+                return store(sv)
+        self.stream_puts_executor += 1
+        return await asyncio.get_running_loop().run_in_executor(
+            None, store, sv)
+
     async def _run_stream_async(self, spec: TaskSpec,
                                 aiterator) -> Optional[BaseException]:
         """Async-actor variant of :meth:`_run_stream` — drains an async (or
         sync) generator on the actor's event loop without blocking it for
         backpressure waits."""
         import asyncio
+
+        from raytpu.runtime.generator import StreamEnd
 
         tid = spec.task_id
         st = self._stream_begin(tid)
@@ -270,10 +303,8 @@ class Worker:
                     if self.is_cancelled(tid):
                         return TaskCancelledError(
                             f"task {spec.name} cancelled")
-                    # put may do blocking I/O (shm seal / daemon RPC):
-                    # keep it off the actor's event loop.
-                    if not await loop.run_in_executor(
-                            None, self._stream_put, spec, st, n, value):
+                    if not await self._store_async(value, functools.partial(
+                            self._stream_put, spec, st, n, value)):
                         break
                     n += 1
                     while self._backpressured(spec, st, n):
@@ -297,8 +328,8 @@ class Worker:
                     if self.is_cancelled(tid):
                         return TaskCancelledError(
                             f"task {spec.name} cancelled")
-                    if not await loop.run_in_executor(
-                            None, self._stream_put, spec, st, n, value):
+                    if not await self._store_async(value, functools.partial(
+                            self._stream_put, spec, st, n, value)):
                         break
                     n += 1
                     while self._backpressured(spec, st, n):
@@ -307,8 +338,8 @@ class Worker:
             self._stream_abandon(tid, st)
             return e if isinstance(e, TaskError) else TaskError.from_exception(
                 spec.name, e)
-        await loop.run_in_executor(
-            None, self._stream_finish, spec, st, n)
+        await self._store_async(StreamEnd(n), functools.partial(
+            self._stream_finish, spec, st, n))
         return None
 
     # -- cancellation ---------------------------------------------------------

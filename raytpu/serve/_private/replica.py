@@ -330,15 +330,27 @@ class Replica:
                         method_name, request_args, request_kwargs
                     )
                     if hasattr(result, "__aiter__"):
-                        async for chunk in result:
-                            yield chunk
+                        # What can be awaited is awaited here, on the
+                        # loop: no pool thread runs for a chunk (an LLM
+                        # replica's streams get a decode step's tokens
+                        # through one call onto this loop).
+                        try:
+                            async for chunk in result:
+                                yield chunk
+                        finally:
+                            # The stream ended, or its consumer went away
+                            # (GeneratorExit, a cancel): close it now, so
+                            # that its request is aborted and its KV pages
+                            # freed, not at GC time.
+                            aclose = getattr(result, "aclose", None)
+                            if aclose is not None:
+                                await aclose()
                     elif hasattr(result, "__next__") or hasattr(
                             result, "__iter__"):
-                        # Drain sync generators on the executor: each
-                        # next() may block (an LLM replica waits a full
-                        # decode step per token) and must not stall the
-                        # event loop — concurrent streams and health
-                        # checks keep running between chunks.
+                        # Drain other sync iterators on the executor:
+                        # each next() may compute or block and must not
+                        # stall the event loop — concurrent streams and
+                        # health checks keep running between chunks.
                         it = iter(result)
                         loop = asyncio.get_event_loop()
                         # run_in_executor does NOT propagate contextvars,
@@ -402,10 +414,14 @@ class Replica:
             target, "__call__", target)
         if inspect.isasyncgenfunction(fn) or inspect.isgeneratorfunction(fn):
             # Generator functions return their (a)sync generator instantly;
-            # the stream driver drains it off-loop.
+            # the stream driver awaits the one and drains the other
+            # off-loop.
             return target(*args, **kwargs)
         # Plain handler used with the streaming path: same executor /
-        # coroutine semantics as the non-streaming invoke (single chunk).
+        # coroutine semantics as the non-streaming invoke. What it
+        # returns is one chunk, or a stream of them if it can be iterated
+        # (``LLMDeployment.generate`` admits its request there, on a pool
+        # thread, and returns a stream this loop awaits).
         return await self._invoke(method_name, args, kwargs)
 
     def _resolve_target(self, method_name: str):
@@ -436,9 +452,13 @@ class Replica:
         # replica's event loop (reference: sync methods execute on the
         # replica's executor; keeps queue-length metrics & health checks
         # live while user code computes).
+        # run_in_executor does NOT propagate contextvars: carry the
+        # request context over, so that the handler (and the engine
+        # underneath it) sees the router-stamped request id.
         loop = asyncio.get_event_loop()
         out = await loop.run_in_executor(
-            self._executor, lambda: target(*args, **kwargs)
+            self._executor, contextvars.copy_context().run,
+            lambda: target(*args, **kwargs)
         )
         if inspect.isawaitable(out):
             out = await out

@@ -317,8 +317,8 @@ class _Phase:
     stamps the clock last and leaving stamps it first, so the stamps are
     the innermost of the three records of the phase."""
 
-    __slots__ = ("name", "attrs", "t0", "t1", "_recorder", "_opens",
-                 "_after", "_entry", "_annotation", "_span")
+    __slots__ = ("name", "attrs", "t0", "t1", "record", "_recorder",
+                 "_opens", "_after", "_entry", "_annotation", "_span")
 
     def __init__(self, recorder: "StepRecorder", name: str,
                  attrs: Optional[Dict[str, Any]], opens: bool, after: bool):
@@ -342,7 +342,7 @@ class _Phase:
             self._annotation.__enter__()
         recorder = self._recorder
         if self._opens:
-            record = recorder.open = StepRecord(self.attrs)
+            self.record = record = recorder.open = StepRecord(self.attrs)
             record.phases.extend(recorder._early)
             recorder._early.clear()
             self._entry = record
@@ -352,6 +352,7 @@ class _Phase:
             record = recorder.open
             if record is None and self._after and recorder._ring:
                 record = recorder._ring[-1]
+            self.record = record  # None: the next step adopts the phase
             (recorder._early if record is None
              else record.phases).append(entry)
             self.t0 = entry[1] = entry[2] = time.perf_counter()

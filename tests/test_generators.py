@@ -383,3 +383,143 @@ class TestEventDrivenCluster:
         finally:
             raytpu.shutdown()
             c.shutdown()
+
+
+# -- async actors: an element is stored on the loop where that cannot block ---
+
+
+def _stream_puts():
+    """(on the loop, through the executor) so far, of this process's
+    worker: where an async actor's stream elements were stored."""
+    from raytpu.runtime import api
+
+    worker, _ = api._worker_and_backend()
+    return worker.stream_puts_inline, worker.stream_puts_executor
+
+
+class TestAsyncActorStreamStore:
+    """``Worker._run_stream_async``: a small element (and the stream's
+    end) is serialized and put into the in-process store on the actor's
+    event loop; what may block — a value over the store's inline limit,
+    a worker that forwards each element to its node — keeps the
+    executor (ISSUE 44)."""
+
+    @staticmethod
+    def _actor():
+        @raytpu.remote
+        class Source:
+            def __init__(self):
+                self.made = 0
+
+            async def nums(self, n):
+                for i in range(n):
+                    self.made += 1
+                    yield i
+
+            async def blobs(self, sizes):
+                for size in sizes:
+                    yield b"x" * size
+
+            def sync_nums(self, n):
+                for i in range(n):
+                    yield i
+
+            async def made_so_far(self):
+                return self.made
+
+        return Source.remote()
+
+    @pytest.mark.parametrize("method,n", [("nums", 1), ("nums", 40),
+                                          ("sync_nums", 12)])
+    def test_small_values_never_enter_an_executor_to_be_stored(
+            self, fabric, method, n):
+        a = self._actor()
+        raytpu.get(a.made_so_far.remote())  # the actor is up
+        inline0, pool0 = _stream_puts()
+        g = getattr(a, method).options(num_returns="streaming").remote(n)
+        assert [raytpu.get(r) for r in g] == list(range(n))  # in order
+        with pytest.raises(StopIteration):  # the StreamEnd was read
+            next(g)
+        inline1, pool1 = _stream_puts()
+        assert pool1 - pool0 == 0
+        assert inline1 - inline0 == n + 1  # every element and the end
+
+    def test_backpressure_holds_on_the_loop(self, fabric):
+        a = self._actor()
+        raytpu.get(a.made_so_far.remote())
+        _, pool0 = _stream_puts()
+        g = a.nums.options(num_returns="streaming",
+                           generator_backpressure_num_objects=2).remote(10)
+        time.sleep(0.6)  # the producer stalls at the cap
+        assert raytpu.get(a.made_so_far.remote()) <= 3
+        assert [raytpu.get(r) for r in g] == list(range(10))
+        assert _stream_puts()[1] == pool0
+
+    def test_stream_close_stops_the_producer_and_drops_its_pins(
+            self, fabric):
+        from raytpu.core.ids import ObjectID
+        from raytpu.runtime import api
+
+        a = self._actor()
+        g = a.nums.options(num_returns="streaming",
+                           generator_backpressure_num_objects=4
+                           ).remote(10_000)
+        taken = [raytpu.get(next(g)) for _ in range(3)]
+        assert taken == [0, 1, 2]
+        g.close()
+        time.sleep(0.3)
+        made = raytpu.get(a.made_so_far.remote())
+        assert made < 20, "the producer ran on after stream_close"
+        time.sleep(0.3)
+        assert raytpu.get(a.made_so_far.remote()) == made
+        store = api._backend.store
+        deadline = time.monotonic() + 5
+        left = None
+        while time.monotonic() < deadline:
+            left = [i for i in range(4, made + 1) if store.contains(
+                ObjectID.for_task_return(g.task_id, i))]
+            if not left:
+                break
+            time.sleep(0.05)
+        assert not left, f"elements never taken stay pinned: {left}"
+
+    def test_a_value_over_the_inline_limit_goes_through_the_executor(
+            self, fabric):
+        from raytpu.core.config import cfg
+
+        big = cfg.max_direct_call_object_size + 1024
+        a = self._actor()
+        raytpu.get(a.made_so_far.remote())
+        inline0, pool0 = _stream_puts()
+        g = a.blobs.options(num_returns="streaming").remote([8, big, 8])
+        assert [len(raytpu.get(r)) for r in g] == [8, big, 8]
+        inline1, pool1 = _stream_puts()
+        assert pool1 - pool0 == 1       # the large one alone
+        assert inline1 - inline0 == 3   # two small ones and the end
+
+    def test_a_worker_that_forwards_elements_keeps_the_executor(
+            self, fabric):
+        """A cluster worker ships each element to its node daemon
+        (``on_stream_element``, an RPC): never on the loop."""
+        import threading
+
+        from raytpu.runtime import api
+
+        worker, _ = api._worker_and_backend()
+        a = self._actor()
+        raytpu.get(a.made_so_far.remote())
+        forwarded = []
+        worker.on_stream_element = lambda oid: forwarded.append(
+            (oid, threading.current_thread().name))
+        try:
+            inline0, pool0 = _stream_puts()
+            g = a.nums.options(num_returns="streaming").remote(5)
+            assert [raytpu.get(r) for r in g] == list(range(5))
+            inline1, pool1 = _stream_puts()
+        finally:
+            worker.on_stream_element = None
+        assert inline1 - inline0 == 0
+        assert pool1 - pool0 == 6
+        assert len(forwarded) == 6  # five elements and the end
+        assert all(name.startswith("asyncio_") for _, name in forwarded), \
+            forwarded

@@ -497,3 +497,299 @@ class TestSteppingLoopHandsTheLockOver:
         # decode instead of waiting for a to finish.
         assert arrivals["b"][0] < arrivals["a"][-1]
         assert max(hist) >= 2
+
+
+# -- a stream that can be awaited (ISSUE 44) ------------------------------------
+
+
+def _consume_async(dep, requests, take=None):
+    """Each of ``requests`` (``generate``'s arguments) admitted from a
+    pool thread and consumed with ``async for`` on one event loop, as the
+    serve replica does. Returns each stream's tokens or the exception
+    that ended it; ``take`` tokens in, a stream is closed."""
+    import asyncio
+
+    async def one(args, kwargs):
+        loop = asyncio.get_running_loop()
+        stream = await loop.run_in_executor(
+            None, lambda: dep.generate(*args, **kwargs))
+        seen = []
+        try:
+            async for tok in stream:
+                seen.append(tok)
+                if take is not None and len(seen) == take:
+                    await stream.aclose()
+        except RuntimeError as e:
+            return e, seen
+        return None, seen
+
+    async def main():
+        return await asyncio.gather(*(one(a, k) for a, k in requests))
+
+    return asyncio.run(main())
+
+
+class TestAwaitedTokenStream:
+    """``LLMDeployment.generate`` returns a stream for ``for`` and for
+    ``async for``; consumed by a coroutine it waits on its loop, and a
+    step's tokens reach all such streams through one call onto it."""
+
+    @pytest.mark.parametrize("sampling", [
+        {}, {"temperature": 0.9, "top_k": 8, "seed": 5}],
+        ids=["greedy", "sampled"])
+    def test_for_and_async_for_give_the_same_tokens(self, sampling):
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            prompt = list(range(1, 9))
+            plain = list(dep.generate(prompt, max_new_tokens=12, **sampling))
+            (err, awaited), = _consume_async(
+                dep, [((prompt,), dict(max_new_tokens=12, **sampling))])
+            assert err is None and awaited == plain and len(plain) == 12
+            stats = dep.stats()
+            assert stats["published_tokens"] == 24
+            # The thread's went through its queue, the coroutine's not.
+            assert stats["published_queued"] == 12
+            assert stats["publish_loop_calls"] == 12
+            assert dep._streams == {}
+        finally:
+            dep.shutdown()
+
+    def test_a_stream_is_consumed_one_way(self):
+        import asyncio
+
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            stream = dep.generate([1, 2, 3], max_new_tokens=4)
+
+            async def first():
+                return await stream.__anext__()
+
+            loop = asyncio.new_event_loop()
+            try:
+                assert isinstance(loop.run_until_complete(first()), int)
+                with pytest.raises(RuntimeError, match="awaits this stream"):
+                    next(stream)
+                loop.run_until_complete(stream.aclose())
+            finally:
+                loop.close()
+            assert dep.stats()["running"] == 0
+        finally:
+            dep.shutdown()
+
+    def test_eight_streams_through_serve_are_the_engines_and_none_queues(
+            self, serve_instance, reference):
+        # Prompts of five chunks: a stream's first token is steps away
+        # when the replica's loop first awaits it, so none is queued.
+        app = serve.LLMDeployment.bind(
+            model="llama", seed=0,
+            engine_options=dict(ENGINE_OPTIONS, prefill_chunk=8))
+        handle = serve.run(app, name="llm-awaited", route_prefix=None)
+        prompts = [[(7 * i + j) % 200 + 1 for j in range(36 + i % 3)]
+                   for i in range(8)]
+        got = {}
+
+        def consume(i):
+            got[i] = list(handle.generate.remote_streaming(
+                prompts[i], max_new_tokens=4))
+
+        threads = [threading.Thread(target=consume, args=(i,))
+                   for i in range(8)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=180)
+        assert not any(th.is_alive() for th in threads)
+        for i in range(8):
+            assert got[i] == reference(prompts[i], 4), i
+        stats = handle.stats.remote().result()
+        assert stats["published_tokens"] == 32
+        assert stats["published_queued"] == 0
+        # One call onto the loop for each step that published, whatever
+        # the number of streams it published to.
+        steps = handle.step_log.remote().result()
+        publishes = [p for s in steps for p in s.get("publishes", ())]
+        assert len(steps) < 64  # the log holds them all
+        assert sum(p["tokens"] for p in publishes) == 32
+        assert all(p["loop_calls"] == 1 and p["queued"] == 0
+                   for p in publishes)
+        assert stats["publish_loop_calls"] == len(publishes) < 32
+        assert max(p["tokens"] for p in publishes) > 1
+
+    def test_a_client_that_leaves_frees_its_pages_at_once(self):
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            (err, seen), = _consume_async(
+                dep, [(([1, 2, 3, 4, 5],), dict(max_new_tokens=40))], take=2)
+            assert err is None and len(seen) == 2
+            # ``aclose`` returned: the abort has run, behind the step that
+            # held the lock, and not at some later collection.
+            stats = dep.stats()
+            assert stats["running"] == 0 and stats["waiting"] == 0
+            assert stats["kv_utilization"] == 0.0
+            assert dep._streams == {}
+            steps = len(dep.step_log())
+            time.sleep(0.2)
+            assert len(dep.step_log()) - steps <= 1  # nothing left to run
+        finally:
+            dep.shutdown()
+
+    def test_a_step_that_raises_ends_every_awaited_stream(self, monkeypatch):
+        from raytpu.inference.engine import InferenceEngine
+
+        step = InferenceEngine.step
+        calls = {"n": 0}
+
+        def step_that_dies(self):
+            calls["n"] += 1
+            if calls["n"] > 3:
+                with self.recorder.step("infer.step", {"decodes": 0}):
+                    raise MemoryError("RESOURCE_EXHAUSTED: the program "
+                                      "does not load")
+            return step(self)
+
+        monkeypatch.setattr(InferenceEngine, "step", step_that_dies)
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            t0 = time.monotonic()
+            ended = _consume_async(dep, [
+                (([1, 2, 3],), dict(max_new_tokens=40)),
+                (([4, 5, 6, 7],), dict(max_new_tokens=40))])
+            assert time.monotonic() - t0 < 60
+            for err, seen in ended:
+                assert isinstance(err, RuntimeError), (err, seen)
+                assert "RESOURCE_EXHAUSTED" in str(err)
+                assert isinstance(err.__cause__, MemoryError)
+                assert len(seen) < 40
+            assert sum(len(seen) for _, seen in ended) >= 1
+            with pytest.raises(RuntimeError, match="step loop died"):
+                dep.generate([9, 9], max_new_tokens=2)
+        finally:
+            dep.shutdown()
+
+    def test_shutdown_ends_an_awaited_stream(self):
+        import asyncio
+
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+
+        async def main():
+            loop = asyncio.get_running_loop()
+            stream = await loop.run_in_executor(
+                None, lambda: dep.generate([1, 2, 3], max_new_tokens=60))
+            seen = [await stream.__anext__()]
+            await loop.run_in_executor(None, dep.shutdown)
+            async for tok in stream:
+                seen.append(tok)
+            return seen
+
+        assert 1 <= len(asyncio.run(main())) < 60
+
+    def test_a_verify_steps_two_tokens_arrive_in_order(self):
+        """A model that drafts for itself yields a sequence one token or
+        two a step: both go to the stream in one call, in order."""
+        dep = serve.LLMDeployment._target(
+            model="exaone_moe", engine_options=dict(
+                page_size=4, max_num_seqs=4, max_model_len=96))
+        try:
+            requests = [(([5 + i, 6, 7, 8, 9],),
+                         dict(max_new_tokens=24, temperature=1.0, seed=3 + i))
+                        for i in range(3)]
+            plain = [list(dep.generate(*a, **k)) for a, k in requests]
+            awaited_from = time.perf_counter()
+            ended = _consume_async(dep, requests)
+            assert [seen for _, seen in ended] == plain
+            assert all(err is None for err, _ in ended)
+            assert all(len(p) == 24 for p in plain)
+            # Some step gave a stream two tokens, and published them in
+            # the one call of the step that followed.
+            steps = [s for s in dep.step_log() if s["start"] > awaited_from]
+            doubles = [i for i, s in enumerate(steps[:-1])
+                       if s.get("emitted", 0) > s.get("decodes", 0) > 0]
+            assert doubles, "no draft was kept: the test holds nothing"
+            for i in doubles:
+                publish = steps[i + 1]["publishes"][0]
+                assert publish == {"tokens": steps[i]["emitted"],
+                                   "loop_calls": 1, "queued": 0}
+        finally:
+            dep.shutdown()
+
+    def test_no_pool_thread_runs_for_a_token(self, serve_instance,
+                                             monkeypatch):
+        """The mechanism's point, without a clock: sixteen streams for K
+        decode steps submit to the replica's executor once a request
+        (its admission) and to the loops' default executors what a
+        request costs the actor, whatever K is. With ``next()`` on the
+        executor and the element's store on the default one it was two
+        submissions a token."""
+        import collections
+        from concurrent.futures import ThreadPoolExecutor
+
+        submitted = collections.Counter()
+        submit = ThreadPoolExecutor.submit
+
+        def counting_submit(self, fn, *args, **kwargs):
+            submitted[self._thread_name_prefix.split("-")[0]] += 1
+            return submit(self, fn, *args, **kwargs)
+
+        monkeypatch.setattr(ThreadPoolExecutor, "submit", counting_submit)
+        app = serve.LLMDeployment.options(max_ongoing_requests=32).bind(
+            model="llama", seed=0, engine_options=dict(
+                ENGINE_OPTIONS, max_num_seqs=16))
+        handle = serve.run(app, name="llm-no-pool", route_prefix=None)
+
+        def run(new_tokens):
+            before = collections.Counter(submitted)
+            got = {}
+
+            def consume(i):
+                got[i] = list(handle.generate.remote_streaming(
+                    [i + 1, 2, 3], max_new_tokens=new_tokens))
+
+            threads = [threading.Thread(target=consume, args=(i,))
+                       for i in range(16)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=180)
+            assert all(len(got[i]) == new_tokens for i in range(16)), got
+            return submitted - before
+
+        run(4)  # programs compiled, executors made
+        short, long = run(6), run(36)
+        assert short["replica"] == long["replica"] == 16
+        # 16 x 30 more tokens: the default executors saw the requests
+        # (and what the controller's loops asked meanwhile), no token.
+        assert abs(long["asyncio"] - short["asyncio"]) <= 16, (short, long)
+        assert long["asyncio"] <= 4 * 16, (short, long)
+
+    def test_a_thread_whose_wait_ran_out_keeps_what_a_step_then_published(
+            self):
+        """The deployment lets go of a stream when it sends its end; a
+        consumer thread whose wait ran out meanwhile finds the request
+        gone and must still read what is in its queue."""
+        import queue
+
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            stream = dep.generate([1, 2, 3], max_new_tokens=3)
+            deadline = time.monotonic() + 60
+            while dep._streams and time.monotonic() < deadline:
+                time.sleep(0.01)
+            assert dep._streams == {}  # all three published, and the end
+
+            class RanOutOnce:
+                def __init__(self, real):
+                    self.real, self.ran_out = real, False
+
+                def get(self, timeout=None):
+                    if not self.ran_out:
+                        self.ran_out = True
+                        raise queue.Empty
+                    return self.real.get(timeout=timeout)
+
+                def put(self, item):
+                    self.real.put(item)
+
+            stream._queue = RanOutOnce(stream._queue)
+            assert len(list(stream)) == 3
+        finally:
+            dep.shutdown()

@@ -375,9 +375,10 @@ class TestServeRequestE2E:
 
 
 class TestEngineKnowsLiveSet:
-    """Satellite: ``_engine_knows`` is an O(1) live-id set, and it still
-    tells streams apart correctly when requests are aborted out of
-    band (the behavior the old O(n) waiting+running scan provided)."""
+    """Satellite: ``_engine_knows`` is an O(1) look-up among the live
+    streams, and it still tells streams apart correctly when requests
+    are aborted out of band (the behavior the old O(n) waiting+running
+    scan provided)."""
 
     def _dep(self):
         from raytpu import serve
@@ -394,7 +395,7 @@ class TestEngineKnowsLiveSet:
             {"request_id": "known-rid", "deployment": "d", "tenant": ""})
         try:
             it = dep.generate(list(range(1, 9)), max_new_tokens=64)
-            first = next(it)  # generator body ran: request registered
+            first = next(it)  # the request was admitted under its id
         finally:
             replica_mod._request_context.reset(token)
         assert first is not None
@@ -408,7 +409,7 @@ class TestEngineKnowsLiveSet:
         dep = self._dep()
         toks = list(dep.generate(list(range(1, 6)), max_new_tokens=3))
         assert len(toks) == 3
-        assert dep._live == set() and dep._req_info == {}
+        assert dep._streams == {}
 
 
 # -- chaos: producer dies mid-stream ------------------------------------------
