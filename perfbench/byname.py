@@ -43,16 +43,19 @@ def load_module(dirs: Sequence[str], kind: str, name: str):
 
 
 def load_reader(dirs: Sequence[str], name: str):
-    return load_module(dirs, "layer_metrics", name)
-
-
-def load_beside(path: str, name: str):
-    """The reader ``<name>.py`` in the directory of the reader at ``path``:
-    for a reader that is another one under a second name, because its
-    cells report another end-to-end metric for it to move."""
-    return load_module([os.path.dirname(os.path.dirname(
-        os.path.abspath(path)))], os.path.basename(os.path.dirname(
-            os.path.abspath(path))), name)
+    """The reader of the per-layer metric ``name``:
+    ``layer_metrics/<name>.py`` or, for a metric that is another one
+    under a second name (``<base>.<tag>``: a per-layer metric moves one
+    end-to-end metric, so a quantity whose cells report different ones
+    is entered once for each, and read by the same code), the reader of
+    the name with its last tag taken off."""
+    while True:
+        try:
+            return load_module(dirs, "layer_metrics", name)
+        except FileNotFoundError:
+            if "." not in name:
+                raise
+        name = name.rpartition(".")[0]
 
 
 def load_family(dirs: Sequence[str], cfg: Dict):

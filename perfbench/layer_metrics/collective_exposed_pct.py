@@ -2,11 +2,6 @@
 collective was in flight and no other instruction ran on that chip
 (``trace_reduce.collective_exposed_seconds``)."""
 
-LAYER = "sharding"
-UNIT = "%"
-MOVES = "train_tokens_per_s_chip"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import trace_reduce

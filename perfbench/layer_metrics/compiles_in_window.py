@@ -3,11 +3,6 @@ of ``stats()``'s three compile dictionaries from the last step before
 the window to the window's last step. Must be 0; a run where it is
 not is reported as incorrect."""
 
-LAYER = "engine step"
-UNIT = "count"
-MOVES = "itl_p95_ms"
-SOURCE = "program_counter"
-
 
 def read(run):
     return run.counted_in_window("compiles")

@@ -2,11 +2,6 @@
 engine appends to ``stats()["decode_batch_hist"]``, read at the call into
 the decode."""
 
-LAYER = "scheduler"
-UNIT = "seqs"
-MOVES = "out_tokens_per_s"
-SOURCE = "program_counter"
-
 
 def read(run):
     sizes = [r.decodes for r in run.engine_steps if r.decodes]

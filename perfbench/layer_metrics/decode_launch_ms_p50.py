@@ -2,11 +2,6 @@
 decode steps: the numpy inputs, the block table, the five host-to-device
 puts and the call of the jitted decode returning (the enqueue)."""
 
-LAYER = "engine step"
-UNIT = "ms"
-MOVES = "itl_p95_ms"
-SOURCE = "program_span"
-
 
 def read(run):
     from perfbench import steplog

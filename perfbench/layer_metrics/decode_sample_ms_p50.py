@@ -3,11 +3,6 @@ decode steps: advancing each sequence and emitting the token ids the
 device sampled (since PR 35; one ``sample_token`` a sequence on the host
 before)."""
 
-LAYER = "engine step"
-UNIT = "ms"
-MOVES = "itl_p95_ms"
-SOURCE = "program_span"
-
 
 def read(run):
     from perfbench import steplog

@@ -2,11 +2,6 @@
 decode steps: the host blocked in ``np.asarray(logits)``, on the device's
 step and on the copy of the logits back."""
 
-LAYER = "engine step"
-UNIT = "ms"
-MOVES = "itl_p95_ms"
-SOURCE = "program_span"
-
 
 def read(run):
     from perfbench import steplog

@@ -2,11 +2,6 @@
 averaged over the chips: 1 - union of the device-op intervals over the
 window (training cells)."""
 
-LAYER = "device"
-UNIT = "%"
-MOVES = "train_tokens_per_s_chip"
-SOURCE = "device_trace"
-
 
 def read(run):
     return run.device_idle_pct()

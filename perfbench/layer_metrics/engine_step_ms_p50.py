@@ -3,11 +3,6 @@ the window's steps that decoded."""
 
 import statistics
 
-LAYER = "engine step"
-UNIT = "ms"
-MOVES = "itl_p95_ms"
-SOURCE = "program_span"
-
 
 def read(run):
     steps = [r.end - r.start for r in run.engine_steps if r.decodes]

@@ -8,10 +8,6 @@ policy repeats is counted as often as it ran."""
 
 import re
 
-LAYER = "kernels"
-UNIT = "%"
-MOVES = "train_tokens_per_s_chip"
-SOURCE = "device_trace"
 
 SHAPE = re.compile(r"(bf16|f32)\[(\d+),(\d+),(\d+)\]")
 

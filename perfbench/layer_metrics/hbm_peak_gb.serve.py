@@ -2,11 +2,6 @@
 runtime's ``peak_bytes_in_use`` (buffers) plus ``peak_bytes_reserved``
 (scratch of the loaded programs), in GB (serving cells)."""
 
-LAYER = "device"
-UNIT = "GB"
-MOVES = "out_tokens_per_s"
-SOURCE = "program_counter"
-
 
 def read(run):
     return run.hbm_peak_gb()

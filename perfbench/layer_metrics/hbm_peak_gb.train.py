@@ -2,11 +2,6 @@
 runtime's ``peak_bytes_in_use`` (buffers) plus ``peak_bytes_reserved``
 (scratch of the loaded programs), in GB (training cells)."""
 
-LAYER = "device"
-UNIT = "GB"
-MOVES = "train_tokens_per_s_chip"
-SOURCE = "program_counter"
-
 
 def read(run):
     return run.hbm_peak_gb()

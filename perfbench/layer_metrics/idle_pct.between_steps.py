@@ -2,11 +2,6 @@
 ``infer.step`` was open, or only the replica loop's ``serve.llm.*``
 phases: publishing, the hand-over of the lock, the wait for it."""
 
-LAYER = "device"
-UNIT = "%"
-MOVES = "itl_p95_ms"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import steplog

@@ -2,11 +2,6 @@
 on its way to a launch: ``infer.schedule``, a prefill phase or
 ``infer.decode.launch`` innermost (``steplog.idle_bucket``)."""
 
-LAYER = "device"
-UNIT = "%"
-MOVES = "itl_p95_ms"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import steplog

@@ -2,11 +2,6 @@
 was inside ``infer.decode.sample``: sampling and emitting on the host
 before the next step can be launched."""
 
-LAYER = "device"
-UNIT = "%"
-MOVES = "itl_p95_ms"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import steplog

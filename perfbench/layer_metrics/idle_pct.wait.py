@@ -2,11 +2,6 @@
 was inside ``infer.decode.wait``: the chip idle while the host waits on
 it, which is launch latency and the copy back, not host work."""
 
-LAYER = "device"
-UNIT = "%"
-MOVES = "itl_p95_ms"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import steplog

@@ -6,11 +6,6 @@ latent-attention layer one row (640 lanes held for 576 published:
 15,360 over 12 layers where the architecture's are 13,824). ``None`` for
 a program whose records lack it."""
 
-LAYER = "KV cache"
-UNIT = "B"
-MOVES = "out_tokens_per_s"
-SOURCE = "program_counter"
-
 
 def read(run):
     for r in reversed(run.engine_steps):

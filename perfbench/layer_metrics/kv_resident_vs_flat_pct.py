@@ -10,11 +10,6 @@ a full one), and the layers of each kind from the family file
 give back would show here. ``None`` for a program or a family without
 them."""
 
-LAYER = "KV cache"
-UNIT = "%"
-MOVES = "itl_p50_ms"
-SOURCE = "program_counter"
-
 
 def read(run):
     from perfbench import steplog

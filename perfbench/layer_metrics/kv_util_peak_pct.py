@@ -2,11 +2,6 @@
 any engine step of the window (``PagedKVCache.utilization()``, the value
 behind ``stats()["kv_utilization"]``)."""
 
-LAYER = "KV cache"
-UNIT = "%"
-MOVES = "out_tokens_per_s"
-SOURCE = "program_counter"
-
 
 def read(run):
     if not run.engine_steps:

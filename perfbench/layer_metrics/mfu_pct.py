@@ -7,11 +7,6 @@ seconds and the window's mean is not the untraced run's."""
 
 import statistics
 
-LAYER = "trainer"
-UNIT = "%"
-MOVES = "train_tokens_per_s_chip"
-SOURCE = "host_clock"
-
 
 def read(run):
     ends = run.step_ends

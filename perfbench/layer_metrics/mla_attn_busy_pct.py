@@ -4,11 +4,6 @@ time in which any operation ran on chip 0, both inside those steps'
 ``pb.engine.step`` spans (``perfbench/latent.py``). Whether the mechanism
 does the share of a step's work the cell was built for."""
 
-LAYER = "kernels"
-UNIT = "%"
-MOVES = "out_tokens_per_s"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import latent

@@ -7,11 +7,6 @@ steps' live pages, a row once at its published width: the family file's
 FLOPs over those pages' slots (``latent_attn_flops``) over the peak rate.
 At one query token a sequence the bytes are the larger by four."""
 
-LAYER = "kernels"
-UNIT = "%"
-MOVES = "itl_p95_ms"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import latent, roofline

@@ -4,11 +4,6 @@ median over the window's plain decode steps of the step record's
 the weights of the experts it touches and no others, so this is the
 share of the expert weights a step has to read."""
 
-LAYER = "expert layer"
-UNIT = "%"
-MOVES = "out_tokens_per_s"
-SOURCE = "program_counter"
-
 
 def read(run):
     from perfbench import moe

@@ -4,11 +4,6 @@ over the time in which any operation ran on chip 0, both inside the
 traced ``pb.engine.step`` spans. Whether the new mechanism does most of
 the work."""
 
-LAYER = "kernels"
-UNIT = "%"
-MOVES = "out_tokens_per_s"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import moe

@@ -9,11 +9,6 @@ the peak rate; the count functions are the family file's
 (``expert_ffn_bytes``, ``expert_ffn_flops``). A decode step at 128 pairs
 a layer is bound by the bytes."""
 
-LAYER = "kernels"
-UNIT = "%"
-MOVES = "out_tokens_per_s"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import moe, roofline

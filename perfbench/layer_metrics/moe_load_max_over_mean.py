@@ -5,11 +5,6 @@ steps of the most tokens one expert of one layer received
 step; the rows of the fullest expert are what a grouped matmul's
 longest group holds."""
 
-LAYER = "expert layer"
-UNIT = "ratio"
-MOVES = "itl_p95_ms"
-SOURCE = "program_counter"
-
 
 def read(run):
     from perfbench import moe

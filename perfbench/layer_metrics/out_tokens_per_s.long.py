@@ -1,13 +1,8 @@
 """Output tokens delivered to the clients per second of the window,
 as ``serve_cell.run`` takes it in every serving cell (``out_tokens_per_s``),
-for the cells where its runs spread too widely to be held to a bound:
-read here, with none, while ``itl_p50_ms`` is the cell's end-to-end
-metric (PERF.md section 2)."""
-
-LAYER = "serve path"
-UNIT = "tokens/s"
-MOVES = "itl_p50_ms"
-SOURCE = "host_clock"
+for the cells that are held end to end to ``tpot_mean_ms``, its inverse
+a stream, under a bound of their own (PERF.md section 2): read here,
+with none, so that the ledger keeps it in the other cells' unit."""
 
 
 def read(run):

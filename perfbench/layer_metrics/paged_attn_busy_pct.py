@@ -4,11 +4,6 @@ time in which any operation ran on chip 0, both inside those steps'
 ``pb.engine.step`` spans (``perfbench/paged_kinds.py``). Whether the
 cache does the share of a step's work the cell was built for."""
 
-LAYER = "kernels"
-UNIT = "%"
-MOVES = "itl_p50_ms"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import paged_kinds

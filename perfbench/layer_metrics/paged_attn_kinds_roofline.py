@@ -10,11 +10,6 @@ token a sequence is bound by those bytes. ``paged_attn_roofline`` counts
 every layer as reading the whole context and would read several hundred
 per cent here."""
 
-LAYER = "kernels"
-UNIT = "%"
-MOVES = "itl_p50_ms"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import paged_kinds, roofline

@@ -10,11 +10,6 @@ those bytes, not by FLOPs. Steps and spans are paired in order: both
 come from the one thread that steps the engine, and only the step in
 flight when the profiler stopped can have lost its span."""
 
-LAYER = "kernels"
-UNIT = "%"
-MOVES = "itl_p95_ms"
-SOURCE = "device_trace"
-
 
 def read(run):
     from perfbench import roofline, trace_reduce

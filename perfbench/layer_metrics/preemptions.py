@@ -2,11 +2,6 @@
 ``stats()["num_preemptions"]`` from the last step before the window to
 the window's last step."""
 
-LAYER = "KV cache"
-UNIT = "count"
-MOVES = "itl_p95_ms"
-SOURCE = "program_counter"
-
 
 def read(run):
     return run.counted_in_window("preemptions")

@@ -5,11 +5,6 @@ stall a turnover puts on the whole batch. ``xl-batch-decode`` has 4 or 5
 such steps in a 40 s window (156-157 decode steps, one turnover every 32),
 so this is the median of 4 or 5 readings."""
 
-LAYER = "engine step"
-UNIT = "ms"
-MOVES = "itl_p95_ms"
-SOURCE = "program_span"
-
 
 def read(run):
     from perfbench import steplog

@@ -4,11 +4,6 @@ inside the window. Source: client stamps and ``ProbedEngine.step``."""
 
 import statistics
 
-LAYER = "serve path"
-UNIT = "ms"
-MOVES = "itl_p95_ms"
-SOURCE = "program_span"
-
 
 def read(run):
     from perfbench.serve_cell import gaps_in

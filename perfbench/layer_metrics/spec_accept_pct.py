@@ -4,11 +4,6 @@ over the window's steps, the step records' ``accepted`` over their
 yields one token a sequence drafts nothing, its records have neither
 field, and nothing is read."""
 
-LAYER = "speculation"
-UNIT = "%"
-MOVES = "out_tokens_per_s"
-SOURCE = "program_counter"
-
 
 def read(run):
     from perfbench import steplog

@@ -5,11 +5,6 @@ and what the launch waits for). The module's device time is inside the
 step's ``infer.decode.wait``, with the model's. Nothing is read where no
 step holds the phase."""
 
-LAYER = "speculation"
-UNIT = "ms"
-MOVES = "itl_p95_ms"
-SOURCE = "program_span"
-
 
 def read(run):
     from perfbench import steplog

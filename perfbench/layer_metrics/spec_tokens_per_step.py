@@ -4,11 +4,6 @@ the step records' ``emitted`` (tokens the decode gave out) over their
 1, every draft rejected, and 2. Nothing is read of a program that does
 not draft (no ``drafted`` in its records)."""
 
-LAYER = "speculation"
-UNIT = "tokens/step"
-MOVES = "out_tokens_per_s"
-SOURCE = "program_counter"
-
 
 def read(run):
     from perfbench import steplog

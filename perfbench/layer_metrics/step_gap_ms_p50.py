@@ -5,11 +5,6 @@ waiting for it again (``serve.llm.lock_wait``). What the serve path adds
 to a token's gap, timed from inside (``serve_overhead_ms`` times it from
 outside)."""
 
-LAYER = "serve path"
-UNIT = "ms"
-MOVES = "itl_p95_ms"
-SOURCE = "program_span"
-
 
 def read(run):
     from perfbench import steplog

@@ -3,11 +3,6 @@ by the fetched loss, over the window's steps."""
 
 import statistics
 
-LAYER = "trainer"
-UNIT = "ms"
-MOVES = "train_tokens_per_s_chip"
-SOURCE = "host_clock"
-
 
 def read(run):
     ends = run.step_ends

@@ -10,6 +10,7 @@ logits check against the plain reference, and the lead-in traffic.
 
 from __future__ import annotations
 
+import bisect
 import gc
 import statistics
 import threading
@@ -135,6 +136,61 @@ def gaps_in(streams: Sequence[Stream], window: Tuple[float, float]
     lo, hi = window
     return [b - a for s in streams for a, b in zip(s.times, s.times[1:])
             if lo < b <= hi]
+
+
+def delivered_in(streams: Sequence[Stream], window: Tuple[float, float]
+                 ) -> int:
+    """Tokens that arrived inside the window, all streams."""
+    lo, hi = window
+    # Stamps are a clock's, in order: the tokens inside are a slice.
+    return sum(bisect.bisect_right(s.times, hi)
+               - bisect.bisect_right(s.times, lo) for s in streams)
+
+
+def tpot_mean(streams: Sequence[Stream], window: Tuple[float, float],
+              clients: int) -> Optional[float]:
+    """A stream's time per output token over all of the window: its
+    seconds, times the ``clients`` of a closed loop (each has one request
+    in flight or on its way at any time), over every token that arrived
+    inside it. The rate's inverse, a stream: a turnover's queueing and
+    prefill, a seat left empty, a chunk step and a stall are all in it,
+    and a program that gives a stream two tokens a step halves it."""
+    delivered = delivered_in(streams, window)
+    return (window[1] - window[0]) * clients / delivered if delivered \
+        else None
+
+
+# Tokens to a run of ``tpot_p50_ms``. Long enough that a run crosses some
+# tens of steps whatever a step yields (never zero, and a drafting
+# program's acceptance is averaged inside it), short enough that a window
+# holds about a thousand of them, so that their median is a plain step's
+# period over what a step gives and leaves out the turnovers and the
+# stalls: a per-layer reading, which says where ``tpot_mean_ms`` (all the
+# window's time over all its tokens) got its time from, and no bound holds
+# it. A constant of the metric, not of a mix: two cells read alike only
+# while they cut alike.
+TPOT_RUN = 64
+
+
+def tpot_runs(streams: Sequence[Stream], window: Tuple[float, float]
+              ) -> List[float]:
+    """A stream's time per output token, over runs of ``TPOT_RUN`` tokens.
+
+    Of each stream (one request), the tokens from its second on that
+    arrived inside the window, in order, cut into consecutive runs of
+    ``TPOT_RUN`` with the remainder dropped; a run's value is the time
+    from the token before the run to the run's last token, over
+    ``TPOT_RUN``. A run never spans two requests, and a request's first
+    token (its queueing and prefill) is in none."""
+    lo, hi = window
+    out: List[float] = []
+    for s in streams:
+        start = max(1, bisect.bisect_right(s.times, lo))
+        stop = bisect.bisect_right(s.times, hi)
+        for first in range(start, stop - TPOT_RUN + 1, TPOT_RUN):
+            out.append((s.times[first + TPOT_RUN - 1] - s.times[first - 1])
+                       / TPOT_RUN)
+    return out
 
 
 def from_due(streams: Sequence[Stream], what: str) -> List[float]:
@@ -482,7 +538,7 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
         judged = [s for s in streams if s.times and s.times[-1] > lo]
         bad = [s for s in judged if not s.ok(rows)]
     gaps = gaps_in(streams, window)
-    delivered = sum(1 for s in streams for x in s.times if lo < x <= hi)
+    delivered = delivered_in(streams, window)
     # No step before the window (no warm-up): every compile counts.
     compiles = steps[-1].compiles - (before.compiles if before else 0) \
         if steps else 0
@@ -490,10 +546,17 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
            "out_tokens_per_s": delivered / (hi - lo)}
     if gaps:
         e2e["itl_p95_ms"] = 1e3 * percentile(gaps, 95)
-        # The gap a stream sees as a rule: a plain step's length. The
-        # end-to-end metric of the cells whose tail and rate spread too
-        # widely from run to run to be held to a bound (PERF.md section 2).
-        e2e["itl_p50_ms"] = 1e3 * percentile(gaps, 50)
+    mean = tpot_mean(streams, window, int(mix["clients"])) \
+        if mix["kind"] == "closed" else None
+    if mean is not None:
+        # The end-to-end metric of the cells whose rate and tail are not
+        # held to the bounds the steadier cells keep (PERF.md section 2).
+        e2e["tpot_mean_ms"] = 1e3 * mean
+    # The same between turnovers and stalls: the median over runs of
+    # ``TPOT_RUN`` tokens, read per layer beside it.
+    runs = tpot_runs(streams, window)
+    if runs:
+        e2e["tpot_p50_ms"] = 1e3 * statistics.median(runs)
     ttft, late = (from_due(judged, what) for what in ("first_token",
                                                       "sent"))
     if ttft:
@@ -535,6 +598,14 @@ def run(*, cell, cfg, mix, dirs, seed, seconds, trace_dir, trace_seconds,
         "largest_step_gap_phase": step_gap_phase,
         "longest_step_ms": longest_ms, "longest_step_phase": longest_phase,
         "median_gap_ms": 1e3 * statistics.median(gaps) if gaps else None,
+        # Here too, for the cells that are not held to the mean; and what
+        # the median of the runs has to agree with, from the program's
+        # own step log: a plain decode step's period over the tokens a
+        # sequence is given a step.
+        "tpot_mean_ms": e2e.get("tpot_mean_ms"),
+        "tpot_runs": len(runs), "tpot_p50_ms": e2e.get("tpot_p50_ms"),
+        "tpot_quartiles_ms": [1e3 * percentile(runs, q) for q in (25, 75)]
+        if runs else None, **steplog.decode_period(logged),
         # Here too, for the cells that report it per layer: a traced
         # run's is the profiler's as much as the program's.
         "p95_gap_ms": e2e.get("itl_p95_ms"),

@@ -91,6 +91,34 @@ def step_gap_ms_p50(run) -> Optional[float]:
         b["start"] - a["end"] for a, b in zip(steps, steps[1:]))
 
 
+def decode_period(steps: Sequence[Dict]) -> Dict[str, Optional[float]]:
+    """What a stream's time per output token is made of, by the
+    program's own stamps: ``step_period_ms_p50``, the median time from a
+    plain decode step's start to the next step's; ``tokens_per_seq_step``,
+    tokens the window's decodes gave out over the sequences they ran (1
+    where the program does not draft); ``prefill_share_ms``, what the
+    steps that held a prefill took beyond that median, spread over all
+    the decoding steps. The first over the second is what
+    ``tpot_p50_ms`` should read at the client; ``tpot_mean_ms`` lies over
+    that by the third, by the plain steps' own tail (their mean over
+    their median), by the seats a turnover leaves empty and by any
+    stall."""
+    pairs = [(a, b["start"] - a["start"]) for a, b in zip(steps, steps[1:])
+             if a["decodes"]]
+    plain = [1e3 * p for a, p in pairs if not a.get("prefills")]
+    out = dict.fromkeys(("step_period_ms_p50", "tokens_per_seq_step",
+                         "prefill_share_ms"))
+    if plain:
+        period = out["step_period_ms_p50"] = statistics.median(plain)
+        out["tokens_per_seq_step"] = sum(
+            a.get("emitted", a["decodes"]) for a, _ in pairs) \
+            / sum(a["decodes"] for a, _ in pairs)
+        out["prefill_share_ms"] = sum(
+            1e3 * p - period for a, p in pairs if a.get("prefills")) \
+            / len(pairs)
+    return out
+
+
 def largest_step_gap(steps: Sequence[Dict]
                      ) -> Tuple[Optional[float], Optional[str]]:
     """The longest time from one step's end to the next one's start, in

@@ -29,8 +29,10 @@ TWOTOKEN = os.path.join(HERE, "twotoken")
 ROWS = os.path.join(HERE, "rows")
 MELLUM = os.path.join(HERE, "mellum")
 V = 7
-SERVING_MIXES = ("batch-decode", "moe-batch-decode", "long-decode",
-                 "latent-decode")
+SERVING_MIXES = ("batch-decode", "moe-batch-decode", "latent-decode")
+# Their clients sample: K-EXAONE's since PR 42, Mellum's since PR 46 (so
+# that the seed does not decide how far the routing collapses).
+SAMPLED_MIXES = ("long-decode", "selfdraft-decode")
 
 
 def committed_mix(name):
@@ -212,6 +214,18 @@ def test_a_committed_mix_sends_todays_call(name):
         s = Stream(index, [1, 2, 3], 2,
                    sampling=traffic.request_sampling(mix, SEED, index))
         assert consume(s) == [(([1, 2, 3],), {"max_new_tokens": 2})]
+
+
+@pytest.mark.parametrize("name", SAMPLED_MIXES)
+def test_a_committed_mix_that_samples_asks_it_of_every_request(name):
+    mix = committed_mix(name)
+    assert mix["sampling"] == {"temperature": 1.0} and mix["sampling_why"]
+    asked = [traffic.request_sampling(mix, SEED, i)
+             for i in (-100, -1, 0, 5)]
+    assert all(a["temperature"] == 1.0 and a["top_k"] == 0 for a in asked)
+    assert len({a["seed"] for a in asked}) == 4
+    s = Stream(5, [1, 2, 3], 2, sampling=asked[3])
+    assert consume(s) == [(([1, 2, 3],), {"max_new_tokens": 2, **asked[3]})]
 
 
 def test_a_mix_with_sampling_says_how_every_request_samples():
@@ -425,11 +439,12 @@ def two_token_probe():
         probe.ProbedEngine.__bases__ = bases
 
 
-def two_token_cell(monkeypatch, tmp_path, traced, misreport=False):
+def two_token_cell(monkeypatch, tmp_path, traced, misreport=False,
+                   like="xl-batch-decode"):
     monkeypatch.setattr(TwoTokenEngine, "misreport", misreport)
     monkeypatch.setattr(TwoTokenEngine, "accepted", 0)
     monkeypatch.setattr(TwoTokenEngine, "rejected", 0)
-    bench = benchmark_with({"tiny-sampled-closed": ("xl-batch-decode", 1)})
+    bench = benchmark_with({"tiny-sampled-closed": (like, 1)})
     result = run.run_cell(
         bench, [TWOTOKEN, REHEARSAL, run.HERE], "tiny-sampled-closed", SEED,
         1.5, traced, require_tpu=False, work_dir=str(tmp_path))
@@ -462,6 +477,34 @@ def test_a_step_of_two_tokens_is_checked_and_timed(traced, tmp_path,
         assert set(got) == names(bench, "end_to_end", "xl-batch-decode")
         assert all(math.isfinite(m["value"]) and m["value"] > 0
                    for m in got.values())
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_a_step_of_two_tokens_has_a_time_per_output_token(
+        traced, tmp_path, monkeypatch, two_token_probe):
+    """The cell of a self-drafting program is held to ``tpot_mean_ms``,
+    the window over what its steps gave a stream, and reads
+    ``tpot_p50_ms`` per layer: two tokens of a step reach a stream
+    together, so a gap in three is zero here and neither reading is;
+    the median of runs lies under a step's length, because some steps
+    gave two."""
+    monkeypatch.setattr(serve_cell, "TPOT_RUN", 4)   # requests of 16 tokens
+    bench, result, engine = two_token_cell(
+        monkeypatch, tmp_path, traced, like="kexaone-selfdraft-decode")
+    assert result["correct"] is True, result
+    got = result["metrics"]
+    assert TwoTokenEngine.accepted > 20 and TwoTokenEngine.rejected > 20
+    steps = [r.end - r.start for r in engine.steps if r.decodes]
+    if traced:
+        assert 0.4 * 1e3 * min(steps) < got["tpot_p50_ms"]["value"] \
+            < 1e3 * max(steps)
+        return
+    assert set(got) == names(bench, "end_to_end",
+                             "kexaone-selfdraft-decode") \
+        == {"tpot_mean_ms", "setup_s"}
+    # Every step gave a stream one token or two; turnovers, prefills and
+    # waits come on top of the decode steps.
+    assert got["tpot_mean_ms"]["value"] > 0.5 * 1e3 * min(steps)
 
 
 def test_a_row_too_many_reads_not_correct(tmp_path, monkeypatch,

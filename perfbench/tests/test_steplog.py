@@ -318,3 +318,48 @@ def test_medians_of_a_hand_made_window(monkeypatch):
     # Only steps that ended inside the window count.
     data.window = (steps[1]["end"], steps[3]["end"])
     assert len(steplog.window_steps(data)) == 2
+
+
+# ---- what tpot_p50_ms (per layer) has to agree with --------------------------------------------
+
+
+def plain_steps(n, period, **fields):
+    return [dict(start=k * period, end=k * period + 0.8 * period,
+                 decodes=16, phases=[], **fields) for k in range(n)]
+
+
+@pytest.mark.parametrize("emitted,tokens", [(None, 1.0), (16, 1.0),
+                                            (22, 1.375), (32, 2.0)])
+def test_decode_period_is_a_plain_steps_period_over_what_it_gave(emitted,
+                                                                 tokens):
+    """A program that does not draft has no ``emitted`` in its records:
+    a token a sequence a step."""
+    fields = {} if emitted is None else {"emitted": emitted, "drafted": 16}
+    got = steplog.decode_period(plain_steps(50, 0.0125, **fields))
+    assert got["step_period_ms_p50"] == pytest.approx(12.5)
+    assert got["tokens_per_seq_step"] == pytest.approx(tokens)
+    assert got["prefill_share_ms"] == 0.0
+    assert set(got) == {"step_period_ms_p50", "tokens_per_seq_step",
+                        "prefill_share_ms"}
+
+
+def test_decode_period_spreads_the_prefill_steps_over_all():
+    """One step in ten holds a chunk and takes 100 ms more: 10 ms a step
+    on the plain period, which the median of the plain steps leaves out."""
+    steps, now = [], 0.0
+    for k in range(101):
+        chunk = k % 10 == 5
+        steps.append(dict(start=now, end=now + 0.010, decodes=32,
+                          phases=[], **({"prefills": [{}]} if chunk else {})))
+        now += 0.0125 + (0.100 if chunk else 0.0)
+    got = steplog.decode_period(steps)
+    assert got["step_period_ms_p50"] == pytest.approx(12.5)
+    assert got["prefill_share_ms"] == pytest.approx(10.0)
+    assert got["tokens_per_seq_step"] == 1.0
+
+
+def test_decode_period_of_a_window_without_a_plain_step_is_nothing():
+    for steps in ([], plain_steps(1, 0.0125),
+                  [dict(start=0.0, end=1.0, decodes=0, phases=[]),
+                   dict(start=1.0, end=2.0, decodes=0, phases=[])]):
+        assert set(steplog.decode_period(steps).values()) == {None}
