@@ -135,7 +135,7 @@ class Right:
                 tokens[o.request_id].append(o.token_id)
             state = np.asarray(eng._draft_state[1])
             for seq in seqs:
-                slot = eng._slot_of.get(seq.request_id)
+                slot = eng.cache._seats.get(seq.request_id)
                 if slot is not None and seq.cached_len >= seq.prefill_len:
                     drafts[seq.request_id][seq.cached_len - 1] = state[slot]
         eng.stop_capture()

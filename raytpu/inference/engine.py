@@ -24,6 +24,17 @@ The TPU compile-once discipline, concretely:
   writes a sequence's positions the engine slides its window table on
   (``cache.slide``); a model of one kind gets the single arrays and the
   programs it always had.
+- **A layer that keeps a state** (``serving.layer_states``: a short
+  convolution's newest input rows) has no pool. The pools are the other
+  layers' alone, and such a layer has one state array ``[max_num_seqs +
+  1, *shape]`` (``self.cache.state``), given to the three programs
+  donated behind the pools and rebound with them. A sequence's row of
+  every state array is its *seat* (``cache.seat``: taken and given back
+  with its pages, so admission stops when either runs out, and a
+  preempted sequence recomputes both); a program is given its sequences'
+  seats as data, a padding row seat 0, the scratch row as page 0 is the
+  scratch page. There is one seat map: what a drafting model's module
+  leaves between steps (below) lies at the same seats.
 
 The jitted callables are constructed exactly once, the three programs
 by the one ``_build_program`` and the sampler by ``_build_sampler`` —
@@ -50,12 +61,12 @@ programs go out back to back with no host round trip between them:
 result is ``[bucket, 2, V]``), ``_accept_fn`` (accept or resample: ``int32
 [bucket, 2]`` ids come back, -1 where the draft was not kept) and
 ``_draft_fn`` (the module over the kept tokens, which writes its own pool
-and leaves the next draft and its logits on the device, a row a sequence
-slot: ``_draft_state``). The prefill and chunk programs hold the module
-too and sample the first token inside, so every decoding sequence has a
-draft. The module's pool is one more full-attention pool behind the full
-layers' tables; a rejected draft's rows, there and in the model's pools,
-are written again by the next step.
+and leaves the next draft and its logits on the device, a row a
+sequence's seat: ``_draft_state``). The prefill and chunk programs hold
+the module too and sample the first token inside, so every decoding
+sequence has a draft. The module's pool is one more full-attention pool
+behind the full layers' tables; a rejected draft's rows, there and in
+the model's pools, are written again by the next step.
 """
 
 from __future__ import annotations
@@ -86,6 +97,8 @@ _waiting_gauge = Gauge("raytpu_infer_waiting_requests",
                        "Requests queued for admission")
 _kv_util_gauge = Gauge("raytpu_infer_kv_page_utilization",
                        "Fraction of KV pages in use")
+_state_seats_gauge = Gauge("raytpu_infer_state_seats_in_use",
+                           "Sequences that hold a seat in the state arrays")
 _prefill_tps_gauge = Gauge("raytpu_infer_prefill_tokens_per_s",
                            "Prefill throughput of the last engine step")
 _decode_tps_gauge = Gauge("raytpu_infer_decode_tokens_per_s",
@@ -116,7 +129,9 @@ class StepOutput:
 
 # Where ``ks`` and ``vs`` stand in the three programs' arguments: given
 # donated, so each program updates the pools in the buffers it received.
+# The state arrays of a model whose layers keep a state stand behind them.
 _POOLS = (1, 2)
+_POOLS_AND_STATE = (1, 2, 3)
 
 
 def _pow2_buckets(lo: int, hi: int) -> List[int]:
@@ -169,7 +184,9 @@ class InferenceEngine:
     has the copy's bytes by dtype, ``stats()["kv_pool_bytes"]`` the
     bytes of the 2 x layers KV pools (``kv_pool_bytes_by_kind``: of the
     full and of the window layers'), or of a latent-attention model's
-    one pool a layer (``serving.kv_row``: ``cache.v`` is then empty).
+    one pool a layer (``serving.kv_row``: ``cache.v`` is then empty),
+    ``stats()["state_bytes"]`` those of the state arrays of the layers
+    that keep a state and no pool (``serving.layer_states``).
 
     A program that fails while it runs (not while it is traced or
     compiled) has consumed the pools it was given and returned none:
@@ -196,6 +213,10 @@ class InferenceEngine:
                 f"served: the engine asks a model config for `serving` "
                 f"(the family's prefill, prefill_chunk and decode entry "
                 f"points, its working-copy rule, kv_heads, head_dim)")
+        if served.layer_states and (tp > 1 or mesh is not None):
+            raise ValueError(
+                "a model with layers that keep a state is served on one "
+                "device: its state arrays are not sharded yet")
         # A routed-expert family: its programs return a fourth value.
         if served.expert_counts and (tp > 1 or mesh is not None):
             raise ValueError(
@@ -207,6 +228,12 @@ class InferenceEngine:
                 "device: every head reads the whole row, so the pool has "
                 "no head axis to shard on")
 
+        if served.layer_states and enable_prefix_cache:
+            raise ValueError(
+                "a model with layers that keep a state is served without "
+                "the prefix cache: the state at a prefix's end is in none "
+                "of its pages")
+
         # Self-drafting: on for a family that has a prediction module,
         # unless asked off; such an engine runs the one-position programs.
         if drafting and served.drafting is None:
@@ -214,6 +241,10 @@ class InferenceEngine:
                 f"drafting=True: {type(model_config).__name__} has no "
                 f"prediction module to draft with (`serving.drafting`)")
         self._drafting = served.drafting if drafting is not False else None
+        if self._drafting and served.layer_states:
+            raise ValueError(
+                "drafting over layers that keep a state is not there yet: "
+                "a rejected draft's row has moved the state on")
         # Tokens each expert received, a row a routed layer; the module's
         # routed layers after the model's.
         self._expert_tokens = None
@@ -269,16 +300,22 @@ class InferenceEngine:
             window_pages = PagedKVCache.window_pool_pages(
                 window, page_size, max_num_seqs, self.prefill_chunk)
         elif enable_prefix_cache is None:
-            enable_prefix_cache = True
+            enable_prefix_cache = not served.layer_states
         # The module's pools come after the model's: full-attention ones.
+        # A layer that keeps a state has no pool, and a sequence a seat
+        # in its state array; a drafting engine seats the module's state.
         module_pools = self._drafting.pools if self._drafting else 0
+        state_shapes = [shape for shape in served.layer_states if shape]
         self.cache = PagedKVCache(
-            model_config.n_layer + module_pools, num_pages, page_size,
+            model_config.n_layer - len(state_shapes) + module_pools,
+            num_pages, page_size,
             served.kv_heads, served.head_dim, dtype=model_config.dtype,
             layer_windows=served.layer_windows and (
                 *served.layer_windows, *(None,) * module_pools),
             window_pages=window_pages,
-            window_burst=self.prefill_chunk, latent_row=served.kv_row)
+            window_burst=self.prefill_chunk, latent_row=served.kv_row,
+            state_shapes=state_shapes,
+            seats=max_num_seqs if state_shapes or self._drafting else 0)
         # Tensor parallelism: shard the weights with the parallel-layer
         # rule table and the KV pools along their last dimension, whole
         # heads to a shard (a head's features are contiguous). Each jit
@@ -325,6 +362,7 @@ class InferenceEngine:
         # A token's bytes in the pools of every layer, as held (a latent
         # row is held on whole tiles).
         self._kv_token_bytes = self.cache.token_bytes
+        self._state_bytes = sum(a.nbytes for a in self.cache.state)
         self._devices = sorted(f"{d.platform}:{d.id}"
                                for d in self.cache.k[0].devices())
         self.prefix_cache = (PrefixCache(self.cache)
@@ -392,11 +430,9 @@ class InferenceEngine:
         # One XLA program per key: a prompt's length bucket; a chunk's
         # length (a decode's batch) bucket x the trimmed table width.
         if self._drafting is not None:
-            # A slot a running sequence (and one that padding rows name)
-            # in what the module leaves on the device between steps: the
-            # next draft and the logits it was drawn from.
-            self._slot_of: Dict[str, int] = {}
-            self._free_slots = list(range(max_num_seqs))
+            # What the module leaves on the device between steps, the
+            # next draft and the logits it was drawn from: a row a
+            # sequence's seat (``cache.seat``; padding rows name row 0).
             self._draft_state = (
                 self._put(np.zeros(max_num_seqs + 1, np.int32)),
                 self._put(np.zeros((max_num_seqs + 1,
@@ -437,15 +473,20 @@ class InferenceEngine:
     def _build_program(self, jax, name, fwd, compiles, bucket_key):
         """One of the three jitted programs, ``(params, ks, vs, *inputs)
         -> (logits, ks, vs[, expert count])``: the family's entry point
-        ``fwd`` on the donated pools. ``compiles`` counts its traces
-        under ``bucket_key(*inputs)``; ``name`` is what a trace and the
-        compile cache know the program by."""
+        ``fwd`` on the donated pools. Of a model whose layers keep a
+        state, ``(params, ks, vs, states, seats, *inputs) -> (logits, ks,
+        vs, states[, expert count])``, the state arrays donated too.
+        ``compiles`` counts its traces under ``bucket_key(*inputs)``;
+        ``name`` is what a trace and the compile cache know the program
+        by."""
         cfg, kv_sh = self._config, self._kv_sharding
+        carried = 2 if self.cache.state else 0  # states and seats
 
         def program(params, ks, vs, *inputs):
+            state, inputs = inputs[:carried], inputs[carried:]
             with self._traced(name, compiles, bucket_key(*inputs)):
-                logits, ks2, vs2, *experts = fwd(
-                    cfg, params, *inputs, ks, vs)
+                logits, ks2, vs2, *more = fwd(
+                    cfg, params, *inputs, ks, vs, *state)
             if kv_sh is not None:
                 # Pin the pool sharding through the update: the pools
                 # must come back kv-head-sharded, never resharded.
@@ -453,10 +494,11 @@ class InferenceEngine:
                        for x in ks2]
                 vs2 = [jax.lax.with_sharding_constraint(x, kv_sh)
                        for x in vs2]
-            return (logits, ks2, vs2, *experts)
+            return (logits, ks2, vs2, *more)
 
         program.__name__ = name
-        return jax.jit(program, donate_argnums=_POOLS)
+        return jax.jit(program, donate_argnums=_POOLS_AND_STATE if carried
+                       else _POOLS)
 
     def _build_sampler(self, jax, compiles):
         """The jitted sampler, ``(logits, temperature, top_k, seed,
@@ -586,6 +628,34 @@ class InferenceEngine:
             self._sampled_batch = (list(seqs), rows, stochastic)
         return rows, stochastic
 
+    def _seats(self, ids: SequenceT[str], bucket: int) -> np.ndarray:
+        """Each sequence's seat, int32 ``[bucket]``; padding rows name
+        seat 0, the scratch row."""
+        seats = np.zeros(bucket, dtype=np.int32)
+        seats[:len(ids)] = [self.cache.seat(rid) for rid in ids]
+        return seats
+
+    def _program_args(self, ids: SequenceT[str], bucket: int) -> tuple:
+        """What each of the three programs takes before its inputs: the
+        working copy, the pools, and of a model whose layers keep a state
+        the state arrays and the seats of the ``bucket`` rows' sequences."""
+        cache = self.cache
+        if not cache.state:
+            return self._params, cache.k, cache.v
+        return (self._params, cache.k, cache.v, cache.state,
+                self._put(self._seats(ids, bucket)))
+
+    def _call(self, fn, ids: SequenceT[str], bucket: int, *inputs):
+        """Run one of the three programs for the sequences ``ids`` and
+        rebind what it consumed: the pools and the state arrays. Returns
+        ``(logits, what else it returned: a routed model's count)``."""
+        cache = self.cache
+        logits, cache.k, cache.v, *more = fn(
+            *self._program_args(ids, bucket), *inputs)
+        if cache.state:
+            cache.state, *more = more
+        return logits, more
+
     def _by_kind(self, of):
         """``of(kind)`` on the device, as a program takes what it is
         given a kind of pool (``dests``, block tables): the one array,
@@ -666,6 +736,7 @@ class InferenceEngine:
                 "live_pages": 0, "live_pages_full": 0,
                 "live_pages_window": 0, "window_pages_released": 0,
                 "pages_owned_full": 0, "pages_owned_window": 0,
+                "state_seats": 0, "state_bytes": 0,
                 "sampled_stochastic": 0,
                 "kv_bytes_per_token": self._kv_token_bytes} | (
                     {"drafted": 0, "accepted": 0, "emitted": 0}
@@ -676,12 +747,6 @@ class InferenceEngine:
                 ph.attrs["admitted"] = st.attrs["admitted"] = (
                     waiting + len(plan.preempted)
                     - len(self.scheduler.waiting))
-                if self._drafting:
-                    # The slots of what has finished or was preempted.
-                    running = {s.request_id for s in self.scheduler.running}
-                    for rid in [r for r in self._slot_of
-                                if r not in running]:
-                        self._free_slots.append(self._slot_of.pop(rid))
             # The mesh is thread-local state and any thread may step.
             with (self._jax.set_mesh(self.mesh) if self.mesh is not None
                   else contextlib.nullcontext()):
@@ -698,6 +763,10 @@ class InferenceEngine:
                 st.attrs["pages_owned_full"] = self.cache.used_pages()
                 st.attrs["pages_owned_window"] = \
                     self.cache.window_pages_owned()
+            if self.cache.total_seats:
+                st.attrs["state_seats"] = seats = self.cache.seats_in_use()
+                st.attrs["state_bytes"] = seats * self.cache.state_bytes
+                _state_seats_gauge.set(seats)
 
             # Throughput gauges reflect THIS step — a step that moved no
             # tokens zeroes them, so autoscalers never read the last busy
@@ -822,8 +891,7 @@ class InferenceEngine:
                 self._put(tokens), self._put(positions), dests, tables)
             program = ("_chunk", f"{bucket}x{p_used}")
         if self._drafting is None:
-            logits, ks, vs, *experts = fn(
-                self._params, self.cache.k, self.cache.v, *inputs)
+            logits, experts = self._call(fn, [seq.request_id], 1, *inputs)
         else:
             # The tokens that follow the rows', for the module; -1 where
             # a fresh prompt ends and the program samples the one to come.
@@ -831,17 +899,16 @@ class InferenceEngine:
             known = seq.tokens[start + 1:end + 1]
             following[0, :len(known)] = known
             following[0, len(known):take] = -1
-            if seq.request_id not in self._slot_of:
-                self._slot_of[seq.request_id] = self._free_slots.pop()
             rows, stochastic = self._sampling_rows([seq], 1)
-            logits, ks, vs, count, first, self._draft_state = fn(
+            (logits, self.cache.k, self.cache.v, count, first,
+             self._draft_state) = fn(
                 self._params, self.cache.k, self.cache.v, self._draft_state,
                 (self._put(following), self._put(np.int32(take - 1)),
                  self._put(np.int32(end - 1)),
-                 self._put(np.int32(self._slot_of[seq.request_id])), *rows),
+                 self._put(np.int32(self.cache.seat(seq.request_id))),
+                 *rows),
                 *inputs)
             experts = [count]
-        self.cache.k, self.cache.v = ks, vs
         self._count_experts(experts, program)
         seq.cached_len = end
         # The chunk's burst goes back: what the next query will see stays.
@@ -917,11 +984,10 @@ class InferenceEngine:
                     decodes=b, bucket=bucket, table_width=P,
                     live_pages=live_pages, live_pages_full=live_pages)
                 device_positions = self._put(positions)
-                logits, ks, vs, *experts = self._decode_fn(
-                    self._params, self.cache.k, self.cache.v,
-                    self._put(tokens), device_positions,
-                    dests, tables, self._put(context_lens))
-                self.cache.k, self.cache.v = ks, vs
+                logits, experts = self._call(
+                    self._decode_fn, ids, bucket, self._put(tokens),
+                    device_positions, dests, tables,
+                    self._put(context_lens))
                 for count in experts:
                     # Asked for now, it comes back beside the ids; left
                     # to the wait it is a transfer of its own, 0.5 ms.
@@ -955,10 +1021,11 @@ class InferenceEngine:
                 flops = prof.ensure_flops(
                     ("decode", bucket, P),
                     lambda: cost_analysis_flops(
-                        self._decode_fn, self._params, self.cache.k,
-                        self.cache.v, self._put(tokens),
-                        self._put(positions), dests, tables,
-                        self._put(context_lens)))
+                        self._decode_fn,
+                        *self._program_args([s.request_id for s in seqs],
+                                            bucket),
+                        self._put(tokens), self._put(positions), dests,
+                        tables, self._put(context_lens)))
                 # Launch to token ids on the host: the real step.
                 prof.observe_step(wait.t1 - launch.t0, flops=flops)
                 self._hbm_tick += 1
@@ -982,18 +1049,16 @@ class InferenceEngine:
                 tokens = np.zeros(bucket, dtype=np.int32)
                 positions = np.zeros(bucket, dtype=np.int32)
                 # Padding: the scratch page's first two slots, the
-                # scratch slot of the module's state.
+                # scratch row of the module's state.
                 dests = [np.tile(np.arange(2, dtype=np.int32), (bucket, 1))
                          for _ in self.cache.kinds]
-                slots = np.full(bucket, len(self._draft_state[0]) - 1,
-                                dtype=np.int32)
+                slots = self._seats(ids, bucket)
                 fields = recorder.open.fields
                 live_pages = 0
                 for i, seq in enumerate(seqs):
                     pos = seq.cached_len
                     tokens[i] = (seq.generated or seq.prompt)[-1]
                     positions[i] = pos
-                    slots[i] = self._slot_of[seq.request_id]
                     live_pages += self.cache.pages_for(pos + 2)
                     for kind in self.cache.kinds:
                         if kind:  # the window table slid on to both
@@ -1152,7 +1217,11 @@ class InferenceEngine:
         ``window_pages_released`` (pages the window tables gave back in
         the step), ``pages_owned_full`` and ``pages_owned_window`` (pages
         sequences own in one pool of each kind when the step ends; both
-        0 for a model without window layers), ``sampled_stochastic`` (rows
+        0 for a model without window layers), ``state_seats`` (sequences
+        that hold a seat when the step ends: of a model whose layers keep
+        a state, or that drafts for itself; else 0) and ``state_bytes``
+        (what they hold in the state arrays: seats x
+        ``PagedKVCache.state_bytes``), ``sampled_stochastic`` (rows
         whose token was drawn and not the argmax: how often the sampler's
         stochastic branch had work), for a model that drafts for itself
         ``drafted`` (drafts the decode verified: its sequences),
@@ -1219,6 +1288,12 @@ class InferenceEngine:
             # same by kind of layer (``window``: 0 without such layers).
             "kv_pool_bytes": self._kv_pool_bytes,
             "kv_pool_bytes_by_kind": dict(self._kv_pool_bytes_by_kind),
+            # Of the layers that keep a state and no pool: the state
+            # arrays' bytes; and the seats, of those or of a drafting
+            # model's module (both 0 where no seat map is kept).
+            "state_bytes": self._state_bytes,
+            "state_seats": self.cache.seats_in_use(),
+            "state_seats_total": self.cache.total_seats,
             "devices": list(self._devices),
             "num_preemptions": self.scheduler.num_preemptions,
             "running": len(self.scheduler.running),

@@ -69,6 +69,26 @@ written to the scratch page. Admission keeps one seat of
 exactly the pools and tables described above. Pages of a window pool are
 never shared: a prompt's are gone by the time another could use them,
 so the prefix cache and the KV hand-off refuse such a model.
+
+**Seats, and layers that keep a state.** A layer need not hold keys and
+values at all: a short convolution keeps the newest rows of its input and
+nothing else, the same few rows however long the sequence. Such a layer
+(``state_shapes``) has no pool of pages here but one *state array*
+``[seats + 1, *shape]``, ``state[i]``, and a sequence one *seat* in all of
+them: row ``seat`` is its state, read and written by the programs as the
+pools are (donated, rebound after every call). Seat 0 is scratch, as page
+0 is: a padded decode batch's dummy rows read and write it. A seat is
+taken with a sequence's pages (:meth:`allocate` fails when either runs
+out) and given back with them (:meth:`free`); nothing clears it in
+between, so the first program that writes a sequence's state (the one
+that holds its position 0) starts from zeros and not from what the seat
+holds. A preempted sequence loses its seat with its pages and recomputes
+both. The state at the end of a prefix is nowhere in the prefix's pages,
+so the prefix cache and the KV hand-off refuse such a model too. The seat
+map is kept whenever ``seats`` is given, state arrays or none: the engine
+of a model that drafts for itself seats what its module leaves between
+steps by it. ``token_bytes`` is what a token costs in the pools and
+``state_bytes`` what a sequence costs in the state arrays.
 """
 
 from __future__ import annotations
@@ -106,13 +126,19 @@ class PagedKVCache:
             latent_row]``, from whose rows keys and values are both
             read: ``k`` holds it and ``v`` is empty. Tables, slots and
             the free list are what they are for any model of one kind.
+        state_shapes: the shape of the state a sequence keeps in each
+            layer that has one and no pool (``num_layers`` counts the
+            layers with pools alone); needs ``seats``.
+        seats: sequences that can hold a seat at once; 0 keeps no seat
+            map.
     """
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_kv_heads: int, head_dim: int, dtype=None, *,
                  layer_windows: Sequence[Optional[int]] = (),
                  window_pages: Optional[int] = None, window_burst: int = 1,
-                 latent_row: Optional[int] = None):
+                 latent_row: Optional[int] = None,
+                 state_shapes: Sequence[Sequence[int]] = (), seats: int = 0):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is scratch)")
         if page_size < 1:
@@ -156,6 +182,17 @@ class PagedKVCache:
         self.k: List = [jnp.zeros(shape, self.dtype) for shape in shapes]
         self.v: List = [] if latent_row else [
             jnp.zeros(shape, self.dtype) for shape in shapes]
+        if state_shapes and seats < 1:
+            raise ValueError("a layer that keeps a state needs `seats`")
+        # One state array a layer that keeps one; row 0 is scratch.
+        self.state: List = [jnp.zeros((seats + 1, *shape), self.dtype)
+                            for shape in state_shapes]
+        # Bytes one sequence costs in them: its seat's row in each.
+        self.state_bytes = sum(math.prod(shape) for shape in state_shapes) \
+            * jnp.dtype(self.dtype).itemsize
+        self.total_seats = seats
+        self._free_seats: List[int] = list(range(seats, 0, -1))
+        self._seats: Dict[str, int] = {}
         # The window pools' free list and each sequence's sliding table,
         # [first logical page, its pages from there].
         self._wfree: List[int] = list(range(self.num_window_pages - 1, 0, -1))
@@ -200,6 +237,13 @@ class PagedKVCache:
         """Bytes one token costs in the pools of every layer, as held: a
         row of K and one of V a layer, or a latent layer's one row."""
         return sum(a.shape[2] * a.dtype.itemsize for a in self.k + self.v)
+
+    def seats_in_use(self) -> int:
+        return len(self._seats)
+
+    def seat(self, seq_id: str) -> int:
+        """``seq_id``'s row of the state arrays (never 0, the scratch)."""
+        return self._seats[seq_id]
 
     @property
     def kinds(self) -> tuple:
@@ -258,11 +302,13 @@ class PagedKVCache:
         All-or-nothing: returns False (allocating nothing) if the free
         list cannot cover the request. Raises if ``seq_id`` already has
         a table — callers must :meth:`free` before re-allocating.
+        Where seats are kept the sequence takes one too, and the call
+        fails when none is free, whatever the pages.
         """
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
         need = self.pages_for(max(1, num_tokens))
-        if not self._window_seat() or not self._reserve(need):
+        if not self._has_seat() or not self._reserve(need):
             return False
         self._tables[seq_id] = [self._take_free() for _ in range(need)]
         self._seat(seq_id)
@@ -292,7 +338,7 @@ class PagedKVCache:
         # the retainer's eviction list.
         for page in prefix_pages:
             self._incref(page)
-        if not self._window_seat() or not self._reserve(tail):
+        if not self._has_seat() or not self._reserve(tail):
             for page in reversed(prefix_pages):
                 self._decref(page)  # rollback: back to parked/free
             return False
@@ -316,10 +362,14 @@ class PagedKVCache:
         return True
 
     def free(self, seq_id: str) -> None:
-        """Release a sequence's pages (idempotent). A page returns to
-        the pool only when its last reference drops; ref-0 pages the
-        retainer claims stay out of the free list but reclaimable."""
+        """Release a sequence's pages and its seat (idempotent). A page
+        returns to the pool only when its last reference drops; ref-0
+        pages the retainer claims stay out of the free list but
+        reclaimable."""
         table = self._tables.pop(seq_id, None)
+        seat = self._seats.pop(seq_id, None)
+        if seat is not None:
+            self._free_seats.append(seat)
         first_pages = self._wtables.pop(seq_id, None)
         if first_pages:
             self._wfree.extend(reversed(first_pages[1]))
@@ -331,15 +381,19 @@ class PagedKVCache:
 
     # ---- a window layer's sliding tables -----------------------------
 
-    def _window_seat(self) -> bool:
-        """Whether the window pools have a seat for one more sequence
-        (always, for a model without window layers)."""
-        return self.window is None \
-            or len(self._wtables) < self.max_window_seqs
+    def _has_seat(self) -> bool:
+        """Whether one more sequence can be seated: in the window pools
+        (always, for a model without window layers) and in the seat map
+        (always, where none is kept)."""
+        return (self.window is None
+                or len(self._wtables) < self.max_window_seqs) \
+            and (not self.total_seats or bool(self._free_seats))
 
     def _seat(self, seq_id: str) -> None:
         if self.window is not None:
             self._wtables[seq_id] = [0, []]
+        if self.total_seats:
+            self._seats[seq_id] = self._free_seats.pop()
 
     def slide(self, seq_id: str, lo: int, hi: int) -> int:
         """Before a program whose queries for ``seq_id`` stand at

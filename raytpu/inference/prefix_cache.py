@@ -84,6 +84,11 @@ class PrefixCache:
                 "no prefix cache over window layers: a prompt's window "
                 "pages are given back as the window slides on, so a "
                 "later prompt finds its prefix in the full pools only")
+        if cache.state:
+            raise ValueError(
+                "no prefix cache over layers that keep a state: the state "
+                "at a prefix's end is in none of its pages, and a sequence "
+                "that took them would start behind a state nobody kept")
         self.cache = cache
         self.page_size = cache.page_size
         # chain hash -> page id holding that page's KV

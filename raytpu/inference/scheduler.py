@@ -7,12 +7,16 @@ YOUNGEST sequence (latest arrival) to recompute later when pages run
 out, so the oldest requests always make progress and the total
 recomputation bill is minimized — and (2) admits waiting requests
 FIFO while both a sequence slot and enough KV pages for their prompt
-are available. Fresh prefills therefore merge with in-flight decodes
+are available, and where the cache keeps seats (a model whose layers
+keep a state, :mod:`raytpu.inference.kv_cache`) a seat: ``cache.allocate``
+gives pages and seat together or neither, so admission stops at the last
+seat whatever pages are left. Fresh prefills therefore merge with in-flight decodes
 in the same iteration instead of waiting for the batch to drain
 (the continuous-batching throughput lever).
 
 Preemption is preempt-to-RECOMPUTE (vLLM's default for small
-sequences): the victim's pages are freed, its ``cached_len`` drops to
+sequences): the victim's pages are freed (and its seat: the state it
+held is recomputed with its keys and values), its ``cached_len`` drops to
 0, and it re-enters the FRONT of the waiting queue; when re-admitted,
 its prompt *plus everything it already generated* is re-prefetched in
 one bucketed prefill. Already-sampled tokens are never re-sampled, so

@@ -45,7 +45,11 @@ prefix cache works over its pages, a role is refused) and "exaone_moe"
 (window layers among rope-less full ones, a sigmoid-routed expert layer
 and a prediction module through which the model drafts for itself: a
 decode step yields a sequence one token or two; as "mellum", no prefix
-cache and no role). Each reaches the
+cache and no role) and "lfm2_moe" (gated short convolutions in three
+layers of four, which keep a state a sequence at its seat and no keys or
+values, beside full-attention layers' pools and sigmoid-routed experts;
+no prefix cache and no role: a prefix's pages say nothing of the state
+at its end). Each reaches the
 engine through its config's ``serving`` and nothing else.
 """
 
@@ -313,11 +317,12 @@ class LLMDeployment:
     """Serve a decoder LM with continuous batching + streaming tokens.
 
     Args:
-        model: "llama", "gpt2", "mixtral", "olmoe", "mellum", "joyai" or
-            "exaone_moe".
+        model: "llama", "gpt2", "mixtral", "olmoe", "mellum", "joyai",
+            "exaone_moe" or "lfm2_moe".
         model_config: the family's config (``LlamaConfig``,
             ``GPT2Config``, ``MixtralConfig``, ``OlmoeConfig``,
-            ``MellumConfig``, ``JoyAIConfig``, ``ExaoneMoeConfig``) or a
+            ``MellumConfig``, ``JoyAIConfig``, ``ExaoneMoeConfig``,
+            ``Lfm2MoeConfig``) or a
             kwargs dict for one. Defaults to the family's ``tiny()``
             config in fp32/reference-attention mode (CPU-runnable).
         engine_options: kwargs forwarded to :class:`InferenceEngine`
@@ -336,7 +341,9 @@ class LLMDeployment:
             prefix-cache pages, and it is served without that cache.
             Nor does a latent-attention model ("joyai": one pool a
             layer): the hand-off's wire segments are pages of K and of V,
-            ``kv_heads * head_dim`` wide.
+            ``kv_heads * head_dim`` wide. Nor does a model with layers
+            that keep a state ("lfm2_moe"): the state at a prefix's end
+            is in none of its pages, and the wire has no segment for it.
     """
 
     def __init__(self, model: str = "llama", model_config=None,
@@ -354,19 +361,22 @@ class LLMDeployment:
             from raytpu.models.gpt2 import GPT2, GPT2Config, init_params
 
             cfg_cls, model_cls, init = GPT2Config, GPT2, init_params
-        elif model in ("mixtral", "olmoe", "mellum", "joyai", "exaone_moe"):
+        elif model in ("mixtral", "olmoe", "mellum", "joyai", "exaone_moe",
+                       "lfm2_moe"):
             from raytpu.models import mixtral
 
             cfg_cls = {"mixtral": mixtral.MixtralConfig,
                        "olmoe": mixtral.OlmoeConfig,
                        "mellum": mixtral.MellumConfig,
                        "joyai": mixtral.JoyAIConfig,
-                       "exaone_moe": mixtral.ExaoneMoeConfig}[model]
+                       "exaone_moe": mixtral.ExaoneMoeConfig,
+                       "lfm2_moe": mixtral.Lfm2MoeConfig}[model]
             model_cls, init = mixtral.Mixtral, mixtral.init_params
         else:
             raise ValueError(f"unknown model family: {model!r}; known: "
                              f"'llama', 'gpt2', 'mixtral', 'olmoe', "
-                             f"'mellum', 'joyai', 'exaone_moe'")
+                             f"'mellum', 'joyai', 'exaone_moe', "
+                             f"'lfm2_moe'")
         if model_config is None:
             model_config = dataclasses.replace(
                 cfg_cls.tiny(), dtype=jnp.float32, attn_impl="reference",
@@ -393,6 +403,12 @@ class LLMDeployment:
                 f"disaggregated: the hand-off's wire segments are pages of "
                 f"K and of V, kv_heads * head_dim wide, and its layers "
                 f"hold one latent pool")
+        if role is not None and self._engine.cache.state:
+            raise ValueError(
+                f"role={role!r}: a model with layers that keep a state is "
+                f"not served disaggregated: the state at a prefix's end is "
+                f"in none of the pages the hand-off ships, and its wire "
+                f"has no segment for a state")
         self._handoff_source = disagg.KVHandoffSource(self._engine)
         # One condition serializes engine mutation (add/abort/step):
         # producers signal "new work" to the loop through it.

@@ -401,6 +401,21 @@ class Serving:
     back ``k_caches`` one pool a layer and ``v_caches`` empty.
     ``kv_heads`` and ``head_dim`` are then the query's, and size no pool.
 
+    A layer that keeps a state (``layer_states``: one entry a layer of
+    the model, ``None`` for a layer with a pool, else the shape of what a
+    sequence keeps there, a short convolution's ``(taps - 1, width)``):
+    it has no pool, ``k_caches`` and ``v_caches`` hold the other layers'
+    alone, in their order, and the three entry points take two arguments
+    more after them, ``states`` (one ``[seats + 1, *shape]`` array a
+    layer that keeps one, donated like the pools) and ``seats`` (int32
+    ``[1]``, or ``[B]`` for ``decode``: each sequence's row of every
+    state array; 0, the scratch row, for a padding row), and return
+    ``states`` written after the pools: ``(logits, k_caches, v_caches,
+    states[, count])``. A program writes at a sequence's seat the state
+    after its last live row (not after the bucket's last), and the
+    program that holds a sequence's position 0 starts from zeros whatever
+    the seat holds: nothing else clears a seat between two sequences.
+
     ``inputs`` -> logits: ``prefill`` (tokens [1, T], dests [T]) ->
     [T, V]; ``prefill_chunk`` (tokens [1, T], positions [T], dests [T],
     block_tables [1, P]) -> [1, T, V]; ``decode`` (tokens [B], positions
@@ -415,6 +430,7 @@ class Serving:
     expert_counts: Optional[Tuple[int, int]] = None  # (layers, experts)
     layer_windows: Tuple[Optional[int], ...] = ()
     kv_row: Optional[int] = None
+    layer_states: Tuple[Optional[Tuple[int, ...]], ...] = ()
     # Not None: the family drafts for itself (a prediction module), and
     # an engine built with drafting on runs these and not the three above.
     drafting: Optional["Drafting"] = None

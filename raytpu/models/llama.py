@@ -33,6 +33,11 @@ from raytpu.models.gpt2 import Serving, cast_leaves, write_prompt_rows
 # a program's ``dests``): full first.
 FULL, WINDOW = "full_attention", "sliding_attention"
 KINDS = (FULL, WINDOW)
+# A third kind of layer, which is no attention: a gated short convolution
+# (:mod:`raytpu.models.short_conv`). It holds no keys and values, so it has
+# no pool and stands in nothing that is given a kind of pool; a sequence
+# keeps a state in it instead (``Serving.layer_states``).
+CONV = "conv"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +90,13 @@ class LlamaConfig:
     # The kinds of layer whose q and k are roped; None is every kind. A
     # kind left out sees no positions at all (EXAONE's full layers).
     rope_kinds: Optional[Tuple[str, ...]] = None
+    # A CONV layer's depthwise convolution reaches this many positions,
+    # its own among them; a sequence's state there is the ``conv_taps -
+    # 1`` newest rows of the convolution's input.
+    conv_taps: int = 3
+    # The output head is the embedding, transposed, and not a matrix of
+    # its own.
+    tie_embeddings: bool = False
     dtype: Any = jnp.bfloat16
     # The type the matrices and the embedding are *held* in. The norms'
     # scales (and a routed layer's router) stay float32 whatever it is.
@@ -118,10 +130,11 @@ class LlamaConfig:
         if self.layer_types is not None:
             types = tuple(self.layer_types)
             object.__setattr__(self, "layer_types", types)
-            if len(types) != self.n_layer or set(types) - set(KINDS):
+            if len(types) != self.n_layer \
+                    or set(types) - {*KINDS, CONV}:
                 raise ValueError(
                     f"layer_types names {self.n_layer} layers, each "
-                    f"{FULL!r} or {WINDOW!r}: got {types}")
+                    f"{FULL!r}, {WINDOW!r} or {CONV!r}: got {types}")
             if WINDOW in types and not self.window:
                 raise ValueError("a window layer needs `window`")
 
@@ -139,14 +152,28 @@ class LlamaConfig:
     def attn_scope(self, kind: str) -> Optional[str]:
         """The ``jax.named_scope`` a layer of ``kind`` attends under in
         the serving walk; ``None`` where every layer is alike."""
-        if not self.layer_types:
+        if not self.layer_types or kind == CONV:  # (its own: "conv.*")
             return None
         return "attn.window" if kind == WINDOW else "attn.full"
 
     def attention(self, kind: str = FULL, **kw):
-        """The attention module of a layer of ``kind``: what a block
-        builds (``name=``) and what the serving walk applies."""
+        """The operator of a layer of ``kind``, attention or the short
+        convolution: what a block builds (``name=``) and what the
+        serving walk applies."""
+        if kind == CONV:
+            from raytpu.models.short_conv import ShortConv  # imports this
+
+            return ShortConv(self, **kw)
         return LlamaAttention(self, kind, **kw)
+
+    def held_index(self, i: int) -> int:
+        """Where layer ``i``'s own array stands in what a served model is
+        given: its pools among the pools (``k_caches``, ``v_caches``), or
+        its state among the ``states``; ``i`` itself where every layer
+        has a pool."""
+        kinds = self.layer_types or ()
+        return sum((kind == CONV) == (kinds[i] == CONV)
+                   for kind in kinds[:i]) if CONV in kinds else i
 
     def ffn_width(self, i: int) -> Optional[int]:
         """Layer ``i``'s feed-forward by its index: the width of its
@@ -158,13 +185,17 @@ class LlamaConfig:
         """How ``InferenceEngine`` serves this family; a routed config
         (``MixtralConfig`` and what extends it) through the same walk."""
         routed = sum(self.ffn_width(i) is None for i in range(self.n_layer))
+        kinds = self.layer_types or ()
         return Serving(
             llama_prefill, llama_prefill_chunk, llama_decode, serving_params,
             kv_heads=self.n_kv_head, head_dim=self.head_dim,
             expert_counts=(routed, self.n_expert_held) if routed else None,
             layer_windows=tuple(
                 self.window if kind == WINDOW else None
-                for kind in self.layer_types or ()))
+                for kind in kinds if kind != CONV),
+            layer_states=tuple(
+                (self.conv_taps - 1, self.n_embd) if kind == CONV else None
+                for kind in kinds) if CONV in kinds else ())
 
     @property
     def n_params_approx(self) -> int:
@@ -453,7 +484,7 @@ class LlamaBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        x = x + LlamaAttention(c, self.kind, name="attn")(
+        x = x + c.attention(self.kind, name=op_name(self.kind))(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="input_norm")(x))
         x = x + LlamaMLP(c, name="mlp")(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps,
@@ -467,9 +498,9 @@ class Llama(nn.Module):
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False):
         c = self.config
-        x = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
-                     param_dtype=c.param_dtype,
-                     name="embed_tokens")(tokens)
+        embed = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
+                         param_dtype=c.param_dtype, name="embed_tokens")
+        x = embed(tokens)
         block = LlamaBlock
         if c.remat and c.remat != "none":
             policy = None
@@ -491,6 +522,8 @@ class Llama(nn.Module):
         x = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="final_norm")(x)
         if return_hidden:
             return x
+        if c.tie_embeddings:
+            return embed.attend(x).astype(jnp.float32)
         # Untied LM head (llama-style), bf16 matmul with fp32 accumulation.
         logits = nn.Dense(c.vocab_size, use_bias=False, dtype=c.dtype,
                           param_dtype=c.param_dtype, name="lm_head")(x)
@@ -575,8 +608,18 @@ def serving_params(config: LlamaConfig, params):
 
 
 def _lm_logits(c: LlamaConfig, params, x):
+    if c.tie_embeddings:  # the embedding's rows are the head's columns
+        embedding = params["embed_tokens"]["embedding"].astype(c.dtype)
+        return jax.lax.dot_general(
+            x, embedding, (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
     kernel = params["lm_head"]["kernel"].astype(c.dtype)
     return jnp.dot(x, kernel).astype(jnp.float32)
+
+
+def op_name(kind: str) -> str:
+    """What a block's parameters call its first operator."""
+    return "conv" if kind == CONV else "attn"
 
 
 def _feed_forward(c: LlamaConfig, lp, h, live, i: int):
@@ -614,28 +657,30 @@ def _serve(c: LlamaConfig, params, x, live, method: str, cache_args,
     ``x``, the final norm and the head. Layer ``i`` attends through
     ``c.attention(kind).<method>(h, *cache_args(i))``, which returns its
     output and the layer's K and V (rows, or the pools it wrote), or
-    the one pool of a latent layer (``V list`` is then empty);
+    the one pool of a latent layer (``V list`` is then empty), or a CONV
+    layer's state array, written;
     ``live`` (``x``'s leading shape) marks the rows that are tokens, for
-    :func:`_feed_forward`. Returns ``(fp32 logits, K list, V list)`` and
-    for a config with routed layers a fourth value, the int32 ``[routed
+    :func:`_feed_forward`. Returns ``(fp32 logits, K list, V list)``,
+    then the list of state arrays where a layer keeps one, and
+    for a config with routed layers one value more, the int32 ``[routed
     layers, experts held]`` count of tokens each expert received. Where
     the layers are of two kinds each attends under
     ``jax.named_scope("attn.full")`` or ``("attn.window")``; a latent
     layer under ``("attn.mla")``. With ``hidden`` a last value more: the
     residual stream after the last block, before the final norm, which a
     prediction module reads (:func:`raytpu.models.mixtral.draft_rows`)."""
-    attn = {kind: c.attention(kind) for kind in KINDS}
+    attn = {kind: c.attention(kind) for kind in set(c.layer_types or KINDS)}
     norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
-    ks, vs, routed = [], [], []
+    ks, vs, states, routed = [], [], [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
         h = norm.apply({"params": lp["input_norm"]}, x)
         kind = c.layer_kind(i)
         with (jax.named_scope(c.attn_scope(kind))
               if c.attn_scope(kind) else contextlib.nullcontext()):
-            y, k, *v = attn[kind].apply({"params": lp["attn"]}, h,
+            y, k, *v = attn[kind].apply({"params": lp[op_name(kind)]}, h,
                                         *cache_args(i), method=method)
-        ks.append(k)
+        (states if kind == CONV else ks).append(k)
         vs.extend(v)
         x = x + y
         h = norm.apply({"params": lp["post_attn_norm"]}, x)
@@ -646,55 +691,78 @@ def _serve(c: LlamaConfig, params, x, live, method: str, cache_args,
     last = (x,) if hidden else ()
     x = norm.apply({"params": params["final_norm"]}, x)
     logits = _lm_logits(c, params, x)
+    held = (ks, vs, states) if states else (ks, vs)
     if not routed:
-        return (logits, ks, vs, *last)
-    return (logits, ks, vs, jnp.stack(routed), *last)
+        return (logits, *held, *last)
+    return (logits, *held, jnp.stack(routed), *last)
 
 
 def _pools(k_caches, v_caches, i: int):
-    """Layer ``i``'s pools as its attention module takes them: K and V,
-    or the one pool of a latent layer (``v_caches`` is then empty)."""
+    """Pool ``i`` as an attention module takes it: K and V, or the one
+    pool of a latent layer (``v_caches`` is then empty)."""
     return (k_caches[i], v_caches[i]) if v_caches else (k_caches[i],)
 
 
+def _by_layer(c: LlamaConfig, k_caches, v_caches, states, conv, paged):
+    """``cache_args`` of :func:`_serve`: layer ``i``'s own pools before
+    ``paged(kind)`` (nothing at all where ``paged`` is None), or for a
+    CONV layer its state array before ``conv``
+    (:meth:`LlamaConfig.held_index` says which of each list is its)."""
+    def cache_args(i: int):
+        kind, own = c.layer_kind(i), c.held_index(i)
+        if kind == CONV:
+            return (states[own], *conv)
+        if paged is None:  # a whole prompt's attention reads no pool
+            return ()
+        return (*_pools(k_caches, v_caches, own), *paged(kind))
+
+    return cache_args
+
+
 def llama_prefill(config: LlamaConfig, params, tokens, dests, k_caches,
-                  v_caches):
+                  v_caches, states=(), seats=None):
     """Whole-prompt forward: ``tokens`` [1, T] from position 0 (flash
     attention), its roped K and V written to the pools at ``dests`` [T]
-    -> (fp32 logits [T, V], k_caches, v_caches[, count])."""
+    -> (fp32 logits [T, V], k_caches, v_caches[, states][, count]).
+    ``states`` and ``seats``: ``Serving.layer_states``."""
     c = config
     live = live_rows(dests, k_caches[0])[None]
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
-    logits, ks, vs, *count = _serve(c, params, x, live, "prefill",
-                                    lambda i: ())
+    logits, ks, vs, *more = _serve(c, params, x, live, "prefill", _by_layer(
+        c, k_caches, v_caches, states, (seats, live), None))
     if c.layer_types:  # each layer's rows where its kind of pool has them
-        dests = [of_kind(dests, c.layer_kind(i)) for i in range(c.n_layer)]
+        dests = [of_kind(dests, kind) for kind in c.layer_types
+                 if kind != CONV]
     ks, vs = write_prompt_rows(k_caches, v_caches, dests, ks, vs)
-    return (logits[0], ks, vs, *count)
+    return (logits[0], ks, vs, *more)
 
 
 def llama_prefill_chunk(config: LlamaConfig, params, tokens, positions,
-                        dests, block_tables, k_caches, v_caches):
+                        dests, block_tables, k_caches, v_caches, states=(),
+                        seats=None):
     """Chunked-prefill forward: ``tokens`` [1, T] at absolute
     ``positions`` [T] -> (fp32 logits [1, T, V], updated k_caches,
-    v_caches[, count]). See :meth:`LlamaAttention.prefill_chunk` for the
-    cache argument shapes."""
+    v_caches[, states][, count]). See :meth:`LlamaAttention.prefill_chunk`
+    for the cache argument shapes."""
     c = config
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     live = live_rows(dests, k_caches[0])[None]
-    return _serve(c, params, x, live, "prefill_chunk", lambda i: (
-        *_pools(k_caches, v_caches, i), of_kind(dests, c.layer_kind(i)),
-        of_kind(block_tables, c.layer_kind(i)), positions))
+    return _serve(c, params, x, live, "prefill_chunk", _by_layer(
+        c, k_caches, v_caches, states, (seats, live, positions[0] == 0),
+        lambda kind: (of_kind(dests, kind), of_kind(block_tables, kind),
+                      positions)))
 
 
 def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
-                 block_tables, context_lens, k_caches, v_caches):
+                 block_tables, context_lens, k_caches, v_caches, states=(),
+                 seats=None):
     """Single-token decode forward: ``tokens`` [B] -> (fp32 logits
-    [B, V], updated k_caches, v_caches[, count]). See
+    [B, V], updated k_caches, v_caches[, states][, count]). See
     :meth:`LlamaAttention.decode_step` for the cache argument shapes."""
     c = config
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
     live = live_rows(dests, k_caches[0])
-    return _serve(c, params, x, live, "decode_step", lambda i: (
-        *_pools(k_caches, v_caches, i), of_kind(dests, c.layer_kind(i)),
-        of_kind(block_tables, c.layer_kind(i)), positions, context_lens))
+    return _serve(c, params, x, live, "decode_step", _by_layer(
+        c, k_caches, v_caches, states, (seats,),
+        lambda kind: (of_kind(dests, kind), of_kind(block_tables, kind),
+                      positions, context_lens)))

@@ -36,6 +36,11 @@ head of q and of k, the same sigmoid-routed layer, and one
 multi-token-prediction module (``mtp_layers``, :class:`PredictionModule`)
 through which the model drafts for itself when it is served
 (:func:`mtp_prefill` and its siblings; ``Serving.drafting``).
+:class:`Lfm2MoeConfig` is LFM2-24B-A2B's: gated short convolutions
+(:mod:`raytpu.models.short_conv`) in three layers of four, which keep a
+state a sequence and no keys or values, full attention in the others,
+two leading dense layers, the sigmoid-routed layer with no shared expert,
+and a head tied to the embedding.
 """
 
 from __future__ import annotations
@@ -49,9 +54,9 @@ import jax
 import jax.numpy as jnp
 
 from raytpu.models.gpt2 import Drafting, write_prompt_rows
-from raytpu.models.llama import (FULL, WINDOW, LlamaConfig, LlamaMLP,
+from raytpu.models.llama import (CONV, FULL, WINDOW, LlamaConfig, LlamaMLP,
                                  RMSNorm, Rope, _lm_logits, _serve,
-                                 live_rows, of_kind)
+                                 live_rows, of_kind, op_name)
 from raytpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 
 
@@ -70,6 +75,9 @@ class MixtralConfig(LlamaConfig):
     # value is the standard deviation it is seeded with (a trained one is
     # not zero).
     choice_bias: Optional[float] = None
+    # Added to the chosen experts' weights' sum before they are divided
+    # by it (LFM2's published code: 1e-6).
+    topk_sum_eps: float = 0.0
     # What the chosen experts' weights are multiplied by, normalised or not.
     routed_scale: float = 1.0
     # Shared experts: one SwiGLU of ``n_shared * n_inter`` beside the
@@ -146,6 +154,15 @@ class OlmoeConfig(MixtralConfig):
                    n_expert_per_tok=2)
 
 
+def _cut_to_depth(config, published) -> None:
+    """``config.layer_types`` as given, or the ``published`` pattern, cut
+    to the first ``n_layer`` entries: a cut in depth keeps the list's
+    head, and a configuration file keeps the list whole."""
+    types = config.layer_types or published
+    object.__setattr__(config, "layer_types",
+                       tuple(types)[:config.n_layer])
+
+
 @dataclasses.dataclass(frozen=True)
 class MellumConfig(MixtralConfig):
     """Mellum2-12B-A2.5B (``JetBrains/Mellum2-12B-A2.5B-Instruct``) as
@@ -177,12 +194,8 @@ class MellumConfig(MixtralConfig):
     scan_layers: bool = False
 
     def __post_init__(self):
-        # The published pattern, or the first ``n_layer`` entries of a
-        # longer list (a cut in depth keeps the list's head).
-        types = self.layer_types or tuple(
-            FULL if i % 4 == 3 else WINDOW for i in range(self.n_layer))
-        object.__setattr__(self, "layer_types",
-                           tuple(types)[:self.n_layer])
+        _cut_to_depth(self, (
+            FULL if i % 4 == 3 else WINDOW for i in range(self.n_layer)))
         super().__post_init__()
 
     @classmethod
@@ -305,10 +318,8 @@ class ExaoneMoeConfig(MixtralConfig):
     scan_layers: bool = False
 
     def __post_init__(self):
-        types = self.layer_types or tuple(
-            FULL if i % 4 == 3 else WINDOW for i in range(self.n_layer))
-        object.__setattr__(self, "layer_types",
-                           tuple(types)[:self.n_layer])
+        _cut_to_depth(self, (
+            FULL if i % 4 == 3 else WINDOW for i in range(self.n_layer)))
         super().__post_init__()
 
     @property
@@ -331,6 +342,61 @@ class ExaoneMoeConfig(MixtralConfig):
                    window=8, rope_theta=10000.0)
 
 
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig(MixtralConfig):
+    """LFM2-24B-A2B (``LiquidAI/LFM2-24B-A2B``, ``model_type: lfm2_moe``)
+    as published: 40 layers over a hidden size of 2,048, three gated
+    short convolutions of 3 taps (``conv_L_cache``) to every full
+    attention layer (32 query heads on 8 kv heads of 64, a norm over each
+    head of q and of k, rope at theta 1e6); layers 0 and 1
+    (``num_dense_layers``) a dense SwiGLU of 11,776, the others 64 routed
+    experts of 1,536 (``n_inter``) of which a token takes 4 by sigmoid
+    score + a bias (``use_expert_bias``), weights over their sum + 1e-6,
+    no shared expert; the head is the embedding (the LFM2 family's
+    convention: the published config has no key for it). ``layer_types``
+    is the published list cut to ``n_layer``; layers are held one tree
+    each. A conv layer has no pool of pages: ``serving.layer_states``."""
+
+    vocab_size: int = 65536
+    block_size: int = 128000
+    n_layer: int = 40
+    n_head: int = 32
+    n_kv_head: int = 8
+    n_embd: int = 2048
+    head_dim: int = 64
+    n_inter: int = 1536
+    n_expert: int = 64
+    n_expert_per_tok: int = 4
+    norm_topk_prob: bool = True
+    topk_sum_eps: float = 1e-6
+    norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    qk_head_norm: bool = True
+    conv_taps: int = 3
+    tie_embeddings: bool = True
+    scoring: str = "sigmoid"
+    choice_bias: float = 0.0
+    first_dense: int = 2
+    dense_inter: int = 11776
+    scan_layers: bool = False
+
+    def __post_init__(self):
+        # Published: full attention at layers 2, 6, 10, ... 38.
+        _cut_to_depth(self, (
+            FULL if i % 4 == 2 else CONV for i in range(self.n_layer)))
+        super().__post_init__()
+
+    @classmethod
+    def tiny(cls) -> "Lfm2MoeConfig":
+        """Both dense layers and one period after them at toy widths:
+        conv conv.dense | full conv conv conv, four query heads a kv
+        head, and of 8 experts a token takes 2."""
+        return cls(vocab_size=512, block_size=256, n_layer=6, n_head=8,
+                   n_kv_head=2, n_embd=64, head_dim=8, n_inter=32,
+                   n_expert=8, n_expert_per_tok=2, dense_inter=96,
+                   rope_theta=10000.0, choice_bias=0.01)
+
+
 class MoEFFN(nn.Module):
     """Top-k routed SwiGLU experts, dropless.
 
@@ -346,15 +412,15 @@ class MoEFFN(nn.Module):
     read an expert's matrices only if a row chose it).
 
     Scores are a softmax over the experts, or each expert's own sigmoid
-    (``config.scoring``), then chosen by score + ``bias`` where the
-    config has a ``choice_bias`` (the weights are the scores without it),
-    normalised over the chosen (``norm_topk_prob``) and multiplied by
-    ``routed_scale``. With ``experts_held = (first, count)`` the three
-    matrices hold ``count`` experts, the router still ``n_expert``: a
-    pair whose expert lies outside the share is a dead row like
-    padding's, ``tokens`` counts the held experts alone, and the output
-    is their part of the layer's. A shared expert (``n_shared``) is
-    added under ``jax.named_scope("moe.shared")``.
+    (``config.scoring``), then chosen by score + ``bias`` where the config
+    has a ``choice_bias`` (the weights are the scores without it),
+    normalised over the chosen (``norm_topk_prob``: over their sum, plus
+    ``topk_sum_eps``) and multiplied by ``routed_scale``. With
+    ``experts_held = (first, count)`` the three matrices hold ``count``
+    experts, the router still ``n_expert``: a pair whose expert lies outside
+    the share is a dead row like padding's, ``tokens`` counts the held
+    experts alone, and the output is their part of the layer's. A shared
+    expert (``n_shared``) is added under ``jax.named_scope("moe.shared")``.
     """
 
     config: MixtralConfig
@@ -383,7 +449,10 @@ class MoEFFN(nn.Module):
             else:
                 topw, topi = jax.lax.top_k(probs, k)          # [N, k]
             if c.norm_topk_prob:
-                topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
+                total = jnp.sum(topw, axis=-1, keepdims=True)
+                if c.topk_sum_eps:
+                    total = total + c.topk_sum_eps
+                topw = topw / total
             if c.routed_scale != 1.0:
                 topw = topw * c.routed_scale
 
@@ -443,7 +512,7 @@ class MixtralBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        x = x + c.attention(self.kind, name="attn")(
+        x = x + c.attention(self.kind, name=op_name(self.kind))(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="input_norm")(x))
         h = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="post_attn_norm")(x)
         if self.dense_width is not None:
@@ -487,9 +556,9 @@ class Mixtral(nn.Module):
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False):
         c = self.config
-        x = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
-                     param_dtype=c.param_dtype,
-                     name="embed_tokens")(tokens)
+        embed = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
+                         param_dtype=c.param_dtype, name="embed_tokens")
+        x = embed(tokens)
         block = MixtralBlock
         if c.remat and c.remat != "none":
             policy = None
@@ -518,6 +587,8 @@ class Mixtral(nn.Module):
         x = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="final_norm")(x)
         if return_hidden:
             return x
+        if c.tie_embeddings:
+            return embed.attend(x).astype(jnp.float32)
         # Untied output head, as llama's.
         logits = nn.Dense(c.vocab_size, use_bias=False, dtype=c.dtype,
                           param_dtype=c.param_dtype, name="lm_head")(x)
@@ -704,3 +775,4 @@ def draft_rows(config, params, hidden, next_tokens, row, positions, dests,
 Mellum = Mixtral
 JoyAI = Mixtral
 ExaoneMoe = Mixtral
+Lfm2Moe = Mixtral

@@ -96,6 +96,8 @@ DECLARED_METRICS: Dict[str, str] = {
     "raytpu_infer_prefix_hits_total": "prefix cache lookup hits",
     "raytpu_infer_prefix_lookups_total": "prefix cache lookups",
     "raytpu_infer_running_requests": "requests in the running batch",
+    "raytpu_infer_state_seats_in_use":
+        "sequences holding a seat in the engine's state arrays",
     "raytpu_infer_step_seconds": "decode step wall time",
     "raytpu_infer_ttft_seconds": "time-to-first-token distribution",
     "raytpu_infer_waiting_requests": "requests queued for admission",

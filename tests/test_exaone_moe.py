@@ -120,7 +120,7 @@ def serve(cfg, params, requests, *, engine=None, decoded_only=False,
             tokens[o.request_id].append(o.token_id)
         if eng._drafting is not None:
             state = np.asarray(eng._draft_state[1])
-            for rid, slot in eng._slot_of.items():
+            for rid, slot in eng.cache._seats.items():
                 seq = seqs[rid]
                 if seq.cached_len >= seq.prefill_len:  # its prompt is in
                     drafts[rid][seq.cached_len - 1] = state[slot]
