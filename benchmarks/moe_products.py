@@ -5,7 +5,10 @@ For every shape this runs a chain of ``--layers`` layers (each with
 matrices of its own, as a program has them: one set read by every call
 would sit in fast memory) through ``jax.lax.ragged_dot``, through
 megablox ``gmm`` as shipped with tiles ``(128, K, N)`` (decode shapes
-only) and through ``raytpu/ops/grouped_matmul.py``'s kernel, and prints
+whose experts fit VMEM whole only) and through
+``raytpu/ops/grouped_matmul.py``'s kernel, in the blocks of columns its
+own rule gives (``kernel``) and, where that rule cuts an expert, in half
+and a quarter of them (``kernel tn=<gate and up>,<down>``), and prints
 the device's milliseconds a layer beside the least its bytes and FLOPs
 allow. Run it on the chip:
 
@@ -14,13 +17,16 @@ allow. Run it on the chip:
 A time is the device's own: its busy time in a profiler trace of
 ``--repeat`` calls of the chain, compile and warm-up excluded (on the
 host's clock a call of these sizes is mostly its dispatch). One JSON line
-a shape; the whole table in ``chiprun_out/moe_products.json``. Off a TPU
+a shape; the whole table in ``chiprun_out/moe_products.json``
+(``moe_products_buffer<n>.json`` under ``--buffer-mib n``,
+``moe_products_rows<n>.json`` under ``--row-tile n``). Off a TPU
 it refuses to time anything.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import glob
 import importlib
 import json
@@ -66,6 +72,13 @@ SHAPES = {
     "olmoe-wide-131072": (131072, 64, 2048, 1024, drawn(64, 131072)),
     "mellum-wide-131072": (131072, 64, 2304, 896, drawn(64, 131072)),
     "eight-experts-32768": (32768, 8, 2048, 1024, drawn(8, 32768)),
+    # PR 48. LFM2 holds all 64 experts and a step of 64 streams touches
+    # 63; K-EXAONE holds 16 of 128, so an eighth of a verify step's 32 x
+    # 8 rows is live, on three experts (the cells' step logs, PR 47).
+    "lfm2-decode": (256, 64, 2048, 1536, spread(64, [5] * 4 + [4] * 59)),
+    "lfm2-chunk-2048": (8192, 64, 2048, 1536, drawn(64, 8192)),
+    "kexaone-verify": (256, 16, 6144, 2048, spread(16, [11, 11, 10])),
+    "kexaone-chunk-2048": (16384, 16, 6144, 2048, drawn(16, 2048)),
 }
 
 
@@ -87,13 +100,21 @@ def ways(rows, k, n):
         h = jax.nn.silu(one(x, wg, (128, k, n))) * one(x, wi, (128, k, n))
         return one(h, wo, (128, n, k))
 
-    def kernel(x, wg, wi, wo, t):
+    def kernel(x, wg, wi, wo, t, up=None, down=None):
         return gm._moe_grouped_pallas(
-            gm._moe_grouped_pallas(x, (wg, wi), t), (wo,), t)
+            gm._moe_grouped_pallas(x, (wg, wi), t, tn=up), (wo,), t, tn=down)
 
     found = {"ragged_dot": ragged, "kernel": kernel}
-    if rows <= 256:
-        found["megablox"] = megablox
+    up, down = gm._block_width(k, n, 2, 2), gm._block_width(n, k, 2, 1)
+    if (up, down) == (n, k):
+        if rows <= 256:
+            found["megablox"] = megablox
+        return found
+    # An expert the rule cuts: half and a quarter of its blocks too.
+    for cut in (2, 4):
+        if not (up // cut % 128 or down // cut % 128):
+            found[f"kernel tn={up // cut},{down // cut}"] = functools.partial(
+                kernel, up=up // cut, down=down // cut)
     return found
 
 
@@ -167,19 +188,37 @@ def main() -> None:
     parser.add_argument("shapes", nargs="*", default=list(SHAPES))
     parser.add_argument("--layers", type=int, default=4)
     parser.add_argument("--repeat", type=int, default=5)
+    parser.add_argument(
+        "--buffer-mib", type=int,
+        help="the kernel's VMEM for expert blocks, where its rule is to be "
+             "read at another than ops/grouped_matmul.py's own")
+    parser.add_argument(
+        "--row-tile", type=int,
+        help="the rows of one visit, likewise (a multiple of 16)")
     args = parser.parse_args()
     import jax
+
+    from raytpu.ops import grouped_matmul as gm
+
+    if args.buffer_mib:
+        gm._EXPERT_BUFFER_BYTES = args.buffer_mib << 20
+        gm._VMEM_LIMIT_BYTES = (args.buffer_mib + 24) << 20
+    if args.row_tile:
+        gm._ROW_TILE = args.row_tile
 
     if jax.devices()[0].platform != "tpu":
         sys.exit("moe_products times the device: run it through chiprun")
     out_dir = os.path.join(REPO, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     table = []
+    out_name = "moe_products{}{}.json".format(
+        f"_buffer{args.buffer_mib}" if args.buffer_mib else "",
+        f"_rows{args.row_tile}" if args.row_tile else "")
     for name in args.shapes:
         table.append(measure(name, args.layers, args.repeat,
                              os.path.join(out_dir, "moe_products_trace")))
         print(json.dumps(table[-1]), flush=True)
-        with open(os.path.join(out_dir, "moe_products.json"), "w") as f:
+        with open(os.path.join(out_dir, out_name), "w") as f:
             json.dump(table, f, indent=1)
     shutil.rmtree(os.path.join(out_dir, "moe_products_trace"),
                   ignore_errors=True)

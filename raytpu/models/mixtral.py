@@ -8,8 +8,9 @@ one top-k routed expert layer, :class:`MoEFFN`. It is *dropless*: every
 (token, expert) pair the router chooses is computed, so a token's output
 does not depend on what shares its batch. The assignments are sorted by
 expert and the three expert matrices multiplied group by group
-(:mod:`raytpu.ops.grouped_matmul`: a Pallas kernel on one TPU where an
-expert's matrices fit its fast memory, and ``jax.lax.ragged_dot``, which
+(:mod:`raytpu.ops.grouped_matmul`: a Pallas kernel on one TPU, an
+expert's matrices streamed whole where they fit its fast memory and in
+blocks of columns where they do not, and ``jax.lax.ragged_dot``, which
 the TPU compiler turns into a kernel of its own, everywhere else; both
 read only the experts that received a row), so the work grows with
 ``n_expert_per_tok`` and not with ``n_expert``. A Switch-style
@@ -407,9 +408,10 @@ class MoEFFN(nn.Module):
     received. The router runs in float32 at full precision over all the
     experts; the expert matrices multiply in ``config.dtype`` with
     float32 sums, gate and up in one pass over the sorted rows and down
-    in another (``ops.grouped_matmul``: which of its two ways is a
-    matter of the program's shapes and of where it is lowered, and both
-    read an expert's matrices only if a row chose it).
+    in another (``ops.grouped_matmul``: which of its two ways, and in
+    what blocks of an expert's columns, is a matter of the operands'
+    shapes and type and of where the program is lowered, and both read
+    an expert's matrices only if a row chose it).
 
     Scores are a softmax over the experts, or each expert's own sigmoid
     (``config.scoring``), then chosen by score + ``bias`` where the config

@@ -18,24 +18,37 @@ Here the row axis is cut at every tile and every group boundary into
 *visits*, one (row tile, expert) pair each in row order (the walk of
 ``jax.experimental.pallas.ops.tpu.megablox``); the visit's expert is
 scalar-prefetched into the matrices' index map, so the pipeline streams
-an expert's matrix once, whole and contiguous, and an expert no row
-chose is never fetched. A group that straddles two row tiles makes two
-consecutive visits of one block, which the pipeline does not fetch
-again; the dead rows are a last group of their own, stored as zeros and
-multiplied by nothing.
+an expert's matrix once and an expert no row chose is never fetched. A
+group that straddles two row tiles makes two consecutive visits of one
+block, which the pipeline does not fetch again; the dead rows are a last
+group of their own, stored as zeros and multiplied by nothing.
 
-Which products take the kernel is decided when a program is traced, from
-``(rows, k, n)`` alone (:func:`takes_kernel`): those whose rows
-fill whole tiles and whose experts fit VMEM whole. On the v5e it was the
-faster at every width measured (PERF.md, PR 40): 2-8 rows an expert (a
-decode step, bound by its matrices' bytes: the chip does 240 FLOPs in
-the time it moves a byte, perfbench/peaks.py, and a group of r rows does
-r a byte), 256 (a chunk) and 1,024 to 4,096 (a training batch's), so no
-count of rows sends a product back. It lowers for the TPU only
-(``jax.lax.platform_dependent``); every other backend, a program sharded
-over a mesh and every other shape multiply by ``ragged_dot``, which is
-also the backward pass of both. :func:`kernel_calls` tells a program's
-builder how many of its products went which way.
+An expert whose matrices fit the buffers is streamed whole and
+contiguous. One that does not (K-EXAONE's ``[6144, 2048]``) is cut along
+N into blocks of whole lanes, the widest that divide N and fit
+(:func:`_block_width`): the grid is (blocks, visits) with the visits
+innermost, so each block of each touched expert is still streamed once,
+a tile's consecutive visits still find its output block where they left
+it, and the rows are read once a block. Gate and up are cut at the same
+columns and no sum crosses a block, so the arithmetic is the whole
+expert's. There are no blocks along K: they would need an accumulator
+across grid steps, and no served width needs them once N is cut.
+
+Which products take the kernel, and in what blocks, is decided when a
+program is traced, from what the operands show (:func:`takes_kernel`):
+``(rows, k, n)``, the matrices' item size, and how many of them the pass
+streams. The rows must fill whole tiles, and a block of whole lanes must
+fit VMEM; the kernel's own guard reads the same numbers. On the v5e it
+was the faster at every width measured (PERF.md, PR 40 and PR 48): 2-8
+rows an expert (a decode step, bound by its matrices' bytes: the chip
+does 240 FLOPs in the time it moves a byte, perfbench/peaks.py, and a
+group of r rows does r a byte), 256 (a chunk) and 1,024 to 4,096 (a
+training batch's), so no count of rows sends a product back. It lowers
+for the TPU only (``jax.lax.platform_dependent``); every other backend, a
+program sharded over a mesh and every other shape multiply by
+``ragged_dot``, which is also the backward pass of both.
+:func:`kernel_calls` tells a program's builder how many of its products
+went which way.
 """
 
 from __future__ import annotations
@@ -43,7 +56,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import threading
-from typing import Iterator, List, Sequence, Tuple
+from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,20 +82,34 @@ def _row_tile(m: int) -> int:
     return min(_ROW_TILE, m)
 
 
-def _fits(k: int, n: int, itemsize: int = 4, matrices: int = 2) -> bool:
-    """Whether ``matrices`` experts' ``[k, n]`` blocks fit their buffers
-    whole (gate and up of float32 unless told: the most a product asks)."""
-    return 2 * matrices * k * n * itemsize <= _EXPERT_BUFFER_BYTES
+def _fits(k: int, tn: int, itemsize: int, matrices: int) -> bool:
+    """Whether ``matrices`` blocks ``[k, tn]`` of ``itemsize`` bytes an
+    element fit the experts' buffers, each twice."""
+    return 2 * matrices * k * tn * itemsize <= _EXPERT_BUFFER_BYTES
 
 
-def takes_kernel(rows: int, k: int, n: int) -> bool:
-    """Whether a product of ``rows`` sorted rows over experts' matrices
-    ``[k, n]`` goes through the kernel on a TPU: where the rows fill
-    whole tiles of 16 (a bf16 sublane tile) and of ``_ROW_TILE`` and an
-    expert's matrices fit VMEM whole."""
+def _block_width(k: int, n: int, itemsize: int, matrices: int) -> int:
+    """The columns of an expert's ``[k, n]`` matrices one visit takes:
+    ``n`` where they fit their buffers whole, else the most lanes (a
+    multiple of 128) that divide ``n`` and fit, 0 where none does."""
+    if _fits(k, n, itemsize, matrices):
+        return n
+    return max((tn for tn in range(128, n, 128)
+                if n % tn == 0 and _fits(k, tn, itemsize, matrices)),
+               default=0)
+
+
+def takes_kernel(rows: int, k: int, n: int, itemsize: int,
+                 matrices: int) -> bool:
+    """Whether a product of ``rows`` sorted rows over ``matrices``
+    experts' matrices ``[k, n]`` of ``itemsize`` bytes an element (two
+    for :func:`grouped_swiglu`, one for :func:`grouped_matmul`) goes
+    through the kernel on a TPU: where the rows fill whole tiles of 16
+    (a bf16 sublane tile) and of ``_ROW_TILE`` and the matrices fit VMEM
+    whole or in blocks of whole lanes along ``n``."""
     tile = _row_tile(rows)
     return (rows > 0 and rows % tile == 0 and tile % 16 == 0
-            and _fits(k, n))
+            and _block_width(k, n, itemsize, matrices) > 0)
 
 
 def _visits(tokens, m: int, tm: int):
@@ -110,12 +137,12 @@ def _visits(tokens, m: int, tm: int):
 
 def _grouped_kernel(starts_ref, experts_ref, live_ref, rows_ref, *refs,
                     tm: int):
-    """One visit: the row tile times the visit's expert in float32; the
-    visit's own rows of the tile are stored, the others left as they
-    are."""
+    """One visit of one block of columns: the row tile times the block
+    of the visit's expert in float32; the visit's own rows of the tile
+    are stored, the others left as they are."""
     del experts_ref
     *w_refs, out_ref = refs
-    v = pl.program_id(0)
+    v = pl.program_id(1)
     lo, hi = starts_ref[v], starts_ref[v + 1]
     n_live = live_ref[0]
     at = lo // tm * tm + jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
@@ -135,38 +162,49 @@ def _grouped_kernel(starts_ref, experts_ref, live_ref, rows_ref, *refs,
         out_ref[...] = jnp.where(mine, jnp.zeros_like(out_ref), out_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def _moe_grouped_pallas(rows, ws: Tuple[jax.Array, ...], tokens, *,
-                        interpret: bool = False):
+                        tn: Optional[int] = None, interpret: bool = False):
+    """``tn``: the block of columns, ``_block_width``'s unless a test or
+    ``benchmarks/moe_products.py`` asks for a narrower one."""
     m, k = rows.shape
     n = ws[0].shape[2]
     tm = _row_tile(m)
-    if m % tm or not _fits(k, n, ws[0].dtype.itemsize, len(ws)):
+    itemsize, matrices = ws[0].dtype.itemsize, len(ws)
+    tn = tn or _block_width(k, n, itemsize, matrices)
+    if (m % tm or not tn or n % tn or (tn < n and tn % 128)
+            or not _fits(k, tn, itemsize, matrices)):
         raise ValueError(
-            f"{m} rows in tiles of {tm} over matrices [{k}, {n}]: not a "
-            f"shape of the grouped kernel (takes_kernel)")
+            f"{m} rows in tiles of {tm} over {matrices} matrices [{k}, {n}]"
+            f" of {itemsize} bytes in blocks of {tn} columns: not a shape "
+            f"of the grouped kernel (takes_kernel)")
     starts, experts, n_visits, n_live = _visits(tokens, m, tm)
 
-    def row_index(v, starts_ref, experts_ref, live_ref):
+    # The visits are the inner axis: a tile's follow one another, so its
+    # output block stays where it is between them, and a touched
+    # expert's block of columns is streamed once.
+    def row_index(j, v, starts_ref, experts_ref, live_ref):
         return starts_ref[v] // tm, 0
 
-    def expert_index(v, starts_ref, experts_ref, live_ref):
-        return experts_ref[v], 0, 0
+    def expert_index(j, v, starts_ref, experts_ref, live_ref):
+        return experts_ref[v], 0, j
+
+    def out_index(j, v, starts_ref, experts_ref, live_ref):
+        return starts_ref[v] // tm, j
 
     kwargs = {}
     if not interpret:
-        # A tile's visits follow one another.
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=(pltpu.GridDimensionSemantics.ARBITRARY,),
+            dimension_semantics=(pltpu.GridDimensionSemantics.ARBITRARY,) * 2,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES)
     return pl.pallas_call(
         functools.partial(_grouped_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(n_visits,),
+            grid=(n // tn, n_visits),
             in_specs=[pl.BlockSpec((tm, k), row_index)]
-            + [pl.BlockSpec((None, k, n), expert_index)] * len(ws),
-            out_specs=pl.BlockSpec((tm, n), row_index),
+            + [pl.BlockSpec((None, k, tn), expert_index)] * len(ws),
+            out_specs=pl.BlockSpec((tm, tn), out_index),
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
         interpret=interpret,
@@ -231,7 +269,8 @@ def _products(rows, ws: Tuple[jax.Array, ...], tokens):
     kernel."""
     mesh = jax.sharding.get_abstract_mesh()
     if not ((mesh.empty or mesh.size == 1)
-            and takes_kernel(rows.shape[0], *ws[0].shape[1:])):
+            and takes_kernel(rows.shape[0], *ws[0].shape[1:],
+                             ws[0].dtype.itemsize, len(ws))):
         return _ragged(rows, ws, tokens)
     for calls, lowers in _notes.open:
         calls[0] += lowers

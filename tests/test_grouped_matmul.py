@@ -1,9 +1,10 @@
 """The grouped-matmul kernel of the routed-expert layer
 (``raytpu/ops/grouped_matmul.py``): against ``jax.lax.ragged_dot`` in
-interpret mode at the three served families' expert shapes, the rule
-that says which products take it, ``MoEFFN`` through it against the
-benchmark's plain references, its gradients, and the step record's
-``moe_grouped_calls``. All on the CPU; a time comes only from the chip.
+interpret mode at the served families' expert shapes, whole and in
+blocks of columns, the rule that says which products take it and in what
+blocks, ``MoEFFN`` through it against the benchmark's plain references,
+its gradients, and the step record's ``moe_grouped_calls``. All on the
+CPU; a time comes only from the chip.
 """
 
 from __future__ import annotations
@@ -27,6 +28,7 @@ F32 = dict(dtype=jnp.float32, attn_impl="reference",
 
 # (K, N) of one expert's gate and up matrices as published.
 MELLUM, OLMOE, JOYAI = (2304, 896), (2048, 1024), (2048, 768)
+LFM2, EXAONE = (2048, 1536), (6144, 2048)
 MIXTRAL = (4096, 14336)   # 8x7B's, not served: 117 MB an expert matrix
 
 
@@ -39,9 +41,10 @@ def operands(m, e, k, n, dtype, seed=0):
     return rows, ws
 
 
-def both_ways(rows, ws, tokens):
+def both_ways(rows, ws, tokens, **kernel):
     tokens = jnp.asarray(tokens, jnp.int32)
-    got = gm._moe_grouped_pallas(rows, tuple(ws), tokens, interpret=True)
+    got = gm._moe_grouped_pallas(rows, tuple(ws), tokens, interpret=True,
+                                 **kernel)
     want = gm._ragged(rows, tuple(ws), tokens)
     return (np.asarray(got.astype(jnp.float32)),
             np.asarray(want.astype(jnp.float32)), int(tokens.sum()))
@@ -119,16 +122,87 @@ def test_groups(case, dtype):
     within_rounding(got, want, live, dtype)
 
 
-@pytest.mark.parametrize("kn", [MIXTRAL, MIXTRAL[::-1]], ids=["up", "down"])
-def test_an_expert_that_does_not_fit_whole_is_not_the_kernels(kn):
-    """No blocks along K: such a product stays a ``ragged_dot``, and the
-    kernel called with it all the same says so when it is traced."""
+@pytest.mark.parametrize("kn,matrices,tn", [
+    (MIXTRAL, 2, 1024), (MIXTRAL[::-1], 1, 512),
+    (EXAONE, 2, 512), (EXAONE[::-1], 1, 3072),
+], ids=["mixtral-up", "mixtral-down", "exaone-up", "exaone-down"])
+def test_an_expert_that_does_not_fit_whole_goes_through_in_blocks(
+        kn, matrices, tn):
+    """Blocks along N, none along K: the most whole lanes that divide N
+    and fit the buffers, and the kernel traces at the published width."""
     k, n = kn
-    assert not gm.takes_kernel(256, k, n)
+    assert gm.takes_kernel(256, k, n, 2, matrices)
+    assert gm._block_width(k, n, 2, matrices) == tn
+    assert n % tn == 0 and tn % 128 == 0
+    assert gm._fits(k, tn, 2, matrices)
+    assert not any(n % wider == 0 and gm._fits(k, wider, 2, matrices)
+                   for wider in range(tn + 128, n + 1, 128))
     bf16 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+    out = jax.eval_shape(gm._moe_grouped_pallas, bf16((256, k)),
+                         (bf16((8, k, n)),) * matrices,
+                         jax.ShapeDtypeStruct((8,), jnp.int32))
+    assert out.shape == (256, n) and out.dtype == jnp.bfloat16
+
+
+@pytest.fixture
+def narrow_buffers(monkeypatch):
+    """The experts' buffers cut down until ``[128, 512]`` fits in two
+    blocks (bf16) or four (float32) and no wider; the kernel is traced
+    anew under them, and again without."""
+    monkeypatch.setattr(gm, "_EXPERT_BUFFER_BYTES", 2 * 2 * 128 * 256 * 2)
+    gm._moe_grouped_pallas.clear_cache()
+    yield
+    gm._moe_grouped_pallas.clear_cache()
+
+
+BLOCKED = ["a group straddles a row tile", "dead rows at the end",
+           "a dead tile", "no live row", "a group spans three tiles"]
+
+
+@pytest.mark.parametrize("dtype,tn", [(jnp.float32, 128),
+                                      (jnp.bfloat16, 256)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", BLOCKED)
+def test_groups_in_blocks(case, dtype, tn, narrow_buffers):
+    """The walk of ``test_groups`` over experts cut along N by the rule's
+    own reckoning: gate and up at the same columns, the down product's
+    one matrix in blocks twice as wide."""
+    m, tokens = CASES[case]
+    rows, (wg, wi, wo) = operands(m, len(tokens), 128, 512, dtype, seed=5)
+    item = jnp.dtype(dtype).itemsize
+    assert gm._block_width(128, 512, item, 2) == tn
+    assert gm._block_width(128, 512, item, 1) == 2 * tn
+    got, want, live = both_ways(rows, [wg], tokens)
+    within_rounding(got, want, live, dtype)
+    got, want, live = both_ways(rows, [wg, wi], tokens)
+    within_rounding(got, want, live, dtype)
+    # Whatever the blocks, the float32 sums and the one rounding are the
+    # same: no sum crosses a block. (A wider one does not fit here.)
+    for width in sorted({128, tn}):
+        cut, _, _ = both_ways(rows, [wg, wi], tokens, tn=width)
+        np.testing.assert_array_equal(cut, got)
+
+
+def test_the_kernel_refuses_blocks_it_cannot_take():
+    rows, (wg, wi, wo) = operands(32, 4, 128, 512, jnp.bfloat16)
+    tokens = jnp.asarray([3, 0, 20, 5], jnp.int32)
+    for tn in (64, 192, 384):      # half a lane tile; no divisor of 512
+        with pytest.raises(ValueError, match="takes_kernel"):
+            gm._moe_grouped_pallas(rows, (wg, wi), tokens, tn=tn,
+                                   interpret=True)
+
+
+def test_a_width_with_no_block_of_whole_lanes_stays_a_ragged_dot(
+        monkeypatch):
+    """8,192 x 1,000 in float32: too large whole, and no multiple of 128
+    divides 1,000."""
+    monkeypatch.setattr(gm, "_EXPERT_BUFFER_BYTES", 1 << 20)
+    assert gm._block_width(8192, 1000, 4, 2) == 0
+    assert not gm.takes_kernel(256, 8192, 1000, 4, 2)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
     with pytest.raises(ValueError, match="takes_kernel"):
-        jax.eval_shape(gm._moe_grouped_pallas, bf16((256, k)),
-                       (bf16((8, k, n)),),
+        jax.eval_shape(gm._moe_grouped_pallas.__wrapped__, f32((256, 8192)),
+                       (f32((8, 8192, 1000)),) * 2,
                        jax.ShapeDtypeStruct((8,), jnp.int32))
 
 
@@ -156,30 +230,54 @@ def test_the_walk_names_each_touched_expert_once():
     ("Mellum chunk 2048", 16384, MELLUM, True),
     ("JoyAI chunk 2048", 16384, JOYAI, True),
     ("OLMoE training, 16 x 1024 x 8", 131072, OLMOE, True),
-    ("Mixtral 8x7B, 8 x 2048 x 2: no expert fits whole", 32768, MIXTRAL,
-     False),
-    ("Mixtral 8x7B's decode", 256, MIXTRAL, False),
+    ("LFM2 decode, 64 x 4", 256, LFM2, True),
+    ("LFM2 chunk 2048", 8192, LFM2, True),
+    ("K-EXAONE verify, 16 x 2 x 8: in blocks", 256, EXAONE, True),
+    ("K-EXAONE chunk 2048: in blocks", 16384, EXAONE, True),
+    ("Mixtral 8x7B, 8 x 2048 x 2: in blocks", 32768, MIXTRAL, True),
+    ("Mixtral 8x7B's decode: in blocks", 256, MIXTRAL, True),
     ("one sequence's decode: 8 rows, half a bf16 tile", 8, OLMOE, False),
     ("rows that fill no whole tile", 200, OLMOE, False),
     ("no row", 0, OLMOE, False),
 ])
 def test_shape_rule(what, rows, kn, takes):
+    """Both orientations as the layer has them in bf16: gate and up
+    ``[k, n]`` in one pass, down ``[n, k]`` alone."""
     k, n = kn
-    assert gm.takes_kernel(rows, k, n) is takes, what
-    assert gm.takes_kernel(rows, n, k) is takes, what
+    assert gm.takes_kernel(rows, k, n, 2, 2) is takes, what
+    assert gm.takes_kernel(rows, n, k, 2, 1) is takes, what
+
+
+@pytest.mark.parametrize("kn,itemsize,matrices,tn", [
+    # LFM2's gate and up: 25.2 MB in flight as bf16, 50.3 MB as float32.
+    (LFM2, 2, 2, 1536), (LFM2, 4, 2, 768),
+    (LFM2[::-1], 2, 1, 2048), (LFM2[::-1], 4, 1, 2048),
+    (OLMOE, 2, 2, 1024), (OLMOE, 4, 2, 1024),
+    (MELLUM, 2, 2, 896), (JOYAI, 4, 2, 768),
+    (EXAONE, 4, 2, 256), (EXAONE[::-1], 4, 1, 2048),
+])
+def test_the_item_size_and_the_matrices_streamed_decide_the_block(
+        kn, itemsize, matrices, tn):
+    k, n = kn
+    assert gm._block_width(k, n, itemsize, matrices) == tn
+    assert gm.takes_kernel(256, k, n, itemsize, matrices)
 
 
 def test_the_rule_knows_no_model():
     import inspect
 
     assert list(inspect.signature(gm.takes_kernel).parameters) == [
-        "rows", "k", "n"]
+        "rows", "k", "n", "itemsize", "matrices"]
+    # No default stands in for an operand: the rule and the kernel's own
+    # guard read the same two numbers off the matrices.
+    assert all(p.default is p.empty for p in
+               inspect.signature(gm.takes_kernel).parameters.values())
 
 
 def test_off_the_tpu_the_products_are_ragged_dots():
     rows, (wg, wi, wo) = operands(32, 4, 128, 256, jnp.float32)
     tokens = jnp.asarray([3, 0, 20, 5], jnp.int32)
-    assert gm.takes_kernel(32, 128, 256)
+    assert gm.takes_kernel(32, 128, 256, 4, 2)
     # Both are traced, and only the backend's own is lowered.
     traced = str(jax.make_jaxpr(gm.grouped_swiglu)(rows, wg, wi, tokens))
     assert "pallas_call" in traced and "ragged_dot" in traced
@@ -431,6 +529,99 @@ def test_a_chunks_record_counts_the_grouped_calls_too():
     first = eng.step_log()["steps"][-1]
     assert {name for name, _ in eng._grouped_calls} == {"_chunk"}
     assert first["moe_grouped_calls"] == 2 * c.n_layer
+
+
+def abstract_engine(c, seqs):
+    """An engine over a tree of shapes: its programs can be traced,
+    nothing of the model is held. Its pools are told to lie on a TPU."""
+    from raytpu.inference import InferenceEngine
+    from raytpu.models.mixtral import Mixtral
+
+    given = jax.eval_shape(
+        lambda: Mixtral(c).init(jax.random.PRNGKey(0),
+                                jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = InferenceEngine(c, given, page_size=8, max_num_seqs=seqs,
+                          max_model_len=64)
+    eng._devices = ["tpu:0"]
+    return eng
+
+
+def shapes_of(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+
+def ids(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def lfm2_decode(c, seqs):
+    """LFM2's decode program of ``seqs`` sequences, traced: the products
+    noted, and the layers that are routed."""
+    eng = abstract_engine(c, seqs)
+    pools, states = shapes_of(eng.cache.k), shapes_of(eng.cache.state)
+    jax.eval_shape(eng._decode_fn, eng._params, pools, pools, states,
+                   ids(seqs), ids(seqs), ids(seqs), ids(seqs),
+                   ids(seqs, 8), ids(seqs))
+    (_, calls), = eng._grouped_calls.items()
+    return calls, c.n_layer - c.first_dense
+
+
+def exaone_verify(c, seqs):
+    """K-EXAONE's verify program (two positions a sequence) and its
+    module's draft program: the products noted in each, and the routed
+    layers of each."""
+    eng = abstract_engine(c, seqs)
+    pools, state = shapes_of(eng.cache.k), shapes_of(eng._draft_state)
+    pair = (ids(seqs, 2),) * 2
+    tables = (ids(seqs, 8),) * 2
+    jax.eval_shape(eng._decode_fn, eng._params, pools, pools, state,
+                   ids(seqs), ids(seqs), ids(seqs), pair, tables)
+    rows = (jax.ShapeDtypeStruct((seqs,), jnp.float32), ids(seqs),
+            jax.ShapeDtypeStruct((seqs,), jnp.uint32))
+    jax.eval_shape(eng._draft_fn, eng._params, pools, pools, state,
+                   ids(seqs),
+                   jax.ShapeDtypeStruct((seqs, 2, c.n_embd), c.dtype),
+                   ids(seqs, 2), ids(seqs), ids(seqs), pair, tables, *rows)
+    calls = {name: n for (name, _), n in eng._grouped_calls.items()}
+    return (calls["_decode"] + calls["_draft"],
+            c.n_layer - c.first_dense + c.mtp_layers)
+
+
+# A family's program of its published expert widths at the cell's batch
+# (LFM2: 64 streams x 4 experts; K-EXAONE: 16 x 2 positions x 8), cut in
+# depth and vocabulary, which no product of the routed layer sees.
+PUBLISHED = {
+    "lfm2": ("Lfm2MoeConfig", 4, LFM2, lfm2_decode, 64, 4),
+    "exaone": ("ExaoneMoeConfig", 3, EXAONE, exaone_verify, 16, 16),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("seqs", ["the cell's batch", 2])
+@pytest.mark.parametrize("name", sorted(PUBLISHED))
+def test_programs_at_published_expert_widths_note_what_the_rule_says(
+        name, seqs, dtype):
+    from raytpu.models import mixtral
+
+    cls, layers, kn, program, batch, rows_a_seq = PUBLISHED[name]
+    c = getattr(mixtral, cls)(
+        n_layer=layers, vocab_size=512, block_size=256, dtype=dtype,
+        param_dtype=dtype, attn_impl="reference", paged_attn="reference",
+        remat=False)
+    assert (c.n_embd, c.n_inter) == kn
+    seqs = batch if seqs == "the cell's batch" else seqs
+    calls, routed = program(c, seqs)
+    rows, item = seqs * rows_a_seq, jnp.dtype(dtype).itemsize
+    says = (gm.takes_kernel(rows, c.n_embd, c.n_inter, item, 2)
+            + gm.takes_kernel(rows, c.n_inter, c.n_embd, item, 1))
+    assert routed >= 2 and calls == says * routed
+    # The cell's batch fills two row tiles and both products are the
+    # kernel's in either type (float32 in narrower blocks); two
+    # sequences' rows fill no tile of 16 in LFM2 and two in K-EXAONE.
+    assert says == (2 if rows % 16 == 0 else 0)
+    assert (says == 2) == (seqs == batch or name == "exaone")
 
 
 def test_a_dense_engines_records_do_not():

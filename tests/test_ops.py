@@ -316,13 +316,18 @@ def _routed_shapes(rows, experts, k, n):
             bf16(experts, n, k), jax.ShapeDtypeStruct((experts,), jnp.int32))
 
 
-# The routed layer's products at the three served families' decode shapes
-# (rows = sequences x experts a token) and at Mellum2's chunk of 2,048.
+# The routed layer's products at the served families' decode shapes
+# (rows = sequences x experts a token) and at chunks of 2,048; K-EXAONE's
+# experts go through in blocks of columns.
 _ROUTED_CASES = {
     "mellum_decode": _routed_shapes(256, 64, 2304, 896),
     "olmoe_decode": _routed_shapes(128, 64, 2048, 1024),
     "joyai_decode": _routed_shapes(256, 32, 2048, 768),
     "mellum_chunk": _routed_shapes(16384, 64, 2304, 896),
+    "lfm2_decode": _routed_shapes(256, 64, 2048, 1536),
+    "lfm2_chunk": _routed_shapes(8192, 64, 2048, 1536),
+    "kexaone_verify": _routed_shapes(256, 16, 6144, 2048),
+    "kexaone_chunk": _routed_shapes(16384, 16, 6144, 2048),
 }
 
 
