@@ -17,6 +17,19 @@ The TPU compile-once discipline, concretely:
   only the first time a bucket size appears. Dummy rows point at the
   scratch page (page 0) with ``context_len=1`` so padding attends to
   one masked-garbage slot and pollutes nothing.
+- **A decode step's launch sends the device what changed since the
+  last one.** The small rows (tokens, positions, dests, context lengths,
+  seats) are built over the batch from the cache's kept tables
+  (``_decode_inputs``: no Python call a sequence) and go over with the
+  call itself, as numpy arrays (``_hand``; what more than one program
+  reads is put once, ``_put``: the positions, a verify step's rows).
+  A kind's block tables stay on the device from step to
+  step and are put again only when the batch's membership, the table
+  width or one of the batch's rows of that kind has changed
+  (``_batch_tables``, ``cache.table_version``): a row changes once every
+  ``page_size`` steps. The sampler's per-request rows follow the same
+  rule (``_batch_rows``). The step record counts both (``host_puts``,
+  ``tables_reused``).
 - **Two kinds of layer** (``serving.layer_windows``: window layers among
   full ones) are two kinds of pool behind the one cache manager, and
   the chunk and decode programs take ``dests`` and block tables as a
@@ -409,6 +422,16 @@ class InferenceEngine:
         # for: (its sequences, the rows on the device, how many of them
         # are stochastic). Remade when the batch's membership changes.
         self._sampled_batch = ([], None, 0)
+        # The decode batch whose block tables the device holds, at the
+        # width they were put at, and a kind of pool the version of the
+        # batch's rows then (``cache.table_version``) and the array: what
+        # a step passes again while none of these has moved
+        # (:meth:`_batch_tables`).
+        self._tabled_batch: Tuple[list, int] = ([], 0)
+        self._tables_kept: Dict[int, Tuple[int, Any]] = {}
+        # Arrays handed from the host to the device so far (``_put``),
+        # and the block tables put and passed again, over kinds and steps.
+        self._host_puts = self._table_puts = self._table_reuses = 0
         # One record per step(): its phases' stamps and what it ran.
         self.recorder = tracing.StepRecorder()
         # Called, if set, once a decode step's program is on its way to
@@ -607,62 +630,104 @@ class InferenceEngine:
             temperature[i] = seq.sampling.temperature
             top_k[i] = min(seq.sampling.top_k, np.iinfo(np.int32).max)
             seed[i] = seq.sampling.seed & 0xFFFFFFFF
-        return ((self._put(temperature), self._put(top_k), self._put(seed)),
+        return (self._put((temperature, top_k, seed)),
                 int(np.count_nonzero(temperature > 0.0)))
 
     def _put(self, x):
-        """Host array → device input. Under a tp mesh, inputs are
+        """Host arrays → device arrays, in one call however many: ``x``
+        is an array or a tuple of them (of tuples: what a program takes a
+        kind of pool; None stays None). Under a tp mesh, inputs are
         committed replicated — jit rejects a mix of mesh-sharded params
         and default-device-committed arrays."""
+        self._host_puts += len(self._jax.tree_util.tree_leaves(x))
+        return self._jax.device_put(x, self._repl_sharding)
+
+    def _hand(self, x):
+        """Host arrays (``x`` as :meth:`_put` takes it) for a jitted call
+        that reads them once, counted as what they are, transfers from
+        the host. On one device the call is given them as they are: the
+        dispatch's own transfer costs less than a put (on the v5e 1.0 ms
+        a decode launch of five small rows against 1.4 as one put and 1.7
+        as five; ``PERF.md``, PR 49). Under a tp mesh they are put, so
+        that they stay committed replicated. What several programs read,
+        or a later step, is :meth:`_put` either way."""
         if self._repl_sharding is not None:
-            return self._jax.device_put(x, self._repl_sharding)
-        return self._jnp.asarray(x)
+            return self._put(x)
+        self._host_puts += len(self._jax.tree_util.tree_leaves(x))
+        return x
+
+    @staticmethod
+    def _same_batch(batch: SequenceT[Sequence],
+                    seqs: SequenceT[Sequence]) -> bool:
+        """Whether ``seqs`` are the sequences of ``batch``, row for row:
+        a sequence is known by what it is, not by its name."""
+        return len(batch) == len(seqs) \
+            and all(map(operator.is_, batch, seqs))
 
     def _batch_rows(self, seqs: SequenceT[Sequence], bucket: int):
         """:meth:`_sampling_rows` of a decode's batch, put again only when
         its membership has changed since the last step."""
         batch, rows, stochastic = self._sampled_batch
-        if len(batch) != len(seqs) \
-                or not all(map(operator.is_, batch, seqs)):
+        if not self._same_batch(batch, seqs):
             rows, stochastic = self._sampling_rows(seqs, bucket)
             self._sampled_batch = (list(seqs), rows, stochastic)
         return rows, stochastic
 
-    def _seats(self, ids: SequenceT[str], bucket: int) -> np.ndarray:
-        """Each sequence's seat, int32 ``[bucket]``; padding rows name
-        seat 0, the scratch row."""
-        seats = np.zeros(bucket, dtype=np.int32)
-        seats[:len(ids)] = [self.cache.seat(rid) for rid in ids]
-        return seats
+    def _batch_tables(self, seqs: SequenceT[Sequence], ids: List[str],
+                      rows: np.ndarray, width: int):
+        """A decode batch's block tables on the device, as a program
+        takes them by kind. A kind's is the array the last step was given
+        while the batch's membership, the ``width`` and what the cache
+        holds in the batch's ``rows`` of that kind are what they were
+        then: a row changes once every ``page_size`` steps. What has
+        moved is gathered and put again, in one call."""
+        cache, kept = self.cache, self._tables_kept
+        if self._tabled_batch[1] != width \
+                or not self._same_batch(self._tabled_batch[0], seqs):
+            self._tabled_batch = (list(seqs), width)
+            kept.clear()
+        versions = [cache.table_version(rows, kind) for kind in cache.kinds]
+        stale = [kind for kind in cache.kinds
+                 if kept.get(kind, (None,))[0] != versions[kind]]
+        if stale:
+            fresh = self._put(tuple(
+                cache.table_array(ids, width, batch=len(rows), kind=kind)
+                for kind in stale))
+            for kind, table in zip(stale, fresh):
+                kept[kind] = (versions[kind], table)
+        reused = len(versions) - len(stale)
+        self.recorder.open.fields["tables_reused"] = reused
+        self._table_puts += len(stale)
+        self._table_reuses += reused
+        return self._by_kind(lambda kind: kept[kind][1])
 
-    def _program_args(self, ids: SequenceT[str], bucket: int) -> tuple:
-        """What each of the three programs takes before its inputs: the
-        working copy, the pools, and of a model whose layers keep a state
-        the state arrays and the seats of the ``bucket`` rows' sequences."""
+    def _call(self, fn, seats, *inputs):
+        """Run one of the three programs and rebind what it consumed:
+        the pools and, of a model whose layers keep a state, the state
+        arrays, which it takes with its sequences' ``seats`` (on the
+        device; else None). Returns ``(logits, what else it returned: a
+        routed model's count)``."""
         cache = self.cache
-        if not cache.state:
-            return self._params, cache.k, cache.v
-        return (self._params, cache.k, cache.v, cache.state,
-                self._put(self._seats(ids, bucket)))
-
-    def _call(self, fn, ids: SequenceT[str], bucket: int, *inputs):
-        """Run one of the three programs for the sequences ``ids`` and
-        rebind what it consumed: the pools and the state arrays. Returns
-        ``(logits, what else it returned: a routed model's count)``."""
-        cache = self.cache
+        state = (cache.state, seats) if cache.state else ()
         logits, cache.k, cache.v, *more = fn(
-            *self._program_args(ids, bucket), *inputs)
+            self._params, cache.k, cache.v, *state, *inputs)
         if cache.state:
             cache.state, *more = more
         return logits, more
 
+    def _seats(self, ids: SequenceT[str], bucket: int):
+        """The seats of a program's sequences, int32 ``[bucket]``, for
+        the host's one put of its inputs; None where no layer keeps a
+        state."""
+        return self.cache.seats(ids, bucket) if self.cache.state else None
+
     def _by_kind(self, of):
-        """``of(kind)`` on the device, as a program takes what it is
-        given a kind of pool (``dests``, block tables): the one array,
-        or over two kinds the pair, full first."""
+        """``of(kind)`` as a program takes what it is given a kind of
+        pool (``dests``, block tables): the one array, or over two kinds
+        the pair, full first."""
         if not self._two_kinds:
-            return self._put(of(0))
-        return tuple(self._put(of(kind)) for kind in self.cache.kinds)
+            return of(0)
+        return tuple(of(kind) for kind in self.cache.kinds)
 
     def _count_experts(self, experts, *programs) -> None:
         """Add what the programs of a routed model returned beside their
@@ -737,7 +802,7 @@ class InferenceEngine:
                 "live_pages_window": 0, "window_pages_released": 0,
                 "pages_owned_full": 0, "pages_owned_window": 0,
                 "state_seats": 0, "state_bytes": 0,
-                "sampled_stochastic": 0,
+                "sampled_stochastic": 0, "host_puts": 0, "tables_reused": 0,
                 "kv_bytes_per_token": self._kv_token_bytes} | (
                     {"drafted": 0, "accepted": 0, "emitted": 0}
                     if self._drafting else {})) as st:
@@ -873,7 +938,7 @@ class InferenceEngine:
             seq.request_id, start, take, bucket, kind))
         if whole:
             attrs.update(tokens=take, bucket=bucket)
-            fn, inputs = self._prefill_fn, (self._put(tokens), dests)
+            fn, inputs = self._prefill_fn, (tokens, dests)
             program = ("_prefill", bucket)
         else:
             attrs.update(tokens=take, bucket=bucket, start=start)
@@ -887,11 +952,12 @@ class InferenceEngine:
                 [seq.request_id], p_used, kind=kind))
             if self.paged_attn_impl == "reference":
                 self._pages_gathered += p_used
-            fn, inputs = self._chunk_fn, (
-                self._put(tokens), self._put(positions), dests, tables)
+            fn, inputs = self._chunk_fn, (tokens, positions, dests, tables)
             program = ("_chunk", f"{bucket}x{p_used}")
+        seats, *inputs = self._hand(
+            (self._seats([seq.request_id], 1), *inputs))
         if self._drafting is None:
-            logits, experts = self._call(fn, [seq.request_id], 1, *inputs)
+            logits, experts = self._call(fn, seats, *inputs)
         else:
             # The tokens that follow the rows', for the module; -1 where
             # a fresh prompt ends and the program samples the one to come.
@@ -903,9 +969,9 @@ class InferenceEngine:
             (logits, self.cache.k, self.cache.v, count, first,
              self._draft_state) = fn(
                 self._params, self.cache.k, self.cache.v, self._draft_state,
-                (self._put(following), self._put(np.int32(take - 1)),
-                 self._put(np.int32(end - 1)),
-                 self._put(np.int32(self.cache.seat(seq.request_id))),
+                (*self._put((following, np.int32(take - 1),
+                             np.int32(end - 1),
+                             np.int32(self.cache.seat(seq.request_id)))),
                  *rows),
                 *inputs)
             experts = [count]
@@ -931,74 +997,78 @@ class InferenceEngine:
             self._emit(seq, int(np.asarray(ids)[0]), out)
         return take
 
+    def _decode_inputs(self, seqs: List[Sequence], ahead: int):
+        """What a decode step over ``seqs`` hands its programs, each
+        writing ``ahead`` positions a sequence from the one it stands at,
+        built over the batch and not a sequence at a time: ``(ids,
+        bucket, table width, tokens, positions, dests by kind, block
+        tables by kind)``, host arrays of ``bucket`` rows but the tables,
+        which are on the device (:meth:`_batch_tables`). A padding row
+        names the scratch page's first slots and position 0. Slides the
+        window tables on to the step's positions and fills the step
+        record's fields of what the step reads."""
+        cache, fields = self.cache, self.recorder.open.fields
+        b = len(seqs)
+        bucket = _bucket_for(b, self.decode_buckets)
+        ids = [s.request_id for s in seqs]
+        rows = cache.rows(ids, bucket)
+        tokens = np.zeros(bucket, dtype=np.int32)
+        tokens[:b] = [(s.generated or s.prompt)[-1] for s in seqs]
+        positions = np.zeros(bucket, dtype=np.int32)
+        positions[:b] = [s.cached_len for s in seqs]
+        # Trim the block tables to the batch's actual max page count
+        # (bucketed): the reference gather then reads O(batch max
+        # context), not O(longest-ever sequence).
+        P = _bucket_for(cache.table_width(rows), self.page_buckets)
+        newest = positions[:b] + (ahead - 1)
+        fields["window_pages_released"] += cache.slide_rows(
+            ids, rows[:b], positions[:b], newest + 1)
+        written = positions if ahead == 1 else \
+            positions[:, None] + np.arange(ahead, dtype=np.int32)
+        dests = self._by_kind(lambda kind: cache.slots(rows, written, kind))
+        # What the paged kernel must read: the newest position's context.
+        live_pages = int(cache.pages_read(newest).sum())
+        fields.update(decodes=b, bucket=bucket, table_width=P,
+                      live_pages=live_pages, live_pages_full=live_pages)
+        if self._two_kinds:
+            fields["live_pages_window"] += int(
+                cache.pages_read(newest, 1).sum())
+        if self.paged_attn_impl == "reference":
+            self._pages_gathered += bucket * P
+        return (ids, bucket, P, tokens, positions, dests,
+                self._batch_tables(seqs, ids, rows, P))
+
     def _run_decode(self, seqs: List[Sequence],
                     out: List[StepOutput]) -> int:
         if self._drafting is not None:
             return self._run_verify(seqs, out)
         recorder = self.recorder
+        fields = recorder.open.fields
         with recorder.phase("infer.decode") as dec:
             with recorder.phase("infer.decode.launch") as launch:
-                b = len(seqs)
-                bucket = _bucket_for(b, self.decode_buckets)
-                # Trim the block tables to the batch's actual max page
-                # count (bucketed): the reference gather then reads
-                # O(batch max context), not O(longest-ever sequence).
-                P = _bucket_for(max(self.cache.num_seq_pages(s.request_id)
-                                    for s in seqs), self.page_buckets)
-                tokens = np.zeros(bucket, dtype=np.int32)
-                positions = np.zeros(bucket, dtype=np.int32)
-                # page-0 slot 0 = scratch
-                dests = np.zeros(bucket, dtype=np.int32)
-                context_lens = np.ones(bucket, dtype=np.int32)
-                live_pages = 0  # what the paged kernel must read
-                ids = [s.request_id for s in seqs]
-                fields = recorder.open.fields
-                for i, seq in enumerate(seqs):
-                    pos = seq.cached_len
-                    tokens[i] = seq.tokens[-1]
-                    positions[i] = pos
-                    dests[i] = self.cache.slot(seq.request_id, pos)
-                    context_lens[i] = pos + 1
-                    live_pages += self.cache.pages_for(pos + 1)
-                if self._two_kinds:
-                    # The same for the window layers' pools, their tables
-                    # slid on to this step's positions first.
-                    wdests = np.zeros(bucket, dtype=np.int32)
-                    for i, seq in enumerate(seqs):
-                        pos = seq.cached_len
-                        fields["window_pages_released"] += self.cache.slide(
-                            seq.request_id, pos, pos + 1)
-                        wdests[i] = self.cache.slot(seq.request_id, pos, 1)
-                        fields["live_pages_window"] += \
-                            self.cache.pages_read(pos, 1)
-                    dests = (dests, wdests)
-                else:
-                    dests = (dests,)
-                dests = self._by_kind(lambda kind: dests[kind])
-                tables = self._by_kind(lambda kind: self.cache.table_array(
-                    ids, P, batch=bucket, kind=kind))
-                if self.paged_attn_impl == "reference":
-                    self._pages_gathered += bucket * P
-                dec.attrs.update(batch=b, bucket=bucket)
-                fields.update(
-                    decodes=b, bucket=bucket, table_width=P,
-                    live_pages=live_pages, live_pages_full=live_pages)
-                device_positions = self._put(positions)
-                logits, experts = self._call(
-                    self._decode_fn, ids, bucket, self._put(tokens),
-                    device_positions, dests, tables,
-                    self._put(context_lens))
+                put = self._host_puts
+                ids, bucket, P, tokens, positions, dests, tables = \
+                    self._decode_inputs(seqs, 1)
+                dec.attrs.update(batch=len(seqs), bucket=bucket)
+                # The small rows go over with the call; the positions,
+                # which the sampler behind it takes too, are put once.
+                seats, tokens, dests, context_lens = self._hand(
+                    (self._seats(ids, bucket), tokens, dests, positions + 1))
+                inputs = (tokens, self._put(positions), dests, tables,
+                          context_lens)
+                logits, experts = self._call(self._decode_fn, seats, *inputs)
                 for count in experts:
                     # Asked for now, it comes back beside the ids; left
                     # to the wait it is a transfer of its own, 0.5 ms.
                     count.copy_to_host_async()
+                fields["host_puts"] = self._host_puts - put
             with recorder.phase("infer.decode.wait") as wait:
                 # The chip is on the decode. The sampler goes out behind
                 # it, over the logits where they lie and the positions
                 # the decode was given; the requests' own rows are put
                 # again only when the batch's membership has changed.
                 rows, stochastic = self._batch_rows(seqs, bucket)
-                ids = self._sample_fn(logits, *rows, device_positions)
+                ids = self._sample_fn(logits, *rows, inputs[1])
                 ids.copy_to_host_async()
                 fields["sampled_stochastic"] += stochastic
                 # The host blocked on the device and on the copy back,
@@ -1018,20 +1088,19 @@ class InferenceEngine:
                 # FLOPs from XLA's own cost model, computed once per
                 # (batch bucket x table width) program — lower() reuses
                 # the jit cache, so this never triggers a second compile.
+                cache = self.cache
                 flops = prof.ensure_flops(
                     ("decode", bucket, P),
                     lambda: cost_analysis_flops(
-                        self._decode_fn,
-                        *self._program_args([s.request_id for s in seqs],
-                                            bucket),
-                        self._put(tokens), self._put(positions), dests,
-                        tables, self._put(context_lens)))
+                        self._decode_fn, self._params, cache.k, cache.v,
+                        *((cache.state, seats) if cache.state else ()),
+                        *inputs))
                 # Launch to token ids on the host: the real step.
                 prof.observe_step(wait.t1 - launch.t0, flops=flops)
                 self._hbm_tick += 1
                 if self._hbm_tick % 32 == 1:
                     prof.observe_hbm()
-        return b
+        return len(seqs)
 
     def _run_verify(self, seqs: List[Sequence],
                     out: List[StepOutput]) -> int:
@@ -1039,53 +1108,27 @@ class InferenceEngine:
         positions a sequence, the token it stands at and its draft, and
         one token or two out. Returns the tokens emitted."""
         recorder = self.recorder
+        fields = recorder.open.fields
         with recorder.phase("infer.decode") as dec:
             with recorder.phase("infer.decode.launch"):
+                put = self._host_puts
+                # Both positions' rows: a padding row's go to the scratch
+                # page's first two slots, and it names the scratch row of
+                # the module's state.
+                ids, bucket, P, tokens, positions, dests, tables = \
+                    self._decode_inputs(seqs, 2)
                 b = len(seqs)
-                bucket = _bucket_for(b, self.decode_buckets)
-                ids = [s.request_id for s in seqs]
-                P = _bucket_for(max(self.cache.num_seq_pages(r)
-                                    for r in ids), self.page_buckets)
-                tokens = np.zeros(bucket, dtype=np.int32)
-                positions = np.zeros(bucket, dtype=np.int32)
-                # Padding: the scratch page's first two slots, the
-                # scratch row of the module's state.
-                dests = [np.tile(np.arange(2, dtype=np.int32), (bucket, 1))
-                         for _ in self.cache.kinds]
-                slots = self._seats(ids, bucket)
-                fields = recorder.open.fields
-                live_pages = 0
-                for i, seq in enumerate(seqs):
-                    pos = seq.cached_len
-                    tokens[i] = (seq.generated or seq.prompt)[-1]
-                    positions[i] = pos
-                    live_pages += self.cache.pages_for(pos + 2)
-                    for kind in self.cache.kinds:
-                        if kind:  # the window table slid on to both
-                            fields["window_pages_released"] += \
-                                self.cache.slide(seq.request_id, pos, pos + 2)
-                            fields["live_pages_window"] += \
-                                self.cache.pages_read(pos + 1, 1)
-                        dests[kind][i] = [
-                            self.cache.slot(seq.request_id, pos + j, kind)
-                            for j in range(2)]
-                dests = self._by_kind(lambda kind: dests[kind])
-                tables = self._by_kind(lambda kind: self.cache.table_array(
-                    ids, P, batch=bucket, kind=kind))
-                if self.paged_attn_impl == "reference":
-                    self._pages_gathered += bucket * P
                 dec.attrs.update(batch=b, bucket=bucket)
-                fields.update(
-                    decodes=b, bucket=bucket, table_width=P,
-                    live_pages=live_pages, live_pages_full=live_pages,
-                    drafted=b)
+                fields["drafted"] = b
                 rows, stochastic = self._batch_rows(seqs, bucket)
-                slots, positions = self._put(slots), self._put(positions)
+                slots, tokens, positions, dests = self._put(
+                    (self.cache.seats(ids, bucket), tokens, positions,
+                     dests))
                 state = self._draft_state
                 with recorder.phase("infer.decode.verify"):
                     logits, ks, vs, count, hidden = self._decode_fn(
                         self._params, self.cache.k, self.cache.v, state,
-                        slots, self._put(tokens), positions, dests, tables)
+                        slots, tokens, positions, dests, tables)
                 with recorder.phase("infer.decode.accept"):
                     chosen, kept = self._accept_fn(
                         logits, state, slots, positions, *rows)
@@ -1097,6 +1140,7 @@ class InferenceEngine:
                 self.cache.k, self.cache.v = ks, vs
                 for x in (count, more):
                     x.copy_to_host_async()
+                fields["host_puts"] = self._host_puts - put
             with recorder.phase("infer.decode.wait") as wait:
                 # Every row draws twice (accept, then a token) and the
                 # module once more; counted as the sampler's rows are.
@@ -1280,6 +1324,11 @@ class InferenceEngine:
             # model layer materializes page_size tokens per column;
             # 0 on the kernel path).
             "gathered_pages": self._pages_gathered,
+            # Block tables the decode steps put on the device, and those
+            # they passed again as the device held them, over the kinds
+            # of pool and the steps.
+            "table_puts": self._table_puts,
+            "table_reuses": self._table_reuses,
             "paged_attn_impl": self.paged_attn_impl,
             # Bytes of the tree the programs take, by dtype, over all
             # shards: all in the compute type but the norms' leaves.

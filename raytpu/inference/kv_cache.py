@@ -43,6 +43,18 @@ fail, so caching never reduces usable capacity.
 Host-side bookkeeping (block tables, free list, refcounts) is plain
 Python — it's O(pages touched) per step and never traced.
 
+**The tables as a program takes them are kept, not rebuilt.** Beside the
+lists, which say what a sequence owns and gives back, the manager keeps
+each kind's block tables as one array ``int32[rows, columns]``: a
+sequence's pages in its *row* (taken and given back with its pages; row 0
+is no sequence's, and a padding row of a batch names it), logical page
+``c`` in column ``c``, scratch (0) in every other column. The array is
+written where the lists are (``_grant`` on the right, :meth:`slide` on the
+left, :meth:`free`), so :meth:`table_array` is a gather of rows, a batch's
+slots one indexing (:meth:`slots`), and a reader that kept what it was
+given last can ask whether its rows have changed since
+(:meth:`table_version`): a row changes once every ``page_size`` positions.
+
 **Pools by kind of layer.** A model whose layers are not all alike
 (``layer_windows``) has two kinds of pool under this one manager, and a
 sequence one block table a kind. A *full* layer's pool is everything
@@ -203,6 +215,17 @@ class PagedKVCache:
         # page id -> number of block tables referencing it. Pages on
         # the free list (or retained by the prefix cache) have no entry.
         self._refs: Dict[int, int] = {}
+        # The tables as a program takes them, a kind (see the module's
+        # docstring), grown by doubling (``_fit``). ``_span[kind][row]``
+        # is the row's ``(first logical page held, one past its last)``
+        # and ``_changed[kind][row]`` what ``_writes`` read when the row
+        # was last written.
+        self._table = [np.zeros((1, 1), np.int32) for _ in self.kinds]
+        self._span = [np.zeros((1, 2), np.int64) for _ in self.kinds]
+        self._changed = [np.zeros(1, np.int64) for _ in self.kinds]
+        self._writes = 0
+        self._rows: Dict[str, int] = {}
+        self._free_rows: List[int] = []
         # Optional prefix-cache hook (see PrefixCache): retain(page)
         # keeps a ref-0 page reclaimable instead of freeing it;
         # reclaim(n) evicts up to n retained pages back to the free
@@ -310,8 +333,8 @@ class PagedKVCache:
         need = self.pages_for(max(1, num_tokens))
         if not self._has_seat() or not self._reserve(need):
             return False
-        self._tables[seq_id] = [self._take_free() for _ in range(need)]
         self._seat(seq_id)
+        self._grant(seq_id, 0, [self._take_free() for _ in range(need)])
         return True
 
     def allocate_shared(self, seq_id: str, num_tokens: int,
@@ -342,9 +365,9 @@ class PagedKVCache:
             for page in reversed(prefix_pages):
                 self._decref(page)  # rollback: back to parked/free
             return False
-        self._tables[seq_id] = list(prefix_pages) + [
-            self._take_free() for _ in range(tail)]
         self._seat(seq_id)
+        self._grant(seq_id, 0, list(prefix_pages) + [
+            self._take_free() for _ in range(tail)])
         return True
 
     def extend(self, seq_id: str, num_tokens_total: int) -> bool:
@@ -358,7 +381,7 @@ class PagedKVCache:
             return True
         if not self._reserve(need):
             return False
-        table.extend(self._take_free() for _ in range(need))
+        self._grant(seq_id, 0, [self._take_free() for _ in range(need)])
         return True
 
     def free(self, seq_id: str) -> None:
@@ -367,6 +390,13 @@ class PagedKVCache:
         pages the retainer claims stay out of the free list but
         reclaimable."""
         table = self._tables.pop(seq_id, None)
+        row = self._rows.pop(seq_id, None)
+        if row is not None:
+            for kind in self.kinds:
+                self._table[kind][row] = 0
+                self._span[kind][row] = 0
+                self._touch(kind, row)
+            self._free_rows.append(row)
         seat = self._seats.pop(seq_id, None)
         if seat is not None:
             self._free_seats.append(seat)
@@ -390,10 +420,52 @@ class PagedKVCache:
             and (not self.total_seats or bool(self._free_seats))
 
     def _seat(self, seq_id: str) -> None:
+        """What a new sequence holds before its first page: empty tables,
+        a row of the kept ones, and a seat where seats are kept."""
+        self._tables[seq_id] = []
         if self.window is not None:
             self._wtables[seq_id] = [0, []]
         if self.total_seats:
             self._seats[seq_id] = self._free_seats.pop()
+        if not self._free_rows:
+            self._fit(rows=len(self._table[0]) + 1)
+        self._rows[seq_id] = self._free_rows.pop()
+
+    def _fit(self, rows: int = 0, columns: int = 0) -> None:
+        """Have the kept tables hold ``rows`` rows and ``columns``
+        columns: twice what is asked for where they do not."""
+        have, wide = self._table[0].shape
+        if rows <= have and columns <= wide:
+            return
+        shape = (max(have, 2 * rows), max(wide, 2 * columns))
+
+        def grown(old, shape):
+            new = np.zeros(shape, old.dtype)
+            new[tuple(slice(n) for n in old.shape)] = old
+            return new
+
+        self._table = [grown(a, shape) for a in self._table]
+        self._span = [grown(a, (shape[0], 2)) for a in self._span]
+        self._changed = [grown(a, shape[:1]) for a in self._changed]
+        self._free_rows.extend(range(shape[0] - 1, have - 1, -1))
+
+    def _touch(self, kind: int, row: int) -> None:
+        self._writes += 1
+        self._changed[kind][row] = self._writes
+
+    def _grant(self, seq_id: str, kind: int, pages: List[int]) -> None:
+        """``pages`` behind those ``seq_id`` holds of ``kind``: in its
+        list and in its row of the kept table, the one writer of both on
+        the right."""
+        first, held = self._logical_pages(seq_id, kind)
+        row = self._rows[seq_id]
+        start = first + len(held)
+        end = start + len(pages)
+        self._fit(columns=end)
+        held.extend(pages)
+        self._table[kind][row, start:end] = pages
+        self._span[kind][row] = first, end
+        self._touch(kind, row)
 
     def slide(self, seq_id: str, lo: int, hi: int) -> int:
         """Before a program whose queries for ``seq_id`` stand at
@@ -412,26 +484,47 @@ class PagedKVCache:
         first, owned = held
         keep = max(0, lo - self.window + 1) // self.page_size
         released = min(max(0, keep - first), len(owned))
+        row = self._rows[seq_id]
         if released:
             self._wfree.extend(reversed(owned[:released]))
             del owned[:released]
+            self._table[1][row, first:first + released] = 0
+            self._touch(1, row)
         held[0] = first = first + released if owned else keep
-        for _ in range(self.pages_for(hi) - first - len(owned)):
-            owned.append(self._wfree.pop())
+        self._span[1][row] = first, first + len(owned)
+        need = self.pages_for(hi) - first - len(owned)
+        if need > 0:
+            self._grant(seq_id, 1, [self._wfree.pop() for _ in range(need)])
         return released
+
+    def slide_rows(self, seq_ids: Sequence[str], rows: np.ndarray,
+                   lo: np.ndarray, hi: np.ndarray) -> int:
+        """:meth:`slide` for a batch, ``seq_ids[i]`` in row ``rows[i]``
+        from ``lo[i]`` to ``hi[i]``: only the sequences whose window
+        crosses a page's edge are visited, which is one step in
+        ``page_size`` each. Returns the pages given back in all."""
+        if self.window is None:
+            return 0
+        span = self._span[1][rows]
+        keep = np.maximum(0, lo - self.window + 1) // self.page_size
+        due = (keep > span[:, 0]) | ((hi - 1) // self.page_size >= span[:, 1])
+        return sum(self.slide(seq_ids[i], int(lo[i]), int(hi[i]))
+                   for i in np.flatnonzero(due))
 
     def window_table(self, seq_id: str):
         """``(first logical page, its pages from there)`` of ``seq_id``."""
         first, pages = self._wtables[seq_id]
         return first, list(pages)
 
-    def pages_read(self, pos: int, kind: int = 0) -> int:
+    def pages_read(self, pos, kind: int = 0):
         """Pages of one layer of ``kind`` that a query at position
-        ``pos`` reads: its whole context, or its window's span."""
+        ``pos`` reads: its whole context, or its window's span. Of an
+        array of positions, an array."""
         last = pos // self.page_size
         if kind == 0:
             return last + 1
-        return last - max(0, pos - self.window + 1) // self.page_size + 1
+        return last - np.maximum(0, pos - self.window + 1) \
+            // self.page_size + 1
 
     # ---- refcount plumbing ------------------------------------------
 
@@ -498,19 +591,60 @@ class PagedKVCache:
             return pos % self.page_size
         return table[page] * self.page_size + pos % self.page_size
 
+    def rows(self, seq_ids: Sequence[str],
+             batch: Optional[int] = None) -> np.ndarray:
+        """Each sequence's row of the kept tables, ``[batch]``; rows past
+        ``len(seq_ids)`` name row 0, which is scratch all through."""
+        out = np.zeros(batch if batch is not None else len(seq_ids), np.intp)
+        out[:len(seq_ids)] = [self._rows[sid] for sid in seq_ids]
+        return out
+
+    def seats(self, seq_ids: Sequence[str], batch: int) -> np.ndarray:
+        """Each sequence's seat, int32 ``[batch]``; rows past
+        ``len(seq_ids)`` name seat 0, the scratch row."""
+        out = np.zeros(batch, np.int32)
+        out[:len(seq_ids)] = [self._seats[sid] for sid in seq_ids]
+        return out
+
+    def table_version(self, rows: np.ndarray, kind: int = 0) -> int:
+        """A number that moves whenever the content of one of ``rows``
+        of ``kind``'s kept table does: a reader that was given these
+        rows' tables when it read ``n`` holds what :meth:`table_array`
+        would give it now for as long as it reads ``n``."""
+        return int(self._changed[kind][rows].max(initial=0))
+
+    def table_width(self, rows: np.ndarray) -> int:
+        """The most pages one of ``rows`` holds in a full layer's pool:
+        the columns a table of them needs."""
+        return int(self._span[0][rows, 1].max(initial=0))
+
+    def slots(self, rows: np.ndarray, positions: np.ndarray,
+              kind: int = 0) -> np.ndarray:
+        """:meth:`slot` for a batch: the flat slot in a pool of ``kind``
+        of ``positions[i]`` (``[n]``, or ``[n, T]``: ``T`` positions a
+        sequence) of the sequence in row ``rows[i]``, int32. A position a
+        window table has slid past lies in a scratch column of the kept
+        table, so it is given a slot of the scratch page, and so is a
+        padding row's (row 0)."""
+        page = positions // self.page_size
+        index = rows if positions.ndim == 1 else rows[:, None]
+        if (page >= self._span[kind][index, 1])[rows > 0].any():
+            raise IndexError(
+                f"a position of {positions.tolist()} lies beyond its "
+                f"sequence's allocation")
+        return (self._table[kind][index, page] * self.page_size
+                + positions % self.page_size).astype(np.int32, copy=False)
+
     def table_array(self, seq_ids: Sequence[str], max_pages: int,
                     batch: Optional[int] = None, kind: int = 0
                     ) -> np.ndarray:
         """Stacked block tables ``[batch, max_pages]`` int32, padded
         with 0 (scratch) — rows past ``len(seq_ids)`` are dummy rows.
         Column ``c`` is logical page ``c`` for either ``kind``: a window
-        table's columns left of its first page are scratch too."""
-        b = batch if batch is not None else len(seq_ids)
-        out = np.zeros((b, max_pages), dtype=np.int32)
-        for i, sid in enumerate(seq_ids):
-            first, table = self._logical_pages(sid, kind)
-            out[i, first:first + len(table)] = table
-        return out
+        table's columns left of its first page are scratch too. A gather
+        of the sequences' rows of the kept table."""
+        self._fit(columns=max_pages)
+        return self._table[kind][self.rows(seq_ids, batch), :max_pages]
 
     def prefill_dests(self, seq_id: str, length: int,
                       bucket: int) -> np.ndarray:

@@ -326,6 +326,35 @@ def test_a_request_draws_the_same_alone_and_in_a_full_batch(params):
         assert alone["r0"] == together[f"r{i}"]
 
 
+@pytest.mark.parametrize("pages", [None, 16])
+def test_a_verify_step_is_handed_what_the_loop_built(params, pages):
+    """Every call of the verification program against the loop that built
+    its inputs before the tables were kept (``kept_tables``): two
+    positions' dests a kind, both kinds' tables, the module's slots; with
+    sequences that end at different steps and, on 16 pages, one that is
+    preempted and comes back in whatever row is free. Greedy tokens are
+    those of the run that never drafts."""
+    from kept_tables import watch_decode
+
+    requests = [(p, SamplingParams(max_new_tokens=n))
+                for p, n in zip(prompts(18, 17, 7), (20, 20, 11))]
+    options = {**ENGINE, **({"num_pages": pages} if pages else {})}
+    eng = InferenceEngine(TINY, params, **options)
+    calls = watch_decode(eng)
+    on, _, _, _ = serve(TINY, params, requests, engine=eng, capture=False)
+    off, _, _, _ = serve(TINY, params, requests, capture=False,
+                         drafting=False)
+    assert on == off
+    assert (eng.stats()["num_preemptions"] > 0) == bool(pages)
+    # Slots, tokens, positions and a kind's dests each step, the tables
+    # that moved, and the sampler's three rows with a new batch.
+    assert {0, 2} <= {reused for reused, _ in calls} <= {0, 1, 2}
+    for reused, puts in calls:
+        assert puts - (5 + 2 - reused) in ((0, 3) if reused == 0 else (0,))
+    stats = eng.stats()
+    assert stats["table_puts"] + stats["table_reuses"] == 2 * len(calls)
+
+
 def always_kept(eng):
     """Have ``eng``'s verification keep every draft."""
     accept = eng._accept_fn

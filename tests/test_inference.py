@@ -129,6 +129,146 @@ class TestPagedKVCache:
         assert all(0 <= d < 4 for d in dests[5:])  # page-0 slots
 
 
+class TestKeptTables:
+    """The block tables a program takes are kept a row a sequence and
+    written where the lists are (ISSUE 49): after every mutation they are
+    what the lists build (``kept_tables.table_from_lists``, the old
+    ``table_array``), and the batch forms of ``slot`` and ``slide`` are
+    the one-sequence ones."""
+
+    def make(self, kinds, window_seqs=5):
+        if kinds == 1:
+            return PagedKVCache(2, 80, 4, 1, 8, seats=window_seqs)
+        return PagedKVCache(
+            4, 80, 4, 1, 8, layer_windows=(8, None, 8, None),
+            window_pages=PagedKVCache.window_pool_pages(8, 4, window_seqs, 6),
+            window_burst=6)
+
+    def held_to_the_lists(self, c, lens):
+        from kept_tables import table_from_lists
+
+        ids = sorted(lens)
+        width = max([c.num_seq_pages(r) for r in ids] + [1]) + 2
+        rows = c.rows(ids, len(ids) + 2)
+        assert c.table_width(rows) == width - 2 or not ids
+        for kind in c.kinds:
+            want = table_from_lists(c, ids, width, len(ids) + 2, kind)
+            got = c.table_array(ids, width, batch=len(ids) + 2, kind=kind)
+            assert got.dtype == np.int32 and np.array_equal(got, want)
+            # Every position a sequence holds, and of a window table
+            # those it has slid past, which lie in the scratch page.
+            for sid, row in zip(ids, rows):
+                pos = np.arange(lens[sid], dtype=np.int32)
+                one = np.full(len(pos), row)
+                if kind and lens[sid]:
+                    first, pages = c.window_table(sid)
+                    pos = pos[:(first + len(pages)) * c.page_size]
+                    one = one[:len(pos)]
+                assert c.slots(one, pos, kind).tolist() \
+                    == [c.slot(sid, int(p), kind) for p in pos]
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    @pytest.mark.parametrize("kinds", [1, 2])
+    def test_the_kept_table_is_the_lists_after_every_mutation(
+            self, kinds, seed):
+        rng = np.random.default_rng(seed)
+        c = self.make(kinds)
+        twin = self.make(kinds)  # slid a sequence at a time
+        lens, versions, retired = {}, {}, []
+        for turn in range(120):
+            free = [r for r in "abcde" if r not in lens]
+            op = rng.choice(["allocate", "grow", "grow", "grow", "free"])
+            if op == "allocate" and free:
+                sid, n = free[0], int(rng.integers(1, 30))
+                share = [r for r in lens if lens[r] >= 8]
+                if kinds == 1 and share and rng.random() < 0.5:
+                    # A prefix grafted in: another sequence's first pages.
+                    pages = c.block_table(share[0])[:2]
+                    n = max(n, 9)
+                    for x in (c, twin):
+                        assert x.allocate_shared(sid, n, pages)
+                    assert c.block_table(sid)[:2] == pages
+                else:
+                    for x in (c, twin):
+                        assert x.allocate(sid, n)
+                # A prompt written in chunks of 6, its window slid on.
+                for lo in range(0, n, 6):
+                    for x in (c, twin):
+                        x.slide(sid, lo, min(lo + 6, n))
+                for x in (c, twin):
+                    x.slide(sid, n, n)
+                lens[sid] = n
+            elif op == "grow" and lens:
+                # A decode step of every live sequence, over the edges
+                # of pages: one or two positions each.
+                ids = sorted(lens)
+                ahead = int(rng.integers(1, 3))
+                for sid in ids:
+                    for x in (c, twin):
+                        assert x.extend(sid, lens[sid] + ahead)
+                rows = c.rows(ids)
+                before = {k: c.table_version(rows, k) for k in c.kinds}
+                tables = {k: c.table_array(ids, 12, kind=k) for k in c.kinds}
+                lo = np.array([lens[r] for r in ids], dtype=np.int32)
+                released = c.slide_rows(ids, rows, lo, lo + ahead)
+                assert released == sum(
+                    twin.slide(r, lens[r], lens[r] + ahead) for r in ids)
+                for k in c.kinds:
+                    # The version stands still only where the rows do.
+                    same = np.array_equal(
+                        tables[k], c.table_array(ids, 12, kind=k))
+                    assert (c.table_version(rows, k) == before[k]) == same
+                written = lo[:, None] + np.arange(ahead, dtype=np.int32)
+                for k in c.kinds:
+                    assert c.slots(rows, written, k).tolist() == [
+                        [c.slot(r, lens[r] + j, k) for j in range(ahead)]
+                        for r in ids]
+                for sid in ids:
+                    lens[sid] += int(rng.integers(1, ahead + 1))
+            elif op == "free" and lens:
+                # Freed, as a preempted sequence is: its name comes back
+                # under "allocate", in whatever row is free then.
+                sid = sorted(lens)[int(rng.integers(len(lens)))]
+                for x in (c, twin):
+                    x.free(sid)
+                del lens[sid]
+                retired.append(sid)
+            self.held_to_the_lists(c, lens)
+            for sid in lens:
+                assert c.block_table(sid) == twin.block_table(sid)
+                if kinds == 2:
+                    assert c.window_table(sid) == twin.window_table(sid)
+        assert retired and len(c._rows) == len(lens)
+        for sid in list(lens):
+            c.free(sid)
+        assert not any(t.any() for t in c._table)
+        assert c.free_pages() == c.total_pages
+        assert c.window_pages_owned() == 0
+
+    def test_a_position_beyond_the_allocation_is_refused(self):
+        c = self.make(1)
+        c.allocate("a", 10)  # 3 pages
+        rows = c.rows(["a"], 2)
+        assert c.slots(rows, np.array([11, 0])).tolist() \
+            == [c.slot("a", 11), 0]
+        with pytest.raises(IndexError):
+            c.slots(rows, np.array([12, 0]))
+        with pytest.raises(IndexError):
+            c.slots(rows, np.array([[11, 12], [0, 1]]))
+        assert c.seats(["a"], 3).tolist() == [c.seat("a"), 0, 0]
+
+    def test_the_tables_grow_with_the_sequences_and_the_widths(self):
+        c = PagedKVCache(1, 400, 2, 1, 4)
+        for i in range(20):
+            assert c.allocate(f"s{i}", 3 * i + 1)
+        ids = [f"s{i}" for i in range(20)]
+        from kept_tables import table_from_lists
+
+        assert np.array_equal(c.table_array(ids, 64, batch=32),
+                              table_from_lists(c, ids, 64, 32))
+        assert len(set(c.rows(ids).tolist())) == 20 and c.rows(ids).min() > 0
+
+
 class _FakePageCache(PagedKVCache):
     """Real cache minus the JAX arrays (scheduler never touches them)."""
 
@@ -636,6 +776,98 @@ class TestDeviceSampling:
         assert again.generate([[1, 2, 3]], hot) \
             == eng.generate([[1, 2, 3]], hot)
         assert eng.generate([[1, 2, 3]], cold) == first
+
+
+class TestDecodeLaunch:
+    """A decode step hands the device what changed since the last one
+    (ISSUE 49): every call of the decode program is held to the loop that
+    built its inputs before (``kept_tables.watch_decode``), and the step
+    record says what was put and what was passed again."""
+
+    def make(self, params, **kw):
+        from kept_tables import watch_decode
+
+        kw = {"page_size": 8, "max_num_seqs": 4, "max_model_len": 64, **kw}
+        eng = InferenceEngine(LCFG, params, **kw)
+        return eng, watch_decode(eng)
+
+    def test_a_table_is_put_again_only_when_a_row_of_it_has_changed(
+            self, llama_model):
+        model, params = llama_model
+        eng, calls = self.make(params)
+        out = eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=15))
+        assert out[0] == reference_greedy(model, params, [1, 2, 3], 15)
+        # Fourteen decodes, at positions 3 to 16: a new batch, then a new
+        # page at 8 and at 16. Tokens, positions, dests and context
+        # lengths go over every step, the table beside them when it moved.
+        reused = [0, 1, 1, 1, 1, 0, 1, 1, 1, 1, 1, 1, 1, 0]
+        assert calls == [(r, 5 - r) for r in reused]
+        steps = [s for s in eng.step_log()["steps"] if s["decodes"]]
+        assert [(s["tables_reused"], s["host_puts"]) for s in steps] == calls
+        assert [s["table_width"] for s in steps] == [1] * 5 + [2] * 8 + [4]
+        stats = eng.stats()
+        assert (stats["table_puts"], stats["table_reuses"]) == (3, 11)
+        # A step with no decode says so.
+        idle = [s for s in eng.step_log()["steps"] if not s["decodes"]]
+        assert idle and all((s["tables_reused"], s["host_puts"]) == (0, 0)
+                            for s in idle)
+
+    def test_a_turnover_puts_the_table_again(self, llama_model):
+        _, params = llama_model
+        eng, calls = self.make(params, page_size=16)
+        eng.add_request("a", [1, 2, 3], SamplingParams(max_new_tokens=9))
+        eng.add_request("b", [4, 5], SamplingParams(max_new_tokens=4))
+        batches = []
+        while eng.has_unfinished():
+            eng.step()
+            batches.append(sorted(s.request_id
+                                  for s in eng.scheduler.running))
+        # No page's edge is crossed: only the membership moves, once when
+        # the two decode together and once when "b" has left.
+        assert [r for r, _ in calls] == [0, 1, 1, 0, 1, 1, 1, 1]
+        assert ["a"] in batches and ["a", "b"] in batches
+
+    @pytest.mark.parametrize("scenario", ["staggered", "preempted",
+                                          "shared_prefix"])
+    def test_the_program_is_handed_what_the_loop_built(
+            self, llama_model, scenario):
+        model, params = llama_model
+        prompts = [list(range(1, 8)), list(range(20, 25)),
+                   list(range(30, 41))]
+        new, options = 8, {"page_size": 4, "max_model_len": 32}
+        if scenario == "preempted":
+            # 5 usable pages: two growing sequences cannot both stay, and
+            # the one that comes back takes whatever row is free.
+            prompts, options = prompts[:2], {
+                **options, "num_pages": 6, "max_num_seqs": 2,
+                "max_model_len": 24}
+        if scenario == "shared_prefix":
+            prompts = [list(range(1, 14)), list(range(1, 14)) + [50, 51]]
+        eng, calls = self.make(params, **options)
+        sampling = SamplingParams(max_new_tokens=new)
+        if scenario == "preempted":
+            outs = eng.generate(prompts, sampling)
+            assert eng.stats()["num_preemptions"] >= 1
+        else:
+            # One after another has started: the later graft the first's
+            # prompt pages where they share them.
+            outs = {f"r{i}": [] for i in range(len(prompts))}
+            for i, prompt in enumerate(prompts):
+                eng.add_request(f"r{i}", prompt, sampling)
+                for _ in range(2):
+                    for o in eng.step():
+                        outs[o.request_id].append(o.token_id)
+            while eng.has_unfinished():
+                for o in eng.step():
+                    outs[o.request_id].append(o.token_id)
+            outs = list(outs.values())
+        if scenario == "shared_prefix":
+            assert eng.prefix_cache.stats()["hit_tokens"] > 0
+        for prompt, out in zip(prompts, outs):
+            assert out == reference_greedy(model, params, prompt, new)
+        assert {r for r, _ in calls} == {0, 1}
+        assert all(puts == 5 - r for r, puts in calls)
+        assert eng.cache.free_pages() == eng.cache.total_pages
 
 
 class TestStepLog:

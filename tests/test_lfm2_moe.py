@@ -239,6 +239,27 @@ def test_preempted_and_resumed_gives_the_tokens_of_an_unpreempted_run(
         assert moved(family, params, eng, rid, prompt, out) < ROUNDING
 
 
+@pytest.mark.parametrize("pages", [None, 13])
+def test_a_step_with_seats_is_handed_what_the_loop_built(params, pages):
+    """Every call of the decode program against the loop that built its
+    inputs before the tables were kept (``kept_tables``): the seats go
+    over with the small rows in one put, the one kind's table when a row
+    of it has moved; on 13 pages a sequence is preempted and comes back."""
+    from kept_tables import watch_decode
+
+    batch = prompts(10, 12, 9, seed=7)
+    calm, _, _ = serve(params, batch, new_tokens=20)
+    eng = Recording(TINY, params, **{
+        **ENGINE, **({"num_pages": pages} if pages else {})})
+    calls = watch_decode(eng)
+    tight, _, _ = serve(params, batch, new_tokens=20, engine=eng)
+    assert tight == calm
+    assert (eng.stats()["num_preemptions"] > 0) == bool(pages)
+    # Seats, tokens, positions, dests, context lengths; the table.
+    assert {reused for reused, _ in calls} == {0, 1}
+    assert all(puts == 5 + 1 - reused for reused, puts in calls)
+
+
 def test_admission_stops_at_the_last_seat_with_pages_to_spare():
     cache = PagedKVCache(1, 64, 4, 2, 16, state_shapes=[(2, 64)], seats=2)
     sched = Scheduler(cache, max_num_seqs=4, max_model_len=96)

@@ -487,6 +487,46 @@ def test_batched_decode_is_solo_decode(params):
         assert solo == out
 
 
+@pytest.mark.parametrize("chunk", [None, 8])
+def test_a_step_of_two_kinds_is_handed_what_the_loop_built(params, chunk):
+    """Every call of the decode program against the loop that built its
+    inputs before the tables were kept (``kept_tables``): both kinds'
+    tables and dests, the rows a window table has slid past among them,
+    with sequences that come and go, and greedy tokens a sequence what it
+    decodes alone. A kind's table goes over again when one of its rows
+    has changed, and both with the batch."""
+    from kept_tables import watch_decode
+
+    eng = InferenceEngine(TINY, params, prefill_chunk=chunk, **ENGINE)
+    calls = watch_decode(eng)
+    batch, new = prompts(5, 21, 37, 11), (30, 12, 9, 17)
+    outs, batches = {f"r{i}": [] for i in range(4)}, []
+    for i, (prompt, n) in enumerate(zip(batch, new)):
+        eng.add_request(f"r{i}", prompt, SamplingParams(max_new_tokens=n))
+    while eng.has_unfinished():
+        decoding = [s.request_id for s in eng.scheduler.running
+                    if s.cached_len >= s.prefill_len]
+        for o in eng.step():
+            outs[o.request_id].append(o.token_id)
+        batches.append(decoding)
+    for prompt, n, out in zip(batch, new, outs.values()):
+        assert InferenceEngine(TINY, params, **ENGINE).generate(
+            [prompt], SamplingParams(max_new_tokens=n))[0] == out
+    # Tokens, positions, context lengths and a kind's dests each step,
+    # and the tables that moved: a page of 4 fills every fourth step a
+    # sequence, and the window table gives one back as often.
+    assert all(puts == 5 + 2 - reused for reused, puts in calls)
+    assert {reused for reused, _ in calls} == {0, 1, 2}
+    decoded = [b for b in batches if b]
+    assert len(decoded) == len(calls)
+    for before, now, (reused, _) in zip(decoded, decoded[1:], calls[1:]):
+        assert reused == 0 or before == now
+    stats = eng.stats()
+    assert stats["table_puts"] + stats["table_reuses"] == 2 * len(calls)
+    assert stats["table_reuses"] == sum(reused for reused, _ in calls)
+    assert eng.cache.window_pages_owned() == 0
+
+
 def test_engine_sizes_and_reports_pools_by_kind(params):
     eng = InferenceEngine(TINY, params, prefill_chunk=8, **ENGINE)
     c = eng.cache
