@@ -426,7 +426,7 @@ def main_decode_sweep() -> None:
 
     from raytpu.inference.engine import _bucket_for, _pow2_buckets
     from raytpu.models.llama import Llama, LlamaConfig, init_params
-    from raytpu.models.llama import llama_decode
+    from raytpu.models.llama import llama_step
     from raytpu.ops.paged_attention import (paged_attention,
                                             paged_attention_reference)
 
@@ -462,15 +462,15 @@ def main_decode_sweep() -> None:
             pages = 1 + b * pages_live + np.arange(pages_live)
             tables[b, :pages_live] = pages
             dests[b] = pages[ctx // page_size] * page_size + ctx % page_size
-        tokens = np.ones(batch, np.int32)
-        positions = np.full(batch, ctx, np.int32)
-        context_lens = np.full(batch, ctx + 1, np.int32)
+        # One row a sequence: ``llama_step`` at [B, 1].
+        tokens = np.ones((batch, 1), np.int32)
+        positions = np.full((batch, 1), ctx, np.int32)
         return ks, vs, tuple(jnp.asarray(a) for a in (
-            tokens, positions, dests, tables, context_lens))
+            tokens, positions, dests[:, None], tables))
 
     def decode_fn(paged):
         cfg = dataclasses.replace(base, paged_attn=paged)
-        return jax.jit(functools.partial(llama_decode, cfg))
+        return jax.jit(functools.partial(llama_step, cfg))
 
     rows = []
     for batch in batches:

@@ -5,7 +5,8 @@ forward, dq and dk/dv kernels of ``raytpu/ops/flash_attention.py`` one at a
 time, in ONE process (a process a tile cost a quarter of a minute each to
 reach the chip, and SWEEP_ATTN_r05.json is five of them timing out). The
 tiles go in through ``DEFAULT_BLOCK_Q/K``, the module attributes that
-RAYTPU_FLASH_BLOCK_Q/K set at import. Run it on the chip:
+stand before the file's own table (``None``: the table). Run it on the
+chip:
 
     chiprun --chips 1 -- python benchmarks/sweep_attn.py
     ... --shapes 256x1024x64,16x256x128 --tiles 512x256,256x512

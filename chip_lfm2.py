@@ -91,14 +91,18 @@ def wrong_config(pcfg, control: str):
     if control == "no_bias":
         return dataclasses.replace(pcfg, choice_bias=None)
 
+    # Both are wrong in a prompt's rows alone (``T > 1``): a decode step
+    # goes through the same ``step`` at one row a sequence.
     class NoCarry(ShortConv):
-        def prefill_chunk(self, x, state, seats, live, first):
-            return super().prefill_chunk(x, state, seats, live, True)
+        def step(self, x, state, seats, live, first):
+            return super().step(x, state, seats, live,
+                                first if live.shape[1] == 1 else True)
 
     class BucketEnd(ShortConv):
-        def prefill_chunk(self, x, state, seats, live, first):
-            return super().prefill_chunk(x, state, seats,
-                                         jnp.ones_like(live), first)
+        def step(self, x, state, seats, live, first):
+            return super().step(
+                x, state, seats,
+                live if live.shape[1] == 1 else jnp.ones_like(live), first)
 
     class SwapBC(ShortConv):
         def _gates(self, x):

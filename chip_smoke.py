@@ -112,8 +112,8 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
     import jax
 
     from raytpu.inference import InferenceEngine
-    from raytpu.models.gpt2 import (GPT2, gpt2_decode, gpt2_prefill,
-                                    gpt2_prefill_chunk, init_params)
+    from raytpu.models.gpt2 import (GPT2, gpt2_prefill, gpt2_step,
+                                    init_params)
     from raytpu.parallel.sharding import shard_params
 
     cfg = model_config
@@ -137,8 +137,9 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
         cache = eng.cache
         p = params if eng.mesh is None else shard_params(params, eng.mesh)
         prefill = jax.jit(functools.partial(gpt2_prefill, c))
-        chunked = jax.jit(functools.partial(gpt2_prefill_chunk, c))
-        decode = jax.jit(functools.partial(gpt2_decode, c))
+        # The family's one paged entry point, over [B, T] positions: a
+        # chunk at [1, T], a decode step at [B, 1].
+        step = jax.jit(functools.partial(gpt2_step, c))
         out = logits[name] = {}
         with _mesh_context(eng.mesh):
             # A whole prompt of one chunk, written to pages of its own.
@@ -151,41 +152,43 @@ def logits_phase(model_config, *, page_size: int, chunk: int,
                 cache.allocate(sid, lens[sid] + 1)
                 for start in range(0, lens[sid], chunk):
                     args = (p, toks[sid][None, start:start + chunk],
-                            np.arange(start, start + chunk, dtype=np.int32),
-                            cache.chunk_dests(sid, start, chunk, chunk),
+                            np.arange(start, start + chunk,
+                                      dtype=np.int32)[None],
+                            cache.chunk_dests(sid, start, chunk, chunk)[None],
                             cache.table_array(
                                 [sid], cache.num_seq_pages(sid)),
                             cache.k, cache.v)
-                    last, cache.k, cache.v = chunked(*args)
+                    last, cache.k, cache.v = step(*args)
                 return args, last
 
             def decode_args(sids):
                 pos = np.asarray([lens[sid] for sid in sids], np.int32)
-                return (p, np.asarray([toks[sid][lens[sid]] for sid in sids],
+                return (p, np.asarray([[toks[sid][lens[sid]]] for sid in sids],
                                       np.int32),
-                        pos,
-                        np.asarray([cache.slot(sid, lens[sid])
+                        pos[:, None],
+                        np.asarray([[cache.slot(sid, lens[sid])]
                                     for sid in sids], np.int32),
                         cache.table_array(
                             sids, max(map(cache.num_seq_pages, sids))),
-                        pos + 1, cache.k, cache.v)
+                        cache.k, cache.v)
 
             # b then a, so the last chunk is a's second: the one that
             # attends pages an earlier chunk wrote.
             prefill_in_chunks("b")
             args, out["chunk"] = prefill_in_chunks("a")
             dargs = decode_args(["a", "b"])
-            out["decode"] = decode(*dargs)[0]
+            out["decode"] = step(*dargs)[0][:, 0]
             for sid in ("whole", "a"):  # room for the long ones
                 cache.free(sid)
             for sid in ("c", "d"):
                 prefill_in_chunks(sid)
-            out["decode_long"] = decode(*decode_args(["c", "d", "b"]))[0]
+            out["decode_long"] = step(
+                *decode_args(["c", "d", "b"]))[0][:, 0]
             if name == "kernel":
                 facts["mosaic_calls"] = {
                     "prefill": _mosaic_calls(prefill, *pargs),
-                    "chunk": _mosaic_calls(chunked, *args),
-                    "decode": _mosaic_calls(decode, *dargs)}
+                    "chunk": _mosaic_calls(step, *args),
+                    "decode": _mosaic_calls(step, *dargs)}
                 if chips > 1:
                     facts["spread"] = _spread_facts(eng, cfg, chips)
         out.update({k: np.asarray(v, np.float32) for k, v in out.items()})

@@ -209,16 +209,6 @@ declare_env("RAYTPU_ZEROCOPY",
             "zero-copy data plane: pinned shm views + serialize-into-place "
             "(bool, default on; off is byte-identical to the legacy layout)")
 
-# Kernels (ops/flash_attention.py, ops/paged_attention.py).
-declare_env("RAYTPU_FLASH_DOT",
-            "operand type of the flash kernels' products: input (as q, k, v "
-            "come, float32 accumulation; the default) | f32 (upcast first)")
-declare_env("RAYTPU_FLASH_BLOCK_Q", "flash-attention query tile rows")
-declare_env("RAYTPU_FLASH_BLOCK_K", "flash-attention key tile rows")
-declare_env("RAYTPU_PAGED_ATTN",
-            "paged-attention impl: auto|on|off|kernel|interpret|reference")
-declare_env("RAYTPU_PAGED_BLOCK_Q", "paged-attention query-token block")
-
 # Runtime environments (runtime_env/container.py, runtime_env/pip_env.py).
 declare_env("RAYTPU_CONTAINER_ENGINE", "container engine binary (docker/podman)")
 declare_env("RAYTPU_ALLOW_PIP", "allow pip-install runtime envs (bool)")

@@ -15,7 +15,7 @@ The TPU compile-once discipline, concretely:
   two up to ``max_num_seqs``). Tokens/positions/slots/block tables are
   data, not shapes, so changing batch *composition* never recompiles —
   only the first time a bucket size appears. Dummy rows point at the
-  scratch page (page 0) with ``context_len=1`` so padding attends to
+  scratch page (page 0) at position 0 so padding attends to
   one masked-garbage slot and pollutes nothing.
 - **A decode step's launch sends the device what changed since the
   last one.** The small rows (tokens, positions, dests, context lengths,
@@ -50,9 +50,14 @@ The TPU compile-once discipline, concretely:
   leaves between steps (below) lies at the same seats.
 
 The jitted callables are constructed exactly once, the three programs
-by the one ``_build_program`` and the sampler by ``_build_sampler`` —
-the per-iteration loop (:meth:`InferenceEngine.step`) only *calls*
-them. A lint test pins this: ``jax.jit`` may appear in ``_build_*``
+by the one ``_build_program`` and the sampler by ``_build_sampler``. A
+family has two entry points (``serving.prefill``, ``serving.step``): the
+prefill program is the first, and the chunk and the decode programs are
+both the second, ``step`` over ``[B, T]`` positions, at ``[1, T]`` and at
+``[B, 1]`` (``_chunk_of``, ``_decode_of``: each keeps its program's own
+inputs and result). The per-iteration loop
+(:meth:`InferenceEngine.step`) only *calls* them. A lint test pins
+this: ``jax.jit`` may appear in ``_build_*``
 constructors only. The compile counters increment inside the traced
 function body, which Python executes only during tracing — i.e. exactly
 once per XLA compile — giving tests and the bench an honest recompile
@@ -165,6 +170,45 @@ def _width(block_tables) -> int:
     return jax.tree_util.tree_leaves(block_tables)[0].shape[1]
 
 
+def _rows(x, axis: int):
+    """A program's rows as the family's ``step`` takes them, ``[B, T]``:
+    ``x`` (``positions``, or ``dests``: the one array or the pair by
+    kind) with an axis of one put in. A chunk's rows are one sequence's
+    (``[T]`` -> ``[1, T]``, axis 0), a decode's one a sequence (``[B]``
+    -> ``[B, 1]``, axis 1)."""
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda a: jax.numpy.expand_dims(a, axis), x)
+
+
+def _chunk_of(step):
+    """The chunk program's forward, ``(config, params, tokens [1, T],
+    positions [T], dests [T], block_tables [1, P], *held) -> (logits
+    [1, T, V], ...)``, from a family's ``step``."""
+    def chunk(cfg, params, tokens, positions, dests, block_tables, *held):
+        return step(cfg, params, tokens, _rows(positions, 0),
+                    _rows(dests, 0), block_tables, *held)
+
+    return chunk
+
+
+def _decode_of(step):
+    """The decode program's forward, ``(config, params, tokens [B],
+    positions [B], dests [B], block_tables [B, P], context_lens [B],
+    *held) -> (logits [B, V], ...)``, from a family's ``step``.
+    ``context_lens`` is ``positions + 1`` and read by nothing: a row's
+    query sees slots ``0 .. position``."""
+    def decode(cfg, params, tokens, positions, dests, block_tables,
+               context_lens, *held):
+        logits, *rest = step(cfg, params, tokens[:, None],
+                             _rows(positions, 1), _rows(dests, 1),
+                             block_tables, *held)
+        return (logits[:, 0], *rest)
+
+    return decode
+
+
 def _bucket_for(n: int, buckets: SequenceT[int]) -> int:
     for b in buckets:
         if b >= n:
@@ -180,7 +224,7 @@ class InferenceEngine:
     :meth:`generate` to run a closed batch to completion.
 
     What it serves is the config's to say: ``model_config.serving``
-    (:class:`raytpu.models.gpt2.Serving`) names the family's three entry
+    (:class:`raytpu.models.gpt2.Serving`) names the family's two entry
     points, its working-copy rule and the pools' head shape; the engine
     knows no family, and what a pool row holds it leaves to them.
 
@@ -224,8 +268,8 @@ class InferenceEngine:
             raise TypeError(
                 f"{type(model_config).__name__} does not say how it is "
                 f"served: the engine asks a model config for `serving` "
-                f"(the family's prefill, prefill_chunk and decode entry "
-                f"points, its working-copy rule, kv_heads, head_dim)")
+                f"(the family's prefill and step entry points, its "
+                f"working-copy rule, kv_heads, head_dim)")
         if served.layer_states and (tp > 1 or mesh is not None):
             raise ValueError(
                 "a model with layers that keep a state is served on one "
@@ -401,13 +445,11 @@ class InferenceEngine:
         # adds at most log2(P_max) programs per batch bucket) instead
         # of always paying for the longest-ever sequence.
         self.page_buckets = _pow2_buckets(1, self.max_pages_per_seq)
-        # Resolved paged-attention impl ("tpu"/"interpret"/"reference")
-        # — informational, and gates the pages-gathered accounting: the
-        # kernel path never materializes a gather.
+        # Resolved paged-attention impl ("tpu"/"interpret"/"reference"):
+        # informational (``stats()``).
         from raytpu.ops.paged_attention import resolve_paged_impl
         self.paged_attn_impl = resolve_paged_impl(
             getattr(model_config, "paged_attn", None))
-        self._pages_gathered = 0
         self._prefill_compiles: Dict[int, int] = {}
         self._chunk_compiles: Dict[str, int] = {}
         self._decode_compiles: Dict[str, int] = {}
@@ -469,11 +511,12 @@ class InferenceEngine:
                 jax, "_prefill", served.prefill, self._prefill_compiles,
                 lambda tokens, dests: tokens.shape[1])
             self._chunk_fn = self._build_program(
-                jax, "_chunk", served.prefill_chunk, self._chunk_compiles,
+                jax, "_chunk", _chunk_of(served.step), self._chunk_compiles,
                 lambda tokens, positions, dests, block_tables:
                 f"{tokens.shape[1]}x{_width(block_tables)}")
             self._decode_fn = self._build_program(
-                jax, "_decode", served.decode, self._decode_compiles,
+                jax, "_decode", _decode_of(served.step),
+                self._decode_compiles,
                 lambda tokens, positions, dests, block_tables, context_lens:
                 f"{tokens.shape[0]}x{_width(block_tables)}")
             self._sample_fn = self._build_sampler(
@@ -577,11 +620,19 @@ class InferenceEngine:
             program.__name__ = name
             return jax.jit(program, donate_argnums=_POOLS)
 
+        def draft_chunk(cfg, params, hidden, next_tokens, row, positions,
+                        dests, block_tables, ks, vs):
+            # One sequence's rows, and the module's logits of its one row.
+            own, *rest = drafting.draft_step(
+                cfg, params, hidden, next_tokens, row[None],
+                _rows(positions, 0), _rows(dests, 0), block_tables, ks, vs)
+            return (own[0], *rest)
+
         def _decode(params, ks, vs, state, slots, tokens, positions, dests,
                     block_tables):
             with traced("_decode", self._decode_compiles,
                         f"{tokens.shape[0]}x{_width(block_tables)}"):
-                return drafting.verify(
+                return drafting.step(
                     cfg, params,
                     jnp.stack([tokens, state[0][slots]], axis=1),
                     positions[:, None]
@@ -599,7 +650,7 @@ class InferenceEngine:
                    positions, dests, block_tables, *rows):
             with traced("_draft", self._draft_compiles,
                         f"{ids.shape[0]}x{_width(block_tables)}"):
-                own, ks, vs, count = drafting.draft_rows(
+                own, ks, vs, count = drafting.draft_step(
                     cfg, params, hidden, ids, kept - 1,
                     positions[:, None]
                     + jnp.arange(2, dtype=positions.dtype),
@@ -612,8 +663,8 @@ class InferenceEngine:
             _build_prompt("_prefill", drafting.prefill,
                            drafting.draft_prefill, self._prefill_compiles,
                            lambda tokens, dests: tokens.shape[1]),
-            _build_prompt("_chunk", drafting.prefill_chunk,
-                           drafting.draft_chunk, self._chunk_compiles,
+            _build_prompt("_chunk", _chunk_of(drafting.step), draft_chunk,
+                           self._chunk_compiles,
                            lambda tokens, positions, dests, block_tables:
                            f"{tokens.shape[1]}x{_width(block_tables)}"),
             jax.jit(_decode, donate_argnums=_POOLS), jax.jit(_accept),
@@ -950,8 +1001,6 @@ class InferenceEngine:
                                  self.page_buckets)
             tables = self._by_kind(lambda kind: self.cache.table_array(
                 [seq.request_id], p_used, kind=kind))
-            if self.paged_attn_impl == "reference":
-                self._pages_gathered += p_used
             fn, inputs = self._chunk_fn, (tokens, positions, dests, tables)
             program = ("_chunk", f"{bucket}x{p_used}")
         seats, *inputs = self._hand(
@@ -1033,8 +1082,6 @@ class InferenceEngine:
         if self._two_kinds:
             fields["live_pages_window"] += int(
                 cache.pages_read(newest, 1).sum())
-        if self.paged_attn_impl == "reference":
-            self._pages_gathered += bucket * P
         return (ids, bucket, P, tokens, positions, dests,
                 self._batch_tables(seqs, ids, rows, P))
 
@@ -1320,10 +1367,6 @@ class InferenceEngine:
                                else None),
             # Of the steps the recorder's ring still holds.
             "decode_batch_hist": self.recorder.values("decodes"),
-            # Block-table columns handed to the reference gather (each
-            # model layer materializes page_size tokens per column;
-            # 0 on the kernel path).
-            "gathered_pages": self._pages_gathered,
             # Block tables the decode steps put on the device, and those
             # they passed again as the device held them, over the kinds
             # of pool and the steps.

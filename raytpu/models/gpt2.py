@@ -40,9 +40,9 @@ class GPT2Config:
     remat: Any = True
     scan_layers: bool = True
     attn_impl: Optional[str] = None  # None=auto, "reference", "interpret", "tpu"
-    # Paged-attention impl for decode/chunked-prefill against the KV
-    # page pool: None defers to RAYTPU_PAGED_ATTN; "kernel"/"interpret"/
-    # "reference" pin it (see raytpu.ops.paged_attention).
+    # Paged-attention impl of ``step`` against the KV page pool: None is
+    # the kernel on a TPU and the reference elsewhere; "kernel"/
+    # "interpret"/"reference" pin it (see raytpu.ops.paged_attention).
     paged_attn: Optional[str] = None
     # Cross-entropy chunking: 0 = one [B,T,V] fp32 logits buffer (1.6 GB at
     # batch 8 / 50k vocab); N>0 = flash-xent style, logits computed N rows at
@@ -61,8 +61,8 @@ class GPT2Config:
     @property
     def serving(self) -> "Serving":
         """How ``InferenceEngine`` serves this family."""
-        return Serving(gpt2_prefill, gpt2_prefill_chunk, gpt2_decode,
-                       serving_params, kv_heads=self.n_head,
+        return Serving(gpt2_prefill, gpt2_step, serving_params,
+                       kv_heads=self.n_head,
                        head_dim=self.n_embd // self.n_head)
 
     @property
@@ -75,11 +75,11 @@ class GPT2Config:
 
 class CausalSelfAttention(nn.Module):
     """MHA with training (``__call__``), cache-emitting ``prefill``, and
-    paged single-token ``decode_step`` entry points — setup()-style so
-    all three share the c_attn/c_proj params (attribute names keep the
-    param tree identical to the old compact version). No rope: GPT-2's
-    positions live in ``wpe``, so decode just embeds at the absolute
-    position and attends; KV heads == query heads."""
+    paged ``step`` entry points — setup()-style so all three share the
+    c_attn/c_proj params (attribute names keep the param tree identical
+    to the old compact version). No rope: GPT-2's positions live in
+    ``wpe``, so a step just embeds at the absolute positions and
+    attends; KV heads == query heads."""
 
     config: GPT2Config
 
@@ -116,53 +116,25 @@ class CausalSelfAttention(nn.Module):
         y = y.transpose(0, 2, 1, 3).reshape(b, t, e)
         return self.c_proj(y), k_cache, v_cache
 
-    def prefill_chunk(self, x, k_pages, v_pages, dests, block_tables,
-                      positions):
-        """Chunked-prefill paged-cache attention; same contract as
-        :meth:`raytpu.models.llama.LlamaAttention.prefill_chunk` minus
-        rope (``positions`` here only drive the causal mask — the wpe
-        lookup upstream already positioned the embeddings)."""
+    def step(self, x, k_pages, v_pages, dests, block_tables, positions):
+        """``x`` [B * T, E] at ``[B, T]`` positions against the paged
+        cache; same contract as
+        :meth:`raytpu.models.llama.LlamaAttention.step` minus rope
+        (``positions`` here only drive the causal mask — the wpe lookup
+        upstream already positioned the embeddings)."""
         c = self.config
-        b, t, e = x.shape
-        h = c.n_head
-        d = e // h
-        qkv = self.c_attn(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        b, t = positions.shape
+        h, e = c.n_head, x.shape[-1]
+        q, k, v = jnp.split(self.c_attn(x), 3, axis=-1)
         from raytpu.ops.paged_attention import (paged_attention,
                                                 scatter_kv_slots)
 
-        # A pool row is a token's K (or V) as c_attn wrote it: [T, E].
-        k_pages = scatter_kv_slots(k_pages, dests, k[0])
-        v_pages = scatter_kv_slots(v_pages, dests, v[0])
-        o = paged_attention(q.reshape(b, t, h, d), k_pages, v_pages,
-                            block_tables, positions[None, :],
-                            force=c.paged_attn)
-        y = o.reshape(b, t, e)
-        return self.c_proj(y), k_pages, v_pages
-
-    def decode_step(self, x, k_pages, v_pages, dests, block_tables,
-                    context_lens):
-        """One-token paged-cache attention; same contract as
-        :meth:`raytpu.models.llama.LlamaAttention.decode_step` minus
-        rope (``positions`` is consumed upstream by the wpe lookup)."""
-        c = self.config
-        b, e = x.shape
-        h = c.n_head
-        d = e // h
-        qkv = self.c_attn(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, h, d)
-        from raytpu.ops.paged_attention import (paged_attention,
-                                                scatter_kv_slots)
-
-        k_pages = scatter_kv_slots(k_pages, dests, k)  # rows [B, E]
-        v_pages = scatter_kv_slots(v_pages, dests, v)
-        # The token at position p sees slots 0..p = 0..context_lens-1.
-        o = paged_attention(q[:, None], k_pages, v_pages, block_tables,
-                            (context_lens - 1)[:, None],
-                            force=c.paged_attn)
-        y = o[:, 0].reshape(b, e)
-        return self.c_proj(y), k_pages, v_pages
+        # A pool row is a token's K (or V) as c_attn wrote it: [B * T, E].
+        k_pages = scatter_kv_slots(k_pages, dests.reshape(b * t), k)
+        v_pages = scatter_kv_slots(v_pages, dests.reshape(b * t), v)
+        o = paged_attention(q.reshape(b, t, h, e // h), k_pages, v_pages,
+                            block_tables, positions, force=c.paged_attn)
+        return self.c_proj(o.reshape(b * t, e)), k_pages, v_pages
 
 
 class MLP(nn.Module):
@@ -358,8 +330,8 @@ _SERVED_IN_COMPUTE_DTYPE = ("c_attn", "c_proj", "c_fc", "wte", "wpe")
 
 def serving_params(config: GPT2Config, params):
     """The working copy of ``params`` to serve from: every leaf that
-    :func:`gpt2_prefill`, :func:`gpt2_prefill_chunk` and
-    :func:`gpt2_decode` cast to ``config.dtype`` is in it already, so
+    :func:`gpt2_prefill` and :func:`gpt2_step` cast to
+    ``config.dtype`` is in it already, so
     their casts are no-ops and no step converts a weight; the values are
     the ones they would have computed, so the logits are the same bits.
     The layer norms' ``scale`` and ``bias`` stay as given:
@@ -380,13 +352,23 @@ def _tied_logits(c: GPT2Config, params, x):
 @dataclasses.dataclass(frozen=True)
 class Serving:
     """How a family is served: what ``InferenceEngine`` asks of a config
-    (its ``serving`` attribute) so that it need know no family. The three
+    (its ``serving`` attribute) so that it need know no family. The two
     entry points have one contract, ``fn(config, params, *inputs,
     k_caches, v_caches)`` -> fp32 logits, then the two lists of pools
     (``[num_pages, page_size, kv_heads * head_dim]`` a layer) with the
     call's rows written, then, where ``expert_counts`` is set, the int32
     tokens each expert of each layer received. What a pool row holds is
     known to the family and to ``raytpu.ops.paged_attention`` alone.
+
+    ``prefill(config, params, tokens [1, T], dests [T] (a kind),
+    k_caches, v_caches[, states, seats])`` -> logits [T, V]: a whole
+    prompt from position 0 through flash attention, no pool read.
+    ``step(config, params, tokens [B, T], positions [B, T], dests [B, T]
+    (a kind), block_tables [B, P] (a kind), k_caches, v_caches[, states,
+    seats])`` -> logits [B, T, V]: ``T`` consecutive positions a sequence
+    against the pools, every row written before any attends. The engine
+    builds its chunk program from it at ``[1, T]`` and its decode
+    program at ``[B, 1]`` (``[B, 2]`` where the model drafts for itself).
 
     The cache's shape by kind of layer: ``kv_heads`` and ``head_dim`` are
     every pool's row, and ``layer_windows`` (empty: every layer full)
@@ -405,25 +387,19 @@ class Serving:
     the model, ``None`` for a layer with a pool, else the shape of what a
     sequence keeps there, a short convolution's ``(taps - 1, width)``):
     it has no pool, ``k_caches`` and ``v_caches`` hold the other layers'
-    alone, in their order, and the three entry points take two arguments
+    alone, in their order, and the two entry points take two arguments
     more after them, ``states`` (one ``[seats + 1, *shape]`` array a
     layer that keeps one, donated like the pools) and ``seats`` (int32
-    ``[1]``, or ``[B]`` for ``decode``: each sequence's row of every
+    ``[B]``, ``[1]`` for ``prefill``: each sequence's row of every
     state array; 0, the scratch row, for a padding row), and return
     ``states`` written after the pools: ``(logits, k_caches, v_caches,
     states[, count])``. A program writes at a sequence's seat the state
     after its last live row (not after the bucket's last), and the
     program that holds a sequence's position 0 starts from zeros whatever
-    the seat holds: nothing else clears a seat between two sequences.
-
-    ``inputs`` -> logits: ``prefill`` (tokens [1, T], dests [T]) ->
-    [T, V]; ``prefill_chunk`` (tokens [1, T], positions [T], dests [T],
-    block_tables [1, P]) -> [1, T, V]; ``decode`` (tokens [B], positions
-    [B], dests [B], block_tables [B, P], context_lens [B]) -> [B, V]."""
+    the seat holds: nothing else clears a seat between two sequences."""
 
     prefill: Callable
-    prefill_chunk: Callable
-    decode: Callable
+    step: Callable
     params: Callable  # (config, params) -> the working copy to serve from
     kv_heads: int
     head_dim: int
@@ -432,7 +408,7 @@ class Serving:
     kv_row: Optional[int] = None
     layer_states: Tuple[Optional[Tuple[int, ...]], ...] = ()
     # Not None: the family drafts for itself (a prediction module), and
-    # an engine built with drafting on runs these and not the three above.
+    # an engine built with drafting on runs these and not the two above.
     drafting: Optional["Drafting"] = None
 
 
@@ -442,26 +418,25 @@ class Drafting:
     itself: a decode step verifies one drafted token beside the last
     emitted one and yields one or two.
 
-    The model's three walks, as :class:`Serving`'s but for one more value
-    at the end, the residual stream before the final norm (the module's
-    input), and ``verify`` in place of ``decode``: (tokens [B, 2],
-    positions [B, 2], dests [B, 2] a kind, block_tables) -> logits
-    [B, 2, V]. The module's three take ``(config, params, hidden,
+    The model's two walks, ``prefill`` and ``step``, as
+    :class:`Serving`'s but for one more value at the end, the residual
+    stream before the final norm (the module's input); a decode step is
+    ``step`` at tokens [B, 2] -> logits [B, 2, V]. The module's two,
+    ``draft_prefill`` and ``draft_step``, take ``(config, params, hidden,
     next_tokens, row, *the walk's cache inputs, k_caches, v_caches)``:
     the model's ``hidden`` of every position beside the token that
-    follows it, and return ``(the module's logits of row ``row`` (a
-    prompt's, [V]) or of row ``row[b]`` of each sequence ([B, V]),
-    k_caches, v_caches, the module's expert count)``. The module's
+    follows it, and return ``(the module's logits of row ``row`` of the
+    prompt [V] (``draft_prefill``) or of row ``row[b]`` of each sequence
+    [B, V] (``draft_step``), k_caches, v_caches, the module's expert
+    count)``. The module's
     ``pools`` full-attention pools follow the model's in ``k_caches`` and
     ``v_caches``, behind the full layers' tables and dests; its expert
     counts follow the model's."""
 
     prefill: Callable
-    prefill_chunk: Callable
-    verify: Callable
+    step: Callable
     draft_prefill: Callable
-    draft_chunk: Callable
-    draft_rows: Callable
+    draft_step: Callable
     pools: int = 1
 
 
@@ -480,13 +455,16 @@ def write_prompt_rows(k_caches, v_caches, dests, ks, vs):
              for vc, d, v in zip(v_caches, per_layer, vs)])
 
 
-def _serve(c: GPT2Config, params, x, method: str, cache_args):
+def _serve(c: GPT2Config, params, x, cache_args, whole: bool = False):
     """The serving walk, written once: the blocks over the embedded
-    ``x``, ``ln_f`` and the tied head. Layer ``i`` attends through
-    ``CausalSelfAttention.<method>(h, *cache_args(i))``, which returns
+    ``x``, ``ln_f`` and the tied head. Layer ``i`` attends
+    through ``CausalSelfAttention.step(h, *cache_args(i))`` over ``x``
+    [B * T, E], a row a position, or through ``prefill`` of a ``whole``
+    prompt from position 0 over ``x`` [1, T, E], which returns
     its output and the layer's K and V (rows, or the pools it wrote).
     Returns ``(fp32 logits, K list, V list)``."""
     attn, mlp, ln = CausalSelfAttention(c), MLP(c), nn.LayerNorm(dtype=c.dtype)
+    method = "prefill" if whole else "step"
     ks, vs = [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
@@ -511,29 +489,21 @@ def gpt2_prefill(config: GPT2Config, params, tokens, dests, k_caches,
     x = params["wte"]["embedding"].astype(c.dtype)[tokens] + \
         params["wpe"]["embedding"].astype(c.dtype)[
             jnp.arange(tokens.shape[1])][None]
-    logits, ks, vs = _serve(c, params, x, "prefill", lambda i: ())
+    logits, ks, vs = _serve(c, params, x, lambda i: (), whole=True)
     ks, vs = write_prompt_rows(k_caches, v_caches, dests, ks, vs)
     return logits[0], ks, vs
 
 
-def gpt2_prefill_chunk(config: GPT2Config, params, tokens, positions,
-                       dests, block_tables, k_caches, v_caches):
-    """Chunked-prefill forward: ``tokens`` [1, T] at absolute
-    ``positions`` [T] -> (fp32 logits [1, T, V], updated k_caches,
-    v_caches); positions feed both the wpe lookup and the causal mask."""
+def gpt2_step(config: GPT2Config, params, tokens, positions, dests,
+              block_tables, k_caches, v_caches):
+    """The paged forward (``Serving.step``): ``tokens`` [B, T] at absolute
+    ``positions`` [B, T] -> (fp32 logits [B, T, V], updated k_caches,
+    v_caches); positions feed both the wpe lookup and the causal mask.
+    The walk runs over the ``B * T`` rows (see
+    :func:`raytpu.models.llama.llama_step`)."""
     c = config
-    x = params["wte"]["embedding"].astype(c.dtype)[tokens] + \
-        params["wpe"]["embedding"].astype(c.dtype)[positions][None]
-    return _serve(c, params, x, "prefill_chunk", lambda i: (
+    x = params["wte"]["embedding"].astype(c.dtype)[tokens.reshape(-1)] + \
+        params["wpe"]["embedding"].astype(c.dtype)[positions.reshape(-1)]
+    logits, ks, vs = _serve(c, params, x, lambda i: (
         k_caches[i], v_caches[i], dests, block_tables, positions))
-
-
-def gpt2_decode(config: GPT2Config, params, tokens, positions, dests,
-                block_tables, context_lens, k_caches, v_caches):
-    """Single-token decode forward: ``tokens`` [B] -> (fp32 logits
-    [B, V], updated k_caches, v_caches); positions feed the wpe lookup."""
-    c = config
-    x = params["wte"]["embedding"].astype(c.dtype)[tokens] + \
-        params["wpe"]["embedding"].astype(c.dtype)[positions]
-    return _serve(c, params, x, "decode_step", lambda i: (
-        k_caches[i], v_caches[i], dests, block_tables, context_lens))
+    return logits.reshape(*tokens.shape, -1), ks, vs

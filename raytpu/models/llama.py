@@ -104,9 +104,9 @@ class LlamaConfig:
     remat: Any = "dots"              # False/"none" | True/"full" | "dots"
     scan_layers: bool = True
     attn_impl: Optional[str] = None
-    # Paged-attention impl for decode/chunked-prefill against the KV
-    # page pool: None defers to RAYTPU_PAGED_ATTN; "kernel"/"interpret"/
-    # "reference" pin it (see raytpu.ops.paged_attention).
+    # Paged-attention impl of ``step`` against the KV page pool: None is
+    # the kernel on a TPU and the reference elsewhere; "kernel"/
+    # "interpret"/"reference" pin it (see raytpu.ops.paged_attention).
     paged_attn: Optional[str] = None
     loss_chunk: int = 0
 
@@ -187,7 +187,7 @@ class LlamaConfig:
         routed = sum(self.ffn_width(i) is None for i in range(self.n_layer))
         kinds = self.layer_types or ()
         return Serving(
-            llama_prefill, llama_prefill_chunk, llama_decode, serving_params,
+            llama_prefill, llama_step, serving_params,
             kv_heads=self.n_kv_head, head_dim=self.head_dim,
             expert_counts=(routed, self.n_expert_held) if routed else None,
             layer_windows=tuple(
@@ -266,9 +266,10 @@ def apply_rope(x, cos, sin):
 
 
 def apply_rope_single(x, cos, sin):
-    """Rotate one token per sequence; x is [B, H, D], cos/sin [B, D/2]
-    (from ``rope_tables(d, positions)`` with per-sequence absolute
-    positions — the decode-step counterpart of :func:`apply_rope`)."""
+    """Rotate rows that each have a position of their own; x is
+    [N, H, D], cos/sin [N, D/2] (from ``rope_tables(d, positions)`` with
+    the rows' absolute positions — a paged step's counterpart of
+    :func:`apply_rope`)."""
     x1, x2 = jnp.split(x, 2, axis=-1)
     cos = cos[:, None, :].astype(x.dtype)
     sin = sin[:, None, :].astype(x.dtype)
@@ -279,8 +280,8 @@ def apply_rope_single(x, cos, sin):
 class LlamaAttention(nn.Module):
     """GQA attention with three entry points sharing one parameter set:
     ``__call__`` (training forward), ``prefill`` (forward that also
-    returns the roped K/V for cache writing), and ``decode_step``
-    (single-token paged-cache attention). setup()-style so all three
+    returns the roped K/V for cache writing), and ``step`` (``[B, T]``
+    positions against the paged cache). setup()-style so all three
     can touch the projections; attribute names keep the param tree
     identical to the old compact version (q_proj/k_proj/v_proj/o_proj),
     so ``TRANSFORMER_RULES`` sharding and existing checkpoints are
@@ -361,88 +362,30 @@ class LlamaAttention(nn.Module):
         y = y.transpose(0, 2, 1, 3).reshape(b, t, h * d)
         return self.o_proj(y), k_cache, v_cache
 
-    def prefill_chunk(self, x, k_pages, v_pages, dests, block_tables,
-                      positions):
-        """Chunked-prefill attention against the paged cache.
-
-        ``x`` [1, T, E] holds one CHUNK of a prompt whose earlier
-        tokens (prior chunks, or a shared prefix-cache hit) are already
-        in the pages. The chunk's roped K/V scatter into ``dests`` [T]
-        first — so the chunk attends to itself — then each token
-        attends to every cached position ``<=`` its own absolute
-        ``positions`` [T] through ``block_tables`` [1, P]. Padding rows
-        carry page-0 dests and position 0; their outputs are garbage
-        the engine discards. Returns ``(out [1, T, E], k_pages',
-        v_pages')``.
-        """
-        c = self.config
-        b, t, _ = x.shape
-        h, kv, d = c.n_head, c.n_kv_head, c.head_dim
-        q, k, v = self._qkv(x)
-        q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
-        k = k.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
-        v = v.reshape(b, t, kv, d).transpose(0, 2, 1, 3)
-        q, k = self._roped(q, k, positions, apply_rope)
-        # Rows as the pools hold them: a token's heads side by side.
-        k_cache = k.transpose(0, 2, 1, 3).reshape(t, kv * d)
-        v_cache = v.transpose(0, 2, 1, 3).reshape(t, kv * d)
-        from raytpu.ops.paged_attention import (paged_attention,
-                                                scatter_kv_slots)
-
-        k_pages = scatter_kv_slots(k_pages, dests, k_cache)
-        v_pages = scatter_kv_slots(v_pages, dests, v_cache)
-        # Each chunk token attends cached slots <= its absolute
-        # position (gathered/paged slot l holds logical position l).
-        o = paged_attention(q.transpose(0, 2, 1, 3), k_pages, v_pages,
-                            block_tables, positions[None, :],
-                            force=c.paged_attn, window=self.window)
-        y = o.reshape(b, t, h * d)
-        return self.o_proj(y), k_pages, v_pages
-
-    def decode_step(self, x, k_pages, v_pages, dests, block_tables,
-                    positions, context_lens):
-        """One-token attention against the paged cache.
+    def step(self, x, k_pages, v_pages, dests, block_tables, positions):
+        """Attention against the paged cache: ``T`` consecutive positions
+        a sequence (a prompt's chunk at ``B = 1``, a decode step at
+        ``T = 1``, a step that verifies a draft at ``T = 2``).
 
         Args:
-            x: [B, E] current-token hidden states.
-            k_pages / v_pages: [num_pages, page_size, KV * D] cache.
-            dests: [B] flat slots where this token's K/V is written.
-            block_tables: [B, P] page ids per sequence (0-padded; page
-                0 is scratch so padding attends to masked garbage only).
-            positions: [B] absolute position of the current token.
-            context_lens: [B] tokens visible INCLUDING the current one.
+            x: [B * T, E] hidden states, a row a position, sequence by
+                sequence (the serving walk runs over rows: ``B`` and
+                ``T`` are ``positions``' shape).
+            k_pages / v_pages: [num_pages, page_size, KV * D] pools.
+            dests: [B, T] flat slots where the rows' K/V are written;
+                padding rows name the scratch page, page 0.
+            block_tables: [B, P] page ids per sequence (0-padded, so
+                padding attends to masked garbage only).
+            positions: [B, T] absolute positions, rising by one along a
+                sequence: a row's query sees slots ``0 .. position``
+                (a window layer: the newest ``window`` of them).
 
-        Returns ``(out [B, E], k_pages', v_pages')``. The scatter
-        happens before the gather so the token attends to itself.
-        """
+        All rows are written before any attends, so a row sees itself
+        and the rows before it of its own step. Padding rows' outputs
+        are garbage the engine discards. Returns ``(out [B * T, E],
+        k_pages', v_pages')``."""
         c = self.config
-        b, _ = x.shape
-        h, kv, d = c.n_head, c.n_kv_head, c.head_dim
-        q, k, v = self._qkv(x)
-        q, k, v = q.reshape(b, h, d), k.reshape(b, kv, d), v.reshape(b, kv, d)
-        q, k = self._roped(q, k, positions, apply_rope_single)
-        from raytpu.ops.paged_attention import (paged_attention,
-                                                scatter_kv_slots)
-
-        k_pages = scatter_kv_slots(k_pages, dests, k.reshape(b, kv * d))
-        v_pages = scatter_kv_slots(v_pages, dests, v.reshape(b, kv * d))
-        # The token at position p sees slots 0..p = 0..context_lens-1.
-        o = paged_attention(q[:, None], k_pages, v_pages, block_tables,
-                            (context_lens - 1)[:, None],
-                            force=c.paged_attn, window=self.window)
-        y = o[:, 0].reshape(b, h * d)
-        return self.o_proj(y), k_pages, v_pages
-
-    def decode_rows(self, x, k_pages, v_pages, dests, block_tables,
-                    positions):
-        """``T`` consecutive positions a sequence against the paged cache:
-        a step that verifies a draft. ``x`` [B, T, E]; ``dests`` and
-        ``positions`` [B, T] (a sequence's positions rise by one);
-        ``block_tables`` [B, P]. All of a sequence's rows are written
-        before any attends, so row ``j`` sees rows ``<= j`` of its own
-        step. Returns ``(out [B, T, E], k_pages', v_pages')``."""
-        c = self.config
-        b, t, _ = x.shape
+        b, t = positions.shape
         h, kv, d = c.n_head, c.n_kv_head, c.head_dim
         q, k, v = self._qkv(x)
         q, k = self._roped(q.reshape(b * t, h, d), k.reshape(b * t, kv, d),
@@ -457,7 +400,7 @@ class LlamaAttention(nn.Module):
         o = paged_attention(q.reshape(b, t, h, d), k_pages, v_pages,
                             block_tables, positions, force=c.paged_attn,
                             window=self.window)
-        return self.o_proj(o.reshape(b, t, h * d)), k_pages, v_pages
+        return self.o_proj(o.reshape(b * t, h * d)), k_pages, v_pages
 
 
 class LlamaMLP(nn.Module):
@@ -592,8 +535,8 @@ def layer_params(params, i: int):
 
 def serving_params(config: LlamaConfig, params):
     """The working copy of ``params`` to serve from: the leaves that
-    :func:`llama_prefill`, :func:`llama_prefill_chunk` and
-    :func:`llama_decode` cast to ``config.dtype`` (every ``nn.Dense``
+    :func:`llama_prefill` and :func:`llama_step` cast to
+    ``config.dtype`` (every ``nn.Dense``
     kernel, ``lm_head`` among them, ``embed_tokens``, and a routed
     layer's stacked expert matrices ``wi``/``wg``/``wo``) are in it
     already, so no step converts a weight and the logits are the same
@@ -651,11 +594,13 @@ def live_rows(dests, k_cache):
     return of_kind(dests, FULL) >= k_cache.shape[1]
 
 
-def _serve(c: LlamaConfig, params, x, live, method: str, cache_args,
+def _serve(c: LlamaConfig, params, x, live, cache_args, whole: bool = False,
            hidden: bool = False):
     """The serving walk, written once: the blocks over the embedded
-    ``x``, the final norm and the head. Layer ``i`` attends through
-    ``c.attention(kind).<method>(h, *cache_args(i))``, which returns its
+    ``x``, the final norm and the head. Layer ``i`` attends
+    through its module's ``step(h, *cache_args(i))`` over ``x`` [B * T,
+    E], a row a position, or through ``prefill`` of a ``whole`` prompt
+    from position 0 over ``x`` [1, T, E], which returns its
     output and the layer's K and V (rows, or the pools it wrote), or
     the one pool of a latent layer (``V list`` is then empty), or a CONV
     layer's state array, written;
@@ -668,9 +613,10 @@ def _serve(c: LlamaConfig, params, x, live, method: str, cache_args,
     ``jax.named_scope("attn.full")`` or ``("attn.window")``; a latent
     layer under ``("attn.mla")``. With ``hidden`` a last value more: the
     residual stream after the last block, before the final norm, which a
-    prediction module reads (:func:`raytpu.models.mixtral.draft_rows`)."""
+    prediction module reads (:func:`raytpu.models.mixtral.draft_step`)."""
     attn = {kind: c.attention(kind) for kind in set(c.layer_types or KINDS)}
     norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
+    method = "prefill" if whole else "step"
     ks, vs, states, routed = [], [], [], []
     for i in range(c.n_layer):
         lp = layer_params(params, i)
@@ -728,8 +674,8 @@ def llama_prefill(config: LlamaConfig, params, tokens, dests, k_caches,
     c = config
     live = live_rows(dests, k_caches[0])[None]
     x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
-    logits, ks, vs, *more = _serve(c, params, x, live, "prefill", _by_layer(
-        c, k_caches, v_caches, states, (seats, live), None))
+    logits, ks, vs, *more = _serve(c, params, x, live, _by_layer(
+        c, k_caches, v_caches, states, (seats, live), None), whole=True)
     if c.layer_types:  # each layer's rows where its kind of pool has them
         dests = [of_kind(dests, kind) for kind in c.layer_types
                  if kind != CONV]
@@ -737,32 +683,23 @@ def llama_prefill(config: LlamaConfig, params, tokens, dests, k_caches,
     return (logits[0], ks, vs, *more)
 
 
-def llama_prefill_chunk(config: LlamaConfig, params, tokens, positions,
-                        dests, block_tables, k_caches, v_caches, states=(),
-                        seats=None):
-    """Chunked-prefill forward: ``tokens`` [1, T] at absolute
-    ``positions`` [T] -> (fp32 logits [1, T, V], updated k_caches,
-    v_caches[, states][, count]). See :meth:`LlamaAttention.prefill_chunk`
-    for the cache argument shapes."""
+def llama_step(config: LlamaConfig, params, tokens, positions, dests,
+               block_tables, k_caches, v_caches, states=(), seats=None):
+    """The paged forward (``Serving.step``): ``tokens`` [B, T] at
+    absolute ``positions`` [B, T] against the pools -> (fp32 logits
+    [B, T, V], updated k_caches, v_caches[, states][, count]). See
+    :meth:`LlamaAttention.step` for the cache argument shapes; a sequence
+    whose first row stands at position 0 starts a CONV layer's state
+    from zeros. The walk runs over the ``B * T`` rows, not over
+    ``[B, T, E]``: with an axis of one in the middle of every activation
+    the v5e's compiler laid a decode step's projections out so that it
+    copied their matrices every step (``PERF.md``, PR 50)."""
     c = config
-    x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
-    live = live_rows(dests, k_caches[0])[None]
-    return _serve(c, params, x, live, "prefill_chunk", _by_layer(
-        c, k_caches, v_caches, states, (seats, live, positions[0] == 0),
+    x = params["embed_tokens"]["embedding"].astype(c.dtype)[
+        tokens.reshape(-1)]
+    live = live_rows(dests, k_caches[0])
+    logits, *held = _serve(c, params, x, live.reshape(-1), _by_layer(
+        c, k_caches, v_caches, states, (seats, live, positions[:, 0] == 0),
         lambda kind: (of_kind(dests, kind), of_kind(block_tables, kind),
                       positions)))
-
-
-def llama_decode(config: LlamaConfig, params, tokens, positions, dests,
-                 block_tables, context_lens, k_caches, v_caches, states=(),
-                 seats=None):
-    """Single-token decode forward: ``tokens`` [B] -> (fp32 logits
-    [B, V], updated k_caches, v_caches[, states][, count]). See
-    :meth:`LlamaAttention.decode_step` for the cache argument shapes."""
-    c = config
-    x = params["embed_tokens"]["embedding"].astype(c.dtype)[tokens]
-    live = live_rows(dests, k_caches[0])
-    return _serve(c, params, x, live, "decode_step", _by_layer(
-        c, k_caches, v_caches, states, (seats,),
-        lambda kind: (of_kind(dests, kind), of_kind(block_tables, kind),
-                      positions, context_lens)))
+    return (logits.reshape(*tokens.shape, -1), *held)

@@ -36,7 +36,7 @@ positions, roped, among full ones that see no positions, a norm over each
 head of q and of k, the same sigmoid-routed layer, and one
 multi-token-prediction module (``mtp_layers``, :class:`PredictionModule`)
 through which the model drafts for itself when it is served
-(:func:`mtp_prefill` and its siblings; ``Serving.drafting``).
+(:func:`mtp_prefill` and :func:`mtp_step`; ``Serving.drafting``).
 :class:`Lfm2MoeConfig` is LFM2-24B-A2B's: gated short convolutions
 (:mod:`raytpu.models.short_conv`) in three layers of four, which keep a
 state a sequence and no keys or values, full attention in the others,
@@ -329,8 +329,8 @@ class ExaoneMoeConfig(MixtralConfig):
         if not self.mtp_layers:
             return served
         return dataclasses.replace(served, drafting=Drafting(
-            mtp_prefill, mtp_prefill_chunk, mtp_verify, draft_prefill,
-            draft_chunk, draft_rows, pools=self.mtp_layers))
+            mtp_prefill, mtp_step, draft_prefill, draft_step,
+            pools=self.mtp_layers))
 
     @classmethod
     def tiny(cls) -> "ExaoneMoeConfig":
@@ -534,9 +534,9 @@ class PredictionModule(nn.Module):
     over all ``u`` (keys and values of its own), and a final norm of its
     own. The model's head over the result is the logits of the token
     after the next. This is its whole-sequence form, which makes its
-    parameters; a served model runs it through :func:`draft_rows` and its
-    siblings. (Its training loss is not built: :class:`Mixtral` returns
-    the model's own logits.)"""
+    parameters; a served model runs it through :func:`draft_step` and
+    :func:`draft_prefill`. (Its training loss is not built:
+    :class:`Mixtral` returns the model's own logits.)"""
 
     config: MixtralConfig
 
@@ -632,8 +632,8 @@ def init_params(model: Mixtral, config: MixtralConfig, seed: int = 0,
 
 # ---------------------------------------------------------------------------
 # Serving a model that drafts for itself (``Serving.drafting``): the
-# model's three walks, which also give the residual stream the module
-# reads, and the module's three. The module's pool is the last of
+# model's two walks, which also give the residual stream the module
+# reads, and the module's two. The module's pool is the last of
 # ``k_caches`` / ``v_caches``, behind the full layers' tables and dests.
 # ---------------------------------------------------------------------------
 
@@ -649,53 +649,39 @@ def mtp_prefill(config, params, tokens, dests, k_caches, v_caches):
     c, n = config, config.n_layer
     live = live_rows(dests, k_caches[0])[None]
     logits, ks, vs, count, hidden = _serve(
-        c, params, _embedded(c, params, tokens), live, "prefill",
-        lambda i: (), hidden=True)
+        c, params, _embedded(c, params, tokens), live, lambda i: (),
+        whole=True, hidden=True)
     per_layer = [of_kind(dests, c.layer_kind(i)) for i in range(n)]
     ks, vs = write_prompt_rows(k_caches[:n], v_caches[:n], per_layer, ks, vs)
     return logits[0], ks + k_caches[n:], vs + v_caches[n:], count, hidden
 
 
-def _model_rows(c: MixtralConfig, params, tokens, live, method: str,
-                positions, dests, block_tables, k_caches, v_caches):
-    """The model's walk against its pools through the attention's
-    ``method``, the module's pools handed on as they are, with the
-    residual stream as the last value."""
+def mtp_step(config, params, tokens, positions, dests, block_tables,
+             k_caches, v_caches):
+    """:func:`raytpu.models.llama.llama_step` over the model's pools, the
+    module's handed on as they are, with the residual stream [B, T, E] as
+    the last value. A decode step is two positions a sequence: ``tokens``
+    [B, 2], the last emitted one and the draft, -> fp32 logits
+    [B, 2, V]."""
+    c = config
     logits, ks, vs, count, hidden = _serve(
-        c, params, _embedded(c, params, tokens), live, method,
+        c, params, _embedded(c, params, tokens.reshape(-1)),
+        live_rows(dests, k_caches[0]).reshape(-1),
         lambda i: (k_caches[i], v_caches[i],
                    of_kind(dests, c.layer_kind(i)),
                    of_kind(block_tables, c.layer_kind(i)), positions),
         hidden=True)
-    return (logits, ks + k_caches[c.n_layer:], vs + v_caches[c.n_layer:],
-            count, hidden)
+    return (logits.reshape(*tokens.shape, -1), ks + k_caches[c.n_layer:],
+            vs + v_caches[c.n_layer:], count,
+            hidden.reshape(*tokens.shape, -1))
 
 
-def mtp_prefill_chunk(config, params, tokens, positions, dests,
-                      block_tables, k_caches, v_caches):
-    """:func:`raytpu.models.llama.llama_prefill_chunk`, likewise."""
-    return _model_rows(
-        config, params, tokens, live_rows(dests, k_caches[0])[None],
-        "prefill_chunk", positions, dests, block_tables, k_caches, v_caches)
-
-
-def mtp_verify(config, params, tokens, positions, dests, block_tables,
-               k_caches, v_caches):
-    """A decode step of two positions a sequence: ``tokens`` [B, 2], the
-    last emitted one and the draft, at ``positions`` [B, 2] -> (fp32
-    logits [B, 2, V], k_caches, v_caches, count, the residual stream
-    [B, 2, E]). ``dests`` [B, 2] a kind; padding rows name the scratch
-    page. See :meth:`LlamaAttention.decode_rows`."""
-    return _model_rows(
-        config, params, tokens, live_rows(dests, k_caches[0]),
-        "decode_rows", positions, dests, block_tables, k_caches, v_caches)
-
-
-def _module(c: MixtralConfig, params, hidden, next_tokens, live,
-            method: str, cache_args):
-    """:class:`PredictionModule` over ``hidden`` [..., E] and the tokens
-    that follow, attending through ``c.attention(FULL).<method>(h,
-    *cache_args)`` as a block of :func:`_serve` does, under
+def _module(c: MixtralConfig, params, hidden, next_tokens, live, cache_args):
+    """:class:`PredictionModule` over ``hidden`` and the tokens that
+    follow, attending through ``c.attention(FULL).step(h, *cache_args)``
+    over rows [B * T, E] (``prefill(h)`` of a whole prompt [1, T, E],
+    where there are no ``cache_args``) as a block of :func:`_serve` does,
+    under
     ``jax.named_scope("attn.mtp")``. Returns the module's normed output,
     what its attention returned of K and V, and its expert count."""
     mp = params["mtp"]
@@ -712,7 +698,7 @@ def _module(c: MixtralConfig, params, hidden, next_tokens, live,
     with jax.named_scope("attn.mtp"):
         y, k, v = c.attention(FULL).apply(
             {"params": bp["attn"]}, normed("input_norm", u, bp),
-            *cache_args, method=method)
+            *cache_args, method="step" if cache_args else "prefill")
     u = u + y
     y, count = MoEFFN(c).apply({"params": bp["moe"]},
                                normed("post_attn_norm", u, bp), live)
@@ -728,45 +714,34 @@ def draft_prefill(config, params, hidden, next_tokens, row, dests,
     c = config
     dests = of_kind(dests, FULL)
     live = live_rows(dests, k_caches[0])[None]
-    x, k, v, count = _module(c, params, hidden, next_tokens, live,
-                             "prefill", ())
+    x, k, v, count = _module(c, params, hidden, next_tokens, live, ())
     ks, vs = write_prompt_rows(k_caches[c.n_layer:], v_caches[c.n_layer:],
                                dests, [k], [v])
     return (_lm_logits(c, params, x[0, row]), k_caches[:c.n_layer] + ks,
             v_caches[:c.n_layer] + vs, count)
 
 
-def draft_chunk(config, params, hidden, next_tokens, row, positions, dests,
-                block_tables, k_caches, v_caches):
-    """The module over a prompt's chunk against its pool, as
-    :func:`draft_prefill`."""
-    c = config
-    dests = of_kind(dests, FULL)
-    live = live_rows(dests, k_caches[0])[None]
-    x, k, v, count = _module(
-        c, params, hidden, next_tokens, live, "prefill_chunk",
-        (k_caches[c.n_layer], v_caches[c.n_layer], dests,
-         of_kind(block_tables, FULL), positions))
-    return (_lm_logits(c, params, x[0, row]), k_caches[:c.n_layer] + [k],
-            v_caches[:c.n_layer] + [v], count)
-
-
-def draft_rows(config, params, hidden, next_tokens, row, positions, dests,
+def draft_step(config, params, hidden, next_tokens, row, positions, dests,
                block_tables, k_caches, v_caches):
-    """The module behind a verify step: ``hidden`` [B, 2, E] beside the
-    tokens the step kept, ``next_tokens`` [B, 2] (a rejected draft's
-    place holds any id: its row is routed nowhere, attends nothing that
-    is kept and is written again by the next step) -> (its logits of row
-    ``row[b]`` of each sequence [B, V], k_caches, v_caches, count)."""
+    """The module behind the model's :func:`mtp_step`, against its pool:
+    ``hidden`` [B, T, E] beside the tokens that follow, ``next_tokens``
+    [B, T], of which a sequence's rows up to ``row[b]`` count: a prompt's
+    chunk up to its last live row, a decode step's two up to the last
+    token the step kept (a rejected draft's place holds any id: its row
+    is routed nowhere, attends nothing that is kept and is written again
+    by the next step) -> (its logits of row ``row[b]`` of each sequence
+    [B, V], k_caches, v_caches, count)."""
     c = config
     dests = of_kind(dests, FULL)
     live = live_rows(dests, k_caches[0]) \
         & (jnp.arange(hidden.shape[1]) <= row[:, None])
     x, k, v, count = _module(
-        c, params, hidden, next_tokens, live, "decode_rows",
+        c, params, hidden.reshape(-1, hidden.shape[-1]),
+        next_tokens.reshape(-1), live.reshape(-1),
         (k_caches[c.n_layer], v_caches[c.n_layer], dests,
          of_kind(block_tables, FULL), positions))
-    x = jnp.take_along_axis(x, row[:, None, None], axis=1)[:, 0]
+    x = jnp.take_along_axis(x.reshape(hidden.shape), row[:, None, None],
+                            axis=1)[:, 0]
     return (_lm_logits(c, params, x), k_caches[:c.n_layer] + [k],
             v_caches[:c.n_layer] + [v], count)
 

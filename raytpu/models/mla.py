@@ -13,8 +13,9 @@ head (roped); ``kv_b_proj`` expands the latent to each head's
 - ``prefill`` (and ``__call__``, training) is the *expanded* form: the
   latent goes through ``kv_b_proj`` and flash attention runs on heads of
   ``qk_nope_dim + qk_rope_dim`` (values zero-padded to that width).
-- ``prefill_chunk`` and ``decode_step`` are the *absorbed* form against
-  the paged latent cache (:mod:`raytpu.ops.mla_attention`): a layer has
+- ``step`` (``[B, T]`` positions: a prompt's chunk, a decode step) is
+  the *absorbed* form against the paged latent cache
+  (:mod:`raytpu.ops.mla_attention`): a layer has
   ONE pool, a token's row ``[normed latent | roped key | zeros]``, read
   once as keys and as values; ``kv_b_proj`` is folded into the query
   (``W_uk``) and applied to the attended latent (``W_uv``).
@@ -129,40 +130,25 @@ class LatentAttention(nn.Module):
         y = jnp.einsum("bthr,rhd->bthd", u, w[..., nope:])
         return y.reshape(y.shape[:2] + (h * vd,))
 
-    def prefill_chunk(self, x, pages, dests, block_tables, positions):
-        """One CHUNK of a prompt, ``x`` [1, T, E] at absolute
-        ``positions`` [T], against the latent pages: the chunk's rows
-        scatter into ``dests`` [T] first, then each token attends every
-        cached position ``<=`` its own. Returns ``(out [1, T, E],
-        pages')``."""
+    def step(self, x, pages, dests, block_tables, positions):
+        """``x`` [B * T, E], ``T`` consecutive positions a sequence at
+        absolute ``positions`` [B, T], against the latent pages (see
+        :meth:`LlamaAttention.step` for the arguments): the rows scatter
+        into ``dests`` [B, T] first, then each attends every cached
+        position ``<=`` its own. Returns ``(out [B * T, E], pages')``."""
         from raytpu.ops.mla_attention import latent_rows
         from raytpu.ops.paged_attention import scatter_kv_slots
 
         c = self.config
+        b, t = positions.shape
         q_nope, q_pe, c_kv, k_pe = self._project(x)
-        cos, sin = rope_tables(c.qk_rope_dim, positions, c.rope_theta)
-        q_pe = apply_rope(q_pe.transpose(0, 2, 1, 3), cos, sin)
-        q_pe = q_pe.transpose(0, 2, 1, 3)
-        k_pe = apply_rope(k_pe[:, None], cos, sin)[:, 0]
-        pages = scatter_kv_slots(pages, dests, latent_rows(c_kv, k_pe)[0])
-        y = self._absorbed(q_nope, q_pe, pages, block_tables,
-                           positions[None, :])
-        return self.o_proj(y), pages
-
-    def decode_step(self, x, pages, dests, block_tables, positions,
-                    context_lens):
-        """One token a sequence, ``x`` [B, E], against the latent pages
-        (see :meth:`LlamaAttention.decode_step` for the arguments).
-        Returns ``(out [B, E], pages')``."""
-        from raytpu.ops.mla_attention import latent_rows
-        from raytpu.ops.paged_attention import scatter_kv_slots
-
-        c = self.config
-        q_nope, q_pe, c_kv, k_pe = self._project(x)
-        cos, sin = rope_tables(c.qk_rope_dim, positions, c.rope_theta)
+        cos, sin = rope_tables(c.qk_rope_dim, positions.reshape(b * t),
+                               c.rope_theta)
         q_pe = apply_rope_single(q_pe, cos, sin)
         k_pe = apply_rope_single(k_pe[:, None], cos, sin)[:, 0]
-        pages = scatter_kv_slots(pages, dests, latent_rows(c_kv, k_pe))
-        y = self._absorbed(q_nope[:, None], q_pe[:, None], pages,
-                           block_tables, (context_lens - 1)[:, None])
-        return self.o_proj(y[:, 0]), pages
+        pages = scatter_kv_slots(pages, dests.reshape(b * t),
+                                 latent_rows(c_kv, k_pe))
+        y = self._absorbed(q_nope.reshape(b, t, c.n_head, -1),
+                           q_pe.reshape(b, t, c.n_head, -1), pages,
+                           block_tables, positions)
+        return self.o_proj(y.reshape(b * t, -1)), pages

@@ -12,11 +12,12 @@ few however long it is: its *state*, a row ``[L - 1, width]`` of the
 layer's state array at the sequence's seat
 (:mod:`raytpu.inference.kv_cache`), and no keys or values.
 
-The module has the three walks the attention modules have, over one
-parameter set (``in_proj``, ``kernel`` [L, width], ``out_proj``): a whole
-prompt, a chunk that reads the state the chunk before it left, and a
-decode row a sequence. Each is given the state array and the seats and
-returns the array written; the state left is that after the last *live*
+The module has the walks the attention modules have, over one parameter
+set (``in_proj``, ``kernel`` [L, width], ``out_proj``): the training
+forward, a whole prompt, and a ``step`` of ``[B, T]`` rows that reads the
+state the rows before them left (a prompt's chunk, a decode row a
+sequence). The last two are given the state array and the seats and
+return the array written; the state left is that after the last *live*
 row, and a padding row's seat is 0, the scratch row. The products run in
 ``config.dtype``, the convolution's sum in float32. Under
 ``jax.named_scope("conv.in_proj" | "conv.mix" | "conv.out_proj")``.
@@ -78,35 +79,30 @@ class ShortConv(nn.Module):
                 v.dtype), v)
         return self._out(gate, mixed)
 
-    def prefill_chunk(self, x, state, seats, live, first):
-        """``x`` [1, T, E]: a prompt's chunk behind the state at ``seats``
-        [1], or behind zeros where the chunk holds position 0 (``first``:
-        what the seat holds is then another sequence's); ``live`` [1, T]
-        marks the rows that are tokens, the first so many. Returns ``(out
-        [1, T, E], state)`` with the state after the last live row
-        written at the seat: a chunk of fewer live rows than the state
-        has carries the newest of the old ones on."""
+    def step(self, x, state, seats, live, first):
+        """``x`` [B * T, E] (or [B, T, E]): ``T`` consecutive rows a
+        sequence (a prompt's chunk at ``B = 1``, a decode row at ``T =
+        1``) behind the state at its seat (``seats`` [B]; padding rows
+        name seat 0), or behind zeros where the sequence's rows start at
+        position 0 (``first`` [B], or one bool for all: what the seat
+        holds is then another sequence's); ``live`` [B, T] marks the rows
+        that are tokens, of each sequence the first so many. Returns
+        ``(out, state)``, ``out`` as ``x`` is shaped, with the state after
+        each sequence's last live row written at its seat: fewer live
+        rows than the state has carry the newest of the old ones on."""
         v, gate = self._gates(x)
         with jax.named_scope("conv.mix"):
-            before = jnp.where(first, 0, state[seats])
-            mixed, rows = self._mix(before, v)
-            after = jax.lax.dynamic_slice_in_dim(
-                rows, jnp.sum(live, dtype=jnp.int32), before.shape[-2],
-                axis=-2)
+            before = jnp.where(jnp.reshape(first, (-1, 1, 1)), 0,
+                               state[seats])
+            mixed, rows = self._mix(before, v.reshape(*live.shape, -1))
+            after = jax.vmap(functools.partial(
+                jax.lax.dynamic_slice_in_dim,
+                slice_size=before.shape[-2]))(
+                    rows, jnp.sum(live, axis=-1, dtype=jnp.int32))
             state = state.at[seats].set(after.astype(state.dtype))
-        return self._out(gate, mixed), state
+        return self._out(gate, mixed.reshape(gate.shape)), state
 
     def prefill(self, x, state, seats, live):
-        """A whole prompt, ``x`` [1, T, E] from position 0: a chunk that
-        holds position 0."""
-        return self.prefill_chunk(x, state, seats, live, True)
-
-    def decode_step(self, x, state, seats):
-        """``x`` [B, E], one row a sequence behind the state at its seat
-        (``seats`` [B]; padding rows name seat 0) -> ``(out [B, E],
-        state)``."""
-        v, gate = self._gates(x)
-        with jax.named_scope("conv.mix"):
-            mixed, rows = self._mix(state[seats], v[:, None])
-            state = state.at[seats].set(rows[:, 1:].astype(state.dtype))
-        return self._out(gate, mixed[:, 0]), state
+        """A whole prompt, ``x`` [1, T, E] from position 0: a step whose
+        rows start there."""
+        return self.step(x, state, seats, live, True)

@@ -54,7 +54,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
@@ -63,21 +62,10 @@ from jax import lax
 from jax.sharding import PartitionSpec
 
 
-def _env_block(name: str) -> Optional[int]:
-    """A tile's rows from the environment, or None: malformed, empty and
-    non-positive values fall back silently (a bad env var must not break
-    every import of raytpu.ops)."""
-    try:
-        v = int(os.environ.get(name) or 0)
-    except ValueError:
-        return None
-    return v if v > 0 else None
-
-
-# Rows of q and of k in a tile of scores where the environment (or a test,
-# or benchmarks/sweep_attn.py) overrides the table below; unset, the table.
-DEFAULT_BLOCK_Q = _env_block("RAYTPU_FLASH_BLOCK_Q")
-DEFAULT_BLOCK_K = _env_block("RAYTPU_FLASH_BLOCK_K")
+# Rows of q and of k in a tile of scores where a test or
+# benchmarks/sweep_attn.py overrides the table below; None, the table.
+DEFAULT_BLOCK_Q: Optional[int] = None
+DEFAULT_BLOCK_K: Optional[int] = None
 
 # kernel: (rows of q, rows of k, rows of keys in a chunk on the diagonal),
 # as the chip chose them at [256, 1024, 64], [100, 1024, 64] and the
@@ -98,29 +86,6 @@ def _tile(kernel: str, block_q=None, block_k=None):
     lengths and the head size are no arguments: `_TILES` has one row."""
     want_q, want_k, chunk = _TILES[kernel]
     return block_q or want_q, block_k or want_k, chunk
-
-
-def _env_dot_mode() -> str:
-    """"input" | "f32", with synonyms; unknown values warn and fall back
-    (a bad env var must not break every import of raytpu.ops)."""
-    raw = (os.environ.get("RAYTPU_FLASH_DOT") or "input").lower()
-    mode = {"input": "input", "bf16": "input",
-            "f32": "f32", "fp32": "f32", "float32": "f32"}.get(raw)
-    if mode is None:
-        import warnings
-        warnings.warn(f"RAYTPU_FLASH_DOT={raw!r} not recognized "
-                      f"(use 'input' or 'f32'); using 'input'",
-                      RuntimeWarning, stacklevel=2)
-        mode = "input"
-    return mode
-
-
-# MXU operand dtype inside the kernels (RAYTPU_FLASH_DOT). "input" feeds
-# q, k, v (and p and ds, cast back down) to the MXU as they come, with
-# float32 accumulation: what every cell runs and every measurement in
-# PERF.md is of. "f32" upcasts every operand first (several passes of the
-# MXU a product); no chip run has timed it since the tile was turned over.
-DEFAULT_DOT_MODE = _env_dot_mode()
 
 
 def _fit_block(t: int, want: int, interpret: bool) -> int:
@@ -148,8 +113,8 @@ def _fit_block(t: int, want: int, interpret: bool) -> int:
     if not ok(want) or t % want:
         raise ValueError(
             f"no sublane-aligned pallas block (>= {floor}, %8 == 0) tiles "
-            f"sequence length {t}; use force='reference', pad the "
-            f"sequence, or raise RAYTPU_FLASH_BLOCK_Q/K")
+            f"sequence length {t}; use force='reference' or pad the "
+            f"sequence")
     return want
 
 
@@ -441,7 +406,7 @@ def _walk_keys(tile, q_start, block_q: int, sub: int, n_sub: int, first,
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                   *, causal: bool, sm_scale: float, scale_q: bool, sub: int,
-                  chunk: int, off: int, steps, dot_mode: str, window=None):
+                  chunk: int, off: int, steps, window=None):
     """``steps``: the grid's steps along its last two axes (blocks of
     queries, major blocks of keys), in all three kernels."""
     block_q, d = q_ref.shape[1:]
@@ -455,9 +420,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros((1, block_q), jnp.float32)
         acc_scr[...] = jnp.zeros((d, block_q), jnp.float32)
 
-    # "input" mode feeds the MXU in the residual dtype (bf16 in, fp32
-    # accumulate); "f32" upcasts operands first.
-    mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
+    # The MXU is fed in the residual dtype (bf16 in, fp32 accumulate).
+    mxu = q_ref.dtype
     q = q_ref[0].astype(mxu)  # [Bq, D]
     if scale_q:
         q = q * sm_scale
@@ -557,8 +521,7 @@ def _flash_forward_pallas(q, k, v, causal: bool, sm_scale: float,
             scale_q=_scale_on_operand(sm_scale), sub=sub,
             chunk=_diagonal_chunk(chunk, block_q, sub, off, n_major == 1,
                                   causal, window),
-            off=off, steps=(n_qb, n_major), dot_mode=DEFAULT_DOT_MODE,
-            window=window),
+            off=off, steps=(n_qb, n_major), window=window),
         grid=(bh, n_qb, n_major),
         in_specs=[
             pl.BlockSpec((1, block_q, d), held),
@@ -597,7 +560,7 @@ def _flash_forward_pallas(q, k, v, causal: bool, sm_scale: float,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, causal: bool, sm_scale: float,
                          scale_q: bool, sub: int, chunk: int, off: int,
-                         steps, dot_mode: str):
+                         steps):
     block_q, d = q_ref.shape[1:]
     n_sub = k_ref.shape[1] // sub
     iq = _grid_index(1, steps[0])
@@ -607,7 +570,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[...] = jnp.zeros((d, block_q), jnp.float32)
 
-    mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
+    mxu = q_ref.dtype
     q = q_ref[0].astype(mxu)
     if scale_q:
         q = q * sm_scale
@@ -639,7 +602,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                           sm_scale: float, scale_k: bool, sub: int, chunk: int,
-                          off: int, steps, dot_mode: str):
+                          off: int, steps):
     block_k, d = k_ref.shape[1:]
     n_sub = q_ref.shape[1] // sub
     ik = _grid_index(1, steps[0])
@@ -650,7 +613,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros((block_k, d), jnp.float32)
         dv_scr[...] = jnp.zeros((block_k, d), jnp.float32)
 
-    mxu = jnp.float32 if dot_mode == "f32" else q_ref.dtype
+    mxu = q_ref.dtype
     kb = k_ref[0].astype(mxu)  # [Bk, D]
     if scale_k:
         kb = kb * sm_scale
@@ -734,8 +697,7 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
             scale_q=scale_on_operand, sub=sub,
             chunk=_diagonal_chunk(chunk, held_q, sub, off, t_kv == major,
                                   causal),
-            off=off, steps=(n_held, t_kv // major),
-            dot_mode=DEFAULT_DOT_MODE),
+            off=off, steps=(n_held, t_kv // major)),
         grid=(bh, n_held, t_kv // major),
         in_specs=[held_spec, walked_spec, walked_spec, held_spec,
                   stat_spec, stat_spec],
@@ -772,8 +734,7 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
             scale_k=scale_on_operand, sub=sub,
             chunk=_diagonal_chunk(chunk, held_k, sub, off, n_major == 1,
                                   causal),
-            off=off, steps=(t_kv // held_k, n_major),
-            dot_mode=DEFAULT_DOT_MODE),
+            off=off, steps=(t_kv // held_k, n_major)),
         grid=(bh, t_kv // held_k, n_major),
         in_specs=[walked_spec, held_spec, held_spec, walked_spec,
                   stat_spec, stat_spec],

@@ -38,7 +38,7 @@ same kernel with ``block_q`` tokens a step.
 ``mla_paged_attention_reference`` is the dense float32 form over the
 gathered pages: the numerics ground truth, and the CPU default.
 Implementation choice is :func:`raytpu.ops.paged_attention.
-resolve_paged_impl`'s, by the same ``force`` / ``RAYTPU_PAGED_ATTN``.
+resolve_paged_impl`'s, by the same ``force``.
 """
 
 from __future__ import annotations

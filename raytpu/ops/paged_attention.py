@@ -60,27 +60,17 @@ gather is allowed — lint rule RTP011 bans it from models/ and
 inference/), an ``interpret=True`` path so CPU tier-1 tests execute the
 real kernel, and a ``force=`` override.
 
-Implementation selection (``resolve_paged_impl``):
+Implementation selection (``resolve_paged_impl``), from the platform and
+the model config's ``paged_attn`` field and nothing else:
 
-- ``RAYTPU_PAGED_ATTN`` unset / ``auto``: kernel on TPU, reference on
-  CPU (default CPU behavior unchanged).
-- ``1`` / ``on`` / ``true``: kernel on TPU, *interpret-mode kernel* on
-  CPU — tests toggle this to execute the real kernel.
-- ``0`` / ``off`` / ``false`` / ``reference``: dense reference.
-- model configs override the env via their ``paged_attn`` field
-  (``kernel`` / ``interpret`` / ``reference`` / ``auto`` / ``on``).
-
-Env knobs (see ``raytpu.core.config.describe_env``):
-
-- ``RAYTPU_PAGED_ATTN``: implementation toggle, above.
-- ``RAYTPU_PAGED_BLOCK_Q``: query-token block (default 256; decode uses
-  T=1 so this only matters for chunked prefill).
+- ``None`` / ``auto``: kernel on TPU, reference elsewhere.
+- ``kernel`` (``tpu``), ``interpret``, ``reference``: that one — tests
+  and the benchmark's plain references pin it.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 import warnings
 
 import jax
@@ -104,55 +94,31 @@ __all__ = [
 ]
 
 
-def _env_block(name: str, default: int) -> int:
-    raw = os.environ.get(name)
-    if not raw:
-        return default
-    try:
-        val = int(raw)
-    except ValueError:
-        warnings.warn(
-            f"{name}={raw!r} is not an int; using {default}",
-            RuntimeWarning, stacklevel=2)
-        return default
-    return max(1, val)
+# Query tokens of one grid step (a decode step has one or two a
+# sequence, so this only matters for a prompt's chunk).
+BLOCK_Q = 256
 
-
-_VALID_PAGED = {
-    "auto": "auto", "": "auto",
-    "1": "on", "on": "on", "true": "on", "yes": "on",
-    "0": "reference", "off": "reference", "false": "reference",
-    "no": "reference", "reference": "reference",
-    "kernel": "tpu", "tpu": "tpu",
-    "interpret": "interpret",
-}
+_VALID_PAGED = {"auto": "auto", "kernel": "tpu", "tpu": "tpu",
+                "interpret": "interpret", "reference": "reference"}
 
 
 def resolve_paged_impl(selector=None) -> str:
     """Resolve the paged-attention implementation to run.
 
-    ``selector`` is the model config's ``paged_attn`` field; ``None``
-    defers to the ``RAYTPU_PAGED_ATTN`` env toggle.  Returns one of
-    ``"tpu"`` / ``"interpret"`` / ``"reference"``.
+    ``selector`` is the model config's ``paged_attn`` field; ``None`` is
+    ``"auto"``, the kernel on a TPU and the reference elsewhere. Returns
+    one of ``"tpu"`` / ``"interpret"`` / ``"reference"``.
     """
-    source = "config paged_attn"
-    if selector is None:
-        selector = os.environ.get("RAYTPU_PAGED_ATTN", "auto")
-        source = "RAYTPU_PAGED_ATTN"
-    raw = str(selector).strip().lower()
+    raw = "auto" if selector is None else str(selector).strip().lower()
     mode = _VALID_PAGED.get(raw)
     if mode is None:
         warnings.warn(
-            f"{source}={raw!r} not recognized (use 'auto', 'on', 'off', "
+            f"config paged_attn={raw!r} not recognized (use 'auto', "
             f"'kernel', 'interpret', or 'reference'); using 'auto'",
             RuntimeWarning, stacklevel=2)
         mode = "auto"
     if mode == "auto":
         return "tpu" if _on_tpu() else "reference"
-    if mode == "on":
-        # Toggled on: run the real kernel even without hardware, via
-        # the Pallas interpreter, so CPU tests cover the kernel path.
-        return "tpu" if _on_tpu() else "interpret"
     return mode
 
 
@@ -470,7 +436,7 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, positions,
             f"{d} that divide the {h} query heads")
     rep = h // kv
     n_pg = block_tables.shape[1]
-    bq_t = _fit_q_block(t, _env_block("RAYTPU_PAGED_BLOCK_Q", 256))
+    bq_t = _fit_q_block(t, BLOCK_Q)
     n_qb = t // bq_t
     g = _kv_heads_per_block(kv, d, bq_t * rep)
     n_grp = kv // g
@@ -568,7 +534,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions, *,
         token at position p attends slots 0..p.
       sm_scale: softmax scale (default ``head_dim ** -0.5``).
       force: implementation selector (the model config's ``paged_attn``
-        field); ``None`` defers to ``RAYTPU_PAGED_ATTN``.
+        field; :func:`resolve_paged_impl`).
       window: a window layer's width in tokens: a token at position p
         attends slots ``p - window < l <= p`` (``window`` of them, its
         own among them). The table's columns left of the page that holds

@@ -90,9 +90,12 @@ def check_engine_programs(eng, prompt_a, prompt_b, chunk: int = 8):
         return lambda params, ks, vs, *inputs: fwd(
             cfg, params, *inputs, ks, vs)[:3]
 
+    from raytpu.inference.engine import _chunk_of, _decode_of
+
     served = cfg.serving
     prefill_4d, chunk_4d, decode_4d = map(
-        on_4d, (served.prefill, served.prefill_chunk, served.decode))
+        on_4d, (served.prefill, _chunk_of(served.step),
+                _decode_of(served.step)))
 
     done = []
 

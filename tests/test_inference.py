@@ -1116,8 +1116,8 @@ class TestEngineGPT2:
             return lambda *a: traced.append(name) or fwd(*a)
 
         cfg = self._stranger(serving=property(lambda c: gpt2_mod.Serving(
-            said("gpt2_prefill"), said("gpt2_prefill_chunk"),
-            said("gpt2_decode"), gpt2_mod.serving_params,
+            said("gpt2_prefill"), said("gpt2_step"),
+            gpt2_mod.serving_params,
             kv_heads=c.n_head, head_dim=c.n_embd // c.n_head)))
         eng = InferenceEngine(cfg, params, page_size=8, max_num_seqs=4,
                               max_model_len=64, prefill_chunk=8)
@@ -1125,8 +1125,7 @@ class TestEngineGPT2:
         outs = eng.generate([pa, pb], SamplingParams(max_new_tokens=6))
         assert outs[0] == reference_greedy(model, params, pa, 6)
         assert outs[1] == reference_greedy(model, params, pb, 6)
-        assert {"gpt2_prefill", "gpt2_prefill_chunk", "gpt2_decode"} \
-            == set(traced)
+        assert {"gpt2_prefill", "gpt2_step"} == set(traced)
         # Once a bucket; the sampler over their logits is the engine's.
         assert len(traced) == eng._programs_traced() - sum(
             eng.stats()["sample_compiles"].values())
@@ -1211,11 +1210,12 @@ class TestServingParams:
         return {
             "prefill": lambda p: getattr(mod, f"{prefix}_prefill")(
                 cfg, p, tokens, 8 + jnp.arange(16), *pools),
-            "chunk": lambda p: getattr(mod, f"{prefix}_prefill_chunk")(
-                cfg, p, tokens[:, :4], positions, dests, table, *pools),
-            "decode": lambda p: getattr(mod, f"{prefix}_decode")(
-                cfg, p, tokens[0, :4], positions, dests,
-                jnp.tile(table, (4, 1)), positions + 1, *pools),
+            "chunk": lambda p: getattr(mod, f"{prefix}_step")(
+                cfg, p, tokens[:, :4], positions[None], dests[None], table,
+                *pools),
+            "decode": lambda p: getattr(mod, f"{prefix}_step")(
+                cfg, p, tokens[0, :4, None], positions[:, None],
+                dests[:, None], jnp.tile(table, (4, 1)), *pools),
         }
 
     @pytest.mark.parametrize("program", ["prefill", "chunk", "decode"])

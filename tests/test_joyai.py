@@ -272,8 +272,8 @@ def test_interleaved_rope_scores_are_the_pairwise_rotations():
 
 def test_absorbed_attention_is_the_expanded(params):
     """One layer's ``prefill`` (expanded, flash reference) against
-    ``prefill_chunk`` and ``decode_step`` (absorbed, through the latent
-    pages) on the same rows, float32."""
+    ``step`` at a chunk's shape and at a decode row's (absorbed, through
+    the latent pages) on the same rows, float32."""
     attn, lp = LatentAttention(TINY), params["layers_1"]["attn"]
     rng = np.random.default_rng(2)
     x = jnp.asarray(rng.standard_normal((1, 21, 64)), jnp.float32)
@@ -286,13 +286,13 @@ def test_absorbed_attention_is_the_expanded(params):
     slots = lambda pos: np.asarray(table)[0][pos // 8] * 8 + pos % 8  # noqa
     pos = np.arange(16)
     got, pages = attn.apply(
-        {"params": lp}, x[:, :16], pages, jnp.asarray(slots(pos)), table,
-        jnp.asarray(pos), method="prefill_chunk")
-    np.testing.assert_allclose(got, want[:, :16], atol=2e-5)
+        {"params": lp}, x[0, :16], pages, jnp.asarray(slots(pos))[None],
+        table, jnp.asarray(pos)[None], method="step")
+    np.testing.assert_allclose(got, want[0, :16], atol=2e-5)
     for p in range(16, 21):
         got, pages = attn.apply(
-            {"params": lp}, x[:, p], pages, jnp.asarray([slots(p)]), table,
-            jnp.asarray([p]), jnp.asarray([p + 1]), method="decode_step")
+            {"params": lp}, x[:, p], pages, jnp.asarray([[slots(p)]]),
+            table, jnp.asarray([[p]]), method="step")
         np.testing.assert_allclose(got, want[:, p], atol=2e-5)
     # The pool holds what prefill returned, a row a token, once.
     held = np.asarray(pages).reshape(64, width)[slots(np.arange(21))]

@@ -14,6 +14,7 @@ to rounding, 1e-4 of the largest reference logit.
 import dataclasses
 import math
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -276,7 +277,9 @@ def test_windowed_paged_attention(paged_case, window, impl, monkeypatch):
         < 2e-6
     # A prompt's chunk: 16 rows of sequence 0 from position 20, in two
     # query blocks.
-    monkeypatch.setenv("RAYTPU_PAGED_BLOCK_Q", "8")
+    # (The module itself: raytpu.ops exports a function of its name.)
+    monkeypatch.setattr(sys.modules[paged_attention.__module__],
+                        "BLOCK_Q", 8)
     rows = np.arange(20, 36, dtype=np.int32)[None]
     got = paged_attention(
         c["q"][:1, 20:36], c["pool_k"], c["pool_v"],

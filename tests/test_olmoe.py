@@ -472,9 +472,8 @@ def test_routed_configs_are_served_by_llamas_description():
     for c in (TINY, MixtralConfig.tiny()):
         assert "serving" not in vars(type(c))
         served = c.serving
-        assert (served.prefill, served.prefill_chunk, served.decode,
-                served.params) == (dense.prefill, dense.prefill_chunk,
-                                   dense.decode, dense.params)
+        assert (served.prefill, served.step, served.params) \
+            == (dense.prefill, dense.step, dense.params)
         assert (served.kv_heads, served.head_dim) == (c.n_kv_head, c.head_dim)
         assert served.expert_counts == (c.n_layer, c.n_expert)
     _, params = model_and_params(TINY)

@@ -392,50 +392,20 @@ class TestFlatPoolsAgainstThe4DPools:
 
 
 class TestImplResolution:
-    def test_env_toggle(self, monkeypatch):
-        # CPU: auto -> reference; on -> interpret (real kernel in
-        # tests); off -> reference.
-        monkeypatch.delenv("RAYTPU_PAGED_ATTN", raising=False)
-        assert resolve_paged_impl() == "reference"
-        for raw in ("1", "on", "true"):
-            monkeypatch.setenv("RAYTPU_PAGED_ATTN", raw)
-            assert resolve_paged_impl() == "interpret"
-        for raw in ("0", "off", "reference"):
-            monkeypatch.setenv("RAYTPU_PAGED_ATTN", raw)
-            assert resolve_paged_impl() == "reference"
-
-    def test_config_overrides_env(self, monkeypatch):
-        monkeypatch.setenv("RAYTPU_PAGED_ATTN", "off")
-        assert resolve_paged_impl("interpret") == "interpret"
-        assert resolve_paged_impl("reference") == "reference"
-
-    def test_bad_env_value_warns_and_falls_back(self, monkeypatch):
-        monkeypatch.setenv("RAYTPU_PAGED_ATTN", "bogus")
-        with pytest.warns(RuntimeWarning, match="RAYTPU_PAGED_ATTN"):
-            assert resolve_paged_impl() == "reference"  # auto on CPU
+    @pytest.mark.parametrize("selector, impl", [
+        (None, "reference"), ("auto", "reference"), ("kernel", "tpu"),
+        ("tpu", "tpu"), ("interpret", "interpret"),
+        ("reference", "reference")])
+    def test_selector_off_a_tpu(self, selector, impl):
+        # The config's field and the platform, nothing else: auto is the
+        # reference here, a pinned value itself; none of them warns.
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            assert resolve_paged_impl(selector) == impl
 
     def test_bad_config_value_warns(self):
         with pytest.warns(RuntimeWarning, match="paged_attn"):
-            resolve_paged_impl("not-an-impl")
-
-    def test_bad_flash_dot_env_warns(self, monkeypatch):
-        # Satellite: ops/flash_attention's bad-env report goes through
-        # warnings, not a bare print.
-        from raytpu.ops.flash_attention import _env_dot_mode
-
-        monkeypatch.setenv("RAYTPU_FLASH_DOT", "bogus")
-        with pytest.warns(RuntimeWarning, match="RAYTPU_FLASH_DOT"):
-            assert _env_dot_mode() == "input"
-
-    def test_good_values_do_not_warn(self, monkeypatch):
-        from raytpu.ops.flash_attention import _env_dot_mode
-
-        monkeypatch.setenv("RAYTPU_FLASH_DOT", "f32")
-        monkeypatch.setenv("RAYTPU_PAGED_ATTN", "on")
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            assert _env_dot_mode() == "f32"
-            assert resolve_paged_impl() == "interpret"
+            assert resolve_paged_impl("not-an-impl") == "reference"
 
 
 def _kernel_cfg(cfg):
@@ -444,6 +414,13 @@ def _kernel_cfg(cfg):
 
 def _ref_cfg(cfg):
     return dataclasses.replace(cfg, paged_attn="reference")
+
+
+def _stats(eng):
+    """``eng.stats()`` and, of each decode step still on record, the block
+    table's columns it was handed (what the reference path gathers)."""
+    return eng.stats() | {
+        "table_widths": eng.recorder.values("table_width")}
 
 
 class TestEngineTokenIdentity:
@@ -456,7 +433,7 @@ class TestEngineTokenIdentity:
     def _generate(self, cfg, params, prompts, **eng_kw):
         eng = InferenceEngine(cfg, params, **eng_kw)
         outs = eng.generate(prompts, SamplingParams(max_new_tokens=8))
-        return outs, eng.stats()
+        return outs, _stats(eng)
 
     def _staggered(self, cfg, params, prompts, **eng_kw):
         """Staggered arrivals: the decode batch grows/shrinks, walking
@@ -473,7 +450,7 @@ class TestEngineTokenIdentity:
             for o in eng.step():
                 results[int(o.request_id[1:])].append(o.token_id)
             it += 1
-        return [results[i] for i in range(len(prompts))], eng.stats()
+        return [results[i] for i in range(len(prompts))], _stats(eng)
 
     def test_llama_kernel_matches_reference_across_buckets(
             self, llama_params):
@@ -487,18 +464,20 @@ class TestEngineTokenIdentity:
         assert len(sker["decode_compiles"]) >= 2
         assert sref["paged_attn_impl"] == "reference"
         assert sker["paged_attn_impl"] == "interpret"
-        # Kernel path never materializes a gather.
-        assert sref["gathered_pages"] > 0
-        assert sker["gathered_pages"] == 0
+        # Both were handed the same tables, trimmed under the 8 columns
+        # a sequence may have.
+        assert sref["table_widths"] == sker["table_widths"]
+        assert 0 < max(sker["table_widths"]) < 8
 
     def test_gpt2_kernel_matches_reference(self, gpt2_params):
         kw = dict(page_size=8, max_num_seqs=4, max_model_len=64)
-        ref, _ = self._generate(_ref_cfg(GCFG), gpt2_params,
-                                self.PROMPTS, **kw)
+        ref, sref = self._generate(_ref_cfg(GCFG), gpt2_params,
+                                   self.PROMPTS, **kw)
         ker, sker = self._generate(_kernel_cfg(GCFG), gpt2_params,
                                    self.PROMPTS, **kw)
         assert ref == ker
-        assert sker["gathered_pages"] == 0
+        assert sker["paged_attn_impl"] == "interpret"
+        assert sref["table_widths"] == sker["table_widths"]
 
     def test_prefix_cache_hit_identical(self, llama_params):
         # Shared 16-token system prefix: the second/third request hit
@@ -520,7 +499,7 @@ class TestEngineTokenIdentity:
                     for o in eng.step():
                         toks.append(o.token_id)
                 results[i] = toks
-            return results, eng.stats()
+            return results, _stats(eng)
 
         ref, sref = collect(_ref_cfg(LCFG))
         ker, sker = collect(_kernel_cfg(LCFG))
@@ -530,7 +509,7 @@ class TestEngineTokenIdentity:
         # The prefix-hit tails ran the chunk path in both impls.
         assert sref["chunk_prefill_compiles"]
         assert sker["chunk_prefill_compiles"]
-        assert sker["gathered_pages"] == 0
+        assert sref["table_widths"] == sker["table_widths"]
 
     def test_preemption_resume_identical(self, llama_params):
         # 5 usable pages of 4 tokens force preempt-to-recompute; the
@@ -582,12 +561,13 @@ class TestCompileOnceAndTrim:
         assert eng.max_pages_per_seq == 24
         eng.generate([[1, 2, 3], [5, 6, 7, 8]],
                      SamplingParams(max_new_tokens=6))
-        stats = eng.stats()
-        decode_steps = len(stats["decode_batch_hist"])
-        untrimmed = decode_steps * 2 * eng.max_pages_per_seq
-        assert 0 < stats["gathered_pages"] < untrimmed / 2, (
-            f"{stats['gathered_pages']} columns gathered; untrimmed "
-            f"would be ~{untrimmed}")
+        widths = eng.recorder.values("table_width")
+        untrimmed = len(widths) * 2 * eng.max_pages_per_seq
+        gathered = sum(rows * width for rows, width in zip(
+            eng.recorder.values("bucket"), widths, strict=True))
+        assert 0 < gathered < untrimmed / 2, (
+            f"{gathered} columns gathered; untrimmed would be "
+            f"~{untrimmed}")
 
     def test_trim_never_drops_live_pages(self, llama_params):
         # A sequence that grows past a page-bucket boundary mid-decode
