@@ -129,6 +129,12 @@ _drafted_total = Counter("raytpu_infer_drafted_tokens_total",
                          "Drafted tokens a decode step verified")
 _draft_accepted_total = Counter("raytpu_infer_draft_accepted_total",
                                 "Drafted tokens the verification kept")
+_moe_pairs_total = Counter(
+    "raytpu_infer_moe_pairs_total",
+    "Live (token, choice) pairs routed by a router with identity experts")
+_moe_zero_pairs_total = Counter(
+    "raytpu_infer_moe_zero_pairs_total",
+    "Of those pairs, the ones that chose an identity expert")
 _ttft_hist = Histogram(
     "raytpu_infer_ttft_seconds",
     "Time from request admission to its first sampled token",
@@ -310,6 +316,10 @@ class InferenceEngine:
             self._expert_tokens = np.zeros(
                 (layers + (self._drafting.pools if self._drafting else 0),
                  experts), np.int64)
+        # Of a router with identity experts (``serving.expert_pairs``):
+        # the live (token, choice) pairs that chose one, and all of them.
+        self._moe_pairs = ({"moe_zero_pairs": 0, "moe_pairs": 0}
+                           if served.expert_pairs else None)
 
         self._config = model_config
         # The working copy, made once and before anything else takes
@@ -783,7 +793,8 @@ class InferenceEngine:
     def _count_experts(self, experts, *programs) -> None:
         """Add what the programs of a routed model returned beside their
         logits (int32 ``[layers, experts]``: live tokens each expert held
-        here received; a verify step's model and module each give their
+        here received, two columns more under ``serving.expert_pairs``;
+        a verify step's model and module each give their
         layers') to the running total and to the open step's
         record, and what was noted of ``programs`` (name and bucket
         key) when they were traced: how many of their expert products go
@@ -793,8 +804,18 @@ class InferenceEngine:
             return
         counts = np.concatenate([np.atleast_2d(np.asarray(count))
                                  for count in experts])
-        self._expert_tokens += counts
         fields = self.recorder.open.fields
+        if self._moe_pairs is not None:
+            # A row's last two values are the layer's pairs, not tokens.
+            zero, pairs = counts[:, -2:].sum(axis=0).tolist()
+            counts = counts[:, :-2]
+            for name, total, more in (
+                    ("moe_zero_pairs", _moe_zero_pairs_total, zero),
+                    ("moe_pairs", _moe_pairs_total, pairs)):
+                self._moe_pairs[name] += more
+                fields[name] = fields.get(name, 0) + more
+                total.inc(more)
+        self._expert_tokens += counts
         fields["moe_assignments"] = (fields.get("moe_assignments", 0)
                                      + int(counts.sum()))
         fields["moe_experts_touched"] = (
@@ -1331,7 +1352,11 @@ class InferenceEngine:
         ``moe_assignments`` ((token, expert) pairs computed),
         ``moe_experts_touched`` (experts that received a token, summed
         over layers and programs) and ``moe_expert_max`` (the most
-        tokens one expert of one layer received in one program) and
+        tokens one expert of one layer received in one program), where
+        the router has identity experts ``moe_zero_pairs`` and
+        ``moe_pairs`` (live (token, choice) pairs that chose an identity
+        expert, which costs no product, and all live pairs, held here or
+        not) and
         ``moe_grouped_calls`` (expert products that went through
         ``ops.grouped_matmul``'s kernel and not ``ragged_dot``: two a
         routed layer of a decode program on a TPU); where
@@ -1397,6 +1422,12 @@ class InferenceEngine:
             # [layers][experts]; None for a dense family.
             "expert_tokens": (self._expert_tokens.tolist()
                               if self._expert_tokens is not None else None),
+            # Of a router with identity experts: the live (token, choice)
+            # pairs that chose one and all live pairs; None without.
+            "moe_zero_pairs": (self._moe_pairs["moe_zero_pairs"]
+                               if self._moe_pairs else None),
+            "moe_pairs": (self._moe_pairs["moe_pairs"]
+                          if self._moe_pairs else None),
             "ttft_p50_s": self.ttft_quantile(0.5),
             "ttft_p95_s": self.ttft_quantile(0.95),
             "prefix_cache": (self.prefix_cache.stats()

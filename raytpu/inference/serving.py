@@ -49,7 +49,10 @@ cache and no role) and "lfm2_moe" (gated short convolutions in three
 layers of four, which keep a state a sequence at its seat and no keys or
 values, beside full-attention layers' pools and sigmoid-routed experts;
 no prefix cache and no role: a prefix's pages say nothing of the state
-at its end). Each reaches the
+at its end) and "longcat_flash" (two latent-attention sublayers a layer,
+so two latent pools, and one routed layer beside them on a shortcut whose
+router also scores identity experts; as "joyai", the prefix cache works
+over its pages and a role is refused). Each reaches the
 engine through its config's ``serving`` and nothing else.
 """
 
@@ -318,11 +321,11 @@ class LLMDeployment:
 
     Args:
         model: "llama", "gpt2", "mixtral", "olmoe", "mellum", "joyai",
-            "exaone_moe" or "lfm2_moe".
+            "exaone_moe", "lfm2_moe" or "longcat_flash".
         model_config: the family's config (``LlamaConfig``,
             ``GPT2Config``, ``MixtralConfig``, ``OlmoeConfig``,
             ``MellumConfig``, ``JoyAIConfig``, ``ExaoneMoeConfig``,
-            ``Lfm2MoeConfig``) or a
+            ``Lfm2MoeConfig``, ``LongcatFlashConfig``) or a
             kwargs dict for one. Defaults to the family's ``tiny()``
             config in fp32/reference-attention mode (CPU-runnable).
         engine_options: kwargs forwarded to :class:`InferenceEngine`
@@ -339,9 +342,10 @@ class LLMDeployment:
             ``kv_export_*`` trio (direct-instantiation tests). A model
             with window layers takes no role: what is handed off are
             prefix-cache pages, and it is served without that cache.
-            Nor does a latent-attention model ("joyai": one pool a
-            layer): the hand-off's wire segments are pages of K and of V,
-            ``kv_heads * head_dim`` wide. Nor does a model with layers
+            Nor does a latent-attention model ("joyai",
+            "longcat_flash": one pool an attention): the hand-off's wire
+            segments are pages of K and of V, ``kv_heads * head_dim``
+            wide. Nor does a model with layers
             that keep a state ("lfm2_moe"): the state at a prefix's end
             is in none of its pages, and the wire has no segment for it.
     """
@@ -362,7 +366,7 @@ class LLMDeployment:
 
             cfg_cls, model_cls, init = GPT2Config, GPT2, init_params
         elif model in ("mixtral", "olmoe", "mellum", "joyai", "exaone_moe",
-                       "lfm2_moe"):
+                       "lfm2_moe", "longcat_flash"):
             from raytpu.models import mixtral
 
             cfg_cls = {"mixtral": mixtral.MixtralConfig,
@@ -370,13 +374,14 @@ class LLMDeployment:
                        "mellum": mixtral.MellumConfig,
                        "joyai": mixtral.JoyAIConfig,
                        "exaone_moe": mixtral.ExaoneMoeConfig,
-                       "lfm2_moe": mixtral.Lfm2MoeConfig}[model]
+                       "lfm2_moe": mixtral.Lfm2MoeConfig,
+                       "longcat_flash": mixtral.LongcatFlashConfig}[model]
             model_cls, init = mixtral.Mixtral, mixtral.init_params
         else:
             raise ValueError(f"unknown model family: {model!r}; known: "
                              f"'llama', 'gpt2', 'mixtral', 'olmoe', "
                              f"'mellum', 'joyai', 'exaone_moe', "
-                             f"'lfm2_moe'")
+                             f"'lfm2_moe', 'longcat_flash'")
         if model_config is None:
             model_config = dataclasses.replace(
                 cfg_cls.tiny(), dtype=jnp.float32, attn_impl="reference",

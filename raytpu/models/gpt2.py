@@ -404,6 +404,10 @@ class Serving:
     kv_heads: int
     head_dim: int
     expert_counts: Optional[Tuple[int, int]] = None  # (layers, experts)
+    # The router scores identity experts beside the real ones: a count's
+    # rows are two values longer, the live (token, choice) pairs of the
+    # layer that chose an identity and all its live pairs.
+    expert_pairs: bool = False
     layer_windows: Tuple[Optional[int], ...] = ()
     kv_row: Optional[int] = None
     layer_states: Tuple[Optional[Tuple[int, ...]], ...] = ()

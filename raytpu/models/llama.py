@@ -180,16 +180,32 @@ class LlamaConfig:
         SwiGLU, or ``None`` where it is a routed-expert layer."""
         return self.n_inter
 
+    def shortcut_to(self, i: int) -> Optional[int]:
+        """``None``, or the layer at whose end a routed-expert layer is
+        added that stands *beside* layer ``i``'s feed-forward: it reads
+        the same normed stream, and what lies between (the feed-forward,
+        the layers up to that one) does not wait for it: a shortcut
+        (:class:`raytpu.models.mixtral.LongcatFlashConfig`)."""
+        return None
+
+    def layer_scope(self, i: int) -> Optional[str]:
+        """The ``jax.named_scope`` the serving walk runs all of layer
+        ``i`` under; ``None``: none."""
+        return None
+
     @property
     def serving(self) -> Serving:
         """How ``InferenceEngine`` serves this family; a routed config
         (``MixtralConfig`` and what extends it) through the same walk."""
-        routed = sum(self.ffn_width(i) is None for i in range(self.n_layer))
+        routed = sum(self.ffn_width(i) is None
+                     or self.shortcut_to(i) is not None
+                     for i in range(self.n_layer))
         kinds = self.layer_types or ()
         return Serving(
             llama_prefill, llama_step, serving_params,
             kv_heads=self.n_kv_head, head_dim=self.head_dim,
             expert_counts=(routed, self.n_expert_held) if routed else None,
+            expert_pairs=bool(routed and self.n_zero_expert),
             layer_windows=tuple(
                 self.window if kind == WINDOW else None
                 for kind in kinds if kind != CONV),
@@ -207,8 +223,13 @@ class LlamaConfig:
 
 
 class RMSNorm(nn.Module):
+    """``gain``: a constant the normed values are multiplied by beside
+    the learned ``scale``, in float32 before the cast (a latent
+    attention's scale corrections, :mod:`raytpu.models.mla`)."""
+
     dtype: Any = jnp.bfloat16
     eps: float = 1e-5
+    gain: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -216,7 +237,10 @@ class RMSNorm(nn.Module):
         xf = x.astype(jnp.float32)
         normed = xf * jax.lax.rsqrt(
             jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps)
-        return (normed * scale).astype(self.dtype)
+        normed = normed * scale
+        if self.gain != 1.0:
+            normed = normed * self.gain
+        return normed.astype(self.dtype)
 
 
 def yarn_frequencies(head_dim: int, rope: Rope):
@@ -565,18 +589,26 @@ def op_name(kind: str) -> str:
     return "conv" if kind == CONV else "attn"
 
 
+def _routed(c: LlamaConfig, lp, h, live):
+    """A block's routed-expert layer on the normed ``h``: its output and
+    its count, the tokens each expert held here received."""
+    return c.routed().apply({"params": lp["moe"]}, h, live)
+
+
 def _feed_forward(c: LlamaConfig, lp, h, live, i: int):
     """The second half of block ``i`` on the normed ``h``, as the config
     says of that layer (``ffn_width``): SwiGLU, or the routed experts.
     ``live`` marks the rows that are tokens and not padding; only the
     routed layer needs it, to route padding nowhere. Returns the output
-    and the tokens each expert received (``None`` when dense)."""
+    and the routed layer's count (``None`` when dense)."""
     width = c.ffn_width(i)
     if width is None:
-        from raytpu.models import mixtral  # it imports this module
-
-        return mixtral.MoEFFN(c).apply({"params": lp["moe"]}, h, live)
+        return _routed(c, lp, h, live)
     return LlamaMLP(c, width).apply({"params": lp["mlp"]}, h), None
+
+
+def _scope(name: Optional[str]):
+    return jax.named_scope(name) if name else contextlib.nullcontext()
 
 
 def of_kind(x, kind: str):
@@ -608,7 +640,11 @@ def _serve(c: LlamaConfig, params, x, live, cache_args, whole: bool = False,
     :func:`_feed_forward`. Returns ``(fp32 logits, K list, V list)``,
     then the list of state arrays where a layer keeps one, and
     for a config with routed layers one value more, the int32 ``[routed
-    layers, experts held]`` count of tokens each expert received. Where
+    layers, experts held]`` count of tokens each expert received (two
+    columns more under ``Serving.expert_pairs``). A routed layer that
+    stands beside layer ``i``'s feed-forward (``c.shortcut_to(i)``)
+    reads the same normed stream, and its output is carried to the end
+    of the layer named and added there. Where
     the layers are of two kinds each attends under
     ``jax.named_scope("attn.full")`` or ``("attn.window")``; a latent
     layer under ``("attn.mla")``. With ``hidden`` a last value more: the
@@ -618,22 +654,29 @@ def _serve(c: LlamaConfig, params, x, live, cache_args, whole: bool = False,
     norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
     method = "prefill" if whole else "step"
     ks, vs, states, routed = [], [], [], []
+    shortcuts = {}  # a routed layer's output, by the layer it is added at
     for i in range(c.n_layer):
-        lp = layer_params(params, i)
-        h = norm.apply({"params": lp["input_norm"]}, x)
-        kind = c.layer_kind(i)
-        with (jax.named_scope(c.attn_scope(kind))
-              if c.attn_scope(kind) else contextlib.nullcontext()):
-            y, k, *v = attn[kind].apply({"params": lp[op_name(kind)]}, h,
-                                        *cache_args(i), method=method)
-        (states if kind == CONV else ks).append(k)
-        vs.extend(v)
-        x = x + y
-        h = norm.apply({"params": lp["post_attn_norm"]}, x)
-        y, counts = _feed_forward(c, lp, h, live, i)
-        if counts is not None:
-            routed.append(counts)
-        x = x + y
+        with _scope(c.layer_scope(i)):
+            lp = layer_params(params, i)
+            h = norm.apply({"params": lp["input_norm"]}, x)
+            kind = c.layer_kind(i)
+            with _scope(c.attn_scope(kind)):
+                y, k, *v = attn[kind].apply(
+                    {"params": lp[op_name(kind)]}, h, *cache_args(i),
+                    method=method)
+            (states if kind == CONV else ks).append(k)
+            vs.extend(v)
+            x = x + y
+            h = norm.apply({"params": lp["post_attn_norm"]}, x)
+            y, counts = _feed_forward(c, lp, h, live, i)
+            to = c.shortcut_to(i)
+            if to is not None:
+                shortcuts[to], counts = _routed(c, lp, h, live)
+            if counts is not None:
+                routed.append(counts)
+            x = x + y
+            if i in shortcuts:
+                x = x + shortcuts.pop(i)
     last = (x,) if hidden else ()
     x = norm.apply({"params": params["final_norm"]}, x)
     logits = _lm_logits(c, params, x)

@@ -42,6 +42,12 @@ through which the model drafts for itself when it is served
 state a sequence and no keys or values, full attention in the others,
 two leading dense layers, the sigmoid-routed layer with no shared expert,
 and a head tied to the embedding.
+:class:`LongcatFlashConfig` is LongCat-Flash-Chat's: a published layer is
+two latent-attention sublayers, each with a dense feed-forward, and one
+routed layer beside the first feed-forward whose output is added at the
+layer's end (a shortcut: ``shortcut_to``); its router scores identity
+experts beside the real ones (``n_zero_expert``), which return their
+input and cost no product.
 """
 
 from __future__ import annotations
@@ -64,6 +70,10 @@ from raytpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 @dataclasses.dataclass(frozen=True)
 class MixtralConfig(LlamaConfig):
     n_expert: int = 8
+    # Identity ("zero-computation") experts the router scores after the
+    # ``n_expert`` real ones: one that is chosen returns the token as it
+    # came, times its weight; no matrices, no row in the grouped products.
+    n_zero_expert: int = 0
     n_expert_per_tok: int = 2
     # Whether the chosen experts' weights are rescaled to sum to one.
     norm_topk_prob: bool = True
@@ -123,6 +133,11 @@ class MixtralConfig(LlamaConfig):
 
     def ffn_width(self, i: int) -> Optional[int]:
         return self.dense_inter if i < self.first_dense else None
+
+    def routed(self, **kw):
+        """The routed-expert layer: what a block builds (``name=``) and
+        what the serving walk applies."""
+        return MoEFFN(self, **kw)
 
     @classmethod
     def tiny(cls) -> "MixtralConfig":
@@ -213,7 +228,44 @@ class MellumConfig(MixtralConfig):
 
 
 @dataclasses.dataclass(frozen=True)
-class JoyAIConfig(MixtralConfig):
+class LatentMoEConfig(MixtralConfig):
+    """A routed config whose attention is latent
+    (:mod:`raytpu.models.mla`): queries through a rank of ``q_lora_rank``,
+    keys and values through a latent of ``kv_lora_rank`` and one roped
+    key of ``qk_rope_dim``, behind one pool a layer. ``head_dim`` sizes
+    nothing here. Layers are held one tree each."""
+
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    rope_interleave: bool = True
+    # The normed query latent times sqrt(n_embd / q_lora_rank), the
+    # normed key/value latent times sqrt(n_embd / kv_lora_rank).
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    scan_layers: bool = False
+
+    def attention(self, kind: str = FULL, **kw):
+        from raytpu.models.mla import LatentAttention  # it imports llama
+
+        return LatentAttention(self, **kw)
+
+    def attn_scope(self, kind: str) -> str:
+        return "attn.mla"
+
+    @property
+    def serving(self):
+        from raytpu.ops.mla_attention import latent_row_width
+
+        return dataclasses.replace(
+            super().serving,
+            kv_row=latent_row_width(self.kv_lora_rank, self.qk_rope_dim))
+
+
+@dataclasses.dataclass(frozen=True)
+class JoyAIConfig(LatentMoEConfig):
     """JoyAI-LLM-Flash (``jdopensource/JoyAI-LLM-Flash``, 48B-A2.7B) as
     published: 40 layers of latent attention (:mod:`raytpu.models.mla`:
     32 heads, queries through a rank of 1,536, keys and values through a
@@ -244,29 +296,6 @@ class JoyAIConfig(MixtralConfig):
     n_shared: int = 1
     first_dense: int = 1
     dense_inter: int = 7168
-    q_lora_rank: int = 1536
-    kv_lora_rank: int = 512
-    qk_nope_dim: int = 128
-    qk_rope_dim: int = 64
-    v_head_dim: int = 128
-    rope_interleave: bool = True
-    scan_layers: bool = False
-
-    def attention(self, kind: str = FULL, **kw):
-        from raytpu.models.mla import LatentAttention  # it imports llama
-
-        return LatentAttention(self, **kw)
-
-    def attn_scope(self, kind: str) -> str:
-        return "attn.mla"
-
-    @property
-    def serving(self):
-        from raytpu.ops.mla_attention import latent_row_width
-
-        return dataclasses.replace(
-            super().serving,
-            kv_row=latent_row_width(self.kv_lora_rank, self.qk_rope_dim))
 
     @classmethod
     def tiny(cls) -> "JoyAIConfig":
@@ -278,6 +307,83 @@ class JoyAIConfig(MixtralConfig):
                    n_expert=16, n_expert_per_tok=4, dense_inter=96,
                    q_lora_rank=48, kv_lora_rank=128, qk_nope_dim=16,
                    qk_rope_dim=8, v_head_dim=16)
+
+
+@dataclasses.dataclass(frozen=True)
+class LongcatFlashConfig(LatentMoEConfig):
+    """LongCat-Flash-Chat (``meituan-longcat/LongCat-Flash-Chat``,
+    560B-A27B; arXiv:2509.01322) as published: 28 layers over a hidden
+    size of 6,144, each **two** latent-attention sublayers (64 heads, the
+    ranks and head sizes of DeepSeek-V3, both latents scaled, interleaved
+    rope at theta 1e7) with a dense SwiGLU of 12,288 each, and **one**
+    routed layer that reads the first sublayer's normed stream beside its
+    feed-forward and is added at the layer's end, so that the second
+    sublayer does not wait for it (the shortcut, "ScMoE"): with the two
+    attentions ``A``, the four norms ``N`` and the feed-forwards ``F``,
+
+        x1 = x + A0(N(x)); h1 = N(x1); s = MoE(h1); x2 = x1 + F0(h1)
+        x3 = x2 + A1(N(x2)); out = x3 + F1(N(x3)) + s
+
+    The router is a softmax over 768 outputs, 512 routed experts of 2,048
+    (``n_inter``) and 256 identity experts (``n_zero_expert``); a token
+    takes 12 by score + a bias, weights the scores without it, not
+    renormalised, times 6; no shared expert. A token's work is between 0
+    and 12 expert products a layer, by how many of its choices are
+    identities.
+
+    **``n_layer`` counts attention sublayers**, two a published layer:
+    the serving walk, the cache and the engine see 56 layers of one
+    attention, one pool and one dense feed-forward each, of which the
+    even ones carry a routed layer's output to the end of the next
+    (``shortcut_to``). So every count of pools by layer holds as it is,
+    and the walk learned one thing, to carry a value from a layer to a
+    later one. ``head_dim`` sizes nothing here."""
+
+    vocab_size: int = 131072
+    block_size: int = 131072
+    n_layer: int = 56
+    n_head: int = 64
+    n_kv_head: int = 64
+    n_embd: int = 6144
+    n_inter: int = 2048
+    n_expert: int = 512
+    n_zero_expert: int = 256
+    n_expert_per_tok: int = 12
+    norm_topk_prob: bool = False
+    norm_eps: float = 1e-5
+    rope_theta: float = 10000000.0
+    choice_bias: float = 0.0
+    routed_scale: float = 6.0
+    dense_inter: int = 12288
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.n_layer % 2:
+            raise ValueError(f"n_layer counts sublayers, two a published "
+                             f"layer: {self.n_layer} is odd")
+
+    def ffn_width(self, i: int) -> int:
+        return self.dense_inter
+
+    def shortcut_to(self, i: int) -> Optional[int]:
+        return i + 1 if i % 2 == 0 else None
+
+    def layer_scope(self, i: int) -> str:
+        return f"sublayer.{i % 2}"
+
+    @classmethod
+    def tiny(cls) -> "LongcatFlashConfig":
+        """Two published layers (four sublayers) at toy widths: of 32
+        routed and 16 identity experts a token takes 6, so 32 shares of
+        one expert add up to the layer; the latent is 128 wide because
+        the kernel slices values out of a row by whole lane tiles."""
+        return cls(vocab_size=512, block_size=256, n_layer=4, n_head=4,
+                   n_kv_head=4, n_embd=64, n_inter=32, n_expert=32,
+                   n_zero_expert=16, n_expert_per_tok=6, dense_inter=96,
+                   q_lora_rank=48, kv_lora_rank=128, qk_nope_dim=16,
+                   qk_rope_dim=8, v_head_dim=16, choice_bias=0.01)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -405,7 +511,10 @@ class MoEFFN(nn.Module):
     rows that are tokens: padding is routed nowhere, costs no expert a
     row and is not counted. Returns ``(y, tokens)``: the layer's output
     and the int32 ``[n_expert]`` number of live tokens each expert
-    received. The router runs in float32 at full precision over all the
+    received, after them, where the router has identity experts, two
+    values more (``Serving.expert_pairs``): the live (token, choice)
+    pairs that chose one, and all live pairs. The router runs in float32
+    at full precision over all the
     experts; the expert matrices multiply in ``config.dtype`` with
     float32 sums, gate and up in one pass over the sorted rows and down
     in another (``ops.grouped_matmul``: which of its two ways, and in
@@ -423,45 +532,75 @@ class MoEFFN(nn.Module):
     the share is a dead row like padding's, ``tokens`` counts the held
     experts alone, and the output is their part of the layer's. A shared
     expert (``n_shared``) is added under ``jax.named_scope("moe.shared")``.
+
+    With ``n_zero_expert`` the router and the bias are ``n_expert +
+    n_zero_expert`` wide. A chosen index past the real experts is an
+    identity expert: a dead row for the grouped products, like another
+    chip's expert, whose weight goes to the identity term, ``(sum of the
+    token's identity weights) x`` added in float32 with the routed sum
+    under ``jax.named_scope("moe.zero")``; whole on every chip, which owns
+    its tokens, whatever share of the real experts it holds.
     """
 
     config: MixtralConfig
+
+    @nn.nowrap  # a plain function of the config: no scope of its own
+    def route(self, probs, bias):
+        """A token's choices and their weights from its scores ``probs``
+        [N, outputs] and the choice ``bias`` (``None``: the config has
+        none): the ``n_expert_per_tok`` largest of score + bias, weighed
+        by the score without it, normalised and scaled as the config
+        says -> ``(topw, topi)``, both [N, k]."""
+        c = self.config
+        if bias is not None:
+            _, topi = jax.lax.top_k(probs + bias, c.n_expert_per_tok)
+            topw = jnp.take_along_axis(probs, topi, axis=-1)
+        else:
+            topw, topi = jax.lax.top_k(probs, c.n_expert_per_tok)
+        if c.norm_topk_prob:
+            total = jnp.sum(topw, axis=-1, keepdims=True)
+            if c.topk_sum_eps:
+                total = total + c.topk_sum_eps
+            topw = topw / total
+        if c.routed_scale != 1.0:
+            topw = topw * c.routed_scale
+        return topw, topi
+
+    @nn.nowrap
+    def identity(self, xf, topw, topi):
+        """The identity experts' term, float32 [N, D]: each token times
+        the sum of its weights for the identity experts it chose."""
+        chosen = jnp.where(topi >= self.config.n_expert, topw, 0.0)
+        return xf.astype(jnp.float32) * jnp.sum(chosen, axis=-1,
+                                                keepdims=True)
 
     @nn.compact
     def __call__(self, x, live=None):
         c = self.config
         d = x.shape[-1]
         k, e = c.n_expert_per_tok, c.n_expert_held
+        scored = c.n_expert + c.n_zero_expert  # the router's outputs
         xf = x.reshape(-1, d)
         n = xf.shape[0]
         with jax.named_scope("moe.router"):
-            router = nn.Dense(c.n_expert, use_bias=False, dtype=jnp.float32,
+            router = nn.Dense(scored, use_bias=False, dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST,
                               name="router")(xf.astype(jnp.float32))
             if c.scoring == "softmax":
                 probs = jax.nn.softmax(router, axis=-1)       # [N, E]
             else:
                 probs = jax.nn.sigmoid(router)
+            bias = None
             if c.choice_bias is not None:
                 bias = self.param(
                     "bias", nn.initializers.normal(c.choice_bias),
-                    (c.n_expert,), jnp.float32)
-                _, topi = jax.lax.top_k(probs + bias, k)
-                topw = jnp.take_along_axis(probs, topi, axis=-1)
-            else:
-                topw, topi = jax.lax.top_k(probs, k)          # [N, k]
-            if c.norm_topk_prob:
-                total = jnp.sum(topw, axis=-1, keepdims=True)
-                if c.topk_sum_eps:
-                    total = total + c.topk_sum_eps
-                topw = topw / total
-            if c.routed_scale != 1.0:
-                topw = topw * c.routed_scale
+                    (scored,), jnp.float32)
+            topw, topi = self.route(probs, bias)              # [N, k]
 
         # Switch-style load balance: E * sum_e(frac_routed_e * mean_prob_e)
-        top1 = jax.nn.one_hot(topi[:, 0], c.n_expert, dtype=jnp.float32)
-        aux = c.n_expert * jnp.sum(jnp.mean(top1, axis=0)
-                                   * jnp.mean(probs, axis=0))
+        top1 = jax.nn.one_hot(topi[:, 0], scored, dtype=jnp.float32)
+        aux = scored * jnp.sum(jnp.mean(top1, axis=0)
+                               * jnp.mean(probs, axis=0))
         self.sow("intermediates", "moe_aux", aux)
 
         init = nn.initializers.normal
@@ -480,6 +619,8 @@ class MoEFFN(nn.Module):
                 # An expert of another chip's share: no row here.
                 flat = flat - c.experts_held[0]
                 flat = jnp.where((flat >= 0) & (flat < e), flat, e)
+            elif c.n_zero_expert:
+                flat = jnp.minimum(flat, e)  # an identity: no row either
             if live is not None:
                 flat = jnp.where(jnp.repeat(live.reshape(n), k), flat, e)
             order = jnp.argsort(flat)
@@ -496,6 +637,14 @@ class MoEFFN(nn.Module):
                             out.astype(jnp.float32)
                             * topw.reshape(n * k)[order][:, None], 0.0)
             y = jnp.sum(out[jnp.argsort(order)].reshape(n, k, d), axis=1)
+        if c.n_zero_expert:
+            with jax.named_scope("moe.zero"):
+                y = y + self.identity(xf, topw, topi)
+                tokens_live = (jnp.ones(n, bool) if live is None
+                               else live.reshape(n))
+                tokens = jnp.concatenate([tokens, jnp.stack([
+                    jnp.sum((topi >= c.n_expert) & tokens_live[:, None]),
+                    k * jnp.sum(tokens_live)]).astype(jnp.int32)])
         y = y.reshape(x.shape).astype(c.dtype)
         if c.n_shared:
             with jax.named_scope("moe.shared"):
@@ -505,22 +654,30 @@ class MoEFFN(nn.Module):
 
 class MixtralBlock(nn.Module):
     """``dense_width``: the block's feed-forward is a SwiGLU that wide
-    and not the routed layer (``config.ffn_width`` of its index)."""
+    and not the routed layer (``config.ffn_width`` of its index).
+    ``routed_beside``: a routed layer reads the normed stream beside that
+    SwiGLU, and the block returns its output as a second value, for a
+    later block to add at its end as ``shortcut``
+    (``config.shortcut_to``)."""
 
     config: MixtralConfig
     kind: str = FULL
     dense_width: Optional[int] = None
+    routed_beside: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, shortcut=None):
         c = self.config
         x = x + c.attention(self.kind, name=op_name(self.kind))(
             RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="input_norm")(x))
         h = RMSNorm(dtype=c.dtype, eps=c.norm_eps, name="post_attn_norm")(x)
-        if self.dense_width is not None:
-            return x + LlamaMLP(c, self.dense_width, name="mlp")(h)
-        y, _ = MoEFFN(c, name="moe")(h)
-        return x + y
+        if self.dense_width is None:
+            x = x + c.routed(name="moe")(h)[0]
+        else:
+            x = x + LlamaMLP(c, self.dense_width, name="mlp")(h)
+            if self.routed_beside:  # carried on, not added here
+                return x, c.routed(name="moe")(h)[0]
+        return x if shortcut is None else x + shortcut
 
 
 class PredictionModule(nn.Module):
@@ -577,9 +734,17 @@ class Mixtral(nn.Module):
             )(block(c, name="layers"), x, None)
         else:
             # Layers of different kinds are not one scanned body.
+            shortcuts = {}  # a routed layer's output, by where it is added
             for i in range(c.n_layer):
-                x = block(c, c.layer_kind(i), c.ffn_width(i),
-                          name=f"layers_{i}")(x)
+                to = c.shortcut_to(i)
+                layer = block(c, c.layer_kind(i), c.ffn_width(i),
+                              to is not None, name=f"layers_{i}")
+                out = layer(x, shortcuts.pop(i)) if i in shortcuts \
+                    else layer(x)
+                if to is None:
+                    x = out
+                else:
+                    x, shortcuts[to] = out
         if c.mtp_layers and self.is_initializing():
             # The module's parameters are made with the model's; the
             # training forward does not read them.
@@ -700,8 +865,8 @@ def _module(c: MixtralConfig, params, hidden, next_tokens, live, cache_args):
             {"params": bp["attn"]}, normed("input_norm", u, bp),
             *cache_args, method="step" if cache_args else "prefill")
     u = u + y
-    y, count = MoEFFN(c).apply({"params": bp["moe"]},
-                               normed("post_attn_norm", u, bp), live)
+    y, count = c.routed().apply({"params": bp["moe"]},
+                                normed("post_attn_norm", u, bp), live)
     return normed("final_norm", u + y), k, v, count
 
 
@@ -753,3 +918,4 @@ Mellum = Mixtral
 JoyAI = Mixtral
 ExaoneMoe = Mixtral
 Lfm2Moe = Mixtral
+LongcatFlash = Mixtral

@@ -8,7 +8,13 @@ from one compressed latent a token: ``kv_a_proj`` gives ``kv_lora_rank``
 values (RMSNorm'd) and ONE ``qk_rope_dim``-wide key shared by every
 head (roped); ``kv_b_proj`` expands the latent to each head's
 ``qk_nope_dim`` key part and ``v_head_dim`` values. Scores are over
-``sqrt(qk_nope_dim + qk_rope_dim)``.
+``sqrt(qk_nope_dim + qk_rope_dim)``. Where the config says so
+(``mla_scale_q_lora``, ``mla_scale_kv_lora``: LongCat-Flash) the normed
+query latent is multiplied by ``sqrt(n_embd / q_lora_rank)`` and the
+normed key/value latent by ``sqrt(n_embd / kv_lora_rank)``, which give
+the two bottlenecks' outputs the variance a full-width projection would
+have; the roped shared key is not scaled. The pool's row holds the
+scaled latent, so the absorbed form is the same with or without.
 
 - ``prefill`` (and ``__call__``, training) is the *expanded* form: the
   latent goes through ``kv_b_proj`` and flash attention runs on heads of
@@ -44,8 +50,9 @@ def deinterleave(x):
 
 class LatentAttention(nn.Module):
     """``config`` carries ``q_lora_rank``, ``kv_lora_rank``,
-    ``qk_nope_dim``, ``qk_rope_dim``, ``v_head_dim``, ``rope_interleave``
-    beside what every llama-family config has."""
+    ``qk_nope_dim``, ``qk_rope_dim``, ``v_head_dim``, ``rope_interleave``,
+    ``mla_scale_q_lora`` and ``mla_scale_kv_lora`` beside what every
+    llama-family config has."""
 
     config: object
 
@@ -53,12 +60,36 @@ class LatentAttention(nn.Module):
         c = self.config
         dense = functools.partial(nn.Dense, use_bias=False, dtype=c.dtype,
                                   param_dtype=c.param_dtype)
+
+        def gain(on: bool, rank: int) -> float:
+            return (c.n_embd / rank) ** 0.5 if on else 1.0
+
+        def expansion(on: bool, features: int):
+            """The projection out of a latent. Where the latent is
+            scaled it is seeded as a projection out of the hidden size
+            would be, variance 1 / n_embd: the correction's premise is
+            one standard deviation for every matrix, under which a
+            latent's expansion comes out sqrt(rank / n_embd) of the roped
+            key's size. (Seeded at its own fan-in *and* scaled, attention
+            scores are 7 times as wide as either alone makes them and a
+            seeded model is chaotic: PERF.md, PR 51.)"""
+            if not on:
+                return dense(features)
+            return dense(features, kernel_init=nn.initializers.normal(
+                c.n_embd ** -0.5))
+
         self.q_a_proj = dense(c.q_lora_rank)
-        self.q_a_norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
-        self.q_b_proj = dense(c.n_head * (c.qk_nope_dim + c.qk_rope_dim))
+        self.q_a_norm = RMSNorm(
+            dtype=c.dtype, eps=c.norm_eps,
+            gain=gain(c.mla_scale_q_lora, c.q_lora_rank))
+        self.q_b_proj = expansion(
+            c.mla_scale_q_lora, c.n_head * (c.qk_nope_dim + c.qk_rope_dim))
         self.kv_a_proj = dense(c.kv_lora_rank + c.qk_rope_dim)
-        self.kv_a_norm = RMSNorm(dtype=c.dtype, eps=c.norm_eps)
-        self.kv_b_proj = dense(c.n_head * (c.qk_nope_dim + c.v_head_dim))
+        self.kv_a_norm = RMSNorm(
+            dtype=c.dtype, eps=c.norm_eps,
+            gain=gain(c.mla_scale_kv_lora, c.kv_lora_rank))
+        self.kv_b_proj = expansion(
+            c.mla_scale_kv_lora, c.n_head * (c.qk_nope_dim + c.v_head_dim))
         self.o_proj = dense(c.n_embd)
 
     @property
@@ -72,7 +103,8 @@ class LatentAttention(nn.Module):
     def _project(self, x):
         """``x`` [..., E] -> ``(q_nope [..., H, nope], q_pe [..., H, rope],
         c_kv [..., rank] normed, k_pe [..., rope])``, nothing roped yet
-        (the roped parts de-interleaved where the config says so)."""
+        (the roped parts de-interleaved, the latents scaled, where the
+        config says so)."""
         c = self.config
         q = self.q_b_proj(self.q_a_norm(self.q_a_proj(x)))
         q = q.reshape(x.shape[:-1]

@@ -89,6 +89,10 @@ DECLARED_METRICS: Dict[str, str] = {
     "raytpu_infer_handoff_pages_total":
         "KV pages grafted via disaggregated prefill->decode handoff",
     "raytpu_infer_kv_page_utilization": "KV page pool utilization 0..1",
+    "raytpu_infer_moe_pairs_total":
+        "live (token, choice) pairs routed by a router with identity experts",
+    "raytpu_infer_moe_zero_pairs_total":
+        "routed pairs that chose an identity expert (no product)",
     "raytpu_infer_prefill_tokens_per_s": "prefill throughput",
     "raytpu_infer_prefill_tokens_total": "prefill tokens processed",
     "raytpu_infer_prefix_evictions_total": "prefix cache evictions",
