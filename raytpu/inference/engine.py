@@ -68,6 +68,13 @@ a program's logits stay there and a step brings back ``int32[bucket]``
 token ids. A row's draw is keyed by its request's seed and its own
 position, so batched output == solo output.
 
+**One decode step is in flight** (:meth:`InferenceEngine.step`): the ids
+stay on the device as the next decode's ``tokens``, which is dispatched
+before they are fetched, so the chip does not idle a host round trip a
+step. ``Sequence.cached_len`` counts the positions whose write is
+dispatched, and the tokens a call of ``step`` returns are those of the
+decode the call before dispatched.
+
 **A model that drafts for itself** (``serving.drafting``: a prediction
 module; on unless the engine is built with ``drafting=False``) decodes
 two positions a step: the token a sequence stands at and the module's
@@ -103,7 +110,7 @@ from raytpu.inference.kv_cache import PagedKVCache
 from raytpu.inference.prefix_cache import PrefixCache
 from raytpu.inference.sampling import (SamplingParams, draft_token, sample,
                                        speculative)
-from raytpu.inference.scheduler import Scheduler, Sequence
+from raytpu.inference.scheduler import RUNNING, Scheduler, Sequence
 from raytpu.util import compile_cache, task_events, tracing
 from raytpu.util.metrics import Counter, Gauge, Histogram
 from raytpu.util.profiler import profiling_enabled
@@ -149,6 +156,22 @@ class StepOutput:
     token_id: int
     finished: bool = False
     finish_reason: Optional[str] = None
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A decode step that is dispatched and not yet fetched: its batch,
+    row for row, the ids its sampler leaves on the device (``int32
+    [bucket]``: the next step's ``tokens`` as they lie), a routed model's
+    counts beside them, the program's name and bucket key, when its
+    launch began, and its FLOPs where the profiler is on."""
+
+    seqs: List[Sequence]
+    ids: Any
+    experts: list
+    program: Tuple[str, str]
+    launched: float
+    flops: Optional[float] = None
 
 
 # Where ``ks`` and ``vs`` stand in the three programs' arguments: given
@@ -464,6 +487,7 @@ class InferenceEngine:
         self._chunk_compiles: Dict[str, int] = {}
         self._decode_compiles: Dict[str, int] = {}
         self._sample_compiles: Dict[str, int] = {}
+        self._carry_compiles: Dict[str, int] = {}
         self._accept_compiles: Dict[str, int] = {}
         self._draft_compiles: Dict[str, int] = {}
         # What ``ops.grouped_matmul`` noted while a program was traced,
@@ -484,11 +508,21 @@ class InferenceEngine:
         # Arrays handed from the host to the device so far (``_put``),
         # and the block tables put and passed again, over kinds and steps.
         self._host_puts = self._table_puts = self._table_reuses = 0
+        # The decode step dispatched and not yet fetched (see ``step``),
+        # when the last fetch ended, and over the engine's life the
+        # decodes dispatched ahead of the fetch before them, those built
+        # from the host's tokens with none in flight, and the rows
+        # fetched for a sequence that had ended meanwhile.
+        self._flight: Optional[_Flight] = None
+        self._fetched_at = 0.0
+        self._decodes_ahead = self._decodes_drained = 0
+        self._ahead_rows_dropped = 0
         # One record per step(): its phases' stamps and what it ran.
         self.recorder = tracing.StepRecorder()
-        # Called, if set, once a decode step's program is on its way to
-        # the device, first thing in ``infer.decode.wait``: while the
-        # chip works the host is free, and nowhere else in a step is it.
+        # Called, if set, in ``infer.decode.wait`` once a decode step's
+        # programs are on their way to the device, before the host
+        # blocks on the ids of the step in flight: while the chip works
+        # the host is free, and nowhere else in a step is it.
         self.on_launch: Optional[Callable[[], None]] = None
         self._prefill_tokens = 0
         self._decode_tokens = 0
@@ -531,6 +565,7 @@ class InferenceEngine:
                 f"{tokens.shape[0]}x{_width(block_tables)}")
             self._sample_fn = self._build_sampler(
                 jax, self._sample_compiles)
+            self._carry_fn = self._build_carry(jax, self._carry_compiles)
 
     # ---- compiled steps (the ONLY jax.jit call sites) ---------------
 
@@ -591,6 +626,26 @@ class InferenceEngine:
                           temperature, top_k, seed, position)
 
         return jax.jit(_sample)
+
+    def _build_carry(self, jax, compiles):
+        """The jitted hand-over of a step's tokens where the batch has
+        moved since the step in flight, ``(ids in flight, source, fresh)
+        -> tokens``, ``int32[bucket]`` each: row ``i`` takes the
+        in-flight id of row ``source[i]``, or where that is -1 (a
+        sequence that has just joined, a padding row) ``fresh[i]``, the
+        last token the host knows. One program a decode bucket, entered
+        with the bucket's first decode (:meth:`_run_decode`); ids of
+        another bucket are fetched first (:meth:`step`). ``compiles``
+        counts its traces under the bucket."""
+        jnp = self._jnp
+
+        def _carry(ids, source, fresh):
+            # Trace-time only, as in ``_build_program``.
+            key = str(ids.shape[0])
+            compiles[key] = compiles.get(key, 0) + 1
+            return jnp.where(source >= 0, ids[jnp.maximum(source, 0)], fresh)
+
+        return jax.jit(_carry)
 
     def _build_drafting(self, jax, drafting):
         """The five jitted programs of a model that drafts for itself
@@ -854,20 +909,60 @@ class InferenceEngine:
         return self.scheduler.abort(request_id)
 
     def has_unfinished(self) -> bool:
-        return self.scheduler.has_unfinished()
+        """Whether a call of :meth:`step` has anything to do: a request
+        waits or runs, or a decode is in flight (of sequences that were
+        all aborted since: its rows are still to be fetched and dropped)."""
+        return self.scheduler.has_unfinished() or self._flight is not None
 
     # ---- the iteration ----------------------------------------------
 
     def step(self) -> List[StepOutput]:
-        """One scheduler iteration: run every admitted prefill, then one
-        padded decode step over all running sequences, its tokens
-        sampled on the device and fetched as ids; retire finished
-        sequences (freeing their pages). Leaves one record in
-        ``self.recorder`` (see :meth:`step_log`)."""
+        """One scheduler iteration: run every admitted prefill, then
+        dispatch one padded decode step over all running sequences, its
+        tokens sampled on the device, and fetch the ids of the decode
+        the call before dispatched; retire finished sequences (freeing
+        their pages). Leaves one record in ``self.recorder`` (see
+        :meth:`step_log`).
+
+        **One decode is in flight.** A call leaves its decode and its
+        sampler on the device (``_flight``) and returns the tokens of
+        the one before. The next call schedules, builds and dispatches
+        its own decode behind it and only then blocks on the ids in
+        flight: the chip goes from one step to the next with no host in
+        between. The ids never leave the device on their way into the
+        next decode: where the batch is the in-flight step's row for
+        row they are its ``tokens`` as they lie, and where it has moved
+        (a sequence joined after its prefill, left by length, which the
+        scheduler knows a step early, or was ended by a stop token or an
+        ``abort``) one small program gathers each row's id from the row
+        it had in flight and takes a joiner's from the host
+        (``_carry_fn``). Everything else a decode takes the host knows
+        before the ids arrive, because ``Sequence.cached_len`` advances
+        when a position's write is dispatched (``Sequence.in_flight``
+        counts the tokens not fetched yet). So the tokens a call returns
+        are those of the decode dispatched a call earlier, and a
+        sequence's last token comes from a call that dispatches nothing
+        for it.
+
+        A row fetched for a sequence that has ended meanwhile is
+        dropped: its write went to a slot of the sequence's own page,
+        which whatever is dispatched later reuses later on the chip. The
+        decode in flight is *drained*, fetched with nothing dispatched
+        behind it, where there is nothing to dispatch (the batch's last
+        tokens), where the batch has moved to another bucket (the
+        hand-over is one program a bucket) and before an iteration in
+        which the scheduler would preempt (``Scheduler.pages_short``: a
+        preempted sequence re-prefills ``tokens``, which must hold every
+        token whose KV was written); the decode after a drain, like an idle
+        engine's first, is built from the host's tokens. A model that drafts for itself
+        keeps nothing in flight (:meth:`_run_verify`: how far a sequence
+        advances is the device's answer there)."""
         out: List[StepOutput] = []
         recorder = self.recorder
         compiled = self._programs_traced()
         preempted = self.scheduler.num_preemptions
+        decoded = 0
+
         with recorder.step("infer.step", {
                 "decodes": 0, "bucket": 0, "table_width": 0,
                 "live_pages": 0, "live_pages_full": 0,
@@ -875,9 +970,15 @@ class InferenceEngine:
                 "pages_owned_full": 0, "pages_owned_window": 0,
                 "state_seats": 0, "state_bytes": 0,
                 "sampled_stochastic": 0, "host_puts": 0, "tables_reused": 0,
+                "ahead": 0, "ahead_rows_dropped": 0,
                 "kv_bytes_per_token": self._kv_token_bytes} | (
                     {"drafted": 0, "accepted": 0, "emitted": 0}
                     if self._drafting else {})) as st:
+            if self._flight is not None and self.scheduler.pages_short():
+                # It would preempt: a victim re-prefills ``tokens``, which
+                # the fetch completes, and an ended sequence's pages may
+                # make the preemption needless.
+                decoded += self._drain(out)
             with recorder.phase("infer.schedule") as ph:
                 waiting = len(self.scheduler.waiting)
                 plan = self.scheduler.schedule()
@@ -890,9 +991,17 @@ class InferenceEngine:
                 prefilled = 0
                 for seq in plan.prefills:
                     prefilled += self._run_prefill(seq, out)
-                decoded = 0
-                if plan.decodes:
-                    decoded = self._run_decode(plan.decodes, out)
+                decodes = plan.decodes
+                if self._flight is not None and (
+                        not decodes or _bucket_for(
+                            len(decodes), self.decode_buckets)
+                        != len(self._flight.ids)):
+                    # The batch's last tokens; or ids of another bucket,
+                    # which no program hands over.
+                    decoded += self._drain(out)
+                    decodes = [s for s in decodes if s.state == RUNNING]
+                if decodes:
+                    decoded += self._run_decode(decodes, out)
             st.attrs["compiled"] = self._programs_traced() - compiled
             st.attrs["preempted"] = \
                 self.scheduler.num_preemptions - preempted
@@ -935,6 +1044,7 @@ class InferenceEngine:
                 + sum(self._chunk_compiles.values())
                 + sum(self._decode_compiles.values())
                 + sum(self._sample_compiles.values())
+                + sum(self._carry_compiles.values())
                 + sum(self._accept_compiles.values())
                 + sum(self._draft_compiles.values()))
 
@@ -1067,16 +1177,19 @@ class InferenceEngine:
             self._emit(seq, int(np.asarray(ids)[0]), out)
         return take
 
-    def _decode_inputs(self, seqs: List[Sequence], ahead: int):
+    def _decode_inputs(self, seqs: List[Sequence], depth: int):
         """What a decode step over ``seqs`` hands its programs, each
-        writing ``ahead`` positions a sequence from the one it stands at,
+        writing ``depth`` positions a sequence from the one it stands at,
         built over the batch and not a sequence at a time: ``(ids,
         bucket, table width, tokens, positions, dests by kind, block
         tables by kind)``, host arrays of ``bucket`` rows but the tables,
-        which are on the device (:meth:`_batch_tables`). A padding row
-        names the scratch page's first slots and position 0. Slides the
-        window tables on to the step's positions and fills the step
-        record's fields of what the step reads."""
+        which are on the device (:meth:`_batch_tables`). The tokens are
+        the last the host knows of each sequence: a decode behind a step
+        in flight takes that step's ids in their place
+        (:meth:`_tokens_ahead`). A padding row names the scratch page's
+        first slots and position 0. Slides the window tables on to the
+        step's positions and fills the step record's fields of what the
+        step reads."""
         cache, fields = self.cache, self.recorder.open.fields
         b = len(seqs)
         bucket = _bucket_for(b, self.decode_buckets)
@@ -1090,11 +1203,11 @@ class InferenceEngine:
         # (bucketed): the reference gather then reads O(batch max
         # context), not O(longest-ever sequence).
         P = _bucket_for(cache.table_width(rows), self.page_buckets)
-        newest = positions[:b] + (ahead - 1)
+        newest = positions[:b] + (depth - 1)
         fields["window_pages_released"] += cache.slide_rows(
             ids, rows[:b], positions[:b], newest + 1)
-        written = positions if ahead == 1 else \
-            positions[:, None] + np.arange(ahead, dtype=np.int32)
+        written = positions if depth == 1 else \
+            positions[:, None] + np.arange(depth, dtype=np.int32)
         dests = self._by_kind(lambda kind: cache.slots(rows, written, kind))
         # What the paged kernel must read: the newest position's context.
         live_pages = int(cache.pages_read(newest).sum())
@@ -1106,22 +1219,54 @@ class InferenceEngine:
         return (ids, bucket, P, tokens, positions, dests,
                 self._batch_tables(seqs, ids, rows, P))
 
+    def _tokens_ahead(self, before: _Flight, seqs: List[Sequence],
+                      known: np.ndarray):
+        """The tokens of a decode over ``seqs`` from the ids ``before``
+        has in flight, on the device: the ids themselves where the batch
+        is that step's row for row, else gathered a row from the row the
+        sequence had there; a sequence that was not in it (it has just
+        been prefilled) and a padding row keep the host's ``known``
+        (whatever id the sampler gave a padding row in flight does as
+        well: it writes the scratch page and counts in nothing)."""
+        if self._same_batch(before.seqs, seqs):
+            return before.ids
+        row = {id(seq): i for i, seq in enumerate(before.seqs)}
+        source = np.full(len(known), -1, dtype=np.int32)
+        source[:len(seqs)] = [row.get(id(seq), -1) for seq in seqs]
+        return self._carry_fn(before.ids, *self._hand((source, known)))
+
     def _run_decode(self, seqs: List[Sequence],
                     out: List[StepOutput]) -> int:
+        """Dispatch the decode over ``seqs`` and its sampler, leave them
+        in flight, and only then fetch and emit the step that was: this
+        decode goes out *ahead* of that fetch, its tokens the ids in
+        flight (:meth:`_tokens_ahead`). With none in flight (an idle
+        engine's first decode, the one after a drain) the tokens are the
+        host's. Returns the rows the fetched step decoded."""
         if self._drafting is not None:
             return self._run_verify(seqs, out)
         recorder = self.recorder
         fields = recorder.open.fields
+        before = self._flight
         with recorder.phase("infer.decode") as dec:
             with recorder.phase("infer.decode.launch") as launch:
                 put = self._host_puts
                 ids, bucket, P, tokens, positions, dests, tables = \
                     self._decode_inputs(seqs, 1)
+                if before is None:
+                    # One form of ``tokens`` for the program's cache, the
+                    # one the ids in flight have: an array on the device.
+                    tokens = self._put(tokens)
+                    self._decodes_drained += 1
+                else:
+                    tokens = self._tokens_ahead(before, seqs, tokens)
+                    fields["ahead"] = 1
+                    self._decodes_ahead += 1
                 dec.attrs.update(batch=len(seqs), bucket=bucket)
                 # The small rows go over with the call; the positions,
                 # which the sampler behind it takes too, are put once.
-                seats, tokens, dests, context_lens = self._hand(
-                    (self._seats(ids, bucket), tokens, dests, positions + 1))
+                seats, dests, context_lens = self._hand(
+                    (self._seats(ids, bucket), dests, positions + 1))
                 inputs = (tokens, self._put(positions), dests, tables,
                           context_lens)
                 logits, experts = self._call(self._decode_fn, seats, *inputs)
@@ -1129,46 +1274,107 @@ class InferenceEngine:
                     # Asked for now, it comes back beside the ids; left
                     # to the wait it is a transfer of its own, 0.5 ms.
                     count.copy_to_host_async()
-                fields["host_puts"] = self._host_puts - put
-            with recorder.phase("infer.decode.wait") as wait:
-                # The chip is on the decode. The sampler goes out behind
-                # it, over the logits where they lie and the positions
-                # the decode was given; the requests' own rows are put
-                # again only when the batch's membership has changed.
-                rows, stochastic = self._batch_rows(seqs, bucket)
-                ids = self._sample_fn(logits, *rows, inputs[1])
-                ids.copy_to_host_async()
-                fields["sampled_stochastic"] += stochastic
-                # The host blocked on the device and on the copy back,
-                # after whatever its owner has for it meanwhile.
-                if self.on_launch is not None:
-                    self.on_launch()
-                ids = np.asarray(ids)
-                wait.attrs["bytes"] = ids.nbytes
-                self._count_experts(experts, ("_decode", f"{bucket}x{P}"))
-            with recorder.phase("infer.decode.sample"):
-                # Advancing and emitting what was sampled on the device.
-                for seq, token in zip(seqs, ids.tolist()):
+                # The writes are dispatched: what the scheduler and the
+                # next launch count from.
+                for seq in seqs:
                     seq.cached_len += 1
-                    self._emit(seq, token, out)
+                    seq.in_flight += 1
+                fields["host_puts"] = self._host_puts - put
+            flops = None
             if profiling_enabled():
-                prof = step_profiler("infer")
                 # FLOPs from XLA's own cost model, computed once per
                 # (batch bucket x table width) program — lower() reuses
                 # the jit cache, so this never triggers a second compile.
                 cache = self.cache
-                flops = prof.ensure_flops(
+                flops = step_profiler("infer").ensure_flops(
                     ("decode", bucket, P),
                     lambda: cost_analysis_flops(
                         self._decode_fn, self._params, cache.k, cache.v,
                         *((cache.state, seats) if cache.state else ()),
                         *inputs))
-                # Launch to token ids on the host: the real step.
-                prof.observe_step(wait.t1 - launch.t0, flops=flops)
-                self._hbm_tick += 1
-                if self._hbm_tick % 32 == 1:
-                    prof.observe_hbm()
-        return len(seqs)
+            with recorder.phase("infer.decode.wait") as wait:
+                # The chip is on the decode, or still on the one before.
+                # The sampler goes out behind it, over the logits where
+                # they lie and the positions the decode was given; the
+                # requests' own rows are put again only when the batch's
+                # membership has changed.
+                rows, stochastic = self._batch_rows(seqs, bucket)
+                sampled = self._sample_fn(logits, *rows, inputs[1])
+                sampled.copy_to_host_async()
+                fields["sampled_stochastic"] += stochastic
+                if str(bucket) not in self._carry_compiles:
+                    # The bucket's first decode enters its hand-over too:
+                    # traffic that has warmed a decode program has warmed
+                    # what follows it when the batch moves.
+                    self._carry_fn(sampled, *self._hand((
+                        np.arange(bucket, dtype=np.int32),
+                        np.zeros(bucket, dtype=np.int32))))
+                self._flight = _Flight(
+                    list(seqs), sampled, experts,
+                    ("_decode", f"{bucket}x{P}"), launch.t0, flops)
+                # The host blocked on the device and on the copy back of
+                # the step before, after whatever its owner has for it
+                # meanwhile.
+                fetched = self._fetch(before, wait)
+            with recorder.phase("infer.decode.sample"):
+                return self._give(before, fetched, out)
+
+    def _drain(self, out: List[StepOutput]) -> int:
+        """Fetch the decode in flight with none dispatched behind it,
+        under the phases a decode step's fetch has. Returns the rows it
+        decoded."""
+        recorder = self.recorder
+        flight, self._flight = self._flight, None
+        with recorder.phase("infer.decode"):
+            with recorder.phase("infer.decode.wait") as wait:
+                tokens = self._fetch(flight, wait)
+            with recorder.phase("infer.decode.sample"):
+                return self._give(flight, tokens, out)
+
+    def _fetch(self, flight: Optional[_Flight], wait) -> List[int]:
+        """Inside ``infer.decode.wait``: the owner's ``on_launch``, then
+        the block on ``flight``'s ids and on a routed model's counts,
+        which go into the record open now: the step under whose span
+        that decode's kernels mostly ran, a call after its own."""
+        if self.on_launch is not None:
+            self.on_launch()
+        if flight is None:
+            return []
+        ids = np.asarray(flight.ids)
+        wait.attrs["bytes"] = ids.nbytes
+        self._count_experts(flight.experts, flight.program)
+        now = time.perf_counter()
+        if profiling_enabled() and flight.flops is not None:
+            # From one fetch to the next, or from its launch where the
+            # chip had been let idle before it: the real step.
+            prof = step_profiler("infer")
+            prof.observe_step(now - max(flight.launched, self._fetched_at),
+                              flops=flight.flops)
+            self._hbm_tick += 1
+            if self._hbm_tick % 32 == 1:
+                prof.observe_hbm()
+        self._fetched_at = now
+        return ids.tolist()
+
+    def _give(self, flight: Optional[_Flight], tokens: List[int],
+              out: List[StepOutput]) -> int:
+        """Inside ``infer.decode.sample``: emit what ``flight`` sampled, a
+        token a sequence. The row of a sequence that has ended since its
+        dispatch (a stop token a step before, an ``abort``) is dropped
+        and counted. Returns the rows it decoded, as a routed model's
+        counts hold them: the dropped among them."""
+        if flight is None:
+            return 0
+        dropped = 0
+        for seq, token in zip(flight.seqs, tokens):
+            seq.in_flight -= 1
+            if seq.state == RUNNING:
+                self._emit(seq, token, out)
+            else:
+                dropped += 1
+        self.recorder.open.fields["ahead_rows_dropped"] += dropped
+        self._ahead_rows_dropped += dropped
+        return len(flight.seqs)
 
     def _run_verify(self, seqs: List[Sequence],
                     out: List[StepOutput]) -> int:
@@ -1319,9 +1525,28 @@ class InferenceEngine:
         ``start``, ``end``, ``phases`` (``[name, t0, t1]`` in order of
         their start: ``infer.schedule``, ``infer.prefill`` or
         ``infer.prefill_chunk`` per prefill, ``infer.decode`` and inside
-        it ``.launch``, ``.wait``, ``.sample``, plus what the stepping
-        loop put around the step), and what the step ran: ``decodes``,
-        ``bucket``, ``table_width``, ``live_pages`` (pages its decode
+        it ``.launch`` (this step's decode built and dispatched),
+        ``.wait`` (its sampler dispatched, the owner's ``on_launch``, the
+        block on the ids of the decode *in flight*, the one the step
+        before dispatched) and ``.sample`` (those ids emitted); a step
+        that dispatches no decode and fetches one (the batch's last
+        tokens) has an ``infer.decode`` of ``.wait`` and ``.sample``
+        alone, as has one whose batch moved to another bucket before
+        its own, and a preemption's drain one inside ``infer.schedule``;
+        plus what the stepping
+        loop put around the step), and what the step ran. What describes
+        a decode describes the one the step *dispatched*: ``decodes``,
+        ``bucket``, ``table_width``, ``live_pages*``, ``host_puts``,
+        ``tables_reused`` and ``ahead`` (1 where it was dispatched
+        before the ids of the step before were fetched, its tokens the
+        ids in flight, gathered on the device where the batch had
+        moved; 0 where it was built from the host's tokens, an idle
+        engine's first decode and the one after a drain, or the step
+        dispatched none). What comes back with a fetch
+        describes the decode the step *fetched*, a call after its
+        dispatch: a routed model's ``moe_*`` counts and
+        ``ahead_rows_dropped`` (rows fetched for a sequence that had
+        ended while they were in flight). ``live_pages`` (pages its decode
         had to read of a sequence's whole context), ``live_pages_full``
         and ``live_pages_window`` (pages its decode read in one full
         layer, the same number, and in one window layer: the windows'
@@ -1380,8 +1605,10 @@ class InferenceEngine:
                                        in self._chunk_compiles.items()},
             "decode_compiles": {str(k): v for k, v
                                 in self._decode_compiles.items()},
-            # The sampler's, by the shape of the logits it was given.
+            # The sampler's, by the shape of the logits it was given, and
+            # the tokens' hand-over's, by the decode bucket.
             "sample_compiles": dict(self._sample_compiles),
+            "carry_compiles": dict(self._carry_compiles),
             # Of a model that drafts for itself: the accept/resample
             # program's and the module's, and the drafts verified so far
             # and kept (None: no drafting).
@@ -1397,6 +1624,13 @@ class InferenceEngine:
             # of pool and the steps.
             "table_puts": self._table_puts,
             "table_reuses": self._table_reuses,
+            # Decodes dispatched before the ids of the step before them
+            # were fetched, those built from the host's tokens with none
+            # in flight, and rows fetched for a sequence that had ended
+            # while they were in flight (all 0 where the model drafts).
+            "decodes_ahead": self._decodes_ahead,
+            "decodes_drained": self._decodes_drained,
+            "ahead_rows_dropped": self._ahead_rows_dropped,
             "paged_attn_impl": self.paged_attn_impl,
             # Bytes of the tree the programs take, by dtype, over all
             # shards: all in the compute type but the norms' leaves.

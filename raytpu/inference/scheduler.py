@@ -14,6 +14,15 @@ seat whatever pages are left. Fresh prefills therefore merge with in-flight deco
 in the same iteration instead of waiting for the batch to drain
 (the continuous-batching throughput lever).
 
+The engine keeps one decode step in flight: ``Sequence.cached_len``
+counts the positions whose write is dispatched and ``Sequence.in_flight``
+the tokens sampled on the device and not yet fetched, so a slot is
+secured from ``cached_len`` as ever. A sequence whose tokens in flight
+hold its last by length stays among the running until they are fetched
+and is given no slot and no row meanwhile. The scheduler knows nothing
+else of the step in flight: the engine asks :meth:`Scheduler.pages_short`
+and fetches what is in flight before an iteration that would preempt.
+
 Preemption is preempt-to-RECOMPUTE (vLLM's default for small
 sequences): the victim's pages are freed (and its seat: the state it
 held is recomputed with its keys and values), its ``cached_len`` drops to
@@ -47,10 +56,17 @@ class Sequence:
     sampling: SamplingParams = dataclasses.field(default_factory=SamplingParams)
     arrival: int = 0
     generated: List[int] = dataclasses.field(default_factory=list)
-    # Tokens whose K/V currently live in the paged cache. After a
-    # prefill this is len(tokens) - 1 (the newest sampled token's KV is
-    # written by its decode step); 0 means preempted/never prefilled.
+    # Positions whose K/V a dispatched program has written, or will have
+    # by the time anything dispatched later runs: it advances when the
+    # write is dispatched, not when the token sampled behind it is
+    # fetched. After a prefill this is len(tokens) - 1 (the newest
+    # sampled token's KV is written by its decode step), and with a
+    # decode in flight len(tokens) - 1 + in_flight; 0 means
+    # preempted/never prefilled.
     cached_len: int = 0
+    # Tokens of its own a dispatched decode has sampled on the device
+    # and the host has not fetched yet (the engine's step in flight).
+    in_flight: int = 0
     state: str = WAITING
     finish_reason: Optional[str] = None
     # Serving-plane attribution (stamped by the replica from its request
@@ -160,6 +176,38 @@ class Scheduler:
     def has_unfinished(self) -> bool:
         return bool(self.waiting or self.running)
 
+    def _last_in_flight(self, seq: Sequence) -> bool:
+        """Whether the tokens ``seq`` has in flight hold its last by
+        length: it stays among the running until they are fetched, and
+        takes no slot and no row of a decode meanwhile."""
+        return seq.in_flight > 0 and (
+            len(seq.generated) + seq.in_flight >= seq.sampling.max_new_tokens
+            or seq.num_tokens + seq.in_flight >= self.max_model_len)
+
+    def _decoding(self, seq: Sequence) -> bool:
+        """Whether ``seq`` takes a slot and a row of the next decode: it
+        runs, its prompt is cached, and its last token is not out."""
+        return seq.state == RUNNING and seq.cached_len >= seq.prefill_len \
+            and not self._last_in_flight(seq)
+
+    def pages_short(self) -> bool:
+        """Whether the next :meth:`schedule` would preempt: the decoding
+        sequences' next slots take more pages than are free. The engine
+        asks before it schedules with a decode in flight and fetches
+        first if so: the fetch may end sequences and give pages back, and
+        leaves ``tokens`` holding every token whose KV was written, as a
+        preempted sequence's re-prefill needs them."""
+        cache, ahead = self.cache, self.step_positions
+        free = cache.free_pages()
+        if free >= len(self.running) * cache.pages_for(
+                ahead + cache.page_size - 1):
+            return False  # a page each and more: the usual step
+        need = sum(
+            max(0, cache.pages_for(seq.cached_len + ahead)
+                - cache.num_seq_pages(seq.request_id))
+            for seq in self.running if self._decoding(seq))
+        return need > free
+
     # ---- the per-iteration decision --------------------------------
 
     def schedule(self) -> ScheduleOutput:
@@ -172,10 +220,10 @@ class Scheduler:
         #    Sequences still mid-prefill (chunked) skip this: their
         #    admission already reserved pages for the whole prompt.
         for seq in sorted(self.running, key=lambda s: s.arrival):
-            if seq.state != RUNNING:
-                continue  # preempted by an earlier turn of this loop
-            if seq.cached_len < seq.prefill_len:
-                continue  # mid-prefill: allocation covers prefill_len
+            if not self._decoding(seq):
+                # Preempted by an earlier turn of this loop; or
+                # mid-prefill: allocation covers prefill_len.
+                continue
             while not self.cache.extend(
                     seq.request_id, seq.cached_len + self.step_positions):
                 victim = max(self.running, key=lambda s: s.arrival)
@@ -184,8 +232,7 @@ class Scheduler:
                 if victim is seq:
                     break
 
-        decodes = [s for s in self.running if s.state == RUNNING
-                   and s.cached_len >= s.prefill_len]
+        decodes = [s for s in self.running if self._decoding(s)]
         # Running sequences whose prompt isn't fully cached yet keep
         # prefilling (one chunk per engine step) alongside the decodes.
         prefills: List[Sequence] = [

@@ -487,7 +487,7 @@ class LLMDeployment:
                     self._end_streams()
                     self._cv.notify_all()
                     return
-                self._publish()  # of a step that launched no decode
+                self._publish()  # of a step that waited for no decode
                 self._held = outs
                 if not self._engine.has_unfinished():
                     self._publish()  # no launch is coming to wait for
@@ -510,7 +510,13 @@ class LLMDeployment:
     def _publish(self) -> None:
         """Hand the tokens of the last step to their streams. The loop
         does, under the lock, and where it can as the engine's
-        ``on_launch``: while the next step runs on the device. The
+        ``on_launch``: while the next step runs on the device. (The
+        engine keeps one decode in flight: a step's tokens are those of
+        the decode the step before dispatched, and ``on_launch`` comes
+        before every block on a step's ids, also in a step that only
+        fetches the batch's last ones and dispatches nothing. After
+        that step the engine has nothing unfinished, and the loop
+        publishes its tokens at once.) The
         streams that an event loop awaits (every stream of a serve
         replica) get theirs through one call onto that loop
         (:meth:`_send`), where each is stored as its client's next object

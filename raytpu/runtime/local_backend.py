@@ -413,8 +413,9 @@ class LocalBackend:
         self._exec_threads = _SoftThreadPool()
         self._tasks: Dict[TaskID, _TaskRecord] = {}
         self._waiting_on: Dict[ObjectID, set] = {}  # oid -> task_ids
-        # oid -> waiter count for wait_any_object_ready (stream consumers)
-        self._obj_watch: Dict[ObjectID, int] = {}
+        # oid -> the events of the threads in wait_any_object_ready that
+        # watch it (stream consumers), each woken by its own object's put
+        self._obj_watch: Dict[ObjectID, List[threading.Event]] = {}
         self._ready: List[TaskID] = []
         self._running: Dict[TaskID, _TaskRecord] = {}
         self._actors: Dict[ActorID, _ActorRuntime] = {}
@@ -721,7 +722,13 @@ class LocalBackend:
 
     def _on_object_available(self, oid: ObjectID) -> None:
         with self._lock:
-            notify = oid in self._obj_watch
+            # A put wakes the threads that watch its object and no
+            # other: woken through one condition, 64 token streams made
+            # 4,096 wake-ups a decode step, each for this lock, and the
+            # dispatcher among them, so that a new request's probe
+            # waited seconds for its turn (PERF.md, PR 52).
+            for woken in self._obj_watch.pop(oid, ()):
+                woken.set()
             waiters = self._waiting_on.pop(oid, None)
             if waiters:
                 for tid in waiters:
@@ -732,39 +739,40 @@ class LocalBackend:
                     if not rec.missing_deps:
                         rec.state = "ready"
                         self._ready.append(tid)
-                notify = True
-            if notify:
                 self._cv.notify_all()
 
     def wait_any_object_ready(self, refs, timeout: Optional[float] = None
                               ) -> bool:
         """Block until any of ``refs`` exists in the store (event-driven:
         the put hook wakes us — no polling; VERDICT r3 weak #5). Returns
-        False on timeout."""
+        False on timeout. The caller waits on an event of its own, which
+        only a put of one of its ``refs`` sets."""
         oids = [r.id for r in refs]
         deadline = (None if timeout is None
                     else time.monotonic() + timeout)
+        woken = threading.Event()
         with self._lock:
+            if any(self.store.contains(o) for o in oids):
+                return True
             for oid in oids:
-                self._obj_watch[oid] = self._obj_watch.get(oid, 0) + 1
-            try:
-                while True:
-                    if any(self.store.contains(o) for o in oids):
-                        return True
-                    if deadline is None:
-                        self._cv.wait(timeout=5.0)
-                    else:
-                        remaining = deadline - time.monotonic()
-                        if remaining <= 0:
-                            return False
-                        self._cv.wait(timeout=remaining)
-            finally:
+                self._obj_watch.setdefault(oid, []).append(woken)
+        try:
+            while True:
+                remaining = (5.0 if deadline is None
+                             else deadline - time.monotonic())
+                if remaining <= 0:
+                    return False
+                if woken.wait(remaining) or any(
+                        self.store.contains(o) for o in oids):
+                    return True
+        finally:
+            with self._lock:
                 for oid in oids:
-                    n = self._obj_watch.get(oid, 0) - 1
-                    if n <= 0:
-                        self._obj_watch.pop(oid, None)
-                    else:
-                        self._obj_watch[oid] = n
+                    watchers = self._obj_watch.get(oid)
+                    if watchers is not None and woken in watchers:
+                        watchers.remove(woken)
+                        if not watchers:
+                            del self._obj_watch[oid]
 
     def _bundle_for(self, spec: TaskSpec) -> Optional[_Bundle]:
         sched = spec.scheduling

@@ -7,7 +7,8 @@ the reference the vectorised launch and the kept tables are held to.
 ``watch_decode`` compares everything an engine's decode program is handed,
 call by call, with what that loop builds from the cache's lists at that
 moment, so a table passed again from the last step is caught the moment it
-is stale."""
+is stale. The tokens of a step dispatched ahead are the ids in flight, on
+the device: those are held to what the sequences are given at the fetch."""
 
 import numpy as np
 
@@ -83,13 +84,20 @@ def watch_decode(eng):
         given = args[3 + carried:]
         if carried:
             same(args[4], [seats], "seats")
-        same(given[0], [tokens], "tokens")
+        fields = eng.recorder.open.fields
+        if fields.get("ahead"):
+            # The ids of the step in flight, which the host has not
+            # seen: held to what it emits once ``run`` has fetched them.
+            seen["ahead"] = np.asarray(given[0])
+            assert seen["ahead"].dtype == tokens.dtype \
+                and seen["ahead"].shape == tokens.shape
+        else:
+            same(given[0], [tokens], "tokens")
         same(given[1], [positions], "positions")
         same(given[2], dests, "dests")
         same(given[3], tables, "tables")
         if ahead == 1:
             same(given[4], [context], "context_lens")
-        fields = eng.recorder.open.fields
         assert (fields["live_pages"], fields["live_pages_full"],
                 fields["live_pages_window"]) == (live, live, window)
         assert fields["table_width"] == tables[0].shape[1]
@@ -104,10 +112,23 @@ def watch_decode(eng):
 
     def noted(seqs, out):
         seen["seqs"] = list(seqs)
+        flight = getattr(eng, "_flight", None)
+        carried = flight is not None and not eng._same_batch(flight.seqs,
+                                                             seqs)
         n = run(seqs, out)
         fields = eng.recorder.open.fields
         assert fields["tables_reused"] == seen["reused"]
-        calls.append((fields["tables_reused"], fields["host_puts"]))
+        # The tokens are no put of an ahead step's, and two (the rows'
+        # sources, the joiners' tokens) where the batch has moved:
+        # counted as one, so that a caller's sums read as they did.
+        calls.append((fields["tables_reused"], fields["host_puts"]
+                      + fields["ahead"] - 2 * carried))
+        if fields["ahead"]:
+            # Fetched inside ``run``: a live row's id in flight is the
+            # token the sequence was given, and a joiner's the host's last
+            # (none of these tests ends a sequence with a row in flight).
+            given = seen.pop("ahead")[:len(seqs)].tolist()
+            assert given == [s.tokens[-1] for s in seqs]
         return n
 
     eng._run_decode, eng._decode_fn = noted, watched

@@ -314,6 +314,26 @@ def test_greedy_drafting_is_no_drafting_token_for_token(params):
             assert rel_err(rows_on[rid][pos], row) < ROUNDING, (rid, pos)
 
 
+def test_a_drafting_engine_keeps_no_step_in_flight(params):
+    """How far a sequence advances in a verify step is the device's
+    answer, so nothing is dispatched ahead of its fetch; the same model
+    served without its module runs ahead like any other."""
+    requests = [(p, SamplingParams(max_new_tokens=n))
+                for p, n in zip(prompts(11, 19), (9, 6))]
+    _, _, _, eng = serve(TINY, params, requests, capture=False)
+    log = eng.step_log()["steps"]
+    assert any(s["decodes"] for s in log) and eng._flight is None
+    assert all(s["ahead"] == 0 and s["ahead_rows_dropped"] == 0
+               for s in log)
+    stats = eng.stats()
+    assert (stats["decodes_ahead"], stats["decodes_drained"],
+            stats["ahead_rows_dropped"]) == (0, 0, 0)
+    _, _, _, plain = serve(TINY, params, requests, capture=False,
+                           drafting=False)
+    assert plain.stats()["decodes_ahead"] > 0
+    assert {s["ahead"] for s in plain.step_log()["steps"]} == {0, 1}
+
+
 def test_a_request_draws_the_same_alone_and_in_a_full_batch(params):
     hot = [(p, SamplingParams(max_new_tokens=12, temperature=1.0,
                               seed=40 + i))

@@ -331,6 +331,61 @@ class TestEventDrivenDelivery:
         lat = woke - put_at["t"]
         assert lat < 0.05, f"wakeup took {lat * 1e3:.1f}ms - not event-driven"
 
+    def test_a_put_wakes_the_threads_that_watch_it_and_no_other(
+            self, fabric):
+        """A waiter has an event of its own, which only a put of one of
+        its objects sets: with one condition for all of them, 64 token
+        streams woke 64 consumers for every token (PERF.md, PR 52)."""
+        import threading
+
+        import numpy as np
+
+        from raytpu.runtime import api
+        from raytpu.runtime.object_ref import ObjectRef
+        from raytpu.runtime.serialization import serialize
+        from raytpu.core.ids import ObjectID, TaskID
+
+        _, backend = api._worker_and_backend()
+        oids = [ObjectID.for_task_return(TaskID.from_random(), 1)
+                for _ in range(3)]
+        watched = [[oids[0]], [oids[0], oids[1]], [oids[2]]]
+        woke = [None] * len(watched)
+
+        def waiter(i):
+            woke[i] = backend.wait_any_object_ready(
+                [ObjectRef(o, _skip_refcount=True) for o in watched[i]],
+                timeout=30.0)
+
+        threads = [threading.Thread(target=waiter, args=(i,))
+                   for i in range(len(watched))]
+        for t in threads:
+            t.start()
+        deadline = time.monotonic() + 5.0
+        while sum(len(v) for v in backend._obj_watch.values()) < 4:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        events = {id(e) for v in backend._obj_watch.values() for e in v}
+        assert len(events) == 3  # one a waiter, under each object it watches
+        backend.store.put(oids[0], serialize(np.arange(4)))
+        threads[0].join(5.0)
+        threads[1].join(5.0)
+        assert woke[:2] == [True, True]
+        time.sleep(0.05)
+        assert threads[2].is_alive() and woke[2] is None
+        # Who has left watches nothing; who waits is still watched.
+        assert list(backend._obj_watch) == [oids[2]]
+        backend.store.put(oids[2], serialize(np.arange(4)))
+        threads[2].join(5.0)
+        assert woke[2] is True and not backend._obj_watch
+        # An object that is there is not waited for, one that never
+        # comes is waited for no longer than asked.
+        assert backend.wait_any_object_ready(
+            [ObjectRef(oids[2], _skip_refcount=True)], timeout=0.0) is True
+        t0 = time.monotonic()
+        assert backend.wait_any_object_ready(
+            [ObjectRef(oids[1], _skip_refcount=True)], timeout=0.1) is False
+        assert 0.09 <= time.monotonic() - t0 < 1.0 and not backend._obj_watch
+
     def test_stream_consume_latency(self, fabric):
         """Per-token delivery latency (yield -> consumer wakeup) stays in
         event-driven territory while the producer paces tokens out."""

@@ -262,7 +262,7 @@ class TestSteppingLoopStepLog:
             tokens = list(dep.generate([1, 2, 3], max_new_tokens=4))
             assert len(tokens) == 4
             deadline = time.monotonic() + 10
-            while len(dep.step_log()) < 4 and time.monotonic() < deadline:
+            while len(dep.step_log()) < 5 and time.monotonic() < deadline:
                 time.sleep(0.01)
             # With the engine lock held by someone else, as a step
             # holds it for as long as a compile.
@@ -276,7 +276,8 @@ class TestSteppingLoopStepLog:
             (steps,) = got
         finally:
             dep.shutdown()
-        assert len(steps) == 4  # one prefill, three decodes
+        # One prefill, three decodes, and the last one's fetch.
+        assert len(steps) == 5
         assert dep.step_log(last=2) == steps[-2:]
         assert dep.step_log(last=0) == []
         for step in steps:
@@ -285,18 +286,24 @@ class TestSteppingLoopStepLog:
             assert names[1] == "infer.schedule"
             wait = step["phases"][0]
             assert wait[1] <= wait[2] <= step["start"]
-        assert [s["decodes"] for s in steps] == [0, 1, 1, 1]
-        # A step's token is published while the next step runs on the
-        # device, as that step's wait begins; the last step's, which no
-        # launch follows, at once.
+        assert [s["decodes"] for s in steps] == [0, 1, 1, 1, 0]
+        assert [s["ahead"] for s in steps] == [0, 0, 1, 1, 0]
+        # A step's tokens are those of the decode the step before
+        # dispatched. They are published while the next step's decode
+        # runs on the device, as that step's wait begins (the first
+        # decode fetches none: the step after it has nothing to
+        # publish); the last step's, which no launch follows, at once.
         published = [[p for p in s["phases"] if p[0] == "serve.llm.publish"]
                      for s in steps]
-        assert [len(p) for p in published] == [0, 1, 1, 2]
-        for step, (publish, *_) in zip(steps[1:], published[1:]):
+        assert [len(p) for p in published] == [0, 1, 0, 1, 2]
+        for step, found in zip(steps[1:], published[1:]):
+            if not found:
+                continue
             at = {p[0]: p for p in step["phases"]}
-            wait = at["infer.decode.wait"]
-            assert at["infer.decode.launch"][2] <= wait[1] <= publish[1]
-            assert publish[2] <= wait[2]
+            wait, publish = at["infer.decode.wait"], found[0]
+            if "infer.decode.launch" in at:
+                assert at["infer.decode.launch"][2] <= wait[1]
+            assert wait[1] <= publish[1] and publish[2] <= wait[2]
         last = steps[-1]["phases"][-1]
         assert last[0] == "serve.llm.publish"
         assert steps[-1]["end"] <= last[1] <= last[2]
@@ -551,6 +558,55 @@ class TestAwaitedTokenStream:
             assert stats["published_queued"] == 12
             assert stats["publish_loop_calls"] == 12
             assert dep._streams == {}
+        finally:
+            dep.shutdown()
+
+    def test_a_streams_last_token_comes_a_call_after_its_last_dispatch(
+            self, reference):
+        """The engine keeps one decode in flight: a stream's last token
+        is fetched by the call after the one that dispatched its row,
+        which may dispatch nothing. Every token arrives, in order, and
+        the end is sent once, at once where no launch is left to wait
+        for."""
+        from raytpu.inference import serving
+
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            ends, send = [], dep._send
+
+            def counted(sends):
+                ends.extend(stream for stream, items in sends
+                            if items[-1] is serving._END)
+                return send(sends)
+
+            dep._send = counted
+            prompts = [[3, 1, 4, 1, 5], [9, 2, 6, 5, 3, 5, 8]]
+            new = (9, 4)
+            requests = [((p,), dict(max_new_tokens=n))
+                        for p, n in zip(prompts, new)]
+            ended = _consume_async(dep, requests)
+            for (err, seen), prompt, n in zip(ended, prompts, new):
+                assert err is None and seen == reference(prompt, n)
+            assert len(ends) == len(set(map(id, ends))) == 2
+            assert dep._streams == {} and dep._held == []
+            stats = dep.stats()
+            assert stats["published_tokens"] == sum(new)
+            assert stats["running"] == 0 and stats["decodes_ahead"] > 0
+            assert not dep._engine.has_unfinished()
+            steps = dep.step_log()
+            # The last step dispatched no decode and fetched one: it
+            # published the step before's tokens as its wait began, and
+            # its own, the stream's last, as soon as it had returned.
+            last = steps[-1]
+            assert last["decodes"] == 0 and last["ahead"] == 0
+            names = [p[0] for p in last["phases"]]
+            assert "infer.decode.launch" not in names
+            assert names.count("serve.llm.publish") == 2
+            assert names[-1] == "serve.llm.publish"
+            assert [p["tokens"] for p in last["publishes"]] == [1, 1]
+            # The shorter stream ended while the other ran on: its end
+            # went out in the wait of the step after its last fetch.
+            assert sum(s["ahead"] for s in steps) >= sum(new) - 2 - 6
         finally:
             dep.shutdown()
 

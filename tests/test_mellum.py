@@ -507,8 +507,10 @@ def test_a_step_of_two_kinds_is_handed_what_the_loop_built(params, chunk):
     for i, (prompt, n) in enumerate(zip(batch, new)):
         eng.add_request(f"r{i}", prompt, SamplingParams(max_new_tokens=n))
     while eng.has_unfinished():
+        # (A sequence whose last token is in flight takes no row.)
         decoding = [s.request_id for s in eng.scheduler.running
-                    if s.cached_len >= s.prefill_len]
+                    if s.cached_len >= s.prefill_len
+                    and not eng.scheduler._last_in_flight(s)]
         for o in eng.step():
             outs[o.request_id].append(o.token_id)
         batches.append(decoding)

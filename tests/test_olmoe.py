@@ -301,8 +301,12 @@ def test_engine_prefill_then_decode_matches_reference_in_float32(family, k):
     assert total.shape == (2, 8) and total.sum() == (21 + 5) * 2 * k
     steps = eng.step_log()["steps"]
     assert steps[0]["moe_assignments"] == 21 * 2 * k
+    # A decode's counts come back with its ids, in the step after its
+    # dispatch: none in the first decode's, the last in a step that
+    # dispatches nothing.
+    assert "moe_assignments" not in steps[1] and len(steps) == 1 + 5 + 1
     assert all(s["moe_assignments"] == 2 * k and s["moe_expert_max"] == 1
-               and s["moe_experts_touched"] == 2 * k for s in steps[1:])
+               and s["moe_experts_touched"] == 2 * k for s in steps[2:])
 
 
 def test_engine_chunked_prefill_matches_reference_in_float32(family):
@@ -330,11 +334,15 @@ def test_live_rows_counted_over_a_batch_of_three(family):
     prompts = [list(range(1, 10)), list(range(3, 20)), list(range(5, 12))]
     eng.generate(prompts, SamplingParams(max_new_tokens=4))
     # Bucket 4 holds 3 live rows: 3 decode steps of 3 rows, 2 layers, k=2.
-    decodes = [s for s in eng.step_log()["steps"] if s["decodes"] == 3
+    steps = eng.step_log()["steps"]
+    decodes = [s for s in steps if s["decodes"] == 3
                and not s.get("prefills")]
-    assert len(decodes) == 3
-    assert all(s["bucket"] == 4 and s["moe_assignments"] == 3 * 2 * 2
-               for s in decodes)
+    assert len(decodes) == 3 and all(s["bucket"] == 4 for s in decodes)
+    # Each one's counts are in the record of the step after it.
+    counted = [s for s in steps if not s.get("prefills")
+               and "moe_assignments" in s]
+    assert counted == steps[-3:] and all(
+        s["moe_assignments"] == 3 * 2 * 2 for s in counted)
     total = np.asarray(eng.stats()["expert_tokens"]).sum()
     assert total == (9 + 17 + 7 + 3 * 3) * 2 * 2
     # A dense family's engine has no count and its records no such field.
