@@ -5,7 +5,9 @@ Ray Train (``BASELINE.json`` north star; examples under
 ``doc/source/train/examples/deepspeed/``). This is the TPU-first redesign:
 bf16 params/activations with fp32 loss/optimizer math, flash attention
 (:mod:`raytpu.ops.flash_attention`), `jax.checkpoint` rematerialization per
-block, `lax.scan` over layers (one compiled block body instead of n_layer
+block (``remat=True`` keeps the flash kernel's residuals across the
+boundary and recomputes the rest: :func:`remat_block`), `lax.scan` over
+layers (one compiled block body instead of n_layer
 unrolled copies → fast compiles, same XLA code), and parameter names chosen
 to match ``TRANSFORMer_RULES`` (c_attn/c_proj/c_fc → TP column/row splits).
 """
@@ -32,7 +34,11 @@ class GPT2Config:
     dtype: Any = jnp.bfloat16
     # Rematerialization policy per block (memory <-> recompute-FLOPs knob):
     #   False/"none": save all activations (fastest when HBM allows)
-    #   True/"full":  save nothing, recompute the whole block (~+1/3 FLOPs)
+    #   True/"full":  save the flash kernel's residuals (remat_block: q, k,
+    #                 v, o, lse) and nothing else: the backward of a block
+    #                 recomputes its norms, output projection, c_fc and gelu
+    #                 (~+1/5 FLOPs) but neither the kernel nor the qkv
+    #                 projection and transposes that fed it
     #   "dots":       save matmul outputs only, recompute elementwise/norm/
     #                 attention-score work (few % extra FLOPs; the v5e sweet
     #                 spot — batch 16 no-remat OOMs 16.9G/15.75G HBM because
@@ -71,6 +77,26 @@ class GPT2Config:
         per_block = 12 * c.n_embd * c.n_embd
         return c.vocab_size * c.n_embd + c.block_size * c.n_embd + \
             c.n_layer * per_block + 2 * c.n_embd
+
+
+def remat_block(block, remat):
+    """``block`` under the config field ``remat``, for every family:
+    ``False``/``"none"`` is the block itself; ``True``/``"full"`` saves
+    the flash kernel's residuals (q, k, v, o and the log-sum-exp:
+    ``flash_attention.RESIDUAL_NAMES``) and recomputes the rest, so the
+    backward of a layer re-runs the norms, the output projection and
+    the feed-forward but neither the kernel nor what fed it;
+    ``"dots"`` saves the matmul outputs."""
+    if not remat or remat == "none":
+        return block
+    if remat == "dots":
+        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    else:
+        from raytpu.ops.flash_attention import RESIDUAL_NAMES
+
+        policy = jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES)
+    return nn.remat(block, prevent_cse=False, policy=policy)
 
 
 class CausalSelfAttention(nn.Module):
@@ -177,12 +203,7 @@ class GPT2(nn.Module):
         x = x + nn.Embed(c.block_size, c.n_embd, dtype=c.dtype,
                          name="wpe")(pos)
 
-        block = Block
-        if c.remat and c.remat != "none":
-            policy = None  # save nothing
-            if c.remat == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            block = nn.remat(Block, prevent_cse=False, policy=policy)
+        block = remat_block(Block, c.remat)
         if c.scan_layers:
             x, _ = nn.scan(
                 lambda mdl, carry, _: (mdl(carry, deterministic), None),

@@ -26,7 +26,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raytpu.models.gpt2 import Serving, cast_leaves, write_prompt_rows
+from raytpu.models.gpt2 import (Serving, cast_leaves, remat_block,
+                                write_prompt_rows)
 
 # The two kinds of attention layer, as a published ``layer_types`` names
 # them, and where each stands in what is given a kind (a cache's tables,
@@ -468,12 +469,7 @@ class Llama(nn.Module):
         embed = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
                          param_dtype=c.param_dtype, name="embed_tokens")
         x = embed(tokens)
-        block = LlamaBlock
-        if c.remat and c.remat != "none":
-            policy = None
-            if c.remat == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            block = nn.remat(LlamaBlock, prevent_cse=False, policy=policy)
+        block = remat_block(LlamaBlock, c.remat)
         if c.scan_layers and not c.layer_types:
             x, _ = nn.scan(
                 lambda mdl, carry, _: (mdl(carry), None),

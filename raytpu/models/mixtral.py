@@ -60,7 +60,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raytpu.models.gpt2 import Drafting, write_prompt_rows
+from raytpu.models.gpt2 import Drafting, remat_block, write_prompt_rows
 from raytpu.models.llama import (CONV, FULL, WINDOW, LlamaConfig, LlamaMLP,
                                  RMSNorm, Rope, _lm_logits, _serve,
                                  live_rows, of_kind, op_name)
@@ -718,12 +718,7 @@ class Mixtral(nn.Module):
         embed = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
                          param_dtype=c.param_dtype, name="embed_tokens")
         x = embed(tokens)
-        block = MixtralBlock
-        if c.remat and c.remat != "none":
-            policy = None
-            if c.remat == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            block = nn.remat(MixtralBlock, prevent_cse=False, policy=policy)
+        block = remat_block(MixtralBlock, c.remat)
         if c.scan_layers and not c.layer_types and not c.first_dense:
             x, _ = nn.scan(
                 lambda mdl, carry, _: (mdl(carry), None),

@@ -59,6 +59,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec
 
 
@@ -762,23 +763,40 @@ _BHTD = "bh.."  # per_shard layout of q/k/v/o/g
 _BHT = "bh."  # and of lse [B, H, T]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, use_pallas, window=None):
-    o, _ = _flash_fwd(q, k, v, causal, sm_scale, use_pallas, window)
-    return o
+# What a block under ``jax.checkpoint`` keeps of this op
+# (``raytpu.models.gpt2.remat_block`` saves these names and nothing
+# else): the five arrays ``_flash_bwd`` takes. With them saved the
+# backward of a layer runs neither the forward kernel nor the projection
+# and transposes that made q, k and v a second time.
+RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_o", "flash_lse")
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, use_pallas, window=None):
+def _flash_run(q, k, v, causal, sm_scale, use_pallas, window):
+    """``(o, lse [B, H, T])`` by the implementation ``use_pallas``."""
     if use_pallas in ("tpu", "interpret"):
-        o, lse = per_shard(
+        return per_shard(
             functools.partial(
                 _flash_forward_pallas, causal=causal, sm_scale=sm_scale,
                 block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                 interpret=(use_pallas == "interpret"), window=window),
             (q, k, v), (_BHTD,) * 3, (_BHTD, _BHT))
-    else:
-        o, lse = _attn_fwd_reference(q, k, v, causal, sm_scale, window)
-        lse = lse[..., 0]
+    o, lse = _attn_fwd_reference(q, k, v, causal, sm_scale, window)
+    return o, lse[..., 0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, use_pallas, window=None):
+    return _flash_run(q, k, v, causal, sm_scale, use_pallas, window)[0]
+
+
+def _flash_fwd(q, k, v, causal, sm_scale, use_pallas, window=None):
+    o, lse = _flash_run(q, k, v, causal, sm_scale, use_pallas, window)
+    # Named here, inside the rule and after ``per_shard`` returned the
+    # global arrays: a name on the layer's output alone would leave lse
+    # unsaved and the kernel would run again. Outside a checkpoint a
+    # name is the identity.
+    q, k, v, o, lse = (checkpoint_name(x, name) for x, name
+                       in zip((q, k, v, o, lse), RESIDUAL_NAMES))
     return o, (q, k, v, o, lse)
 
 

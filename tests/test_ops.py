@@ -331,6 +331,106 @@ _ROUTED_CASES = {
 }
 
 
+class TestRematKeepsTheKernelsResiduals:
+    """``remat=True`` saves what ``_flash_bwd`` takes of the forward
+    (``flash_attention.RESIDUAL_NAMES``) and recomputes the rest of the
+    block, so the gradient of a layer holds the forward kernel once
+    (forward, dq, dk/dv: 3 calls) where saving nothing holds it twice
+    (4), and the mathematics is the same to the last bit."""
+
+    LAYERS = 3
+
+    @staticmethod
+    def saves_nothing(block, remat):
+        """The tree's ``remat_block`` before PR 54."""
+        import flax.linen as nn
+
+        if not remat or remat == "none":
+            return block
+        policy = None
+        if remat == "dots":
+            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        return nn.remat(block, prevent_cse=False, policy=policy)
+
+    def value_and_grad(self, remat, scan):
+        import dataclasses
+
+        from raytpu.models.gpt2 import GPT2, GPT2Config, gpt2_loss_fn
+
+        cfg = dataclasses.replace(
+            GPT2Config.tiny(), n_layer=self.LAYERS, attn_impl="interpret",
+            remat=remat, scan_layers=scan)
+        model = GPT2(cfg)
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 128), 0,
+                                    cfg.vocab_size, jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+        fn = jax.value_and_grad(lambda p: gpt2_loss_fn(model, p, tokens))
+        return fn, params
+
+    @pytest.mark.parametrize("scan", [True, False],
+                             ids=["scanned", "unrolled"])
+    def test_one_forward_kernel_a_layer_and_the_same_bits(self, scan,
+                                                          monkeypatch):
+        from raytpu.models import gpt2
+
+        # A scanned body is in the jaxpr once, an unrolled layer each time.
+        layers = 1 if scan else self.LAYERS
+        got = {}
+        for name, remat in (("kept", True), ("none", "none"),
+                            ("nothing", True)):
+            if name == "nothing":
+                monkeypatch.setattr(gpt2, "remat_block", self.saves_nothing)
+            fn, params = self.value_and_grad(remat, scan)
+            calls = len(list(_pallas_calls(jax.make_jaxpr(fn)(params).jaxpr)))
+            got[name] = (calls, jax.jit(fn)(params))
+        assert got["kept"][0] == 3 * layers
+        assert got["none"][0] == 3 * layers
+        assert got["nothing"][0] == 4 * layers
+        for other in ("none", "nothing"):
+            for a, b_ in zip(jax.tree.leaves(got["kept"][1]),
+                             jax.tree.leaves(got[other][1])):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+    @pytest.mark.parametrize("remat", ["dots", "none", False])
+    def test_the_other_policies_build_what_they_built(self, remat,
+                                                      monkeypatch):
+        from raytpu.models import gpt2
+
+        fn, params = self.value_and_grad(remat, True)
+        now = str(jax.make_jaxpr(fn)(params))
+        monkeypatch.setattr(gpt2, "remat_block", self.saves_nothing)
+        fn, params = self.value_and_grad(remat, True)
+        assert str(jax.make_jaxpr(fn)(params)) == now
+
+    def test_true_and_full_are_one_policy_in_the_three_families(self):
+        import flax.linen as nn
+
+        from raytpu.models import gpt2, llama, mixtral
+        from raytpu.ops.flash_attention import RESIDUAL_NAMES
+
+        assert llama.remat_block is mixtral.remat_block is gpt2.remat_block
+        assert gpt2.remat_block(gpt2.Block, False) is gpt2.Block
+        assert gpt2.remat_block(gpt2.Block, "none") is gpt2.Block
+        for remat in (True, "full", "dots"):
+            assert issubclass(gpt2.remat_block(gpt2.Block, remat), nn.Module)
+        assert RESIDUAL_NAMES == ("flash_q", "flash_k", "flash_v", "flash_o",
+                                  "flash_lse")
+
+    def test_under_a_mesh_the_names_are_on_the_global_arrays(self):
+        """``xl-train-fsdp4``'s form: the kernels run per shard, the
+        residuals are named after ``per_shard`` returned."""
+        mesh = build_mesh({"fsdp": 4}, jax.devices()[:4])
+        with jax.set_mesh(mesh):
+            fn, params = self.value_and_grad("none", True)
+            ref = jax.jit(fn)(params)
+            fn, params = self.value_and_grad(True, True)
+            calls = len(list(_pallas_calls(jax.make_jaxpr(fn)(params).jaxpr)))
+            got = jax.jit(fn)(params)
+        assert calls == 3
+        for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
 class TestTpuLowering:
     """Every Pallas kernel must lower for the TPU from a CPU host, alone
     and from a program sharded over four devices."""

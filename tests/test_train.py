@@ -285,6 +285,61 @@ class TestGPT2Model:
             losses.append(float(loss))
         assert losses[-1] < losses[0]
 
+    @staticmethod
+    def tiny_cell_losses(steps=4):
+        """The losses of the benchmark's tiny training cell
+        (``perfbench/tests/rehearsal``: ``remat: true``, the kernels
+        interpreted, AdamW), built as ``train_cell`` builds a cell."""
+        import os
+
+        from perfbench import byname, train_cell
+
+        bench = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "perfbench")
+        dirs = [os.path.join(bench, "tests", "rehearsal"), bench]
+        cfg = byname.load_json(dirs, "configs", "tiny-gpt2")
+        mix = byname.load_json(dirs, "traffic", "tiny-train")
+        built = train_cell.build(byname.load_family(dirs, cfg), cfg, mix,
+                                 jax.devices()[:1],
+                                 dict(mix["model_overrides"]))
+        assert built["config"].remat is True
+        key = jax.random.PRNGKey(7)
+        params, opt_state = built["init"](key)
+        losses = []
+        for i in range(steps):
+            params, opt_state, loss = built["step"](params, opt_state,
+                                                    built["batch"](key, i))
+            losses.append(float(loss))
+        return losses
+
+    def test_what_remat_keeps_does_not_change_the_mathematics(
+            self, monkeypatch):
+        """The tiny training cell's first-step loss and its loss after
+        three steps are those of the tree before PR 54, whose
+        ``remat: true`` saved nothing (a run of commit 96a0a01 here:
+        6.71872615814209, 6.712253570556641, 6.674738883972168,
+        6.639933109283447). The first is a forward pass and is held to
+        the last bit; the later ones move in the last bit with the CPU
+        backend's thread count (one core: ...eee, ...4a8), so against the
+        recorded values they are held to two units of it, and to the last
+        bit against the saving of nothing built in this process."""
+        import flax.linen as nn
+
+        from raytpu.models import gpt2
+
+        recorded = [float.fromhex(h) for h in (
+            "0x1.adff9c0000000p+2", "0x1.ad95900000000p+2",
+            "0x1.ab2eec0000000p+2", "0x1.a8f4aa0000000p+2")]
+        kept = self.tiny_cell_losses()
+        assert kept[0] == recorded[0]
+        np.testing.assert_allclose(kept, recorded, rtol=2 * 2.0 ** -23,
+                                   atol=0)
+        monkeypatch.setattr(
+            gpt2, "remat_block",
+            lambda block, remat: nn.remat(block, prevent_cse=False,
+                                          policy=None))
+        assert self.tiny_cell_losses() == kept
+
     def test_sharded_train_step_8dev(self):
         """Milestone B shape: GPT-2 with dp x fsdp x tp sharding on the
         virtual 8-device mesh."""
