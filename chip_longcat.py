@@ -140,10 +140,18 @@ def reference_rows(compiled, family, cfg, params, prompt, sampled):
 
 
 def compare(family, cfg, pcfg, params, prompts, options, engines, compiled,
-            controls, label) -> dict:
+            controls, label, *, serve=None, wrong=None,
+            program_controls=None, log=None) -> dict:
+    """One seed's readings. ``serve``, ``wrong``, ``program_controls`` and
+    ``log``: another cell's engine wrapper, wrong configs, their names and
+    its log line (``chip_glm5.py``); this module's by default."""
+    serve, wrong = serve or Served, wrong or wrong_config
+    program_controls = program_controls or PROGRAM_CONTROLS
+    log = log or globals()["log"]
+
     def served(name, config):
         if name not in engines:
-            engines[name] = Served(config, params, options)
+            engines[name] = serve(config, params, options)
         return engines[name]
 
     got, sampled, facts = served("right", pcfg).run(params, prompts)
@@ -158,8 +166,8 @@ def compare(family, cfg, pcfg, params, prompts, options, engines, compiled,
         return out
     forced = [list(p) + list(s[:DECODES]) for p, s in zip(prompts, sampled)]
     for control in ("forced",) + tuple(controls):
-        if control in PROGRAM_CONTROLS:
-            eng, tree = served(control, wrong_config(pcfg, control)), params
+        if control in program_controls:
+            eng, tree = served(control, wrong(pcfg, control)), params
         else:
             eng = served("right", pcfg)
             tree = rounded_to_float8(params) if control == "float8" \

@@ -142,6 +142,12 @@ _moe_pairs_total = Counter(
 _moe_zero_pairs_total = Counter(
     "raytpu_infer_moe_zero_pairs_total",
     "Of those pairs, the ones that chose an identity expert")
+_dsa_scored_total = Counter(
+    "raytpu_infer_dsa_rows_scored_total",
+    "Cached positions an indexer scored, a layer's, over the queries run")
+_dsa_selected_total = Counter(
+    "raytpu_infer_dsa_rows_selected_total",
+    "Of those positions, the ones the queries' attention then read")
 _ttft_hist = Histogram(
     "raytpu_infer_ttft_seconds",
     "Time from request admission to its first sampled token",
@@ -270,7 +276,9 @@ class InferenceEngine:
     has the copy's bytes by dtype, ``stats()["kv_pool_bytes"]`` the
     bytes of the 2 x layers KV pools (``kv_pool_bytes_by_kind``: of the
     full and of the window layers'), or of a latent-attention model's
-    one pool a layer (``serving.kv_row``: ``cache.v`` is then empty),
+    one pool a layer (``serving.kv_row``: ``cache.v`` is then empty, or
+    holds the index keys of a model whose attention chooses its rows,
+    ``serving.indexer``),
     ``stats()["state_bytes"]`` those of the state arrays of the layers
     that keep a state and no pool (``serving.layer_states``).
 
@@ -310,9 +318,9 @@ class InferenceEngine:
                 "its expert layer (tp, ep) in the engine is not there yet")
         if served.kv_row and (tp > 1 or mesh is not None):
             raise ValueError(
-                "a model of one latent pool a layer is served on one "
-                "device: every head reads the whole row, so the pool has "
-                "no head axis to shard on")
+                "a model of latent pools is served on one device: every "
+                "head reads the whole row (and an indexer's heads the "
+                "whole index key), so a pool has no head axis to shard on")
 
         if served.layer_states and enable_prefix_cache:
             raise ValueError(
@@ -343,6 +351,10 @@ class InferenceEngine:
         # the live (token, choice) pairs that chose one, and all of them.
         self._moe_pairs = ({"moe_zero_pairs": 0, "moe_pairs": 0}
                            if served.expert_pairs else None)
+
+        # Rows a query's attention keeps of those its indexer scores
+        # (``serving.indexer``); None: it reads every cached row.
+        self._index_topk = served.indexer[1] if served.indexer else None
 
         self._config = model_config
         # The working copy, made once and before anything else takes
@@ -404,6 +416,7 @@ class InferenceEngine:
                 *served.layer_windows, *(None,) * module_pools),
             window_pages=window_pages,
             window_burst=self.prefill_chunk, latent_row=served.kv_row,
+            index_row=served.indexer[0] if served.indexer else None,
             state_shapes=state_shapes,
             seats=max_num_seqs if state_shapes or self._drafting else 0)
         # Tensor parallelism: shard the weights with the parallel-layer
@@ -444,10 +457,14 @@ class InferenceEngine:
         self._kv_pool_bytes = sum(
             a.nbytes for a in self.cache.k + self.cache.v)
         self._two_kinds = len(self.cache.kinds) > 1
-        pools_a_layer = 2 if self.cache.v else 1  # K and V, or a latent
+        # A layer's K and V, its one latent pool, or that and its index
+        # keys' (``cache.v`` empty, or a pool a layer).
+        second = self.cache.v or [None] * len(self.cache.k)
         self._kv_pool_bytes_by_kind = {
-            name: sum(pools_a_layer * k.nbytes for k, of in zip(
-                self.cache.k, self.cache.layer_kinds) if of == kind)
+            name: sum(k.nbytes + (v.nbytes if v is not None else 0)
+                      for k, v, of in zip(self.cache.k, second,
+                                          self.cache.layer_kinds)
+                      if of == kind)
             for kind, name in enumerate(("full", "window"))}
         # A token's bytes in the pools of every layer, as held (a latent
         # row is held on whole tiles).
@@ -845,6 +862,25 @@ class InferenceEngine:
             return of(0)
         return tuple(of(kind) for kind in self.cache.kinds)
 
+    def _count_chosen(self, positions) -> None:
+        """Add to the open step's record and to the running totals what
+        the indexers of one layer did for queries at the absolute
+        ``positions``: ``dsa_rows_scored``, the cached positions each
+        scored (its own among them), and ``dsa_rows_selected``, the
+        ``index_topk`` at most its attention then read. Known to the
+        host: a query's context is its position. A model without an
+        indexer has neither field."""
+        if self._index_topk is None:
+            return
+        cached = np.asarray(positions, np.int64) + 1
+        fields = self.recorder.open.fields
+        for name, total, more in (
+                ("dsa_rows_scored", _dsa_scored_total, int(cached.sum())),
+                ("dsa_rows_selected", _dsa_selected_total,
+                 int(np.minimum(cached, self._index_topk).sum()))):
+            fields[name] = fields.get(name, 0) + more
+            total.inc(more)
+
     def _count_experts(self, experts, *programs) -> None:
         """Add what the programs of a routed model returned beside their
         logits (int32 ``[layers, experts]``: live tokens each expert held
@@ -973,7 +1009,9 @@ class InferenceEngine:
                 "ahead": 0, "ahead_rows_dropped": 0,
                 "kv_bytes_per_token": self._kv_token_bytes} | (
                     {"drafted": 0, "accepted": 0, "emitted": 0}
-                    if self._drafting else {})) as st:
+                    if self._drafting else {}) | (
+                    {"dsa_rows_scored": 0, "dsa_rows_selected": 0}
+                    if self._index_topk else {})) as st:
             if self._flight is not None and self.scheduler.pages_short():
                 # It would preempt: a victim re-prefills ``tokens``, which
                 # the fetch completes, and an ended sequence's pages may
@@ -1114,6 +1152,7 @@ class InferenceEngine:
         # of a whole prompt's (flash attention, no pool read) only what
         # the decode after it will see.
         end = start + take
+        self._count_chosen(np.arange(start, end))
         released = self.cache.slide(seq.request_id,
                                     end if whole else start, end)
         dests = self._by_kind(lambda kind: self.cache.chunk_dests(
@@ -1216,6 +1255,7 @@ class InferenceEngine:
         if self._two_kinds:
             fields["live_pages_window"] += int(
                 cache.pages_read(newest, 1).sum())
+        self._count_chosen(written[:b])
         return (ids, bucket, P, tokens, positions, dests,
                 self._batch_tables(seqs, ids, rows, P))
 
@@ -1588,7 +1628,11 @@ class InferenceEngine:
         the experts held here are a share of the router's, a pair whose
         expert is not held is in none of them (no row was computed for
         it). ``kv_bytes_per_token`` is what one token costs in the pools
-        of every layer, as held.
+        of every layer, as held. A model whose attention reads the rows
+        an indexer chooses (``serving.indexer``) also carries
+        ``dsa_rows_scored`` and ``dsa_rows_selected``: the cached
+        positions one layer's indexers scored for the step's queries,
+        decoded and prefilled, and those their attention then read.
         ``"oldest_start"`` is the start of the oldest step still held,
         so a reader can tell a truncated log from a quiet engine. Call
         it from the thread that steps, or under the lock that

@@ -24,7 +24,11 @@ array read out of them before a step is deleted after it.
 A latent-attention model (``latent_row``) has one pool a layer, not a K
 and a V: a token's row holds the compressed latent its keys and values
 are both computed from, so ``v`` is empty and everything else here, which
-deals in pages and not in what a row holds, is unchanged.
+deals in pages and not in what a row holds, is unchanged. Where its
+attention chooses the rows it reads (``index_row``), ``v`` holds a second
+pool a layer, the indexer's keys, ``index_row`` wide: two pools of unequal
+row width under one block table, so a page shared, freed or recomputed is
+a page of both.
 
 Page 0 is reserved as *scratch*: it is never handed to a sequence, and
 every padded slot in a bucketed prefill or dummy row in a padded decode
@@ -138,6 +142,9 @@ class PagedKVCache:
             latent_row]``, from whose rows keys and values are both
             read: ``k`` holds it and ``v`` is empty. Tables, slots and
             the free list are what they are for any model of one kind.
+        index_row: with ``latent_row``: each layer also has a pool of
+            index keys ``[num_pages, page_size, index_row]``, which ``v``
+            holds, under the latent pool's own tables and slots.
         state_shapes: the shape of the state a sequence keeps in each
             layer that has one and no pool (``num_layers`` counts the
             layers with pools alone); needs ``seats``.
@@ -150,6 +157,7 @@ class PagedKVCache:
                  layer_windows: Sequence[Optional[int]] = (),
                  window_pages: Optional[int] = None, window_burst: int = 1,
                  latent_row: Optional[int] = None,
+                 index_row: Optional[int] = None,
                  state_shapes: Sequence[Sequence[int]] = (), seats: int = 0):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is scratch)")
@@ -187,13 +195,18 @@ class PagedKVCache:
                     f"is scratch")
         if latent_row and self.window is not None:
             raise ValueError("a latent pool has no window layers")
+        if index_row and not latent_row:
+            raise ValueError("index keys stand beside a latent pool")
         self.latent_row = latent_row
         width = latent_row or num_kv_heads * head_dim
-        shapes = [(self.num_window_pages if kind else num_pages, page_size,
-                   width) for kind in self.layer_kinds]
-        self.k: List = [jnp.zeros(shape, self.dtype) for shape in shapes]
-        self.v: List = [] if latent_row else [
-            jnp.zeros(shape, self.dtype) for shape in shapes]
+        # What ``v`` holds a row of: V, nothing, or a latent layer's
+        # index keys.
+        v_width = index_row if latent_row else width
+        self.k: List = [jnp.zeros(
+            (self.num_window_pages if kind else num_pages, page_size, width),
+            self.dtype) for kind in self.layer_kinds]
+        self.v: List = [jnp.zeros(k.shape[:2] + (v_width,), self.dtype)
+                        for k in self.k] if v_width else []
         if state_shapes and seats < 1:
             raise ValueError("a layer that keeps a state needs `seats`")
         # One state array a layer that keeps one; row 0 is scratch.
@@ -258,7 +271,8 @@ class PagedKVCache:
     @property
     def token_bytes(self) -> int:
         """Bytes one token costs in the pools of every layer, as held: a
-        row of K and one of V a layer, or a latent layer's one row."""
+        row of K and one of V a layer, or a latent layer's one row (and
+        its index key, where it has an indexer)."""
         return sum(a.shape[2] * a.dtype.itemsize for a in self.k + self.v)
 
     def seats_in_use(self) -> int:

@@ -52,8 +52,13 @@ no prefix cache and no role: a prefix's pages say nothing of the state
 at its end) and "longcat_flash" (two latent-attention sublayers a layer,
 so two latent pools, and one routed layer beside them on a shortcut whose
 router also scores identity experts; as "joyai", the prefix cache works
-over its pages and a role is refused). Each reaches the
-engine through its config's ``serving`` and nothing else.
+over its pages and a role is refused) and "glm_moe_dsa" (latent
+attention that reads only the cached positions a learned indexer
+chooses: two pools a layer of unequal row width under one block table,
+the latent rows and the index keys; three leading dense layers and
+"joyai"'s routed layer; the prefix cache shares a page of both pools at
+once, a preempted sequence recomputes both, a role is refused). Each
+reaches the engine through its config's ``serving`` and nothing else.
 """
 
 from __future__ import annotations
@@ -321,11 +326,12 @@ class LLMDeployment:
 
     Args:
         model: "llama", "gpt2", "mixtral", "olmoe", "mellum", "joyai",
-            "exaone_moe", "lfm2_moe" or "longcat_flash".
+            "exaone_moe", "lfm2_moe", "longcat_flash" or "glm_moe_dsa".
         model_config: the family's config (``LlamaConfig``,
             ``GPT2Config``, ``MixtralConfig``, ``OlmoeConfig``,
             ``MellumConfig``, ``JoyAIConfig``, ``ExaoneMoeConfig``,
-            ``Lfm2MoeConfig``, ``LongcatFlashConfig``) or a
+            ``Lfm2MoeConfig``, ``LongcatFlashConfig``, ``GlmDsaConfig``)
+            or a
             kwargs dict for one. Defaults to the family's ``tiny()``
             config in fp32/reference-attention mode (CPU-runnable).
         engine_options: kwargs forwarded to :class:`InferenceEngine`
@@ -343,7 +349,8 @@ class LLMDeployment:
             with window layers takes no role: what is handed off are
             prefix-cache pages, and it is served without that cache.
             Nor does a latent-attention model ("joyai",
-            "longcat_flash": one pool an attention): the hand-off's wire
+            "longcat_flash": one pool an attention; "glm_moe_dsa": a
+            latent pool and an index-key pool): the hand-off's wire
             segments are pages of K and of V, ``kv_heads * head_dim``
             wide. Nor does a model with layers
             that keep a state ("lfm2_moe"): the state at a prefix's end
@@ -366,7 +373,7 @@ class LLMDeployment:
 
             cfg_cls, model_cls, init = GPT2Config, GPT2, init_params
         elif model in ("mixtral", "olmoe", "mellum", "joyai", "exaone_moe",
-                       "lfm2_moe", "longcat_flash"):
+                       "lfm2_moe", "longcat_flash", "glm_moe_dsa"):
             from raytpu.models import mixtral
 
             cfg_cls = {"mixtral": mixtral.MixtralConfig,
@@ -375,13 +382,15 @@ class LLMDeployment:
                        "joyai": mixtral.JoyAIConfig,
                        "exaone_moe": mixtral.ExaoneMoeConfig,
                        "lfm2_moe": mixtral.Lfm2MoeConfig,
-                       "longcat_flash": mixtral.LongcatFlashConfig}[model]
+                       "longcat_flash": mixtral.LongcatFlashConfig,
+                       "glm_moe_dsa": mixtral.GlmDsaConfig}[model]
             model_cls, init = mixtral.Mixtral, mixtral.init_params
         else:
             raise ValueError(f"unknown model family: {model!r}; known: "
                              f"'llama', 'gpt2', 'mixtral', 'olmoe', "
                              f"'mellum', 'joyai', 'exaone_moe', "
-                             f"'lfm2_moe', 'longcat_flash'")
+                             f"'lfm2_moe', 'longcat_flash', "
+                             f"'glm_moe_dsa'")
         if model_config is None:
             model_config = dataclasses.replace(
                 cfg_cls.tiny(), dtype=jnp.float32, attn_impl="reference",
@@ -407,7 +416,8 @@ class LLMDeployment:
                 f"role={role!r}: a latent-attention model is not served "
                 f"disaggregated: the hand-off's wire segments are pages of "
                 f"K and of V, kv_heads * head_dim wide, and its layers "
-                f"hold one latent pool")
+                f"hold a latent pool (and an indexer's keys, where it "
+                f"has one)")
         if role is not None and self._engine.cache.state:
             raise ValueError(
                 f"role={role!r}: a model with layers that keep a state is "

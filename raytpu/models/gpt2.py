@@ -404,6 +404,15 @@ class Serving:
     back ``k_caches`` one pool a layer and ``v_caches`` empty.
     ``kv_heads`` and ``head_dim`` are then the query's, and size no pool.
 
+    A latent layer with an indexer (``indexer``: ``(index_row,
+    index_topk)``) holds a second pool under the same block tables and
+    ``dests``: one index key a token, ``index_row`` features wide, from
+    which a query's indexer chooses the ``index_topk`` cached positions
+    it attends. The programs then take and give back the latent pools as
+    ``k_caches`` and the index-key pools as ``v_caches``, one of each a
+    layer, of unequal row width. A page is a page of both, so whatever
+    deals in pages (the prefix cache, preemption) deals in both at once.
+
     A layer that keeps a state (``layer_states``: one entry a layer of
     the model, ``None`` for a layer with a pool, else the shape of what a
     sequence keeps there, a short convolution's ``(taps - 1, width)``):
@@ -431,6 +440,7 @@ class Serving:
     expert_pairs: bool = False
     layer_windows: Tuple[Optional[int], ...] = ()
     kv_row: Optional[int] = None
+    indexer: Optional[Tuple[int, int]] = None  # (index_row, index_topk)
     layer_states: Tuple[Optional[Tuple[int, ...]], ...] = ()
     # Not None: the family drafts for itself (a prediction module), and
     # an engine built with drafting on runs these and not the two above.

@@ -630,7 +630,8 @@ def _serve(c: LlamaConfig, params, x, live, cache_args, whole: bool = False,
     E], a row a position, or through ``prefill`` of a ``whole`` prompt
     from position 0 over ``x`` [1, T, E], which returns its
     output and the layer's K and V (rows, or the pools it wrote), or
-    the one pool of a latent layer (``V list`` is then empty), or a CONV
+    the one pool of a latent layer (``V list`` is then empty; with an
+    indexer it holds the layer's index keys), or a CONV
     layer's state array, written;
     ``live`` (``x``'s leading shape) marks the rows that are tokens, for
     :func:`_feed_forward`. Returns ``(fp32 logits, K list, V list)``,
@@ -683,8 +684,9 @@ def _serve(c: LlamaConfig, params, x, live, cache_args, whole: bool = False,
 
 
 def _pools(k_caches, v_caches, i: int):
-    """Pool ``i`` as an attention module takes it: K and V, or the one
-    pool of a latent layer (``v_caches`` is then empty)."""
+    """Pool ``i`` as an attention module takes it: K and V (a latent
+    layer with an indexer: its latent pool and its index keys), or the
+    one pool of a latent layer (``v_caches`` is then empty)."""
     return (k_caches[i], v_caches[i]) if v_caches else (k_caches[i],)
 
 

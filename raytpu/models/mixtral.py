@@ -48,6 +48,11 @@ routed layer beside the first feed-forward whose output is added at the
 layer's end (a shortcut: ``shortcut_to``); its router scores identity
 experts beside the real ones (``n_zero_expert``), which return their
 input and cost no product.
+:class:`GlmDsaConfig` is GLM-5's: latent attention whose queries read
+only the cached positions a learned indexer chooses
+(:class:`raytpu.models.mla.SparseLatentAttention`), the index keys in a
+pool of their own beside the latent pool, three leading dense layers and
+JoyAI's routed layer.
 """
 
 from __future__ import annotations
@@ -384,6 +389,86 @@ class LongcatFlashConfig(LatentMoEConfig):
                    n_zero_expert=16, n_expert_per_tok=6, dense_inter=96,
                    q_lora_rank=48, kv_lora_rank=128, qk_nope_dim=16,
                    qk_rope_dim=8, v_head_dim=16, choice_bias=0.01)
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmDsaConfig(LatentMoEConfig):
+    """GLM-5 (``zai-org/GLM-5``, ``model_type: glm_moe_dsa``, 744B-A40B)
+    as published: 78 layers over a hidden size of 6,144 of latent
+    attention that reads only the cached positions a learned indexer
+    chooses (:class:`raytpu.models.mla.SparseLatentAttention`: 64 heads
+    of 192 not roped + 64 roped, values of 256, queries through a rank of
+    2,048, keys and values through a latent of 512, interleaved rope at
+    theta 1e6; the indexer 32 heads of 128 that keep the 2,048
+    best-scored positions). Layers 0-2 a dense SwiGLU of 12,288, the
+    others JoyAI's routed layer to the letter: 256 experts of 2,048
+    (``n_inter``), 8 a token by sigmoid score + a correction bias,
+    weights normalised and times 2.5, beside one shared expert. The
+    multi-token-prediction module and the indexer's training loss are
+    not built. A layer keeps two pools under one block table, the
+    latent rows and the index keys (``serving.indexer``). Layers are
+    held one tree each; ``head_dim`` sizes nothing here."""
+
+    vocab_size: int = 154880
+    block_size: int = 202752
+    n_layer: int = 78
+    n_head: int = 64
+    n_kv_head: int = 64
+    n_embd: int = 6144
+    head_dim: int = 64
+    n_inter: int = 2048
+    n_expert: int = 256
+    n_expert_per_tok: int = 8
+    norm_topk_prob: bool = True
+    norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    scoring: str = "sigmoid"
+    choice_bias: float = 0.0
+    routed_scale: float = 2.5
+    n_shared: int = 1
+    first_dense: int = 3
+    dense_inter: int = 12288
+    q_lora_rank: int = 2048
+    qk_nope_dim: int = 192
+    v_head_dim: int = 256
+    # The indexer: a query keeps the ``index_topk`` cached positions its
+    # ``index_n_head`` heads of ``index_head_dim`` score best.
+    index_topk: int = 2048
+    index_n_head: int = 32
+    index_head_dim: int = 128
+    index_rope_interleave: bool = True
+    index_norm_eps: float = 1e-6  # the index key's LayerNorm
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.index_head_dim < self.qk_rope_dim:
+            raise ValueError(
+                f"an index key of {self.index_head_dim} values has no "
+                f"room for the {self.qk_rope_dim} that are roped")
+
+    def attention(self, kind: str = FULL, **kw):
+        from raytpu.models.mla import SparseLatentAttention
+
+        return SparseLatentAttention(self, **kw)
+
+    @property
+    def serving(self):
+        return dataclasses.replace(
+            super().serving, indexer=(self.index_head_dim, self.index_topk))
+
+    @classmethod
+    def tiny(cls) -> "GlmDsaConfig":
+        """A dense layer and two routed ones at toy widths, whose
+        indexer keeps 16 positions: a context of 96 is six times that.
+        The latent is 128 wide because the kernel slices values out of a
+        row by whole lane tiles."""
+        return cls(vocab_size=512, block_size=256, n_layer=3, n_head=4,
+                   n_kv_head=4, n_embd=64, head_dim=16, n_inter=32,
+                   n_expert=16, n_expert_per_tok=4, first_dense=1,
+                   dense_inter=96, q_lora_rank=48, kv_lora_rank=128,
+                   qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+                   choice_bias=0.01, index_topk=16, index_n_head=4,
+                   index_head_dim=16)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -914,3 +999,4 @@ JoyAI = Mixtral
 ExaoneMoe = Mixtral
 Lfm2Moe = Mixtral
 LongcatFlash = Mixtral
+GlmDsa = Mixtral

@@ -100,13 +100,16 @@ class LatentAttention(nn.Module):
     def __call__(self, x):
         return self.prefill(x)[0]
 
-    def _project(self, x):
+    def _project(self, x, c_q=None):
         """``x`` [..., E] -> ``(q_nope [..., H, nope], q_pe [..., H, rope],
         c_kv [..., rank] normed, k_pe [..., rope])``, nothing roped yet
         (the roped parts de-interleaved, the latents scaled, where the
-        config says so)."""
+        config says so). ``c_q``: the normed query latent, where the
+        caller has it already."""
         c = self.config
-        q = self.q_b_proj(self.q_a_norm(self.q_a_proj(x)))
+        if c_q is None:
+            c_q = self.q_a_norm(self.q_a_proj(x))
+        q = self.q_b_proj(c_q)
         q = q.reshape(x.shape[:-1]
                       + (c.n_head, c.qk_nope_dim + c.qk_rope_dim))
         q_nope, q_pe = q[..., :c.qk_nope_dim], q[..., c.qk_nope_dim:]
@@ -184,3 +187,158 @@ class LatentAttention(nn.Module):
                            q_pe.reshape(b, t, c.n_head, -1), pages,
                            block_tables, positions)
         return self.o_proj(y.reshape(b * t, -1)), pages
+
+
+def _as_pool(x):
+    """``x`` [B, T, W], a row a position from 0, as a pool of pages and
+    the block tables under which sequence ``b``'s position ``p`` is row
+    ``p`` of its table: ``([B * n, page, W], int32 [B, n])``."""
+    b, t, w = x.shape
+    page = 128 if t % 128 == 0 else t
+    n = t // page
+    return (x.reshape(b * n, page, w),
+            jnp.arange(b * n, dtype=jnp.int32).reshape(b, n))
+
+
+class SparseLatentAttention(LatentAttention):
+    """Latent attention that reads only the cached positions a learned
+    *indexer* chooses (DeepSeek sparse attention; GLM-5's
+    ``glm_moe_dsa``). Beside ``LatentAttention``'s, ``config`` carries
+    ``index_topk``, ``index_n_head``, ``index_head_dim`` and
+    ``index_rope_interleave``.
+
+    The indexer takes the query latent the attention computes anyway:
+    ``q_I = W_Iqb c_q`` (``index_n_head`` heads of ``index_head_dim``),
+    one key a token ``k_I = LayerNorm(W_Ik x)`` (scale and bias), the
+    first ``qk_rope_dim`` values of each roped with the attention's own
+    tables, and head weights ``w = (W_Iw x) / sqrt(index_n_head *
+    index_head_dim)``. A query at ``t`` scores ``I[t, s] = sum_h w[t, h]
+    relu(q_I[t, h] . k_I[s])`` for ``s <= t`` and attends the
+    ``index_topk`` best-scored positions (:mod:`raytpu.ops.dsa_attention`;
+    a context no longer than that is attended whole, and the result is
+    ``LatentAttention``'s). The choice is made from float32 scores and
+    passes no gradient.
+
+    A layer keeps **two** pools under one block table: the latent rows
+    and the index keys, ``index_head_dim`` wide. ``step`` takes and
+    returns both (the serving walk's K and V places); ``prefill`` returns
+    both kinds of row. A whole prompt no longer than ``index_topk`` is
+    ``LatentAttention.prefill`` (flash attention, expanded); a longer one
+    is not causal-dense attention, and goes through the absorbed form
+    over its own rows as a pool."""
+
+    def setup(self):
+        super().setup()
+        c = self.config
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=c.dtype,
+                                  param_dtype=c.param_dtype)
+        self.index_q_proj = dense(c.index_n_head * c.index_head_dim)
+        self.index_k_proj = dense(c.index_head_dim)
+        self.index_k_norm = nn.LayerNorm(epsilon=c.index_norm_eps,
+                                         dtype=c.dtype)
+        self.index_w_proj = dense(c.index_n_head)
+
+    def _index_roped(self, v, cos, sin):
+        """``v`` [N, heads, index_head_dim] with its first ``qk_rope_dim``
+        values rotated by the attention's own tables."""
+        c = self.config
+        head, tail = v[..., :c.qk_rope_dim], v[..., c.qk_rope_dim:]
+        if c.index_rope_interleave:
+            head = deinterleave(head)
+        return jnp.concatenate([apply_rope_single(head, cos, sin), tail], -1)
+
+    def _index_keys(self, x, cos, sin):
+        """One roped index key a row of ``x`` [N, E] -> [N, D]."""
+        k = self.index_k_norm(self.index_k_proj(x))
+        return self._index_roped(k[:, None], cos, sin)[:, 0]
+
+    def _index_queries(self, x, c_q, cos, sin):
+        """``(q_I [N, Hi, D] roped, w [N, Hi] float32)`` of the rows
+        ``x`` [N, E] whose normed query latent is ``c_q``."""
+        c = self.config
+        q = self.index_q_proj(c_q).reshape(
+            x.shape[0], c.index_n_head, c.index_head_dim)
+        w = self.index_w_proj(x).astype(jnp.float32) \
+            * (c.index_n_head * c.index_head_dim) ** -0.5
+        return self._index_roped(q, cos, sin), w
+
+    def _rows(self, x, positions):
+        """Everything of the rows ``x`` [N, E] at ``positions`` [N] that
+        the absorbed form takes: ``(q_nope, q_pe roped, latent rows,
+        q_I, k_I, w)``."""
+        from raytpu.ops.mla_attention import latent_rows
+
+        c = self.config
+        c_q = self.q_a_norm(self.q_a_proj(x))
+        q_nope, q_pe, c_kv, k_pe = self._project(x, c_q)
+        cos, sin = rope_tables(c.qk_rope_dim, positions, c.rope_theta)
+        q_pe = apply_rope_single(q_pe, cos, sin)
+        k_pe = apply_rope_single(k_pe[:, None], cos, sin)[:, 0]
+        q_idx, w_idx = self._index_queries(x, c_q, cos, sin)
+        return (q_nope, q_pe, latent_rows(c_kv, k_pe), q_idx,
+                self._index_keys(x, cos, sin), w_idx)
+
+    def _chosen(self, q_nope, q_pe, q_idx, w_idx, pages, index_pages,
+                block_tables, positions):
+        """The absorbed form over the rows the indexer chooses ->
+        [B * T, H * v_head_dim]; ``positions`` [B, T], the rest a row a
+        position."""
+        from raytpu.ops.dsa_attention import dsa_paged_attention
+
+        c = self.config
+        b, t = positions.shape
+        h, nope, vd = c.n_head, c.qk_nope_dim, c.v_head_dim
+        w = self.kv_b_proj.variables["params"]["kernel"].astype(c.dtype)
+        w = w.reshape(c.kv_lora_rank, h, nope + vd)
+        q_lat = jnp.einsum("nhd,rhd->nhr", q_nope, w[..., :nope])
+        u = dsa_paged_attention(
+            q_lat.reshape(b, t, h, -1), q_pe.reshape(b, t, h, -1),
+            q_idx.reshape((b, t) + q_idx.shape[1:]),
+            w_idx.reshape(b, t, -1), pages, index_pages, block_tables,
+            positions, index_topk=c.index_topk, sm_scale=self.sm_scale,
+            force=c.paged_attn)
+        y = jnp.einsum("nhr,rhd->nhd", u.reshape(b * t, h, -1),
+                       w[..., nope:])
+        return y.reshape(b * t, h * vd)
+
+    def prefill(self, x):
+        """``LatentAttention.prefill`` with a third value, the index
+        keys [B, T, index_head_dim] for the second pool."""
+        c = self.config
+        b, t, e = x.shape
+        positions = jnp.tile(jnp.arange(t), b)
+        # Every position is chosen: dense. (So are the parameters made:
+        # the dense form touches every one but the index queries'.)
+        if t <= c.index_topk or self.is_initializing():
+            out, rows = super().prefill(x)
+            flat = x.reshape(b * t, e)
+            cos, sin = rope_tables(c.qk_rope_dim, positions, c.rope_theta)
+            if self.is_initializing():  # the queries' side has parameters
+                self._index_queries(flat, self.q_a_proj(flat), cos, sin)
+            keys = self._index_keys(flat, cos, sin)
+            return out, rows, keys.reshape(b, t, -1)
+        q_nope, q_pe, rows, q_idx, keys, w_idx = self._rows(
+            x.reshape(b * t, e), positions)
+        pages, tables = _as_pool(rows.reshape(b, t, -1))
+        index_pages, _ = _as_pool(keys.reshape(b, t, -1))
+        y = self._chosen(q_nope, q_pe, q_idx, w_idx, pages, index_pages,
+                         tables, positions.reshape(b, t))
+        return (self.o_proj(y).reshape(b, t, e), rows.reshape(b, t, -1),
+                keys.reshape(b, t, -1))
+
+    def step(self, x, pages, index_pages, dests, block_tables, positions):
+        """``LatentAttention.step`` over both pools: the rows' latent
+        rows and index keys scatter into ``dests`` first, then each
+        attends the positions ``<=`` its own that its indexer chooses.
+        Returns ``(out [B * T, E], pages', index_pages')``."""
+        from raytpu.ops.paged_attention import scatter_kv_slots
+
+        b, t = positions.shape
+        q_nope, q_pe, rows, q_idx, keys, w_idx = self._rows(
+            x, positions.reshape(b * t))
+        pages = scatter_kv_slots(pages, dests.reshape(b * t), rows)
+        index_pages = scatter_kv_slots(index_pages, dests.reshape(b * t),
+                                       keys)
+        y = self._chosen(q_nope, q_pe, q_idx, w_idx, pages, index_pages,
+                         block_tables, positions)
+        return self.o_proj(y), pages, index_pages

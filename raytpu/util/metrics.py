@@ -80,6 +80,10 @@ DECLARED_METRICS: Dict[str, str] = {
         "drafted tokens the verification kept (self-drafting)",
     "raytpu_infer_drafted_tokens_total":
         "drafted tokens a decode step verified (self-drafting)",
+    "raytpu_infer_dsa_rows_scored_total":
+        "cached positions an indexer scored (a layer's, sparse attention)",
+    "raytpu_infer_dsa_rows_selected_total":
+        "scored positions the attention then read (sparse attention)",
     "raytpu_infer_handoff_aborts_total":
         "KV handoffs aborted mid-stream (peer death, TTL sweep)",
     "raytpu_infer_handoff_bytes_total":
