@@ -272,7 +272,11 @@ class InferenceEngine:
     a weight; a leaf already in the compute type is the caller's own
     array. The tree given is not kept: float32 weights under bf16
     compute cost half their bytes once the caller lets go of them, and
-    a caller that wants the original keeps it. ``stats()["param_bytes"]``
+    a caller that wants the original keeps it. The copy may hold a leaf
+    the given tree has not (GPT-2's lookup tables at a width that is not
+    whole lane tiles: :func:`raytpu.models.gpt2.serving_params`);
+    ``stats()["relaid_param_bytes"]`` has the bytes of such leaves.
+    ``stats()["param_bytes"]``
     has the copy's bytes by dtype, ``stats()["kv_pool_bytes"]`` the
     bytes of the 2 x layers KV pools (``kv_pool_bytes_by_kind``: of the
     full and of the window layers'), or of a latent-attention model's
@@ -362,11 +366,20 @@ class InferenceEngine:
         # in it already, so no step converts a weight. The tree given is
         # not kept; a caller that wants it keeps it.
         self._params = served.params(model_config, params)
+        given_shapes = {
+            jax.tree_util.keystr(path): leaf.shape for path, leaf
+            in jax.tree_util.tree_flatten_with_path(params)[0]}
         self._param_bytes: Dict[str, int] = {}
-        for leaf in jax.tree_util.tree_leaves(self._params):
+        # Of leaves the copy holds in another shape than given, or that
+        # the tree given has not (a family's lookup tables).
+        self._relaid_param_bytes = 0
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self._params)[0]:
             name = str(leaf.dtype)
-            self._param_bytes[name] = (self._param_bytes.get(name, 0)
-                                       + leaf.size * leaf.dtype.itemsize)
+            nbytes = leaf.size * leaf.dtype.itemsize
+            self._param_bytes[name] = self._param_bytes.get(name, 0) + nbytes
+            if given_shapes.get(jax.tree_util.keystr(path)) != leaf.shape:
+                self._relaid_param_bytes += nbytes
         self.max_model_len = min(max_model_len or model_config.block_size,
                                  model_config.block_size)
         self.page_size = page_size
@@ -1679,6 +1692,10 @@ class InferenceEngine:
             # Bytes of the tree the programs take, by dtype, over all
             # shards: all in the compute type but the norms' leaves.
             "param_bytes": dict(self._param_bytes),
+            # Of those, the bytes of leaves held in another shape than
+            # given (a table a step gathers rows from, its rows padded
+            # to whole lane tiles); 0 where the copy is only a cast.
+            "relaid_param_bytes": self._relaid_param_bytes,
             # Bytes of the 2 x layers KV pools, over all shards, and the
             # same by kind of layer (``window``: 0 without such layers).
             "kv_pool_bytes": self._kv_pool_bytes,

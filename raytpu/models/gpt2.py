@@ -349,6 +349,25 @@ def cast_leaves(params, dtype, cast):
 _SERVED_IN_COMPUTE_DTYPE = ("c_attn", "c_proj", "c_fc", "wte", "wpe")
 
 
+LANES = 128  # a TPU tile's minor dimension
+
+
+def lookup_table(table):
+    """``table`` [rows, width] with its rows zero-padded to a whole
+    number of lane tiles, for a program that gathers rows from it: the
+    device keeps such an array row-major. ``None`` where the width is
+    whole already (the table serves as it is). A shape standing in for
+    an array gives a shape."""
+    rows, width = table.shape
+    pad = -width % LANES
+    if not pad:
+        return None
+    if isinstance(table, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct((rows, width + pad), table.dtype,
+                                    sharding=table.sharding)
+    return jnp.pad(table, ((0, 0), (0, pad)))
+
+
 def serving_params(config: GPT2Config, params):
     """The working copy of ``params`` to serve from: every leaf that
     :func:`gpt2_prefill` and :func:`gpt2_step` cast to
@@ -358,9 +377,34 @@ def serving_params(config: GPT2Config, params):
     The layer norms' ``scale`` and ``bias`` stay as given:
     ``nn.LayerNorm(dtype=...)`` normalises, scales and shifts in float32
     and casts the result, so rounding them first would change it. Make
-    it once (``InferenceEngine`` does), not per call."""
-    return cast_leaves(params, config.dtype,
-                       lambda keys: keys[-2] in _SERVED_IN_COMPUTE_DTYPE)
+    it once (``InferenceEngine`` does), not per call.
+
+    One leaf the given tree has not: where ``n_embd`` is not a whole
+    number of 128-lane tiles (GPT-2 XL's 1,600 is 12.5), ``wte`` and
+    ``wpe`` each gain a ``lookup`` beside their ``embedding``
+    (:func:`lookup_table`), the same rows zero-padded to whole tiles,
+    which the forwards gather their token and position rows from. The
+    device holds an ``embedding`` of such a width column-major, as the
+    tied head's product reads it, and a program that gathered rows from
+    it re-laid the whole table first, in every call (PERF.md section 6,
+    PR 56). At a whole width the tree has no such leaf and the
+    forwards gather from ``embedding``."""
+    working = cast_leaves(params, config.dtype,
+                          lambda keys: keys[-2] in _SERVED_IN_COMPUTE_DTYPE)
+    for name in ("wte", "wpe"):
+        lookup = lookup_table(working[name]["embedding"])
+        if lookup is not None:
+            working = {**working, name: {**working[name], "lookup": lookup}}
+    return working
+
+
+def _embedded(c: GPT2Config, table, ids):
+    """Rows ``ids`` of an embedding module's ``table`` in ``c.dtype``:
+    from its ``lookup`` leaf where the working copy holds one
+    (:func:`serving_params`), else from ``embedding``."""
+    if "lookup" in table:
+        return table["lookup"][ids][..., :c.n_embd]
+    return table["embedding"].astype(c.dtype)[ids]
 
 
 def _tied_logits(c: GPT2Config, params, x):
@@ -380,6 +424,11 @@ class Serving:
     call's rows written, then, where ``expert_counts`` is set, the int32
     tokens each expert of each layer received. What a pool row holds is
     known to the family and to ``raytpu.ops.paged_attention`` alone.
+    ``params`` here is the working copy ``params(config, given)`` made:
+    the given tree's leaves as the forwards use them, and it may hold a
+    leaf the given tree has not (GPT-2's ``lookup`` tables, where the
+    hidden width is not whole lane tiles: :func:`serving_params`). The
+    forwards take the tree as given too, from any other caller.
 
     ``prefill(config, params, tokens [1, T], dests [T] (a kind),
     k_caches, v_caches[, states, seats])`` -> logits [T, V]: a whole
@@ -521,9 +570,8 @@ def gpt2_prefill(config: GPT2Config, params, tokens, dests, k_caches,
     attention), its K and V written to the pools at ``dests`` [T] ->
     (fp32 logits [T, V], k_caches, v_caches)."""
     c = config
-    x = params["wte"]["embedding"].astype(c.dtype)[tokens] + \
-        params["wpe"]["embedding"].astype(c.dtype)[
-            jnp.arange(tokens.shape[1])][None]
+    x = _embedded(c, params["wte"], tokens) + _embedded(
+        c, params["wpe"], jnp.arange(tokens.shape[1]))[None]
     logits, ks, vs = _serve(c, params, x, lambda i: (), whole=True)
     ks, vs = write_prompt_rows(k_caches, v_caches, dests, ks, vs)
     return logits[0], ks, vs
@@ -537,8 +585,8 @@ def gpt2_step(config: GPT2Config, params, tokens, positions, dests,
     The walk runs over the ``B * T`` rows (see
     :func:`raytpu.models.llama.llama_step`)."""
     c = config
-    x = params["wte"]["embedding"].astype(c.dtype)[tokens.reshape(-1)] + \
-        params["wpe"]["embedding"].astype(c.dtype)[positions.reshape(-1)]
+    x = _embedded(c, params["wte"], tokens.reshape(-1)) + _embedded(
+        c, params["wpe"], positions.reshape(-1))
     logits, ks, vs = _serve(c, params, x, lambda i: (
         k_caches[i], v_caches[i], dests, block_tables, positions))
     return logits.reshape(*tokens.shape, -1), ks, vs
