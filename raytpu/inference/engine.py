@@ -148,6 +148,13 @@ _dsa_scored_total = Counter(
 _dsa_selected_total = Counter(
     "raytpu_infer_dsa_rows_selected_total",
     "Of those positions, the ones the queries' attention then read")
+_gc_pause_total = Counter(
+    "raytpu_host_gc_pause_seconds_total",
+    "Seconds this process's interpreter was held by the cycle collector")
+_gc_collections_total = Counter(
+    "raytpu_host_gc_collections_total",
+    "Collections of the cycle collector in this process",
+    tag_keys=("generation",))
 _ttft_hist = Histogram(
     "raytpu_infer_ttft_seconds",
     "Time from request admission to its first sampled token",
@@ -166,12 +173,15 @@ class StepOutput:
 
 @dataclasses.dataclass
 class _Flight:
-    """A decode step that is dispatched and not yet fetched: its batch,
+    """A decode step that is dispatched and not yet fetched: its ordinal
+    (the engine's count of decodes dispatched, from 1: the step records'
+    ``dispatched`` and ``fetched``), its batch,
     row for row, the ids its sampler leaves on the device (``int32
     [bucket]``: the next step's ``tokens`` as they lie), a routed model's
     counts beside them, the program's name and bucket key, when its
     launch began, and its FLOPs where the profiler is on."""
 
+    n: int
     seqs: List[Sequence]
     ids: Any
     experts: list
@@ -545,10 +555,12 @@ class InferenceEngine:
         # fetched for a sequence that had ended meanwhile.
         self._flight: Optional[_Flight] = None
         self._fetched_at = 0.0
+        # Decodes dispatched so far: the newest one's ordinal.
+        self._dispatched = 0
         self._decodes_ahead = self._decodes_drained = 0
         self._ahead_rows_dropped = 0
         # One record per step(): its phases' stamps and what it ran.
-        self.recorder = tracing.StepRecorder()
+        self.recorder = tracing.StepRecorder(keep=("decodes",))
         # Called, if set, in ``infer.decode.wait`` once a decode step's
         # programs are on their way to the device, before the host
         # blocks on the ids of the step in flight: while the chip works
@@ -1020,6 +1032,7 @@ class InferenceEngine:
                 "state_seats": 0, "state_bytes": 0,
                 "sampled_stochastic": 0, "host_puts": 0, "tables_reused": 0,
                 "ahead": 0, "ahead_rows_dropped": 0,
+                "dispatched": 0, "fetched": 0, "carried": 0,
                 "kv_bytes_per_token": self._kv_token_bytes} | (
                     {"drafted": 0, "accepted": 0, "emitted": 0}
                     if self._drafting else {}) | (
@@ -1030,10 +1043,10 @@ class InferenceEngine:
                 # the fetch completes, and an ended sequence's pages may
                 # make the preemption needless.
                 decoded += self._drain(out)
-            with recorder.phase("infer.schedule") as ph:
+            with recorder.phase("infer.schedule"):
                 waiting = len(self.scheduler.waiting)
                 plan = self.scheduler.schedule()
-                ph.attrs["admitted"] = st.attrs["admitted"] = (
+                st.attrs["admitted"] = (
                     waiting + len(plan.preempted)
                     - len(self.scheduler.waiting))
             # The mesh is thread-local state and any thread may step.
@@ -1086,6 +1099,14 @@ class InferenceEngine:
             _running_gauge.set(len(self.scheduler.running))
             _waiting_gauge.set(len(self.scheduler.waiting))
             _kv_util_gauge.set(self.cache.utilization())
+            collected = tracing.gc_unpublished()
+            if collected is not None:
+                counts, seconds = collected
+                _gc_pause_total.inc(seconds)
+                for generation, count in enumerate(counts):
+                    if count:
+                        _gc_collections_total.inc(
+                            count, tags={"generation": str(generation)})
         return out
 
     def _programs_traced(self) -> int:
@@ -1283,6 +1304,7 @@ class InferenceEngine:
         well: it writes the scratch page and counts in nothing)."""
         if self._same_batch(before.seqs, seqs):
             return before.ids
+        self.recorder.open.fields["carried"] = 1
         row = {id(seq): i for i, seq in enumerate(before.seqs)}
         source = np.full(len(known), -1, dtype=np.int32)
         source[:len(seqs)] = [row.get(id(seq), -1) for seq in seqs]
@@ -1332,6 +1354,8 @@ class InferenceEngine:
                 for seq in seqs:
                     seq.cached_len += 1
                     seq.in_flight += 1
+                self._dispatched += 1
+                fields["dispatched"] = self._dispatched
                 fields["host_puts"] = self._host_puts - put
             flops = None
             if profiling_enabled():
@@ -1345,7 +1369,7 @@ class InferenceEngine:
                         self._decode_fn, self._params, cache.k, cache.v,
                         *((cache.state, seats) if cache.state else ()),
                         *inputs))
-            with recorder.phase("infer.decode.wait") as wait:
+            with recorder.phase("infer.decode.wait", cpu="wait_cpu_s"):
                 # The chip is on the decode, or still on the one before.
                 # The sampler goes out behind it, over the logits where
                 # they lie and the positions the decode was given; the
@@ -1363,12 +1387,12 @@ class InferenceEngine:
                         np.arange(bucket, dtype=np.int32),
                         np.zeros(bucket, dtype=np.int32))))
                 self._flight = _Flight(
-                    list(seqs), sampled, experts,
+                    self._dispatched, list(seqs), sampled, experts,
                     ("_decode", f"{bucket}x{P}"), launch.t0, flops)
                 # The host blocked on the device and on the copy back of
                 # the step before, after whatever its owner has for it
                 # meanwhile.
-                fetched = self._fetch(before, wait)
+                fetched = self._fetch(before)
             with recorder.phase("infer.decode.sample"):
                 return self._give(before, fetched, out)
 
@@ -1379,22 +1403,23 @@ class InferenceEngine:
         recorder = self.recorder
         flight, self._flight = self._flight, None
         with recorder.phase("infer.decode"):
-            with recorder.phase("infer.decode.wait") as wait:
-                tokens = self._fetch(flight, wait)
+            with recorder.phase("infer.decode.wait", cpu="wait_cpu_s"):
+                tokens = self._fetch(flight)
             with recorder.phase("infer.decode.sample"):
                 return self._give(flight, tokens, out)
 
-    def _fetch(self, flight: Optional[_Flight], wait) -> List[int]:
+    def _fetch(self, flight: Optional[_Flight]) -> List[int]:
         """Inside ``infer.decode.wait``: the owner's ``on_launch``, then
         the block on ``flight``'s ids and on a routed model's counts,
-        which go into the record open now: the step under whose span
-        that decode's kernels mostly ran, a call after its own."""
+        which go into the record open now beside the decode's ordinal
+        (``fetched``): the step under whose span that decode's kernels
+        mostly ran, a call after its own."""
         if self.on_launch is not None:
             self.on_launch()
         if flight is None:
             return []
         ids = np.asarray(flight.ids)
-        wait.attrs["bytes"] = ids.nbytes
+        self.recorder.open.fields["fetched"] = flight.n
         self._count_experts(flight.experts, flight.program)
         now = time.perf_counter()
         if profiling_enabled() and flight.flops is not None:
@@ -1467,15 +1492,17 @@ class InferenceEngine:
                 self.cache.k, self.cache.v = ks, vs
                 for x in (count, more):
                     x.copy_to_host_async()
+                # Nothing stays in flight: dispatched and fetched here.
+                self._dispatched += 1
+                fields["dispatched"] = fields["fetched"] = self._dispatched
                 fields["host_puts"] = self._host_puts - put
-            with recorder.phase("infer.decode.wait") as wait:
+            with recorder.phase("infer.decode.wait", cpu="wait_cpu_s"):
                 # Every row draws twice (accept, then a token) and the
                 # module once more; counted as the sampler's rows are.
                 fields["sampled_stochastic"] += stochastic
                 if self.on_launch is not None:
                     self.on_launch()
                 chosen = np.asarray(chosen)
-                wait.attrs["bytes"] = chosen.nbytes
                 key = f"{bucket}x{P}"
                 self._count_experts((count, more), ("_decode", key),
                                     ("_draft", key))
@@ -1587,19 +1614,38 @@ class InferenceEngine:
         alone, as has one whose batch moved to another bucket before
         its own, and a preemption's drain one inside ``infer.schedule``;
         plus what the stepping
-        loop put around the step), and what the step ran. What describes
-        a decode describes the one the step *dispatched*: ``decodes``,
-        ``bucket``, ``table_width``, ``live_pages*``, ``host_puts``,
-        ``tables_reused`` and ``ahead`` (1 where it was dispatched
+        loop put around the step), and what the step ran. A record
+        says which decode each of its numbers is of: ``dispatched`` is
+        the ordinal of the decode the step's launch dispatched (the
+        engine's count of decodes dispatched, from 1; 0: it dispatched
+        none) and ``fetched`` the ordinal of the decode whose ids its
+        wait blocked on (0: it fetched none). A plain step fetches the
+        decode the step before dispatched (``fetched == dispatched -
+        1``); a drained step has ``fetched`` alone; a model that drafts
+        for itself keeps nothing in flight (``dispatched == fetched``).
+        Of decode ``dispatched`` are ``decodes``, ``bucket``,
+        ``table_width``, ``live_pages*``, ``host_puts``,
+        ``tables_reused``, ``dsa_rows_*`` (its share of them: a step's
+        prefills add theirs), ``ahead`` (1 where it was dispatched
         before the ids of the step before were fetched, its tokens the
-        ids in flight, gathered on the device where the batch had
-        moved; 0 where it was built from the host's tokens, an idle
-        engine's first decode and the one after a drain, or the step
-        dispatched none). What comes back with a fetch
-        describes the decode the step *fetched*, a call after its
-        dispatch: a routed model's ``moe_*`` counts and
+        ids in flight; 0 where it was built from the host's tokens, an
+        idle engine's first decode and the one after a drain, or the
+        step dispatched none) and ``carried`` (1 where those ids went
+        through the hand-over program, ``_carry_fn``, because the batch
+        had moved; 0 where they were taken as they lay or came from the
+        host). Of decode ``fetched``, a call after its dispatch, are
+        what comes back with a fetch: a routed model's ``moe_*`` counts
+        (to which a step's prefills add theirs), ``emitted`` and
         ``ahead_rows_dropped`` (rows fetched for a sequence that had
-        ended while they were in flight). ``live_pages`` (pages its decode
+        ended while they were in flight). The device's side of the
+        pairing is the profiler's: one ``XLA Modules`` event an
+        execution of ``jit__decode``, in the order of the ordinals.
+        ``cpu_s`` is the stepping thread's CPU time over the step
+        (``time.thread_time()`` at its open and close) and
+        ``wait_cpu_s`` the part of it inside ``infer.decode.wait``;
+        ``(end - start - the wait's seconds) - (cpu_s - wait_cpu_s)`` is
+        the time the thread that feeds the chip was off the CPU outside
+        the wait, blocked or without the interpreter. ``live_pages`` (pages its decode
         had to read of a sequence's whole context), ``live_pages_full``
         and ``live_pages_window`` (pages its decode read in one full
         layer, the same number, and in one window layer: the windows'
@@ -1647,7 +1693,12 @@ class InferenceEngine:
         positions one layer's indexers scored for the step's queries,
         decoded and prefilled, and those their attention then read.
         ``"oldest_start"`` is the start of the oldest step still held,
-        so a reader can tell a truncated log from a quiet engine. Call
+        so a reader can tell a truncated log from a quiet engine.
+        ``"pauses"`` holds what stopped this process's interpreter and
+        ended after ``since``, on the steps' clock
+        (:func:`raytpu.util.tracing.host_pauses`: ``["host.gc", t0, t1,
+        {generation, collected, stepping}]`` a full or a long
+        collection). Call
         it from the thread that steps, or under the lock that
         serialises ``step()``."""
         return self.recorder.log(since)
@@ -1666,11 +1717,8 @@ class InferenceEngine:
             # the tokens' hand-over's, by the decode bucket.
             "sample_compiles": dict(self._sample_compiles),
             "carry_compiles": dict(self._carry_compiles),
-            # Of a model that drafts for itself: the accept/resample
-            # program's and the module's, and the drafts verified so far
-            # and kept (None: no drafting).
-            "accept_compiles": dict(self._accept_compiles),
-            "draft_compiles": dict(self._draft_compiles),
+            # Of a model that drafts for itself: the drafts verified so
+            # far and kept (None: no drafting).
             "drafted_tokens": self._drafted if self._drafting else None,
             "draft_accepted": (self._draft_accepted if self._drafting
                                else None),
@@ -1723,7 +1771,6 @@ class InferenceEngine:
                                if self._moe_pairs else None),
             "moe_pairs": (self._moe_pairs["moe_pairs"]
                           if self._moe_pairs else None),
-            "ttft_p50_s": self.ttft_quantile(0.5),
             "ttft_p95_s": self.ttft_quantile(0.95),
             "prefix_cache": (self.prefix_cache.stats()
                              if self.prefix_cache else None),

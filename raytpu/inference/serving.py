@@ -77,7 +77,7 @@ from raytpu.inference import disagg
 from raytpu.inference.engine import InferenceEngine
 from raytpu.inference.sampling import SamplingParams
 from raytpu.serve.deployment import deployment
-from raytpu.util import serve_slo, task_events
+from raytpu.util import serve_slo, task_events, tracing
 
 logger = logging.getLogger(__name__)
 
@@ -762,21 +762,31 @@ class LLMDeployment:
         while a step is in flight."""
         return dict(self._pressure)
 
-    def step_log(self, last: int = _STEP_TAIL) -> list:
+    def step_log(self, last: int = _STEP_TAIL, pauses: bool = False):
         """The newest ``last`` engine steps (at most 64), oldest first,
         as :meth:`InferenceEngine.step_log` gives them, each with the
         loop's ``serve.llm.lock_wait`` and ``serve.llm.publish`` among
-        its phases: the answer to "why was that token late". Reads the
-        stepping loop's snapshot and takes no lock."""
+        its phases: the answer to "why was that token late". With
+        ``pauses`` the engine's form, ``{"steps": those, "pauses": what
+        stopped this process's interpreter since the oldest of them
+        began}`` (:func:`raytpu.util.tracing.host_pauses`, the steps'
+        clock). Reads the stepping loop's snapshot and takes no lock."""
         tail = self._step_tail
-        return [r.as_dict() for r in tail[-last:]] if last > 0 else []
+        steps = [r.as_dict() for r in tail[-last:]] if last > 0 else []
+        if not pauses:
+            return steps
+        return {"steps": steps, "pauses": tracing.host_pauses(
+            steps[0]["start"]) if steps else []}
 
     def stats(self) -> dict:
         """Engine statistics, plus which process this replica is and
-        the chips it leased (empty in local mode)."""
+        the chips it leased (empty in local mode), and ``host_pauses``:
+        the collections of this process's cycle collector so far, by
+        generation (``{"gc": {"2": {count, seconds, longest_s}, ...}}``)."""
         with self._cv:
             stats = self._engine.stats()
             stats.update(self._published)
+        stats["host_pauses"] = tracing.host_pause_totals()
         stats["replica"] = {
             "pid": os.getpid(),
             "chips": os.environ.get("RAYTPU_VISIBLE_CHIPS", "")}

@@ -142,6 +142,12 @@ DECLARED_METRICS: Dict[str, str] = {
         "tokens streamed to consumers, by deployment and tenant",
     "raytpu_serve_tokens_wasted_total":
         "tokens whose work was discarded, by cause",
+    # -- the serving process's interpreter -----------------------------
+    "raytpu_host_gc_collections_total":
+        "collections of the cycle collector in an engine's process, "
+        "by generation",
+    "raytpu_host_gc_pause_seconds_total":
+        "seconds the cycle collector held an engine's process",
     # -- metrics pipeline itself ---------------------------------------
     "raytpu_metrics_series_dropped_total":
         "tag-sets folded into <other> by the cardinality cap",

@@ -34,6 +34,12 @@ as a ``jax.profiler.TraceAnnotation`` (so a profiler session shows it on
 the device trace's clock) and opened as a :func:`span` of the same name.
 Ring spans carry that same monotonic clock as ``t0`` beside the wall
 clock ``start``, so the three can be laid on one axis.
+
+Host pauses: what stops the interpreter under a step is kept on the same
+clock in one bounded ring a process (:func:`host_pauses`), installed by
+the first :class:`StepRecorder` built, so a process without an engine
+pays nothing. Today the one kind is ``host.gc``, a collection of the
+cycle collector, from ``gc.callbacks``.
 """
 
 from __future__ import annotations
@@ -41,6 +47,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import gc
 import itertools
 import json
 import os
@@ -274,6 +281,128 @@ def span(name: str, attributes: Optional[Dict[str, Any]] = None):
     return _Span(name, attributes)
 
 
+# -- host pauses ---------------------------------------------------------------
+
+# A collection is kept as an entry where it was a full one or took longer
+# than this; every collection is counted.
+GC_KEPT_OVER_S = 1e-3
+PAUSE_RING = 1024
+# [kind, t0, t1, attrs] on ``time.perf_counter()``, oldest first.
+_pauses: "deque[list]" = deque(maxlen=PAUSE_RING)
+# Collections so far by generation: how many, their seconds, and the
+# longest of those kept.
+_gc_counts = [0, 0, 0]
+_gc_seconds = [0.0, 0.0, 0.0]
+_gc_longest = [0.0, 0.0, 0.0]
+# What :func:`gc_unpublished` has handed out of the first two.
+_gc_published = [0, 0, 0, 0.0]
+_gc_t0 = 0.0  # the newest collection's start
+_gc_annotation = None
+_stepping_thread = 0  # ident of the thread that last opened a step
+_pauses_installed = False
+_now = time.perf_counter
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks``: ``start`` stamps, ``stop`` counts the collection
+    and keeps an entry of a full or a long one. Collections do not nest
+    and run under the interpreter's lock, so the module's state is
+    enough; nothing is allocated here but a kept entry. A young
+    collection costs two clock reads and three additions."""
+    global _gc_t0
+    if phase == "start":
+        if info["generation"] == 2:
+            _gc_full_begins()
+        _gc_t0 = _now()
+        return
+    t1 = _now()
+    took = t1 - _gc_t0
+    generation = info["generation"]
+    _gc_counts[generation] += 1
+    _gc_seconds[generation] += took
+    if took > GC_KEPT_OVER_S or generation == 2:
+        _gc_keep(generation, _gc_t0, t1, info)
+
+
+def _gc_full_begins() -> None:
+    """A full collection lies beside the device's operations in a
+    profiler session, as a phase does."""
+    global _gc_annotation
+    annotation = _trace_annotation or _annotation_type()
+    if annotation is not None:
+        _gc_annotation = annotation("host.gc")
+        _gc_annotation.__enter__()
+
+
+def _gc_keep(generation: int, t0: float, t1: float,
+             info: Dict[str, int]) -> None:
+    global _gc_annotation
+    if _gc_annotation is not None:
+        _gc_annotation.__exit__(None, None, None)
+        _gc_annotation = None
+    if t1 - t0 > _gc_longest[generation]:
+        _gc_longest[generation] = t1 - t0
+    _pauses.append(["host.gc", t0, t1, {
+        "generation": generation, "collected": info["collected"],
+        "stepping": threading.get_ident() == _stepping_thread}])
+
+
+def _install_host_pauses() -> None:
+    """Once a process, by the first :class:`StepRecorder` it builds."""
+    global _pauses_installed, _gc_t0
+    if not _pauses_installed:
+        _pauses_installed = True
+        # A collection that is running now is counted from here.
+        _gc_t0 = _now()
+        gc.callbacks.append(_on_gc)
+
+
+def host_pauses(since: float = 0.0) -> List[list]:
+    """The pauses of this process's interpreter that ended after ``since``
+    (``perf_counter`` seconds, the step log's clock), oldest first:
+    ``[kind, t0, t1, attrs]``. ``host.gc`` is a collection of the cycle
+    collector, with ``generation``, ``collected`` and ``stepping`` (whether
+    the thread that collected is the one that last opened a step); kept
+    are the full collections and any that took over ``GC_KEPT_OVER_S``,
+    the newest ``PAUSE_RING`` of them. Empty in a process that has built
+    no :class:`StepRecorder`."""
+    for _ in range(4):
+        try:
+            kept = list(_pauses)
+            break
+        except RuntimeError:  # a collection ended while it was copied
+            continue
+    else:
+        return []
+    return [[kind, t0, t1, dict(attrs)] for kind, t0, t1, attrs in kept
+            if t1 > since]
+
+
+def host_pause_totals() -> Dict[str, Dict[str, Dict[str, float]]]:
+    """Every collection since the callback was installed, by generation
+    (string keys: the dict crosses the serve wire): ``count``,
+    ``seconds``, and ``longest_s``, the longest of those kept as an
+    entry (a full one, or one over ``GC_KEPT_OVER_S``; 0.0: none was)."""
+    return {"gc": {str(g): {"count": _gc_counts[g],
+                            "seconds": _gc_seconds[g],
+                            "longest_s": _gc_longest[g]}
+                   for g in range(3)}}
+
+
+def gc_unpublished() -> Optional[Tuple[List[int], float]]:
+    """Collections by generation and their seconds since the last call
+    that returned any: what a step's end adds to the metrics pipeline.
+    None, at the cost of a sum of three, where there was none."""
+    counts = _gc_counts
+    done = _gc_published
+    if counts[0] + counts[1] + counts[2] == done[0] + done[1] + done[2]:
+        return None
+    seconds = sum(_gc_seconds)
+    out = ([counts[g] - done[g] for g in range(3)], seconds - done[3])
+    done[:] = [*counts, seconds]
+    return out
+
+
 # -- step records -------------------------------------------------------------
 
 _trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
@@ -315,19 +444,27 @@ class StepRecord:
 class _Phase:
     """An open phase (or, with ``opens``, the step itself). Entering
     stamps the clock last and leaving stamps it first, so the stamps are
-    the innermost of the three records of the phase."""
+    the innermost of the three records of the phase. A phase that counts
+    its thread's CPU time (the step always, a phase opened with ``cpu``)
+    reads ``time.thread_time()`` inside its stamps, so the CPU seconds of
+    a phase never pass its wall seconds."""
 
     __slots__ = ("name", "attrs", "t0", "t1", "record", "_recorder",
-                 "_opens", "_after", "_entry", "_annotation", "_span")
+                 "_opens", "_after", "_entry", "_annotation", "_span",
+                 "_cpu", "_cpu0")
 
     def __init__(self, recorder: "StepRecorder", name: str,
-                 attrs: Optional[Dict[str, Any]], opens: bool, after: bool):
+                 attrs: Optional[Dict[str, Any]], opens: bool, after: bool,
+                 cpu: Optional[str] = None):
         self.name = name
         self.attrs: Dict[str, Any] = {} if attrs is None else attrs
         self.t0 = self.t1 = 0.0
         self._recorder = recorder
         self._opens = opens
         self._after = after
+        # The record's field that takes this thread's CPU seconds over
+        # the phase (the step's own: ``cpu_s``), read inside the stamps.
+        self._cpu = "cpu_s" if opens else cpu
 
     def __enter__(self) -> "_Phase":
         self._span = None
@@ -342,6 +479,8 @@ class _Phase:
             self._annotation.__enter__()
         recorder = self._recorder
         if self._opens:
+            global _stepping_thread
+            _stepping_thread = threading.get_ident()
             self.record = record = recorder.open = StepRecord(self.attrs)
             record.phases.extend(recorder._early)
             recorder._early.clear()
@@ -356,16 +495,22 @@ class _Phase:
             (recorder._early if record is None
              else record.phases).append(entry)
             self.t0 = entry[1] = entry[2] = time.perf_counter()
+        if self._cpu is not None:
+            self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
+        if self._cpu is not None and self.record is not None:
+            fields = self.record.fields
+            fields[self._cpu] = fields.get(self._cpu, 0.0) \
+                + time.thread_time() - self._cpu0
         self.t1 = time.perf_counter()
         if self._opens:
             self._entry.end = self.t1
             if ev is not None:
                 self.attrs["error"] = repr(ev)
             self._recorder.open = None
-            self._recorder._ring.append(self._entry)
+            self._recorder._close(self._entry)
         else:
             self._entry[2] = self.t1
         if self._annotation is not None:
@@ -381,14 +526,36 @@ class StepRecorder:
     One thread at a time steps, so one step at most is open, and a
     closed record is written only by the phases that follow its step
     (``after=True``). ``maxlen`` 4096 holds a 40 s window at a 10 ms
-    step."""
+    step. A record's ``cpu_s`` is its thread's CPU time over the step
+    (``time.thread_time()``): what the step's wall time holds beyond it,
+    the thread spent off the CPU, blocked or without the interpreter."""
 
-    def __init__(self, maxlen: int = 4096):
+    def __init__(self, maxlen: int = 4096, keep: Tuple[str, ...] = ()):
         self._ring: "deque[StepRecord]" = deque(maxlen=maxlen)
         self.open: Optional[StepRecord] = None
         # Phases closed while no step was open, for the next to adopt
         # (the wait for the lock that precedes it).
         self._early: "deque[list]" = deque(maxlen=16)
+        # Of the fields in ``keep``, the truthy values of the records the
+        # ring holds, each beside its record's count: what ``values``
+        # would walk the ring for, kept as the ring is appended to.
+        self._closed = 0
+        self._kept: Dict[str, "deque[Tuple[int, Any]]"] = {
+            field: deque() for field in keep}
+        _install_host_pauses()
+
+    def _close(self, record: StepRecord) -> None:
+        """A step has ended: into the ring, and out of ``_kept`` what
+        the ring dropped for it."""
+        self._ring.append(record)
+        self._closed += 1
+        dropped = self._closed - self._ring.maxlen
+        for field, kept in self._kept.items():
+            value = record.fields.get(field)
+            if value:
+                kept.append((self._closed, value))
+            while kept and kept[0][0] <= dropped:
+                kept.popleft()
 
     def step(self, name: str,
              fields: Optional[Dict[str, Any]] = None) -> _Phase:
@@ -398,16 +565,23 @@ class StepRecorder:
         return _Phase(self, name, fields, True, False)
 
     def phase(self, name: str, attrs: Optional[Dict[str, Any]] = None,
-              after: bool = False) -> _Phase:
+              after: bool = False, cpu: Optional[str] = None) -> _Phase:
         """Open a phase of the step in flight. With no step open it
-        belongs to the next one, or with ``after`` to the last."""
-        return _Phase(self, name, attrs, False, after)
+        belongs to the next one, or with ``after`` to the last. ``cpu``
+        names a field of the record that the phase adds its thread's CPU
+        seconds to (``time.thread_time()``: two clock calls)."""
+        return _Phase(self, name, attrs, False, after, cpu)
 
     def __len__(self) -> int:
         return len(self._ring)
 
     def values(self, field: str) -> list:
-        """The truthy values of ``field`` over the ring, oldest first."""
+        """The truthy values of ``field`` over the ring, oldest first:
+        a copy of what was kept for a field in ``keep``, else a walk of
+        the ring."""
+        kept = self._kept.get(field)
+        if kept is not None:
+            return [v for _, v in kept]
         return [v for r in self._ring if (v := r.fields.get(field))]
 
     def tail(self, n: int) -> List[StepRecord]:
@@ -420,7 +594,9 @@ class StepRecorder:
         """The records that ended after ``since`` (``perf_counter``
         seconds), oldest first, as plain dicts; and the start of the
         oldest record the ring still holds (None when empty), so that a
-        reader can tell truncation from silence."""
+        reader can tell truncation from silence; and under ``pauses``
+        what stopped this process's interpreter and ended after
+        ``since`` (:func:`host_pauses`), on the same clock."""
         steps = []
         for record in reversed(self._ring):
             if record.end <= since:
@@ -428,7 +604,7 @@ class StepRecorder:
             steps.append(record.as_dict())
         steps.reverse()
         return {"oldest_start": self._ring[0].start if self._ring else None,
-                "steps": steps}
+                "steps": steps, "pauses": host_pauses(since)}
 
 
 def traced(name: Optional[str] = None) -> Callable:
@@ -490,7 +666,9 @@ def profile(logdir: str, *, host_tracer_level: int = 2):
     the TPU analogue of the reference's nsight runtime-env plugin). The
     host's lines hold what entered a ``TraceAnnotation`` in the region:
     every phase of a :class:`StepRecorder` (``infer.*``, ``serve.llm.*``)
-    lies beside the device's operations, on their clock.
+    lies beside the device's operations, on their clock, and so does a
+    full collection of the cycle collector (``host.gc``) in a process
+    that has built a recorder.
     ``host_tracer_level`` is the profiler's (1: annotations only, 2: the
     runtime's own events too); Python's call tracer stays off, it would
     bury the phases under every function call."""

@@ -998,7 +998,35 @@ class TestDecodeAhead:
         assert eng._flight is None and not eng.scheduler.running
         assert eng.cache.free_pages() == eng.cache.total_pages
         assert eng.cache.seats_in_use() == 0
+        TestDecodeAhead.ordinals_hold(eng.step_log()["steps"], calls)
         return stats
+
+    @staticmethod
+    def ordinals_hold(steps, calls):
+        """A record says which decode each of its numbers is of (ISSUE
+        57): ``dispatched`` counts the decodes dispatched, from 1; every
+        decode is fetched once, by a later record; and ``carried`` is 1
+        exactly where the batch in flight was another than the step's."""
+        decoded = [s for s in steps if s["decodes"]]
+        assert [s["dispatched"] for s in decoded] \
+            == list(range(1, len(decoded) + 1))
+        assert all(s["dispatched"] == 0 for s in steps if not s["decodes"])
+        fetched = [s["fetched"] for s in steps if s["fetched"]]
+        assert fetched == list(range(1, len(decoded) + 1))
+        at = {s["dispatched"]: i for i, s in enumerate(steps)
+              if s["dispatched"]}
+        for i, s in enumerate(steps):
+            if s["fetched"]:
+                assert at[s["fetched"]] < i  # an earlier record's decode
+            if s["ahead"]:  # behind the decode in flight, fetched here
+                assert s["fetched"] == s["dispatched"] - 1
+            assert 0 <= s["wait_cpu_s"] <= s["cpu_s"] \
+                <= s["end"] - s["start"] if "wait_cpu_s" in s \
+                else 0 <= s["cpu_s"] <= s["end"] - s["start"]
+        for step, (batch, flight) in zip(decoded, calls):
+            assert step["carried"] == (flight is not None
+                                       and flight != batch), (batch, flight)
+        assert all(s["carried"] == 0 for s in steps if not s["decodes"])
 
     def test_a_stop_token_drops_the_row_in_flight(self, ahead_family):
         cfg, params = ahead_family
@@ -1169,6 +1197,65 @@ class TestDecodeAhead:
         assert [s["ahead"] for s in decoded] == [0] + [1] * 5 + [0] + [1] * 3
         assert calls[6] == (["short"], None)
 
+    def test_a_record_names_the_decode_it_dispatched_and_the_one_it_fetched(
+            self, ahead_family):
+        """A joiner, a leaver and a drain (the batch falls to another
+        bucket): the ordinals pair every number of a record with its
+        decode, whatever the batch did."""
+        cfg, params = ahead_family
+        prompts = self.prompts(cfg, 5, 7, 6)
+        samplings = [self.sampling(41, max_new_tokens=12),
+                     self.sampling(42, max_new_tokens=4),
+                     self.sampling(43, max_new_tokens=5)]
+        eng = InferenceEngine(cfg, params, **{**self.OPTIONS,
+                                              "decode_buckets": None})
+        calls, _ = self.watch(eng)
+        outs = {}
+        eng.add_request("stays", prompts[0], samplings[0])
+        eng.add_request("leaves", prompts[1], samplings[1])
+        self.run(eng, outs, steps=3)
+        eng.add_request("joins", prompts[2], samplings[2])
+        self.run(eng, outs)
+        self.holds(eng, calls)
+        steps = eng.step_log()["steps"]
+        decoded = [s for s in steps if s["decodes"]]
+        # The batch moved inside a bucket (carried), fell to another
+        # (drained: fetched in a step that dispatches from the host's
+        # tokens), and the last token came from a step that dispatched
+        # nothing.
+        assert {s["carried"] for s in decoded} == {0, 1}
+        drained = [s for s in decoded[1:] if not s["ahead"]]
+        assert drained and all(s["fetched"] == s["dispatched"] - 1
+                               and not s["carried"] for s in drained)
+        assert (steps[-1]["dispatched"], steps[-1]["fetched"]) \
+            == (0, len(decoded))
+
+    def test_a_drafting_engine_fetches_the_decode_it_dispatched(self):
+        from raytpu.models.mixtral import ExaoneMoe, ExaoneMoeConfig
+
+        cfg = dataclasses.replace(ExaoneMoeConfig.tiny(),
+                                  experts_held=(2, 4),
+                                  paged_attn="reference", choice_bias=0.05,
+                                  **_F32)
+        params = mixtral_init(ExaoneMoe(cfg), cfg, seed=1, batch=1)
+        eng = InferenceEngine(cfg, params, page_size=4, max_num_seqs=4,
+                              max_model_len=96)
+        assert eng._drafting is not None
+        for i, prompt in enumerate(self.prompts(cfg, 9, 6)):
+            eng.add_request(f"r{i}", prompt,
+                            self.sampling(50 + i, max_new_tokens=7 + i))
+        self.run(eng, {})
+        steps = eng.step_log()["steps"]
+        decoded = [s for s in steps if s["decodes"]]
+        assert decoded and len(decoded) + 1 >= len(steps) - 1
+        assert [(s["dispatched"], s["fetched"]) for s in decoded] \
+            == [(n, n) for n in range(1, len(decoded) + 1)]
+        assert all((s["dispatched"], s["fetched"], s["carried"],
+                    s["ahead"]) == (0, 0, 0, 0)
+                   for s in steps if not s["decodes"])
+        assert all(s["carried"] == 0 and 0 <= s["wait_cpu_s"] <= s["cpu_s"]
+                   for s in decoded)
+
     def test_tokens_go_to_the_program_in_one_form(self, llama_model):
         """The host's tokens of a drained step are put first, so the
         decode program's cache holds one entry a bucket and width
@@ -1263,8 +1350,10 @@ class TestStepLog:
         # Two decodes: the first fetches nothing, the second the first's
         # ids, and a step that dispatches none the second's.
         assert len(waits) == 3
-        assert [w["attributes"].get("bytes") for w in waits] \
-            == [None, 4 * 1, 4 * 1]
+        fetching = [s for s in eng.step_log()["steps"]
+                    if self.phases(s, "infer.decode.wait")]
+        assert [(s["dispatched"], s["fetched"]) for s in fetching] \
+            == [(1, 0), (2, 1), (0, 2)]
         for wait in waits:
             dec = by_id[wait["parent_span_id"]]
             assert dec["name"] == "infer.decode"
@@ -1281,7 +1370,8 @@ class TestStepLog:
             self, llama_model):
         _, params = llama_model
         eng = self.make_engine(params)
-        assert eng.step_log() == {"oldest_start": None, "steps": []}
+        empty = eng.step_log()
+        assert (empty["oldest_start"], empty["steps"]) == (None, [])
         eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=5))
         log = eng.step_log()
         # The prefill, four decodes, and the last one's fetch.

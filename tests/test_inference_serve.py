@@ -255,6 +255,48 @@ class TestSteppingLoopStepLog:
     """The loop's own phases in the step's record, and its death made
     visible (ISSUE 24)."""
 
+    def test_the_replica_names_what_stopped_its_interpreter(self):
+        """ISSUE 57: a collection while the replica serves is in the
+        step log's ``pauses``, on the steps' clock, and in the totals of
+        ``stats()``; the ordinals reach the replica's records."""
+        import gc
+
+        from raytpu.inference import engine as engine_mod
+
+        dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
+        try:
+            assert dep.step_log(pauses=True) == {"steps": [], "pauses": []}
+            before = dep.stats()["host_pauses"]["gc"]["2"]["count"]
+            published = engine_mod._gc_collections_total.value
+            stream = dep.generate([1, 2, 3], max_new_tokens=6)
+            first = next(iter(stream))
+            gc.collect()
+            tokens = [first] + list(stream)
+            assert len(tokens) == 6
+            deadline = time.monotonic() + 10
+            while len(dep.step_log()) < 7 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            log = dep.step_log(pauses=True)
+            stats = dep.stats()
+        finally:
+            dep.shutdown()
+        assert log["steps"] == dep.step_log()
+        full = [p for p in log["pauses"] if p[3]["generation"] == 2]
+        assert full and all(p[0] == "host.gc" for p in log["pauses"])
+        assert all(p[2] > log["steps"][0]["start"] for p in log["pauses"])
+        # This thread collected once, and it is not the one that steps.
+        assert any(p[3]["stepping"] is False for p in full)
+        totals = stats["host_pauses"]["gc"]["2"]
+        assert totals["count"] >= before + 1 and totals["seconds"] > 0
+        assert totals["longest_s"] >= max(p[2] - p[1] for p in full)
+        # A step's end hands the collections to the metrics pipeline.
+        assert engine_mod._gc_collections_total.value > published
+        assert engine_mod._gc_pause_total.value > 0
+        assert [(s["dispatched"], s["fetched"]) for s in log["steps"]] == [
+            (0, 0), (1, 0), (2, 1), (3, 2), (4, 3), (5, 4), (0, 5)]
+        assert all(0 <= s["cpu_s"] <= s["end"] - s["start"]
+                   for s in log["steps"])
+
     def test_step_log_carries_the_loops_phases_and_takes_no_lock(self):
         dep = serve.LLMDeployment._target(engine_options=ENGINE_OPTIONS)
         try:

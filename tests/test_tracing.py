@@ -442,7 +442,8 @@ class TestStepRecorder:
 
     def test_log_since_filters_and_reports_the_oldest_start(self):
         rec = tracing.StepRecorder()
-        assert rec.log() == {"oldest_start": None, "steps": []}
+        empty = rec.log()
+        assert (empty["oldest_start"], empty["steps"]) == (None, [])
         for i in range(4):
             self._step(rec, i=i)
         everything = rec.log()
@@ -470,7 +471,9 @@ class TestStepRecorder:
         rec = tracing.StepRecorder()
         st = self._step(rec, decodes=2)
         step, inner = _by_name("t.step")[0], _by_name("t.a.inner")[0]
-        assert step["attributes"] == {"decodes": 2}
+        # The record's fields: what the site gave and the thread's CPU time.
+        assert step["attributes"] == {"decodes": 2,
+                                      "cpu_s": step["attributes"]["cpu_s"]}
         assert inner["attributes"] == {"k": 1}
         a = _by_name("t.a")[0]
         assert inner["parent_span_id"] == a["span_id"]
@@ -520,6 +523,183 @@ class TestStepRecorder:
                              cwd=str(pathlib.Path(__file__).parent.parent))
         assert out.returncode == 0, out.stderr
         assert out.stdout.strip() == "1"
+
+
+# -- host pauses: what stopped the interpreter, on the step log's clock ---------
+
+
+class TestHostPauses:
+    """ISSUE 57: the collector's pauses in one bounded ring a process,
+    installed by the first recorder built, and a step's own CPU time."""
+
+    def test_a_full_collection_inside_a_step_leaves_one_entry(self):
+        import gc
+
+        rec = tracing.StepRecorder()
+        before = tracing.host_pause_totals()["gc"]["2"]["count"]
+        with rec.step("t.step") as st:
+            with rec.phase("t.a"):
+                gc.collect()
+        log = rec.log(since=st.t0)
+        (pause,) = [p for p in log["pauses"] if p[1] >= st.t0]
+        kind, t0, t1, attrs = pause
+        assert kind == "host.gc" and attrs["generation"] == 2
+        assert st.t0 <= t0 <= t1 <= st.t1
+        assert attrs["stepping"] is True and attrs["collected"] >= 0
+        totals = tracing.host_pause_totals()["gc"]["2"]
+        assert totals["count"] == before + 1
+        assert totals["longest_s"] >= t1 - t0 > 0
+        assert totals["seconds"] >= totals["longest_s"]
+        # Plain data: a reader may change what it was given.
+        attrs["generation"] = 7
+        assert tracing.host_pauses(st.t0)[0][3]["generation"] == 2
+
+    def test_pauses_honour_since_in_the_log_and_in_the_module(self):
+        import gc
+
+        rec = tracing.StepRecorder()
+        gc.collect()
+        cut = time.perf_counter()
+        gc.collect()
+        later = rec.log(since=cut)["pauses"]
+        assert len(later) == 1 and later[0][2] > cut
+        everything = tracing.host_pauses()
+        assert len(everything) >= 2 and everything[-1] == later[0]
+        assert [p[1] for p in everything] == sorted(
+            p[1] for p in everything)  # oldest first
+        assert tracing.host_pauses(time.perf_counter()) == []
+
+    def test_a_short_young_collection_is_counted_and_not_kept(self):
+        import gc
+
+        tracing.StepRecorder()
+        counted = tracing.host_pause_totals()["gc"]["0"]["count"]
+        kept = len(tracing.host_pauses())
+        cut = time.perf_counter()
+        gc.collect(0)
+        assert tracing.host_pause_totals()["gc"]["0"]["count"] == counted + 1
+        # Kept only had it taken over a millisecond (a loaded machine).
+        new = tracing.host_pauses(cut)
+        assert all(p[2] - p[1] > tracing.GC_KEPT_OVER_S for p in new)
+        assert len(tracing.host_pauses()) == kept + len(new)
+
+    def test_a_thread_that_does_not_step_is_told_apart(self):
+        import gc
+        import threading
+
+        rec = tracing.StepRecorder()
+        with rec.step("t.step"):
+            pass
+        cut = time.perf_counter()
+        other = threading.Thread(target=gc.collect)
+        other.start()
+        other.join()
+        (pause,) = tracing.host_pauses(cut)
+        assert pause[3]["stepping"] is False
+
+    def test_the_ring_is_bounded(self, monkeypatch):
+        import collections
+        import gc
+
+        tracing.StepRecorder()
+        assert tracing._pauses.maxlen == tracing.PAUSE_RING
+        monkeypatch.setattr(tracing, "_pauses", collections.deque(maxlen=4))
+        for _ in range(7):
+            gc.collect()
+        assert len(tracing.host_pauses()) == 4
+
+    def test_the_callback_is_installed_once_and_not_by_import(self):
+        import gc
+        import subprocess
+        import sys
+
+        for _ in range(3):
+            tracing.StepRecorder()
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        code = ("import gc\n"
+                "import raytpu\n"
+                "from raytpu.util import tracing\n"
+                "assert tracing._on_gc not in gc.callbacks\n"
+                "gc.collect()\n"
+                "assert tracing.host_pauses() == []\n"
+                "tracing.StepRecorder()\n"
+                "gc.collect()\n"
+                "print(gc.callbacks.count(tracing._on_gc),"
+                " len(tracing.host_pauses()))\n")
+        out = subprocess.run([sys.executable, "-c", code], timeout=120,
+                             capture_output=True, text=True,
+                             cwd=str(pathlib.Path(__file__).parent.parent))
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.split() == ["1", "1"]
+
+    def test_a_full_collection_enters_the_profilers_annotation(
+            self, monkeypatch):
+        import gc
+
+        seen = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name))
+
+        tracing.StepRecorder()
+        monkeypatch.setattr(tracing, "_trace_annotation", Annotation)
+        gc.collect(0)
+        assert seen == []  # a young collection is no event of the trace
+        gc.collect()
+        assert seen == [("enter", "host.gc"), ("exit", "host.gc")]
+
+    def test_what_is_unpublished_is_handed_out_once(self):
+        import gc
+
+        tracing.StepRecorder()
+        tracing.gc_unpublished()
+        assert tracing.gc_unpublished() is None
+        gc.collect()
+        gc.collect(0)
+        counts, seconds = tracing.gc_unpublished()
+        assert counts[2] == 1 and counts[0] >= 1 and seconds > 0
+        assert tracing.gc_unpublished() is None
+
+    def test_a_steps_cpu_time_lies_inside_its_wall_time(self):
+        rec = tracing.StepRecorder()
+        with rec.step("t.step", {"decodes": 1}):
+            with rec.phase("t.launch"):
+                sum(range(20000))
+            with rec.phase("t.wait", cpu="wait_cpu_s"):
+                time.sleep(0.02)
+            with rec.phase("t.wait", cpu="wait_cpu_s"):
+                sum(range(20000))
+        (step,) = rec.log()["steps"]
+        wall = step["end"] - step["start"]
+        assert 0 < step["wait_cpu_s"] <= step["cpu_s"] <= wall
+        # The sleep is wall time and no CPU time: off the CPU.
+        assert wall - step["cpu_s"] >= 0.015
+        waits = [t1 - t0 for name, t0, t1 in step["phases"]
+                 if name == "t.wait"]
+        assert step["wait_cpu_s"] <= sum(waits)
+        # A phase with no step open has no record to count into.
+        with rec.phase("t.early", cpu="wait_cpu_s"):
+            pass
+
+    def test_kept_values_are_the_rings_and_cost_no_walk(self):
+        kept = tracing.StepRecorder(maxlen=8, keep=("decodes",))
+        walked = tracing.StepRecorder(maxlen=8)
+        for i in range(21):
+            for rec in (kept, walked):
+                with rec.step("t.step", {"decodes": i % 3, "i": i}):
+                    pass
+            assert kept.values("decodes") == walked.values("decodes")
+        assert kept.values("decodes") == [
+            i % 3 for i in range(13, 21) if i % 3]
+        assert len(kept._kept["decodes"]) <= 8
+        assert kept.values("i") == list(range(13, 21))  # not kept: walked
 
 
 # -- metrics satellites -------------------------------------------------------
