@@ -14,9 +14,11 @@ layers and 32 held experts, engine and cache as the cell builds them:
 difference over the largest reference logit, over the prompt's last row
 and the decoded positions) at every seed, and on the last ``--controls``
 seeds the controls, programs wrong in one way each. ``long`` sends one
-prompt of ``--tokens`` through the *chunk* program (2,048 rows a call,
-the absorbed kernel at ``T`` > 1 against the latent pages) and eight
-decodes over that cache, against the reference computed in blocks. One
+prompt of ``--tokens`` through the *chunk* program (2,048 rows a call:
+since PR 58 the expanded form, each chunk's own rows and the cached
+segments before them under the flash kernel, merged by log-sum-exp) and
+eight absorbed decodes over that cache, against the reference computed
+in blocks. One
 engine a program is built and reused from seed to seed (the programs
 take the parameters as an argument), so a seed costs its weights, its
 requests and the reference.

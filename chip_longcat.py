@@ -17,8 +17,10 @@ and the decoded positions) at every seed, and on the last ``--controls``
 seeds the controls, programs wrong in one way each. ``long`` sends one
 prompt of ``--tokens`` through the *chunk* program (the cell serves its
 prompts whole; here a chunk is as long as the mix's whole-prompt bucket:
-the absorbed kernel at ``T`` > 1, four query tokens of 64 heads a grid
-step, against both pools a layer) and eight decodes over that cache,
+over the break-even of 171 queries, so since PR 58 the expanded form,
+a chunk's own rows and the cached segments before them under the flash
+kernel, against both pools a layer) and eight absorbed decodes over
+that cache,
 against the reference computed in blocks. One engine a program is built
 and reused from seed to seed (``chip_joyai.Served``).
 

@@ -369,6 +369,11 @@ class InferenceEngine:
         # Rows a query's attention keeps of those its indexer scores
         # (``serving.indexer``); None: it reads every cached row.
         self._index_topk = served.indexer[1] if served.indexer else None
+        # How the family attends a chunk (``Serving.chunk_parts``), and
+        # the chunks and parts that went another way than a decode row.
+        self._chunk_parts = served.chunk_parts
+        self._chunks_expanded = 0
+        self._chunk_segments_expanded = 0
 
         self._config = model_config
         # The working copy, made once and before anything else takes
@@ -1207,6 +1212,14 @@ class InferenceEngine:
                 [seq.request_id], p_used, kind=kind))
             fn, inputs = self._chunk_fn, (tokens, positions, dests, tables)
             program = ("_chunk", f"{bucket}x{p_used}")
+            parts = self._chunk_parts and self._chunk_parts(
+                bucket, start, self.cache.page_size)
+            if parts:
+                fields = self.recorder.open.fields
+                fields["chunk_expanded"] = \
+                    fields.get("chunk_expanded", 0) + parts[1]
+                self._chunks_expanded += 1
+                self._chunk_segments_expanded += parts[1]
         seats, *inputs = self._hand(
             (self._seats([seq.request_id], 1), *inputs))
         if self._drafting is None:
@@ -1692,6 +1705,11 @@ class InferenceEngine:
         ``dsa_rows_scored`` and ``dsa_rows_selected``: the cached
         positions one layer's indexers scored for the step's queries,
         decoded and prefilled, and those their attention then read.
+        ``chunk_expanded``, where the family attended the step's chunk in
+        another form than a decode row (``serving.chunk_parts``: a
+        latent model's chunk over its break-even, expanded): the parts
+        it took, the chunk's own rows one and one a segment of cached
+        rows before them; absent where the chunk went as a decode row.
         ``"oldest_start"`` is the start of the oldest step still held,
         so a reader can tell a truncated log from a quiet engine.
         ``"pauses"`` holds what stopped this process's interpreter and
@@ -1736,6 +1754,11 @@ class InferenceEngine:
             "decodes_ahead": self._decodes_ahead,
             "decodes_drained": self._decodes_drained,
             "ahead_rows_dropped": self._ahead_rows_dropped,
+            # Chunks the family attended expanded and the parts they
+            # took (a record's ``chunk_expanded``, summed); 0 where every
+            # chunk went as a decode row goes.
+            "chunks_expanded": self._chunks_expanded,
+            "chunk_segments_expanded": self._chunk_segments_expanded,
             "paged_attn_impl": self.paged_attn_impl,
             # Bytes of the tree the programs take, by dtype, over all
             # shards: all in the compute type but the norms' leaves.

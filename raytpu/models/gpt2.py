@@ -452,6 +452,12 @@ class Serving:
     values are both read, and no V pool. The programs then take and give
     back ``k_caches`` one pool a layer and ``v_caches`` empty.
     ``kv_heads`` and ``head_dim`` are then the query's, and size no pool.
+    Such a layer may attend a chunk in another algebraic form than a
+    decode row (``chunk_parts(T, start, page_size)``: ``None`` where the
+    chunk program's ``T`` rows from ``start`` on attend as a decode row
+    does, else ``(rows of a segment, parts)``, the parts the cached rows
+    and the chunk's own are expanded and attended in); the engine asks
+    it only to say so in the step's record.
 
     A latent layer with an indexer (``indexer``: ``(index_row,
     index_topk)``) holds a second pool under the same block tables and
@@ -489,6 +495,7 @@ class Serving:
     expert_pairs: bool = False
     layer_windows: Tuple[Optional[int], ...] = ()
     kv_row: Optional[int] = None
+    chunk_parts: Optional[Callable] = None
     indexer: Optional[Tuple[int, int]] = None  # (index_row, index_topk)
     layer_states: Tuple[Optional[Tuple[int, ...]], ...] = ()
     # Not None: the family drafts for itself (a prediction module), and

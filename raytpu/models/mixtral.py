@@ -260,13 +260,26 @@ class LatentMoEConfig(MixtralConfig):
     def attn_scope(self, kind: str) -> str:
         return "attn.mla"
 
+    def chunk_parts(self, t: int, start, page_size: int):
+        """How ``LatentAttention.step`` attends one sequence's ``t``
+        tokens from ``start`` on: ``None`` absorbed, or expanded in
+        ``(rows of a segment, parts)``, by these widths
+        (:func:`raytpu.ops.mla_attention.expands`, ``expanded_parts``)."""
+        from raytpu.ops.mla_attention import expanded_parts, expands
+
+        if not expands(t, rank=self.kv_lora_rank, nope_dim=self.qk_nope_dim,
+                       rope_dim=self.qk_rope_dim, v_dim=self.v_head_dim):
+            return None
+        return expanded_parts(t, start, page_size)
+
     @property
     def serving(self):
         from raytpu.ops.mla_attention import latent_row_width
 
         return dataclasses.replace(
             super().serving,
-            kv_row=latent_row_width(self.kv_lora_rank, self.qk_rope_dim))
+            kv_row=latent_row_width(self.kv_lora_rank, self.qk_rope_dim),
+            chunk_parts=self.chunk_parts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -453,8 +466,10 @@ class GlmDsaConfig(LatentMoEConfig):
 
     @property
     def serving(self):
+        # A chunk reads the rows its indexer chooses, not its context.
         return dataclasses.replace(
-            super().serving, indexer=(self.index_head_dim, self.index_topk))
+            super().serving, indexer=(self.index_head_dim, self.index_topk),
+            chunk_parts=None)
 
     @classmethod
     def tiny(cls) -> "GlmDsaConfig":

@@ -19,12 +19,18 @@ scaled latent, so the absorbed form is the same with or without.
 - ``prefill`` (and ``__call__``, training) is the *expanded* form: the
   latent goes through ``kv_b_proj`` and flash attention runs on heads of
   ``qk_nope_dim + qk_rope_dim`` (values zero-padded to that width).
-- ``step`` (``[B, T]`` positions: a prompt's chunk, a decode step) is
-  the *absorbed* form against the paged latent cache
-  (:mod:`raytpu.ops.mla_attention`): a layer has
-  ONE pool, a token's row ``[normed latent | roped key | zeros]``, read
-  once as keys and as values; ``kv_b_proj`` is folded into the query
-  (``W_uk``) and applied to the attended latent (``W_uv``).
+- ``step`` (``[B, T]`` positions: a prompt's chunk, a decode step)
+  attends the paged latent cache (:mod:`raytpu.ops.mla_attention`): a
+  layer has ONE pool, a token's row ``[normed latent | roped key |
+  zeros]``. A decode row, and any ``T`` under the break-even of
+  :func:`raytpu.ops.mla_attention.expands`, is the *absorbed* form: the
+  row read once as keys and as values, ``kv_b_proj`` folded into the
+  query (``W_uk``) and applied to the attended latent (``W_uv``). A
+  prompt's chunk (one sequence, ``T`` over the break-even) is the
+  expanded form again, a third of the absorbed form's FLOPs: its own
+  rows as ``prefill`` does them, the cached rows before them gathered
+  and put through ``kv_b_proj`` a segment at a time under the flash
+  kernel, the parts merged by their log-sum-exps in float32.
 
 ``rope_interleave``: the roped values are read as adjacent pairs
 ``(2j, 2j + 1)`` and laid out ``[evens | odds]`` before the rotation by
@@ -37,6 +43,7 @@ from __future__ import annotations
 import functools
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from raytpu.models.llama import (RMSNorm, apply_rope, apply_rope_single,
@@ -52,7 +59,8 @@ class LatentAttention(nn.Module):
     """``config`` carries ``q_lora_rank``, ``kv_lora_rank``,
     ``qk_nope_dim``, ``qk_rope_dim``, ``v_head_dim``, ``rope_interleave``,
     ``mla_scale_q_lora`` and ``mla_scale_kv_lora`` beside what every
-    llama-family config has."""
+    llama-family config has, and says by ``chunk_parts`` which ``T``
+    attend expanded (:class:`raytpu.models.mixtral.LatentMoEConfig`)."""
 
     config: object
 
@@ -134,20 +142,69 @@ class LatentAttention(nn.Module):
         cos, sin = rope_tables(c.qk_rope_dim, jnp.arange(t), c.rope_theta)
         q_pe = apply_rope(q_pe.transpose(0, 2, 1, 3), cos, sin)
         k_pe = apply_rope(k_pe[:, None], cos, sin)          # [B, 1, T, rope]
-        kv = self.kv_b_proj(c_kv).reshape(b, t, h, nope + vd)
-        kv = kv.transpose(0, 2, 1, 3)
         q = jnp.concatenate([q_nope.transpose(0, 2, 1, 3), q_pe], -1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_pe, (b, h, t,
-                                                     c.qk_rope_dim))], -1)
-        # The flash kernels take one head size: the values ride on the
-        # first ``vd`` lanes of a head as wide as the keys'.
-        v = jnp.pad(kv[..., nope:],
-                    ((0, 0),) * 3 + ((0, q.shape[-1] - vd),))
+        k, v = self._expand(c_kv, k_pe)
         y = flash_attention(q, k, v, causal=True, sm_scale=self.sm_scale,
                             force=c.attn_impl)[..., :vd]
         y = y.transpose(0, 2, 1, 3).reshape(b, t, h * vd)
         return self.o_proj(y), latent_rows(c_kv, k_pe[:, 0])
+
+    @nn.nowrap  # no scope of its own: the caller's operations, as written
+    def _expand(self, c_kv, k_pe):
+        """The expanded form's keys and values of ``c_kv`` [B, S, rank]
+        and the roped ``k_pe`` [B, 1, S, rope] -> ``(k, v)`` [B, H, S,
+        nope + rope]: ``kv_b_proj``'s key part beside the shared roped
+        key, and its values zero-padded to the same head."""
+        c = self.config
+        b, s, _ = c_kv.shape
+        h, nope, vd = c.n_head, c.qk_nope_dim, c.v_head_dim
+        kv = self.kv_b_proj(c_kv).reshape(b, s, h, nope + vd)
+        kv = kv.transpose(0, 2, 1, 3)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (b, h, s,
+                                                     c.qk_rope_dim))], -1)
+        # The flash kernels take one head size: the values ride on the
+        # first ``vd`` lanes of a head as wide as the keys'.
+        v = jnp.pad(kv[..., nope:],
+                    ((0, 0),) * 3 + ((0, k.shape[-1] - vd),))
+        return k, v
+
+    def _expanded(self, q_nope, q_pe, c_kv, k_pe, pages, block_tables,
+                  start, segment, parts):
+        """The expanded form's attention of one sequence's chunk:
+        ``q_nope`` [T, H, nope] and the roped ``q_pe`` [T, H, rope] of
+        the tokens from ``start`` on, whose ``c_kv`` [T, rank] and roped
+        ``k_pe`` [T, rope] are in ``pages`` already -> [T, H *
+        v_head_dim]. One softmax a query over its own rows up to itself
+        and every cached row before ``start``, in ``parts`` parts
+        (:func:`raytpu.ops.mla_attention.expanded_parts`): its own rows,
+        then the cached ones ``segment`` at a time, only a live segment
+        gathered and expanded, and the last one's rows from ``start`` on
+        no keys."""
+        from raytpu.ops.flash_attention import (flash_attention_part,
+                                                merge_parts)
+        from raytpu.ops.mla_attention import gather_segment
+
+        c = self.config
+        t, h, vd = q_nope.shape[0], c.n_head, c.v_head_dim
+        attend = functools.partial(flash_attention_part,
+                                   sm_scale=self.sm_scale, force=c.attn_impl)
+        q = jnp.concatenate([q_nope, q_pe], -1).transpose(1, 0, 2)[None]
+        own = attend(q, *self._expand(c_kv[None], k_pe[None, None]),
+                     causal=True)
+
+        def past(i, merged):
+            rows = gather_segment(pages, block_tables[0], i, segment)
+            rows = rows[None].astype(c.dtype)
+            k_pe = rows[..., c.kv_lora_rank:c.kv_lora_rank + c.qk_rope_dim]
+            k, v = self._expand(rows[..., :c.kv_lora_rank], k_pe[:, None])
+            o, lse = attend(q, k, v, causal=False,
+                            kv_len=jnp.minimum(start - i * segment, segment))
+            return merge_parts([merged, (o[..., :vd], lse)])
+
+        y, _ = jax.lax.fori_loop(
+            0, parts - 1, past, merge_parts([(own[0][..., :vd], own[1])]))
+        return y[0].astype(c.dtype).transpose(1, 0, 2).reshape(t, h * vd)
 
     def _absorbed(self, q_nope, q_pe, pages, block_tables, positions):
         """The absorbed form's attention: ``q_nope`` [B, T, H, nope] and
@@ -170,7 +227,10 @@ class LatentAttention(nn.Module):
         absolute ``positions`` [B, T], against the latent pages (see
         :meth:`LlamaAttention.step` for the arguments): the rows scatter
         into ``dests`` [B, T] first, then each attends every cached
-        position ``<=`` its own. Returns ``(out [B * T, E], pages')``."""
+        position ``<=`` its own: absorbed, or, where one sequence brings
+        enough queries that ``config.chunk_parts`` says so (a prompt's
+        chunk), expanded. Returns ``(out [B * T, E],
+        pages')``."""
         from raytpu.ops.mla_attention import latent_rows
         from raytpu.ops.paged_attention import scatter_kv_slots
 
@@ -183,9 +243,15 @@ class LatentAttention(nn.Module):
         k_pe = apply_rope_single(k_pe[:, None], cos, sin)[:, 0]
         pages = scatter_kv_slots(pages, dests.reshape(b * t),
                                  latent_rows(c_kv, k_pe))
-        y = self._absorbed(q_nope.reshape(b, t, c.n_head, -1),
-                           q_pe.reshape(b, t, c.n_head, -1), pages,
-                           block_tables, positions)
+        start = positions[0, 0]
+        parts = c.chunk_parts(t, start, pages.shape[1]) if b == 1 else None
+        if parts is not None:
+            y = self._expanded(q_nope, q_pe, c_kv, k_pe, pages, block_tables,
+                               start, *parts)
+        else:
+            y = self._absorbed(q_nope.reshape(b, t, c.n_head, -1),
+                               q_pe.reshape(b, t, c.n_head, -1), pages,
+                               block_tables, positions)
         return self.o_proj(y.reshape(b * t, -1)), pages
 
 

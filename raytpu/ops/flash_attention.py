@@ -174,13 +174,15 @@ def _causal_mask(t_q: int, t_k: int, window=None):
 
 
 def _attn_fwd_reference(q, k, v, causal: bool, sm_scale: float,
-                        window=None):
+                        window=None, kv_len=None):
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
         mask = _causal_mask(q.shape[2], k.shape[2], window)
         s = jnp.where(mask[None, None], s, -1e30)
+    if kv_len is not None:
+        s = jnp.where(jnp.arange(k.shape[2]) < kv_len, s, -1e30)
     lse = jax.scipy.special.logsumexp(s, axis=-1, keepdims=True)
     p = jnp.exp(s - lse)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
@@ -382,17 +384,23 @@ _TN = ((0,), (0,))  # a.T @ b
 
 
 def _walk_keys(tile, q_start, block_q: int, sub: int, n_sub: int, first,
-               chunk: int, off: int, causal: bool, window=None):
+               chunk: int, off: int, causal: bool, window=None, kv_len=None):
     """The forward's and dq's walk: ``tile(rows, limit, masked, q0)`` over
     the sub-blocks of keys a held block of queries sees in this major
     block. Rolled loops, one body masked and one not; the diagonal's
-    tiles unrolled in chunks where :func:`_diagonal_chunk` allows."""
+    tiles unrolled in chunks where :func:`_diagonal_chunk` allows.
+    ``kv_len`` (not causal: a traced scalar): the keys from it on are
+    none. A sub-block past it is not visited, the one it crosses is
+    masked, and ``limit`` is then the last live key's row in the tile."""
     lo, whole_lo, whole_hi, hi = _seen_keys(
         q_start, block_q, sub, first, n_sub, off, causal, window)
+    if kv_len is not None:
+        whole_hi = jnp.clip(kv_len // sub, first, hi)
+        hi = jnp.clip((kv_len + sub - 1) // sub, first, hi)
 
     def step(j, carry, masked):
-        tile(_sub_rows(j - first, sub, n_sub), q_start + off - j * sub,
-             masked, 0)
+        limit = q_start + off if kv_len is None else kv_len - 1
+        tile(_sub_rows(j - first, sub, n_sub), limit - j * sub, masked, 0)
         return carry
 
     if window is not None:
@@ -401,15 +409,16 @@ def _walk_keys(tile, q_start, block_q: int, sub: int, n_sub: int, first,
     if chunk:
         for q0 in range(0, block_q, chunk):
             tile(_rows(q_start + off + q0, chunk), 0, True, q0)
-    elif causal:
+    elif causal or kv_len is not None:
         _loop(whole_hi, hi, functools.partial(step, masked=True))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                   *, causal: bool, sm_scale: float, scale_q: bool, sub: int,
-                  chunk: int, off: int, steps, window=None):
+                  chunk: int, off: int, steps, window=None, kv_len=None):
     """``steps``: the grid's steps along its last two axes (blocks of
-    queries, major blocks of keys), in all three kernels."""
+    queries, major blocks of keys), in all three kernels. ``kv_len``:
+    as :func:`_walk_keys` takes it."""
     block_q, d = q_ref.shape[1:]
     n_sub = k_ref.shape[1] // sub
     iq = _grid_index(1, steps[0])
@@ -427,7 +436,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     if scale_q:
         q = q * sm_scale
     q_start = iq * block_q
-    rel = _key_less_query(sub, block_q) if causal else None
+    if kv_len is not None:  # a key's row alone decides: no diagonal
+        rel = lax.broadcasted_iota(jnp.int32, (sub, block_q), 0)
+    else:
+        rel = _key_less_query(sub, block_q) if causal else None
 
     def tile(rows, limit, masked, q0):
         """The keys in ``rows`` of the major block against the queries
@@ -451,13 +463,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         m_scr[:, lanes] = m_new
 
     _walk_keys(tile, q_start, block_q, sub, n_sub, ik * n_sub, chunk, off,
-               causal, window)
+               causal, window, kv_len)
 
     @_when(ik == steps[1] - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l).T.astype(o_ref.dtype)
         lse_ref[0, :, :block_q] = m_scr[...] + jnp.log(l)
+
+
+def _flash_kernel_to(len_ref, *refs, **kw):
+    """:func:`_flash_kernel` with a key limit, the scalar prefetched."""
+    _flash_kernel(*refs, kv_len=len_ref[0], **kw)
 
 
 def _walked_index(block: int, major: int, n_major: int, off: int,
@@ -496,8 +513,12 @@ def _scale_on_operand(sm_scale: float) -> bool:
 
 
 def _flash_forward_pallas(q, k, v, causal: bool, sm_scale: float,
-                          block_q, block_k, interpret: bool, window=None):
-    """``(o [B, H, T, D], lse [B, H, T])``."""
+                          block_q, block_k, interpret: bool, window=None,
+                          kv_len=None):
+    """``(o [B, H, T, D], lse [B, H, T])``. ``kv_len`` (an int32 scalar,
+    not causal): only the first ``kv_len`` keys are keys; it reaches the
+    kernel as a prefetched scalar, and without it the call is the one it
+    was."""
     from jax.experimental import pallas as pl  # noqa: PLC0415
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
@@ -512,17 +533,24 @@ def _flash_forward_pallas(q, k, v, causal: bool, sm_scale: float,
     lanes = _stat_lanes(block_q, n_qb)
     off = t_kv - t_q  # bottom-aligned diagonal (reference tril k=off)
 
-    def held(ib, iq, ik):
+    def held(ib, iq, ik, *_):
         return (ib, iq, 0)
 
     walked = _walked_index(block_q, major, n_major, off, causal, window)
-    o3, lse3 = pl.pallas_call(
-        functools.partial(
-            _flash_kernel, causal=causal, sm_scale=sm_scale,
-            scale_q=_scale_on_operand(sm_scale), sub=sub,
-            chunk=_diagonal_chunk(chunk, block_q, sub, off, n_major == 1,
-                                  causal, window),
-            off=off, steps=(n_qb, n_major), window=window),
+    if kv_len is not None:
+        every = walked
+
+        def walked(ib, iq, ik, len_ref):  # a major block past it: not fetched
+            last = jnp.maximum(len_ref[0] - 1, 0) // major
+            return every(ib, iq, jnp.minimum(ik, last))
+
+    kernel = functools.partial(
+        _flash_kernel, causal=causal, sm_scale=sm_scale,
+        scale_q=_scale_on_operand(sm_scale), sub=sub,
+        chunk=_diagonal_chunk(chunk, block_q, sub, off, n_major == 1,
+                              causal, window),
+        off=off, steps=(n_qb, n_major), window=window)
+    grid = dict(
         grid=(bh, n_qb, n_major),
         in_specs=[
             pl.BlockSpec((1, block_q, d), held),
@@ -531,20 +559,30 @@ def _flash_forward_pallas(q, k, v, causal: bool, sm_scale: float,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), held),
-            pl.BlockSpec((1, 1, lanes), lambda ib, iq, ik: (ib, 0, iq)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, n_qb * lanes), jnp.float32),
+            pl.BlockSpec((1, 1, lanes), lambda ib, iq, ik, *_: (ib, 0, iq)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, block_q), jnp.float32),
             pltpu.VMEM((1, block_q), jnp.float32),
             pltpu.VMEM((d, block_q), jnp.float32),
+        ])
+    operands = (q.reshape(bh, t_q, d), k.reshape(bh, t_kv, d),
+                v.reshape(bh, t_kv, d))
+    if kv_len is not None:
+        kernel = functools.partial(_flash_kernel_to, **kernel.keywords)
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **grid))
+        operands = (jnp.asarray(kv_len, jnp.int32).reshape(1),) + operands
+    o3, lse3 = pl.pallas_call(
+        kernel,
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, 1, n_qb * lanes), jnp.float32),
         ],
         interpret=interpret,
+        **grid,
         **_compiler(interpret),
-    )(q.reshape(bh, t_q, d), k.reshape(bh, t_kv, d), v.reshape(bh, t_kv, d))
+    )(*operands)
     if lanes != block_q:
         lse3 = lse3.reshape(bh, n_qb, lanes)[:, :, :block_q]
     return o3.reshape(b, h, t_q, d), lse3.reshape(b, h, t_q)
@@ -771,16 +809,23 @@ _BHT = "bh."  # and of lse [B, H, T]
 RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_o", "flash_lse")
 
 
-def _flash_run(q, k, v, causal, sm_scale, use_pallas, window):
-    """``(o, lse [B, H, T])`` by the implementation ``use_pallas``."""
+def _flash_run(q, k, v, causal, sm_scale, use_pallas, window, kv_len=None,
+               blocks=None):
+    """``(o, lse [B, H, T])`` by the implementation ``use_pallas``.
+    ``blocks``: ``(DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)`` as a caller read
+    them that is traced once for many calls; None: as they stand now."""
     if use_pallas in ("tpu", "interpret"):
+        block_q, block_k = blocks or (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+        fwd = functools.partial(
+            _flash_forward_pallas, causal=causal, sm_scale=sm_scale,
+            block_q=block_q, block_k=block_k,
+            interpret=(use_pallas == "interpret"), window=window)
+        if kv_len is None:
+            return per_shard(fwd, (q, k, v), (_BHTD,) * 3, (_BHTD, _BHT))
         return per_shard(
-            functools.partial(
-                _flash_forward_pallas, causal=causal, sm_scale=sm_scale,
-                block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                interpret=(use_pallas == "interpret"), window=window),
-            (q, k, v), (_BHTD,) * 3, (_BHTD, _BHT))
-    o, lse = _attn_fwd_reference(q, k, v, causal, sm_scale, window)
+            lambda q, k, v, n: fwd(q, k, v, kv_len=n), (q, k, v, kv_len),
+            (_BHTD,) * 3 + ("",), (_BHTD, _BHT))
+    o, lse = _attn_fwd_reference(q, k, v, causal, sm_scale, window, kv_len)
     return o, lse[..., 0]
 
 
@@ -846,3 +891,48 @@ def flash_attention(q, k, v, *, causal: bool = True,
         raise ValueError("a window is a causal layer's: causal=False")
     return _flash(q, k, v, causal, sm_scale, resolve_flash_impl(force),
                   window)
+
+
+def flash_attention_part(q, k, v, *, causal: bool, sm_scale: float,
+                         force: Optional[str] = None, kv_len=None):
+    """The forward alone, for a caller that attends its keys a part at a
+    time: ``(o [B, H, T, D], lse float32 [B, H, T])``, the part's
+    attention and the log of its softmax's sum, by which
+    :func:`merge_parts` weighs it against the others. ``kv_len`` (an
+    int32 scalar, traced or not; not causal): of ``k`` and ``v`` only the
+    first ``kv_len`` rows are keys; the rest enter no softmax, and a
+    part with none has an ``lse`` of -1e30, at which the merge gives it
+    no weight. No gradient is defined."""
+    if kv_len is not None and causal:
+        raise ValueError("a key limit is a part's before the diagonal: "
+                         "causal=False")
+    if kv_len is not None:
+        kv_len = jnp.asarray(kv_len, jnp.int32)
+    return _flash_part(q, k, v, kv_len, causal=causal,
+                       sm_scale=float(sm_scale),
+                       impl=resolve_flash_impl(force),
+                       blocks=(DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K))
+
+
+# Jitted: a program that attends in parts calls this once a part a layer,
+# and traces and lowers the kernel once a signature, not once a call
+# (`setup_s`: a chunk program of 12 layers holds 24 calls of two kinds).
+@functools.partial(jax.jit,
+                   static_argnames=("causal", "sm_scale", "impl", "blocks"))
+def _flash_part(q, k, v, kv_len, *, causal, sm_scale, impl, blocks):
+    return _flash_run(q, k, v, causal, sm_scale, impl, None, kv_len, blocks)
+
+
+def merge_parts(parts):
+    """One softmax over the keys of all ``parts``, each ``(o [..., T, D],
+    lse [..., T])`` as :func:`flash_attention_part` gives them: ``(o
+    float32, lse)``, the parts weighed by their sums in float32."""
+    (o, lse), *more = parts
+    o = o.astype(jnp.float32)
+    for o_part, lse_part in more:
+        both = jnp.logaddexp(lse, lse_part)
+        o = (o * jnp.exp(lse - both)[..., None]
+             + o_part.astype(jnp.float32)
+             * jnp.exp(lse_part - both)[..., None])
+        lse = both
+    return o, lse

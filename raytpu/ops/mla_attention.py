@@ -32,8 +32,17 @@ step's first block started by the step before. A grid step's rows are
 ``(token, head)``: all ``H`` heads of ``block_q`` tokens against one
 shared block of rows, ``[rows, width] x [slots, width]^T``, the position
 mask, the online softmax in float32, ``p x block[:, :rank]``. Decode is
-one token a sequence (32 rows at 32 heads); a prompt's chunk runs the
-same kernel with ``block_q`` tokens a step.
+one token a sequence (32 rows at 32 heads); ``T`` > 1 tokens a sequence
+run the same kernel with ``block_q`` tokens a step.
+
+**Which ``T`` takes which form.** The absorbed form pays ``2 (width +
+rank)`` FLOPs a (query, head, key), the expanded one ``4 (nope + rope)``
+and the key's expansion once: :func:`expands` is the break-even, a
+number of queries of one sequence (171 at the widths above). Under it
+(a decode row, a verify step's two) ``LatentAttention.step`` calls this
+kernel; over it (a prompt's chunk) it attends expanded, through
+:mod:`raytpu.ops.flash_attention`, the cached rows :func:`expanded_parts`
+segments at a time, and this kernel is not in the chunk program.
 
 ``mla_paged_attention_reference`` is the dense float32 form over the
 gathered pages: the numerics ground truth, and the CPU default.
@@ -55,6 +64,9 @@ from raytpu.ops.paged_attention import (_LANES, _NEG_INF, _fit_q_block,
                                         resolve_paged_impl)
 
 __all__ = [
+    "expanded_parts",
+    "expands",
+    "gather_segment",
     "latent_row_width",
     "latent_rows",
     "mla_paged_attention",
@@ -71,6 +83,45 @@ def latent_row_width(rank: int, rope_dim: int) -> int:
     """Lanes of a pool row as held: the latent and the roped key, rounded
     up to whole 128-lane tiles."""
     return _whole_lane_tiles(rank + rope_dim)
+
+
+def expands(t: int, *, rank: int, nope_dim: int, rope_dim: int,
+            v_dim: int) -> bool:
+    """Whether ``t`` query tokens of one sequence attend its cache
+    cheaper *expanded* than absorbed, by the products' FLOPs alone. A
+    (query, head, key) costs the absorbed form a score over the row as
+    held and values over the latent, ``2 (width + rank)``; the expanded
+    form scores and weighs heads of ``nope_dim + rope_dim`` (the values
+    ride a head as wide as the keys'), and pays ``2 rank (nope_dim +
+    v_dim)`` a (key, head) once to put the key through ``kv_b_proj``.
+    The keys cancel: the break-even is a number of queries (171 at a
+    latent of 512, a roped key of 64 and heads of 128: a decode row and a
+    verify step's two stay absorbed, a prompt's chunk expands)."""
+    absorbed = 2 * (latent_row_width(rank, rope_dim) + rank)
+    expanded = 2 * 2 * (nope_dim + rope_dim)
+    return t * (absorbed - expanded) > 2 * rank * (nope_dim + v_dim)
+
+
+def expanded_parts(t: int, start: int, page_size: int):
+    """How a chunk of ``t`` query tokens whose first stands at ``start``
+    attends expanded: ``(rows of a segment, parts)``. The rows before
+    ``start`` go through ``kv_b_proj`` a segment at a time, whole pages
+    and no more rows than the chunk has; ``parts`` counts those segments
+    and one for the chunk's own rows (``start`` may be traced)."""
+    segment = max(1, t // page_size) * page_size
+    return segment, 1 + (start + segment - 1) // segment
+
+
+def gather_segment(pages, block_table, i, segment: int) -> jax.Array:
+    """Rows ``[i * segment, (i + 1) * segment)`` (whole pages; ``i`` may
+    be traced) of the sequence behind ``block_table`` [P], gathered out
+    of ``pages`` -> ``[segment, width]``. Columns past the table's end
+    name page 0: like a dead column's, their rows are for the caller to
+    leave out."""
+    per = segment // pages.shape[1]
+    table = jnp.pad(block_table, (0, -block_table.shape[0] % per))
+    table = jax.lax.dynamic_slice(table, (i * per,), (per,))
+    return gather_kv_pages(pages, table[None], pages.shape[2])[0, :, 0]
 
 
 def latent_rows(c_kv: jax.Array, k_pe: jax.Array) -> jax.Array:

@@ -640,10 +640,12 @@ def test_prefix_cache_shares_both_pools_pages(params):
     shared = a[:24] + b
     eng = InferenceEngine(TINY, params, **ENGINE)
     assert eng.prefix_cache is not None
+    before = eng.stats()["prefix_cache"]  # the counters are the process's
     eng.generate([a], SamplingParams(max_new_tokens=2))
     got = eng.generate([shared], SamplingParams(max_new_tokens=8))[0]
     hits = eng.stats()["prefix_cache"]
-    assert hits["hits"] == 1 and hits["hit_tokens"] == 24
+    assert hits["hits"] - before["hits"] == 1
+    assert hits["hit_tokens"] - before["hit_tokens"] == 24
     alone = InferenceEngine(TINY, params, enable_prefix_cache=False,
                             **ENGINE).generate(
         [shared], SamplingParams(max_new_tokens=8))[0]

@@ -24,10 +24,13 @@ import pytest
 from raytpu.inference import InferenceEngine, PagedKVCache
 from raytpu.inference.sampling import SamplingParams
 from raytpu.models import llama as llama_mod
-from raytpu.models.mixtral import (JoyAI, JoyAIConfig, MixtralConfig,
-                                   MoEFFN, OlmoeConfig, init_params)
+from raytpu.models.mixtral import (GlmDsaConfig, JoyAI, JoyAIConfig,
+                                   LatentMoEConfig, LongcatFlashConfig,
+                                   MixtralConfig, MoEFFN, OlmoeConfig,
+                                   init_params)
 from raytpu.models.mla import LatentAttention, deinterleave
-from raytpu.ops.mla_attention import (latent_row_width, latent_rows,
+from raytpu.ops.mla_attention import (expanded_parts, expands,
+                                      latent_row_width, latent_rows,
                                       mla_paged_attention)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -299,6 +302,81 @@ def test_absorbed_attention_is_the_expanded(params):
     np.testing.assert_allclose(held, rows[0], atol=1e-6)
 
 
+# ---- a chunk expands -----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("config, first", [
+    (JoyAIConfig(), 171), (LongcatFlashConfig(), 171), (GlmDsaConfig(), 359),
+    (TINY, 13)], ids=["joyai", "longcat", "glm5", "tiny"])
+def test_the_break_even_is_a_number_of_queries(config, first):
+    """``first`` is the fewest queries of one sequence that attend
+    cheaper expanded, from the widths alone: 2 (640 + 512) against 2 (192
+    + 192) FLOPs a (query, head, key) and 2 x 512 x 256 a (key, head)
+    once at JoyAI's and LongCat's; a decode row and a verify step's two
+    stay absorbed everywhere."""
+    widths = dict(rank=config.kv_lora_rank, nope_dim=config.qk_nope_dim,
+                  rope_dim=config.qk_rope_dim, v_dim=config.v_head_dim)
+    assert [t for t in (1, 2, first - 1, first, 2048)
+            if expands(t, **widths)] == [first, 2048]
+    assert config.chunk_parts(first - 1, 4096, 128) is None
+    # Segments of whole pages, no more rows than the chunk has; the
+    # chunk's own rows are one part, a cut last segment one.
+    assert config.chunk_parts(2048, 0, 128) == (2048, 1)
+    assert config.chunk_parts(2048, 6144, 128) == (2048, 4)
+    assert config.chunk_parts(2048, 6144 + 128, 128) == (2048, 5)
+    assert expanded_parts(20, 17, 8) == (16, 3)
+    # GLM-5's chunk reads the rows its indexer chooses: never expanded.
+    served = config.serving.chunk_parts
+    assert served is None if isinstance(config, GlmDsaConfig) \
+        else served(first, 0, 128) == config.chunk_parts(first, 0, 128)
+
+
+# start, tokens: a first chunk; a later one behind three whole segments;
+# a short last one padded to its bucket; one that starts on a page's edge
+# that is no segment's (a prefix-cache hit's tail); one inside a page.
+_CHUNKS = {"first": (0, 16), "later": (48, 16), "short_last": (64, 5),
+           "page_edge": (24, 16), "inside_a_page": (29, 11)}
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("case", sorted(_CHUNKS))
+def test_expanded_chunk_is_the_absorbed(params, impl, case, monkeypatch):
+    """A chunk of 16 rows attends expanded at the tiny widths (segments
+    of two pages): against the absorbed form over the same pages, by the
+    float32 reference and by ``impl``, and against ``prefill`` over the
+    whole sequence. The table's columns past the live pages and the
+    bucket's padding rows name page 0, which holds 1e4."""
+    start, take = _CHUNKS[case]
+    cfg = dataclasses.replace(TINY, attn_impl=impl, paged_attn=impl)
+    lp = params["layers_1"]["attn"]
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((1, 80, 64)), jnp.float32)
+    whole, rows = LatentAttention(TINY).apply({"params": lp}, x,
+                                              method="prefill")
+    width = rows.shape[-1]
+    table = np.zeros((1, 11), np.int32)
+    table[0, :10] = 1 + rng.permutation(10)
+    slots = lambda pos: table[0][pos // 8] * 8 + pos % 8  # noqa: E731
+    pool = np.full((11, 8, width), 1e4, np.float32)
+    pool.reshape(-1, width)[slots(np.arange(start))] = rows[0, :start]
+    pos, dests = np.zeros((2, 1, 16), np.int32)
+    pos[0, :take] = np.arange(start, start + take)
+    dests[0, :take] = slots(pos[0, :take])
+    xs = jnp.zeros((16, 64)).at[:take].set(x[0, start:start + take])
+
+    def step(c):
+        return LatentAttention(c).apply(
+            {"params": lp}, xs, jnp.asarray(pool), jnp.asarray(dests),
+            jnp.asarray(table), jnp.asarray(pos), method="step")[0][:take]
+
+    assert cfg.chunk_parts(16, start, 8) == (16, 1 + -(-start // 16))
+    got = step(cfg)
+    np.testing.assert_allclose(got, whole[0, start:start + take], atol=2e-5)
+    monkeypatch.setattr(LatentMoEConfig, "chunk_parts", lambda *a: None)
+    np.testing.assert_allclose(got, step(TINY), atol=2e-5)
+    np.testing.assert_allclose(got, step(cfg), atol=2e-5)
+
+
 # ---- the kernel, interpreted ----------------------------------------------------
 
 
@@ -558,14 +636,16 @@ def served_logits(cfg, params, prompt, new, **engine):
 
 
 @pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("chunk", [None, 16])
+@pytest.mark.parametrize("chunk", [None, 8, 16])
 @pytest.mark.parametrize("held", [None, (4, 8)])
 def test_served_logits_are_the_references(family, params, impl, chunk,
                                           held):
-    """A prompt of 43 tokens (whole, expanded through flash attention; or
-    in chunks of 16, absorbed) and 14 decoded positions through the
-    latent pages, against the reference's one expanded forward pass;
-    with every expert held, and with a share of them."""
+    """A prompt of 43 tokens (whole, expanded through flash attention; in
+    chunks of 8, absorbed; or in chunks of 16, over the tiny widths'
+    break-even of 13 queries: expanded, the cached rows a segment of two
+    pages at a time) and 14 decoded positions through the latent pages,
+    against the reference's one expanded forward pass; with every expert
+    held, and with a share of them."""
     cfg = dataclasses.replace(TINY, attn_impl=impl, paged_attn=impl,
                               experts_held=held)
     if held:
@@ -581,6 +661,12 @@ def test_served_logits_are_the_references(family, params, impl, chunk,
     stats = eng.stats()
     assert bool(stats["chunk_prefill_compiles"]) == (chunk is not None)
     log = eng.step_log()["steps"]
+    # Chunks at 0, 16 and 32: their own rows and 0, 1, 2 segments.
+    parts = [1, 2, 3] if chunk == 16 else []
+    assert [s["chunk_expanded"] for s in log if "chunk_expanded" in s] \
+        == parts
+    assert (stats["chunks_expanded"], stats["chunk_segments_expanded"]) \
+        == (len(parts), sum(parts))
     # Two routed layers, 4 experts a token: a pair whose expert is not
     # held is counted nowhere, as it is computed nowhere.
     # (A decode's counts come back with its ids, a step later: the
@@ -656,10 +742,17 @@ def test_prefix_cache_shares_latent_pages(params):
     shared = a[:24] + b
     eng = InferenceEngine(TINY, params, **ENGINE)
     assert eng.prefix_cache is not None
+    before = eng.stats()["prefix_cache"]  # the counters are the process's
     eng.generate([a], SamplingParams(max_new_tokens=2))
     got = eng.generate([shared], SamplingParams(max_new_tokens=8))[0]
     hits = eng.stats()["prefix_cache"]
-    assert hits["hits"] == 1 and hits["hit_tokens"] == 24
+    assert hits["hits"] - before["hits"] == 1
+    assert hits["hit_tokens"] - before["hit_tokens"] == 24
+    # The tail of 9 in a bucket of 16 expands: its own rows, a whole
+    # segment of two pages and one page of the next.
+    assert [s["chunk_expanded"] for s in eng.step_log()["steps"]
+            if "chunk_expanded" in s] == [3]
+    assert eng.stats()["chunk_segments_expanded"] == 3
     alone = InferenceEngine(TINY, params, enable_prefix_cache=False,
                             **ENGINE).generate(
         [shared], SamplingParams(max_new_tokens=8))[0]

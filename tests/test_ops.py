@@ -3,6 +3,7 @@ lowering for the TPU platform from the CPU host (what Mosaic then makes
 of it only the chip, or an AOT compile against libtpu, can say)."""
 
 import functools
+import importlib
 import re
 
 import numpy as np
@@ -12,10 +13,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from raytpu.ops.flash_attention import flash_attention
+from raytpu.ops.flash_attention import (flash_attention,
+                                        flash_attention_part, merge_parts)
 from raytpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 from raytpu.ops.paged_attention import paged_attention
 from raytpu.parallel.mesh import build_mesh
+
+# (``raytpu.ops.flash_attention`` is the function, as the package exports it.)
+flash_mod = importlib.import_module("raytpu.ops.flash_attention")
 
 
 class TestFlashAttention:
@@ -79,6 +84,58 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(ref, np.float32),
             atol=3e-2, rtol=3e-2)
+
+    @pytest.mark.parametrize("force", ["reference", "interpret"])
+    @pytest.mark.parametrize("kv_len", [0, 1, 511, 512, 513, 2048, 2049,
+                                        3000, 4096])
+    def test_part_with_a_key_limit(self, force, kv_len):
+        """Keys from ``kv_len`` on enter no softmax, whatever they hold:
+        the part is the attention over ``k[:kv_len]`` and its
+        log-sum-exp, with the limit traced, over sub-blocks of 512 keys
+        in two major blocks (one past the limit is not visited)."""
+        h, t_q, t_kv, d = 2, 128, 4096, 32
+        q = jax.random.normal(jax.random.PRNGKey(3), (1, h, t_q, d))
+        k, v = jax.random.normal(jax.random.PRNGKey(4), (2, 1, h, t_kv, d))
+        dead = jnp.arange(t_kv)[:, None] >= kv_len
+        o, lse = flash_attention_part(
+            q, jnp.where(dead, 1e4, k), jnp.where(dead, 1e4, v),
+            causal=False, sm_scale=d ** -0.5, force=force,
+            kv_len=jnp.int32(kv_len))
+        if kv_len == 0:
+            assert np.asarray(lse).max() < -1e29
+            return
+        want_o, want_lse = flash_mod._attn_fwd_reference(
+            q, k[:, :, :kv_len], v[:, :, :kv_len], False, d ** -0.5)
+        np.testing.assert_allclose(o, want_o, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(lse, want_lse[..., 0], atol=2e-5,
+                                   rtol=2e-5)
+
+    def test_parts_merge_to_the_whole(self):
+        """Attention over a context in three parts (the last causal, the
+        one before it cut short by a key limit) is attention over it
+        whole: what a latent chunk does with its cached segments."""
+        h, t, d = 2, 64, 16
+        q = jax.random.normal(jax.random.PRNGKey(5), (1, h, t, d))
+        k, v = jax.random.normal(jax.random.PRNGKey(6), (2, 1, h, 3 * t, d))
+        scale, cut = d ** -0.5, 40
+        live = np.r_[0:t, t:t + cut, 2 * t:3 * t]
+        want = flash_attention(q, k[:, :, live], v[:, :, live],
+                               sm_scale=scale, force="reference")
+        for force in ("reference", "interpret"):
+            part = functools.partial(flash_attention_part, q, sm_scale=scale,
+                                     force=force)
+            rows = lambda i: (k[:, :, i * t:(i + 1) * t],  # noqa: E731
+                              v[:, :, i * t:(i + 1) * t])
+            got, _ = merge_parts([
+                part(*rows(2), causal=True), part(*rows(0), causal=False),
+                part(*rows(1), causal=False, kv_len=cut)])
+            np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    def test_a_key_limit_is_a_part_s_before_the_diagonal(self):
+        q = jnp.ones((1, 1, 16, 8))
+        with pytest.raises(ValueError, match="causal=False"):
+            flash_attention_part(q, q, q, causal=True, sm_scale=1.0,
+                                 kv_len=3)
 
     def test_block_autofit(self):
         # 300 and 768 don't divide the 512-tile default; interpret mode
@@ -278,6 +335,14 @@ def _flash_loss(q, k, v):
     return _flash_fwd(q, k, v).astype(jnp.float32).sum()
 
 
+def _flash_part(q, k, v):
+    """A cached segment under a latent chunk: not causal, a traced key
+    limit (here from the data, as a chunk's start is from its inputs)."""
+    return flash_attention_part(
+        q, k, v, causal=False, sm_scale=q.shape[-1] ** -0.5, force="tpu",
+        kv_len=jnp.argmax(k[0, 0, :, 0]).astype(jnp.int32))
+
+
 def _paged_shapes(b, t, h, kv, d, pages=513, page_size=16, width=64):
     pool = jax.ShapeDtypeStruct((pages, page_size, kv * d), jnp.bfloat16)
     return (jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16), pool, pool,
@@ -294,6 +359,9 @@ _TPU_CASES = {
     "flash_bwd_124m": (jax.grad(_flash_loss, argnums=(0, 1, 2)),
                        _flash_shapes(8, 12, 1024, 64)),
     "flash_fwd_prefill_bucket": (_flash_fwd, _flash_shapes(1, 12, 16, 64)),
+    # JoyAI's chunk: 32 heads of 128 + 64 against a segment of 2,048 rows.
+    "flash_part_latent_segment": (_flash_part,
+                                  _flash_shapes(1, 32, 2048, 192)),
     "flash_bwd_gqa_d128": (jax.grad(_flash_loss, argnums=(0, 1, 2)),
                            _flash_shapes(4, 32, 1024, 128)),
     "paged_decode_124m": (_paged, _paged_shapes(8, 1, 12, 12, 64)),
