@@ -38,10 +38,12 @@ The TPU compile-once discipline, concretely:
   (``cache.slide``); a model of one kind gets the single arrays and the
   programs it always had.
 - **A layer that keeps a state** (``serving.layer_states``: a short
-  convolution's newest input rows) has no pool. The pools are the other
-  layers' alone, and such a layer has one state array ``[max_num_seqs +
-  1, *shape]`` (``self.cache.state``), given to the three programs
-  donated behind the pools and rebound with them. A sequence's row of
+  convolution's newest input rows; a delta-rule layer's float32 matrices
+  and its convolutions' tails, two arrays of two dtypes) has no pool. The
+  pools are the other layers' alone, K and V or one latent pool, and such
+  a layer has a state array ``[max_num_seqs + 1, *shape]`` for each thing
+  it keeps (``self.cache.state``, layer by layer), given to the three
+  programs donated behind the pools and rebound with them. A sequence's row of
   every state array is its *seat* (``cache.seat``: taken and given back
   with its pages, so admission stops when either runs out, and a
   preempted sequence recomputes both); a program is given its sequences'
@@ -435,7 +437,7 @@ class InferenceEngine:
         # A layer that keeps a state has no pool, and a sequence a seat
         # in its state array; a drafting engine seats the module's state.
         module_pools = self._drafting.pools if self._drafting else 0
-        state_shapes = [shape for shape in served.layer_states if shape]
+        state_shapes = served.state_arrays
         self.cache = PagedKVCache(
             model_config.n_layer - len(state_shapes) + module_pools,
             num_pages, page_size,

@@ -145,9 +145,11 @@ class PagedKVCache:
         index_row: with ``latent_row``: each layer also has a pool of
             index keys ``[num_pages, page_size, index_row]``, which ``v``
             holds, under the latent pool's own tables and slots.
-        state_shapes: the shape of the state a sequence keeps in each
-            layer that has one and no pool (``num_layers`` counts the
-            layers with pools alone); needs ``seats``.
+        state_shapes: one entry a layer that keeps a state and no pool
+            (``num_layers`` counts the layers with pools alone): what a
+            sequence keeps there, a sequence of ``(shape, dtype)``
+            (``None``: the cache's), an array each
+            (``Serving.state_arrays``); needs ``seats``.
         seats: sequences that can hold a seat at once; 0 keeps no seat
             map.
     """
@@ -158,7 +160,8 @@ class PagedKVCache:
                  window_pages: Optional[int] = None, window_burst: int = 1,
                  latent_row: Optional[int] = None,
                  index_row: Optional[int] = None,
-                 state_shapes: Sequence[Sequence[int]] = (), seats: int = 0):
+                 state_shapes: Sequence[Sequence[tuple]] = (),
+                 seats: int = 0):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is scratch)")
         if page_size < 1:
@@ -209,12 +212,13 @@ class PagedKVCache:
                         for k in self.k] if v_width else []
         if state_shapes and seats < 1:
             raise ValueError("a layer that keeps a state needs `seats`")
-        # One state array a layer that keeps one; row 0 is scratch.
-        self.state: List = [jnp.zeros((seats + 1, *shape), self.dtype)
-                            for shape in state_shapes]
+        # The state arrays, layer by layer, each of its own dtype; row 0
+        # is scratch.
+        self.state: List = [
+            jnp.zeros((seats + 1, *shape), dtype or self.dtype)
+            for entry in state_shapes for shape, dtype in entry]
         # Bytes one sequence costs in them: its seat's row in each.
-        self.state_bytes = sum(math.prod(shape) for shape in state_shapes) \
-            * jnp.dtype(self.dtype).itemsize
+        self.state_bytes = sum(a[0].nbytes for a in self.state)
         self.total_seats = seats
         self._free_seats: List[int] = list(range(seats, 0, -1))
         self._seats: Dict[str, int] = {}

@@ -57,7 +57,11 @@ attention that reads only the cached positions a learned indexer
 chooses: two pools a layer of unequal row width under one block table,
 the latent rows and the index keys; three leading dense layers and
 "joyai"'s routed layer; the prefix cache shares a page of both pools at
-once, a preempted sequence recomputes both, a role is refused). Each
+once, a preempted sequence recomputes both, a role is refused) and
+"ling_hybrid" (five delta-rule linear-attention layers to every latent-
+attention layer: a float32 matrix a head and the convolutions' tails at a
+sequence's seat beside one latent pool, experts chosen inside the best
+groups; as "lfm2_moe", no prefix cache and no role). Each
 reaches the engine through its config's ``serving`` and nothing else.
 """
 
@@ -76,6 +80,7 @@ from raytpu.cluster import constants as tuning
 from raytpu.inference import disagg
 from raytpu.inference.engine import InferenceEngine
 from raytpu.inference.sampling import SamplingParams
+from raytpu.serve.config import DeploymentConfig
 from raytpu.serve.deployment import deployment
 from raytpu.util import serve_slo, task_events, tracing
 
@@ -216,6 +221,19 @@ class TokenStream:
             return item
         raise StopAsyncIteration
 
+    def take_ready(self) -> tuple:
+        """On the loop that awaits the stream: the tokens delivered and
+        not yet taken, now, without waiting (none before the first
+        ``__anext__``; the end stays for ``__anext__``). A serve replica
+        sends them with the token ``__anext__`` just gave, in one object:
+        where the loop lags the engine a stream's tokens travel together
+        and the hand-over catches up."""
+        items = self._items
+        ready = []
+        while items and items[0] is not _END:
+            ready.append(items.popleft())
+        return tuple(ready)
+
     async def aclose(self) -> None:
         """:meth:`close` from a coroutine: the wait for the engine lock
         goes to a pool thread, once, and not onto the loop, where it
@@ -326,17 +344,22 @@ class LLMDeployment:
 
     Args:
         model: "llama", "gpt2", "mixtral", "olmoe", "mellum", "joyai",
-            "exaone_moe", "lfm2_moe", "longcat_flash" or "glm_moe_dsa".
+            "exaone_moe", "lfm2_moe", "longcat_flash", "glm_moe_dsa" or
+            "ling_hybrid".
         model_config: the family's config (``LlamaConfig``,
             ``GPT2Config``, ``MixtralConfig``, ``OlmoeConfig``,
             ``MellumConfig``, ``JoyAIConfig``, ``ExaoneMoeConfig``,
-            ``Lfm2MoeConfig``, ``LongcatFlashConfig``, ``GlmDsaConfig``)
-            or a
+            ``Lfm2MoeConfig``, ``LongcatFlashConfig``, ``GlmDsaConfig``,
+            ``LingHybridConfig``) or a
             kwargs dict for one. Defaults to the family's ``tiny()``
             config in fp32/reference-attention mode (CPU-runnable).
         engine_options: kwargs forwarded to :class:`InferenceEngine`
             (page_size, num_pages, max_num_seqs, prefill_chunk,
-            enable_prefix_cache, ...).
+            enable_prefix_cache, ...). One key is not the engine's:
+            ``serve_options``, a dict of this deployment's own serve
+            options (``health_check_timeout_s``, ...) for a caller that
+            reaches the deployment through ``bind`` alone; see
+            :meth:`deployment_options`.
         seed: parameter-init seed — two replicas (or a test building a
             reference model) with the same seed hold identical weights.
         role: None (serve everything, the default), "prefill" (KV
@@ -353,9 +376,27 @@ class LLMDeployment:
             latent pool and an index-key pool): the hand-off's wire
             segments are pages of K and of V, ``kv_heads * head_dim``
             wide. Nor does a model with layers
-            that keep a state ("lfm2_moe"): the state at a prefix's end
-            is in none of its pages, and the wire has no segment for it.
+            that keep a state ("lfm2_moe", "ling_hybrid"): the state at a
+            prefix's end is in none of its pages, and the wire has no
+            segment for it.
     """
+
+    @staticmethod
+    def deployment_options(*args, **kwargs) -> dict:
+        """What the constructor's arguments imply for the deployment
+        (:meth:`raytpu.serve.deployment.Deployment.bind` asks): a replica
+        takes at least as many requests as its engine has seats
+        (``max_num_seqs``; the engine admits by seats and pages and keeps
+        the rest waiting, so under the serve layer's 100 an engine built
+        for 128 sequences decoded 100 and the router timed the others
+        out), and ``engine_options["serve_options"]`` as given."""
+        options = dict(kwargs.get("engine_options")
+                       or (args[2] if len(args) > 2 else None) or {})
+        implied = dict(options.get("serve_options") or {})
+        seats = int(options.get("max_num_seqs") or 0)
+        if seats > DeploymentConfig().max_ongoing_requests:
+            implied.setdefault("max_ongoing_requests", seats)
+        return implied
 
     def __init__(self, model: str = "llama", model_config=None,
                  engine_options: Optional[dict] = None, seed: int = 0,
@@ -373,7 +414,8 @@ class LLMDeployment:
 
             cfg_cls, model_cls, init = GPT2Config, GPT2, init_params
         elif model in ("mixtral", "olmoe", "mellum", "joyai", "exaone_moe",
-                       "lfm2_moe", "longcat_flash", "glm_moe_dsa"):
+                       "lfm2_moe", "longcat_flash", "glm_moe_dsa",
+                       "ling_hybrid"):
             from raytpu.models import mixtral
 
             cfg_cls = {"mixtral": mixtral.MixtralConfig,
@@ -383,14 +425,15 @@ class LLMDeployment:
                        "exaone_moe": mixtral.ExaoneMoeConfig,
                        "lfm2_moe": mixtral.Lfm2MoeConfig,
                        "longcat_flash": mixtral.LongcatFlashConfig,
-                       "glm_moe_dsa": mixtral.GlmDsaConfig}[model]
+                       "glm_moe_dsa": mixtral.GlmDsaConfig,
+                       "ling_hybrid": mixtral.LingHybridConfig}[model]
             model_cls, init = mixtral.Mixtral, mixtral.init_params
         else:
             raise ValueError(f"unknown model family: {model!r}; known: "
                              f"'llama', 'gpt2', 'mixtral', 'olmoe', "
                              f"'mellum', 'joyai', 'exaone_moe', "
                              f"'lfm2_moe', 'longcat_flash', "
-                             f"'glm_moe_dsa'")
+                             f"'glm_moe_dsa', 'ling_hybrid'")
         if model_config is None:
             model_config = dataclasses.replace(
                 cfg_cls.tiny(), dtype=jnp.float32, attn_impl="reference",
@@ -404,8 +447,10 @@ class LLMDeployment:
         self._role = role
         self._prefill = prefill
         self._peer = None
+        engine_options = dict(engine_options or {})
+        engine_options.pop("serve_options", None)  # deployment_options'
         self._engine = InferenceEngine(model_config, params,
-                                       **(engine_options or {}))
+                                       **engine_options)
         if role is not None and self._engine.cache.window is not None:
             raise ValueError(
                 f"role={role!r}: a model with window layers is not "

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -469,12 +469,16 @@ class Serving:
     deals in pages (the prefix cache, preemption) deals in both at once.
 
     A layer that keeps a state (``layer_states``: one entry a layer of
-    the model, ``None`` for a layer with a pool, else the shape of what a
-    sequence keeps there, a short convolution's ``(taps - 1, width)``):
-    it has no pool, ``k_caches`` and ``v_caches`` hold the other layers'
-    alone, in their order, and the two entry points take two arguments
-    more after them, ``states`` (one ``[seats + 1, *shape]`` array a
-    layer that keeps one, donated like the pools) and ``seats`` (int32
+    the model, ``None`` for a layer with a pool, else what a sequence
+    keeps there: one shape in the cache's dtype, a short convolution's
+    ``(taps - 1, width)``, or a tuple of :class:`State`, an array each,
+    where a layer keeps more than one thing or another type than the
+    cache's: a KDA layer's float32 matrices and its convolutions' tails;
+    :func:`state_specs` reads either): it has no pool, ``k_caches`` and
+    ``v_caches`` hold the other layers' alone, in their order, and the
+    two entry points take two arguments more after them, ``states`` (one
+    ``[seats + 1, *shape]`` array for each thing a layer keeps, layer by
+    layer, donated like the pools) and ``seats`` (int32
     ``[B]``, ``[1]`` for ``prefill``: each sequence's row of every
     state array; 0, the scratch row, for a padding row), and return
     ``states`` written after the pools: ``(logits, k_caches, v_caches,
@@ -497,10 +501,35 @@ class Serving:
     kv_row: Optional[int] = None
     chunk_parts: Optional[Callable] = None
     indexer: Optional[Tuple[int, int]] = None  # (index_row, index_topk)
-    layer_states: Tuple[Optional[Tuple[int, ...]], ...] = ()
+    layer_states: Tuple[Optional[tuple], ...] = ()
     # Not None: the family drafts for itself (a prediction module), and
     # an engine built with drafting on runs these and not the two above.
     drafting: Optional["Drafting"] = None
+
+    @property
+    def state_arrays(self) -> Tuple[Tuple["State", ...], ...]:
+        """``layer_states`` as the cache takes it (``state_shapes``): of
+        each layer that keeps a state, its arrays."""
+        return tuple(state_specs(e) for e in self.layer_states if e)
+
+
+class State(NamedTuple):
+    """One array of what a sequence keeps in a layer without a pool: a
+    row ``shape`` of ``dtype`` (``None``: the cache's own) at its seat."""
+
+    shape: Tuple[int, ...]
+    dtype: Any = None
+
+
+def state_specs(entry) -> Tuple[State, ...]:
+    """An entry of ``Serving.layer_states`` as the arrays it stands for:
+    none for a layer with a pool, one for a bare shape. The one place
+    that tells the two forms apart."""
+    if entry is None:
+        return ()
+    if isinstance(entry[0], int):
+        return (State(tuple(entry)),)
+    return tuple(State(*spec) for spec in entry)
 
 
 @dataclasses.dataclass(frozen=True)

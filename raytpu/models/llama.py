@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from raytpu.models.gpt2 import (Serving, cast_leaves, remat_block,
-                                write_prompt_rows)
+                                state_specs, write_prompt_rows)
 
 # The two kinds of attention layer, as a published ``layer_types`` names
 # them, and where each stands in what is given a kind (a cache's tables,
@@ -39,6 +39,11 @@ KINDS = (FULL, WINDOW)
 # no pool and stands in nothing that is given a kind of pool; a sequence
 # keeps a state in it instead (``Serving.layer_states``).
 CONV = "conv"
+# A fourth, which is no softmax attention either: a delta-rule linear
+# attention (:mod:`raytpu.models.kda`), whose state is two arrays a layer,
+# a float32 matrix a head and the tails of its short convolutions.
+KDA = "kda"
+STATE_KINDS = (CONV, KDA)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,10 +137,11 @@ class LlamaConfig:
             types = tuple(self.layer_types)
             object.__setattr__(self, "layer_types", types)
             if len(types) != self.n_layer \
-                    or set(types) - {*KINDS, CONV}:
+                    or set(types) - {*KINDS, *STATE_KINDS}:
                 raise ValueError(
                     f"layer_types names {self.n_layer} layers, each "
-                    f"{FULL!r}, {WINDOW!r} or {CONV!r}: got {types}")
+                    f"{FULL!r}, {WINDOW!r}, {CONV!r} or {KDA!r}: got "
+                    f"{types}")
             if WINDOW in types and not self.window:
                 raise ValueError("a window layer needs `window`")
 
@@ -153,7 +159,7 @@ class LlamaConfig:
     def attn_scope(self, kind: str) -> Optional[str]:
         """The ``jax.named_scope`` a layer of ``kind`` attends under in
         the serving walk; ``None`` where every layer is alike."""
-        if not self.layer_types or kind == CONV:  # (its own: "conv.*")
+        if not self.layer_types or kind in STATE_KINDS:  # (its own scopes)
             return None
         return "attn.window" if kind == WINDOW else "attn.full"
 
@@ -165,16 +171,30 @@ class LlamaConfig:
             from raytpu.models.short_conv import ShortConv  # imports this
 
             return ShortConv(self, **kw)
+        if kind == KDA:
+            from raytpu.models.kda import KimiDeltaAttention
+
+            return KimiDeltaAttention(self, **kw)
         return LlamaAttention(self, kind, **kw)
 
+    def layer_state(self, kind: str):
+        """What a sequence keeps in a layer of ``kind`` that has no pool
+        (an entry of ``Serving.layer_states``); ``None`` for a layer with
+        a pool."""
+        return (self.conv_taps - 1, self.n_embd) if kind == CONV else None
+
     def held_index(self, i: int) -> int:
-        """Where layer ``i``'s own array stands in what a served model is
+        """Where layer ``i``'s own arrays stand in what a served model is
         given: its pools among the pools (``k_caches``, ``v_caches``), or
-        its state among the ``states``; ``i`` itself where every layer
-        has a pool."""
-        kinds = self.layer_types or ()
-        return sum((kind == CONV) == (kinds[i] == CONV)
-                   for kind in kinds[:i]) if CONV in kinds else i
+        the first of its state arrays among the ``states``; ``i`` itself
+        where every layer has a pool."""
+        kinds = self.layer_types
+        if not kinds:
+            return i
+        if kinds[i] in STATE_KINDS:
+            return sum(len(state_specs(self.layer_state(kind)))
+                       for kind in kinds[:i])
+        return sum(kind not in STATE_KINDS for kind in kinds[:i])
 
     def ffn_width(self, i: int) -> Optional[int]:
         """Layer ``i``'s feed-forward by its index: the width of its
@@ -209,10 +229,9 @@ class LlamaConfig:
             expert_pairs=bool(routed and self.n_zero_expert),
             layer_windows=tuple(
                 self.window if kind == WINDOW else None
-                for kind in kinds if kind != CONV),
-            layer_states=tuple(
-                (self.conv_taps - 1, self.n_embd) if kind == CONV else None
-                for kind in kinds) if CONV in kinds else ())
+                for kind in kinds if kind not in STATE_KINDS),
+            layer_states=tuple(self.layer_state(kind) for kind in kinds)
+            if set(kinds) & set(STATE_KINDS) else ())
 
     @property
     def n_params_approx(self) -> int:
@@ -582,7 +601,7 @@ def _lm_logits(c: LlamaConfig, params, x):
 
 def op_name(kind: str) -> str:
     """What a block's parameters call its first operator."""
-    return "conv" if kind == CONV else "attn"
+    return kind if kind in STATE_KINDS else "attn"
 
 
 def _routed(c: LlamaConfig, lp, h, live):
@@ -632,7 +651,7 @@ def _serve(c: LlamaConfig, params, x, live, cache_args, whole: bool = False,
     output and the layer's K and V (rows, or the pools it wrote), or
     the one pool of a latent layer (``V list`` is then empty; with an
     indexer it holds the layer's index keys), or a CONV
-    layer's state array, written;
+    layer's state array, written (a KDA layer's two);
     ``live`` (``x``'s leading shape) marks the rows that are tokens, for
     :func:`_feed_forward`. Returns ``(fp32 logits, K list, V list)``,
     then the list of state arrays where a layer keeps one, and
@@ -661,8 +680,11 @@ def _serve(c: LlamaConfig, params, x, live, cache_args, whole: bool = False,
                 y, k, *v = attn[kind].apply(
                     {"params": lp[op_name(kind)]}, h, *cache_args(i),
                     method=method)
-            (states if kind == CONV else ks).append(k)
-            vs.extend(v)
+            if kind in STATE_KINDS:
+                states.extend((k, *v))
+            else:
+                ks.append(k)
+                vs.extend(v)
             x = x + y
             h = norm.apply({"params": lp["post_attn_norm"]}, x)
             y, counts = _feed_forward(c, lp, h, live, i)
@@ -693,12 +715,13 @@ def _pools(k_caches, v_caches, i: int):
 def _by_layer(c: LlamaConfig, k_caches, v_caches, states, conv, paged):
     """``cache_args`` of :func:`_serve`: layer ``i``'s own pools before
     ``paged(kind)`` (nothing at all where ``paged`` is None), or for a
-    CONV layer its state array before ``conv``
-    (:meth:`LlamaConfig.held_index` says which of each list is its)."""
+    layer that keeps a state its state arrays before ``conv``
+    (:meth:`LlamaConfig.held_index` says which of each list are its)."""
     def cache_args(i: int):
         kind, own = c.layer_kind(i), c.held_index(i)
-        if kind == CONV:
-            return (states[own], *conv)
+        if kind in STATE_KINDS:
+            held = len(state_specs(c.layer_state(kind)))
+            return (*states[own:own + held], *conv)
         if paged is None:  # a whole prompt's attention reads no pool
             return ()
         return (*_pools(k_caches, v_caches, own), *paged(kind))
@@ -719,7 +742,7 @@ def llama_prefill(config: LlamaConfig, params, tokens, dests, k_caches,
         c, k_caches, v_caches, states, (seats, live), None), whole=True)
     if c.layer_types:  # each layer's rows where its kind of pool has them
         dests = [of_kind(dests, kind) for kind in c.layer_types
-                 if kind != CONV]
+                 if kind not in STATE_KINDS]
     ks, vs = write_prompt_rows(k_caches, v_caches, dests, ks, vs)
     return (logits[0], ks, vs, *more)
 

@@ -53,6 +53,12 @@ only the cached positions a learned indexer chooses
 (:class:`raytpu.models.mla.SparseLatentAttention`), the index keys in a
 pool of their own beside the latent pool, three leading dense layers and
 JoyAI's routed layer.
+:class:`LingHybridConfig` is Ling-3.0-flash-VL's language model: five
+delta-rule linear-attention layers (:mod:`raytpu.models.kda`), which keep
+a float32 matrix a head and their convolutions' tails at a sequence's
+seat, to every latent-attention layer (no query rank, a gate a head), and
+JoyAI's routed layer with its experts chosen inside the best groups
+(``n_group``, ``topk_group``).
 """
 
 from __future__ import annotations
@@ -65,9 +71,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raytpu.models.gpt2 import Drafting, remat_block, write_prompt_rows
-from raytpu.models.llama import (CONV, FULL, WINDOW, LlamaConfig, LlamaMLP,
-                                 RMSNorm, Rope, _lm_logits, _serve,
+from raytpu.models.gpt2 import (Drafting, State, remat_block,
+                                write_prompt_rows)
+from raytpu.models.llama import (CONV, FULL, KDA, WINDOW, LlamaConfig,
+                                 LlamaMLP, RMSNorm, Rope, _lm_logits, _serve,
                                  live_rows, of_kind, op_name)
 from raytpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 
@@ -96,6 +103,13 @@ class MixtralConfig(LlamaConfig):
     topk_sum_eps: float = 0.0
     # What the chosen experts' weights are multiplied by, normalised or not.
     routed_scale: float = 1.0
+    # Choice by groups (DeepSeek-V3's ``noaux_tc`` with groups): the
+    # router's experts are ``n_group`` runs of neighbours, a group's score
+    # is the sum of its two best scores + bias, and a token chooses among
+    # the experts of its ``topk_group`` best groups alone. 1 and 1: no
+    # groups.
+    n_group: int = 1
+    topk_group: int = 1
     # Shared experts: one SwiGLU of ``n_shared * n_inter`` beside the
     # routed ones, applied to every token.
     n_shared: int = 0
@@ -126,6 +140,17 @@ class MixtralConfig(LlamaConfig):
                 raise ValueError(
                     f"experts_held={held} is not a (first, count) share of "
                     f"the router's {self.n_expert} experts")
+        if self.n_group > 1 and (
+                self.n_expert % self.n_group or self.n_zero_expert
+                or not 0 < self.topk_group <= self.n_group
+                or self.n_expert // self.n_group < 2
+                or self.topk_group * (self.n_expert // self.n_group)
+                < self.n_expert_per_tok):
+            raise ValueError(
+                f"n_group={self.n_group}, topk_group={self.topk_group}: "
+                f"the {self.n_expert} experts fall into equal groups of "
+                f"two or more, of which the kept hold a token's "
+                f"{self.n_expert_per_tok}")
         if self.first_dense and not self.dense_inter:
             raise ValueError("leading dense layers need `dense_inter`")
         if self.mtp_layers not in (0, 1):
@@ -240,7 +265,8 @@ class LatentMoEConfig(MixtralConfig):
     key of ``qk_rope_dim``, behind one pool a layer. ``head_dim`` sizes
     nothing here. Layers are held one tree each."""
 
-    q_lora_rank: int = 1536
+    # None: queries through one matrix, no bottleneck.
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -250,6 +276,9 @@ class LatentMoEConfig(MixtralConfig):
     # normed key/value latent times sqrt(n_embd / kv_lora_rank).
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # Each head's attended values times ``sigmoid(W_g x)_h`` before
+    # ``o_proj``.
+    attn_head_gate: bool = False
     scan_layers: bool = False
 
     def attention(self, kind: str = FULL, **kw):
@@ -487,6 +516,94 @@ class GlmDsaConfig(LatentMoEConfig):
 
 
 @dataclasses.dataclass(frozen=True)
+class LingHybridConfig(LatentMoEConfig):
+    """Ling-3.0-flash-VL's language model (``inclusionAI/Ling-3.0-flash-
+    VL``) as published: 42 layers over a hidden size of 2,560 and 32 heads
+    of 128, layer ``i`` a latent-attention layer where ``(i + 1) % 6 ==
+    0`` (queries through one matrix, a latent of 512 and one roped key of
+    64 at theta 6e6, values of 128, a sigmoid gate a head before
+    ``o_proj``) and a KDA layer everywhere else
+    (:mod:`raytpu.models.kda`: short convolutions of 4 taps, a decay a
+    channel bounded at -5, a float32 matrix a head); layers 0 and 1 a
+    dense SwiGLU of 6,144, the others 512 routed experts of 768
+    (``n_inter``), 8 a token by sigmoid score + a bias inside the best 4
+    of 8 groups, weights normalised and times 2.5, beside one shared
+    expert. The prediction module, the SwiGLU clamp of the last layers
+    and the vision tower are not built. ``layer_types`` is that pattern
+    cut to ``n_layer`` (a configuration that holds other layers gives its
+    own); a KDA layer has no pool but two state arrays
+    (``serving.layer_states``), a latent layer one pool. Layers are held
+    one tree each."""
+
+    vocab_size: int = 157184
+    block_size: int = 131072
+    n_layer: int = 42
+    n_head: int = 32
+    n_kv_head: int = 32
+    n_embd: int = 2560
+    head_dim: int = 128
+    n_inter: int = 768
+    n_expert: int = 512
+    n_expert_per_tok: int = 8
+    norm_topk_prob: bool = True
+    norm_eps: float = 1e-6
+    rope_theta: float = 6000000.0
+    scoring: str = "sigmoid"
+    choice_bias: float = 0.0
+    routed_scale: float = 2.5
+    n_group: int = 8
+    topk_group: int = 4
+    n_shared: int = 1
+    first_dense: int = 2
+    dense_inter: int = 6144
+    q_lora_rank: Optional[int] = None
+    attn_head_gate: bool = True
+    # The published rope rotates adjacent pairs only where the config says
+    # so; this one has no such key.
+    rope_interleave: bool = False
+    conv_taps: int = 4
+    # A KDA layer's gate: ``g = kda_lower_bound * sigmoid(exp(A_log) (W_f
+    # x + dt_bias))``; and what a seeded ``A`` and ``dt_bias`` are drawn
+    # from, uniformly (a trained gate is what it is).
+    kda_lower_bound: float = -5.0
+    kda_gate_init: Tuple[Tuple[float, float], Tuple[float, float]] = (
+        (1.0, 4.0), (-4.0, 0.0))
+
+    def __post_init__(self):
+        _cut_to_depth(self, (
+            FULL if (i + 1) % 6 == 0 else KDA for i in range(self.n_layer)))
+        super().__post_init__()
+
+    def attention(self, kind: str = FULL, **kw):
+        if kind == KDA:
+            return LlamaConfig.attention(self, kind, **kw)
+        return super().attention(kind, **kw)
+
+    def attn_scope(self, kind: str) -> Optional[str]:
+        return None if kind == KDA else super().attn_scope(kind)
+
+    def layer_state(self, kind: str):
+        if kind != KDA:
+            return None
+        h, d = self.n_head, self.head_dim
+        return (State((h, d, d), jnp.float32),
+                State((self.conv_taps - 1, 3 * h * d)))
+
+    @classmethod
+    def tiny(cls) -> "LingHybridConfig":
+        """A dense KDA layer and a whole period after it (five KDA, one
+        latent, routed) at toy widths: of 16 experts in 4 groups a token
+        takes 4 inside its best 2; the latent is 128 wide because the
+        kernel slices values out of a row by whole lane tiles."""
+        return cls(vocab_size=512, block_size=256, n_layer=7, n_head=4,
+                   n_kv_head=4, n_embd=64, head_dim=16, n_inter=32,
+                   n_expert=16, n_expert_per_tok=4, n_group=4, topk_group=2,
+                   first_dense=1, dense_inter=96, kv_lora_rank=128,
+                   qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+                   choice_bias=0.01, layer_types=(KDA,) * 6 + (FULL,))
+
+
+@dataclasses.dataclass(frozen=True)
 class ExaoneMoeConfig(MixtralConfig):
     """K-EXAONE-236B-A23B (``LGAI-EXAONE/K-EXAONE-236B-A23B``,
     ``model_type: exaone_moe``) as published: 64 query heads on 8 kv
@@ -624,7 +741,10 @@ class MoEFFN(nn.Module):
 
     Scores are a softmax over the experts, or each expert's own sigmoid
     (``config.scoring``), then chosen by score + ``bias`` where the config
-    has a ``choice_bias`` (the weights are the scores without it),
+    has a ``choice_bias`` (the weights are the scores without it), among
+    the experts of the token's best groups where it has groups
+    (``n_group``, ``topk_group``: a share of the experts still scores all
+    of them and all the groups),
     normalised over the chosen (``norm_topk_prob``: over their sum, plus
     ``topk_sum_eps``) and multiplied by ``routed_scale``. With
     ``experts_held = (first, count)`` the three matrices hold ``count``
@@ -648,12 +768,27 @@ class MoEFFN(nn.Module):
     def route(self, probs, bias):
         """A token's choices and their weights from its scores ``probs``
         [N, outputs] and the choice ``bias`` (``None``: the config has
-        none): the ``n_expert_per_tok`` largest of score + bias, weighed
-        by the score without it, normalised and scaled as the config
-        says -> ``(topw, topi)``, both [N, k]."""
+        none): the ``n_expert_per_tok`` largest of score + bias (inside
+        the token's ``topk_group`` best groups, where the config has
+        groups), weighed by the score without it, normalised and scaled
+        as the config says -> ``(topw, topi)``, both [N, k]."""
         c = self.config
-        if bias is not None:
-            _, topi = jax.lax.top_k(probs + bias, c.n_expert_per_tok)
+        choice = probs if bias is None else probs + bias
+        if c.n_group > 1:
+            # The groups' scores, the best ``topk_group`` of them, and
+            # every expert of another group out of the choice.
+            groups = choice.reshape(-1, c.n_group, c.n_expert // c.n_group)
+            # (Two maxima and not ``top_k``, which sorts on the TPU.)
+            best = jnp.argmax(groups, axis=-1, keepdims=True)
+            ahead = jnp.arange(groups.shape[-1]) == best
+            score = jnp.max(groups, axis=-1) + jnp.max(
+                jnp.where(ahead, -jnp.inf, groups), axis=-1)
+            _, kept = jax.lax.top_k(score, c.topk_group)
+            keep = jnp.any(kept[..., None] == jnp.arange(c.n_group), axis=-2)
+            choice = jnp.where(keep[..., None], groups, -jnp.inf) \
+                .reshape(choice.shape)
+        if bias is not None or c.n_group > 1:
+            _, topi = jax.lax.top_k(choice, c.n_expert_per_tok)
             topw = jnp.take_along_axis(probs, topi, axis=-1)
         else:
             topw, topi = jax.lax.top_k(probs, c.n_expert_per_tok)
@@ -1015,3 +1150,4 @@ ExaoneMoe = Mixtral
 Lfm2Moe = Mixtral
 LongcatFlash = Mixtral
 GlmDsa = Mixtral
+LingHybrid = Mixtral

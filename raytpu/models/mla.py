@@ -2,7 +2,8 @@
 :class:`raytpu.models.llama.LlamaAttention` over one parameter set.
 
 Queries go through a low-rank bottleneck (``q_a_proj`` -> RMSNorm ->
-``q_b_proj``), each head's ``qk_nope_dim + qk_rope_dim`` values split
+``q_b_proj``; with ``q_lora_rank`` ``None`` through one matrix,
+``q_proj``), each head's ``qk_nope_dim + qk_rope_dim`` values split
 into a part that is not roped and a part that is. Keys and values come
 from one compressed latent a token: ``kv_a_proj`` gives ``kv_lora_rank``
 values (RMSNorm'd) and ONE ``qk_rope_dim``-wide key shared by every
@@ -14,7 +15,10 @@ query latent is multiplied by ``sqrt(n_embd / q_lora_rank)`` and the
 normed key/value latent by ``sqrt(n_embd / kv_lora_rank)``, which give
 the two bottlenecks' outputs the variance a full-width projection would
 have; the roped shared key is not scaled. The pool's row holds the
-scaled latent, so the absorbed form is the same with or without.
+scaled latent, so the absorbed form is the same with or without. With
+``attn_head_gate`` each head's attended values are multiplied by
+``sigmoid(W_g x)_h``, one value a head (``g_proj``: the width to
+``n_head``), before ``o_proj``, in every form.
 
 - ``prefill`` (and ``__call__``, training) is the *expanded* form: the
   latent goes through ``kv_b_proj`` and flash attention runs on heads of
@@ -58,7 +62,8 @@ def deinterleave(x):
 class LatentAttention(nn.Module):
     """``config`` carries ``q_lora_rank``, ``kv_lora_rank``,
     ``qk_nope_dim``, ``qk_rope_dim``, ``v_head_dim``, ``rope_interleave``,
-    ``mla_scale_q_lora`` and ``mla_scale_kv_lora`` beside what every
+    ``mla_scale_q_lora``, ``mla_scale_kv_lora`` and ``attn_head_gate``
+    beside what every
     llama-family config has, and says by ``chunk_parts`` which ``T``
     attend expanded (:class:`raytpu.models.mixtral.LatentMoEConfig`)."""
 
@@ -86,12 +91,18 @@ class LatentAttention(nn.Module):
             return dense(features, kernel_init=nn.initializers.normal(
                 c.n_embd ** -0.5))
 
-        self.q_a_proj = dense(c.q_lora_rank)
-        self.q_a_norm = RMSNorm(
-            dtype=c.dtype, eps=c.norm_eps,
-            gain=gain(c.mla_scale_q_lora, c.q_lora_rank))
-        self.q_b_proj = expansion(
-            c.mla_scale_q_lora, c.n_head * (c.qk_nope_dim + c.qk_rope_dim))
+        if c.q_lora_rank is None:
+            self.q_proj = dense(c.n_head * (c.qk_nope_dim + c.qk_rope_dim))
+        else:
+            self.q_a_proj = dense(c.q_lora_rank)
+            self.q_a_norm = RMSNorm(
+                dtype=c.dtype, eps=c.norm_eps,
+                gain=gain(c.mla_scale_q_lora, c.q_lora_rank))
+            self.q_b_proj = expansion(
+                c.mla_scale_q_lora,
+                c.n_head * (c.qk_nope_dim + c.qk_rope_dim))
+        if c.attn_head_gate:
+            self.g_proj = dense(c.n_head)
         self.kv_a_proj = dense(c.kv_lora_rank + c.qk_rope_dim)
         self.kv_a_norm = RMSNorm(
             dtype=c.dtype, eps=c.norm_eps,
@@ -115,9 +126,12 @@ class LatentAttention(nn.Module):
         config says so). ``c_q``: the normed query latent, where the
         caller has it already."""
         c = self.config
-        if c_q is None:
-            c_q = self.q_a_norm(self.q_a_proj(x))
-        q = self.q_b_proj(c_q)
+        if c.q_lora_rank is None:
+            q = self.q_proj(x)
+        else:
+            if c_q is None:
+                c_q = self.q_a_norm(self.q_a_proj(x))
+            q = self.q_b_proj(c_q)
         q = q.reshape(x.shape[:-1]
                       + (c.n_head, c.qk_nope_dim + c.qk_rope_dim))
         q_nope, q_pe = q[..., :c.qk_nope_dim], q[..., c.qk_nope_dim:]
@@ -127,6 +141,17 @@ class LatentAttention(nn.Module):
         if c.rope_interleave:
             q_pe, k_pe = deinterleave(q_pe), deinterleave(k_pe)
         return q_nope, q_pe, c_kv, k_pe
+
+    def _gated(self, x, y):
+        """``y`` [..., H * v_head_dim], each head's values times the
+        head's gate of ``x`` [..., E] where the config has one, through
+        ``o_proj``."""
+        c = self.config
+        if c.attn_head_gate:
+            gate = jax.nn.sigmoid(self.g_proj(x).astype(jnp.float32))
+            y = (y.reshape(*y.shape[:-1], c.n_head, -1)
+                 * gate[..., None].astype(y.dtype)).reshape(y.shape)
+        return self.o_proj(y)
 
     def prefill(self, x):
         """Full-sequence attention over ``x`` [B, T, E], expanded; returns
@@ -147,7 +172,7 @@ class LatentAttention(nn.Module):
         y = flash_attention(q, k, v, causal=True, sm_scale=self.sm_scale,
                             force=c.attn_impl)[..., :vd]
         y = y.transpose(0, 2, 1, 3).reshape(b, t, h * vd)
-        return self.o_proj(y), latent_rows(c_kv, k_pe[:, 0])
+        return self._gated(x, y), latent_rows(c_kv, k_pe[:, 0])
 
     @nn.nowrap  # no scope of its own: the caller's operations, as written
     def _expand(self, c_kv, k_pe):
@@ -252,7 +277,7 @@ class LatentAttention(nn.Module):
             y = self._absorbed(q_nope.reshape(b, t, c.n_head, -1),
                                q_pe.reshape(b, t, c.n_head, -1), pages,
                                block_tables, positions)
-        return self.o_proj(y.reshape(b * t, -1)), pages
+        return self._gated(x, y.reshape(b * t, -1)), pages
 
 
 def _as_pool(x):
@@ -389,7 +414,8 @@ class SparseLatentAttention(LatentAttention):
         index_pages, _ = _as_pool(keys.reshape(b, t, -1))
         y = self._chosen(q_nope, q_pe, q_idx, w_idx, pages, index_pages,
                          tables, positions.reshape(b, t))
-        return (self.o_proj(y).reshape(b, t, e), rows.reshape(b, t, -1),
+        return (self._gated(x.reshape(b * t, e), y).reshape(b, t, e),
+                rows.reshape(b, t, -1),
                 keys.reshape(b, t, -1))
 
     def step(self, x, pages, index_pages, dests, block_tables, positions):
@@ -407,4 +433,4 @@ class SparseLatentAttention(LatentAttention):
                                        keys)
         y = self._chosen(q_nope, q_pe, q_idx, w_idx, pages, index_pages,
                          block_tables, positions)
-        return self.o_proj(y), pages, index_pages
+        return self._gated(x, y), pages, index_pages

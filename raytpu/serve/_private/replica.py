@@ -18,6 +18,7 @@ from typing import Any, Dict, Optional
 
 import cloudpickle
 
+from raytpu.serve.handle import ChunkBatch
 from raytpu.util import serve_slo, task_events
 
 # Ambient per-request context (reference: serve.context._serve_request_context)
@@ -334,9 +335,19 @@ class Replica:
                         # loop: no pool thread runs for a chunk (an LLM
                         # replica's streams get a decode step's tokens
                         # through one call onto this loop).
+                        # A stream that can say what else it has ready
+                        # (``take_ready``: an LLM replica's, whose loop
+                        # fell a step or more behind its engine) sends
+                        # all of it in the one object: the store, the
+                        # wake-up and the fetch are paid an object, so a
+                        # hand-over that lags catches up and one that
+                        # keeps pace sends chunk by chunk as before.
+                        take = getattr(result, "take_ready", None)
                         try:
                             async for chunk in result:
-                                yield chunk
+                                more = take() if take is not None else ()
+                                yield (ChunkBatch((chunk, *more)) if more
+                                       else chunk)
                         finally:
                             # The stream ended, or its consumer went away
                             # (GeneratorExit, a cancel): close it now, so

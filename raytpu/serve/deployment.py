@@ -43,7 +43,23 @@ class Deployment:
         return Deployment(self._target, name, DeploymentConfig(**merged))
 
     def bind(self, *args, **kwargs) -> "Application":
-        return Application(DeploymentNode(self, args, kwargs))
+        """An application node of this deployment built from ``args``.
+
+        A target may say what its constructor's arguments imply for its
+        own deployment: ``deployment_options(*args, **kwargs)`` -> a dict
+        of :meth:`options` fields (a replica whose engine seats 128
+        sequences has to take 128 requests). An implied value stands
+        where the field is still at the serve layer's default; what the
+        decorator or ``.options(...)`` set is kept."""
+        dep = self
+        implied = getattr(self._target, "deployment_options", None)
+        if implied is not None:
+            defaults = DeploymentConfig()
+            updates = {k: v for k, v in implied(*args, **kwargs).items()
+                       if getattr(self.config, k) == getattr(defaults, k)}
+            if updates:
+                dep = self.options(**updates)
+        return Application(DeploymentNode(dep, args, kwargs))
 
     def __call__(self, *a, **kw):
         raise TypeError(

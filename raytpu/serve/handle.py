@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import asyncio
 import time
+from collections import deque
 from typing import Any, Dict, Optional
 
 import raytpu
@@ -34,6 +35,18 @@ class DeploymentResponse:
         return _async_get(self._ref).__await__()
 
 
+class ChunkBatch(tuple):
+    """Several chunks of one stream in the one object that carries them,
+    in order: what a replica sends when its handler's stream had more
+    than one chunk ready (``take_ready``; see
+    ``Replica.handle_request_streaming``), and what
+    :class:`DeploymentResponseGenerator` hands its consumer one by one.
+    A type of its own, so that a handler that yields tuples is left
+    alone."""
+
+    __slots__ = ()
+
+
 class DeploymentResponseGenerator:
     """Iterator over a streaming deployment response's *values* (each chunk
     the handler yielded), wrapping the underlying ObjectRefGenerator.
@@ -55,6 +68,8 @@ class DeploymentResponseGenerator:
         self._t_first = 0.0
         self._t_last = 0.0
         self._settled = False  # SLOs/waste booked (exactly once)
+        # Chunks of a ChunkBatch not handed out yet.
+        self._ready: deque = deque()
 
     @property
     def request_id(self) -> str:
@@ -66,14 +81,20 @@ class DeploymentResponseGenerator:
         return self
 
     def __next__(self) -> Any:
-        try:
-            val = raytpu.get(next(self._gen))
-        except StopIteration:
-            self._settle_ok()
-            raise
-        except Exception as e:
-            self._settle_failed(e)
-            raise
+        if self._ready:
+            val = self._ready.popleft()
+        else:
+            try:
+                val = raytpu.get(next(self._gen))
+            except StopIteration:
+                self._settle_ok()
+                raise
+            except Exception as e:
+                self._settle_failed(e)
+                raise
+            if type(val) is ChunkBatch:
+                self._ready.extend(val)
+                val = self._ready.popleft()
         self._n += 1
         now = time.monotonic()
         self._t_last = now

@@ -891,3 +891,228 @@ class TestAwaitedTokenStream:
             assert len(list(stream)) == 3
         finally:
             dep.shutdown()
+
+
+class TestDeploymentOptionsFromTheConstructor:
+    """``Deployment.bind`` asks the target what its constructor's
+    arguments imply (``LLMDeployment.deployment_options``): a replica
+    takes as many requests as its engine seats, and a caller that reaches
+    the deployment through ``bind`` alone states the rest in
+    ``engine_options["serve_options"]``."""
+
+    @staticmethod
+    def _config(dep=None, **engine_options):
+        dep = serve.LLMDeployment if dep is None else dep
+        return dep.bind(
+            model="llama", engine_options=engine_options
+        )._ingress.deployment.config
+
+    @pytest.mark.parametrize("seats, cap", [
+        (None, 100), (4, 100), (64, 100), (100, 100), (101, 101),
+        (128, 128)])
+    def test_a_replica_takes_as_many_requests_as_its_engine_seats(
+            self, seats, cap):
+        options = {} if seats is None else {"max_num_seqs": seats}
+        cfg = self._config(**options)
+        assert cfg.max_ongoing_requests == cap
+        # Nothing else moved: the serve layer's own defaults.
+        assert cfg.health_check_timeout_s == 30.0
+        assert cfg.health_check_period_s == 2.0
+        assert serve.LLMDeployment.config.max_ongoing_requests == 100
+
+    def test_engine_options_as_the_third_positional_argument(self):
+        app = serve.LLMDeployment.bind("llama", None, {"max_num_seqs": 128})
+        assert app._ingress.deployment.config.max_ongoing_requests == 128
+
+    def test_serve_options_are_the_deployments_and_not_the_engines(self):
+        cfg = self._config(
+            max_num_seqs=128,
+            serve_options={"health_check_timeout_s": 600.0})
+        assert cfg.health_check_timeout_s == 600.0
+        assert cfg.max_ongoing_requests == 128
+        cfg = self._config(
+            max_num_seqs=128, serve_options={"max_ongoing_requests": 256})
+        assert cfg.max_ongoing_requests == 256
+        # The engine never sees the key.
+        dep = serve.LLMDeployment._target(
+            model="llama", engine_options=dict(
+                ENGINE_OPTIONS,
+                serve_options={"health_check_timeout_s": 600.0}))
+        try:
+            assert dep._engine.scheduler.max_num_seqs == 4
+        finally:
+            dep.shutdown()
+
+    def test_what_options_set_is_kept(self):
+        dep = serve.LLMDeployment.options(max_ongoing_requests=7,
+                                          health_check_timeout_s=5.0)
+        cfg = self._config(dep, max_num_seqs=128, serve_options={
+            "health_check_timeout_s": 600.0})
+        assert cfg.max_ongoing_requests == 7
+        assert cfg.health_check_timeout_s == 5.0
+
+    def test_a_target_without_the_method_binds_as_before(self):
+        @serve.deployment(max_ongoing_requests=3)
+        class Plain:
+            def __init__(self, engine_options=None):
+                pass
+
+        node = Plain.bind(engine_options={"max_num_seqs": 128})._ingress
+        assert node.deployment is Plain
+        assert node.deployment.config.max_ongoing_requests == 3
+
+    def test_an_unknown_serve_option_is_refused_at_bind(self):
+        with pytest.raises((ValueError, AttributeError)):
+            self._config(serve_options={"no_such_option": 1})
+
+    def test_more_streams_than_the_serve_layers_cap_all_decode_at_once(
+            self, serve_instance):
+        """104 clients of an engine with 104 seats: under the serve
+        layer's cap of 100 the replica's semaphore held four back until
+        others finished (and, where requests last longer than the
+        router's 30 s, timed them out)."""
+        n = 104
+        app = serve.LLMDeployment.bind(
+            model="llama", seed=0, engine_options=dict(
+                ENGINE_OPTIONS, max_num_seqs=n, decode_buckets=[n]))
+        handle = serve.run(app, name="llm-seats", route_prefix=None)
+        got = {}
+
+        def consume(i):
+            got[i] = list(handle.generate.remote_streaming(
+                [i % 200 + 1, 7, 9], max_new_tokens=40))
+
+        threads = [threading.Thread(target=consume, args=(i,))
+                   for i in range(n)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=300)
+        assert not any(th.is_alive() for th in threads)
+        assert all(len(got[i]) == 40 for i in range(n))
+        hist = handle.stats.remote().result()["decode_batch_hist"]
+        assert max(int(k) for k in hist) == n, hist
+
+
+class _Burst:
+    """An async stream of ``range(n)`` that has up to three more chunks
+    ready after every one it gives."""
+
+    def __init__(self, n):
+        from collections import deque
+
+        self.items = deque(range(n))
+
+    def __aiter__(self):
+        return self
+
+    async def __anext__(self):
+        if not self.items:
+            raise StopAsyncIteration
+        return self.items.popleft()
+
+    def take_ready(self):
+        return tuple(self.items.popleft()
+                     for _ in range(min(3, len(self.items))))
+
+
+class TestChunksTravelTogether:
+    """A stream that has more than one chunk ready sends them in one
+    object (``ChunkBatch``), and the consumer gets them one by one: an
+    LLM replica whose loop lags its engine catches up (128 streams of a
+    12 ms step: 4,100 of 10,500 tokens a second delivered before, 8,900
+    after, here on the CPU with the engine faked)."""
+
+    def test_take_ready_gives_what_was_delivered_and_leaves_the_end(self):
+        import asyncio
+
+        from raytpu.inference.serving import _END, TokenStream, _deliver
+
+        class Dep:
+            @staticmethod
+            def _raise_if_dead():
+                pass
+
+        async def body():
+            stream = TokenStream(Dep, "r")
+            assert stream.take_ready() == ()
+            stream._offer((1,), {})           # queued: no loop awaits yet
+            assert await stream.__anext__() == 1
+            by_loop = {}
+            assert not stream._offer((2, 3), by_loop)
+            assert not stream._offer((4, _END), by_loop)
+            for batch in by_loop.values():
+                _deliver(batch)
+            assert await stream.__anext__() == 2
+            assert stream.take_ready() == (3, 4)
+            assert stream.take_ready() == ()
+            with pytest.raises(StopAsyncIteration):
+                await stream.__anext__()
+
+        asyncio.run(body())
+
+    def test_the_consumer_gets_a_batch_one_by_one(self):
+        from raytpu.serve.handle import (ChunkBatch,
+                                         DeploymentResponseGenerator)
+
+        objects = [ChunkBatch((1, 2, 3)), 4, (5, 6), ChunkBatch((7,))]
+        gen = DeploymentResponseGenerator(iter(objects))
+        import raytpu.serve.handle as handle_mod
+
+        real_get = handle_mod.raytpu.get
+        handle_mod.raytpu.get = lambda ref: ref
+        try:
+            # A handler's own tuple is a chunk, not a batch.
+            assert list(gen) == [1, 2, 3, 4, (5, 6), 7]
+            assert gen._n == 6
+        finally:
+            handle_mod.raytpu.get = real_get
+
+    def test_a_stream_with_chunks_ready_sends_fewer_objects(
+            self, serve_instance):
+        @serve.deployment
+        class Streams:
+            def burst(self, n):
+                return _Burst(n)
+
+        handle = serve.run(Streams.bind(), name="bursts", route_prefix=None)
+        gen = handle.burst.remote_streaming(10)
+        assert list(gen) == list(range(10))
+        # 4 + 4 + 2 chunks in three objects.
+        assert gen._gen._idx == 3
+        assert gen._n == 10
+
+    def test_llm_streams_that_lag_are_whole_and_in_order(
+            self, serve_instance):
+        """Eight clients that take their tokens late: the loop's streams
+        hold several tokens when their consumers come back, and every
+        client still receives its request's tokens, all and in order
+        (greedy: what the same replica gave a client that kept pace)."""
+        app = serve.LLMDeployment.bind(
+            model="llama", seed=0,
+            engine_options=dict(ENGINE_OPTIONS, max_num_seqs=8))
+        handle = serve.run(app, name="llm-lag", route_prefix=None)
+        prompts = [[(5 * i + j) % 200 + 1 for j in range(6)]
+                   for i in range(8)]
+        got = {}
+
+        def consume(key, i, lag):
+            out = []
+            for tok in handle.generate.remote_streaming(
+                    prompts[i], max_new_tokens=16):
+                out.append(tok)
+                if lag and len(out) % 8 == 1:
+                    time.sleep(0.2)   # the engine runs on meanwhile
+            got[key, i] = out
+
+        for lag in (False, True):
+            threads = [threading.Thread(target=consume, args=(lag, i, lag))
+                       for i in range(8)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=300)
+            assert not any(th.is_alive() for th in threads)
+        for i in range(8):
+            assert len(got[True, i]) == 16
+            assert got[True, i] == got[False, i], i

@@ -261,7 +261,8 @@ def test_a_step_with_seats_is_handed_what_the_loop_built(params, pages):
 
 
 def test_admission_stops_at_the_last_seat_with_pages_to_spare():
-    cache = PagedKVCache(1, 64, 4, 2, 16, state_shapes=[(2, 64)], seats=2)
+    cache = PagedKVCache(1, 64, 4, 2, 16, state_shapes=[[((2, 64), None)]],
+                         seats=2)
     sched = Scheduler(cache, max_num_seqs=4, max_model_len=96)
     for i in range(3):
         sched.add(Sequence(f"s{i}", [1, 2, 3]))
@@ -302,8 +303,8 @@ def test_prefix_cache_hand_off_and_a_sharded_engine_refuse(params):
     with pytest.raises(ValueError, match="keep a state"):
         InferenceEngine(TINY, params, enable_prefix_cache=True, **ENGINE)
     with pytest.raises(ValueError, match="keep a state"):
-        PrefixCache(PagedKVCache(1, 8, 4, 2, 16, state_shapes=[(2, 64)],
-                                 seats=2))
+        PrefixCache(PagedKVCache(
+            1, 8, 4, 2, 16, state_shapes=[[((2, 64), None)]], seats=2))
     with pytest.raises(ValueError, match="keep a state"):
         InferenceEngine(TINY, params, tp=2, **ENGINE)
     from raytpu.inference.serving import LLMDeployment
